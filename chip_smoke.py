@@ -1,0 +1,444 @@
+#!/usr/bin/env python3
+"""chip_smoke.py — the quickest proof the serving engine starts on the chip.
+
+Drives the serving main path once, through the entry points a user
+calls::
+
+    ServingEngine.run(trace) -> ServingEngine.step -> Transformer._serving_jit
+      -> layers.RaggedPagedAttention -> the compiled ragged paged kernel
+      -> the fused EP-MoE dispatch + Pallas grouped GEMMs
+
+Model: ``presets.deepseek_moe_16b`` at every width the preset carries
+(hidden 2048, 16 heads x 128, 16 KV heads, 64 experts top-6 at expert
+width 1408, vocab 102400, fp8 MoE wire, int8 weights/activations/KV).
+Depth is the ONLY cut: 28 layers -> 4 (the leading dense layer + three
+expert layers). Weights are random, from ``SEED``. Traffic: a seeded
+Poisson trace of 24 requests (prompts 256-1536, 16-32 new tokens)
+through chunked prefill over an int8 page pool.
+
+The one-chip leg always runs; with four devices visible the same leg
+runs again at tp=4 (4 KV heads and 16 experts per chip, the EP
+all-to-all over ICI), plus one pinned PALLAS_FUSED ``ops.ag_gemm`` and
+``ops.gemm_rs`` against their XLA twins. One process drives every chip.
+
+It FAILS (non-zero exit, reason on the last line, no result line)
+unless JAX's first device is a TPU, every request completes with finite
+logits, the engine never degraded / re-promoted / swallowed an
+exception, the model resolved the FUSED EP transport with the Pallas
+grouped GEMM, the lowered step carries the ragged kernel's Mosaic
+custom call, and one mixed prefill+decode batch agrees between the
+kernel and its XLA twin on the same ServingState (logits, not tokens;
+once as served, once with every activation-side quantization off —
+the sharper instrument, see ``PARITY_VIEWS``).
+
+Set-up/compile seconds are printed apart from run seconds; no speed is
+claimed. The last stdout line is
+``{"ok": true, "device": {"platform", "kind", "count"}}``.
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import faulthandler
+import gc
+import json
+import os
+import sys
+import tempfile
+import time
+
+SEED = 0
+MODEL_CUT = dict(n_layers=4, moe_layers=(1, 2, 3))
+ENGINE = dict(slots=16, token_budget=512, chunk=256, page=128, npages=1024)
+TRACE = dict(n_requests=24, mean_interarrival=2.0, len_lo=256,
+             len_hi=1537, max_new_lo=16, max_new_hi=33)
+#: the parity batch runs the dense-gather XLA twin, whose cost is
+#: tokens x pages_per_seq x page — so it gets its own small engine
+#: (same model, same params, same page size)
+PARITY_ENGINE = dict(slots=4, token_budget=64, chunk=32, page=128, npages=8)
+PARITY_PROMPTS = (40, 70, 50)           # arrivals 0, 1, 3; 6 new tokens
+#: Parity views: config overrides over the SAME params, and the bound on
+#: rms(kernel - twin) / rms(twin) over the batched rows' logits. Kernel
+#: and twin differ only inside attention, at bf16 rounding level (the
+#: kernel feeds the MXU bf16 probabilities and folds the int8 K/V scales
+#: in f32; the twin rounds dequantized K/V to bf16). What the logits
+#: show of that depends on how often the layers downstream re-quantize:
+#: every lossy step (int8 activation rows, the fp8 MoE wire) turns a
+#: sub-LSB difference into whole-LSB flips. "exact_acts" switches those
+#: off — int8 weights, bf16 activations and wire — so a wrong mask, page
+#: or scale fold in the kernel (one misattended position is ~1/20 of a
+#: row's softmax mass) cannot hide; "served" is the preset as it ships
+#: and only catches a path that is grossly off (unrelated logits score
+#: ~1.4). Measured on the v5e (my chip runs, PR 21; CHANGES.md has the
+#: table): exact_acts 0.0075 at tp=4; served 0.047 (tp=1), 0.064
+#: (tp=4), up to 0.069 across depth 2-4 variants. Bounds are 2-3x that.
+PARITY_VIEWS = {
+    "exact_acts": (dict(dense_act_quant=None, moe_act_quant=None,
+                        moe_wire_quant=None), 0.02),
+    "served": ({}, 0.15),
+}
+MAX_STEPS = 4000
+DEADLINE_S = 1150
+
+
+class SmokeFailure(Exception):
+    """A check of the smoke did not hold."""
+
+
+#: one entry per program JAX lowered in this process (None until
+#: ``leg`` hooks the listener) — how "nothing compiled in the warm
+#: pass" is counted, tiny eager programs included
+_lowered: list | None = None
+
+
+def programs_lowered() -> int:
+    global _lowered
+    if _lowered is None:
+        import jax
+
+        _lowered = []
+        jax.monitoring.register_event_duration_secs_listener(
+            lambda name, *a, **k:
+            name.endswith("jaxpr_to_mlir_module_duration")
+            and _lowered.append(name)
+        )
+    return len(_lowered)
+
+
+def say(**kw) -> None:
+    print(json.dumps(kw), flush=True)
+
+
+def need(cond, what: str) -> None:
+    if not cond:
+        raise SmokeFailure(what)
+
+
+def model_config():
+    from triton_distributed_tpu.models import presets
+
+    return presets.deepseek_moe_16b(**MODEL_CUT)
+
+
+def build(devices):
+    """Mesh, model and quantized params on ``devices`` — the repo's own
+    init -> shardings -> quantize_moe_weights -> quantize_dense_weights
+    sequence, with init jitted straight onto its shardings so the f32
+    tree never sits whole on device 0."""
+    import jax
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from triton_distributed_tpu.models import Transformer
+
+    mesh = Mesh(np.asarray(devices), ("x",))
+    model = Transformer(model_config(), mesh, tp_axis="x")
+    params = jax.jit(model.init, out_shardings=model.shardings())(
+        jax.random.PRNGKey(SEED)
+    )
+    params = model.quantize_moe_weights(params)
+    params = model.quantize_dense_weights(params)
+    return model, jax.block_until_ready(params)
+
+
+def parity(model, params, on_chip: bool, tol: float) -> dict:
+    """One mixed prefill+decode batch through the kernel AND its XLA
+    twin from copies of the same ServingState, rms-compared within
+    ``tol``; also lowers that step and looks for the ragged kernel's
+    Mosaic custom call."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from triton_distributed_tpu.serving import (
+        EngineConfig,
+        Request,
+        ServingEngine,
+    )
+
+    class Probe(ServingEngine):
+        pair = None
+        lowered = None
+
+        def _run_device(self, arrays, block_q):
+            q_lens = arrays[4]
+            decode = [s for s in np.flatnonzero(q_lens == 1)
+                      if self.slot_req[s].generated]
+            if self.pair is not None or not decode \
+                    or not (q_lens > 1).any():
+                return super()._run_device(arrays, block_q)
+            self.lowered = self._step_jit().lower(
+                *self._step_args(arrays, block_q)
+            ).as_text()
+            before = jax.tree.map(jnp.copy, (self.state, self.moe_state))
+            got = super()._run_device(arrays, block_q)
+            after = (self.state, self.moe_state)
+            self.state, self.moe_state = before
+            self.use_pallas = False
+            try:
+                want = super()._run_device(arrays, block_q)
+            finally:
+                self.use_pallas = True
+            self.state, self.moe_state = after
+            rows = q_lens > 0
+            self.pair = (got[rows], want[rows], q_lens[rows].tolist())
+            return got
+
+    rng = np.random.default_rng(SEED)
+    trace = [
+        Request(rid=i, max_new=6, arrival=float(t),
+                prompt=rng.integers(0, model.config.vocab, (n,))
+                .astype(np.int32))
+        for i, (n, t) in enumerate(zip(PARITY_PROMPTS, (0, 1, 3)))
+    ]
+    eng = Probe(model, params, EngineConfig(**PARITY_ENGINE),
+                propagate_failures=True)
+    stats = eng.run(trace, max_steps=MAX_STEPS)
+    need(stats.completed == len(trace), "parity trace did not complete")
+    need(eng.pair is not None, "parity trace never formed a mixed batch")
+    got, want, q_lens = eng.pair
+    need(np.isfinite(got).all() and np.isfinite(want).all(),
+         "non-finite logits in the parity batch")
+    rms = float(np.sqrt(np.mean((got - want) ** 2) / np.mean(want ** 2)))
+    need(rms <= tol,
+         f"kernel vs XLA twin logits differ by rms {rms:.4f} of "
+         f"rms|logit| (tolerance {tol})")
+    if on_chip:
+        need(any("tpu_custom_call" in ln
+                 and 'kernel_name = "ragged_paged_attention' in ln
+                 for ln in eng.lowered.splitlines()),
+             "the lowered serving step holds no ragged_paged_attention "
+             "tpu_custom_call")
+    return {"rows_q_lens": q_lens, "rms_rel_err": rms, "tol": tol,
+            "max_abs_err": float(np.abs(got - want).max()),
+            "logit_absmax": float(np.abs(want).max())}
+
+
+def engine(model, params, on_chip: bool):
+    """The ServingEngine both passes run on, failures propagating."""
+    from triton_distributed_tpu.serving import EngineConfig, ServingEngine
+    from triton_distributed_tpu.tune.schedule import GRID_DEFAULT
+
+    eng = ServingEngine(model, params, EngineConfig(**ENGINE),
+                        propagate_failures=True)
+    need(eng.grid_schedule is GRID_DEFAULT,
+         f"engine resolved a stored schedule ({eng.grid_schedule}) from "
+         "a store this run did not write")
+    if on_chip:
+        ctx = model._moe_ep_ctx(
+            -(-eng._t_pad // model.token_shards), inference=True,
+            weights_quantized=True,
+        )
+        need(ctx.transport == "fused" and ctx.use_pallas_gemm
+             and eng.moe_state is not None,
+             f"EP context resolved transport={ctx.transport!r} "
+             f"use_pallas_gemm={ctx.use_pallas_gemm}")
+    return eng
+
+
+def serve(eng):
+    """One pass of the seeded trace through ``eng``, arrivals counted
+    from the engine's clock (an idle engine replays the same schedule);
+    returns (trace, wall seconds, engine steps)."""
+    import numpy as np
+
+    from triton_distributed_tpu.serving import poisson_trace
+
+    vocab = eng.model.config.vocab
+    trace = poisson_trace(seed=SEED, vocab=vocab, **TRACE)
+    for r in trace:
+        r.arrival += eng.step_count
+    done, steps = eng.stats.completed, len(eng.stats.step_times)
+    t0 = time.perf_counter()
+    stats = eng.run(trace, max_steps=MAX_STEPS)
+    wall = time.perf_counter() - t0
+    need(stats.completed - done == len(trace)
+         and all(r.done for r in trace),
+         f"{stats.completed - done}/{len(trace)} requests completed")
+    need(all(len(r.generated) == r.max_new for r in trace),
+         "a request finished short of max_new")
+    toks = np.concatenate([r.generated for r in trace])
+    need(((toks >= 0) & (toks < vocab)).all(),
+         "generated token outside the vocabulary")
+    # non-finite logits raise inside ServingEngine._sample
+    need(not stats.degraded and stats.repromotions == 0
+         and not stats.failures,
+         f"engine degraded={stats.degraded} "
+         f"repromotions={stats.repromotions} failures={stats.failures}")
+    return trace, wall, len(stats.step_times) - steps
+
+
+def spread(eng, params, n: int) -> dict:
+    """Params, page pools and LL state must each sit 1/n per device."""
+    big = {
+        "moe_up": params["blocks"][1]["moe_up"]["q"],
+        "kv_pool": eng.state.layers[1][0]["q"],
+    }
+    if eng.moe_state is not None:       # always, on the chip (engine())
+        big["ll_dispatch"] = eng.moe_state[1].disp_tok
+    out = {}
+    for name, x in big.items():
+        devs = {s.device.id for s in x.addressable_shards}
+        share = x.addressable_shards[0].data.nbytes / x.nbytes
+        need(len(devs) == n and abs(share - 1 / n) < 1e-9,
+             f"{name} is not spread 1/{n} per device: devices "
+             f"{sorted(devs)}, shard share {share:.3f}")
+        out[name] = list(x.addressable_shards[0].data.shape)
+    return out
+
+
+def overlap_ops(mesh) -> dict:
+    """Pinned PALLAS_FUSED ag_gemm / gemm_rs (the first remote DMAs
+    this repo issues on silicon) against their XLA twins, at the
+    model's projection widths."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import NamedSharding
+    from jax.sharding import PartitionSpec as P
+
+    from triton_distributed_tpu import ops
+    from triton_distributed_tpu.kernels.ag_gemm import AGGemmMethod
+    from triton_distributed_tpu.kernels.gemm_rs import GemmRSMethod
+    from triton_distributed_tpu.tools.native import xla_ag_gemm, xla_gemm_rs
+
+    def rnd(i, shape, *spec):
+        x = jax.random.normal(jax.random.PRNGKey(SEED + i), shape,
+                              jnp.bfloat16)
+        return jax.device_put(x, NamedSharding(mesh, P(*spec)))
+
+    m, k, n_qkv, n_o = 2048, 2048, 6144, 2048
+    cases = {
+        "ag_gemm": (
+            ops.ag_gemm, xla_ag_gemm,
+            ops.create_ag_gemm_context(
+                mesh, "x", method=AGGemmMethod.PALLAS_FUSED),
+            rnd(1, (m, k), "x"), rnd(2, (k, n_qkv), None, "x"),
+        ),
+        "gemm_rs": (
+            ops.gemm_rs, xla_gemm_rs,
+            ops.create_gemm_rs_context(
+                mesh, "x", method=GemmRSMethod.PALLAS_FUSED),
+            rnd(3, (m, k), None, "x"), rnd(4, (k, n_o), "x"),
+        ),
+    }
+    out = {}
+    for name, (op, twin, ctx, a, b) in cases.items():
+        got = np.asarray(op(a, b, ctx), np.float32)
+        want = np.asarray(twin(a, b, mesh, "x"), np.float32)
+        err = float(np.abs(got - want).max() / np.abs(want).max())
+        # bf16 outputs of one f32-accumulated K=2048 product: the two
+        # engines may round the last bit apart, no more
+        need(np.isfinite(got).all() and err <= 2.0 ** -6,
+             f"{name} PALLAS_FUSED vs XLA twin: rel err {err:.5f}")
+        out[name] = {"shape": list(got.shape), "rel_err": err}
+    return out
+
+
+def leg(devices, on_chip: bool = True) -> dict:
+    """The whole smoke on one device set."""
+    n = len(devices)
+    built = [programs_lowered()]
+    t0 = time.perf_counter()
+    model, params = build(devices)
+    t_build = time.perf_counter() - t0
+    rec = {"leg": f"{n}chip", "tp": model.tp, "model": "deepseek_moe_16b",
+           "cut": MODEL_CUT, "engine": ENGINE, "build_s": round(t_build, 2)}
+
+    t0 = time.perf_counter()
+    rec["parity"] = {
+        view: parity(
+            type(model)(dataclasses.replace(model.config, **overrides),
+                        model.mesh, tp_axis=model.tp_axis),
+            params, on_chip, tol,
+        )
+        for view, (overrides, tol) in PARITY_VIEWS.items()
+    }
+    rec["parity_s"] = round(time.perf_counter() - t0, 2)
+
+    # pass 1 compiles every block_q rung the trace touches; pass 2 is
+    # the same trace again with nothing left to compile. ONE engine
+    # serves both: a new engine's LL MoE state carries a fresh static
+    # ``instance`` id, so every step program would compile again
+    built.append(programs_lowered())
+    eng = engine(model, params, on_chip)
+    cold, t_cold, _ = serve(eng)
+    built.append(programs_lowered())
+    warm, t_warm, steps = serve(eng)
+    built.append(programs_lowered())
+    need(built[3] == built[2],
+         f"the warm pass lowered {built[3] - built[2]} new program(s)")
+    need([r.generated for r in cold] == [r.generated for r in warm],
+         "the same seeded trace produced different token streams twice")
+    if n > 1:
+        rec["spread"] = spread(eng, params, n)
+        rec["overlap_ops"] = overlap_ops(model.mesh)
+    st = eng.stats
+    rec.update(
+        schedule=str(eng.grid_schedule), schedule_source="default",
+        programs_lowered={"setup": built[1] - built[0],
+                          "cold_pass": built[2] - built[1],
+                          "warm_pass": built[3] - built[2]},
+        requests=len(warm),
+        steps=steps,
+        prompt_tokens=sum(len(r.prompt) for r in warm),
+        generated_tokens=sum(len(r.generated) for r in warm),
+        evictions=st.evictions,
+        degraded=st.degraded, repromotions=st.repromotions,
+        failures=st.failures,
+        setup_compile_s=round(t_build + rec["parity_s"] + t_cold, 2),
+        run_s=round(t_warm, 2),
+        hbm_bytes_in_use=[
+            (d.memory_stats() or {}).get("bytes_in_use") for d in devices
+        ],
+    )
+    del eng, params, model
+    gc.collect()                        # hand the HBM to the next leg
+    return rec
+
+
+def main() -> int:
+    t_start = time.perf_counter()
+    # a wedged collective must end as a failure with every thread's
+    # stack on stderr, inside the smoke's 1200 s allowance — not as a
+    # hang (fires from a watchdog thread even under a blocked C++ wait)
+    faulthandler.dump_traceback_later(DEADLINE_S, exit=True)
+    import jax
+
+    devs = jax.devices()
+    device = {"platform": devs[0].platform, "kind": devs[0].device_kind,
+              "count": len(devs)}
+    if device["platform"] != "tpu":
+        print(f"chip_smoke: FAIL: JAX's first device is "
+              f"{device['platform']!r} ({device['kind']}), not a TPU",
+              flush=True)
+        return 2
+
+    from triton_distributed_tpu.config import enable_compile_cache
+
+    # hermetic tuning state: no measuring autotuner, and a schedule
+    # store this run creates empty (the cwd-relative .autotune_logs/ of
+    # an earlier run must not steer the engine build)
+    with tempfile.TemporaryDirectory() as store:
+        os.environ["TDTPU_AUTOTUNE"] = "0"
+        os.environ["TDTPU_AUTOTUNE_LOG_DIR"] = store
+        say(device=device, compile_cache=enable_compile_cache(),
+            jax=jax.__version__, seed=SEED)
+        try:
+            say(**leg(devs[:1]))
+            if len(devs) >= 4:
+                say(**leg(devs[:4]))
+        except Exception as e:
+            import traceback
+
+            traceback.print_exc()
+            print(f"chip_smoke: FAIL: {type(e).__name__}: {e}", flush=True)
+            return 1
+    say(total_s=round(time.perf_counter() - t_start, 2),
+        speed="not measured")
+    say(ok=True, device=device)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -4,8 +4,9 @@ The reference tests are torchrun multi-process scripts on real GPUs
 (SURVEY.md §4). Here every test runs single-process on a virtual 8-device
 CPU mesh; Pallas kernels execute under the TPU interpreter
 (InterpretParams), which faithfully simulates remote DMA + semaphores.
-Real-TPU execution of the same kernels is covered by bench.py and the
-driver's dryrun.
+Whether the same kernels COMPILE is tests/test_aot_topology.py's job
+(real Mosaic, unattached v5e topology); that they RUN on the chip is
+chip_smoke.py's.
 """
 
 import faulthandler
@@ -25,38 +26,6 @@ import numpy as np  # noqa: E402
 import pytest  # noqa: E402
 from jax.sharding import Mesh  # noqa: E402
 
-# importing config applies the jax API-drift compat shims (ensure_compat)
-# BEFORE any fixture/test touches the shimmed names
-from triton_distributed_tpu import config as _tdtpu_config  # noqa: E402
-
-#: does this jax ship the TPU-simulation interpreter? Tests that need
-#: faithful remote-DMA/semaphore simulation skip when it is absent
-#: (collectives then only run through their XLA-native fallbacks).
-HAS_TPU_SIM = _tdtpu_config.has_tpu_interpreter()
-
-requires_tpu_sim = pytest.mark.skipif(
-    not HAS_TPU_SIM,
-    reason="jax lacks the Pallas TPU-simulation interpreter "
-    "(pltpu.InterpretParams)",
-)
-
-
-#: Test modules whose every test exercises Pallas device semantics the
-#: plain interpreter cannot provide (remote DMA, semaphores, the race
-#: detector). Skipped wholesale when the TPU-simulation interpreter is
-#: absent — the XLA-native degradation paths are covered elsewhere.
-_SIM_REQUIRED_MODULES = frozenset({
-    "test_lang_shmem", "test_races", "test_chaos", "test_ep_moe",
-    "test_moe_tp", "test_ring_attention",
-})
-
-#: Individual tests known to WEDGE (not fail) without the simulator —
-#: e.g. the LL-state decode scans hang in an XLA CPU collective
-#: rendezvous on pre-interpreter jax. A wedge trips the per-test
-#: faulthandler deadline, which hard-exits the whole suite, so these
-#: are skipped up front.
-_SIM_REQUIRED_KEYWORDS = ("ll_state", "fused_ll")
-
 
 def pytest_collection_modifyitems(items):
     """Run the tuned-engine-selection tests LAST. They bench many
@@ -66,29 +35,15 @@ def pytest_collection_modifyitems(items):
     deadlocks in the ordered-effects chain (observed as a hang in
     Token.block_until_ready). The full suite's alphabetical order
     already put test_tune last — this makes that load-bearing ordering
-    explicit so subset runs are safe too.
-
-    Also applies the no-TPU-simulator skips (see
-    ``_SIM_REQUIRED_MODULES`` / ``_SIM_REQUIRED_KEYWORDS``)."""
+    explicit so subset runs are safe too."""
     items.sort(key=lambda it: "TestTunedEngineSelection" in it.nodeid)
-    if not HAS_TPU_SIM:
-        skip = pytest.mark.skip(
-            reason="requires the Pallas TPU-simulation interpreter "
-            "(pltpu.InterpretParams), absent from this jax"
-        )
-        for it in items:
-            if it.module.__name__ in _SIM_REQUIRED_MODULES or any(
-                k in it.nodeid for k in _SIM_REQUIRED_KEYWORDS
-            ):
-                it.add_marker(skip)
 
 
 @pytest.fixture(autouse=True)
 def _fresh_interpreter_state():
     """Isolate tests: the TPU interpreter keeps global shared memory /
     semaphore state per process; stale state from a failed kernel must not
-    leak into the next test. (On pre-interpreter jax the compat shim makes
-    this a no-op — there is no global state to reset.)"""
+    leak into the next test."""
     from jax.experimental.pallas import tpu as pltpu
 
     pltpu.reset_tpu_interpret_mode_state()

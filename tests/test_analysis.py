@@ -449,6 +449,34 @@ class TestMosaicCompat:
         assert _rules(f) == ["MC007"]
         assert "sublane" in f[0].message
 
+    @pytest.mark.parametrize("fixture, rule, phrase", [
+        ("unproven_tile_slice", "MC008", "divisibility proof"),
+        ("thin_lane_dma", "MC009", "trailing dim of 1"),
+        ("i1_vector_select", "MC010", "i1 vector"),
+    ])
+    def test_ragged_bringup_refusals_flagged(self, fixture, rule, phrase):
+        """MC008–MC010: the three constructs Mosaic refused on the
+        ragged paged kernel's first real compile (jax 0.9.0, AOT vs
+        v5e), each reproduced alone — the pre-flight must not call a
+        kernel carrying one clean again."""
+        spec, in_shapes = getattr(fixtures, fixture)()
+        f = mosaic_compat.preflight_spec(
+            spec, in_shapes(4), 4, kernel_name="fx", site="fixture"
+        )
+        assert _rules(f) == [rule]
+        assert phrase in f[0].message
+
+    def test_scan_enters_pl_when_bodies(self):
+        """Every ``pl.when`` stages a ``cond`` whose branches ride a
+        TUPLE param — the scan must walk them (it once did not, and
+        reported the ragged kernel clean with its whole row body
+        unscanned)."""
+        kj, = mosaic_compat.trace_family_kernels(
+            families()["flash_decode.ragged_paged"], 4
+        )
+        prims = {e.primitive.name for e in mosaic_compat._walk_jaxprs(kj)}
+        assert {"dma_start", "dot_general", "multiple_of"} <= prims
+
     def test_fp8_wire_family_flags_mc001_when_forced(self, monkeypatch):
         """The KNOWN f8-cast construct, on a real registry family: with
         the toolchain override asserting in-kernel f8 support, the fp8
@@ -537,7 +565,7 @@ class TestEventModel:
             "SL001", "SL002", "SL003", "SL004", "SL005", "SL006", "SL007",
             "SL008", "SL009", "SL010", "SL011", "SL012", "SL013",
             "MC001", "MC002", "MC003", "MC004", "MC005", "MC006",
-            "MC007",
+            "MC007", "MC008", "MC009", "MC010",
             "SV001", "SV002", "SV003", "SV004", "SV005", "SV006",
             "SV007",
         }

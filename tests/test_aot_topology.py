@@ -16,12 +16,15 @@ races) — that remains the interpreter suite's job (tests/test_races.py,
 chaos suite). Compile + simulate together are the strongest validation
 available without multi-chip hardware.
 
-Marked ``slow`` (round 6): constructing the unattached v5e topology
-plus the full XLA+Mosaic compiles costs ~8 minutes of the tier-1
-budget on the 1-core CI host (462 s of it in the module fixture alone
-— VERDICT r5 noted the suite no longer fit 10 minutes). Run it
-explicitly with ``pytest -m slow tests/test_aot_topology.py`` (nightly
-and before any kernel-touching merge).
+Marked ``slow`` (35 full XLA+Mosaic compiles, ~1 min on the PR-21
+sandbox). Run it explicitly and SERIALLY (the unattached topology cannot be
+built twice at once in xdist workers)::
+
+    pytest -m slow tests/test_aot_topology.py -p no:xdist
+
+before any kernel-touching merge. libtpu ships with the installation,
+so a topology that cannot be built is a FAILURE of this suite, never a
+skip.
 """
 
 
@@ -45,16 +48,9 @@ def _make_topology_mesh():
 
 @pytest.fixture(scope="module")
 def tmesh():
-    """v5e-8 compile-only topology mesh. If the installed libtpu cannot
-    construct one, the skip reason names the failing API (docs/PERF.md
-    records the same contract)."""
-    try:
-        return _make_topology_mesh()
-    except Exception as e:  # pragma: no cover - environment-dependent
-        pytest.skip(
-            "jax.experimental.topologies.get_topology_desc('v5e:2x4') "
-            f"unavailable: {type(e).__name__}: {e}"
-        )
+    """v5e-8 compile-only topology mesh (an error here fails every
+    test of the module — see the module docstring)."""
+    return _make_topology_mesh()
 
 
 @pytest.fixture(autouse=True)
@@ -584,4 +580,136 @@ class TestCollectiveFamilies:
             _sds(tmesh, (b, hkv, s_len, d), jnp.bfloat16, None, None, "x"),
             _sds(tmesh, (b, hkv, s_len, d), jnp.bfloat16, None, None, "x"),
             _sds(tmesh, (b,), jnp.int32),
+        )
+
+
+# ------------------------------------------------ the serving main path
+
+#: chip_smoke.py's one-chip kernel geometry: deepseek_moe_16b heads
+#: (16 KV heads × 128, G = 1), 128-row pages, 16 slots, a 512-token
+#: budget + 256-token parking zone.
+_SMOKE = dict(r=16, pps=64, npages=256, t=768, hkv=16, g=1, d=128,
+              page=128)
+
+
+class TestRaggedPagedAttention:
+    """The one kernel every ``ServingEngine`` step launches, through the
+    full Mosaic backend at the smoke's geometry. jax 0.9.0's Mosaic
+    refused the pre-PR-21 kernel three times over (deny rules
+    MC008–MC010 pin each construct); these cases keep it compiling."""
+
+    @pytest.mark.parametrize("block_q", [8, 256])
+    @pytest.mark.parametrize("topo", [False, True])
+    @pytest.mark.parametrize("quant", [False, True])
+    def test_ragged_paged_compiles(self, tmesh, quant, topo, block_q):
+        from triton_distributed_tpu.kernels.ragged_paged_attention import (
+            causal_topologies,
+            cp_topology_row,
+            ragged_paged_attention,
+            topo_width,
+            tree_topology_row,
+        )
+
+        s = _SMOKE
+        kw = dict(group=s["g"], block_q=block_q, interpret=False)
+        if topo:
+            # row kinds are DATA (one compiled kernel serves them all);
+            # a TREE and a CP row ride along as a baked constant so the
+            # lowered module carries the operand exactly as a
+            # speculative / context-parallel step would
+            w = topo_width(block_q)
+            tp = causal_topologies(s["r"], w)
+            tp[1] = tree_topology_row([-1, 0, 0, 2], w)
+            tp[2] = cp_topology_row(384, w)
+            kw["topologies"] = jnp.asarray(tp)
+        pool_dt = jnp.int8 if quant else jnp.bfloat16
+        pool = (s["npages"], s["hkv"], s["page"], s["d"])
+        args = [
+            _sds(tmesh, (s["hkv"], s["t"] * s["g"], s["d"]), jnp.bfloat16),
+            _sds(tmesh, pool, pool_dt),
+            _sds(tmesh, pool, pool_dt),
+            _sds(tmesh, (s["r"],), jnp.int32),
+            _sds(tmesh, (s["r"],), jnp.int32),
+            _sds(tmesh, (s["r"],), jnp.int32),
+            _sds(tmesh, (s["r"], s["pps"]), jnp.int32),
+        ]
+        if quant:
+            args += [_sds(tmesh, pool[:3], jnp.float32)] * 2
+
+        def local(*a):
+            scales = dict(k_scale=a[7], v_scale=a[8]) if quant else {}
+            return ragged_paged_attention(*a[:7], **kw, **scales)
+
+        fn = jax.jit(jax.shard_map(
+            local, mesh=tmesh, in_specs=(P(),) * len(args),
+            out_specs=(P(), P()), check_vma=False,
+        ))
+        _assert_compiles(fn, *args)
+
+    def test_int8_small_page_refused_cleanly(self, tmesh):
+        """An int8 pool's per-page scale plane is a (1, page) DMA
+        window: below 128 lanes Mosaic refuses it, so the entry must
+        say so itself instead of surfacing a MosaicError."""
+        from triton_distributed_tpu.kernels.ragged_paged_attention import (
+            ragged_paged_attention,
+        )
+
+        pool = jax.ShapeDtypeStruct((8, 4, 32, 128), jnp.int8)
+        sc = jax.ShapeDtypeStruct((8, 4, 32), jnp.float32)
+        i32 = lambda *s: jax.ShapeDtypeStruct(s, jnp.int32)  # noqa: E731
+        with pytest.raises(ValueError, match="page % 128"):
+            jax.eval_shape(
+                lambda q, k, v, ks, vs, *m: ragged_paged_attention(
+                    q, k, v, *m, group=1, k_scale=ks, v_scale=vs,
+                    interpret=False,
+                ),
+                jax.ShapeDtypeStruct((4, 64, 128), jnp.bfloat16),
+                pool, pool, sc, sc, i32(2), i32(2), i32(2), i32(2, 4),
+            )
+
+
+class TestRecordOnly:
+    """Compile attempts for families OFF the serving main path that had
+    never met Mosaic. A refusal is recorded (``-rx`` prints its text,
+    CHANGES.md PR 21 quotes it), not fixed here."""
+
+    @staticmethod
+    def _attempt(fn, *args):
+        try:
+            _assert_compiles(fn, *args)
+        except Exception as e:
+            pytest.xfail(f"{type(e).__name__}: {str(e)[:600]}")
+
+    def test_kv_ship_pages(self, tmesh):
+        from triton_distributed_tpu.kernels.kv_ship import _build_kv_ship
+        from triton_distributed_tpu.lang import wire as wirelib
+
+        pages, rows, cols = 4, 256, 128
+        call = _build_kv_ship(tmesh, "x", pages, rows, cols, 14,
+                              interp_key())
+        fn = jax.jit(jax.shard_map(
+            call, mesh=tmesh, in_specs=(P("x"),) * 3,
+            out_specs=(P("x"), P("x")), check_vma=False,
+        ))
+        self._attempt(
+            fn,
+            _sds(tmesh, (8 * pages,), jnp.int32, "x"),
+            _sds(tmesh, (8 * pages * rows, cols), jnp.int8, "x"),
+            _sds(tmesh, (8 * pages * rows, wirelib.SCALE_LANES),
+                 jnp.float32, "x"),
+        )
+
+    def test_fused_ag_gemm_int8_mxu_wire(self, tmesh):
+        from triton_distributed_tpu.kernels.ag_gemm import _build_fused
+
+        m, k, nn = 1024, 2048, 2048
+        fn = _build_fused(
+            tmesh, "x", (), (m, k), (k, nn), jnp.dtype(jnp.bfloat16),
+            jnp.dtype(jnp.bfloat16), 5, interp_key(), False, None,
+            "int8-mxu",
+        )
+        self._attempt(
+            fn,
+            _sds(tmesh, (m, k), jnp.bfloat16, "x"),
+            _sds(tmesh, (k, nn), jnp.bfloat16, None, "x"),
         )

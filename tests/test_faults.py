@@ -27,7 +27,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import HAS_TPU_SIM, requires_tpu_sim
 
 from triton_distributed_tpu.runtime import (
     AllGatherMethod,
@@ -252,15 +251,6 @@ class TestParsePlan:
 # ---------------------------------------------------------------- watchdog
 
 
-def _ag_method():
-    """The ring allgather when the simulator exists; the (equally
-    instrumented) XLA fallback engine otherwise."""
-    return (
-        AllGatherMethod.RING_1D if HAS_TPU_SIM
-        else AllGatherMethod.XLA_FALLBACK
-    )
-
-
 @pytest.mark.chaos
 class TestWatchdog:
     def test_detects_single_peer_stall_and_raises(self, mesh8):
@@ -277,7 +267,7 @@ class TestWatchdog:
             with pytest.raises(WatchdogTimeout) as exc:
                 with collective_watchdog(deadline=1.5):
                     y = all_gather(
-                        x, mesh8, "x", method=_ag_method(), collective_id=2
+                        x, mesh8, "x", method=AllGatherMethod.RING_1D, collective_id=2
                     )
                     np.asarray(y)       # force completion inside the guard
         elapsed = time.monotonic() - t0
@@ -303,7 +293,7 @@ class TestWatchdog:
             try:
                 with collective_watchdog(deadline=1.0):
                     got["y"] = np.asarray(all_gather(
-                        x, mesh8, "x", method=_ag_method(), collective_id=2
+                        x, mesh8, "x", method=AllGatherMethod.RING_1D, collective_id=2
                     ))
             except WatchdogTimeout:
                 pass
@@ -314,7 +304,7 @@ class TestWatchdog:
 
         x = jnp.ones((64, 128), jnp.float32)
         with collective_watchdog(deadline=30.0):
-            y = np.asarray(all_gather(x, mesh8, "x", method=_ag_method()))
+            y = np.asarray(all_gather(x, mesh8, "x", method=AllGatherMethod.RING_1D))
         np.testing.assert_array_equal(y, np.ones((64, 128), np.float32))
         assert watchdog.last_trip() is None
 
@@ -361,7 +351,6 @@ class TestWatchdog:
 
 @pytest.mark.chaos
 class TestInjectionEndToEnd:
-    @requires_tpu_sim
     def test_delay_plan_bit_correct_and_deterministic(self, mesh8):
         """Seeded per-(rank, step) delays widen race windows without
         changing results, twice over (ISSUE acceptance: same seed →
@@ -381,7 +370,6 @@ class TestInjectionEndToEnd:
         np.testing.assert_array_equal(outs[0], np.asarray(x))
         np.testing.assert_array_equal(outs[0], outs[1])
 
-    @requires_tpu_sim
     def test_corruption_deterministic_under_seed(self, mesh8):
         """A corruption fault visibly lands (the result differs from
         truth at the targeted shard) and is bit-identical across two
@@ -428,7 +416,7 @@ class TestGracefulDegradation:
             demoted = np.asarray(ag_gemm_safe(a, b, ctx), np.float32)
         assert_allclose(demoted, healthy, atol=1e-5, rtol=1e-5)
         # and the demotion is transient: plan cleared -> fused again
-        assert preflight(ctx, "ag_gemm", a, b) is None or not HAS_TPU_SIM
+        assert preflight(ctx, "ag_gemm", a, b) is None
 
     def test_gemm_rs_demotes_on_watchdog_trip(self, mesh8):
         from triton_distributed_tpu.ops import (

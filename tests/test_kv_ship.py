@@ -320,7 +320,53 @@ class TestDisaggregatedEngine:
     ):
         """First DCN-wire failure flips the engine onto the
         device_put fallback (tools.native.xla_kv_ship) — results
-        identical, stats record the degradation."""
+        identical to the HEALTHY wire's (the oracle is the same engine
+        without the injected failure: pages ship verbatim either way,
+        so the degrade may not move a token), stats record the
+        degradation and what failed."""
+        import triton_distributed_tpu.serving.engine as engine_mod
+
+        mp, pp, md, pd = models1
+        _, _, hybrid = roles1
+
+        def build():
+            return DisaggregatedEngine(
+                mp, pp, md, pd,
+                EngineConfig(slots=2, token_budget=32, chunk=8, page=8,
+                             npages=16),
+                hybrid_mesh=hybrid, dcn_axis="dcn", transport="dcn",
+            ), Request(rid=0, prompt=np.arange(11, dtype=np.int32),
+                       max_new=3, arrival=0.0)
+
+        healthy, want = build()
+        assert healthy.run([want], max_ticks=100).completed == 1
+        assert not healthy.stats.degraded_transport
+        assert len(want.generated) == 3
+        eng, req = build()
+
+        def boom(self, qpay, spay):
+            raise RuntimeError("injected wire failure")
+
+        monkeypatch.setattr(
+            engine_mod.DisaggregatedEngine, "_transport_dcn", boom
+        )
+        stats = eng.run([req], max_ticks=100)
+        assert stats.degraded_transport
+        assert eng.transport == "xla"
+        assert stats.completed == 1
+        assert req.generated == want.generated
+        # the swallowed exception is kept: what failed, and when
+        assert stats.transport_failures
+        assert "injected wire failure" in \
+            stats.transport_failures[0]["error"]
+        assert stats.transport_failures[0]["site"] == "kv_ship"
+
+    def test_transport_failure_propagates_on_request(
+        self, models1, roles1, monkeypatch,
+    ):
+        """``propagate_failures=True``: the same injected failure is
+        recorded and RAISED instead of degrading — for callers whose
+        result must not come from the fallback."""
         import triton_distributed_tpu.serving.engine as engine_mod
 
         mp, pp, md, pd = models1
@@ -330,6 +376,7 @@ class TestDisaggregatedEngine:
             EngineConfig(slots=2, token_budget=32, chunk=8, page=8,
                          npages=16),
             hybrid_mesh=hybrid, dcn_axis="dcn", transport="dcn",
+            propagate_failures=True,
         )
 
         def boom(self, qpay, spay):
@@ -340,11 +387,11 @@ class TestDisaggregatedEngine:
         )
         req = Request(rid=0, prompt=np.arange(11, dtype=np.int32),
                       max_new=3, arrival=0.0)
-        stats = eng.run([req], max_ticks=100)
-        assert stats.degraded_transport
-        assert eng.transport == "xla"
-        assert stats.completed == 1
-        assert req.generated == _reference_tokens(mp, pp, req)
+        with pytest.raises(RuntimeError, match="injected wire failure"):
+            eng.run([req], max_ticks=100)
+        assert eng.transport == "dcn"
+        assert not eng.stats.degraded_transport
+        assert len(eng.stats.transport_failures) == 1
 
     def test_max_new_1_completes_on_the_prefill_side(self, models1,
                                                      roles1):

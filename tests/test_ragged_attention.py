@@ -190,7 +190,9 @@ class TestRaggedKernel:
         """Acceptance: an all-CAUSAL topology operand changes NOTHING —
         valid spans byte-identical to the topology-less launch (the
         identity-operand contract; garbage spans excluded, per the
-        packing contract)."""
+        packing contract). Dropping the lse output (``with_lse=False``,
+        the head-sharded serving step) changes nothing in ``out``
+        either."""
         rng = np.random.default_rng(6)
         pools, scales = _pools(rng, quant)
         q, kv_lens, q_lens, q_starts, table = _mixed_batch(rng)
@@ -204,11 +206,19 @@ class TestRaggedKernel:
             qp, *pools, kv_lens, q_lens, q_starts, table, group=G,
             block_q=8, topologies=topo, **scales,
         )
+        bare, no_lse = ragged_paged_attention(
+            qp, *pools, kv_lens, q_lens, q_starts, table, group=G,
+            block_q=8, topologies=topo, with_lse=False, **scales,
+        )
+        assert no_lse is None
         for r in range(3):
             s = int(q_starts[r]) * G
             w = int(q_lens[r]) * G
             np.testing.assert_array_equal(
                 np.asarray(base)[:, s:s + w], np.asarray(got)[:, s:s + w]
+            )
+            np.testing.assert_array_equal(
+                np.asarray(base)[:, s:s + w], np.asarray(bare)[:, s:s + w]
             )
             np.testing.assert_array_equal(
                 np.asarray(base_lse)[:, s:s + w],

@@ -207,6 +207,38 @@ class TestServingEngine:
         assert stats.degraded and calls["n"] >= 1
         assert eng.use_pallas is False
         assert req.generated == _reference_tokens(model, params, req)
+        # the absorbed exception is kept: what failed, at which step
+        assert [f["site"] for f in stats.failures] == ["serving_step"]
+        assert "injected kernel failure" in stats.failures[0]["error"]
+        assert stats.failures[0]["step"] == 0
+
+    def test_kernel_failure_propagates_on_request(self, model_params,
+                                                  monkeypatch):
+        """``propagate_failures=True`` records the failure and RAISES
+        it instead of degrading (chip_smoke.py's contract: a result
+        must not silently come from the XLA twin). Budget 40 keeps this
+        trace off the step-jit cache of the degrade test above (a
+        traced step captured the real kernel)."""
+        import triton_distributed_tpu.kernels.ragged_paged_attention as rpa
+
+        model, params = model_params
+
+        def boom(*a, **k):
+            raise RuntimeError("injected kernel failure")
+
+        monkeypatch.setattr(rpa, "ragged_paged_attention", boom)
+        eng = ServingEngine(
+            model, params,
+            EngineConfig(slots=2, token_budget=40, chunk=8, page=8,
+                         npages=16),
+            propagate_failures=True,
+        )
+        req = Request(rid=0, prompt=np.arange(9, dtype=np.int32),
+                      max_new=3, arrival=0.0)
+        with pytest.raises(RuntimeError, match="injected kernel failure"):
+            eng.run([req], max_steps=50)
+        assert eng.use_pallas is True and not eng.stats.degraded
+        assert len(eng.stats.failures) == 1
 
     def test_serving_state_is_a_donatable_pytree(self, model_params):
         model, _ = model_params

@@ -233,8 +233,20 @@ class TestWinnerValidation:
 class TestPerfModel:
     def test_specs_and_detection(self):
         assert set(TPU_SPECS) == {"v4", "v5e", "v5p", "v6e"}
-        spec = detect_spec()            # CPU test host → fallback, no crash
-        assert spec.bf16_tflops > 0
+        # the CPU test mesh stands in for the AOT target, by name
+        assert detect_spec() is TPU_SPECS["v5e"]
+
+        class Dev:
+            platform = "tpu"
+
+            def __init__(self, kind):
+                self.device_kind = kind
+
+        assert detect_spec(Dev("TPU v5 lite")) is TPU_SPECS["v5e"]
+        assert detect_spec(Dev("TPU v5p")) is TPU_SPECS["v5p"]
+        # an accelerator with no row is an error, never a borrowed row
+        with pytest.raises(ValueError, match="no TpuSpec row"):
+            detect_spec(Dev("TPU v9 mega"))
 
     def test_estimates_scale_sanely(self):
         spec = TPU_SPECS["v5e"]

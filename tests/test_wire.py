@@ -23,7 +23,6 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from conftest import requires_tpu_sim
 
 from triton_distributed_tpu.lang import wire as wirelib
 
@@ -746,11 +745,9 @@ class TestCollectiveRails:
 
 # ---------------------------------------------- fused engines (TPU sim)
 
-@requires_tpu_sim
 class TestFusedWireEngines:
     """The fused Pallas wire rings, executed on the interpreter mesh
-    (skipped on a jax without the TPU-simulation interpreter — the
-    static protocol twin lives in test_analysis.py)."""
+    (the static protocol twin lives in test_analysis.py)."""
 
     @pytest.mark.parametrize("w,tol", [("fp8", 0.06), ("int8", 0.02)])
     def test_fused_ag_gemm_wire(self, mesh8, w, tol):

@@ -34,7 +34,7 @@ Layout:
   declaration — inference supplies the contract so SL008 never goes
   blind).
 * :mod:`mosaic_compat` — the seconds-fast Mosaic pre-flight (MC001–
-  MC003): each family's kernel jaxpr, built for hardware, scanned for
+  MC010): each family's kernel jaxpr, built for hardware, scanned for
   constructs this toolchain's Mosaic backend rejects.
 * :mod:`lint`      — public API (:func:`lint.lint_family`,
   :func:`lint.lint_all`) and the CLI

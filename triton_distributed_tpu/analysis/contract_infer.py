@@ -158,9 +158,6 @@ def _decode_class(out, n) -> str:
 def _shmap(body, mesh, in_specs, out_specs):
     import jax
 
-    from triton_distributed_tpu.config import ensure_compat
-
-    ensure_compat()
     return jax.jit(jax.shard_map(
         body, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
         check_vma=False,

@@ -186,6 +186,34 @@ RULES = {
         "offset (unroll over the candidate offsets with masks) or hoist "
         "the slice to the XLA side",
     ),
+    "MC008": (
+        "mosaic-unproven-tile-slice",
+        Severity.ERROR,
+        "a DMA window is sliced at a TRACED offset on the second-minor "
+        "(sublane) dimension with no pl.multiple_of proof; Mosaic "
+        "refuses any tiled-dim slice whose index it cannot prove "
+        "divisible by the tiling ('Failed to prove that a tile index "
+        "in dimension 1 is divisible by the tiling (8)') — state the "
+        "alignment the packing contract guarantees with "
+        "pl.multiple_of(offset, 8)",
+    ),
+    "MC009": (
+        "mosaic-thin-lane-dma",
+        Severity.ERROR,
+        "a sliced DMA endpoint with a trailing dim of 1 ('Slice shape "
+        "along dimension 2 must be aligned to tiling (128), but is "
+        "1'); a (rows, 1) column cannot be DMA'd through a sliced ref "
+        "— stage it lane-dense (broadcast to 128 lanes) or move the "
+        "whole ref",
+    ),
+    "MC010": (
+        "mosaic-i1-select",
+        Severity.ERROR,
+        "a select whose OPERANDS are i1 (mask) vectors; Mosaic fails "
+        "to legalize arith.select on vector<i1> — select between the "
+        "integer/float quantities the masks derive from, or fold the "
+        "choice into logical and/or of the masks",
+    ),
     "SV001": (
         "serving-page-leak",
         Severity.ERROR,

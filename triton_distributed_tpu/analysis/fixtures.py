@@ -1122,6 +1122,75 @@ def sublane_dynamic_slice(axis="x"):
     )
 
 
+def unproven_tile_slice(axis="x"):
+    """A DMA window sliced at a TRACED second-minor offset Mosaic
+    cannot prove tile-aligned — the ragged kernel's pre-repair
+    ``q_hbm.at[:, pl.ds(q_starts[r] * g, rows)]`` with ``g == 1``
+    ('Failed to prove that a tile index in dimension 1 is divisible by
+    the tiling (8)'). ``pl.multiple_of`` is the repair. MC008."""
+
+    def kernel(idx_ref, x_ref, out_ref, sem):
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        start = idx_ref[0] * 1                 # BUG: no divisibility proof
+        cp = pltpu.make_async_copy(
+            x_ref.at[pl.ds(start, 8)], out_ref, sem.at[0])
+        cp.start()
+        cp.wait()
+
+    return (
+        _spec(kernel, "fixture_unproven_tile_slice",
+              out_shapes=[((8, 128), _F32)], scratch=_sems((1,))),
+        lambda n: [((1,), np.dtype(np.int32)), ((32, 128), _F32)],
+    )
+
+
+def thin_lane_dma(axis="x"):
+    """A (rows, 1) column DMA'd into a sliced window — the ragged
+    kernel's pre-repair ``(hkv, rows, 1)`` lse staging block ('Slice
+    shape along dimension 2 must be aligned to tiling (128), but is
+    1'). The offset carries its proof; only the lane extent is wrong.
+    MC009."""
+
+    def kernel(idx_ref, x_ref, out_ref, sem):
+        from jax.experimental import pallas as pl
+        from jax.experimental.pallas import tpu as pltpu
+
+        start = pl.multiple_of(idx_ref[0] * 8, 8)
+        cp = pltpu.make_async_copy(
+            x_ref, out_ref.at[pl.ds(start, 8)], sem.at[0])  # BUG: (·, 1)
+        cp.start()
+        cp.wait()
+
+    return (
+        _spec(kernel, "fixture_thin_lane_dma",
+              out_shapes=[((32, 1), _F32)], scratch=_sems((1,))),
+        lambda n: [((1,), np.dtype(np.int32)), ((8, 1), _F32)],
+    )
+
+
+def i1_vector_select(axis="x"):
+    """A select whose OPERANDS are mask vectors — the ragged kernel's
+    pre-repair ``jnp.where(kind == TOPO_TREE, tree_valid, valid)``
+    ('failed to legalize operation arith.select' on vector<i1>).
+    MC010."""
+
+    def kernel(kind_ref, x_ref, out_ref):
+        import jax.numpy as jnp
+
+        x = x_ref[...]
+        a, b = x > 0.0, x > 1.0
+        valid = jnp.where(kind_ref[0] == 1, a, b)   # BUG: i1 operands
+        out_ref[...] = jnp.where(valid, x, 0.0)
+
+    return (
+        _spec(kernel, "fixture_i1_vector_select",
+              out_shapes=[((8, 128), _F32)]),
+        lambda n: [((1,), np.dtype(np.int32)), ((8, 128), _F32)],
+    )
+
+
 def cp_ring_skipped_block(axis="x"):
     """The context-parallel KV rotation ring one BLOCK short: the
     schedule mutation ``chunk_order='skip_last'`` threaded through the

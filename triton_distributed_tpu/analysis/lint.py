@@ -43,7 +43,7 @@ def lint_mesh(n: int = 8, axis: str = "x"):
     devices back it."""
     import jax
 
-    return jax.sharding.AbstractMesh(((axis, int(n)),))
+    return jax.sharding.AbstractMesh((int(n),), (axis,))
 
 
 def analyze_spec(spec, in_shapes, n, *, kernel_name, site=None, init=None,
@@ -245,7 +245,7 @@ def main(argv=None) -> int:
                     "declared)")
     ap.add_argument("--mosaic", action="store_true",
                     help="also run the Mosaic-compat pre-flight (rules "
-                    "MC001-MC004: trace each family's kernel jaxpr and "
+                    "MC001-MC010: trace each family's kernel jaxpr and "
                     "scan for constructs this toolchain's Mosaic "
                     "rejects)")
     ap.add_argument("--serving", action="store_true",

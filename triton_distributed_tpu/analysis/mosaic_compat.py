@@ -35,6 +35,17 @@ for the constructs this toolchain's Mosaic backend is KNOWN to reject:
   kernel's tree-topology mask is a STATIC per-position
   ancestor-bitmask unroll rather than an ``anc[par]`` index chase.
 
+* **MC008 / MC009 / MC010** — the three refusals the ragged paged
+  kernel hit on its first real Mosaic compile (jax 0.9.0 / libtpu
+  0.0.34, AOT against v5e): a DMA window sliced at a TRACED
+  second-minor offset with no ``pl.multiple_of`` proof ("Failed to
+  prove that a tile index in dimension 1 is divisible by the tiling");
+  a sliced DMA endpoint with a trailing dim of 1 ("Slice shape along
+  dimension 2 must be aligned to tiling (128), but is 1" — thin minor
+  dims > 1 are not flagged: the lint geometries are deliberately
+  tiny); and a ``select`` whose OPERANDS are i1 vectors ("failed
+  to legalize operation 'arith.select'").
+
 A family whose builder REFUSES cleanly under the hardware contract
 (``require_inkernel`` raising for a pinned fp8 wire) is a pass: the
 contract fires before Mosaic ever would, which is the designed
@@ -91,17 +102,15 @@ def _is_subbyte(dtype) -> bool:
 
 def _walk_jaxprs(jaxpr):
     """Yield every eqn of a jaxpr and (recursively) of the sub-jaxprs
-    carried in eqn params (scan/while/cond bodies, pipeline loops)."""
+    carried in eqn params (scan/while bodies, pipeline loops, and the
+    ``cond`` branch tuples every ``pl.when`` stages)."""
     for eqn in jaxpr.eqns:
         yield eqn
         for v in eqn.params.values():
-            inner = getattr(v, "jaxpr", None)
-            if inner is None and hasattr(v, "eqns"):
-                inner = v
-            if inner is not None and not hasattr(inner, "eqns"):
-                inner = getattr(inner, "jaxpr", None)
-            if inner is not None and hasattr(inner, "eqns"):
-                yield from _walk_jaxprs(inner)
+            for inner in (v if isinstance(v, (tuple, list)) else (v,)):
+                inner = getattr(inner, "jaxpr", inner)
+                if hasattr(inner, "eqns"):
+                    yield from _walk_jaxprs(inner)
 
 
 def _kernel_jaxprs(jaxpr):
@@ -117,8 +126,20 @@ def _kernel_jaxprs(jaxpr):
     return out
 
 
+def _dma_windows(eqn):
+    """``(ref shape, NDIndexer)`` for each indexed src/dst endpoint of
+    one ``dma_start`` eqn (whole-ref endpoints carry no indexer)."""
+    import jax
+
+    parts = jax.tree.unflatten(eqn.params["tree"], eqn.invars)
+    for ref, transforms in ((parts[0], parts[1]), (parts[2], parts[3])):
+        for tr in transforms or ():
+            if hasattr(tr, "indices") and len(tr.shape) >= 2:
+                yield tuple(tr.shape), tr
+
+
 def scan_kernel_jaxpr(kjaxpr, kernel_name, site=None) -> list:
-    """MC001–MC006 over one kernel jaxpr."""
+    """MC001–MC010 over one kernel jaxpr."""
     findings = []
     seen = set()
 
@@ -127,7 +148,41 @@ def scan_kernel_jaxpr(kjaxpr, kernel_name, site=None) -> list:
             seen.add((rule, msg))
             findings.append(Finding(rule, kernel_name, msg, site=site))
 
-    for eqn in _walk_jaxprs(kjaxpr):
+    eqns = list(_walk_jaxprs(kjaxpr))
+    producer = {id(v): e for e in eqns for v in e.outvars}
+
+    def divisor(v, depth=0):
+        """Largest factor PROVABLY dividing a traced offset, the way
+        Mosaic's own index analysis sees it: constants, products with a
+        constant, sums of such, and ``pl.multiple_of`` hints (MC008).
+        Any other op — notably a scalar-prefetch load — proves
+        nothing (1); a value entering from an enclosing jaxpr (loop
+        carry, cond operand) is out of this scan's sight and assumed
+        aligned, so the rule only fires on offsets it fully sees."""
+        import math
+
+        if isinstance(v, int) or hasattr(v, "val"):
+            c = abs(int(getattr(v, "val", v)))
+            return c if c else 1 << 30
+        e = producer.get(id(v))
+        if e is None:
+            return 1 << 30
+        if depth > 16:
+            return 1
+        op = e.primitive.name
+        if op == "multiple_of":
+            return int(e.params["values"][0])
+        if op == "mul":
+            return (divisor(e.invars[0], depth + 1)
+                    * divisor(e.invars[1], depth + 1))
+        if op in ("add", "sub"):
+            return math.gcd(divisor(e.invars[0], depth + 1),
+                            divisor(e.invars[1], depth + 1))
+        if op == "convert_element_type":
+            return divisor(e.invars[0], depth + 1)
+        return 1
+
+    for eqn in eqns:
         name = eqn.primitive.name
         if name == "convert_element_type" and eqn.invars and eqn.outvars:
             src = getattr(eqn.invars[0].aval, "dtype", None)
@@ -223,6 +278,43 @@ def scan_kernel_jaxpr(kjaxpr, kernel_name, site=None) -> list:
                     "over the index set with static masks (the ragged "
                     "kernel's ancestor-bitmask unroll) or gather on "
                     "the XLA side")
+        elif name == "dma_start":
+            for shape, ix in _dma_windows(eqn):
+                sub = ix.indices[-2]
+                start = getattr(sub, "start", None)
+                # whole-tile windows only: the sub-tile (1–2 row)
+                # metadata windows of moe_dispatch compile as they are
+                if (start is not None and sub.size != shape[-2]
+                        and sub.size % 8 == 0 and divisor(start) % 8):
+                    add("MC008",
+                        f"DMA window of {shape} sliced at a traced "
+                        f"second-minor offset (size {sub.size}) with no "
+                        "divisibility proof: Mosaic must PROVE a tile "
+                        "index divisible by the sublane tiling — wrap "
+                        "the offset in pl.multiple_of(offset, 8) (and "
+                        "keep the packing contract that makes it true)")
+                sliced = any(
+                    not hasattr(i, "size") or i.size != d
+                    for i, d in zip(ix.indices, shape)
+                )
+                if sliced and shape[-1] == 1:
+                    add("MC009",
+                        f"sliced DMA endpoint over {shape}: a trailing "
+                        "dim of 1 pads to a 128-lane tile and Mosaic "
+                        "refuses the memref slice as not lane-tile "
+                        "aligned — make the minor dim lane-dense "
+                        "(broadcast the (rows, 1) column to (rows, "
+                        "128)) or DMA the whole ref")
+        elif name == "select_n" and len(eqn.invars) >= 2:
+            case = eqn.invars[1].aval
+            if (str(getattr(case, "dtype", "")) == "bool"
+                    and len(getattr(case, "shape", ())) >= 1):
+                add("MC010",
+                    f"select over i1 vector operands {tuple(case.shape)}"
+                    ": Mosaic cannot legalize arith.select on mask "
+                    "vectors — select the int32/float values the masks "
+                    "are compared against, or combine the masks with "
+                    "logical and/or")
         elif name == "dynamic_slice" and len(eqn.invars) >= 2:
             # MC007: a dynamic_slice whose start index on the SUBLANE
             # (second-minor) dimension is a TRACED value while the
@@ -416,7 +508,7 @@ def main(argv=None) -> int:
         description="Mosaic-compat pre-flight: trace each registered "
         "kernel family's jaxpr (built for hardware) and scan for "
         "constructs this toolchain's Mosaic backend rejects "
-        "(MC001-MC004)",
+        "(MC001-MC010)",
     )
     ap.add_argument("--mesh", type=int, default=8, metavar="N")
     ap.add_argument("--kernel", action="append", default=None,

@@ -137,6 +137,31 @@ def interp_key() -> tuple:
     ) + faults.trace_key()
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache at a place a caller
+    OUTSIDE the process can choose; returns the directory.
+
+    ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and no path
+    is set in code. Otherwise ``.jax_cache/`` at the checkout root
+    (gitignored) — a FIXED path, because the path is part of the cache
+    key: a temp name, pid or timestamp never hits twice. Every
+    executable is kept, however quick its compile — an entry point's
+    set-up is hundreds of small programs beside the few big ones."""
+    import pathlib
+
+    import jax
+
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", 0)
+    cache_dir = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if not cache_dir:
+        cache_dir = str(
+            pathlib.Path(__file__).resolve().parents[1] / ".jax_cache"
+        )
+        jax.config.update("jax_compilation_cache_dir", cache_dir)
+    return cache_dir
+
+
 def autotune_enabled() -> bool:
     """Should ``method=None`` op entries consult the measured autotuner
     (vs the static heuristics)? Default: on real hardware yes, on the CPU
@@ -178,103 +203,6 @@ def local_interpret(force: bool | None = None):
 
 _io_callback_patched = False
 _pipeline_shim_applied = False
-_compat_applied = False
-
-
-def has_tpu_interpreter() -> bool:
-    """Does this jax ship the TPU-simulation interpreter
-    (``pltpu.InterpretParams`` — faithful remote-DMA + semaphore
-    semantics on a CPU mesh)? Older jax lacks it entirely; the
-    test-suite's Pallas-collective coverage requires it, and the
-    graceful-degradation layer (ops falling back to XLA-native paths)
-    is what keeps the package usable without it."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    return hasattr(pltpu, "InterpretParams")
-
-
-def pallas_collectives_available() -> bool:
-    """Can Pallas collective kernels (remote DMA + semaphores) run in
-    this process? True on real TPU and under ``force_compile`` (AOT
-    lowering); off-TPU they need the TPU-simulation interpreter. When
-    False, auto-selected engines degrade to their XLA-native
-    equivalents (explicitly pinned Pallas engines still fail loudly —
-    a pinned method is a contract, not a preference)."""
-    if config.force_compile or on_tpu():
-        return True
-    return has_tpu_interpreter()
-
-
-def ensure_compat():
-    """Best-effort shims for jax API drift (graceful degradation, not
-    emulation): the package targets current jax names; on an older jax
-    the *renamed or superseded* APIs are aliased so that everything
-    which does not require genuinely missing machinery keeps working,
-    and the missing machinery degrades loudly-but-usably:
-
-    * ``jax.shard_map`` ← ``jax.experimental.shard_map.shard_map``
-      (``check_vma`` mapped to the old ``check_rep``).
-    * ``pltpu.CompilerParams`` ← ``pltpu.TPUCompilerParams`` (unknown
-      fields dropped — e.g. ``has_side_effects`` predates the rename).
-    * ``pl.delay`` → no-op when the primitive is absent (chaos delays
-      degrade to nothing; the fault engine's *structural* faults —
-      stalls, signal drops, corruption — do not depend on it).
-    * ``pltpu.reset_tpu_interpret_mode_state`` → no-op (no global
-      interpreter state exists to reset).
-    * ``jax.export`` imported so attribute access works (older jax has
-      the submodule but does not auto-import it).
-
-    Idempotent; opt out with ``TDTPU_NO_COMPAT_SHIMS=1``. The one thing
-    NOT shimmed is the TPU-simulation interpreter itself (see
-    :func:`has_tpu_interpreter`): faking remote-DMA semantics would be
-    dishonest — callers must degrade to XLA-native paths instead.
-    """
-    global _compat_applied
-    if _compat_applied or os.environ.get("TDTPU_NO_COMPAT_SHIMS") == "1":
-        return
-    _compat_applied = True
-    import dataclasses
-    import functools
-
-    import jax
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    if not hasattr(jax, "shard_map"):
-        from jax.experimental.shard_map import shard_map as _shard_map
-
-        @functools.wraps(_shard_map)
-        def shard_map(f, *, mesh, in_specs, out_specs, check_vma=None, **kw):
-            if check_vma is not None:
-                kw.setdefault("check_rep", check_vma)
-            return _shard_map(
-                f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, **kw
-            )
-
-        jax.shard_map = shard_map
-    if not hasattr(pltpu, "CompilerParams"):
-        legacy = pltpu.TPUCompilerParams
-        fields = {f.name for f in dataclasses.fields(legacy)}
-
-        def CompilerParams(**kw):
-            return legacy(**{k: v for k, v in kw.items() if k in fields})
-
-        pltpu.CompilerParams = CompilerParams
-    if not hasattr(jax, "export"):
-        # the submodule exists but is not auto-imported (and package
-        # __getattr__ raises) on older jax — importing it binds the attr
-        try:
-            from jax import export  # noqa: F401
-        except ImportError:         # pragma: no cover — genuinely absent
-            pass
-    if not hasattr(pl, "delay"):
-        pl.delay = lambda cycles: None
-    if not hasattr(pltpu, "reset_tpu_interpret_mode_state"):
-        pltpu.reset_tpu_interpret_mode_state = lambda: None
-    if not hasattr(jax.lax, "axis_size"):
-        # psum of a literal folds statically to the axis size — the
-        # pre-axis_size idiom, so callers still get a Python int
-        jax.lax.axis_size = lambda axis: jax.lax.psum(1, axis)
 
 
 def ensure_pipeline_shim():
@@ -307,12 +235,6 @@ def ensure_pipeline_shim():
         if len(inspect.signature(fn).parameters) != 0:
             raise AttributeError("unexpected _get_tpu_generation signature")
     except (AttributeError, ImportError) as e:
-        if not has_tpu_interpreter():
-            # pre-interpreter jax: the pipeline helper this shim patches
-            # does not exist either — nothing to do (collective kernels
-            # degrade to XLA-native paths elsewhere)
-            _pipeline_shim_applied = True
-            return
         raise RuntimeError(
             "triton_distributed_tpu interpreter shim: jax internals have "
             "drifted (jax._src.pallas.mosaic.pipeline._get_tpu_generation "
@@ -359,11 +281,6 @@ def ensure_interpreter_unblocked():
         if not expected.issubset(params) or not hasattr(_cb, "io_callback_p"):
             raise AttributeError(f"io_callback_impl params {set(params)}")
     except AttributeError as e:
-        if not has_tpu_interpreter():
-            # pre-interpreter jax: the deadlock this patch prevents is an
-            # interpreter-only failure mode — skip quietly
-            _io_callback_patched = True
-            return
         raise RuntimeError(
             "triton_distributed_tpu interpreter shim: jax internals have "
             f"drifted (jax._src.callback.io_callback_impl not patchable: {e})."
@@ -392,17 +309,6 @@ def ensure_interpreter_unblocked():
     _io_callback_patched = True
 
 
-try:
-    ensure_compat()
-except Exception:                               # pragma: no cover
-    # a failed shim must never break package import; the APIs it would
-    # have aliased will then fail at their call sites with jax's own
-    # (clear) AttributeErrors
-    import logging
-
-    logging.getLogger(__name__).exception("ensure_compat failed")
-
-
 def interpret_params(force: bool | None = None):
     """Pallas ``interpret=`` argument for the current platform.
 
@@ -421,13 +327,6 @@ def interpret_params(force: bool | None = None):
         return False
     ensure_interpreter_unblocked()
     ensure_pipeline_shim()
-    if not has_tpu_interpreter():
-        # jax without the TPU-simulation interpreter: degrade to the
-        # plain Pallas interpreter. Purely local kernels still run;
-        # kernels that need remote DMA / semaphore semantics fail loudly
-        # at trace time — callers should have demoted to XLA-native
-        # engines first (ops.overlap.with_fallback / method fallbacks).
-        return True
     return pltpu.InterpretParams(
         detect_races=config.detect_races,
         dma_execution_mode="on_wait",

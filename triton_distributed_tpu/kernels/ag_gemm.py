@@ -950,17 +950,8 @@ def auto_ag_gemm_method(mesh, axis, a, b, dp: int = 1,
     cross-slice factor as ``dcn_axis`` for the hierarchical engine) or on
     shapes with no divisor blocking — and the fallback is *logged* so
     nobody silently benchmarks XLA believing it is the fused kernel."""
-    from triton_distributed_tpu.config import pallas_collectives_available
-
     n = mesh.shape[axis]
     nd = mesh.shape[dcn_axis] if dcn_axis else 1
-    if not pallas_collectives_available():
-        _warn_once(
-            ("ag_gemm", "nosim"),
-            "ag_gemm: Pallas collectives unavailable off-TPU (jax lacks "
-            "the TPU-simulation interpreter); using XLA_RING engine",
-        )
-        return AGGemmMethod.XLA_RING
     topo = detect_topology(mesh, axis)
     if topo.link_kind == LinkKind.DCN:
         _warn_once(

@@ -109,11 +109,6 @@ def _build_all_to_all(mesh, axis, shape, dtype, collective_id, chaos):
 def all_to_all(x, mesh, axis: str = "x", *, collective_id: int = 4):
     """Equal-split AllToAll along dim 0 (row block j of device i → row block
     i of device j). Input/output sharded P(axis) on dim 0."""
-    from triton_distributed_tpu.config import pallas_collectives_available
-
-    if not pallas_collectives_available():
-        # off-TPU without the TPU-simulation interpreter: XLA-native twin
-        return all_to_all_xla(x, mesh, axis)
     n = mesh.shape[axis]
     if n == 1:
         return x

@@ -610,20 +610,9 @@ def all_gather(
     if n == 1:
         return x
     if method is None:
-        from triton_distributed_tpu.config import pallas_collectives_available
         from triton_distributed_tpu.runtime.topology import LinkKind
         from triton_distributed_tpu.tune.autotuner import tuned_method_or_none
 
-        if not pallas_collectives_available():
-            # off-TPU on a jax without the TPU-simulation interpreter:
-            # the Pallas engines cannot execute — degrade to XLA
-            method = AllGatherMethod.XLA_FALLBACK
-            fn = _build_all_gather(
-                mesh, axis, method, x.shape, x.dtype, collective_id,
-                interp_key(),
-                wire=_resolve_ag_wire(wire_dtype, method, x, n),
-            )
-            return fn(x)
         topo = detect_topology(mesh, axis)
         if topo.link_kind == LinkKind.DCN:
             # Pallas remote DMA cannot cross DCN: never bench Pallas

@@ -919,17 +919,8 @@ def auto_gemm_rs_method(mesh, axis, a, b, dp: int = 1,
     logged (nobody should benchmark XLA believing it is the fused kernel).
     A cross-slice TP factor declared as ``dcn_axis`` keeps the fused
     engine intra-slice; only ``axis`` itself crossing DCN forces XLA."""
-    from triton_distributed_tpu.config import pallas_collectives_available
-
     n = mesh.shape[axis]
     nd = mesh.shape[dcn_axis] if dcn_axis else 1
-    if not pallas_collectives_available():
-        _warn_once(
-            ("gemm_rs", "nosim"),
-            "gemm_rs: Pallas collectives unavailable off-TPU (jax lacks "
-            "the TPU-simulation interpreter); using XLA_RING engine",
-        )
-        return GemmRSMethod.XLA_RING
     topo = detect_topology(mesh, axis)
     if topo.link_kind == LinkKind.DCN:
         _warn_once(

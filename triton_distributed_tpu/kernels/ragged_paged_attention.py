@@ -29,9 +29,12 @@ Layout contract (the "GQA-rows" packing):
   natural ``(T, Hq, D)``.
 * ``q_starts`` must be 8-aligned token offsets (the engine packs rows
   at 8-token granularity — ragged, not rectangular: the pad between
-  rows is < 8 tokens, not ``S - len``).
+  rows is < 8 tokens, not ``S - len``). 8 is all Mosaic asks: q/out
+  are never int8, and an HBM bf16 array is tiled ``(8,128)(2,1)`` — 8
+  rows per tile, pairs packed inside it (AOT-verified, jax 0.9.0).
 * KV pools: ``(npages, Hkv, page, D)`` ["phsd"], int8 with
-  ``(npages, Hkv, page)`` f32 scales (the serving default) or bf16;
+  ``(npages, Hkv, page)`` f32 scales (the serving default; compiled
+  by Mosaic only at ``page % 128 == 0``) or bf16;
   ``block_table``: ``(R, pages_per_seq)`` pool page ids; ``kv_lens``:
   per-row TOTAL lengths INCLUDING this step's tokens (append-then-
   attend — the engine scatters the step's K/V into the pool first, so
@@ -78,6 +81,10 @@ from triton_distributed_tpu.lang.launch import shmem_call
 from triton_distributed_tpu.utils.testing import chaos_delay
 
 NEG_INF = -1.0e30
+
+#: lane width of the lse output's trailing dim (every lane carries the
+#: same value; the wrapper reads lane 0)
+LSE_LANES = 128
 
 # ------------------------------------------------------- attention topology
 #
@@ -215,7 +222,7 @@ def unpack_gqa_rows(o, hq):
 
 def _ragged_kernel(
     scale, soft_cap, page, n_bufs, hkv, g, d, block_q, quant, topo_w,
-    *refs,
+    with_lse, *refs,
 ):
     """Grid (R,): one request row per step; all local KV heads unrolled.
 
@@ -234,37 +241,29 @@ def _ragged_kernel(
     width, the TREE ancestor-bitmask mask, and the ``q_len == 0`` row
     skip — inactive rows are hopped over by the cross-row q-prefetch
     (the prefetch targets the NEXT ACTIVE row, not ``r + 1``) and
-    leave carries, buffers, and their stale out spans untouched."""
-    if quant:
-        if topo_w:
-            (table_ref, kv_lens_ref, q_lens_ref, q_starts_ref, topo_ref,
-             q_hbm, k_hbm, v_hbm, ks_hbm, vs_hbm,
-             out_hbm, lse_hbm,
-             qbuf, kbuf, vbuf, ksbuf, vsbuf, obuf, lbuf,
-             sem_q, sem_k, sem_v, sem_ks, sem_vs, sem_o,
-             slot_ref, m_ref, l_ref, acc_ref) = refs
-        else:
-            (table_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
-             q_hbm, k_hbm, v_hbm, ks_hbm, vs_hbm,
-             out_hbm, lse_hbm,
-             qbuf, kbuf, vbuf, ksbuf, vsbuf, obuf, lbuf,
-             sem_q, sem_k, sem_v, sem_ks, sem_vs, sem_o,
-             slot_ref, m_ref, l_ref, acc_ref) = refs
-    else:
-        if topo_w:
-            (table_ref, kv_lens_ref, q_lens_ref, q_starts_ref, topo_ref,
-             q_hbm, k_hbm, v_hbm,
-             out_hbm, lse_hbm,
-             qbuf, kbuf, vbuf, obuf, lbuf,
-             sem_q, sem_k, sem_v, sem_o,
-             slot_ref, m_ref, l_ref, acc_ref) = refs
-        else:
-            (table_ref, kv_lens_ref, q_lens_ref, q_starts_ref,
-             q_hbm, k_hbm, v_hbm,
-             out_hbm, lse_hbm,
-             qbuf, kbuf, vbuf, obuf, lbuf,
-             sem_q, sem_k, sem_v, sem_o,
-             slot_ref, m_ref, l_ref, acc_ref) = refs
+    leave carries, buffers, and their stale out spans untouched.
+
+    ``with_lse`` (static): False drops the lse output, its staging
+    buffer and its DMA — the head-sharded serving step never reads it
+    (only the cp shard merge does)."""
+    refs = iter(refs)
+
+    def take(n):
+        return [next(refs) for _ in range(n)]
+
+    table_ref, kv_lens_ref, q_lens_ref, q_starts_ref = take(4)
+    topo_ref = next(refs) if topo_w else None
+    q_hbm, k_hbm, v_hbm = take(3)
+    ks_hbm, vs_hbm = take(2) if quant else (None, None)
+    out_hbm = next(refs)
+    lse_hbm = next(refs) if with_lse else None
+    qbuf, kbuf, vbuf = take(3)
+    ksbuf, vsbuf = take(2) if quant else (None, None)
+    obuf = next(refs)
+    lbuf = next(refs) if with_lse else None
+    sem_q, sem_k, sem_v = take(3)
+    sem_ks, sem_vs = take(2) if quant else (None, None)
+    sem_o, slot_ref, m_ref, l_ref, acc_ref = take(5)
     r = pl.program_id(0)
     nr = pl.num_programs(0)
     npages = k_hbm.shape[0]
@@ -305,8 +304,10 @@ def _ragged_kernel(
 
     def qdma(rr, qslot):
         # the row's whole query block, every local head, one strided
-        # copy (hkv contiguous (rows, d) runs)
-        start = q_starts_ref[rr] * g
+        # copy (hkv contiguous (rows, d) runs). Mosaic slices a tiled
+        # HBM dim only at offsets it can PROVE tile-aligned: the
+        # 8-aligned q_starts contract is that proof (deny rule MC008)
+        start = pl.multiple_of(q_starts_ref[rr] * g, 8)
         return pltpu.make_async_copy(
             q_hbm.at[:, pl.ds(start, rows)], qbuf.at[qslot],
             sem_q.at[qslot],
@@ -384,6 +385,20 @@ def _ragged_kernel(
                 anc_col = jnp.where(
                     row_tok == t, topo_ref[r, 2 + t], anc_col
                 )
+            # every kind's mask is ONE form over two int32 limit
+            # columns: valid = pos < hi  AND  (pos < lo  OR  anc bit).
+            # CAUSAL / SHARED_PREFIX: lo = hi = limit (the bit term is
+            # vacuous). CP: lo = hi = min(kv_len, limit + aux) — the
+            # frontier shifted right by aux, clamped to the slice. TREE:
+            # hi = kv_len, lo = base (everything below the speculative
+            # region is causal-visible; inside it only ancestors are).
+            # The kind select runs on the int32 columns: Mosaic cannot
+            # legalize a select whose OPERANDS are i1 vectors (MC010).
+            cp_lim = jnp.minimum(limit + aux, kv_len)
+            flat = jnp.where(kind == TOPO_CP, cp_lim, limit)
+            is_tree = kind == TOPO_TREE
+            lim_hi = jnp.where(is_tree, kv_len, flat)
+            lim_lo = jnp.where(is_tree, base, flat)
 
         def body(j, _):
             slot = jax.lax.rem(s0 + j, n_bufs)
@@ -424,36 +439,19 @@ def _ragged_kernel(
                     pos = j * page + jax.lax.broadcasted_iota(
                         jnp.int32, (1, page), 1
                     )
-                    valid = pos < limit       # (rows, page)
                     if topo_w:
-                        # TREE: position base+t is visible to query row
-                        # t' iff bit t of anc[t'] is set; everything
-                        # below base stays causal-visible, everything
-                        # past kv_len masked. SHARED_PREFIX masks as
-                        # causal (the aliasing is table-level).
-                        rel = pos - base      # (rows, page)
+                        # bit t of anc[t'] set ⇔ position base + t is
+                        # visible to query row t' (TREE rows only
+                        # reach this term — see lim_lo above)
                         bit = jax.lax.shift_right_logical(
-                            anc_col, jnp.clip(rel, 0, 31)
+                            anc_col, jnp.clip(pos - base, 0, 31)
                         ) & 1
-                        tree_valid = jnp.logical_and(
-                            pos < kv_len,
-                            jnp.logical_or(rel < 0, bit > 0),
-                        )
-                        valid = jnp.where(
-                            kind == TOPO_TREE, tree_valid, valid
-                        )
-                        # CP: this rank's slice sits ``aux`` tokens to
-                        # the LEFT of the causal frontier, so the limit
-                        # shifts right by aux; the ``pos < kv_len``
-                        # conjunct is load-bearing — on fully-covered
-                        # shards limit + aux exceeds kv_len and padding
-                        # rows must not read past the slice.
-                        cp_valid = jnp.logical_and(
-                            pos < kv_len, pos < limit + aux
-                        )
-                        valid = jnp.where(
-                            kind == TOPO_CP, cp_valid, valid
-                        )
+                        valid = jnp.logical_and(
+                            pos < lim_hi,
+                            jnp.logical_or(pos < lim_lo, bit > 0),
+                        )                     # (rows, page)
+                    else:
+                        valid = pos < limit   # (rows, page)
                 for h in range(hkv):          # static unroll
                     q = qbuf[qslot, h]        # (rows, d)
                     k = kbuf[slot, h]
@@ -515,23 +513,30 @@ def _ragged_kernel(
             l = l_ref[lo:hi]
             safe_l = jnp.where(l > 0.0, l, 1.0)
             obuf[h] = (acc_ref[lo:hi] / safe_l).astype(obuf.dtype)
-            lbuf[h] = jnp.where(
-                l > 0.0, m_ref[lo:hi] + jnp.log(safe_l),
-                jnp.full_like(l, NEG_INF)
-            )
-        start = q_starts_ref[r] * g
-        o_cp = pltpu.make_async_copy(
+            if with_lse:
+                # lane-broadcast: a DMA slice must span whole 128-lane
+                # tiles (a trailing dim of 1 is refused — MC009)
+                lbuf[h] = jnp.broadcast_to(
+                    jnp.where(
+                        l > 0.0, m_ref[lo:hi] + jnp.log(safe_l),
+                        jnp.full_like(l, NEG_INF)
+                    ),
+                    (rows, LSE_LANES),
+                )
+        start = pl.multiple_of(q_starts_ref[r] * g, 8)
+        cps = [pltpu.make_async_copy(
             obuf, out_hbm.at[:, pl.ds(start, rows)], sem_o.at[0]
-        )
-        l_cp = pltpu.make_async_copy(
-            lbuf, lse_hbm.at[:, pl.ds(start, rows)], sem_o.at[1]
-        )
-        o_cp.start()
-        l_cp.start()
+        )]
+        if with_lse:
+            cps.append(pltpu.make_async_copy(
+                lbuf, lse_hbm.at[:, pl.ds(start, rows)], sem_o.at[1]
+            ))
+        for cp in cps:
+            cp.start()
         # wait BEFORE the grid advances: overlapping rows' out regions
         # self-heal by write order, which async completions would break
-        o_cp.wait()
-        l_cp.wait()
+        for cp in cps:
+            cp.wait()
 
     if topo_w:
         @pl.when(q_len > 0)
@@ -545,6 +550,7 @@ def _ragged_kernel(
 def _build_ragged(
     r, pps, npages, t_tokens, hkv, g, d, page, block_q, q_dtype,
     quant, scale, soft_cap, n_bufs, interpret, token=(), topo_w=0,
+    with_lse=True,
 ):
     """Construct the ragged-paged-attention pallas_call (lru-cached on
     the full static geometry; ``token`` busts the cache for lint/
@@ -552,13 +558,15 @@ def _build_ragged(
     ``(table, kv_lens, q_lens, q_starts[, topologies], q, k_pool,
     v_pool [, k_scale, v_scale])`` — the topology operand present iff
     ``topo_w > 0`` (its descriptor width; 0 = the pre-topology
-    launch, bit-for-bit)."""
+    launch, bit-for-bit) — and returning ``[out, lse]`` (``[out]``
+    without ``with_lse``); lse is ``(Hkv, T·G, LSE_LANES)``,
+    lane-broadcast."""
     del token
     q_dtype = jnp.dtype(q_dtype)
     rows = block_q * g
     kernel = functools.partial(
         _ragged_kernel, scale, soft_cap, page, n_bufs, hkv, g, d,
-        block_q, quant, topo_w,
+        block_q, quant, topo_w, with_lse,
     )
     pool_dt = jnp.dtype(jnp.int8) if quant else q_dtype
     in_specs = [
@@ -589,20 +597,22 @@ def _build_ragged(
             pltpu.SemaphoreType.DMA((n_bufs,)),  # sem_ks
             pltpu.SemaphoreType.DMA((n_bufs,)),  # sem_vs
         ]
-    scratch += [
-        pltpu.VMEM((hkv, rows, d), q_dtype),             # obuf
-        pltpu.VMEM((hkv, rows, 1), jnp.float32),         # lbuf
-    ]
+    scratch += [pltpu.VMEM((hkv, rows, d), q_dtype)]     # obuf
+    out_specs = [pl.BlockSpec(memory_space=pl.ANY)]      # out
+    out_shape = [jax.ShapeDtypeStruct((hkv, t_tokens * g, d), q_dtype)]
+    if with_lse:
+        scratch += [pltpu.VMEM((hkv, rows, LSE_LANES), jnp.float32)]
+        out_specs += [pl.BlockSpec(memory_space=pl.ANY)]
+        out_shape += [jax.ShapeDtypeStruct(
+            (hkv, t_tokens * g, LSE_LANES), jnp.float32
+        )]
     sems += [pltpu.SemaphoreType.DMA((2,))]   # sem_o (out, lse)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         # table, kv_lens, q_lens, starts [+ per-row topology]
         num_scalar_prefetch=5 if topo_w else 4,
         grid=(r,),
         in_specs=in_specs,
-        out_specs=[
-            pl.BlockSpec(memory_space=pl.ANY),           # out
-            pl.BlockSpec(memory_space=pl.ANY),           # lse
-        ],
+        out_specs=out_specs,
         scratch_shapes=scratch + sems + [
             pltpu.SMEM((2,), jnp.int32),                 # slot carries
             pltpu.VMEM((hkv * rows, 1), jnp.float32),    # m
@@ -610,12 +620,16 @@ def _build_ragged(
             pltpu.VMEM((hkv * rows, d), jnp.float32),    # acc
         ],
     )
-    # VMEM working set: the kv slot buffers + scale planes + q/out
-    # blocks + softmax state, with pipeline headroom
+    # VMEM working set: the kv slot buffers + scale planes (one
+    # (1, page) row pads to an 8-sublane tile) + q/out blocks +
+    # softmax state (the (·, 1) m/l columns pad to full lanes) + the
+    # lse staging block, with pipeline headroom
     kv_bytes = 2 * n_bufs * hkv * page * d * pool_dt.itemsize
-    sc_bytes = 2 * n_bufs * hkv * page * 4 if quant else 0
+    sc_bytes = 2 * n_bufs * hkv * 8 * page * 4 if quant else 0
     q_bytes = 3 * hkv * rows * d * q_dtype.itemsize
-    st_bytes = hkv * rows * (d + 2) * 4
+    st_bytes = hkv * rows * (d + 2 * 128) * 4
+    if with_lse:
+        st_bytes += hkv * rows * LSE_LANES * 4
     vmem_limit = None
     total = kv_bytes + sc_bytes + q_bytes + st_bytes
     if total > 12 * 1024 * 1024:
@@ -623,10 +637,7 @@ def _build_ragged(
     call = shmem_call(
         kernel,
         grid_spec=grid_spec,
-        out_shape=[
-            jax.ShapeDtypeStruct((hkv, t_tokens * g, d), q_dtype),
-            jax.ShapeDtypeStruct((hkv, t_tokens * g, 1), jnp.float32),
-        ],
+        out_shape=out_shape,
         collective_id=None,                   # purely local kernel
         vmem_limit_bytes=vmem_limit,
         interpret=local_interpret() if interpret is None else interpret,
@@ -654,13 +665,13 @@ def auto_block_q(max_q_len: int, g: int) -> int:
 @functools.partial(
     jax.jit,
     static_argnames=("group", "scale", "soft_cap", "block_q", "n_bufs",
-                     "interpret"),
+                     "with_lse", "interpret"),
 )
 def ragged_paged_attention(
     q, k_pool, v_pool, kv_lens, q_lens, q_starts, block_table, *,
     group: int, topologies=None, k_scale=None, v_scale=None,
     scale: float | None = None, soft_cap: float = 0.0, block_q: int = 8,
-    n_bufs: int = 2, interpret=None,
+    n_bufs: int = 2, with_lse: bool = True, interpret=None,
 ):
     """Mixed prefill-chunk/decode attention over a shared page pool.
 
@@ -681,9 +692,10 @@ def ragged_paged_attention(
     through their (deduplicated) block tables, and ``q_len == 0`` rows
     are skipped by the cross-row prefetch hop.
 
-    Returns (out (Hkv, T·G, D) in q.dtype, lse (Hkv, T·G) f32). Rows
-    of dim 1 outside the per-row valid spans hold garbage (the packing
-    contract; see the module docstring).
+    Returns (out (Hkv, T·G, D) in q.dtype, lse (Hkv, T·G) f32 — None
+    without ``with_lse``, which also drops the kernel's lse writes).
+    Rows of dim 1 outside the per-row valid spans hold garbage (the
+    packing contract; see the module docstring).
     """
     hkv, tg, d = q.shape
     g = group
@@ -701,6 +713,13 @@ def ragged_paged_attention(
             "sublane-aligned (multiple of 8) — pick block_q via "
             "auto_block_q"
         )
+    if quant and page % 128 and not local_interpret(interpret):
+        raise ValueError(
+            f"ragged_paged_attention: int8 pools need page % 128 == 0 "
+            f"under Mosaic (got page={page}) — the (1, page) scale "
+            "plane of one page is a DMA window and must span whole "
+            "128-lane tiles"
+        )
     topo_w = 0
     if topologies is not None:
         tr, tw = topologies.shape
@@ -715,7 +734,7 @@ def ragged_paged_attention(
     call = _build_ragged(
         r, pps, npages, t_tokens, hkv, g, d, page, block_q,
         jnp.dtype(q.dtype).name, quant, float(scale), float(soft_cap),
-        n_bufs, interpret, (), topo_w,
+        n_bufs, interpret, (), topo_w, with_lse,
     )
     args = [
         block_table.astype(jnp.int32), kv_lens.astype(jnp.int32),
@@ -729,8 +748,11 @@ def ragged_paged_attention(
             k_scale.astype(jnp.float32).reshape(npages, hkv, 1, page),
             v_scale.astype(jnp.float32).reshape(npages, hkv, 1, page),
         ]
+    if not with_lse:
+        (out,) = call(*args)
+        return out, None
     out, lse = call(*args)
-    return out, lse.reshape(hkv, tg)
+    return out, lse[..., 0]
 
 
 def ragged_paged_attention_xla(

@@ -449,23 +449,10 @@ def reduce_scatter(
 
     Host entry ≡ reference ``reduce_scatter_2d_op`` (reduce_scatter.py:863).
     """
-    from triton_distributed_tpu.config import pallas_collectives_available
-
     n = mesh.shape[axis]
     full_shape = x.shape[1:] if stacked else x.shape
     rows = full_shape[0]
     cols = int(np.prod(full_shape[1:], dtype=np.int64)) if len(full_shape) > 1 else 1
-    if not pallas_collectives_available():
-        # off-TPU without the TPU-simulation interpreter: degrade to the
-        # XLA-native twin (which carries the wire too)
-        if n == 1:
-            return x[0] if stacked else x
-        return reduce_scatter_xla(
-            x, mesh, axis, stacked=stacked,
-            wire_dtype=_resolve_rs_wire(
-                wire_dtype, rows, cols, n, x.dtype.itemsize
-            ),
-        )
     if n == 1:
         return x[0] if stacked else x
     assert full_shape[0] % n == 0, f"dim0 {full_shape[0]} not divisible by {n}"

@@ -230,6 +230,7 @@ class RaggedPagedAttention:
             if use_pallas:
                 kw["block_q"] = block
                 kw["n_bufs"] = n_bufs
+                kw["with_lse"] = with_lse
             if quant:
                 kq, ks, vq, vs = pools
                 out, lse = fn(qp, kq, vq, kv_lens, q_lens, q_starts,

@@ -489,7 +489,7 @@ class Transformer:
             )[0]
         return w.astype(self.config.dtype)
 
-    def _dmm(self, x, w, out_dtype=None, act_quant=True):
+    def _dmm(self, x, w, out_dtype=None, act_quant=True, shard=None):
         """Decode-time dense matmul dispatching on the weight storage:
         quantized dicts ride the grouped-GEMM kernel (E=1, tiled weight
         streaming with epilogue dequant — the decode GEMMs are
@@ -497,7 +497,13 @@ class Transformer:
         plain arrays take the ordinary XLA dot. With
         ``config.dense_act_quant`` (and ``act_quant=True``), the B
         activation rows quantize per row and the kernel runs the
-        s8×s8 MXU path (W8A8)."""
+        s8×s8 MXU path (W8A8).
+
+        ``shard``: the weight's tp layout per :meth:`shardings` —
+        ``"col"`` (N sharded: wqkv/up), ``"row"`` (K sharded: wo/down,
+        the per-rank partial products are psum'd) or None (replicated:
+        lm_head). GSPMD cannot partition a Mosaic call, so the kernel
+        runs per shard under ``shard_map``."""
         if not isinstance(w, dict):
             return x @ w.astype(out_dtype or self.config.dtype)
         from triton_distributed_tpu.config import fused_vmem_budget
@@ -518,9 +524,11 @@ class Transformer:
         bp = -(-b // 8) * 8
         if bp != b:
             x = jnp.pad(x, ((0, bp - b), (0, 0)))
+        # out_dtype reaches the kernel store: the f32 accumulator casts
+        # straight to it (an astype after a bf16 store would re-widen
+        # already-rounded values — logits want full f32)
         kw = dict(
-            w_scale=w["scale"][None], block_m=bp,
-            vmem_limit_bytes=fused_vmem_budget(),
+            block_m=bp, vmem_limit_bytes=fused_vmem_budget(),
             out_dtype=out_dtype,
         )
         if (
@@ -532,24 +540,43 @@ class Transformer:
                 quantize_act_rows,
             )
 
-            xq, xsc = quantize_act_rows(x)
-            # pin the out dtype: W8A8 grouped_matmul would otherwise
-            # default to bf16 (x is int8), silently downcasting an
-            # f32 model's projection outputs
+            # per-row scales span the WHOLE K dim, so rows quantize
+            # before any K split. Pin the out dtype: W8A8
+            # grouped_matmul would otherwise default to bf16 (x is
+            # int8), silently downcasting an f32 model's outputs
+            x, xsc = quantize_act_rows(x)
             kw["out_dtype"] = out_dtype or self.config.dtype
+            x_scale = (xsc,)
+        else:
+            x = x.astype(self.config.dtype)
+            x_scale = ()
+        t = self.tp_axis
+        # specs of x (M, K), w (1, K, N), w_scale (1, N) and the output
+        x_spec, w_spec, s_spec, o_spec = {
+            "col": (P(), P(None, None, t), P(None, t), P(None, t)),
+            "row": (P(None, t), P(None, t), P(), P()),
+            None: (P(), P(), P(), P()),
+        }[shard]
+
+        def local(x, wq, ws, *xs):
             y = grouped_matmul(
-                xq, w["q"][None], jnp.zeros((1,), jnp.int32),
-                x_scale=xsc, **kw,
+                x, wq, jnp.zeros((1,), jnp.int32), w_scale=ws,
+                x_scale=xs[0] if xs else None, **kw,
             )
-            return y[:b] if bp != b else y
-        xp = x.astype(self.config.dtype)
-        # out_dtype reaches the kernel store: the f32 accumulator casts
-        # straight to it (an astype after a bf16 store would re-widen
-        # already-rounded values — logits want full f32)
-        y = grouped_matmul(
-            xp, w["q"][None], jnp.zeros((1,), jnp.int32), **kw,
-        )
+            return jax.lax.psum(y, t) if shard == "row" else y
+
+        y = jax.shard_map(
+            local, mesh=self.mesh,
+            in_specs=(x_spec, w_spec, s_spec) + (P(),) * len(x_scale),
+            out_specs=o_spec, check_vma=False,
+        )(x, w["q"][None], w["scale"][None], *x_scale)
         return y[:b] if bp != b else y
+
+    @property
+    def _attn_proj_shard(self):
+        """``(wqkv, wo)`` tp layouts for :meth:`_dmm`, mirroring
+        :meth:`shardings` (CP attention replicates the projections)."""
+        return ("col", "row") if self.config.attn == "tp" else (None, None)
 
     def _expert_w(self, w):
         """Expert weights for a dense consumer: widen a quantized dict,
@@ -1101,9 +1128,10 @@ class Transformer:
             combine_partials,
         )
 
+        qkv_sh, wo_sh = self._attn_proj_shard
         for li, (blk, (ck, cv)) in enumerate(zip(params["blocks"], caches)):
             xn = self._rmsnorm(x, blk["norm_attn"])
-            qkv = self._dmm(xn, blk["wqkv"])                    # (B, qkv)
+            qkv = self._dmm(xn, blk["wqkv"], shard=qkv_sh)      # (B, qkv)
             q, k, v = jnp.split(qkv, [c.q_dim, c.q_dim + c.kv_dim], axis=-1)
             q = q.reshape(b, c.n_heads, c.head_dim)
             k = k.reshape(b, c.n_kv_heads, c.head_dim)
@@ -1163,12 +1191,12 @@ class Transformer:
                     k_quant=kq_pair, v_quant=vq_pair,
                 )
             new_caches.append((ck, cv))
-            o = self._dmm(o.reshape(b, c.q_dim), blk["wo"])
+            o = self._dmm(o.reshape(b, c.q_dim), blk["wo"], shard=wo_sh)
             x = x + o
             xn = self._rmsnorm(x, blk["norm_mlp"])
             if "up" in blk:
-                h = jax.nn.silu(self._dmm(xn, blk["up"]))
-                x = x + self._dmm(h, blk["down"])
+                h = jax.nn.silu(self._dmm(xn, blk["up"], shard="col"))
+                x = x + self._dmm(h, blk["down"], shard="row")
             elif c.moe == "ep":
                 st = None if moe_state is None else moe_state[li]
                 y, st = self._decode_moe_ep(blk, xn, st)
@@ -1359,9 +1387,8 @@ class Transformer:
         whose ``lax.scan`` carries the caches, lens, tokens and the LL
         MoE state across steps — no host round-trip per token. Same
         results as :meth:`generate` (the per-step twin kept for
-        step-at-a-time callers and CI); behind a dispatch relay this is
-        the serving entry (one dispatch per SEQUENCE instead of ~90 ms
-        × steps). The functional ``EPMoEState`` carry exists precisely
+        step-at-a-time callers and CI); one dispatch per SEQUENCE
+        instead of one per token. The functional ``EPMoEState`` carry exists precisely
         so the barrier-free fused transport can ride a scan; caches,
         lens and state are donated (in place across calls, like the
         per-step jits)."""
@@ -1605,11 +1632,12 @@ class Transformer:
 
         new_layers = []
         new_states = None if moe_state is None else list(moe_state)
+        qkv_sh, wo_sh = self._attn_proj_shard
         for li, (blk, (kp, vp)) in enumerate(
             zip(params["blocks"], state.layers)
         ):
             xn = self._rmsnorm(x, blk["norm_attn"])
-            qkv = self._dmm(xn, blk["wqkv"])                 # (T, qkv)
+            qkv = self._dmm(xn, blk["wqkv"], shard=qkv_sh)   # (T, qkv)
             q, k, v = jnp.split(
                 qkv, [c.q_dim, c.q_dim + c.kv_dim], axis=-1
             )
@@ -1658,11 +1686,11 @@ class Transformer:
                     q_starts, block_q, use_pallas, n_bufs, topologies,
                 )
             o = unpack_gqa_rows(o, c.n_heads).reshape(t, c.q_dim)
-            x = x + self._dmm(o.astype(c.dtype), blk["wo"])
+            x = x + self._dmm(o.astype(c.dtype), blk["wo"], shard=wo_sh)
             xn = self._rmsnorm(x, blk["norm_mlp"])
             if "up" in blk:
-                h = jax.nn.silu(self._dmm(xn, blk["up"]))
-                x = x + self._dmm(h, blk["down"])
+                h = jax.nn.silu(self._dmm(xn, blk["up"], shard="col"))
+                x = x + self._dmm(h, blk["down"], shard="row")
             elif c.moe == "ep":
                 st = None if moe_state is None else moe_state[li]
                 y, st = self._decode_moe_ep(blk, xn, st)

@@ -146,19 +146,10 @@ def create_ep_moe_context(
         max_m=max_m, hidden=hidden, **kw,
     )
     if ctx.transport is None:
-        from triton_distributed_tpu.config import pallas_collectives_available
-
-        if not pallas_collectives_available() and ctx.quant is None:
-            # off-TPU without the TPU-simulation interpreter: the Pallas
-            # transports cannot execute — auto-select degrades to the
-            # XLA a2a (quantized payloads still require Pallas and fail
-            # loudly below)
-            ctx = replace(ctx, transport="xla")
-        else:
-            ctx = replace(
-                ctx,
-                transport="pallas" if ctx.dcn_axis is not None else "fused",
-            )
+        ctx = replace(
+            ctx,
+            transport="pallas" if ctx.dcn_axis is not None else "fused",
+        )
     assert num_experts % ctx.n == 0, f"{num_experts} experts over {ctx.n} ranks"
     ctx.a2a  # fail fast on bad quant/hidden geometry, not at trace time
     if ctx.quant is not None and ctx.transport == "xla":

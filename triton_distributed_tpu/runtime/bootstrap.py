@@ -69,7 +69,7 @@ def initialize_distributed(
     # Must run before any backend touch: jax.distributed.initialize has to
     # precede backend initialization, so the "already initialized" guard
     # checks the distributed client state, not jax.process_count().
-    already = _distributed_initialized()
+    already = jax.distributed.is_initialized()
     if coord and nproc > 1 and not already:
         _initialize_with_retry(
             coord, nproc, int(os.environ.get("JAX_PROCESS_ID", "0"))
@@ -100,18 +100,6 @@ def initialize_distributed(
     if seed is not None:
         init_seed(ctx.rank, seed)
     return ctx
-
-
-def _distributed_initialized() -> bool:
-    """Is the jax distributed client up? ``jax.distributed.is_initialized``
-    where it exists; older jax exposes only the global client state."""
-    probe = getattr(jax.distributed, "is_initialized", None)
-    if probe is not None:
-        return bool(probe())
-    state = getattr(
-        getattr(jax, "_src", None), "distributed", None
-    )
-    return getattr(getattr(state, "global_state", None), "client", None) is not None
 
 
 def _initialize_with_retry(

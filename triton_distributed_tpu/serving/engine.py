@@ -220,6 +220,10 @@ class EngineStats:
     # re-promotion clears it — see HealthLedger)
     degraded: bool = False
     repromotions: int = 0              # probe-driven returns to the fused path
+    # every device-step failure the degrade path absorbed:
+    # {"step", "site", "error"} — what failed and when, so a degraded
+    # run can say why it is on the XLA twin
+    failures: list = field(default_factory=list)
     # --- speculative decoding (serving/spec.py; zero on plain engines) ---
     spec_rows: int = 0                 # verify rows run (one per spec step)
     draft_tokens: int = 0              # draft tokens proposed into verify rows
@@ -370,7 +374,8 @@ class ServingEngine:
                  on_complete=None, health=None,
                  health_peer: str = "site:serving_step",
                  grid_schedule=None, tenants=None,
-                 aging_ticks: int = 64, ops=None):
+                 aging_ticks: int = 64, ops=None,
+                 propagate_failures: bool = False):
         import jax.numpy as jnp
 
         from triton_distributed_tpu.runtime.health import HealthLedger
@@ -381,6 +386,10 @@ class ServingEngine:
         self.params = params
         self.cfg = cfg
         self.use_pallas = use_pallas
+        # True: a failed device step is recorded and RE-RAISED instead
+        # of degrading onto the XLA twin — for callers whose result
+        # must not silently come from the fallback (chip_smoke.py)
+        self.propagate_failures = propagate_failures
         # the protocol seam: every scheduling/pool transition runs
         # through these verbs (serving/protocol.py) — the same objects
         # analysis/servlint.py model-checks
@@ -826,7 +835,12 @@ class ServingEngine:
         batch contract, (T, vocab) logits)."""
         return self.model._serving_jit
 
-    def _run_device(self, arrays, block_q):
+    def _step_args(self, arrays, block_q) -> tuple:
+        """The jitted step's argument tuple for one assembled batch —
+        what :meth:`_run_device` calls it with, and what
+        ``_step_jit().lower(...)`` needs to show the module a step
+        really launches (chip_smoke.py looks for the ragged kernel's
+        custom call there)."""
         jnp = self._jnp
         (tokens, token_rows, token_pos, q_starts, q_lens, kv_dev,
          topo) = arrays
@@ -838,6 +852,15 @@ class ServingEngine:
                 dtype=jnp.int32,
             ),
         )
+        return (
+            self.params, state, jnp.asarray(tokens),
+            jnp.asarray(token_rows), jnp.asarray(token_pos),
+            jnp.asarray(q_starts), jnp.asarray(q_lens),
+            jnp.asarray(topo),
+            self.moe_state, block_q, self.use_pallas, self._n_bufs,
+        )
+
+    def _run_device(self, arrays, block_q):
         from triton_distributed_tpu.lang.launch import maybe_instrument
 
         # host-mode heartbeat around the jitted step: an armed watchdog
@@ -848,13 +871,7 @@ class ServingEngine:
             collective_id=("serving_step", self.health_peer), n=1,
             step=self.step_count,
         )
-        out = step_fn(
-            self.params, state, jnp.asarray(tokens),
-            jnp.asarray(token_rows), jnp.asarray(token_pos),
-            jnp.asarray(q_starts), jnp.asarray(q_lens),
-            jnp.asarray(topo),
-            self.moe_state, block_q, self.use_pallas, self._n_bufs,
-        )
+        out = step_fn(*self._step_args(arrays, block_q))
         if self.moe_state is None:
             logits, self.state = out
         else:
@@ -900,8 +917,12 @@ class ServingEngine:
                   topo)
         try:
             logits = self._run_device(arrays, block_q)
-        except Exception:
-            if not self.use_pallas:
+        except Exception as e:
+            self.stats.failures.append({
+                "step": self.step_count, "site": "serving_step",
+                "error": f"{type(e).__name__}: {e}",
+            })
+            if not self.use_pallas or self.propagate_failures:
                 raise
             # degradation: fall back to the XLA twin (the op-level
             # with_fallback story at engine level) — scheduling state is
@@ -995,7 +1016,17 @@ class ServingEngine:
         request's token stream."""
         t = self.cfg.temperature
         if t <= 0.0:
-            return int(np.argmax(row_logits))
+            tok = int(np.argmax(row_logits))
+            # argmax lands ON a NaN (or +inf) whenever the row holds
+            # one, so this O(1) look catches a poisoned distribution
+            # that would otherwise decode as a valid-looking token id
+            # (the sampling branch below raises on NaN by itself)
+            if not np.isfinite(row_logits[tok]):
+                raise FloatingPointError(
+                    f"non-finite logits for request {req.rid} at step "
+                    f"{self.step_count}"
+                )
+            return tok
         z = np.asarray(row_logits, np.float64) / t
         k = self.cfg.top_k
         if 0 < k < z.shape[-1]:
@@ -1130,6 +1161,8 @@ class DisaggStats:
     # CURRENTLY on the XLA transfer (probation re-promotion clears it)
     degraded_transport: bool = False
     ship_retries: int = 0              # DCN attempts retried before success/fallback
+    # every failed DCN ship attempt: {"tick", "site", "error"}
+    transport_failures: list = field(default_factory=list)
     transport_repromotions: int = 0    # probe-driven returns to the DCN wire
     # --- slice-death failover ---
     failover_role: str | None = None   # which role's slice died
@@ -1216,7 +1249,8 @@ class DisaggregatedEngine:
                  placement: str = "force", traffic: dict | None = None,
                  moe_state="auto", use_pallas: bool = True, health=None,
                  spec_k: int = 0, drafter=None,
-                 adaptive_k: bool = False):
+                 adaptive_k: bool = False,
+                 propagate_failures: bool = False):
         from dataclasses import replace as _rep
 
         from triton_distributed_tpu.runtime.health import HealthLedger
@@ -1269,6 +1303,8 @@ class DisaggregatedEngine:
                 )
         self.transport = transport
         self._transport_pref = transport   # what we re-promote back to
+        # as on ServingEngine: record AND re-raise instead of degrading
+        self.propagate_failures = propagate_failures
         self.hybrid_mesh = hybrid_mesh
         self.dcn_axis = dcn_axis
         self.ship_delay_steps = int(ship_delay_steps)
@@ -1277,6 +1313,7 @@ class DisaggregatedEngine:
             _rep(cfg, prefill_only=True),
             moe_state=moe_state, use_pallas=use_pallas,
             on_complete=self._on_prefill_complete, health=self.health,
+            propagate_failures=propagate_failures,
         )
         self.spec_k = int(spec_k)
         if spec_k:
@@ -1294,6 +1331,7 @@ class DisaggregatedEngine:
                 spec_k=spec_k, drafter=drafter, adaptive_k=adaptive_k,
                 moe_state=moe_state, use_pallas=use_pallas,
                 health=self.health,
+                propagate_failures=propagate_failures,
             )
         else:
             self.decode = ServingEngine(
@@ -1301,6 +1339,7 @@ class DisaggregatedEngine:
                 _rep(dcfg, prefill_only=False),
                 moe_state=moe_state, use_pallas=use_pallas,
                 health=self.health,
+                propagate_failures=propagate_failures,
             )
         self._ready: deque = deque()       # (req, prefill slot) awaiting ship
         self._inflight: list = []
@@ -1474,7 +1513,13 @@ class DisaggregatedEngine:
         for attempt in range(retries):
             try:
                 return send(qpay, spay)
-            except Exception:
+            except Exception as e:
+                self.stats.transport_failures.append({
+                    "tick": self.ticks, "site": "kv_ship",
+                    "error": f"{type(e).__name__}: {e}",
+                })
+                if self.propagate_failures:
+                    raise
                 if attempt == retries - 1:
                     return None
                 self.stats.ship_retries += 1

@@ -61,7 +61,10 @@ def _run(args) -> None:
     import numpy as np
     from jax.sharding import Mesh
 
+    from triton_distributed_tpu.config import enable_compile_cache
     from triton_distributed_tpu.models import Transformer, presets
+
+    enable_compile_cache()
 
     import inspect
 

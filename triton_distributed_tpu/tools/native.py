@@ -6,10 +6,11 @@ Two kinds of "native" live here:
   Reference: csrc/{op_pybind.cc,registry.cc} expose CUDA host utilities
   into Python via pybind11/torch; here the binding layer is ctypes over a
   plain C ABI (pybind11 is not in this toolchain) and the library is
-  built on first use with g++ (cached under csrc/build/). Every entry
-  point has a pure-python fallback so the package works where no
-  compiler exists — the native path is the fast path, not a hard
-  dependency.
+  built on first use with g++ (cached under csrc/build/, keyed by the
+  source's content hash). Every entry point has a pure-python fallback
+  so the package works where no compiler exists — the native path is
+  the fast path, not a hard dependency — but a FAILED build is logged
+  with the compiler's output, never swallowed.
 * **XLA-native collective equivalents** (bottom of the module): the
   degradation targets of ``ops.overlap.with_fallback`` — pure
   ``lax.all_gather``/``psum_scatter`` + ``jnp.dot`` twins of the fused
@@ -22,6 +23,8 @@ Two kinds of "native" live here:
 from __future__ import annotations
 
 import ctypes
+import hashlib
+import logging
 import os
 import pathlib
 import struct
@@ -42,19 +45,34 @@ def _fnv1a(data: bytes) -> int:
 
 _ROOT = pathlib.Path(__file__).resolve().parents[2]
 _SRC = _ROOT / "csrc" / "tdtpu_native.cpp"
-_SO = _ROOT / "csrc" / "build" / "libtdtpu_native.so"
 _lock = threading.Lock()
 _lib_cache: list = []          # [lib or None] once resolved
+_log = logging.getLogger(__name__)
 
 
-def _build() -> bool:
-    _SO.parent.mkdir(parents=True, exist_ok=True)
+def _so_path() -> pathlib.Path:
+    """Library path keyed by the SOURCE's content hash: a build left
+    in csrc/build/ by another checkout or an older source can never be
+    loaded for this one (mtimes say nothing across copies of a tree)."""
+    tag = hashlib.sha256(_SRC.read_bytes()).hexdigest()[:16]
+    return _ROOT / "csrc" / "build" / f"libtdtpu_native-{tag}.so"
+
+
+def _build(so: pathlib.Path) -> bool:
+    so.parent.mkdir(parents=True, exist_ok=True)
+    tmp = so.with_suffix(f".{os.getpid()}.tmp")
     cmd = ["g++", "-O3", "-shared", "-fPIC", "-std=c++17",
-           "-o", str(_SO), str(_SRC)]
+           "-o", str(tmp), str(_SRC)]
     try:
         subprocess.run(cmd, check=True, capture_output=True, timeout=120)
+        os.replace(tmp, so)        # a half-written library never lands
         return True
-    except (subprocess.SubprocessError, FileNotFoundError):
+    except (subprocess.SubprocessError, FileNotFoundError) as e:
+        _log.warning(
+            "native host library build failed — using the pure-python "
+            "fallbacks: %s\n%s", e,
+            (getattr(e, "stderr", b"") or b"").decode(errors="replace")[-2000:],
+        )
         return False
 
 
@@ -65,10 +83,10 @@ def native_lib():
             return _lib_cache[0]
         lib = None
         if os.environ.get("TDTPU_NO_NATIVE") != "1":
-            fresh = _SO.exists() and _SO.stat().st_mtime >= _SRC.stat().st_mtime
-            if fresh or _build():
+            so = _so_path()
+            if so.exists() or _build(so):
                 try:
-                    lib = ctypes.CDLL(str(_SO))
+                    lib = ctypes.CDLL(str(so))
                     u8p = ctypes.POINTER(ctypes.c_uint8)
                     lib.tdtpu_artifact_write.argtypes = [
                         ctypes.c_char_p, u8p, ctypes.c_uint64]
@@ -81,7 +99,9 @@ def native_lib():
                     lib.tdtpu_dataset_len.restype = ctypes.c_uint64
                     lib.tdtpu_dataset_close.argtypes = [ctypes.c_void_p]
                     lib.tdtpu_dataset_len.argtypes = [ctypes.c_void_p]
-                except OSError:
+                except OSError as e:
+                    _log.warning("native host library %s failed to "
+                                 "load: %s", so, e)
                     lib = None
         _lib_cache.append(lib)
         return lib

@@ -55,20 +55,32 @@ TPU_SPECS = {
     "v6e": TpuSpec("v6e", 918.0, 1640.0, 100.0, 4, int8_tops=1836.0,
                    dcn_gbps=25.0),
 }
-_DEFAULT = TPU_SPECS["v5e"]
+#: The chip the CPU dev/test mesh stands in for: kernels there run under
+#: the interpreter and AOT-compile against an unattached v5e topology,
+#: so the model prices v5e. This names a simulation target — it is NOT
+#: a fallback for accelerators missing from the table.
+CPU_MESH_TARGET = "v5e"
 
 
 def detect_spec(device=None) -> TpuSpec:
     """Map jax's device_kind onto a spec row (≡ get_device_name-keyed
-    tables, gemm_perf_model.py). Unknown kinds fall back to v5e."""
+    tables, gemm_perf_model.py). An accelerator with no row raises: a
+    silently borrowed row would price every schedule, wire and
+    placement decision for the wrong chip."""
     device = device or jax.devices()[0]
-    kind = getattr(device, "device_kind", "").lower()
+    if device.platform == "cpu":
+        return TPU_SPECS[CPU_MESH_TARGET]
+    kind = device.device_kind.lower()
     for key, spec in TPU_SPECS.items():
         if key in kind.replace(" ", "").replace("lite", "e"):
             return spec
     if "v5" in kind:
         return TPU_SPECS["v5e" if "lite" in kind else "v5p"]
-    return _DEFAULT
+    raise ValueError(
+        f"perf_model: no TpuSpec row for device_kind "
+        f"{device.device_kind!r} (known: {sorted(TPU_SPECS)}) — add its "
+        "datasheet peaks to TPU_SPECS"
+    )
 
 
 def estimate_gemm_ms(m: int, k: int, n: int, spec: TpuSpec | None = None,

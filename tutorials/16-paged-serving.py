@@ -21,9 +21,8 @@ model mode:
   through the table in place.
 * ``generate(..., block_table=...)`` / ``generate_scan(...)`` — the
   serving loops run unchanged on pools; generate_scan folds the whole
-  decode into ONE jitted lax.scan (one dispatch per SEQUENCE — behind
-  a ~90 ms dispatch relay that is the difference between usable and
-  not).
+  decode into ONE jitted lax.scan (one dispatch per SEQUENCE, not
+  one per token).
 """
 
 from _common import get_mesh

@@ -30,7 +30,9 @@ def get_mesh(min_devices: int = 8):
     from jax.sharding import Mesh
 
     from triton_distributed_tpu import runtime
+    from triton_distributed_tpu.config import enable_compile_cache
 
+    enable_compile_cache()
     runtime.initialize_distributed()
     devs = jax.devices()
     assert len(devs) >= min_devices, (
