@@ -116,8 +116,15 @@ def _run(spec, cell, seed, seconds, trace, t_start, rehearse, peaks,
         # the LAST seconds of the window: stopping the profiler blocks
         # the loop for seconds, and there no request falls due in it
         span_s = min(TRACE_SECONDS, seconds)
+        # without the profiler's Python tracer: it records every Python
+        # call of the host loop (3k events an engine step) and the
+        # device waits while it does, 1.4-1.9 ms a step (PERF.md §6, PR
+        # 25). Spans are TraceAnnotations and need the host tracer only
+        options = jax.profiler.ProfileOptions()
+        options.python_tracer_level = 0
         tracer = (seconds - span_s, seconds,
-                  lambda: jax.profiler.start_trace(trace_dir),
+                  lambda: jax.profiler.start_trace(
+                      trace_dir, profiler_options=options),
                   jax.profiler.stop_trace)
 
     setup_s = time.perf_counter() - t_start
@@ -133,7 +140,9 @@ def _run(spec, cell, seed, seconds, trace, t_start, rehearse, peaks,
     failed = metrics.failures(win, sizes["vocab"])
     e2e = metrics.end_to_end(win, setup_s)
     walls = sorted((te - ts, ts) for ts, te, *_ in win.steps)
-    say(window={"closed_at_s": win.closed_at, **win.counters,
+    say(window={"closed_at_s": win.closed_at,
+                **{k: v for k, v in win.counters.items()
+                   if v or not k.startswith("stats.")},
                 "profiler_stall_s": win.stalled,
                 "longest_steps_ms_at_s": [
                     [round(1e3 * w, 1), round(ts, 2)]
@@ -189,18 +198,10 @@ def _run(spec, cell, seed, seconds, trace, t_start, rehearse, peaks,
         from benchmark.harness import trace as tracelib
 
         summary = tracelib.TraceSummary.from_file(
-            tracelib.newest_xplane(trace_dir))
-        rec = {
-            "series": metrics.series(win),
-            "counters": win.counters, "trace": summary,
-            "peaks": peaks, "chips": cell.chips, "config": cfg,
-        }
-        for m in cell.per_layer:
-            v = metrics.read_layer_metric(
-                rec, cell.layer_metrics[m["name"]])
-            if v is not None:
-                line["metrics"][m["name"]] = {
-                    "value": v, "unit": m["unit"]}
+            tracelib.newest_xplane(trace_dir),
+            spans=tracelib.known_spans(spec.bench))
+        rec = metrics.layer_record(win, summary, peaks, cell)
+        line["metrics"] = metrics.per_layer(cell, rec)
         device["busy_s"] = summary.busy_seconds()
         device["window_s"] = win.traced[1] - win.traced[0]
         line["breakdown"] = {"device_ops": summary.top_ops(10),

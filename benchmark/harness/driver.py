@@ -32,6 +32,9 @@ class Window:
     #   each batched row advanced ``take`` positions to ``cursor``
     closed_at: float = 0.0
     counters: dict = dataclasses.field(default_factory=dict)
+    # the engine's own per-step lists over the window, as
+    # ``stats.<field>``: one entry per entry of ``steps``
+    stats_series: dict = dataclasses.field(default_factory=dict)
     traced: tuple | None = None     # (start, end) of the traced part
     stalled: float = 0.0            # seconds the profiler's start/stop took
 
@@ -47,8 +50,8 @@ def serve(engine, arrivals: list, seconds: float, drain_s: float, *,
     context manager put around the loop's phases; ``tracer`` is
     ``(start_at_s, stop_at_s, start_fn, stop_fn)`` for a traced run.
     The spans are the loop's own phases and the engine's public
-    ``step()`` (``trace.SPANS``); spans inside the program are the
-    program's to add."""
+    ``step()`` (each a file in ``host_spans/``); spans inside the
+    program are the program's to add, with a file each."""
     clock = time.perf_counter
     stats = engine.stats
     todo = deque(sorted(arrivals, key=lambda a: a.due))
@@ -59,6 +62,7 @@ def serve(engine, arrivals: list, seconds: float, drain_s: float, *,
             ("prefill_tokens", "generated_tokens", "completed",
              "evictions", "deferrals")}
     tokens0 = len(stats.step_tokens)
+    before = program.stats_snapshot(engine)
     lowered0 = program.programs_lowered()
     tracing = False
     t0 = clock()
@@ -125,4 +129,16 @@ def serve(engine, arrivals: list, seconds: float, drain_s: float, *,
     win.counters["device_steps"] = len(win.steps)
     win.counters["programs_lowered"] = (
         program.programs_lowered() - lowered0)
+    # whatever else the engine counts or times, by its field's name:
+    # numbers as deltas, lists that grew by one entry per device step
+    # as per-step series
+    after = program.stats_snapshot(engine)
+    for k, v in after["numbers"].items():
+        win.counters[f"stats.{k}"] = v - before["numbers"].get(k, 0)
+    for k, (entries, n) in after["lists"].items():
+        n0 = before["lists"].get(k, (None, 0))[1]
+        grown = entries[n0:n]
+        if win.steps and len(grown) == len(win.steps) and all(
+                isinstance(x, (int, float)) for x in grown):
+            win.stats_series[f"stats.{k}"] = [float(x) for x in grown]
     return win

@@ -32,10 +32,11 @@ object ``{"kind": ..., <parameters>}`` where the kind takes parameters.
 from __future__ import annotations
 
 import dataclasses
-import importlib
 import statistics
 
 import numpy as np
+
+from benchmark.harness.spec import named
 
 _NORMAL = statistics.NormalDist()
 
@@ -88,11 +89,10 @@ def resolve(kind: str, table: dict):
     from a file added beside the harness."""
     if kind in table:
         return table[kind]
-    module, sep, func = kind.partition(":")
-    if not sep:
+    if ":" not in kind:
         raise ValueError(f"unknown kind {kind!r} (has: "
                          f"{', '.join(table)}, or 'package.module:function')")
-    return getattr(importlib.import_module(module), func)
+    return named(kind)
 
 
 def _kind(entry) -> tuple:

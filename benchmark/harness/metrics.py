@@ -2,17 +2,36 @@
 failures, and the generic readers that per-layer metric files name.
 
 A per-layer metric is ``layer_metrics/<name>.json``:
-``{"reader": <kind>, "args": {...}}``. The kinds:
+``{"reader": <kind>, "args": {...}}``. ``reader`` is one of the kinds
+below or, for a number none of them gives, a function in a file a later
+PR adds (``readers/`` is the place), named ``"package.module:function"``
+and called as ``function(rec, **args) -> float | None``. The kinds:
 
-``percentile_of``          ``series``, ``q``: the q-th percentile of a
-                           named series of the window.
+``percentile_of``          ``series``, ``q``, ``scale`` (default 1):
+                           the q-th percentile of a named series of the
+                           window, times ``scale``.
 ``span_minus_counter``     ``span``, ``counter``: per engine step, the
                            harness's span minus the program's own
                            timing of the same step; the mean.
 ``counter_ratio``          ``num``, ``den``, ``scale``: a ratio of two
                            counts of the window.
-``trace_events_ms_per_step`` ``match``: device time of the trace's
-                           events whose name contains ``match``, per
+``trace_events_ms_per_step`` ``match`` or ``scope``: device time of
+                           the trace's events whose instruction name
+                           contains ``match``, or whose scope path (the
+                           operation's ``op_name``) has ``scope`` as
+                           one ``/``-separated component, per engine
+                           step of the traced part.
+``host_span_ms_per_step``  ``span``, ``self_time`` (default false):
+                           host time inside the trace's host events of
+                           that name — with ``self_time``, less what
+                           the known spans nested in them cover — per
+                           engine step of the traced part.
+``idle_ms_per_step``       ``span``, ``q`` (optional): device-idle time
+                           of the trace that ``TraceSummary.idle_by_path``
+                           gives to that host span or to a span nested
+                           in it; with ``q`` the q-th percentile over
+                           the span's instances (one stalled step does
+                           not move a median), else the sum per
                            engine step of the traced part.
 ``trace_roofline_share``   ``match``, ``needs``, ``peak_bytes``,
                            ``peak_flops``: per traced step the least
@@ -31,8 +50,9 @@ left out of the result line.
 
 from __future__ import annotations
 
-import importlib
 import math
+
+from benchmark.harness.spec import named
 
 
 def percentile(values, q: float) -> float:
@@ -65,7 +85,9 @@ def series(window) -> dict:
     """Named per-request and per-step series of one window, in ms.
     Per-step series are aligned with each other; ``traced_steps``
     holds the indices of the steps inside the traced part and
-    ``traced_rows`` their batched rows."""
+    ``traced_rows`` their batched rows. ``stats.<field>`` are the
+    engine's own per-step lists as the program wrote them (no unit
+    implied: ``percentile_of`` takes a ``scale``)."""
     arr = window.arrivals
     first = [a.token_times[0] - a.due for a in arr if a.token_times]
     gaps = [b - a for r in arr
@@ -79,6 +101,7 @@ def series(window) -> dict:
                           if a.admitted is not None],
         "step_wall_ms": [1e3 * (st[1] - st[0]) for st in window.steps],
         "step_device_ms": [1e3 * st[2] for st in window.steps],
+        **window.stats_series,
     }
     if window.traced:
         lo, hi = window.traced
@@ -122,9 +145,9 @@ def end_to_end(window, setup_s: float) -> dict:
 # record = {"series": {...}, "counters": {...}, "trace": TraceSummary |
 #           None, "peaks": {...}, "chips": n, "config": {...}}
 
-def _percentile_of(rec, series, q):
+def _percentile_of(rec, series, q, scale=1.0):
     xs = rec["series"].get(series)
-    return percentile(xs, q) if xs else None
+    return scale * percentile(xs, q) if xs else None
 
 
 def _span_minus_counter(rec, span, counter):
@@ -141,12 +164,31 @@ def _counter_ratio(rec, num, den, scale=1.0):
     return scale * c.get(num, 0) / c[den]
 
 
-def _trace_events_ms_per_step(rec, match):
+def _per_traced_step(rec, seconds):
+    """``seconds(trace)`` in ms per engine step of the traced part."""
     tr, steps = rec.get("trace"), rec["series"].get("traced_steps")
     if tr is None or not steps:
         return None
-    secs = tr.matched_seconds(match)
+    secs = seconds(tr)
     return None if secs is None else 1e3 * secs / len(steps)
+
+
+def _trace_events_ms_per_step(rec, match=None, scope=None):
+    return _per_traced_step(
+        rec, lambda tr: tr.matched_seconds(match, scope=scope))
+
+
+def _host_span_ms_per_step(rec, span, self_time=False):
+    return _per_traced_step(
+        rec, lambda tr: tr.span_seconds(span, self_time))
+
+
+def _idle_ms_per_step(rec, span, q=None):
+    if q is None:
+        return _per_traced_step(rec, lambda tr: tr.idle_seconds(span))
+    tr = rec.get("trace")
+    per = tr.idle_per_instance(span) if tr is not None else None
+    return 1e3 * percentile(per, q) if per else None
 
 
 def _trace_roofline_share(rec, match, needs, peak_bytes, peak_flops):
@@ -156,8 +198,7 @@ def _trace_roofline_share(rec, match, needs, peak_bytes, peak_flops):
     secs = tr.matched_seconds(match)
     if not secs:
         return None
-    module, _, func = needs.partition(":")
-    step_needs = getattr(importlib.import_module(module), func)
+    step_needs = named(needs)
     # per chip: a step's bytes and operations are spread over the
     # cell's chips, and matched_seconds is already the mean over chips
     bw, peak = float(rec["peaks"][peak_bytes]), float(
@@ -173,13 +214,35 @@ READERS = {
     "counter_ratio": _counter_ratio,
     "trace_events_ms_per_step": _trace_events_ms_per_step,
     "trace_roofline_share": _trace_roofline_share,
+    "host_span_ms_per_step": _host_span_ms_per_step,
+    "idle_ms_per_step": _idle_ms_per_step,
 }
+
+
+def layer_record(window, trace, peaks, cell) -> dict:
+    """What every reader is given of one traced run."""
+    return {"series": series(window), "counters": window.counters,
+            "trace": trace, "peaks": peaks, "chips": cell.chips,
+            "config": cell.config}
+
+
+def per_layer(cell, rec: dict) -> dict:
+    """The cell's per-layer metrics that found something to read, as
+    the result line has them."""
+    out = {}
+    for m in cell.per_layer:
+        v = read_layer_metric(rec, cell.layer_metrics[m["name"]])
+        if v is not None:
+            out[m["name"]] = {"value": v, "unit": m["unit"]}
+    return out
 
 
 def read_layer_metric(rec: dict, definition: dict):
     """One metric from the run's record, or None."""
     kind = definition["reader"]
-    if kind not in READERS:
-        raise KeyError(f"unknown reader kind {kind!r} "
-                       f"(has: {', '.join(READERS)})")
-    return READERS[kind](rec, **definition.get("args", {}))
+    if kind not in READERS and ":" not in kind:
+        raise KeyError(f"unknown reader kind {kind!r} (has: "
+                       f"{', '.join(READERS)}, or "
+                       "'package.module:function')")
+    reader = READERS.get(kind) or named(kind)
+    return reader(rec, **definition.get("args", {}))

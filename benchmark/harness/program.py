@@ -233,6 +233,28 @@ def warm_up(engine, vocab: int) -> dict:
     return {"rungs": rungs, "seconds": time.perf_counter() - t0}
 
 
+def stats_snapshot(engine) -> dict:
+    """``{field: value}`` of every numeric field of ``engine.stats`` and
+    ``{field: (list, length now)}`` of every list field, whatever the
+    fields are: a counter or a per-step timing the program adds later
+    is found here by its name."""
+    st = engine.stats
+    if dataclasses.is_dataclass(st):
+        names = [f.name for f in dataclasses.fields(st)]
+    else:
+        names = [k for k, v in {**vars(type(st)), **vars(st)}.items()
+                 if not k.startswith("_") and not callable(v)
+                 and not isinstance(v, property)]
+    numbers, lists = {}, {}
+    for k in names:
+        v = getattr(st, k)
+        if isinstance(v, list):
+            lists[k] = (v, len(v))
+        elif isinstance(v, (int, float)) and not isinstance(v, bool):
+            numbers[k] = v
+    return {"numbers": numbers, "lists": lists}
+
+
 def check_health(engine) -> None:
     st = engine.stats
     if st.degraded or st.repromotions or st.failures:

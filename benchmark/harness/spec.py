@@ -11,16 +11,29 @@ a cell, a mix, a configuration or a metric as files plus an entry.
     <bench>/mixes/<traffic>.json           traffic-mix parameters
     <bench>/cells/<workload>.json          the cell's offered rate + knee
     <bench>/layer_metrics/<metric>.json    reader kind + arguments
+    <bench>/host_spans/<span>.json         a host span the trace reads
+
+Code a later PR adds — a mix's kind, a roofline's ``needs``, a metric's
+reader — is a function in a file of its own, named where it is used as
+``"package.module:function"`` (``named``).
 """
 
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import json
 import pathlib
 
 #: the checkout root (the directory that holds BENCHMARK.json)
 ROOT = pathlib.Path(__file__).resolve().parents[2]
+
+
+def named(path: str):
+    """The function ``"package.module:function"`` names; a module or a
+    function that is not there raises (ImportError, AttributeError)."""
+    module, _, func = path.partition(":")
+    return getattr(importlib.import_module(module), func)
 
 
 def _read(path: pathlib.Path) -> dict:

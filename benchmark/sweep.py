@@ -18,9 +18,11 @@ One process, one set-up, then any of:
                          program's gaps and the CONTROL's (the reference
                          at the configuration's ``control_bits``) on the
                          same prompts and served tokens.
-``--trace-probe``        a short traced window; the trace's planes,
-                         lines and heaviest events are written to
-                         ``chiprun_out/`` with the ``.xplane.pb``.
+``--trace-probe``        a traced stretch of a 20 s window; the trace's
+                         planes, lines, heaviest events, stat names and
+                         scope paths are written to ``chiprun_out/``
+                         with the ``.xplane.pb`` (``--python-tracer 0``:
+                         without the profiler's Python tracer).
 
     chiprun -- python3 benchmark/sweep.py --workload dsmoe16b.chat \\
         --rates 6,8,10,12,14,17,20 --limit-seeds 1,2,3 --trace-probe
@@ -104,6 +106,9 @@ def main() -> int:
                     type=lambda v: v if v == "fp8" else int(v),
                     help="read another control than the configuration's")
     ap.add_argument("--trace-probe", action="store_true")
+    ap.add_argument("--python-tracer", type=int, choices=(0, 1), default=1,
+                    help="0: the probe's trace without the profiler's "
+                         "Python tracer (what it costs the host loop)")
     ap.add_argument("--root", default=str(ROOT), help=argparse.SUPPRESS)
     args = ap.parse_args()
 
@@ -187,11 +192,15 @@ def main() -> int:
             tdir = str(ROOT / ".profiles" / "bench" / "probe")
             shutil.rmtree(tdir, ignore_errors=True)
             span = jax.profiler.TraceAnnotation
-            arr = loadgen.generate(mix, rate, 6.0, args.seed, vocab)
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = args.python_tracer
+            # long enough for the slots to fill as in a full run
+            arr = loadgen.generate(mix, rate, 20.0, args.seed, vocab)
             win = driver.serve(
-                eng, arr, 6.0, 60.0, span=span,
-                tracer=(2.0, 4.0,
-                        lambda: jax.profiler.start_trace(tdir),
+                eng, arr, 20.0, 60.0, span=span,
+                tracer=(16.0, 18.0,
+                        lambda: jax.profiler.start_trace(
+                            tdir, profiler_options=options),
                         jax.profiler.stop_trace))
             path = tracelib.newest_xplane(tdir)
             size = os.path.getsize(path)
@@ -201,11 +210,18 @@ def main() -> int:
             if size < 48 << 20:
                 shutil.copy(path, out_dir / f"{cell.name}.xplane.pb")
             summ = tracelib.TraceSummary.from_file(path)
+            inside = metrics.series(win)["traced_steps"]
             say(trace_probe={
                 "xplane_bytes": size, "traced": win.traced,
+                "python_tracer": args.python_tracer,
+                "traced_steps": len(inside),
+                "idle_ms_per_step": {
+                    k: 1e3 * v / len(inside)
+                    for k, v in summ.idle_by_host_span(10)},
                 "chips": summ.chips(), "busy_s": summ.busy_seconds(),
-                "host_spans": {k: len(v)
-                               for k, v in summ.host_spans.items()},
+                "host_spans": sorted({"/".join(p[0])
+                                      for p in summ.span_paths()}),
+                "host_events": len(summ.host_events),
                 "top_ops": summ.top_ops(10),
                 "idle": summ.idle_by_host_span(10)})
     return 0
