@@ -1,0 +1,380 @@
+"""The engine's step, measured from inside (ISSUE 26): the six host
+spans and per-step lists of ``ServingEngine.step``, the device scopes of
+the jitted serving step, and the request stamps with their counters.
+
+All on the CPU at a tiny size: what is opened, when, how often and
+under which name — never a time (times come from the chip).
+"""
+
+import re
+import time
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import Mesh
+
+from triton_distributed_tpu.models import Transformer, TransformerConfig
+from triton_distributed_tpu.serving import (
+    DisaggregatedEngine,
+    EngineConfig,
+    Request,
+    ServingEngine,
+    SpeculativeEngine,
+    make_drafter,
+    poisson_trace,
+)
+from triton_distributed_tpu.serving.engine import PHASES
+
+pytestmark = pytest.mark.fast
+
+CFG = dict(
+    vocab=128, n_layers=2, hidden=64, ffn=128,
+    n_heads=4, n_kv_heads=2, head_dim=16,
+    dtype=jnp.float32, param_dtype=jnp.float32,
+)
+#: the three block kinds of ``serving_step``: dense FFN, EP experts
+#: (ops/moe.py) and the non-EP expert branch
+BLOCKS = {
+    "dense": {},
+    "ep": dict(moe="ep", moe_layers=(1,), num_experts=4, topk=2),
+    "tp": dict(moe="tp", moe_layers=(1,), num_experts=4, topk=2),
+}
+#: the device scopes each block kind must show (``embed`` ... ``lm_head``
+#: are every step's; layer 0 is dense in all three)
+COMMON = {"embed", "attn_proj", "kv_append", "attn", "dense_ffn", "lm_head"}
+SCOPES = {
+    "dense": COMMON,
+    "ep": COMMON | {"moe_route", "moe_dispatch", "moe_gemm", "moe_combine"},
+    "tp": COMMON | {"moe_gemm"},
+}
+SPANS = [f"engine.{p}" for p in PHASES]
+ECFG = EngineConfig(slots=4, token_budget=48, chunk=16, page=8, npages=32)
+
+
+@pytest.fixture(scope="module")
+def mesh1():
+    return Mesh(np.asarray(jax.devices()[:1]), ("tp",))
+
+
+def _model(mesh, kind):
+    model = Transformer(TransformerConfig(**CFG, **BLOCKS[kind]), mesh,
+                        "tp", ())
+    return model, model.init(jax.random.PRNGKey(0))
+
+
+@pytest.fixture(scope="module")
+def models(mesh1):
+    return {kind: _model(mesh1, kind) for kind in BLOCKS}
+
+
+class _Annotation:
+    """Stands in for ``jax.profiler.TraceAnnotation``: records what is
+    opened, with its ``step``, and that it is closed again."""
+
+    log: list = []
+    open_now: list = []
+
+    def __init__(self, name, **kw):
+        self.name, self.step = name, kw.get("step")
+
+    def __enter__(self):
+        _Annotation.log.append((self.name, self.step))
+        _Annotation.open_now.append(self.name)
+
+    def __exit__(self, *exc):
+        assert _Annotation.open_now.pop() == self.name
+        return False
+
+
+@pytest.fixture
+def annotations(monkeypatch):
+    _Annotation.log, _Annotation.open_now = [], []
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
+    return _Annotation
+
+
+def _per_step(log):
+    """The recorded spans cut into one list per ``step()`` call."""
+    calls = []
+    for name, step in log:
+        if name == "engine.admit":
+            calls.append([])
+        calls[-1].append((name, step))
+    return calls
+
+
+def _lists(stats):
+    return {p: getattr(stats, f"{p}_times") for p in PHASES}
+
+
+# ------------------------------------------------- the six per-step lists
+
+@pytest.mark.parametrize("kind", ["dense", "ep"])
+def test_each_list_grows_by_one_per_device_step_and_sums_to_the_wall(
+        models, kind):
+    model, params = models[kind]
+    eng = ServingEngine(model, params, ECFG)
+    # nothing submitted: admit and assemble run, the device does not
+    eng.step()
+    assert all(v == [] for v in _lists(eng.stats).values())
+    assert eng.stats.step_times == []
+    trace = poisson_trace(7, 6, 1.0, 5, 30, 3, 6, 128)
+    eng.submit_trace(trace)
+    walls, device_steps = [], 0
+    while not eng.idle:
+        before = len(eng.stats.step_times)
+        t0 = time.perf_counter()
+        eng.step()
+        wall = time.perf_counter() - t0
+        ran = len(eng.stats.step_times) - before
+        assert ran in (0, 1)
+        device_steps += ran
+        # every list has exactly one entry per step that ran the device
+        assert {len(v) for v in _lists(eng.stats).values()} == {device_steps}
+        if ran:
+            walls.append(wall)
+    assert device_steps == len(walls) > 5
+    lists = _lists(eng.stats)
+    sums = [sum(lists[p][i] for p in PHASES) for i in range(device_steps)]
+    assert all(v >= 0.0 for vs in lists.values() for v in vs)
+    # the spans lie inside step(): never more than its wall time, and
+    # (the typical step) within a few % of it
+    assert all(s <= w for s, w in zip(sums, walls))
+    assert np.median(np.asarray(sums) / np.asarray(walls)) > 0.9
+    # step_times keeps its meaning: uploads + dispatch + fetch
+    for i, dt in enumerate(eng.stats.step_times):
+        assert dt == pytest.approx(
+            lists["upload"][i] + lists["dispatch"][i] + lists["fetch"][i],
+            rel=1e-12)
+
+
+# ------------------------------------------------------ the six host spans
+
+def test_six_annotations_open_once_per_device_step_in_order(
+        models, annotations):
+    model, params = models["dense"]
+    eng = ServingEngine(model, params, ECFG)
+    eng.step()                                  # an empty step
+    eng.submit_trace(poisson_trace(3, 4, 1.0, 5, 20, 2, 4, 128))
+    while not eng.idle:
+        eng.step()
+    assert not annotations.open_now
+    calls = _per_step(annotations.log)
+    assert calls[0] == [("engine.admit", 0), ("engine.assemble", 0)]
+    full = [c for c in calls if len(c) > 2]
+    assert len(full) == len(eng.stats.step_times) > 3
+    for call in calls:
+        # one step number on every span of a call, counting up
+        assert len({step for _, step in call}) == 1
+        assert [n for n, _ in call] in (SPANS[:2], SPANS)
+    assert [c[0][1] for c in calls] == list(range(len(calls)))
+
+
+def test_a_degraded_step_re_runs_upload_and_dispatch_into_one_entry(
+        mesh1, models, annotations, monkeypatch):
+    import triton_distributed_tpu.kernels.ragged_paged_attention as rpa
+
+    # a model of its own: a step program traced with the real kernel
+    # would be served from the jit cache and never meet the mock
+    model = Transformer(TransformerConfig(**CFG), mesh1, "tp", ())
+    params = models["dense"][1]
+
+    def boom(*a, **k):
+        raise RuntimeError("injected kernel failure")
+
+    monkeypatch.setattr(rpa, "ragged_paged_attention", boom)
+    eng = ServingEngine(
+        model, params,
+        EngineConfig(slots=2, token_budget=32, chunk=8, page=8, npages=16))
+    req = Request(rid=0, prompt=np.arange(9, dtype=np.int32), max_new=3,
+                  arrival=0.0)
+    stats = eng.run([req], max_steps=50)
+    assert stats.degraded and req.done
+    calls = _per_step(annotations.log)
+    # the failing launch dies inside engine.dispatch; the XLA twin's
+    # re-run opens upload and dispatch again, then fetch and advance
+    assert [n for n, _ in calls[0]] == [
+        "engine.admit", "engine.assemble", "engine.upload",
+        "engine.dispatch", "engine.upload", "engine.dispatch",
+        "engine.fetch", "engine.advance"]
+    assert all([n for n, _ in c] == SPANS for c in calls[1:]
+               if len(c) > 2)
+    # still ONE entry per device step, the two runs summed into it
+    assert {len(v) for v in _lists(stats).values()} == {
+        len(stats.step_times)}
+
+
+def test_the_spans_through_the_speculative_engine(models, annotations):
+    model, params = models["dense"]
+    eng = SpeculativeEngine(model, params, ECFG, spec_k=2,
+                            drafter=make_drafter("ngram"),
+                            use_pallas=False)
+    trace = poisson_trace(5, 4, 1.0, 5, 20, 6, 10, 128)
+    stats = eng.run(trace, max_steps=300)
+    assert stats.completed == 4
+    full = [c for c in _per_step(annotations.log) if len(c) > 2]
+    assert len(full) == len(stats.step_times)
+    assert all([n for n, _ in c] == SPANS for c in full)
+    assert {len(v) for v in _lists(stats).values()} == {len(full)}
+    # stamped in step(), so the override of _advance_row is covered
+    assert all(r.t_submit < r.t_admit < r.t_first for r in trace)
+
+
+def test_the_spans_through_both_roles_of_a_disaggregated_engine(
+        annotations):
+    devs = jax.devices()
+    mesh_p = Mesh(np.asarray(devs[:1]), ("tp",))
+    mesh_d = Mesh(np.asarray(devs[1:2]), ("tp",))
+    hybrid = Mesh(np.asarray(devs[:2]).reshape(2, 1), ("dcn", "tp"))
+    cfg = TransformerConfig(**CFG, kv_quant="int8")
+    mp = Transformer(cfg, mesh_p, "tp", ())
+    md = Transformer(cfg, mesh_d, "tp", ())
+    params = mp.init(jax.random.PRNGKey(0))
+    pp = jax.tree.map(jax.device_put, params, mp.shardings())
+    pd = jax.tree.map(jax.device_put, params, md.shardings())
+    eng = DisaggregatedEngine(
+        mp, pp, md, pd, ECFG, hybrid_mesh=hybrid, dcn_axis="dcn",
+        transport="dcn", ship_delay_steps=1)
+    trace = poisson_trace(7, 4, 1.0, 5, 20, 3, 5, 128)
+    stats = eng.run(trace, max_ticks=400)
+    assert stats.completed == 4 and stats.ships > 0
+    full = [c for c in _per_step(annotations.log) if len(c) > 2]
+    assert all([n for n, _ in c] == SPANS for c in full)
+    assert len(full) == (len(stats.prefill.step_times)
+                         + len(stats.decode.step_times))
+    for role in (stats.prefill, stats.decode):
+        assert {len(v) for v in _lists(role).values()} == {
+            len(role.step_times)}
+    # the prefill role admits and emits the first token; the decode
+    # role takes the row by reserve_shipped and counts neither again
+    assert stats.prefill.admissions == stats.prefill.first_tokens == 4
+    assert stats.decode.admissions == stats.decode.first_tokens == 0
+
+
+# ------------------------------------------------------ the device scopes
+
+def lowered_scope_components(eng) -> set:
+    """Every ``/``-separated component of every operation name in the
+    step program this engine would launch now (its lowered text with
+    debug info)."""
+    from triton_distributed_tpu.kernels.ragged_paged_attention import (
+        auto_block_q,
+    )
+
+    eng._admit()
+    *arrays, batched, _ = eng._assemble()
+    assert batched
+    text = eng._step_jit().lower(*eng._step_args(
+        tuple(arrays), auto_block_q(1, eng._g))).as_text(debug_info=True)
+    parts = set()
+    for path in re.findall(r'loc\("([^"]+)"', text):
+        parts.update(path.split("/"))
+    return parts
+
+
+@pytest.mark.parametrize("kind", list(BLOCKS))
+def test_the_lowered_step_holds_every_scope_as_a_whole_component(
+        models, kind):
+    model, params = models[kind]
+    eng = ServingEngine(model, params, ECFG, use_pallas=False)
+    eng.submit(Request(rid=0, prompt=np.arange(9, dtype=np.int32),
+                       max_new=2))
+    parts = lowered_scope_components(eng)
+    assert SCOPES[kind] <= parts, SCOPES[kind] - parts
+    # a block kind the model does not have leaves no scope behind
+    assert not (SCOPES["ep"] - SCOPES[kind]) & parts
+
+
+# ------------------------------------------- request stamps and counters
+
+def test_a_request_submitted_to_a_full_engine_waits_then_is_stamped(models):
+    model, params = models["dense"]
+    eng = ServingEngine(
+        model, params,
+        EngineConfig(slots=2, token_budget=32, chunk=8, page=8, npages=16))
+    reqs = [Request(rid=i, prompt=(np.arange(9) + i).astype(np.int32),
+                    max_new=3) for i in range(4)]
+    for r in reqs:
+        assert r.t_submit is r.t_admit is r.t_first is None
+        eng.submit(r)
+    eng.step()
+    # two slots: two admitted in the first step, two still waiting
+    assert [r.t_admit is not None for r in reqs] == [True, True, False,
+                                                     False]
+    eng.run(max_steps=100)
+    assert all(r.done for r in reqs)
+    assert all(r.t_submit < r.t_admit < r.t_first for r in reqs)
+    # the late two waited for a slot, through whole steps
+    assert min(r.t_admit - r.t_submit for r in reqs[2:]) > max(
+        r.t_admit - r.t_submit for r in reqs[:2])
+    st = eng.stats
+    assert st.admissions == st.first_tokens == 4
+    assert st.queue_wait_s == pytest.approx(
+        sum(r.t_admit - r.t_submit for r in reqs))
+    assert st.first_token_s == pytest.approx(
+        sum(r.t_first - r.t_admit for r in reqs))
+
+
+def test_an_evicted_and_readmitted_request_is_counted_once(models):
+    model, params = models["dense"]
+    eng = ServingEngine(
+        model, params,
+        EngineConfig(slots=4, token_budget=48, chunk=16, page=8,
+                     npages=12))
+    trace = poisson_trace(7, 8, 1.0, 5, 30, 3, 6, 128)
+    for r in trace:
+        r.arrival = 0.0
+    eng.submit_trace(trace)
+    stamps = {}
+    while not eng.idle:
+        eng.step()
+        for r in trace:
+            if r.t_admit is not None:
+                # the first admission's stamp never moves
+                assert stamps.setdefault(r.rid, r.t_admit) == r.t_admit
+    st = eng.stats
+    assert st.completed == 8 and st.evictions > 0
+    assert any(r.evictions for r in trace)
+    assert st.admissions == st.first_tokens == 8
+    assert st.queue_wait_s == pytest.approx(
+        sum(r.t_admit - r.t_submit for r in trace))
+    assert st.first_token_s == pytest.approx(
+        sum(r.t_first - r.t_admit for r in trace))
+
+
+# ----------------------------------------------- profiler on, profiler off
+
+def test_token_streams_are_identical_with_the_profiler_on_and_off(
+        models, tmp_path):
+    from jax.profiler import ProfileData
+
+    from triton_distributed_tpu.tools import group_profile
+
+    model, params = models["ep"]
+
+    def serve():
+        eng = ServingEngine(model, params, ECFG)
+        trace = poisson_trace(11, 5, 1.0, 5, 24, 3, 6, 128)
+        eng.run(trace, max_steps=300)
+        return [tuple(r.generated) for r in trace], eng.stats
+
+    plain, _ = serve()
+    with group_profile(tmp_path) as where:
+        traced, stats = serve()
+    assert traced == plain and all(len(t) >= 3 for t in plain)
+    # and the spans really are in the profile, on the host plane, once
+    # per device step each
+    files = list(where.rglob("*.xplane.pb"))
+    assert len(files) == 1
+    seen = {}
+    for plane in ProfileData.from_file(str(files[0])).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            for ev in line.events:
+                if ev.name in SPANS:
+                    seen[ev.name] = seen.get(ev.name, 0) + 1
+    assert set(seen) == set(SPANS)
+    assert {seen[n] for n in SPANS[2:]} == {len(stats.step_times)}

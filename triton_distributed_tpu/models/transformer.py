@@ -1253,8 +1253,9 @@ class Transformer:
         b = xn.shape[0]
         shards = self.token_shards
         pad = (-b) % shards
-        xp = jnp.pad(xn, ((0, pad), (0, 0)))
-        logits = xp.astype(jnp.float32) @ blk["router"]
+        with jax.named_scope("moe_route"):
+            xp = jnp.pad(xn, ((0, pad), (0, 0)))
+            logits = xp.astype(jnp.float32) @ blk["router"]
         wq = isinstance(blk["moe_up"], dict)
         ctx = self._moe_ep_ctx(
             (b + pad) // shards, inference=True, weights_quantized=wq
@@ -1608,27 +1609,58 @@ class Transformer:
             unpack_gqa_rows,
         )
 
+        # device scopes (``jax.named_scope``: one component of every
+        # operation's ``op_name``, trace-time only): embed, attn_proj,
+        # kv_append, attn, dense_ffn, lm_head here; moe_route,
+        # moe_dispatch, moe_gemm, moe_combine in ops/moe.py
+        scope = jax.named_scope
         c = self.config
         t = tokens.shape[0]
         page = state.page
         npages = state.npages
-        x = params["embed"][tokens].astype(c.dtype)          # (T, H)
-        valid = token_pos >= 0
-        pos_c = jnp.maximum(token_pos, 0)
-        local_page = state.block_table[
-            jnp.clip(token_rows, 0, state.slots - 1),
-            jnp.clip(pos_c // page, 0, state.pages_per_seq - 1),
-        ]
-        # padding tokens (and unallocated -1 table entries) scatter out
-        # of pool — JAX OOB-scatter drops them
-        pool_idx = jnp.where(
-            valid & (local_page >= 0), local_page, npages
-        )
-        off = pos_c % page
-        heads = jnp.arange(c.n_kv_heads)
-        pi = pool_idx[:, None]
-        hi = heads[None, :]
-        oi = off[:, None]
+        with scope("embed"):
+            x = params["embed"][tokens].astype(c.dtype)      # (T, H)
+        with scope("kv_append"):
+            valid = token_pos >= 0
+            pos_c = jnp.maximum(token_pos, 0)
+            local_page = state.block_table[
+                jnp.clip(token_rows, 0, state.slots - 1),
+                jnp.clip(pos_c // page, 0, state.pages_per_seq - 1),
+            ]
+            # padding tokens (and unallocated -1 table entries) scatter
+            # out of pool — JAX OOB-scatter drops them
+            pool_idx = jnp.where(
+                valid & (local_page >= 0), local_page, npages
+            )
+            off = pos_c % page
+            heads = jnp.arange(c.n_kv_heads)
+            pi = pool_idx[:, None]
+            hi = heads[None, :]
+            oi = off[:, None]
+            if self.tp == 1:
+                # heads unsharded: append as ONE-index row scatters over
+                # the pool viewed as (npages·Hkv·page, D) rows. XLA
+                # flattens the three-index scatter to exactly this
+                # anyway, but the scatter its pass creates drops the
+                # operation's metadata (the append showed in a profile
+                # with no op_name: a third of the step, nameless); the
+                # same flattening done here compiles to the same two
+                # in-place fusions and keeps ``kv_append`` on them. An
+                # out-of-pool page still lands past the last row and is
+                # dropped.
+                rows = ((pi * c.n_kv_heads + hi) * page + oi).reshape(-1)
+                kv_shape = (t * c.n_kv_heads, c.head_dim)
+
+                def append(pool, new):
+                    flat = pool.reshape(-1, *pool.shape[3:])
+                    return flat.at[rows].set(new).reshape(pool.shape)
+            else:
+                # head-sharded pools keep the three-index form: their
+                # rows do not merge into one sharded dimension
+                kv_shape = (t, c.n_kv_heads, c.head_dim)
+
+                def append(pool, new):
+                    return pool.at[pi, hi, oi].set(new)
 
         new_layers = []
         new_states = None if moe_state is None else list(moe_state)
@@ -1636,100 +1668,107 @@ class Transformer:
         for li, (blk, (kp, vp)) in enumerate(
             zip(params["blocks"], state.layers)
         ):
-            xn = self._rmsnorm(x, blk["norm_attn"])
-            qkv = self._dmm(xn, blk["wqkv"], shard=qkv_sh)   # (T, qkv)
-            q, k, v = jnp.split(
-                qkv, [c.q_dim, c.q_dim + c.kv_dim], axis=-1
-            )
-            k = k.reshape(t, c.n_kv_heads, c.head_dim)
-            v = v.reshape(t, c.n_kv_heads, c.head_dim)
-            if isinstance(kp, dict):
-                from triton_distributed_tpu.kernels.flash_decode import (
-                    quantize_kv,
+            with scope("attn_proj"):
+                xn = self._rmsnorm(x, blk["norm_attn"])
+                qkv = self._dmm(xn, blk["wqkv"], shard=qkv_sh)  # (T, qkv)
+                q, k, v = jnp.split(
+                    qkv, [c.q_dim, c.q_dim + c.kv_dim], axis=-1
                 )
+                k = k.reshape(kv_shape)
+                v = v.reshape(kv_shape)
+            with scope("kv_append"):
+                if isinstance(kp, dict):
+                    from triton_distributed_tpu.kernels.flash_decode \
+                        import quantize_kv
 
-                kq8, ks8 = quantize_kv(k)
-                vq8, vs8 = quantize_kv(v)
-                kp = {
-                    "q": kp["q"].at[pi, hi, oi].set(kq8),
-                    "scale": kp["scale"].at[pi, hi, oi].set(ks8),
-                }
-                vp = {
-                    "q": vp["q"].at[pi, hi, oi].set(vq8),
-                    "scale": vp["scale"].at[pi, hi, oi].set(vs8),
-                }
-            else:
-                kp = kp.at[pi, hi, oi].set(k.astype(kp.dtype))
-                vp = vp.at[pi, hi, oi].set(v.astype(vp.dtype))
-            kp = jax.tree.map(
-                lambda a: jax.lax.with_sharding_constraint(
-                    a, self._serving_pool_sharding
-                ), kp,
-            )
-            vp = jax.tree.map(
-                lambda a: jax.lax.with_sharding_constraint(
-                    a, self._serving_pool_sharding
-                ), vp,
-            )
+                    kq8, ks8 = quantize_kv(k)
+                    vq8, vs8 = quantize_kv(v)
+                    kp = {"q": append(kp["q"], kq8),
+                          "scale": append(kp["scale"], ks8)}
+                    vp = {"q": append(vp["q"], vq8),
+                          "scale": append(vp["scale"], vs8)}
+                else:
+                    kp = append(kp, k.astype(kp.dtype))
+                    vp = append(vp, v.astype(vp.dtype))
+                kp = jax.tree.map(
+                    lambda a: jax.lax.with_sharding_constraint(
+                        a, self._serving_pool_sharding
+                    ), kp,
+                )
+                vp = jax.tree.map(
+                    lambda a: jax.lax.with_sharding_constraint(
+                        a, self._serving_pool_sharding
+                    ), vp,
+                )
             new_layers.append((kp, vp))
-            qp = pack_gqa_rows(
-                q.reshape(t, c.n_heads, c.head_dim), c.n_kv_heads
-            )
-            if state.cp > 1:
-                o = self._cp_ragged_attn(
+            with scope("attn"):
+                qp = pack_gqa_rows(
+                    q.reshape(t, c.n_heads, c.head_dim), c.n_kv_heads
+                )
+                attn = (self._cp_ragged_attn if state.cp > 1
+                        else self._ragged_attn)
+                o = attn(
                     qp, kp, vp, state.replace(layers=()), q_lens,
                     q_starts, block_q, use_pallas, n_bufs, topologies,
                 )
-            else:
-                o = self._ragged_attn(
-                    qp, kp, vp, state.replace(layers=()), q_lens,
-                    q_starts, block_q, use_pallas, n_bufs, topologies,
-                )
-            o = unpack_gqa_rows(o, c.n_heads).reshape(t, c.q_dim)
-            x = x + self._dmm(o.astype(c.dtype), blk["wo"], shard=wo_sh)
-            xn = self._rmsnorm(x, blk["norm_mlp"])
+                o = unpack_gqa_rows(o, c.n_heads).reshape(t, c.q_dim)
+            with scope("attn_proj"):
+                x = x + self._dmm(o.astype(c.dtype), blk["wo"],
+                                  shard=wo_sh)
             if "up" in blk:
-                h = jax.nn.silu(self._dmm(xn, blk["up"], shard="col"))
-                x = x + self._dmm(h, blk["down"], shard="row")
+                with scope("dense_ffn"):
+                    xn = self._rmsnorm(x, blk["norm_mlp"])
+                    h = jax.nn.silu(
+                        self._dmm(xn, blk["up"], shard="col"))
+                    x = x + self._dmm(h, blk["down"], shard="row")
             elif c.moe == "ep":
+                with scope("moe_route"):
+                    xn = self._rmsnorm(x, blk["norm_mlp"])
                 st = None if moe_state is None else moe_state[li]
                 y, st = self._decode_moe_ep(blk, xn, st)
-                x = x + y.astype(x.dtype)
+                with scope("moe_combine"):
+                    x = x + y.astype(x.dtype)
                 if new_states is not None:
                     new_states[li] = st
             else:
-                logits_r = xn.astype(jnp.float32) @ blk["router"]
-                w, ids = mu.select_experts(logits_r, c.topk)
-                y = jnp.zeros_like(xn, dtype=jnp.float32)
-                for tt in range(c.topk):
-                    hh = jax.nn.silu(jnp.einsum(
-                        "bh,bhf->bf", xn,
-                        blk["moe_up"][ids[:, tt]].astype(c.dtype),
-                    ))
-                    y += w[:, tt:tt + 1] * jnp.einsum(
-                        "bf,bfh->bh", hh,
-                        blk["moe_down"][ids[:, tt]].astype(c.dtype),
-                    ).astype(jnp.float32)
-                x = x + y.astype(x.dtype)
-        x = self._rmsnorm(x, params["norm_f"])
-        if all_logits:
-            # logits at EVERY packed position — the speculative verify
-            # pass needs the next-token distribution after each draft
-            # token, not just each slot's frontier. Per-token matmul
-            # rows are independent, so logits[q_starts[s]+j] is
-            # bit-identical to what a non-speculative step would have
-            # produced at that sequence position.
-            x_last = x                                       # (T, H)
-        else:
-            last_idx = jnp.clip(q_starts + q_lens - 1, 0, t - 1)
-            x_last = x[last_idx]                             # (slots, H)
-        if isinstance(params["lm_head"], dict):
-            logits = self._dmm(
-                x_last, params["lm_head"], out_dtype=jnp.float32,
-                act_quant=False,
-            )
-        else:
-            logits = x_last.astype(jnp.float32) @ params["lm_head"]
+                # the non-EP expert branch is ``moe_gemm`` whole: router,
+                # top-k and the gathered expert weights in one einsum each
+                with scope("moe_gemm"):
+                    xn = self._rmsnorm(x, blk["norm_mlp"])
+                    logits_r = xn.astype(jnp.float32) @ blk["router"]
+                    w, ids = mu.select_experts(logits_r, c.topk)
+                    y = jnp.zeros_like(xn, dtype=jnp.float32)
+                    for tt in range(c.topk):
+                        hh = jax.nn.silu(jnp.einsum(
+                            "bh,bhf->bf", xn,
+                            blk["moe_up"][ids[:, tt]].astype(c.dtype),
+                        ))
+                        y += w[:, tt:tt + 1] * jnp.einsum(
+                            "bf,bfh->bh", hh,
+                            blk["moe_down"][ids[:, tt]].astype(c.dtype),
+                        ).astype(jnp.float32)
+                    x = x + y.astype(x.dtype)
+        with scope("lm_head"):
+            x = self._rmsnorm(x, params["norm_f"])
+            if all_logits:
+                # logits at EVERY packed position — the speculative
+                # verify pass needs the next-token distribution after
+                # each draft token, not just each slot's frontier.
+                # Per-token matmul rows are independent, so
+                # logits[q_starts[s]+j] is bit-identical to what a
+                # non-speculative step would have produced at that
+                # sequence position.
+                x_last = x                                   # (T, H)
+            else:
+                last_idx = jnp.clip(q_starts + q_lens - 1, 0, t - 1)
+                x_last = x[last_idx]                         # (slots, H)
+            if isinstance(params["lm_head"], dict):
+                logits = self._dmm(
+                    x_last, params["lm_head"], out_dtype=jnp.float32,
+                    act_quant=False,
+                )
+            else:
+                logits = x_last.astype(jnp.float32) @ params["lm_head"]
         new_state = state.replace(layers=tuple(new_layers))
         if moe_state is None:
             return logits, new_state

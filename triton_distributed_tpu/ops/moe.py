@@ -469,14 +469,19 @@ def _ep_assignments_device(ctx: EPMoEContext, x, flat_e, w_flat, out_rows,
     updated workspace dict when ``state`` is given (the barrier-free LL
     transport; fused only).
     """
+    # device scopes (``jax.named_scope``, one component of each
+    # operation's ``op_name``; trace-time only): moe_route, moe_dispatch,
+    # moe_gemm, moe_combine — what a profile of any step that runs this
+    # block (serving, decode, training) is read by
     total = flat_e.shape[0]
     new_state = None
-    order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
-    valid_a = flat_e < ctx.num_experts
-    n_valid = jnp.sum(valid_a.astype(jnp.int32))
-    splits = jnp.zeros((ctx.num_experts,), jnp.int32).at[
-        jnp.clip(flat_e, 0, ctx.num_experts - 1)
-    ].add(valid_a.astype(jnp.int32))
+    with jax.named_scope("moe_route"):
+        order = jnp.argsort(flat_e, stable=True).astype(jnp.int32)
+        valid_a = flat_e < ctx.num_experts
+        n_valid = jnp.sum(valid_a.astype(jnp.int32))
+        splits = jnp.zeros((ctx.num_experts,), jnp.int32).at[
+            jnp.clip(flat_e, 0, ctx.num_experts - 1)
+        ].add(valid_a.astype(jnp.int32))
 
     transport = ctx.transport
     if transport == "fused" and ctx.max_m < total:
@@ -505,74 +510,84 @@ def _ep_assignments_device(ctx: EPMoEContext, x, flat_e, w_flat, out_rows,
         from triton_distributed_tpu.kernels import moe_dispatch as md
 
         a2a = ctx.a2a
-        # single staging pass: gather straight from x into the aligned
-        # per-peer segments (no x_sorted materialization, no slot
-        # inflation — the reference's on-device range computation)
-        counts, offs, offs_al, sendk = md.send_plan(a2a, splits)
-        peer, dest = md.assignment_dest(a2a, flat_e[order], offs, offs_al)
-        payload, scales = md.stage_aligned(
-            a2a, x, order // ctx.topk, dest, n_valid
-        )
-        meta = md.meta_payload(a2a, splits, scales, offs_al, sendk)
-        if state is None:
-            recv_tok, recv_meta = md.dispatch_device(
-                a2a, payload, offs_al, sendk, meta
+        with jax.named_scope("moe_dispatch"):
+            # single staging pass: gather straight from x into the
+            # aligned per-peer segments (no x_sorted materialization, no
+            # slot inflation — the reference's on-device range
+            # computation)
+            counts, offs, offs_al, sendk = md.send_plan(a2a, splits)
+            peer, dest = md.assignment_dest(
+                a2a, flat_e[order], offs, offs_al)
+            payload, scales = md.stage_aligned(
+                a2a, x, order // ctx.topk, dest, n_valid
             )
-        else:
-            dtok, dmeta = md.dispatch_ll_device(
-                a2a, payload, offs_al, sendk, meta,
-                state["parity"], state["disp_tok"], state["disp_meta"],
-                instance,
-            )
-            recv_tok, recv_meta = md.ll_window(a2a, dtok, dmeta,
-                                               state["parity"])
-        toks, rspl = md.recv_view(a2a, recv_tok, recv_meta)
+            meta = md.meta_payload(a2a, splits, scales, offs_al, sendk)
+            if state is None:
+                recv_tok, recv_meta = md.dispatch_device(
+                    a2a, payload, offs_al, sendk, meta
+                )
+            else:
+                dtok, dmeta = md.dispatch_ll_device(
+                    a2a, payload, offs_al, sendk, meta,
+                    state["parity"], state["disp_tok"],
+                    state["disp_meta"], instance,
+                )
+                recv_tok, recv_meta = md.ll_window(a2a, dtok, dmeta,
+                                                   state["parity"])
+            toks, rspl = md.recv_view(a2a, recv_tok, recv_meta)
 
-        slot_m = md.slot_pad(a2a)
-        eid, valid = _slot_tables(ctx, rspl, slot_m)
-        y = _expert_mlp(
-            ctx, toks.reshape(ctx.n * slot_m, ctx.hidden), eid, valid,
-            w_up, w_down,
-        )
-        # return leg: slot-regular — the same chunked kernel with static
-        # slot offsets carries back exactly the received row ranges
-        y_tok, y_meta = md.stage_return(
-            a2a, y.reshape(ctx.n, slot_m, ctx.hidden)
-        )
-        retk = -(-jnp.sum(rspl, axis=1) // md.chunk_rows(a2a))
-        if state is None:
-            comb_tok, comb_meta = md.combine_device(
-                a2a, y_tok, y_meta, retk, sendk
+            slot_m = md.slot_pad(a2a)
+            eid, valid = _slot_tables(ctx, rspl, slot_m)
+        with jax.named_scope("moe_gemm"):
+            y = _expert_mlp(
+                ctx, toks.reshape(ctx.n * slot_m, ctx.hidden), eid, valid,
+                w_up, w_down,
             )
-        else:
-            ctok, cmeta = md.combine_ll_device(
-                a2a, y_tok, y_meta, retk, sendk,
-                state["parity"], state["comb_tok"], state["comb_meta"],
-                instance + 1,
+        with jax.named_scope("moe_combine"):
+            # return leg: slot-regular — the same chunked kernel with
+            # static slot offsets carries back exactly the received row
+            # ranges
+            y_tok, y_meta = md.stage_return(
+                a2a, y.reshape(ctx.n, slot_m, ctx.hidden)
             )
-            comb_tok, comb_meta = md.ll_window(a2a, ctok, cmeta,
-                                               state["parity"])
-            new_state = {
-                "parity": (state["parity"] + 1) % 2,
-                "disp_tok": dtok, "disp_meta": dmeta,
-                "comb_tok": ctok, "comb_meta": cmeta,
-            }
-        y_sorted = md.combine_view(
-            a2a, comb_tok, comb_meta, peer, dest, offs_al, n_valid
-        )
+            retk = -(-jnp.sum(rspl, axis=1) // md.chunk_rows(a2a))
+            if state is None:
+                comb_tok, comb_meta = md.combine_device(
+                    a2a, y_tok, y_meta, retk, sendk
+                )
+            else:
+                ctok, cmeta = md.combine_ll_device(
+                    a2a, y_tok, y_meta, retk, sendk,
+                    state["parity"], state["comb_tok"],
+                    state["comb_meta"], instance + 1,
+                )
+                comb_tok, comb_meta = md.ll_window(a2a, ctok, cmeta,
+                                                   state["parity"])
+                new_state = {
+                    "parity": (state["parity"] + 1) % 2,
+                    "disp_tok": dtok, "disp_meta": dmeta,
+                    "comb_tok": ctok, "comb_meta": cmeta,
+                }
+            y_sorted = md.combine_view(
+                a2a, comb_tok, comb_meta, peer, dest, offs_al, n_valid
+            )
     else:
-        x_sorted = x[order // ctx.topk].astype(ctx.dtype)
-        # dispatch: tokens to the ranks owning their experts
-        toks, rspl = _dispatch(ctx, x_sorted, splits)  # (n,max_m,H),(n,epr)
-        eid, valid = _slot_tables(ctx, rspl, ctx.max_m)
-        y = _expert_mlp(
-            ctx, toks.reshape(ctx.n * ctx.max_m, ctx.hidden), eid, valid,
-            w_up, w_down,
-        )
-        # combine: processed tokens back to their owners
-        y_sorted = _combine(
-            ctx, y.reshape(ctx.n, ctx.max_m, ctx.hidden), splits, total
-        )
+        with jax.named_scope("moe_dispatch"):
+            x_sorted = x[order // ctx.topk].astype(ctx.dtype)
+            # dispatch: tokens to the ranks owning their experts
+            toks, rspl = _dispatch(ctx, x_sorted, splits)
+            eid, valid = _slot_tables(ctx, rspl, ctx.max_m)
+        with jax.named_scope("moe_gemm"):
+            y = _expert_mlp(
+                ctx, toks.reshape(ctx.n * ctx.max_m, ctx.hidden), eid,
+                valid, w_up, w_down,
+            )
+        with jax.named_scope("moe_combine"):
+            # combine: processed tokens back to their owners
+            y_sorted = _combine(
+                ctx, y.reshape(ctx.n, ctx.max_m, ctx.hidden), splits,
+                total
+            )
 
     # back to assignment order via inverse-permutation GATHER (scatter
     # only the (T,) iota; total-coverage since ``order`` is a
@@ -580,20 +595,22 @@ def _ep_assignments_device(ctx: EPMoEContext, x, flat_e, w_flat, out_rows,
     # assignment t belongs to token t//topk, so the (T, H) array IS
     # (out_rows, topk, H) row-major. One gather + one reduction pass
     # instead of a full-width f32 select pass + an f32 scatter-add.
-    inv_order = jnp.zeros((total,), jnp.int32).at[order].set(
-        jnp.arange(total, dtype=jnp.int32)
-    )
-    y_orig = y_sorted[inv_order]                   # (T, H) assignment order
-    # masked assignments carry weight exactly 0, but their y rows may be
-    # garbage (untransported window slack) — zero them before the MAC so
-    # a stray inf/nan cannot poison the sum. Under debug_checksum the
-    # poison NaNs ride rows with nonzero weight, so they stay loud.
-    y_use = jnp.where(
-        (w_flat != 0)[:, None],
-        y_orig.astype(jnp.float32) * w_flat[:, None],
-        0.0,
-    )
-    out = y_use.reshape(out_rows, ctx.topk, ctx.hidden).sum(axis=1)
+    with jax.named_scope("moe_combine"):
+        inv_order = jnp.zeros((total,), jnp.int32).at[order].set(
+            jnp.arange(total, dtype=jnp.int32)
+        )
+        y_orig = y_sorted[inv_order]               # (T, H) assignment order
+        # masked assignments carry weight exactly 0, but their y rows
+        # may be garbage (untransported window slack) — zero them before
+        # the MAC so a stray inf/nan cannot poison the sum. Under
+        # debug_checksum the poison NaNs ride rows with nonzero weight,
+        # so they stay loud.
+        y_use = jnp.where(
+            (w_flat != 0)[:, None],
+            y_orig.astype(jnp.float32) * w_flat[:, None],
+            0.0,
+        )
+        out = y_use.reshape(out_rows, ctx.topk, ctx.hidden).sum(axis=1)
     return (out, new_state) if state is not None else out
 
 
@@ -634,15 +651,20 @@ def _ep_moe_hier_device(x, logits, w_up, w_down, ctx: EPMoEContext):
     where duplicate bytes hurt most)."""
     m = x.shape[0]
     dcn, epl, epr = ctx.dcn, ctx.epl, ctx.experts_per_rank
-    weights, ids = mu.select_experts(logits, ctx.topk)
-    ids = ids.astype(jnp.int32)
+    with jax.named_scope("moe_route"):
+        weights, ids = mu.select_experts(logits, ctx.topk)
+        ids = ids.astype(jnp.int32)
 
-    tok_slot, ids_slot, w_slot, hit, _ = _rail_stage(ctx, x, ids, weights)
-
-    # DCN rail (same-local-rank by mesh construction): unique tokens out
-    rtok = jax.lax.all_to_all(tok_slot, ctx.dcn_axis, 0, 0, tiled=False)
-    rids = jax.lax.all_to_all(ids_slot, ctx.dcn_axis, 0, 0, tiled=False)
-    rw = jax.lax.all_to_all(w_slot, ctx.dcn_axis, 0, 0, tiled=False)
+    with jax.named_scope("moe_dispatch"):
+        tok_slot, ids_slot, w_slot, hit, _ = _rail_stage(
+            ctx, x, ids, weights)
+        # DCN rail (same-local-rank by mesh construction): unique tokens
+        # out
+        rtok = jax.lax.all_to_all(
+            tok_slot, ctx.dcn_axis, 0, 0, tiled=False)
+        rids = jax.lax.all_to_all(
+            ids_slot, ctx.dcn_axis, 0, 0, tiled=False)
+        rw = jax.lax.all_to_all(w_slot, ctx.dcn_axis, 0, 0, tiled=False)
 
     # intra-slice flat EP over the railed set: keep only assignments
     # whose expert lives in MY slice, sentinel the rest
@@ -672,19 +694,20 @@ def _ep_moe_hier_device(x, logits, w_up, w_down, ctx: EPMoEContext):
     # rail back: ONE weighted partial row per unique (token, slice) pair
     # — in ctx.dtype, not the f32 accumulator (DCN is exactly the link
     # where bytes hurt; the cross-slice sum still runs in f32 below)
-    back = jax.lax.all_to_all(
-        part.astype(ctx.dtype).reshape(dcn, m, ctx.hidden),
-        ctx.dcn_axis, 0, 0, tiled=False,
-    )
-    # source side: sum each token's per-slice partials
-    pos = jnp.cumsum(hit, axis=0) - 1                    # (m, dcn)
-    safe_pos = jnp.clip(pos, 0, m - 1)
-    d_idx = jnp.arange(dcn)
-    gathered = back[d_idx[None, :], safe_pos]            # (m, dcn, H)
-    out = jnp.sum(
-        jnp.where(hit[..., None], gathered.astype(jnp.float32), 0.0),
-        axis=1,
-    )
+    with jax.named_scope("moe_combine"):
+        back = jax.lax.all_to_all(
+            part.astype(ctx.dtype).reshape(dcn, m, ctx.hidden),
+            ctx.dcn_axis, 0, 0, tiled=False,
+        )
+        # source side: sum each token's per-slice partials
+        pos = jnp.cumsum(hit, axis=0) - 1                # (m, dcn)
+        safe_pos = jnp.clip(pos, 0, m - 1)
+        d_idx = jnp.arange(dcn)
+        gathered = back[d_idx[None, :], safe_pos]        # (m, dcn, H)
+        out = jnp.sum(
+            jnp.where(hit[..., None], gathered.astype(jnp.float32), 0.0),
+            axis=1,
+        )
     return out.astype(x.dtype)
 
 
@@ -710,7 +733,8 @@ def ep_moe_device(x, logits, w_up, w_down, ctx: EPMoEContext, state=None,
         )
     if ctx.dcn_axis is not None:
         return _ep_moe_hier_device(x, logits, w_up, w_down, ctx)
-    weights, ids = mu.select_experts(logits, ctx.topk)
+    with jax.named_scope("moe_route"):
+        weights, ids = mu.select_experts(logits, ctx.topk)
     res = _ep_assignments_device(
         ctx, x, ids.reshape(-1).astype(jnp.int32),
         weights.reshape(-1).astype(jnp.float32), x.shape[0], w_up, w_down,

@@ -43,6 +43,7 @@ import time
 from collections import deque
 from dataclasses import dataclass, field
 
+import jax
 import numpy as np
 
 
@@ -145,6 +146,15 @@ class Request:
     # DisaggregatedEngine owns the flag; the colocated engine never
     # sets it.
     parked: bool = False
+    # perf_counter stamps, None until the event: handed to
+    # ``ServingEngine.submit``; FIRST given a slot by
+    # ``ProtocolOps.admit`` (a re-admission after an eviction is not a
+    # second wait; a row that enters by ``reserve_shipped`` has none);
+    # end of the advance loop of the first step after which
+    # ``generated`` is non-empty. ``rid`` is what they share.
+    t_submit: float | None = None
+    t_admit: float | None = None
+    t_first: float | None = None
 
     @property
     def seq(self) -> np.ndarray:
@@ -198,9 +208,54 @@ class EngineConfig:
     prefix_share: bool = False
 
 
+#: the phases of one ``ServingEngine.step``, in order; each is the host
+#: span ``engine.<phase>`` and the per-step list ``EngineStats.<phase>_times``
+PHASES = ("admit", "assemble", "upload", "dispatch", "fetch", "advance")
+
+
+class _Phase:
+    """One phase of a step, measured twice: as the host span
+    ``engine.<phase>`` (a ``jax.profiler.TraceAnnotation``, so it lies on
+    the device trace's clock; ``step=`` is what the spans of one step
+    share; a no-op while no profiler runs) and as a ``perf_counter``
+    pair added to ``acc[phase]``, which is what remains untraced."""
+
+    __slots__ = ("ann", "acc", "phase", "t0")
+
+    def __init__(self, acc: dict, phase: str, step: int):
+        self.ann = jax.profiler.TraceAnnotation(f"engine.{phase}", step=step)
+        self.acc, self.phase = acc, phase
+
+    def __enter__(self):
+        self.ann.__enter__()
+        self.t0 = time.perf_counter()
+
+    def __exit__(self, *exc):
+        self.acc[self.phase] += time.perf_counter() - self.t0
+        self.ann.__exit__(*exc)
+
+
 @dataclass
 class EngineStats:
+    # seconds of host clock per device step: uploads + dispatch + fetch
+    # (``upload_times + dispatch_times + fetch_times`` of that step)
     step_times: list = field(default_factory=list)
+    # the six phases of ``ServingEngine.step`` (``PHASES``), seconds of
+    # host clock, one entry per step that ran the device (an empty step
+    # appends to none); a degraded step's re-run is summed into its
+    # entry. The six entries of a step sum to its wall time in step().
+    admit_times: list = field(default_factory=list)
+    assemble_times: list = field(default_factory=list)
+    upload_times: list = field(default_factory=list)
+    dispatch_times: list = field(default_factory=list)
+    fetch_times: list = field(default_factory=list)     # wait + D2H
+    advance_times: list = field(default_factory=list)
+    # request stamps summed where they are taken (``Request.t_*``):
+    # submit -> first slot, and first slot -> first token
+    queue_wait_s: float = 0.0
+    admissions: int = 0
+    first_token_s: float = 0.0
+    first_tokens: int = 0
     step_tokens: list = field(default_factory=list)
     step_generated: list = field(default_factory=list)
     completed: int = 0
@@ -435,6 +490,8 @@ class ServingEngine:
         self.waiting: deque = deque()      # arrived, not admitted
         self.stats = EngineStats()
         self.step_count = 0
+        # seconds of the running step inside each phase (``_Phase``)
+        self._phase_s = dict.fromkeys(PHASES, 0.0)
         # --- multi-tenancy (all defaults reproduce the single-tenant
         # engine exactly: one implicit tenant at full shares, rank 0,
         # so preemption never finds a strictly-lower victim) ---
@@ -533,6 +590,7 @@ class ServingEngine:
     # ------------------------------------------------------------ requests
 
     def submit(self, req: Request) -> None:
+        req.t_submit = time.perf_counter()
         self.pending.append(req)
 
     def submit_trace(self, trace) -> None:
@@ -860,23 +918,38 @@ class ServingEngine:
             self.moe_state, block_q, self.use_pallas, self._n_bufs,
         )
 
+    def _phase(self, phase: str) -> _Phase:
+        return _Phase(self._phase_s, phase, self.step_count)
+
     def _run_device(self, arrays, block_q):
         from triton_distributed_tpu.lang.launch import maybe_instrument
 
-        # host-mode heartbeat around the jitted step: an armed watchdog
-        # sees a wedged serving step (site "serving_step"), and a
-        # fault-plan Stall at that site gates here
-        step_fn = maybe_instrument(
-            self._step_jit(), axis=None, site="serving_step",
-            collective_id=("serving_step", self.health_peer), n=1,
-            step=self.step_count,
-        )
-        out = step_fn(*self._step_args(arrays, block_q))
-        if self.moe_state is None:
-            logits, self.state = out
-        else:
-            logits, self.state, self.moe_state = out
-        return np.asarray(logits)          # host fetch = the fence
+        with self._phase("upload"):
+            args = self._step_args(arrays, block_q)
+        with self._phase("dispatch"):
+            # host-mode heartbeat around the jitted step: an armed
+            # watchdog sees a wedged serving step (site "serving_step"),
+            # and a fault-plan Stall at that site gates here
+            step_fn = maybe_instrument(
+                self._step_jit(), axis=None, site="serving_step",
+                collective_id=("serving_step", self.health_peer), n=1,
+                step=self.step_count,
+            )
+            out = step_fn(*args)
+            if self.moe_state is None:
+                logits, self.state = out
+            else:
+                logits, self.state, self.moe_state = out
+        with self._phase("fetch"):
+            # the host fetch is the fence: the wait for the step program,
+            # the copy down and the delinearize, deliberately one span (a
+            # block_until_ready before it would put a host wake-up on
+            # the critical path untraced)
+            host_logits = np.asarray(logits)
+            # the uploads and the device logits are freed here, inside
+            # the span, not on return (0.1-0.2 ms of a step's idle gap)
+            del args, out, logits
+            return host_logits
 
     def step(self) -> dict:
         """One engine step: admit → assemble → device step → advance
@@ -885,36 +958,40 @@ class ServingEngine:
             auto_block_q,
         )
 
-        self._admit()
-        (tokens, token_rows, token_pos, q_starts, q_lens, kv_dev,
-         topo, batched, takes) = self._assemble()
-        report = {"step": self.step_count, "batched": len(batched),
-                  "tokens": int(q_lens.sum())}
-        if not batched:
-            self.step_count += 1
-            return report
-        block_q = auto_block_q(int(q_lens.max()), self._g)
-        # tuned floor (grid schedule): never past the parking-zone cap
-        block_q = min(self._block_q_cap,
-                      max(block_q, self._block_q_floor))
-        from triton_distributed_tpu.runtime.health import PeerState
+        phase_s = self._phase_s
+        for k in phase_s:
+            phase_s[k] = 0.0
+        with self._phase("admit"):
+            self._admit()
+        with self._phase("assemble"):
+            (tokens, token_rows, token_pos, q_starts, q_lens, kv_dev,
+             topo, batched, takes) = self._assemble()
+            report = {"step": self.step_count, "batched": len(batched),
+                      "tokens": int(q_lens.sum())}
+            if not batched:
+                self.step_count += 1
+                return report
+            block_q = auto_block_q(int(q_lens.max()), self._g)
+            # tuned floor (grid schedule): never past the parking-zone cap
+            block_q = min(self._block_q_cap,
+                          max(block_q, self._block_q_floor))
+            from triton_distributed_tpu.runtime.health import PeerState
 
-        peer = self.health_peer
-        if self.use_pallas \
-                and self.health.state(peer) is PeerState.UNHEALTHY:
-            # the ledger condemned the fused path out-of-band (a shared
-            # ledger's other role, a watchdog trip): demote before
-            # launching
-            self.use_pallas = False
-            self.stats.degraded = True
-        # PROBATION: on the seeded schedule, try the fused path again
-        probing = (not self.use_pallas
-                   and self.health.probe_due(peer, self.step_count))
-        if probing:
-            self.use_pallas = True
-        t0 = time.perf_counter()
-        arrays = (tokens, token_rows, token_pos, q_starts, q_lens, kv_dev,
-                  topo)
+            peer = self.health_peer
+            if self.use_pallas \
+                    and self.health.state(peer) is PeerState.UNHEALTHY:
+                # the ledger condemned the fused path out-of-band (a
+                # shared ledger's other role, a watchdog trip): demote
+                # before launching
+                self.use_pallas = False
+                self.stats.degraded = True
+            # PROBATION: on the seeded schedule, try the fused path again
+            probing = (not self.use_pallas
+                       and self.health.probe_due(peer, self.step_count))
+            if probing:
+                self.use_pallas = True
+            arrays = (tokens, token_rows, token_pos, q_starts, q_lens,
+                      kv_dev, topo)
         try:
             logits = self._run_device(arrays, block_q)
         except Exception as e:
@@ -957,28 +1034,37 @@ class ServingEngine:
                     self.use_pallas = True
                     self.stats.degraded = False
                     self.stats.repromotions += 1
-        dt = time.perf_counter() - t0
-        gen_this_step = 0
-        prefill_this_step = 0
-        for s in sorted(batched):
-            req = self.slot_req[s]
-            emitted, prefill_toks = self._advance_row(
-                s, req, takes[s], logits, q_starts, q_lens)
-            gen_this_step += emitted
-            prefill_this_step += prefill_toks
-        self.stats.step_times.append(dt)
-        self.stats.step_tokens.append(int(q_lens.sum()))
-        self.stats.step_generated.append(gen_this_step)
-        self.stats.note_shape(
-            self._grid_key, dt * 1e3,
-            self.pool.npages - self.pool.available,
-        )
-        self.stats.prefill_tokens += prefill_this_step
-        report.update(
-            ms=round(dt * 1e3, 3), generated=gen_this_step,
-            free_pages=self.pool.available,
-            waiting=len(self.waiting) + len(self.pending),
-        )
+        stats = self.stats
+        dt = phase_s["upload"] + phase_s["dispatch"] + phase_s["fetch"]
+        with self._phase("advance"):
+            gen_this_step = 0
+            prefill_this_step = 0
+            for s in sorted(batched):
+                req = self.slot_req[s]
+                emitted, prefill_toks = self._advance_row(
+                    s, req, takes[s], logits, q_starts, q_lens)
+                gen_this_step += emitted
+                prefill_this_step += prefill_toks
+                if req.t_first is None and req.generated:
+                    req.t_first = time.perf_counter()
+                    if req.t_admit is not None:
+                        stats.first_token_s += req.t_first - req.t_admit
+                        stats.first_tokens += 1
+            stats.step_times.append(dt)
+            stats.step_tokens.append(int(q_lens.sum()))
+            stats.step_generated.append(gen_this_step)
+            stats.note_shape(
+                self._grid_key, dt * 1e3,
+                self.pool.npages - self.pool.available,
+            )
+            stats.prefill_tokens += prefill_this_step
+            report.update(
+                ms=round(dt * 1e3, 3), generated=gen_this_step,
+                free_pages=self.pool.available,
+                waiting=len(self.waiting) + len(self.pending),
+            )
+        for k, v in phase_s.items():
+            getattr(stats, k + "_times").append(v)
         self.step_count += 1
         return report
 

@@ -28,6 +28,7 @@ the refactor.
 
 from __future__ import annotations
 
+import time
 from collections import deque
 
 
@@ -189,6 +190,14 @@ class ProtocolOps:
             s = free[0]
             req.slot = s
             eng.slot_req[s] = req
+            if req.t_admit is None:
+                # the FIRST slot only: a re-admission after an eviction
+                # is not a second queue wait. (A request handed over by
+                # a fleet replica was never ``submit``-ted: no wait.)
+                req.t_admit = time.perf_counter()
+                if req.t_submit is not None:
+                    eng.stats.queue_wait_s += req.t_admit - req.t_submit
+                    eng.stats.admissions += 1
             if len(req.seq) > eng.state.capacity:
                 # cannot ever fit — fail it loudly rather than wedging
                 req.done = True

@@ -42,8 +42,15 @@ def group_profile(log_dir=".profiles", *, enabled: bool = True,
         return
     path = pathlib.Path(log_dir) / f"process-{jax.process_index()}"
     path.mkdir(parents=True, exist_ok=True)
+    # without the profiler's Python tracer: it records every Python call
+    # of the host loop and the device waits while it does (1.4-1.9 ms a
+    # serving step, PERF.md). The program opens its own host spans
+    # (``engine.<phase>``), which need the host tracer only.
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
     with jax.profiler.trace(
-        str(path), create_perfetto_trace=create_perfetto_trace
+        str(path), create_perfetto_trace=create_perfetto_trace,
+        profiler_options=options,
     ):
         yield path
 
