@@ -26,7 +26,8 @@ unless JAX's first device is a TPU, every request completes with finite
 logits, the engine never degraded / re-promoted / swallowed an
 exception, the model resolved the FUSED EP transport with the Pallas
 grouped GEMM, the lowered step carries the ragged kernel's Mosaic
-custom call, and one mixed prefill+decode batch agrees between the
+custom call (and, on one chip, the pool append kernel's: no hidden
+scatter), and one mixed prefill+decode batch agrees between the
 kernel and its XLA twin on the same ServingState (logits, not tokens;
 once as served, once with every activation-side quantization off —
 the sharper instrument, see ``PARITY_VIEWS``).
@@ -209,6 +210,15 @@ def parity(model, params, on_chip: bool, tol: float) -> dict:
                  for ln in eng.lowered.splitlines()),
              "the lowered serving step holds no ragged_paged_attention "
              "tpu_custom_call")
+        # the pool append: by its kernel wherever the heads are
+        # unsharded, and by no kernel (the row scatter) where they are
+        by_kernel = model.kv_append_by_kernel(True)
+        need(any("tpu_custom_call" in ln
+                 and 'kernel_name = "kv_append' in ln
+                 for ln in eng.lowered.splitlines()) == by_kernel,
+             "the lowered serving step's pool append is not the path "
+             f"tp={model.tp} takes (kv_append tpu_custom_call "
+             f"expected: {by_kernel})")
     return {"rows_q_lens": q_lens, "rms_rel_err": rms, "tol": tol,
             "max_abs_err": float(np.abs(got - want).max()),
             "logit_absmax": float(np.abs(want).max())}

@@ -389,6 +389,9 @@ def patched_pallas(rec: ev.Recorder):
         (pltpu, "emit_pipeline", emit_pipeline),
         (pl, "when", when),
         (pl, "delay", lambda cycles: None),
+        # the in-register rotate has no eager rule: numpy's is the same
+        (pltpu, "roll", lambda x, shift, axis, **kw: np.roll(
+            np.asarray(x), _as_int(shift), axis)),
         (pl, "program_id", lambda d: grid_env["ids"][d]),
         (pl, "num_programs", lambda d: grid_env["dims"][d]),
         (jax.lax, "fori_loop", fori_loop),

@@ -402,6 +402,22 @@ def _h_ragged_local(twin, n):
                        "per-rank function, no mesh operand")
 
 
+def _h_kv_append_local(twin, n):
+    """The pool append's twin is a per-rank row scatter over the
+    rank's own pool: rows named land, every other row keeps its bytes,
+    nothing crosses ranks."""
+    del n
+    import jax.numpy as jnp
+
+    pool = jnp.zeros((2, 2, 8, 16), jnp.float32)
+    out = np.asarray(twin(pool, jnp.ones((3, 16), jnp.float32),
+                          jnp.asarray([0, 9, pool[..., 0].size])))
+    if out.shape != pool.shape or out.sum() != 2 * 16:
+        raise ValueError("kv_append local twin dropped or invented rows")
+    return TwinProfile(LOCAL, None, True,
+                       "per-rank function, no mesh operand")
+
+
 #: harness key → runner. Keys are the DEGRADATION_TARGETS dotted paths,
 #: except where one twin serves families of different classes (the
 #: grouped GEMM) — those disambiguate through _twin_key.
@@ -423,6 +439,8 @@ _HARNESSES = {
         _h_cp_decode,
     "triton_distributed_tpu.kernels.ragged_paged_attention."
     "ragged_paged_attention_xla": _h_ragged_local,
+    "triton_distributed_tpu.kernels.kv_append.append_rows_xla":
+        _h_kv_append_local,
 }
 
 #: fallback class table for hosts without n devices (profile marked
@@ -444,6 +462,8 @@ _STATIC_CLASS = {
         (FOLD, "all"),
     "triton_distributed_tpu.kernels.ragged_paged_attention."
     "ragged_paged_attention_xla": (LOCAL, None),
+    "triton_distributed_tpu.kernels.kv_append.append_rows_xla":
+        (LOCAL, None),
 }
 
 

@@ -165,6 +165,10 @@ DEGRADATION_TARGETS = {
         "triton_distributed_tpu.kernels.ragged_paged_attention."
         "ragged_paged_attention_xla",
     "kv_ship.pages": "triton_distributed_tpu.tools.native.xla_kv_ship",
+    # the pool append's XLA twin is the row scatter serving_step keeps
+    # for head-sharded pools and use_pallas=False
+    "kv_append.pages":
+        "triton_distributed_tpu.kernels.kv_append.append_rows_xla",
     "moe_dispatch.a2a": "jax.lax.all_to_all",
     "moe_combine.a2a": "jax.lax.all_to_all",
     # training: both CP schemes degrade onto dense attention (gather KV,
@@ -536,6 +540,30 @@ def _ragged_paged(mesh, n, token):
     build_lint_kernel(token=(token, n))
 
 
+def _kv_append(mesh, n, token):
+    """The pool append (kernels/kv_append.py) is LOCAL like the ragged
+    kernel it feeds: every rank read-modify-writes pages of its own
+    pools."""
+    del mesh
+    from triton_distributed_tpu.kernels.kv_append import build_lint_kernel
+
+    build_lint_kernel(token=(token, n))
+
+
+def _kv_append_in_shapes(n):
+    from triton_distributed_tpu.kernels.kv_append import lint_in_shapes
+
+    del n
+    return [(shape, np.dtype(dtype)) for shape, dtype in lint_in_shapes()]
+
+
+def _kv_append_init(n):
+    from triton_distributed_tpu.kernels.kv_append import lint_units
+
+    del n
+    return {0: np.asarray(lint_units())}
+
+
 def _ragged_in_shapes(n):
     from triton_distributed_tpu.kernels.ragged_paged_attention import (
         LINT_GEOM as g,
@@ -826,6 +854,17 @@ def families() -> dict:
                 kind="local", dst=10,
                 topo={"ref": 4, "kv_lens": 1, "q_lens": 2, "width": 8},
             ),
+        ),
+        KernelFamily(
+            # the serving step's pool append: a LOCAL read-modify-write
+            # of the pages this step's (slot, page) runs name — the
+            # pools are aliased in place and mostly keep their bytes:
+            # own writes only, not full coverage
+            "kv_append.pages", "kv_append", "kv_append_q8",
+            _kv_append,
+            _kv_append_in_shapes,
+            init=_kv_append_init,
+            contract=DeliveryContract(kind="local", dst=9, full=False),
         ),
         KernelFamily(
             # the disaggregated-serving page ship: a PAIRWISE permute —

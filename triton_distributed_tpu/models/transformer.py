@@ -1572,6 +1572,36 @@ class Transformer:
         )
         return out
 
+    def kv_append_by_kernel(self, use_pallas: bool) -> bool:
+        """Does a serving step append by the ``kernels/kv_append``
+        kernel (True) or by its XLA twin, the row scatter? Decided by
+        what is there, no option: a head-sharded pool's rows do not
+        merge into page runs, and ``use_pallas=False`` is the engine's
+        degraded twin (and the CPU references')."""
+        return bool(use_pallas) and self.tp == 1
+
+    @functools.cached_property
+    def _kv_append_kernel(self):
+        """``(units, kp, vp, k_new, v_new) -> (kp, vp)``: one layer's
+        pool append through ``kernels/kv_append`` on every device's own
+        copy of the (head-unsharded) pools — the shard_map the
+        attention layer launches its kernel in, under one jit so that
+        a step's layers trace and lower it once."""
+        from jax.sharding import PartitionSpec as P
+
+        from triton_distributed_tpu.kernels.kv_append import kv_append
+
+        pool, new = P(None, self.tp_axis), P()
+        if self.config.kv_quant is not None:
+            pool, new = ({"q": p, "scale": p} for p in (pool, new))
+        return jax.jit(jax.shard_map(
+            kv_append,
+            mesh=self.mesh,
+            in_specs=(P(), pool, pool, new, new),
+            out_specs=(pool, pool),
+            check_vma=False,
+        ))
+
     def serving_step(self, params, state, tokens, token_rows, token_pos,
                      q_starts, q_lens, topologies=None, moe_state=None, *,
                      block_q: int = 8, use_pallas: bool = True,
@@ -1586,7 +1616,15 @@ class Transformer:
         sequence position (pos < 0 marks padding tokens — their K/V
         writes are dropped); ``q_starts``/``q_lens``: (slots,) per-slot
         spans into the packed array (8-aligned starts, ``q_lens == 0``
-        for slots not in this batch). Returns ``(logits (slots, vocab),
+        for slots not in this batch). THE PACKING CONTRACT, which the
+        pool append relies on: slot ``s``'s tokens are the ONE
+        contiguous span ``[q_starts[s], q_starts[s] + q_lens[s])`` of
+        the packed array, spans do not overlap, and they sit at
+        consecutive sequence positions — ``token_rows == s`` and
+        ``token_pos == token_pos[q_starts[s]] + arange(q_lens[s])`` on
+        the span, ``token_pos < 0`` everywhere else
+        (``ServingEngine._assemble`` packs exactly this, tree-verify
+        rows included). Returns ``(logits (slots, vocab),
         state')`` — logits at each slot's LAST packed token (the
         next-token distribution for rows that finished a chunk at their
         prompt end, garbage for q_lens == 0 slots), plus ``moe_state'``
@@ -1598,8 +1636,12 @@ class Transformer:
         prefix aliasing, and the ``q_lens == 0`` kernel-side row skip
         all ride this operand; None keeps the pre-topology launch.
 
-        Every new K/V token is scattered into the page pools FIRST and
-        attention reads the updated pools (append-then-attend): a
+        Every new K/V token is written into the page pools FIRST and
+        attention reads the updated pools (append-then-attend) — by the
+        ``kernels/kv_append`` kernel, one (slot, page) run at a time,
+        when ``use_pallas`` and the heads are unsharded (``tp == 1``);
+        by its XLA twin, a row scatter, for head-sharded pools and
+        ``use_pallas=False``. Both leave the same bytes. A
         prefill chunk's tokens attend each other causally through the
         pool, and under ``kv_quant`` they are attended in their stored
         int8 form — bit-consistent with every later step by
@@ -1621,46 +1663,71 @@ class Transformer:
         with scope("embed"):
             x = params["embed"][tokens].astype(c.dtype)      # (T, H)
         with scope("kv_append"):
-            valid = token_pos >= 0
-            pos_c = jnp.maximum(token_pos, 0)
-            local_page = state.block_table[
-                jnp.clip(token_rows, 0, state.slots - 1),
-                jnp.clip(pos_c // page, 0, state.pages_per_seq - 1),
-            ]
-            # padding tokens (and unallocated -1 table entries) scatter
-            # out of pool — JAX OOB-scatter drops them
-            pool_idx = jnp.where(
-                valid & (local_page >= 0), local_page, npages
-            )
-            off = pos_c % page
-            heads = jnp.arange(c.n_kv_heads)
-            pi = pool_idx[:, None]
-            hi = heads[None, :]
-            oi = off[:, None]
-            if self.tp == 1:
-                # heads unsharded: append as ONE-index row scatters over
-                # the pool viewed as (npages·Hkv·page, D) rows. XLA
-                # flattens the three-index scatter to exactly this
-                # anyway, but the scatter its pass creates drops the
-                # operation's metadata (the append showed in a profile
-                # with no op_name: a third of the step, nameless); the
-                # same flattening done here compiles to the same two
-                # in-place fusions and keeps ``kv_append`` on them. An
-                # out-of-pool page still lands past the last row and is
-                # dropped.
-                rows = ((pi * c.n_kv_heads + hi) * page + oi).reshape(-1)
-                kv_shape = (t * c.n_kv_heads, c.head_dim)
+            if self.kv_append_by_kernel(use_pallas):
+                # the kernel: one read-modify-write per (slot, page)
+                # run of the packing contract, described ONCE a step
+                # from operands the step already has
+                from triton_distributed_tpu.kernels.kv_append import (
+                    append_units,
+                )
 
-                def append(pool, new):
-                    flat = pool.reshape(-1, *pool.shape[3:])
-                    return flat.at[rows].set(new).reshape(pool.shape)
-            else:
-                # head-sharded pools keep the three-index form: their
-                # rows do not merge into one sharded dimension
                 kv_shape = (t, c.n_kv_heads, c.head_dim)
+                append_layer = functools.partial(
+                    self._kv_append_kernel,
+                    append_units(
+                        q_starts, q_lens,
+                        token_pos[jnp.clip(q_starts, 0, t - 1)],
+                        state.block_table, page=page, t=t,
+                    ),
+                )
+            else:
+                valid = token_pos >= 0
+                pos_c = jnp.maximum(token_pos, 0)
+                local_page = state.block_table[
+                    jnp.clip(token_rows, 0, state.slots - 1),
+                    jnp.clip(pos_c // page, 0, state.pages_per_seq - 1),
+                ]
+                # padding tokens (and unallocated -1 table entries)
+                # scatter out of pool — JAX OOB-scatter drops them
+                pool_idx = jnp.where(
+                    valid & (local_page >= 0), local_page, npages
+                )
+                pi = pool_idx[:, None]
+                hi = jnp.arange(c.n_kv_heads)[None, :]
+                oi = (pos_c % page)[:, None]
+                if self.tp == 1:
+                    # heads unsharded: append as ONE-index row scatters
+                    # over the pool viewed as (npages·Hkv·page, D) rows.
+                    # XLA flattens the three-index scatter to exactly
+                    # this anyway, but the scatter its pass creates
+                    # drops the operation's metadata (the append showed
+                    # in a profile with no op_name, nameless); the same
+                    # flattening done here compiles to the same two
+                    # in-place fusions and keeps ``kv_append`` on them.
+                    # An out-of-pool page still lands past the last row
+                    # and is dropped.
+                    from triton_distributed_tpu.kernels.kv_append import (
+                        append_rows_xla,
+                    )
 
-                def append(pool, new):
-                    return pool.at[pi, hi, oi].set(new)
+                    kv_shape = (t * c.n_kv_heads, c.head_dim)
+                    append = functools.partial(
+                        append_rows_xla,
+                        rows=((pi * c.n_kv_heads + hi) * page + oi)
+                        .reshape(-1),
+                    )
+                else:
+                    # head-sharded pools keep the three-index form:
+                    # their rows do not merge into one sharded dimension
+                    kv_shape = (t, c.n_kv_heads, c.head_dim)
+
+                    def append(pool, new):
+                        return pool.at[pi, hi, oi].set(new)
+
+                def append_layer(kp, vp, k_new, v_new):
+                    # pools and new rows are arrays, or {"q", "scale"}
+                    return (jax.tree.map(append, kp, k_new),
+                            jax.tree.map(append, vp, v_new))
 
         new_layers = []
         new_states = None if moe_state is None else list(moe_state)
@@ -1681,15 +1748,13 @@ class Transformer:
                     from triton_distributed_tpu.kernels.flash_decode \
                         import quantize_kv
 
-                    kq8, ks8 = quantize_kv(k)
-                    vq8, vs8 = quantize_kv(v)
-                    kp = {"q": append(kp["q"], kq8),
-                          "scale": append(kp["scale"], ks8)}
-                    vp = {"q": append(vp["q"], vq8),
-                          "scale": append(vp["scale"], vs8)}
+                    k_new, v_new = (
+                        dict(zip(("q", "scale"), quantize_kv(x)))
+                        for x in (k, v)
+                    )
                 else:
-                    kp = append(kp, k.astype(kp.dtype))
-                    vp = append(vp, v.astype(vp.dtype))
+                    k_new, v_new = k.astype(kp.dtype), v.astype(vp.dtype)
+                kp, vp = append_layer(kp, vp, k_new, v_new)
                 kp = jax.tree.map(
                     lambda a: jax.lax.with_sharding_constraint(
                         a, self._serving_pool_sharding

@@ -263,6 +263,11 @@ class EngineStats:
     prefill_tokens: int = 0
     evictions: int = 0
     deferrals: int = 0
+    # the pool append of the device steps: (slot, page) runs handed to
+    # the ``kernels/kv_append`` kernel, and steps whose append went by
+    # its XLA twin, the row scatter (head-sharded pools, use_pallas off)
+    append_runs: int = 0
+    append_scatter_steps: int = 0
     prefix_hits: int = 0               # pages reattached from the cache
     # --- in-batch shared-prefix dedup (EngineConfig.prefix_share) ---
     shared_prefix_rows: int = 0        # batched rows marked SHARED_PREFIX
@@ -490,6 +495,7 @@ class ServingEngine:
         self.waiting: deque = deque()      # arrived, not admitted
         self.stats = EngineStats()
         self.step_count = 0
+        self._append_runs = 0           # of the batch last assembled
         # seconds of the running step inside each phase (``_Phase``)
         self._phase_s = dict.fromkeys(PHASES, 0.0)
         # --- multi-tenancy (all defaults reproduce the single-tenant
@@ -845,6 +851,7 @@ class ServingEngine:
         topo_w = topo_width(self._block_q_cap)
         topo = causal_topologies(R, topo_w)
         next_start = 0
+        self._append_runs = 0
         batched: set = set()
         takes: dict = {}
         for s in range(R):
@@ -873,6 +880,8 @@ class ServingEngine:
                 q_starts[s] = next_start
                 q_lens[s] = take
                 kv_dev[s] = req.cursor + take
+                # the span's pages: the cursor's own up to the last held
+                self._append_runs += need - req.cursor // cfg.page
                 next_start += _ceil8(take)
                 batched.add(s)
                 takes[s] = take
@@ -936,6 +945,10 @@ class ServingEngine:
                 step=self.step_count,
             )
             out = step_fn(*args)
+            if self.model.kv_append_by_kernel(self.use_pallas):
+                self.stats.append_runs += self._append_runs
+            else:
+                self.stats.append_scatter_steps += 1
             if self.moe_state is None:
                 logits, self.state = out
             else:
