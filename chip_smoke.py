@@ -80,6 +80,21 @@ PARITY_VIEWS = {
 }
 MAX_STEPS = 4000
 DEADLINE_S = 1150
+#: the window leg (PR 29): ``presets.k_exaone_236b`` cut in WIDTH to a
+#: twin that compiles in seconds — every kind of layer, the head size,
+#: the window and the page as served (128 each), a share of the experts
+#: held (4 of 32, from expert 8 on) — and, compiled but not run, one
+#: rung of the step at the published widths as the benchmark serves it
+WINDOW_TWIN = dict(n_layers=5, hidden=256, ffn=256, dense_ffn=512,
+                   n_heads=8, n_kv_heads=2, vocab=1024, num_experts=32,
+                   experts_held=4, first_expert_held=8)
+WINDOW_ENGINE = dict(slots=4, token_budget=512, chunk=256, page=128,
+                     npages=16)
+WINDOW_PROMPTS = (700, 40, 300)         # 700 > ring (4) x page = 512
+WINDOW_TOL = 0.03                       # rms(kernel - twin) / rms(twin)
+PUBLISHED_CUT = dict(n_layers=5, experts_held=16, vocab=19200)
+PUBLISHED_ENGINE = dict(slots=32, token_budget=512, chunk=256, page=128,
+                        npages=2176)
 
 
 class SmokeFailure(Exception):
@@ -345,6 +360,120 @@ def overlap_ops(mesh) -> dict:
     return out
 
 
+def window_leg(devices, on_chip: bool = True) -> dict:
+    """Sliding-window layers over ring pools, a sigmoid-routed share of
+    an expert layer, a shared expert: the width-cut twin served once by
+    the kernels and once by their XLA twins (logits compared), then one
+    rung of the published-width step compiled for this device."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    from triton_distributed_tpu.kernels.ragged_paged_attention import (
+        auto_block_q,
+        topo_width,
+    )
+    from triton_distributed_tpu.models import Transformer, presets
+    from triton_distributed_tpu.serving import (
+        EngineConfig,
+        Request,
+        ServingEngine,
+    )
+    from triton_distributed_tpu.serving.state import ring_pages
+
+    mesh = Mesh(np.asarray(devices), ("x",))
+    t0 = time.perf_counter()
+    model = Transformer(
+        presets.k_exaone_236b(param_dtype=jnp.bfloat16, **WINDOW_TWIN),
+        mesh, tp_axis="x")
+    ring = ring_pages(WINDOW_ENGINE["chunk"], model.config.window,
+                      WINDOW_ENGINE["page"])
+    params = jax.block_until_ready(jax.jit(
+        model.init, out_shardings=model.shardings())(
+            jax.random.PRNGKey(SEED)))
+    rng = np.random.default_rng(SEED)
+    prompts = [rng.integers(0, model.config.vocab, (n,)).astype(np.int32)
+               for n in WINDOW_PROMPTS]
+    served = {}
+    for use_pallas in (True, False):
+        eng = ServingEngine(model, params, EngineConfig(**WINDOW_ENGINE),
+                            use_pallas=use_pallas, propagate_failures=True)
+        rows, sample = [], eng._sample
+
+        def keep(row_logits, req, rows=rows, sample=sample):
+            rows.append(np.asarray(row_logits, np.float32))
+            return sample(row_logits, req)
+
+        eng._sample = keep
+        stats = eng.run([Request(rid=i, prompt=p, max_new=6,
+                                 arrival=float(i))
+                         for i, p in enumerate(prompts)],
+                        max_steps=MAX_STEPS)
+        need(stats.completed == len(prompts) and not stats.failures
+             and not stats.degraded,
+             f"window twin (use_pallas={use_pallas}) did not complete "
+             f"cleanly: {stats.failures}")
+        need(eng.state.ring == ring and all(
+            eng.state.layer_pages(i) == WINDOW_ENGINE["slots"] * ring
+            for i in eng.state.window_layers),
+            "a window layer holds more than slots x ring pages")
+        served[use_pallas] = (np.stack(rows), stats)
+    got, want = served[True][0], served[False][0]
+    need(np.isfinite(got).all(), "window twin: logits not finite")
+    rel = float(np.sqrt(np.mean((got - want) ** 2))
+                / np.sqrt(np.mean(want ** 2)))
+    need(rel <= WINDOW_TOL,
+         f"window twin: kernels and XLA twins disagree, rel rms {rel:.4f}"
+         f" > {WINDOW_TOL}")
+    st = served[True][1]
+    t_twin = time.perf_counter() - t0
+
+    # ---- one published-width rung, compiled for this device, not run
+    t0 = time.perf_counter()
+    cfg = presets.k_exaone_236b(param_dtype=jnp.bfloat16, **PUBLISHED_CUT)
+    big = Transformer(cfg, mesh, tp_axis="x")
+    ecfg = EngineConfig(**PUBLISHED_ENGINE)
+    rep = NamedSharding(mesh, P())
+
+    def arg(shape, dtype, sharding=rep):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    abstract = jax.tree.map(
+        lambda a, sh: arg(a.shape, a.dtype, sh),
+        jax.eval_shape(big.init, jax.random.PRNGKey(0)), big.shardings())
+    state = jax.eval_shape(
+        lambda: big.init_serving_state(
+            ecfg.slots, ecfg.npages, ecfg.page, chunk=ecfg.chunk))
+    pool_sh = big._serving_pool_sharding
+    state = state.replace(layers=jax.tree.map(
+        lambda a: arg(a.shape, a.dtype, pool_sh), state.layers))
+    cap = auto_block_q(ecfg.chunk, cfg.n_heads // cfg.n_kv_heads)
+    t_pad, slots = ecfg.token_budget + cap, ecfg.slots
+    ints = [arg((t_pad,), jnp.int32)] * 3 + [arg((slots,), jnp.int32)] * 2
+    lowered = big._serving_jit.lower(
+        abstract, state, *ints,
+        arg((slots, 2 + 2 * topo_width(cap)), jnp.int32),
+        big.init_decode_state(t_pad, abstract=True), 8, True, 2)
+    text = lowered.as_text()
+    for kernel in (f"ragged_paged_attention_w{cfg.window}",
+                   "ragged_paged_attention", "kv_append"):
+        need(not on_chip or f'kernel_name = "{kernel}"' in text,
+             f"published-width step lowered without the {kernel} kernel")
+    mem = lowered.compile().memory_analysis()
+    return {
+        "leg": "window", "twin_rel_rms": round(rel, 5),
+        "twin_s": round(t_twin, 2),
+        "ring_pages_per_slot": ring,
+        "global_pages_walked": st.global_pages_walked,
+        "window_pages_walked": st.window_pages_walked,
+        "published_rung_compile_s": round(time.perf_counter() - t0, 2),
+        "published_rung_bytes": {
+            "arguments": mem.argument_size_in_bytes,
+            "temporaries": mem.temp_size_in_bytes},
+    }
+
+
 def leg(devices, on_chip: bool = True) -> dict:
     """The whole smoke on one device set."""
     n = len(devices)
@@ -407,7 +536,15 @@ def leg(devices, on_chip: bool = True) -> dict:
     return rec
 
 
-def main() -> int:
+def main(argv=None) -> int:
+    import argparse
+
+    ap = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    ap.add_argument("--legs", default="window,dsmoe",
+                    help="comma-separated: window (the sliding-window / "
+                         "expert-share twin + one published rung "
+                         "compiled), dsmoe (the served trace)")
+    legs = ap.parse_args(argv).legs.split(",")
     t_start = time.perf_counter()
     # a wedged collective must end as a failure with every thread's
     # stack on stderr, inside the smoke's 1200 s allowance — not as a
@@ -435,9 +572,12 @@ def main() -> int:
         say(device=device, compile_cache=enable_compile_cache(),
             jax=jax.__version__, seed=SEED)
         try:
-            say(**leg(devs[:1]))
-            if len(devs) >= 4:
-                say(**leg(devs[:4]))
+            if "window" in legs:
+                say(**window_leg(devs[:1]))
+            if "dsmoe" in legs:
+                say(**leg(devs[:1]))
+                if len(devs) >= 4:
+                    say(**leg(devs[:4]))
         except Exception as e:
             import traceback
 

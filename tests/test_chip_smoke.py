@@ -84,3 +84,26 @@ def test_leg_fails_when_the_engine_swallowed_a_failure(tiny, tmp_path,
         lambda: presets.tiny(presets.deepseek_moe_16b(), hidden=64))
     with pytest.raises(RuntimeError, match="injected kernel failure"):
         chip_smoke.leg(jax.devices()[:1], on_chip=False)
+
+
+def test_window_leg_passes_its_own_checks_at_tiny_size(tmp_path,
+                                                       monkeypatch):
+    """The window leg (sliding-window layers over ring pools, an expert
+    share, a shared expert) at CPU sizes: kernels against their XLA
+    twins, the ring pools' size, and the "published" rung lowered."""
+    small = dict(hidden=64, ffn=64, dense_ffn=96, n_heads=4, n_kv_heads=2,
+                 head_dim=16, vocab=128, num_experts=8, topk=2, window=16)
+    monkeypatch.setenv("TDTPU_AUTOTUNE_LOG_DIR", str(tmp_path))
+    monkeypatch.setattr(chip_smoke, "WINDOW_TWIN", dict(
+        n_layers=5, experts_held=4, first_expert_held=2, **small))
+    monkeypatch.setattr(chip_smoke, "WINDOW_ENGINE", dict(
+        slots=4, token_budget=64, chunk=24, page=8, npages=32))
+    monkeypatch.setattr(chip_smoke, "WINDOW_PROMPTS", (70, 5, 33))
+    monkeypatch.setattr(chip_smoke, "PUBLISHED_CUT", dict(
+        n_layers=5, experts_held=2, **small))
+    monkeypatch.setattr(chip_smoke, "PUBLISHED_ENGINE", dict(
+        slots=4, token_budget=64, chunk=16, page=8, npages=32))
+    rec = chip_smoke.window_leg(jax.devices()[:1], on_chip=False)
+    assert rec["twin_rel_rms"] <= chip_smoke.WINDOW_TOL
+    assert rec["ring_pages_per_slot"] == 6      # ceil((24 + 15) / 8) + 1
+    assert 0 < rec["window_pages_walked"] < rec["global_pages_walked"]

@@ -29,18 +29,26 @@ from jax.experimental.pallas import tpu as pltpu
 from triton_distributed_tpu.config import local_interpret
 
 
-def _ggemm_kernel(nsteps_k, be_ref, x_ref, w_ref, o_ref, acc_ref):
+def _ggemm_kernel(nsteps_k, be_ref, x_ref, w_ref, o_ref, acc_ref,
+                  dummy_expert=None):
     kk = pl.program_id(2)
 
     @pl.when(kk == 0)
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    acc_ref[:] += jax.lax.dot_general(
-        x_ref[:], w_ref[0],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    def _mac():
+        acc_ref[:] += jax.lax.dot_general(
+            x_ref[:], w_ref[0],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    if dummy_expert is None:
+        _mac()
+    else:
+        # a dummy block multiplies nothing: its zeros are stored as is
+        pl.when(be_ref[pl.program_id(0)] < dummy_expert)(_mac)
 
     @pl.when(kk == nsteps_k - 1)
     def _store():
@@ -114,7 +122,7 @@ def quantize_act_rows(x):
 @functools.partial(
     jax.jit,
     static_argnames=("block_m", "block_n", "block_k", "vmem_limit_bytes",
-                     "interpret", "out_dtype"),
+                     "interpret", "out_dtype", "dummy_expert"),
 )
 def grouped_matmul(
     x_sorted, w, block_expert, *,
@@ -123,6 +131,7 @@ def grouped_matmul(
     vmem_limit_bytes: int | None = None,
     interpret=None,
     out_dtype=None,
+    dummy_expert: int | None = None,
 ):
     """x_sorted (cap, K) @ w (E, K, N) → (cap, N), expert per M-block.
 
@@ -166,6 +175,15 @@ def grouped_matmul(
     are MXU-bound (the weight-resident schedule already minimized the
     HBM reads), so doubling the MXU rate is the remaining lever.
     ``out_dtype`` defaults to bf16 here (int8 out makes no sense).
+
+    ``dummy_expert`` (un-quantized weights only): M-blocks whose
+    ``block_expert`` is ``>= dummy_expert`` hold no row of any expert
+    (the trailing group of ``moe_align_block_size`` and its slack).
+    They are stored as zeros without a multiply, and every step of such
+    a block names the SAME weight and activation tile, so the pipeline
+    fetches one tile for a whole run of them instead of streaming an
+    expert's matrix per block. None: every block is multiplied (the
+    caller clamps ``block_expert`` and feeds zero rows).
     """
     from triton_distributed_tpu.config import compiling_for_tpu
     from triton_distributed_tpu.kernels.ag_gemm import _divisor_block
@@ -180,16 +198,39 @@ def grouped_matmul(
     block_k = _divisor_block(kdim, min(block_k, kdim), 128, compiling_for_tpu()) or kdim
     nsteps_k = kdim // block_k
 
-    in_specs = [
-        pl.BlockSpec((block_m, block_k), lambda m, n, k, be: (m, k)),
-        pl.BlockSpec(
-            (1, block_k, block_n), lambda m, n, k, be: (be[m], k, n)
-        ),
-    ]
+    if dummy_expert is None:
+        in_specs = [
+            pl.BlockSpec((block_m, block_k), lambda m, n, k, be: (m, k)),
+            pl.BlockSpec(
+                (1, block_k, block_n), lambda m, n, k, be: (be[m], k, n)
+            ),
+        ]
+    else:
+        if w_scale is not None:
+            raise ValueError(
+                "grouped_matmul: dummy_expert is built for un-quantized "
+                "weights only")
+
+        def live(m, be):
+            return (be[m] < dummy_expert).astype(jnp.int32)
+
+        in_specs = [
+            pl.BlockSpec(
+                (block_m, block_k),
+                lambda m, n, k, be: (m, k * live(m, be))),
+            pl.BlockSpec(
+                (1, block_k, block_n),
+                lambda m, n, k, be: (jnp.minimum(be[m], e - 1),
+                                     k * live(m, be), n * live(m, be)),
+            ),
+        ]
     acc_dtype = jnp.float32
     if w_scale is None:
         assert x_scale is None, "x_scale requires w_scale (W8A8 mode)"
         kernel = functools.partial(_ggemm_kernel, nsteps_k)
+        if dummy_expert is not None:
+            kernel = functools.partial(
+                _ggemm_kernel, nsteps_k, dummy_expert=int(dummy_expert))
         args = (block_expert, x_sorted, w)
     else:
         assert w.dtype.itemsize == 1, (

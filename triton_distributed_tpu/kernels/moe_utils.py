@@ -46,6 +46,33 @@ def select_experts(gate_logits, topk: int, *, renormalize: bool = True):
     return weights, ids.astype(jnp.int32)
 
 
+def select_experts_sigmoid_bias(gate_logits, bias, topk: int, *,
+                                scale: float = 1.0):
+    """Sigmoid router with a selection bias (the DeepSeek-V3 family's
+    ``scoring_func: sigmoid``, no group limit) → (weights (M, k) f32,
+    expert ids (M, k) int32): scores ``s = sigmoid(logits)``; the top-k
+    of ``s + bias`` are SELECTED, and weighted by their own ``s``
+    renormalised over the k and times ``scale``
+    (``routed_scaling_factor``). The bias only moves the choice."""
+    s = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
+    _, ids = jax.lax.top_k(s + bias.astype(jnp.float32), topk)
+    w = jnp.take_along_axis(s, ids, axis=-1)
+    w = w / jnp.sum(w, axis=-1, keepdims=True) * scale
+    return w, ids.astype(jnp.int32)
+
+
+def held_assignments(weights, ids, first: int, held: int):
+    """One chip's SHARE of routed assignments: ``ids`` over the whole
+    router become ids local to the ``held`` experts that start at
+    expert ``first``; every other assignment becomes the sentinel
+    ``held`` with weight exactly 0 — what ``ops.moe`` sorts to the tail
+    and neither stages nor multiplies. Flat ``(M·k,)`` pairs."""
+    local = ids.astype(jnp.int32) - first
+    mine = (local >= 0) & (local < held)
+    return (jnp.where(mine, local, held).reshape(-1),
+            jnp.where(mine, weights.astype(jnp.float32), 0.0).reshape(-1))
+
+
 def aligned_capacity(total: int, num_experts: int, block_m: int) -> int:
     """Static worst-case padded length: every expert wastes < block_m."""
     return round_up_to_block(total + num_experts * (block_m - 1), block_m)

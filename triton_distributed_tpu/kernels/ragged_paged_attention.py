@@ -202,6 +202,16 @@ def _n_valid_pages(kv_len, page):
     return jnp.maximum(jax.lax.div(kv_len + page - 1, page), 1)
 
 
+def _first_page(kv_len, q_len, page, pps, window):
+    """First page of a sliding-window row's walk: the page of position
+    ``kv_len - q_len - window + 1``, the oldest key the row's first
+    query sees (0 while the window still reaches the sequence's start),
+    kept inside the row's valid pages."""
+    first_key = jnp.maximum(kv_len - q_len - window + 1, 0)
+    last = jnp.minimum(_n_valid_pages(kv_len, page), pps) - 1
+    return jnp.minimum(jax.lax.div(first_key, page), last)
+
+
 def pack_gqa_rows(q, hkv):
     """(T, Hq, D) → (Hkv, T·G, D): the kernel's GQA-rows layout — one
     contiguous (q_len·G, D) run per (row, kv-head)."""
@@ -222,7 +232,7 @@ def unpack_gqa_rows(o, hq):
 
 def _ragged_kernel(
     scale, soft_cap, page, n_bufs, hkv, g, d, block_q, quant, topo_w,
-    with_lse, *refs,
+    with_lse, window, *refs,
 ):
     """Grid (R,): one request row per step; all local KV heads unrolled.
 
@@ -245,7 +255,17 @@ def _ragged_kernel(
 
     ``with_lse`` (static): False drops the lse output, its staging
     buffer and its DMA — the head-sharded serving step never reads it
-    (only the cp shard merge does)."""
+    (only the cp shard merge does).
+
+    ``window`` (static): None builds the kernel above bit for bit. An
+    int makes every row a SLIDING-WINDOW row: query position ``i`` sees
+    keys ``i - window < j <= i``. The page walk then starts at the
+    first page that holds a key some valid query of the row can see
+    (``_first_page``: the pages before it are never fetched), and a
+    page that crosses the window's lower edge takes the masked path
+    like a frontier page. Composes with CAUSAL descriptor rows (and
+    their ``q_len == 0`` skip); the other topology kinds have no
+    windowed meaning and the engine refuses them beside a window."""
     refs = iter(refs)
 
     def take(n):
@@ -274,6 +294,18 @@ def _ragged_kernel(
     kv_len = kv_lens_ref[r]
     q_len = q_lens_ref[r]
     nb = jnp.minimum(_n_valid_pages(kv_len, page), pps)
+
+    def first_page(rr):
+        # the first page of row rr's walk: 0, or under a window the
+        # page of the oldest key its first query still sees
+        if window is None:
+            return 0
+        return _first_page(kv_lens_ref[rr], q_lens_ref[rr], page, pps,
+                           window)
+
+    j0 = first_page(r)
+    # pages walked before page j (no op at all without a window)
+    walked = (lambda j: j) if window is None else (lambda j: j - j0)
 
     def dma(rr, j, slot):
         # row rr's j-th page; clamp so a prefetch into a short row's
@@ -343,7 +375,7 @@ def _ragged_kernel(
             def _start_first():
                 fa = jnp.minimum(first_active, nrows - 1)
                 qdma(fa, 0).start()
-                for cp in dma(fa, 0, 0):
+                for cp in dma(fa, first_page(fa), 0):
                     cp.start()
     else:
         @pl.when(r == 0)
@@ -351,7 +383,7 @@ def _ragged_kernel(
             slot_ref[0] = 0                   # KV slot rotation carry
             slot_ref[1] = 0                   # q double-buffer parity
             qdma(0, 0).start()
-            for cp in dma(0, 0, 0):
+            for cp in dma(0, first_page(0), 0):
                 cp.start()
 
     def row_body():
@@ -401,8 +433,8 @@ def _ragged_kernel(
             lim_lo = jnp.where(is_tree, base, flat)
 
         def body(j, _):
-            slot = jax.lax.rem(s0 + j, n_bufs)
-            nxt = jax.lax.rem(s0 + j + 1, n_bufs)
+            slot = jax.lax.rem(s0 + walked(j), n_bufs)
+            nxt = jax.lax.rem(s0 + walked(j) + 1, n_bufs)
 
             @pl.when(j + 1 < nb)
             def _prefetch_in_row():
@@ -413,13 +445,14 @@ def _ragged_kernel(
                 @pl.when(jnp.logical_and(j + 1 == nb, nxt_active < nr))
                 def _prefetch_next_row():
                     qdma(nxt_clamped, 1 - qslot).start()
-                    for cp in dma(nxt_clamped, 0, nxt):
+                    for cp in dma(nxt_clamped, first_page(nxt_clamped),
+                                  nxt):
                         cp.start()
             else:
                 @pl.when(jnp.logical_and(j + 1 == nb, r + 1 < nr))
                 def _prefetch_next_row():
                     qdma(r + 1, 1 - qslot).start()
-                    for cp in dma(r + 1, 0, nxt):
+                    for cp in dma(r + 1, first_page(r + 1), nxt):
                         cp.start()
 
             # chaos hook: widens the slot-rotation window between the
@@ -433,6 +466,11 @@ def _ragged_kernel(
             # path. TREE rows: the speculative region [base, kv_len) is
             # entirely frontier pages, so interior pages stay fast.
             is_frontier = (j + 1) * page > base + 1
+            if window is not None:
+                # ... or the window's lower edge: some valid query of
+                # the row no longer sees this page's first key
+                is_frontier = jnp.logical_or(
+                    is_frontier, j * page < kv_len - window)
 
             def heads(masked):
                 if masked:
@@ -452,6 +490,9 @@ def _ragged_kernel(
                         )                     # (rows, page)
                     else:
                         valid = pos < limit   # (rows, page)
+                    if window is not None:
+                        valid = jnp.logical_and(
+                            valid, pos >= limit - window)
                 for h in range(hkv):          # static unroll
                     q = qbuf[qslot, h]        # (rows, d)
                     k = kbuf[slot, h]
@@ -501,8 +542,8 @@ def _ragged_kernel(
 
             return 0
 
-        jax.lax.fori_loop(0, nb, body, 0)
-        slot_ref[0] = jax.lax.rem(s0 + nb, n_bufs)  # hand the rotation on
+        jax.lax.fori_loop(j0, nb, body, 0)
+        slot_ref[0] = jax.lax.rem(s0 + walked(nb), n_bufs)  # hand the rotation on
         if topo_w:
             slot_ref[1] = jnp.where(nxt_active < nr, 1 - qslot, qslot)
         else:
@@ -550,7 +591,7 @@ def _ragged_kernel(
 def _build_ragged(
     r, pps, npages, t_tokens, hkv, g, d, page, block_q, q_dtype,
     quant, scale, soft_cap, n_bufs, interpret, token=(), topo_w=0,
-    with_lse=True,
+    with_lse=True, window=None,
 ):
     """Construct the ragged-paged-attention pallas_call (lru-cached on
     the full static geometry; ``token`` busts the cache for lint/
@@ -566,7 +607,7 @@ def _build_ragged(
     rows = block_q * g
     kernel = functools.partial(
         _ragged_kernel, scale, soft_cap, page, n_bufs, hkv, g, d,
-        block_q, quant, topo_w, with_lse,
+        block_q, quant, topo_w, with_lse, window,
     )
     pool_dt = jnp.dtype(jnp.int8) if quant else q_dtype
     in_specs = [
@@ -641,7 +682,11 @@ def _build_ragged(
         collective_id=None,                   # purely local kernel
         vmem_limit_bytes=vmem_limit,
         interpret=local_interpret() if interpret is None else interpret,
-        name="ragged_paged_attention" + ("_q8" if quant else ""),
+        # a windowed launch has a name of its own: a profile tells the
+        # sliding-window layers' kernel from the global layers'
+        name="ragged_paged_attention"
+        + ("" if window is None else f"_w{window}")
+        + ("_q8" if quant else ""),
         # slot-rotation carries + cross-row prefetch + out self-heal
         # all require SEQUENTIAL grid execution
         dimension_semantics=("arbitrary",),
@@ -665,13 +710,14 @@ def auto_block_q(max_q_len: int, g: int) -> int:
 @functools.partial(
     jax.jit,
     static_argnames=("group", "scale", "soft_cap", "block_q", "n_bufs",
-                     "with_lse", "interpret"),
+                     "with_lse", "interpret", "window"),
 )
 def ragged_paged_attention(
     q, k_pool, v_pool, kv_lens, q_lens, q_starts, block_table, *,
     group: int, topologies=None, k_scale=None, v_scale=None,
     scale: float | None = None, soft_cap: float = 0.0, block_q: int = 8,
     n_bufs: int = 2, with_lse: bool = True, interpret=None,
+    window: int | None = None,
 ):
     """Mixed prefill-chunk/decode attention over a shared page pool.
 
@@ -691,6 +737,13 @@ def ragged_paged_attention(
     ancestor bitmask, SHARED_PREFIX rows read aliased prefix pages
     through their (deduplicated) block tables, and ``q_len == 0`` rows
     are skipped by the cross-row prefetch hop.
+
+    ``window`` (static): None is full causal attention, today's launch
+    bit for bit. An int is sliding-window attention: query position
+    ``i`` sees keys ``i - window < j <= i``, and each row's walk skips
+    the pages below its window (``block_table`` may then be a ring: a
+    page's id is only read while the page is walked). Descriptor rows
+    beside a window must be CAUSAL.
 
     Returns (out (Hkv, T·G, D) in q.dtype, lse (Hkv, T·G) f32 — None
     without ``with_lse``, which also drops the kernel's lse writes).
@@ -731,10 +784,14 @@ def ragged_paged_attention(
                 f"ragged_paged_attention: topologies shape {(tr, tw)} "
                 f"must be (R={r}, 2+2·W) with 1 <= W <= {TOPO_MAX_NODES}"
             )
+    if window is not None and int(window) < 1:
+        raise ValueError(
+            f"ragged_paged_attention: window must be >= 1, got {window}")
     call = _build_ragged(
         r, pps, npages, t_tokens, hkv, g, d, page, block_q,
         jnp.dtype(q.dtype).name, quant, float(scale), float(soft_cap),
         n_bufs, interpret, (), topo_w, with_lse,
+        None if window is None else int(window),
     )
     args = [
         block_table.astype(jnp.int32), kv_lens.astype(jnp.int32),
@@ -758,7 +815,7 @@ def ragged_paged_attention(
 def ragged_paged_attention_xla(
     q, k_pool, v_pool, kv_lens, q_lens, q_starts, block_table, *,
     group: int, topologies=None, k_scale=None, v_scale=None, scale=None,
-    soft_cap=0.0,
+    soft_cap=0.0, window=None,
 ):
     """Dense-XLA twin (correctness reference + degradation target):
     gather each row's pages into a contiguous cache and run the masked
@@ -810,6 +867,8 @@ def ragged_paged_attention_xla(
         s = soft_cap * jnp.tanh(s / soft_cap)
     pos_s = jnp.arange(s_cap)
     ok = pos_s[None, :] < limit[:, None]       # (T, S) causal
+    if window is not None:
+        ok = ok & (pos_s[None, :] >= (limit - window)[:, None])
     if topologies is not None:
         topologies = jnp.asarray(topologies, jnp.int32)
         w = (topologies.shape[1] - 2) // 2
@@ -922,14 +981,21 @@ def build_grid_lint_kernel(token=(), schedule=None, quant=True):
     return gm
 
 
-def build_lint_kernel(token=(), quant=True):
+#: the lint geometry's sliding window: one (lint) page, as the served
+#: model's is one (serving) page
+LINT_WINDOW = 8
+
+
+def build_lint_kernel(token=(), quant=True, window=None):
     """Construct the ragged kernel exactly as production would (via
     shmem_call, so the LaunchSpec is captured under the family's
     launch name) at :data:`LINT_GEOM`. Used by the kernel registry and
-    the Mosaic pre-flight."""
+    the Mosaic pre-flight. ``window``: the sliding-window variant
+    (launch ``ragged_paged_attention_w<window>_q8``)."""
     gm = LINT_GEOM
     return _build_ragged(
         gm["r"], gm["pps"], gm["npages"], gm["t"], gm["hkv"], gm["g"],
         gm["d"], gm["page"], gm["block_q"], "float32", quant,
         1.0 / math.sqrt(gm["d"]), 0.0, 2, False, token, gm["topo_w"],
+        window=window,
     )

@@ -164,6 +164,9 @@ DEGRADATION_TARGETS = {
     "flash_decode.ragged_paged":
         "triton_distributed_tpu.kernels.ragged_paged_attention."
         "ragged_paged_attention_xla",
+    "flash_decode.ragged_paged_window":
+        "triton_distributed_tpu.kernels.ragged_paged_attention."
+        "ragged_paged_attention_xla",
     "kv_ship.pages": "triton_distributed_tpu.tools.native.xla_kv_ship",
     # the pool append's XLA twin is the row scatter serving_step keeps
     # for head-sharded pools and use_pallas=False
@@ -540,6 +543,21 @@ def _ragged_paged(mesh, n, token):
     build_lint_kernel(token=(token, n))
 
 
+def _ragged_paged_window(mesh, n, token):
+    """The same kernel with a static sliding window (the launch a
+    model's window layers make, over their ring pools): same geometry,
+    same operands, same `local` contract — the window moves where a
+    row's page walk starts and adds the mask's lower edge, not what
+    the row writes."""
+    del mesh
+    from triton_distributed_tpu.kernels.ragged_paged_attention import (
+        LINT_WINDOW,
+        build_lint_kernel,
+    )
+
+    build_lint_kernel(token=(token, n), window=LINT_WINDOW)
+
+
 def _kv_append(mesh, n, token):
     """The pool append (kernels/kv_append.py) is LOCAL like the ragged
     kernel it feeds: every rank read-modify-writes pages of its own
@@ -848,6 +866,20 @@ def families() -> dict:
             "flash_decode.ragged_paged", "ragged_paged",
             "ragged_paged_attention_q8",
             _ragged_paged,
+            _ragged_in_shapes,
+            init=_ragged_init,
+            contract=DeliveryContract(
+                kind="local", dst=10,
+                topo={"ref": 4, "kv_lens": 1, "q_lens": 2, "width": 8},
+            ),
+        ),
+        KernelFamily(
+            # the sliding-window launch of the same kernel (window
+            # layers over ring pools): every out element still the
+            # rank's own computed write
+            "flash_decode.ragged_paged_window", "ragged_paged",
+            "ragged_paged_attention_w8_q8",
+            _ragged_paged_window,
             _ragged_in_shapes,
             init=_ragged_init,
             contract=DeliveryContract(

@@ -196,7 +196,8 @@ class RaggedPagedAttention:
 
     def __call__(self, qp, k_pool, v_pool, kv_lens, q_lens, q_starts,
                  block_table, *, topologies=None, block_q: int = 8,
-                 n_bufs: int = 2, with_lse: bool = False):
+                 n_bufs: int = 2, with_lse: bool = False,
+                 window: int | None = None):
         """qp: (Hkv, T·G, D) packed rows sharded P(axis) on dim 0;
         k_pool/v_pool: (npages, Hkv, page, D) arrays or int8
         ``{"q","scale"}`` dicts, sharded P(None, axis); metadata —
@@ -205,7 +206,9 @@ class RaggedPagedAttention:
         qp — or the ``((Hkv, T·G, D), (Hkv, T·G))`` partial pair under
         ``with_lse`` (the cp-decode path merges per-shard partials with
         ``flash_decode.combine_gqa_partials``; head sharding makes the
-        LSE per-rank-local, so the pair shards exactly like qp)."""
+        LSE per-rank-local, so the pair shards exactly like qp).
+        ``window``: sliding-window attention (the kernel's and its
+        twin's ``window``); None is full causal."""
         from jax.sharding import PartitionSpec as P
 
         from triton_distributed_tpu.kernels.ragged_paged_attention import (
@@ -227,6 +230,8 @@ class RaggedPagedAttention:
                   else ragged_paged_attention_xla)
             kw = dict(group=g, scale=self.scale, soft_cap=self.soft_cap,
                       topologies=topo)
+            if window is not None:
+                kw["window"] = window
             if use_pallas:
                 kw["block_q"] = block
                 kw["n_bufs"] = n_bufs

@@ -86,6 +86,37 @@ def deepseek_moe_16b(**overrides) -> TransformerConfig:
     return TransformerConfig(**cfg)
 
 
+def k_exaone_236b(**overrides) -> TransformerConfig:
+    """K-EXAONE-236B-A23B (LGAI-EXAONE, ``model_type: exaone_moe``) as
+    published: 48 layers in the pattern sliding, sliding, sliding, full
+    (window 128; rotation at theta 1e6 and none on the full layers, q/k
+    RMS norm on all), GQA 64 / 8 x 128, one dense layer (gated, 18432)
+    then 47 sparse ones: 128 experts of width 2048, top-8 by sigmoid
+    score + selection bias, weights renormalised and x 2.5, one shared
+    expert; vocabulary 153600. Its multi-token-prediction head is not
+    part of the forward pass and not stated here.
+
+    The per-layer tuples follow ``n_layers``, so a depth cut needs that
+    one override; ``experts_held`` (with ``first_expert_held``) makes
+    the config one chip's share of an expert-parallel deployment, and
+    ``vocab`` its slice of the vocabulary."""
+    n = int(overrides.get("n_layers", 48))
+    kinds = tuple("full" if i % 4 == 3 else "sliding" for i in range(n))
+    cfg = dict(
+        vocab=153600, n_layers=n, hidden=6144, ffn=2048, dense_ffn=18432,
+        n_heads=64, n_kv_heads=8, head_dim=128, norm_eps=1e-5,
+        layer_attn=kinds, window=128,
+        rope_theta=1e6,
+        rope_layers=tuple(i for i, k in enumerate(kinds) if k == "sliding"),
+        qk_norm=True, gated_ffn=True,
+        moe="ep", moe_layers=tuple(range(1, n)), num_experts=128, topk=8,
+        shared_experts=1, router="sigmoid_bias", routed_scale=2.5,
+        dtype=jnp.bfloat16,
+    )
+    cfg.update(overrides)
+    return TransformerConfig(**cfg)
+
+
 def tiny(preset=None, **overrides) -> TransformerConfig:
     """CI-sized twin: same topology knobs as ``preset`` (or dense
     defaults), tiny dims — what the tests and the driver dryrun use."""
@@ -107,6 +138,19 @@ def tiny(preset=None, **overrides) -> TransformerConfig:
             kv_quant=preset.kv_quant,
             dense_weight_quant=preset.dense_weight_quant,
             dense_act_quant=preset.dense_act_quant,
+            # the serving-path architecture knobs, at the twin's depth
+            # and sizes: the first two layers' kinds, a window of one
+            # (tiny) page, a shared expert, the router and its scale
+            layer_attn=preset.layer_attn[:2],
+            window=min(preset.window, 16),
+            rope_theta=preset.rope_theta,
+            rope_layers=tuple(i for i in preset.rope_layers if i < 2),
+            qk_norm=preset.qk_norm,
+            gated_ffn=preset.gated_ffn,
+            dense_ffn=min(preset.dense_ffn, 384),
+            shared_experts=preset.shared_experts,
+            router=preset.router,
+            routed_scale=preset.routed_scale,
         )
     cfg.update(overrides)
     return TransformerConfig(**cfg)

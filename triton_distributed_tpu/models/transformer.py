@@ -119,6 +119,42 @@ class TransformerConfig:
     remat: bool = False
     dtype: object = jnp.bfloat16
     param_dtype: object = jnp.float32
+    # ---- architecture fields of the SERVING path (PR 29). Every
+    # default is the model above: full causal attention, no rotation,
+    # an un-gated two-matrix FFN of one width, a softmax router over
+    # experts that are all held here. ``serving_step`` implements them;
+    # forward / prefill / decode_step raise on them by name
+    # (``_plain_only``).
+    # per-layer attention kind, "full" | "sliding" (() = all full), and
+    # the sliding layers' window: query i sees keys i - window < j <= i
+    layer_attn: tuple = ()
+    window: int = 0
+    # rotary embedding (rotate-half, every dim of the head) at base
+    # ``rope_theta`` on the layers in ``rope_layers``; none elsewhere
+    rope_theta: float = 0.0
+    rope_layers: tuple = ()
+    # RMS norm of q and of k over the head dim, gains (head_dim,)
+    qk_norm: bool = False
+    # three-matrix gated FFN silu(x Wg) * (x Wu) Wd, dense layers and
+    # experts alike; [Wg | Wu] are stored as ONE matrix (gate first)
+    gated_ffn: bool = False
+    # width of the dense layers' FFN where it is not ``ffn`` (0 = ffn)
+    dense_ffn: int = 0
+    # shared experts beside the routed ones: one dense gated MLP of
+    # width ``shared_experts · ffn`` whose output every token adds
+    shared_experts: int = 0
+    # "softmax": top-k of the softmax, renormalised. "sigmoid_bias":
+    # sigmoid scores, top-k chosen by score + a per-expert bias
+    # (parameter ``router_bias``), weights the chosen scores
+    # renormalised and times ``routed_scale``
+    router: str = "softmax"
+    routed_scale: float = 1.0
+    # this program's SHARE of a wider expert-parallel layer: the router
+    # keeps ``num_experts`` outputs, the ``experts_held`` experts from
+    # ``first_expert_held`` on have their weights here, and the layer
+    # adds the part of the result they give (0 = all are held)
+    experts_held: int = 0
+    first_expert_held: int = 0
 
     def __post_init__(self):
         if self.attn not in ("tp", "ring", "ulysses"):
@@ -173,6 +209,95 @@ class TransformerConfig:
                 "moe_weight_quant targets the EP expert matrices — set "
                 f"moe='ep' (got moe={self.moe!r})"
             )
+        if self.layer_attn and (
+            len(self.layer_attn) != self.n_layers
+            or set(self.layer_attn) - {"full", "sliding"}
+        ):
+            raise ValueError(
+                f"layer_attn must give 'full' or 'sliding' for each of "
+                f"the {self.n_layers} layers, got {self.layer_attn!r}")
+        if (self.window > 0) != bool(self.window_layers):
+            raise ValueError(
+                f"window={self.window} and layer_attn's sliding layers "
+                f"{self.window_layers} go together")
+        if bool(self.rope_layers) != (self.rope_theta > 0) or any(
+                not 0 <= i < self.n_layers for i in self.rope_layers):
+            raise ValueError(
+                f"rope_layers={self.rope_layers!r} need rope_theta > 0 "
+                f"and lie in the {self.n_layers} layers (and the other "
+                f"way round; got rope_theta={self.rope_theta})")
+        if self.router not in ("softmax", "sigmoid_bias"):
+            raise ValueError(
+                f"router must be 'softmax' or 'sigmoid_bias', got "
+                f"{self.router!r}")
+        held = self.experts_held
+        if held and not 0 <= self.first_expert_held <= (
+                self.num_experts - held):
+            raise ValueError(
+                f"experts_held={held} from first_expert_held="
+                f"{self.first_expert_held} do not lie in the router's "
+                f"{self.num_experts} experts")
+        routed = [k for k, on in (
+            ("router", self.router != "softmax"),
+            ("routed_scale", self.routed_scale != 1.0),
+            ("experts_held", held > 0),
+            ("shared_experts", self.shared_experts > 0),
+        ) if on]
+        if routed and self.moe != "ep":
+            raise ValueError(
+                f"{', '.join(routed)}: built for moe='ep' only "
+                f"(got moe={self.moe!r})")
+        if self.gated_ffn and self.moe == "tp":
+            raise ValueError("gated_ffn is not built for moe='tp'")
+        quantized = [k for k in (
+            "moe_weight_quant", "moe_act_quant", "dense_weight_quant")
+            if getattr(self, k) is not None]
+        if quantized and (self.gated_ffn or self.shared_experts or held):
+            raise ValueError(
+                f"{', '.join(quantized)}: not built beside gated_ffn / "
+                "shared_experts / experts_held (their matrices are "
+                "served in param_dtype)")
+
+    @property
+    def window_layers(self) -> tuple:
+        """Indices of the sliding-window attention layers."""
+        return tuple(
+            i for i, k in enumerate(self.layer_attn) if k == "sliding")
+
+    @property
+    def local_experts(self) -> int:
+        """Experts whose weights this program holds."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def experts_published(self) -> int:
+        """The router's width: the experts of the whole layer, of which
+        ``local_experts`` are held here."""
+        return self.num_experts
+
+    @property
+    def dense_ffn_width(self) -> int:
+        return self.dense_ffn or self.ffn
+
+    @property
+    def routed_assignments(self) -> bool:
+        """Does the serving step route in the model (and hand
+        ``ops.ep_moe`` its assignments), not in the op?"""
+        return self.router != "softmax" or self.experts_held > 0
+
+    @property
+    def beyond_plain(self) -> tuple:
+        """Names of the set fields only ``serving_step`` implements."""
+        return tuple(k for k, on in (
+            ("layer_attn", bool(self.window_layers)),
+            ("rope_layers", bool(self.rope_layers)),
+            ("qk_norm", self.qk_norm),
+            ("gated_ffn", self.gated_ffn),
+            ("dense_ffn", self.dense_ffn_width != self.ffn),
+            ("shared_experts", self.shared_experts > 0),
+            ("router", self.router != "softmax"),
+            ("experts_held", self.experts_held > 0),
+        ) if on)
 
     @property
     def q_dim(self) -> int:
@@ -229,6 +354,30 @@ class Transformer:
     # cp_decode.lse_combine). Orthogonal to tp (head sharding) — a
     # tp×cp mesh shards heads within each cp group.
     cp_axis: str | None = None
+
+    def __post_init__(self):
+        c = self.config
+        if c.experts_held and self.tp > 1:
+            raise ValueError(
+                f"experts_held={c.experts_held} with tp={self.tp}: a "
+                "share of an expert-parallel layer is what ONE chip "
+                "holds; sharding it again over tp is not built")
+        if c.window_layers and self.cp > 1:
+            raise ValueError(
+                "sliding-window layers (layer_attn) with cp > 1: a ring "
+                "pool is one chip's, the cp shard walk is not built "
+                "over it")
+
+    def _plain_only(self, path: str) -> None:
+        """``forward`` / ``prefill`` / ``decode_step`` run the plain
+        architecture; refuse, by name, a field only ``serving_step``
+        implements."""
+        beyond = self.config.beyond_plain
+        if beyond:
+            raise ValueError(
+                f"Transformer.{path} does not implement "
+                f"{', '.join(beyond)}: serve this configuration through "
+                "serving_step (ServingEngine)")
 
     @property
     def tp(self) -> int:
@@ -349,7 +498,7 @@ class Transformer:
             wq_mode = "int8"
         w_itemsize = resident_weight_itemsize(wq_mode, c.dtype)
         wr_ok = pallas_ok and (
-            2 * c.hidden * c.ffn * w_itemsize
+            2 * c.hidden * c.ffn * (2 if c.gated_ffn else 1) * w_itemsize
             <= int(0.7 * fused_vmem_budget())
         )
         # W8A8 engages only where its int8 weight dicts will exist
@@ -362,7 +511,8 @@ class Transformer:
         else:
             bm = 256 if pallas_ok else 128
         return ops.create_ep_moe_context(
-            self.mesh, self.tp_axis, num_experts=c.num_experts, topk=c.topk,
+            self.mesh, self.tp_axis, num_experts=c.local_experts,
+            topk=c.topk,
             max_m=m_local * c.topk, hidden=c.hidden, dtype=c.dtype,
             transport="fused" if fused_ok else "xla",
             use_pallas_gemm=pallas_ok,
@@ -372,6 +522,11 @@ class Transformer:
             quant=c.moe_wire_quant if fused_ok else None,
             act_quant=a8,
             batch_axes=tuple(self.dp_axes),
+            gated=c.gated_ffn,
+            # most assignments of a share arrive masked: their blocks
+            # are not multiplied
+            skip_masked=bool(
+                c.experts_held and pallas_ok and wq_mode is None),
         )
 
     # ---------------------------------------------------------------- params
@@ -379,6 +534,7 @@ class Transformer:
     def init(self, key):
         c = self.config
         keys = iter(jax.random.split(key, 4 + 8 * c.n_layers))
+        # (a layer with every new field draws 8 keys: the split holds)
         pd = c.param_dtype
         s = 1.0 / (c.hidden ** 0.5)
 
@@ -391,6 +547,9 @@ class Transformer:
             "lm_head": dense(next(keys), (c.hidden, c.vocab)),
             "blocks": [],
         }
+        # a gated FFN stores [gate | up] as one matrix of twice the width
+        up_w = 2 if c.gated_ffn else 1
+        e, fd = c.local_experts, c.dense_ffn_width
         for i in range(c.n_layers):
             blk = {
                 "norm_attn": jnp.ones((c.hidden,), pd),
@@ -398,17 +557,30 @@ class Transformer:
                 "wqkv": dense(next(keys), (c.hidden, c.qkv_dim)),
                 "wo": dense(next(keys), (c.q_dim, c.hidden)),
             }
+            if c.qk_norm:
+                blk["norm_q"] = jnp.ones((c.head_dim,), pd)
+                blk["norm_k"] = jnp.ones((c.head_dim,), pd)
             if c.moe != "none" and i in c.moe_layers:
                 blk["router"] = dense(next(keys), (c.hidden, c.num_experts))
-                blk["moe_up"] = dense(next(keys), (c.num_experts, c.hidden, c.ffn))
+                if c.router == "sigmoid_bias":
+                    blk["router_bias"] = dense(
+                        next(keys), (c.num_experts,), 0.01)
+                blk["moe_up"] = dense(
+                    next(keys), (e, c.hidden, up_w * c.ffn))
                 blk["moe_down"] = dense(
-                    next(keys), (c.num_experts, c.ffn, c.hidden),
+                    next(keys), (e, c.ffn, c.hidden),
                     1.0 / (c.ffn ** 0.5),
                 )
+                if c.shared_experts:
+                    fs = c.shared_experts * c.ffn
+                    blk["shared_up"] = dense(
+                        next(keys), (c.hidden, up_w * fs))
+                    blk["shared_down"] = dense(
+                        next(keys), (fs, c.hidden), 1.0 / (fs ** 0.5))
             else:
-                blk["up"] = dense(next(keys), (c.hidden, c.ffn))
+                blk["up"] = dense(next(keys), (c.hidden, up_w * fd))
                 blk["down"] = dense(
-                    next(keys), (c.ffn, c.hidden), 1.0 / (c.ffn ** 0.5)
+                    next(keys), (fd, c.hidden), 1.0 / (fd ** 0.5)
                 )
             params["blocks"].append(blk)
         return params
@@ -613,7 +785,13 @@ class Transformer:
             blk = {
                 "norm_attn": rep, "norm_mlp": rep, **attn_sh,
             }
+            if c.qk_norm:
+                blk.update(norm_q=rep, norm_k=rep)
             if c.moe != "none" and i in c.moe_layers:
+                if c.router == "sigmoid_bias":
+                    blk.update(router_bias=rep)
+                if c.shared_experts:
+                    blk.update(shared_up=ns(None, t), shared_down=ns(t, None))
                 if c.moe == "ep":
                     # experts sharded over tp (each rank owns E/tp experts)
                     blk.update(router=rep, moe_up=ns(t), moe_down=ns(t))
@@ -768,6 +946,7 @@ class Transformer:
 
     def forward(self, params, tokens):
         """tokens: (B, S) int32 → logits (B·S, vocab) SP-row-sharded."""
+        self._plain_only("forward")
         c = self.config
         b, s = tokens.shape
         x = self._embed_rows(params, tokens)
@@ -1012,6 +1191,7 @@ class Transformer:
         per-layer K/V are captured into the bhsd seq-sharded caches;
         MoE-TP blocks run the overlapped inference engines.
         """
+        self._plain_only("prefill")
         c = self.config
         b, s = tokens.shape
         cap = _cache_capacity(caches)
@@ -1112,6 +1292,7 @@ class Transformer:
         step returns a 4th element, the updated state to thread into
         the next step.
         """
+        self._plain_only("decode_step")
         c = self.config
         from triton_distributed_tpu.layers import append_kv
 
@@ -1239,6 +1420,18 @@ class Transformer:
             return logits, new_caches, new_lens
         return logits, new_caches, new_lens, new_states
 
+    def _dense_mlp(self, xn, w_up, w_down):
+        """The serving step's dense FFN on normed rows ``xn``:
+        ``down(silu(up(x)))``, or gated (``config.gated_ffn``, ``w_up``
+        = [gate | up]) ``down(silu(gate(x)) * up(x))``."""
+        h = self._dmm(xn, w_up, shard="col")
+        if self.config.gated_ffn:
+            f = h.shape[-1] // 2
+            h = jax.nn.silu(h[:, :f]) * h[:, f:]
+        else:
+            h = jax.nn.silu(h)
+        return self._dmm(h, w_down, shard="row")
+
     def _decode_moe_ep(self, blk, xn, state=None):
         """Decode-step EP MoE: the B last-token activations ride the EP
         dispatch → sharded grouped expert MLP → combine machinery, so
@@ -1256,6 +1449,17 @@ class Transformer:
         with jax.named_scope("moe_route"):
             xp = jnp.pad(xn, ((0, pad), (0, 0)))
             logits = xp.astype(jnp.float32) @ blk["router"]
+            if c.routed_assignments:
+                # the model routes (over ALL the router's experts) and
+                # hands the op its share: local ids, the rest masked
+                if c.router == "sigmoid_bias":
+                    w, ids = mu.select_experts_sigmoid_bias(
+                        logits, blk["router_bias"], c.topk,
+                        scale=c.routed_scale)
+                else:
+                    w, ids = mu.select_experts(logits, c.topk)
+                logits = mu.held_assignments(
+                    w, ids, c.first_expert_held, c.local_experts)
         wq = isinstance(blk["moe_up"], dict)
         ctx = self._moe_ep_ctx(
             (b + pad) // shards, inference=True, weights_quantized=wq
@@ -1423,7 +1627,8 @@ class Transformer:
         short request's pages — and its attention work — on rank 0.)"""
         return NamedSharding(self.mesh, P(None, self.tp_axis))
 
-    def init_serving_state(self, slots: int, npages: int, page: int):
+    def init_serving_state(self, slots: int, npages: int, page: int,
+                           chunk: int | None = None):
         """Build a fresh :class:`~triton_distributed_tpu.serving.state.
         ServingState` — the explicit serving-state object replacing the
         ``init_paged_cache``/``paginate_caches`` tuple plumbing for the
@@ -1441,10 +1646,17 @@ class Transformer:
         keeps the stack replicated and shards the attention WALK), the
         table columns split the same way, and one slot's capacity
         grows to ``cp·pages_per_shard·page`` positions — the whole
-        point of long-context serving."""
+        point of long-context serving.
+
+        SLIDING-WINDOW layers (``config.layer_attn``) get ring pools of
+        ``slots · ring`` pages and the state one ``ring_table`` for all
+        of them (serving/state.py); ``chunk``, the most tokens a step
+        appends to one slot, sizes the ring and is required then."""
         from triton_distributed_tpu.serving.state import (
             ServingState,
             fresh_table,
+            ring_pages,
+            ring_table,
         )
 
         c = self.config
@@ -1460,31 +1672,46 @@ class Transformer:
         pps = min(npages, max(1024 // cp, 1)) * cp
         npages = npages * cp
         spec = self._serving_pool_sharding
-        if c.kv_quant is not None:
-            zq = jax.device_put(
-                jnp.zeros((npages, c.n_kv_heads, page, c.head_dim),
-                          jnp.int8),
-                spec,
-            )
-            zs = jax.device_put(
-                jnp.ones((npages, c.n_kv_heads, page), jnp.float32), spec
-            )
+        windowed = c.window_layers
+        ring = 0
+        if windowed:
+            if chunk is None:
+                raise ValueError(
+                    "init_serving_state: a model with sliding-window "
+                    "layers needs chunk= (it sizes their ring pools)")
+            ring = ring_pages(int(chunk), c.window, page)
 
-            def pool():
-                # independent buffers per leaf — the step jit donates
-                return {"q": zq + jnp.int8(0), "scale": zs + 0.0}
-
-            layers = tuple(
-                (pool(), pool()) for _ in range(c.n_layers)
-            )
-        else:
+        def pools(n):
+            """``(make, ...)``: one template of an n-page pool on the
+            device; every call of ``make`` an independent buffer (the
+            step jit donates each leaf)."""
+            if c.kv_quant is not None:
+                zq = jax.device_put(
+                    jnp.zeros((n, c.n_kv_heads, page, c.head_dim),
+                              jnp.int8),
+                    spec,
+                )
+                zs = jax.device_put(
+                    jnp.ones((n, c.n_kv_heads, page), jnp.float32), spec
+                )
+                return lambda: {"q": zq + jnp.int8(0), "scale": zs + 0.0}
             z = jax.device_put(
-                jnp.zeros((npages, c.n_kv_heads, page, c.head_dim),
-                          c.dtype),
+                jnp.zeros((n, c.n_kv_heads, page, c.head_dim), c.dtype),
                 spec,
             )
             zero = jnp.zeros((), c.dtype)
-            layers = tuple((z + zero, z + zero) for _ in range(c.n_layers))
+            return lambda: z + zero
+
+        full = pools(npages)
+        if windowed:
+            # a window layer keeps slots · ring pages and no more
+            ringed = pools(slots * ring)
+            layers = tuple(
+                (ringed(), ringed()) if i in windowed else (full(), full())
+                for i in range(c.n_layers)
+            )
+        else:
+            layers = tuple((full(), full()) for _ in range(c.n_layers))
         return ServingState(
             layers=layers,
             block_table=jnp.asarray(fresh_table(slots, pps)),
@@ -1492,11 +1719,14 @@ class Transformer:
             cursors=jnp.zeros((slots,), jnp.int32),
             page=page,
             cp=cp,
+            ring_table=ring_table(slots, pps, ring) if windowed else None,
+            window_layers=windowed,
+            ring=ring,
         )
 
     def _ragged_attn(self, qp, k_pool, v_pool, state, q_lens, q_starts,
                      block_q, use_pallas, n_bufs=2, topologies=None,
-                     with_lse=False):
+                     with_lse=False, window=None):
         """One layer's ragged paged attention over the (updated) pools
         via the head-sharded serving layer. qp: (Hkv, T·G, D) packed
         GQA rows (already holding this step's tokens in the pools —
@@ -1513,8 +1743,44 @@ class Transformer:
         return layer(
             qp, k_pool, v_pool, state.kv_lens, q_lens, q_starts,
             state.block_table, topologies=topologies, block_q=block_q,
-            n_bufs=n_bufs, with_lse=with_lse,
+            n_bufs=n_bufs, with_lse=with_lse, window=window,
         )
+
+    def _rope_tables(self, token_pos):
+        """``(cos, sin)``, each (T, 1, head_dim) float32, of the packed
+        tokens' sequence positions (padding tokens: position 0) at
+        ``config.rope_theta``, the two halves of the head alike
+        (rotate-half)."""
+        c = self.config
+        half = c.head_dim // 2
+        inv_freq = c.rope_theta ** (
+            -jnp.arange(half, dtype=jnp.float32) / half)
+        ang = jnp.maximum(token_pos, 0).astype(jnp.float32)[:, None] \
+            * inv_freq[None, :]
+        ang = jnp.concatenate([ang, ang], axis=-1)[:, None, :]
+        return jnp.cos(ang), jnp.sin(ang)
+
+    def _qk_norm_rope(self, blk, q, k, rope):
+        """q (T, q_dim), k (T, kv_dim) → the same, each head RMS-normed
+        over its dim (``config.qk_norm``: gains ``norm_q``, ``norm_k``)
+        and, with ``rope`` (this layer rotates), rotated to its token's
+        position. The rotation runs in float32."""
+        c = self.config
+        t = q.shape[0]
+
+        def one(x, gain):
+            x = x.reshape(t, -1, c.head_dim)
+            if c.qk_norm:
+                x = self._rmsnorm(x, blk[gain])
+            if rope is not None:
+                cos, sin = rope
+                xf = x.astype(jnp.float32)
+                x1, x2 = jnp.split(xf, 2, axis=-1)
+                rot = jnp.concatenate([-x2, x1], axis=-1)
+                x = (xf * cos + rot * sin).astype(x.dtype)
+            return x.reshape(t, -1)
+
+        return one(q, "norm_q"), one(k, "norm_k")
 
     def _cp_ragged_attn(self, qp, kp, vp, state, q_lens, q_starts,
                         block_q, use_pallas, n_bufs, topologies):
@@ -1654,7 +1920,10 @@ class Transformer:
         # device scopes (``jax.named_scope``: one component of every
         # operation's ``op_name``, trace-time only): embed, attn_proj,
         # kv_append, attn, dense_ffn, lm_head here; moe_route,
-        # moe_dispatch, moe_gemm, moe_combine in ops/moe.py
+        # moe_dispatch, moe_gemm, moe_combine in ops/moe.py. Two are
+        # NESTED in those, so that a reading of "under none of the ten"
+        # still covers them: qk_rope (q/k norm + rotation) inside
+        # attn_proj, shared_expert inside dense_ffn
         scope = jax.named_scope
         c = self.config
         t = tokens.shape[0]
@@ -1662,7 +1931,9 @@ class Transformer:
         npages = state.npages
         with scope("embed"):
             x = params["embed"][tokens].astype(c.dtype)      # (T, H)
-        with scope("kv_append"):
+        def appender(table, pool_pages):
+            """``(kv_shape, append_layer)`` for the pools ``table``
+            addresses (the block table, or a ring table)."""
             if self.kv_append_by_kernel(use_pallas):
                 # the kernel: one read-modify-write per (slot, page)
                 # run of the packing contract, described ONCE a step
@@ -1671,63 +1942,76 @@ class Transformer:
                     append_units,
                 )
 
-                kv_shape = (t, c.n_kv_heads, c.head_dim)
-                append_layer = functools.partial(
+                return (t, c.n_kv_heads, c.head_dim), functools.partial(
                     self._kv_append_kernel,
                     append_units(
                         q_starts, q_lens,
                         token_pos[jnp.clip(q_starts, 0, t - 1)],
-                        state.block_table, page=page, t=t,
+                        table, page=page, t=t,
                     ),
                 )
-            else:
-                valid = token_pos >= 0
-                pos_c = jnp.maximum(token_pos, 0)
-                local_page = state.block_table[
-                    jnp.clip(token_rows, 0, state.slots - 1),
-                    jnp.clip(pos_c // page, 0, state.pages_per_seq - 1),
-                ]
-                # padding tokens (and unallocated -1 table entries)
-                # scatter out of pool — JAX OOB-scatter drops them
-                pool_idx = jnp.where(
-                    valid & (local_page >= 0), local_page, npages
+            valid = token_pos >= 0
+            pos_c = jnp.maximum(token_pos, 0)
+            local_page = table[
+                jnp.clip(token_rows, 0, state.slots - 1),
+                jnp.clip(pos_c // page, 0, state.pages_per_seq - 1),
+            ]
+            # padding tokens (and unallocated -1 table entries)
+            # scatter out of pool — JAX OOB-scatter drops them
+            pool_idx = jnp.where(
+                valid & (local_page >= 0), local_page, pool_pages
+            )
+            pi = pool_idx[:, None]
+            hi = jnp.arange(c.n_kv_heads)[None, :]
+            oi = (pos_c % page)[:, None]
+            if self.tp == 1:
+                # heads unsharded: append as ONE-index row scatters
+                # over the pool viewed as (npages·Hkv·page, D) rows.
+                # XLA flattens the three-index scatter to exactly
+                # this anyway, but the scatter its pass creates
+                # drops the operation's metadata (the append showed
+                # in a profile with no op_name, nameless); the same
+                # flattening done here compiles to the same two
+                # in-place fusions and keeps ``kv_append`` on them.
+                # An out-of-pool page still lands past the last row
+                # and is dropped.
+                from triton_distributed_tpu.kernels.kv_append import (
+                    append_rows_xla,
                 )
-                pi = pool_idx[:, None]
-                hi = jnp.arange(c.n_kv_heads)[None, :]
-                oi = (pos_c % page)[:, None]
-                if self.tp == 1:
-                    # heads unsharded: append as ONE-index row scatters
-                    # over the pool viewed as (npages·Hkv·page, D) rows.
-                    # XLA flattens the three-index scatter to exactly
-                    # this anyway, but the scatter its pass creates
-                    # drops the operation's metadata (the append showed
-                    # in a profile with no op_name, nameless); the same
-                    # flattening done here compiles to the same two
-                    # in-place fusions and keeps ``kv_append`` on them.
-                    # An out-of-pool page still lands past the last row
-                    # and is dropped.
-                    from triton_distributed_tpu.kernels.kv_append import (
-                        append_rows_xla,
-                    )
 
-                    kv_shape = (t * c.n_kv_heads, c.head_dim)
-                    append = functools.partial(
-                        append_rows_xla,
-                        rows=((pi * c.n_kv_heads + hi) * page + oi)
-                        .reshape(-1),
-                    )
-                else:
-                    # head-sharded pools keep the three-index form:
-                    # their rows do not merge into one sharded dimension
-                    kv_shape = (t, c.n_kv_heads, c.head_dim)
+                kv_shape = (t * c.n_kv_heads, c.head_dim)
+                append = functools.partial(
+                    append_rows_xla,
+                    rows=((pi * c.n_kv_heads + hi) * page + oi)
+                    .reshape(-1),
+                )
+            else:
+                # head-sharded pools keep the three-index form:
+                # their rows do not merge into one sharded dimension
+                kv_shape = (t, c.n_kv_heads, c.head_dim)
 
-                    def append(pool, new):
-                        return pool.at[pi, hi, oi].set(new)
+                def append(pool, new):
+                    return pool.at[pi, hi, oi].set(new)
 
-                def append_layer(kp, vp, k_new, v_new):
-                    # pools and new rows are arrays, or {"q", "scale"}
-                    return (jax.tree.map(append, kp, k_new),
-                            jax.tree.map(append, vp, v_new))
+            def append_layer(kp, vp, k_new, v_new):
+                # pools and new rows are arrays, or {"q", "scale"}
+                return (jax.tree.map(append, kp, k_new),
+                        jax.tree.map(append, vp, v_new))
+
+            return kv_shape, append_layer
+
+        windowed = state.window_layers
+        with scope("kv_append"):
+            # one description of the step's append per KIND of pool:
+            # the global layers' by the block table, the window layers'
+            # by the ring table (same kernel, same packing contract)
+            kv_shape, append_global = appender(state.block_table, npages)
+            if windowed:
+                _, append_ring = appender(
+                    state.ring_table, state.slots * state.ring)
+        if c.rope_layers:
+            with scope("attn_proj"), scope("qk_rope"):
+                rope = self._rope_tables(token_pos)
 
         new_layers = []
         new_states = None if moe_state is None else list(moe_state)
@@ -1741,8 +2025,15 @@ class Transformer:
                 q, k, v = jnp.split(
                     qkv, [c.q_dim, c.q_dim + c.kv_dim], axis=-1
                 )
+                if c.qk_norm or li in c.rope_layers:
+                    with scope("qk_rope"):
+                        q, k = self._qk_norm_rope(
+                            blk, q, k,
+                            rope if li in c.rope_layers else None)
                 k = k.reshape(kv_shape)
                 v = v.reshape(kv_shape)
+            is_window = li in windowed
+            append_layer = append_ring if is_window else append_global
             with scope("kv_append"):
                 if isinstance(kp, dict):
                     from triton_distributed_tpu.kernels.flash_decode \
@@ -1770,12 +2061,24 @@ class Transformer:
                 qp = pack_gqa_rows(
                     q.reshape(t, c.n_heads, c.head_dim), c.n_kv_heads
                 )
-                attn = (self._cp_ragged_attn if state.cp > 1
-                        else self._ragged_attn)
-                o = attn(
-                    qp, kp, vp, state.replace(layers=()), q_lens,
-                    q_starts, block_q, use_pallas, n_bufs, topologies,
-                )
+                if is_window:
+                    # the ring table in the block table's place, and
+                    # the walk bounded below by the window
+                    o = self._ragged_attn(
+                        qp, kp, vp,
+                        state.replace(layers=(),
+                                      block_table=state.ring_table),
+                        q_lens, q_starts, block_q, use_pallas, n_bufs,
+                        topologies, window=c.window,
+                    )
+                else:
+                    attn = (self._cp_ragged_attn if state.cp > 1
+                            else self._ragged_attn)
+                    o = attn(
+                        qp, kp, vp, state.replace(layers=()), q_lens,
+                        q_starts, block_q, use_pallas, n_bufs,
+                        topologies,
+                    )
                 o = unpack_gqa_rows(o, c.n_heads).reshape(t, c.q_dim)
             with scope("attn_proj"):
                 x = x + self._dmm(o.astype(c.dtype), blk["wo"],
@@ -1783,9 +2086,7 @@ class Transformer:
             if "up" in blk:
                 with scope("dense_ffn"):
                     xn = self._rmsnorm(x, blk["norm_mlp"])
-                    h = jax.nn.silu(
-                        self._dmm(xn, blk["up"], shard="col"))
-                    x = x + self._dmm(h, blk["down"], shard="row")
+                    x = x + self._dense_mlp(xn, blk["up"], blk["down"])
             elif c.moe == "ep":
                 with scope("moe_route"):
                     xn = self._rmsnorm(x, blk["norm_mlp"])
@@ -1793,6 +2094,12 @@ class Transformer:
                 y, st = self._decode_moe_ep(blk, xn, st)
                 with scope("moe_combine"):
                     x = x + y.astype(x.dtype)
+                if "shared_up" in blk:
+                    # the shared expert: dense, every token, counted
+                    # once whatever share of the routed experts is here
+                    with scope("dense_ffn"), scope("shared_expert"):
+                        x = x + self._dense_mlp(
+                            xn, blk["shared_up"], blk["shared_down"])
                 if new_states is not None:
                     new_states[li] = st
             else:

@@ -99,6 +99,17 @@ class EPMoEContext:
     # alignment-padding tax grows with block_m — measured 292 vs 356 µs
     # per decode up-GEMM against W8A16 at bm=64, docs/PERF.md).
     act_quant: str | None = None
+    # gated expert MLP: ``w_up`` is (epr, H, 2F), gate columns first —
+    # ONE grouped GEMM gives both halves and the hidden activation is
+    # ``act(gate) * up`` (three matrices an expert, SwiGLU). False: the
+    # two-matrix ``down(act(up(x)))``.
+    gated: bool = False
+    # a chip's SHARE of a wider expert-parallel layer: most assignments
+    # arrive masked (the sentinel), so the grouped GEMM's trailing dummy
+    # blocks outnumber the real ones; True stores them as zeros without
+    # fetching or multiplying a weight (``grouped_matmul(dummy_expert=)``,
+    # Pallas GEMM with un-quantized weights).
+    skip_masked: bool = False
 
     @property
     def n(self) -> int:
@@ -159,6 +170,10 @@ def create_ep_moe_context(
         )
     if ctx.act_quant not in (None, "int8"):
         raise ValueError(f"act_quant must be None or 'int8', got {ctx.act_quant!r}")
+    if ctx.act_quant is not None and (ctx.gated or ctx.skip_masked):
+        raise ValueError(
+            "gated / skip_masked expert MLPs are built for bf16 and "
+            "weight-only-quantized GEMMs, not W8A8 (act_quant)")
     if ctx.transport == "fused" and ctx.dcn_axis is not None:
         raise ValueError(
             "the fused window-DMA transport is flat (single-slice) only; "
@@ -357,6 +372,13 @@ def _expert_mlp(ctx: EPMoEContext, rows, eid, valid, w_up, w_down):
     # are zero so the product is zero regardless
     be_w = jnp.clip(be, 0, epr - 1)
 
+    def act(h):
+        # gated: the GEMM gave [gate | up]; the hidden is act(gate)·up
+        if not ctx.gated:
+            return _act(ctx.activation, h)
+        f = h.shape[1] // 2
+        return _act(ctx.activation, h[:, :f]) * h[:, f:]
+
     if ctx.use_pallas_gemm:
         gg_kw = {}
         if ctx.gg_block_n is not None:
@@ -374,6 +396,10 @@ def _expert_mlp(ctx: EPMoEContext, rows, eid, valid, w_up, w_down):
                     inp, w["q"], be_w, w_scale=w["scale"],
                     block_m=ctx.block_m, **gg_kw,
                 )
+            if ctx.skip_masked:
+                # the dummy tail keeps its own id: never multiplied
+                return grouped_matmul(inp, w, be, block_m=ctx.block_m,
+                                      dummy_expert=epr, **gg_kw)
             return grouped_matmul(inp, w, be_w, block_m=ctx.block_m, **gg_kw)
 
         if (
@@ -401,7 +427,7 @@ def _expert_mlp(ctx: EPMoEContext, rows, eid, valid, w_up, w_down):
             y = gg8(hq, hsc, w_down)
         else:
             h = gg(xs, w_up)
-            h = _act(ctx.activation, h).astype(ctx.dtype)
+            h = act(h).astype(ctx.dtype)
             y = gg(h, w_down)
     else:
         from triton_distributed_tpu.kernels.group_gemm import (
@@ -421,7 +447,7 @@ def _expert_mlp(ctx: EPMoEContext, rows, eid, valid, w_up, w_down):
         gs_all = padded_splits(counts, ctx.block_m, cap)
         gs = gs_all[:epr].at[-1].add(gs_all[epr])
         h = jax.lax.ragged_dot(xs, w_up, gs)
-        h = _act(ctx.activation, h).astype(ctx.dtype)
+        h = act(h).astype(ctx.dtype)
         y = jax.lax.ragged_dot(h, w_down, gs)
     # no post-GEMM re-masking: invalid/slack rows entered the GEMMs as
     # exact zeros (xs above), so their outputs are exact zeros — the
@@ -715,9 +741,11 @@ def ep_moe_device(x, logits, w_up, w_down, ctx: EPMoEContext, state=None,
                   instance=0):
     """Per-device EP MoE body — callable inside any shard_map.
 
-    x: (M, H) this rank's tokens; logits: (M, E); w_up: (epr, H, F),
-    w_down: (epr, F, H) — this rank's experts. Returns (M, H), plus the
-    updated LL workspace dict when ``state`` is given.
+    x: (M, H) this rank's tokens; logits: (M, E) — or, pre-routed, the
+    pair ``(flat_e (M·topk,) int32, w_flat (M·topk,) f32)`` of
+    :func:`_ep_assignments_device`; w_up: (epr, H, F) ((epr, H, 2F)
+    gated), w_down: (epr, F, H) — this rank's experts. Returns (M, H),
+    plus the updated LL workspace dict when ``state`` is given.
     """
     assert ctx.transport in ("fused", "pallas", "xla"), (
         f"unresolved transport {ctx.transport!r} — build contexts via "
@@ -731,13 +759,22 @@ def ep_moe_device(x, logits, w_up, w_down, ctx: EPMoEContext, state=None,
             "ep_moe_device state= rides the flat fused transport only "
             f"(got transport={ctx.transport!r}, dcn_axis={ctx.dcn_axis!r})"
         )
-    if ctx.dcn_axis is not None:
-        return _ep_moe_hier_device(x, logits, w_up, w_down, ctx)
-    with jax.named_scope("moe_route"):
-        weights, ids = mu.select_experts(logits, ctx.topk)
+    if isinstance(logits, tuple):
+        # PRE-ROUTED (``ep_moe(routed=)``): the caller's router chose;
+        # flat exchange-local ids, the sentinel ``ctx.num_experts`` with
+        # weight exactly 0 for an assignment that is not this layer's
+        if ctx.dcn_axis is not None:
+            raise ValueError("pre-routed ep_moe rides the flat exchange")
+        flat_e, w_flat = logits
+    else:
+        if ctx.dcn_axis is not None:
+            return _ep_moe_hier_device(x, logits, w_up, w_down, ctx)
+        with jax.named_scope("moe_route"):
+            weights, ids = mu.select_experts(logits, ctx.topk)
+        flat_e = ids.reshape(-1).astype(jnp.int32)
+        w_flat = weights.reshape(-1).astype(jnp.float32)
     res = _ep_assignments_device(
-        ctx, x, ids.reshape(-1).astype(jnp.int32),
-        weights.reshape(-1).astype(jnp.float32), x.shape[0], w_up, w_down,
+        ctx, x, flat_e, w_flat, x.shape[0], w_up, w_down,
         state=state, instance=instance,
     )
     if state is not None:
@@ -793,6 +830,13 @@ def ep_moe(x, logits, w_up, w_down, ctx: EPMoEContext, state=None):
     Global shapes: x (M, H) and logits (M, E) token-sharded over
     ``ctx.axis``; w_up (E, H, F) / w_down (E, F, H) expert-sharded over
     ``ctx.axis``. Returns (M, H) token-sharded.
+
+    PRE-ROUTED: pass ``logits`` as the pair ``(flat_e (M·topk,) int32,
+    w_flat (M·topk,) f32)`` — the caller's own router (a sigmoid router
+    with a selection bias, a chip's share of a wider layer:
+    ``moe_utils.held_assignments``); ``flat_e`` are ids local to
+    ``ctx.num_experts`` experts, the sentinel ``ctx.num_experts`` (weight
+    exactly 0) for an assignment that is not this layer's to compute.
 
     With ``state`` (an :class:`EPMoEState` from
     :func:`create_ep_moe_state`): the fused transport runs BARRIER-FREE

@@ -39,6 +39,7 @@ over onto the survivor).
 
 from __future__ import annotations
 
+import logging
 import time
 from collections import deque
 from dataclasses import dataclass, field
@@ -268,6 +269,13 @@ class EngineStats:
     # its XLA twin, the row scatter (head-sharded pools, use_pallas off)
     append_runs: int = 0
     append_scatter_steps: int = 0
+    # pages the ragged kernel walks, per attention layer of the kind,
+    # summed over the batched rows of the device steps (counted in
+    # ``_assemble`` from each row's cursor and take): a GLOBAL layer
+    # walks every page up to the row's length, a sliding-WINDOW layer
+    # only those from its window's first key on (0 without such layers)
+    global_pages_walked: int = 0
+    window_pages_walked: int = 0
     prefix_hits: int = 0               # pages reattached from the cache
     # --- in-batch shared-prefix dedup (EngineConfig.prefix_share) ---
     shared_prefix_rows: int = 0        # batched rows marked SHARED_PREFIX
@@ -460,9 +468,24 @@ class ServingEngine:
         self.health = health if health is not None else HealthLedger(
             seed=cfg.seed)
         self.health_peer = health_peer
+        # sliding-window layers keep ring pools (serving/state.py); what
+        # a ring cannot serve yet is refused here, by name
+        self._window = int(model.config.window) \
+            if model.config.window_layers else 0
+        if self._window:
+            self._refuse_beside_window(cfg)
         self.state = model.init_serving_state(
-            cfg.slots, cfg.npages, cfg.page
+            cfg.slots, cfg.npages, cfg.page, chunk=cfg.chunk
         )
+        if self._window:
+            st = self.state
+            logging.getLogger(__name__).info(
+                "serving state: %d global layer(s) x %d pages, %d window "
+                "layer(s) x %d slots x ring %d = %d pages (window %d, "
+                "chunk %d, page %d)",
+                len(st.layers) - len(st.window_layers), st.npages,
+                len(st.window_layers), cfg.slots, st.ring,
+                cfg.slots * st.ring, self._window, cfg.chunk, cfg.page)
         self._jnp = jnp
         pps = self.state.pages_per_seq
         self.table = np.full((cfg.slots, pps), -1, np.int32)
@@ -496,6 +519,7 @@ class ServingEngine:
         self.stats = EngineStats()
         self.step_count = 0
         self._append_runs = 0           # of the batch last assembled
+        self._pages_walked = [0, 0]     # likewise: [global, window]
         # seconds of the running step inside each phase (``_Phase``)
         self._phase_s = dict.fromkeys(PHASES, 0.0)
         # --- multi-tenancy (all defaults reproduce the single-tenant
@@ -585,6 +609,26 @@ class ServingEngine:
                 "descriptors, and the cp shard loop overwrites the "
                 "topology row with its per-shard frontier shift"
             )
+
+    def _refuse_beside_window(self, cfg) -> None:
+        """A ring pool holds a slot's last ``chunk + window`` positions
+        and nothing else: what would read or keep an older page of a
+        window layer is not built, and raises here."""
+        if cfg.prefix_cache:
+            raise ValueError(
+                "sliding-window layers with prefix_cache=True (and with "
+                "it prefix_share / SHARED_PREFIX rows): a cached prefix "
+                "page of a window layer is overwritten by its ring")
+        if self._spec_key() != (0, 0):
+            raise ValueError(
+                "sliding-window layers under SpeculativeEngine: a "
+                "rejected draft rolls the cursor back over ring pages "
+                "the draft has already overwritten")
+        if cfg.prefill_only:
+            raise ValueError(
+                "sliding-window layers with prefill_only (the prefill "
+                "role of DisaggregatedEngine): kv_ship ships pages by "
+                "the global block table, which does not address a ring")
 
     def _spec_key(self) -> tuple:
         """Speculation coordinates appended to the grid-schedule traffic
@@ -852,6 +896,7 @@ class ServingEngine:
         topo = causal_topologies(R, topo_w)
         next_start = 0
         self._append_runs = 0
+        self._pages_walked = [0, 0]     # [global, window], one layer each
         batched: set = set()
         takes: dict = {}
         for s in range(R):
@@ -882,6 +927,10 @@ class ServingEngine:
                 kv_dev[s] = req.cursor + take
                 # the span's pages: the cursor's own up to the last held
                 self._append_runs += need - req.cursor // cfg.page
+                self._pages_walked[0] += need
+                if self._window:
+                    self._pages_walked[1] += need - max(
+                        req.cursor - self._window + 1, 0) // cfg.page
                 next_start += _ceil8(take)
                 batched.add(s)
                 takes[s] = take
@@ -949,6 +998,8 @@ class ServingEngine:
                 self.stats.append_runs += self._append_runs
             else:
                 self.stats.append_scatter_steps += 1
+            self.stats.global_pages_walked += self._pages_walked[0]
+            self.stats.window_pages_walked += self._pages_walked[1]
             if self.moe_state is None:
                 logits, self.state = out
             else:
@@ -1206,6 +1257,11 @@ class ServingEngine:
         ``kv_quant``)."""
         import jax.numpy as jnp
 
+        if self._window:
+            raise ValueError(
+                "kv_ship / page migration with sliding-window layers: "
+                "pages ship by the global block table, which does not "
+                "address a ring pool")
         gather, _ = self._kv_wire_jits()
         return gather(self.state.layers,
                       jnp.asarray(list(pids), jnp.int32))
@@ -1356,6 +1412,12 @@ class DisaggregatedEngine:
 
         if transport not in ("auto", "dcn", "xla"):
             raise ValueError(f"unknown transport {transport!r}")
+        for m in (prefill_model, decode_model):
+            if m.config.window_layers:
+                raise ValueError(
+                    "DisaggregatedEngine with sliding-window layers: "
+                    "kv_ship ships pages by the global block table, "
+                    "which does not address a ring pool")
         if transport == "auto":
             transport = "dcn" if hybrid_mesh is not None else "xla"
         if transport == "dcn" and hybrid_mesh is None:

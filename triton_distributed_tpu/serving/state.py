@@ -24,6 +24,17 @@ placement story:
   scheduler's cursor so an evicted request's resume point travels with
   the state object.
 
+* **ring pools** (PR 29) — a model with SLIDING-WINDOW attention
+  layers (``TransformerConfig.layer_attn``) gives each of them a pool
+  of its own, ``(slots · ring, Hkv, page, D)``: slot ``s`` owns pages
+  ``[s·ring, (s+1)·ring)`` for good and writes logical page ``j`` of
+  its sequence to page ``s·ring + j % ring``. ``ring_table`` states
+  exactly that in the block table's shape, built once on the device;
+  it is arithmetic, not allocation — no host allocator, no upload, and
+  eviction or preemption owe it nothing, because a ring holds nothing
+  a recompute does not rewrite before it reads. ``ring_pages`` is the
+  least ``ring`` whose live positions never alias.
+
 The object is a pytree (``jax.tree_util``): the serving-step jit
 donates it whole, and with the pool placements pinned the per-step
 append aliases in place — no pool-sized copy per step.
@@ -55,6 +66,12 @@ class ServingState:
     # long request's KV spreads over every shard while the table keeps
     # GLOBAL ids and the scatter-append stays shard-oblivious.
     cp: int = 1
+    # sliding-window layers (static indices into ``layers``): their
+    # pools are rings of ``ring`` pages a slot addressed by
+    # ``ring_table`` (slots, pages_per_seq), not by ``block_table``
+    ring_table: object = None
+    window_layers: tuple = ()
+    ring: int = 0
 
     def replace(self, **kw) -> "ServingState":
         return _dc_replace(self, **kw)
@@ -74,7 +91,16 @@ class ServingState:
 
     @property
     def npages(self) -> int:
-        k0 = self.layers[0][0]
+        """Pages of a GLOBAL layer's pool (what ``block_table`` and the
+        host allocator address); a window layer's holds
+        ``slots · ring``."""
+        i = next((i for i in range(len(self.layers))
+                  if i not in self.window_layers), 0)
+        return self.layer_pages(i)
+
+    def layer_pages(self, i: int) -> int:
+        """Pages of layer ``i``'s pool."""
+        k0 = self.layers[i][0]
         return int((k0["q"] if isinstance(k0, dict) else k0).shape[0])
 
     @property
@@ -85,20 +111,41 @@ class ServingState:
 
 def _flatten(s: ServingState):
     return (
-        (s.layers, s.block_table, s.kv_lens, s.cursors),
-        (s.page, s.cp),
+        (s.layers, s.block_table, s.kv_lens, s.cursors, s.ring_table),
+        (s.page, s.cp, s.window_layers, s.ring),
     )
 
 
 def _unflatten(aux, children):
-    layers, table, lens, cursors = children
+    layers, table, lens, cursors, ring_table = children
     return ServingState(
         layers=layers, block_table=table, kv_lens=lens, cursors=cursors,
-        page=aux[0], cp=aux[1],
+        page=aux[0], cp=aux[1], ring_table=ring_table,
+        window_layers=aux[2], ring=aux[3],
     )
 
 
 jax.tree_util.register_pytree_node(ServingState, _flatten, _unflatten)
+
+
+def ring_pages(chunk: int, window: int, page: int) -> int:
+    """Pages of one slot's ring: a step appends at most ``chunk``
+    tokens and then attends back ``window - 1`` positions from the first
+    of them, so ``chunk + window - 1`` consecutive positions are live at
+    once; they lie in at most ``ceil((chunk + window - 1) / page) + 1``
+    pages (a run that starts at a page's last row), and a ring that
+    long maps them to distinct pages."""
+    return -(-(chunk + window - 1) // page) + 1
+
+
+def ring_table(slots: int, pages_per_seq: int, ring: int):
+    """``(slots, pages_per_seq)`` int32, ``[s, j] = s·ring + j % ring``:
+    the block table of a ring pool, made on the device."""
+    import jax.numpy as jnp
+
+    s = jnp.arange(slots, dtype=jnp.int32)[:, None]
+    j = jnp.arange(pages_per_seq, dtype=jnp.int32)[None, :]
+    return s * ring + j % ring
 
 
 def fresh_table(slots: int, pages_per_seq: int) -> np.ndarray:
