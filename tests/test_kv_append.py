@@ -10,7 +10,8 @@ run names. The kernel relies on the engine's packing contract (one
 its own property test; and whole engines must serve the same token
 streams by either path. Last, the kernel compiles for the chip at both
 benchmark cells' real pool shapes (AOT, no chip: skipped where the
-topology cannot be described).
+topology cannot be described) — and so does the grouped GEMM with a
+dummy tail, which shares the fixture that loads libtpu.
 """
 
 import jax
@@ -397,3 +398,50 @@ def test_kernel_compiles_for_the_chip_at_the_cells_pool_shapes(
     finally:
         config.force_compile = old
     assert compiled.memory_analysis().temp_size_in_bytes < 64 << 20
+
+
+@pytest.mark.parametrize(
+    "cap,bm,e,k,n,quant",
+    [(12928, 128, 64, 2048, 1408, "w8a8"),
+     (12928, 64, 64, 1408, 2048, "w8a16"),
+     (3840, 256, 8, 4096, 14336, None)],
+    ids=["dsmoe16b_w8a8_resident_up", "dsmoe16b_w8a16_resident_down",
+         "mixtral8x7b_bf16_tiled_up"])
+def test_grouped_matmul_with_a_dummy_tail_compiles_for_the_chip(
+        one_chip, cap, bm, e, k, n, quant):
+    """Mosaic accepts all three grouped-GEMM kernels with
+    ``dummy_expert`` (a predicated multiply, index maps pinned by a
+    prefetched block id) at the cells' expert-layer shapes. Here, not
+    in a file of its own: one test file holds the fixture that loads
+    libtpu."""
+    from triton_distributed_tpu.config import config, fused_vmem_budget
+    from triton_distributed_tpu.kernels.group_gemm import grouped_matmul
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    kw = dict(block_m=bm, dummy_expert=e)
+    wdt = jnp.bfloat16 if quant is None else jnp.int8
+    args = [arg((cap, k), jnp.int8 if quant == "w8a8" else jnp.bfloat16),
+            arg((e, k, n), wdt), arg((cap // bm,), jnp.int32)]
+    names = []
+    if quant is not None:            # the experts stay in fast memory
+        kw.update(block_n=1 << 30, block_k=1 << 30,
+                  vmem_limit_bytes=fused_vmem_budget())
+        args.append(arg((e, n), jnp.float32))
+        names.append("w_scale")
+    if quant == "w8a8":
+        args.append(arg((cap, 1), jnp.float32))
+        names.append("x_scale")
+
+    def fn(x, w, be, *scales):
+        return grouped_matmul(x, w, be, **dict(zip(names, scales)), **kw)
+
+    old = config.force_compile
+    config.force_compile = True
+    try:
+        lowered = jax.jit(fn).lower(*args)
+        assert "tpu_custom_call" in lowered.as_text()
+        lowered.compile()
+    finally:
+        config.force_compile = old

@@ -27,6 +27,8 @@ from benchmark.models import exaone_moe as ref  # noqa: E402
 from triton_distributed_tpu.kernels import moe_utils as mu  # noqa: E402
 from triton_distributed_tpu.kernels.group_gemm import (  # noqa: E402
     grouped_matmul,
+    quantize_act_rows,
+    quantize_grouped_weights,
 )
 from triton_distributed_tpu.kernels.ragged_paged_attention import (  # noqa: E402
     _build_ragged,
@@ -238,24 +240,24 @@ def test_sigmoid_router_and_held_assignments():
     assert bool(jnp.all((w_flat.reshape(ids.shape) == 0) == ~mine))
 
 
-def test_dummy_blocks_are_stored_as_zeros_without_a_multiply():
-    """``grouped_matmul(dummy_expert=)``: the blocks of real experts as
-    without it, the trailing dummy blocks exact zeros whatever rows
-    they hold."""
+@pytest.mark.parametrize("quant", [None, "w8a16", "w8a8"])
+def test_dummy_blocks_are_stored_as_zeros_without_a_multiply(quant):
+    """``grouped_matmul(dummy_expert=)``, all three kernels: the blocks
+    of real experts bit for bit as without it, the trailing dummy
+    blocks exact zeros whatever rows they hold."""
     rng = np.random.default_rng(0)
     x = jnp.asarray(rng.standard_normal((48, 16)), jnp.float32)
     w = jnp.asarray(rng.standard_normal((2, 16, 32)), jnp.float32)
     be = jnp.asarray([0, 1, 1, 2, 2, 2], jnp.int32)
-    got = grouped_matmul(x, w, be, block_m=8, block_n=32, block_k=16,
-                         dummy_expert=2)
-    want = grouped_matmul(x, w, jnp.minimum(be, 1), block_m=8, block_n=32,
-                          block_k=16)
+    kw = dict(block_m=8, block_n=32, block_k=16)
+    if quant is not None:
+        w, kw["w_scale"] = quantize_grouped_weights(w, "int8")
+    if quant == "w8a8":
+        x, kw["x_scale"] = quantize_act_rows(x)
+    got = grouped_matmul(x, w, be, dummy_expert=2, **kw)
+    want = grouped_matmul(x, w, jnp.minimum(be, 1), **kw)
     np.testing.assert_array_equal(got[:24], want[:24])
     assert not np.asarray(got[24:]).any() and np.asarray(want[24:]).any()
-    with pytest.raises(ValueError, match="un-quantized"):
-        grouped_matmul(x.astype(jnp.int8), w.astype(jnp.int8), be,
-                       w_scale=jnp.ones((2, 32)), block_m=8,
-                       dummy_expert=2)
 
 
 # --------------------------------------------------- windowed kernel

@@ -29,6 +29,16 @@ from jax.experimental.pallas import tpu as pltpu
 from triton_distributed_tpu.config import local_interpret
 
 
+def _mac_if_live(be_ref, dummy_expert, mac):
+    """Run ``mac`` unless this M-block is a dummy one: a dummy block
+    multiplies nothing, and the zeros of its accumulator are stored as
+    they are (the scales of the quantized epilogues are finite)."""
+    if dummy_expert is None:
+        mac()
+    else:
+        pl.when(be_ref[pl.program_id(0)] < dummy_expert)(mac)
+
+
 def _ggemm_kernel(nsteps_k, be_ref, x_ref, w_ref, o_ref, acc_ref,
                   dummy_expert=None):
     kk = pl.program_id(2)
@@ -44,11 +54,7 @@ def _ggemm_kernel(nsteps_k, be_ref, x_ref, w_ref, o_ref, acc_ref,
             preferred_element_type=jnp.float32,
         )
 
-    if dummy_expert is None:
-        _mac()
-    else:
-        # a dummy block multiplies nothing: its zeros are stored as is
-        pl.when(be_ref[pl.program_id(0)] < dummy_expert)(_mac)
+    _mac_if_live(be_ref, dummy_expert, _mac)
 
     @pl.when(kk == nsteps_k - 1)
     def _store():
@@ -56,7 +62,7 @@ def _ggemm_kernel(nsteps_k, be_ref, x_ref, w_ref, o_ref, acc_ref,
 
 
 def _ggemm_q_kernel(nsteps_k, xdt, be_ref, x_ref, w_ref, s_ref, o_ref,
-                    acc_ref):
+                    acc_ref, dummy_expert=None):
     """Weight-only-quantized variant: W rides HBM in its 1-byte wire
     dtype (int8 / fp8) and is widened tile-by-tile in VMEM; the
     per-(expert, out-channel) scale multiplies the f32 accumulator once
@@ -68,11 +74,14 @@ def _ggemm_q_kernel(nsteps_k, xdt, be_ref, x_ref, w_ref, s_ref, o_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    acc_ref[:] += jax.lax.dot_general(
-        x_ref[:], w_ref[0].astype(xdt),
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-    )
+    def _mac():
+        acc_ref[:] += jax.lax.dot_general(
+            x_ref[:], w_ref[0].astype(xdt),
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.float32,
+        )
+
+    _mac_if_live(be_ref, dummy_expert, _mac)
 
     @pl.when(kk == nsteps_k - 1)
     def _store():
@@ -80,7 +89,7 @@ def _ggemm_q_kernel(nsteps_k, xdt, be_ref, x_ref, w_ref, s_ref, o_ref,
 
 
 def _ggemm_q8a_kernel(nsteps_k, be_ref, x_ref, w_ref, xs_ref, ws_ref,
-                      o_ref, acc_ref):
+                      o_ref, acc_ref, dummy_expert=None):
     """W8A8 variant: BOTH operands ride int8 and the MXU runs its
     native s8×s8→s32 path (measured 320–350 TOP/s on a v5e — 2× the
     bf16 rate), with the rank-1 scale correction
@@ -93,11 +102,14 @@ def _ggemm_q8a_kernel(nsteps_k, be_ref, x_ref, w_ref, xs_ref, ws_ref,
     def _init():
         acc_ref[:] = jnp.zeros_like(acc_ref)
 
-    acc_ref[:] += jax.lax.dot_general(
-        x_ref[:], w_ref[0],
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.int32,
-    )
+    def _mac():
+        acc_ref[:] += jax.lax.dot_general(
+            x_ref[:], w_ref[0],
+            (((1,), (0,)), ((), ())),
+            preferred_element_type=jnp.int32,
+        )
+
+    _mac_if_live(be_ref, dummy_expert, _mac)
 
     @pl.when(kk == nsteps_k - 1)
     def _store():
@@ -176,14 +188,15 @@ def grouped_matmul(
     HBM reads), so doubling the MXU rate is the remaining lever.
     ``out_dtype`` defaults to bf16 here (int8 out makes no sense).
 
-    ``dummy_expert`` (un-quantized weights only): M-blocks whose
+    ``dummy_expert`` (all three kernels): M-blocks whose
     ``block_expert`` is ``>= dummy_expert`` hold no row of any expert
     (the trailing group of ``moe_align_block_size`` and its slack).
-    They are stored as zeros without a multiply, and every step of such
-    a block names the SAME weight and activation tile, so the pipeline
-    fetches one tile for a whole run of them instead of streaming an
-    expert's matrix per block. None: every block is multiplied (the
-    caller clamps ``block_expert`` and feeds zero rows).
+    They are stored as zeros without a multiply, whatever their rows
+    hold, and every step of such a block names the SAME tile of the
+    weight, the activations and both scales, so the pipeline fetches
+    one tile for a whole run of them instead of streaming an expert's
+    matrix per block. None: every block is multiplied (``block_expert``
+    must name a real expert everywhere).
     """
     from triton_distributed_tpu.config import compiling_for_tpu
     from triton_distributed_tpu.kernels.ag_gemm import _divisor_block
@@ -199,38 +212,37 @@ def grouped_matmul(
     nsteps_k = kdim // block_k
 
     if dummy_expert is None:
-        in_specs = [
-            pl.BlockSpec((block_m, block_k), lambda m, n, k, be: (m, k)),
-            pl.BlockSpec(
-                (1, block_k, block_n), lambda m, n, k, be: (be[m], k, n)
-            ),
-        ]
+        kernel_kw = {}
+
+        def owner(m, be):
+            return be[m]
+
+        def tile(m, be, *idx):
+            return idx
     else:
-        if w_scale is not None:
-            raise ValueError(
-                "grouped_matmul: dummy_expert is built for un-quantized "
-                "weights only")
+        kernel_kw = {"dummy_expert": int(dummy_expert)}
 
-        def live(m, be):
-            return (be[m] < dummy_expert).astype(jnp.int32)
+        def owner(m, be):
+            return jnp.minimum(be[m], e - 1)
 
-        in_specs = [
-            pl.BlockSpec(
-                (block_m, block_k),
-                lambda m, n, k, be: (m, k * live(m, be))),
-            pl.BlockSpec(
-                (1, block_k, block_n),
-                lambda m, n, k, be: (jnp.minimum(be[m], e - 1),
-                                     k * live(m, be), n * live(m, be)),
-            ),
-        ]
+        def tile(m, be, *idx):
+            # every step of a dummy block names tile 0 of each operand:
+            # a run of them fetches it once
+            live = (be[m] < dummy_expert).astype(jnp.int32)
+            return tuple(i * live for i in idx)
+
+    in_specs = [
+        pl.BlockSpec((block_m, block_k),
+                     lambda m, n, k, be: tile(m, be, m, k)),
+        pl.BlockSpec(
+            (1, block_k, block_n),
+            lambda m, n, k, be: (owner(m, be), *tile(m, be, k, n)),
+        ),
+    ]
     acc_dtype = jnp.float32
     if w_scale is None:
         assert x_scale is None, "x_scale requires w_scale (W8A8 mode)"
-        kernel = functools.partial(_ggemm_kernel, nsteps_k)
-        if dummy_expert is not None:
-            kernel = functools.partial(
-                _ggemm_kernel, nsteps_k, dummy_expert=int(dummy_expert))
+        kernel = functools.partial(_ggemm_kernel, nsteps_k, **kernel_kw)
         args = (block_expert, x_sorted, w)
     else:
         assert w.dtype.itemsize == 1, (
@@ -242,12 +254,13 @@ def grouped_matmul(
         # Mosaic accepts where a (1, block_n) slice of (E, N) is rejected
         ws3 = w_scale.astype(jnp.float32)[:, None, :]
         ws_spec = pl.BlockSpec(
-            (1, 1, block_n), lambda m, n, k, be: (be[m], 0, n)
+            (1, 1, block_n),
+            lambda m, n, k, be: (owner(m, be), 0, *tile(m, be, n)),
         )
         if x_scale is None:
             in_specs.append(ws_spec)
             kernel = functools.partial(
-                _ggemm_q_kernel, nsteps_k, x_sorted.dtype
+                _ggemm_q_kernel, nsteps_k, x_sorted.dtype, **kernel_kw
             )
             args = (block_expert, x_sorted, w, ws3)
         else:
@@ -256,10 +269,12 @@ def grouped_matmul(
             )
             assert x_scale.shape == (cap, 1), (x_scale.shape, (cap, 1))
             in_specs.append(
-                pl.BlockSpec((block_m, 1), lambda m, n, k, be: (m, 0))
+                pl.BlockSpec((block_m, 1),
+                             lambda m, n, k, be: (*tile(m, be, m), 0))
             )
             in_specs.append(ws_spec)
-            kernel = functools.partial(_ggemm_q8a_kernel, nsteps_k)
+            kernel = functools.partial(
+                _ggemm_q8a_kernel, nsteps_k, **kernel_kw)
             args = (
                 block_expert, x_sorted, w,
                 x_scale.astype(jnp.float32), ws3,

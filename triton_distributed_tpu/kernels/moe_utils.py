@@ -61,14 +61,18 @@ def select_experts_sigmoid_bias(gate_logits, bias, topk: int, *,
     return w, ids.astype(jnp.int32)
 
 
-def held_assignments(weights, ids, first: int, held: int):
+def held_assignments(weights, ids, first: int, held: int, rows=None):
     """One chip's SHARE of routed assignments: ``ids`` over the whole
     router become ids local to the ``held`` experts that start at
     expert ``first``; every other assignment becomes the sentinel
     ``held`` with weight exactly 0 — what ``ops.moe`` sorts to the tail
-    and neither stages nor multiplies. Flat ``(M·k,)`` pairs."""
+    and neither stages nor multiplies. ``rows`` (M,) bool: the rows that
+    are tokens; every assignment of another row (a step's padding) is
+    the sentinel too. Flat ``(M·k,)`` pairs."""
     local = ids.astype(jnp.int32) - first
     mine = (local >= 0) & (local < held)
+    if rows is not None:
+        mine &= rows[:, None]
     return (jnp.where(mine, local, held).reshape(-1),
             jnp.where(mine, weights.astype(jnp.float32), 0.0).reshape(-1))
 

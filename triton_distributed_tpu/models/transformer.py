@@ -523,10 +523,6 @@ class Transformer:
             act_quant=a8,
             batch_axes=tuple(self.dp_axes),
             gated=c.gated_ffn,
-            # most assignments of a share arrive masked: their blocks
-            # are not multiplied
-            skip_masked=bool(
-                c.experts_held and pallas_ok and wq_mode is None),
         )
 
     # ---------------------------------------------------------------- params
@@ -1432,7 +1428,7 @@ class Transformer:
             h = jax.nn.silu(h)
         return self._dmm(h, w_down, shard="row")
 
-    def _decode_moe_ep(self, blk, xn, state=None):
+    def _decode_moe_ep(self, blk, xn, state=None, row_mask=None):
         """Decode-step EP MoE: the B last-token activations ride the EP
         dispatch → sharded grouped expert MLP → combine machinery, so
         expert weights STAY sharded — no gathered (B, H, F) weight
@@ -1441,7 +1437,11 @@ class Transformer:
         low_latency_all_to_all.py:36-118). B is padded up to the token
         -shard count; pad rows are discarded after the combine. With
         ``state``, the transport runs barrier-free over the persistent
-        workspaces; returns (y, state')."""
+        workspaces; returns (y, state'). ``row_mask`` (B,) bool: the
+        rows that are tokens of the step — the assignments of every
+        other row (a serving step's padding) are handed to the op
+        masked, so they are neither shipped nor multiplied; their ``y``
+        is zero. None: every row is a token."""
         c = self.config
         b = xn.shape[0]
         shards = self.token_shards
@@ -1449,9 +1449,10 @@ class Transformer:
         with jax.named_scope("moe_route"):
             xp = jnp.pad(xn, ((0, pad), (0, 0)))
             logits = xp.astype(jnp.float32) @ blk["router"]
-            if c.routed_assignments:
+            if c.routed_assignments or row_mask is not None:
                 # the model routes (over ALL the router's experts) and
-                # hands the op its share: local ids, the rest masked
+                # hands the op its assignments: local ids, masked where
+                # the expert is not held here or the row is no token
                 if c.router == "sigmoid_bias":
                     w, ids = mu.select_experts_sigmoid_bias(
                         logits, blk["router_bias"], c.topk,
@@ -1459,7 +1460,9 @@ class Transformer:
                 else:
                     w, ids = mu.select_experts(logits, c.topk)
                 logits = mu.held_assignments(
-                    w, ids, c.first_expert_held, c.local_experts)
+                    w, ids, c.first_expert_held, c.local_experts,
+                    rows=None if row_mask is None
+                    else jnp.pad(row_mask, (0, pad)))
         wq = isinstance(blk["moe_up"], dict)
         ctx = self._moe_ep_ctx(
             (b + pad) // shards, inference=True, weights_quantized=wq
@@ -2001,6 +2004,9 @@ class Transformer:
             return kv_shape, append_layer
 
         windowed = state.window_layers
+        # padding rows are no token's: an expert layer is handed their
+        # assignments masked
+        is_token = token_pos >= 0
         with scope("kv_append"):
             # one description of the step's append per KIND of pool:
             # the global layers' by the block table, the window layers'
@@ -2091,7 +2097,7 @@ class Transformer:
                 with scope("moe_route"):
                     xn = self._rmsnorm(x, blk["norm_mlp"])
                 st = None if moe_state is None else moe_state[li]
-                y, st = self._decode_moe_ep(blk, xn, st)
+                y, st = self._decode_moe_ep(blk, xn, st, row_mask=is_token)
                 with scope("moe_combine"):
                     x = x + y.astype(x.dtype)
                 if "shared_up" in blk:

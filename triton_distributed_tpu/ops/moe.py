@@ -104,12 +104,6 @@ class EPMoEContext:
     # ``act(gate) * up`` (three matrices an expert, SwiGLU). False: the
     # two-matrix ``down(act(up(x)))``.
     gated: bool = False
-    # a chip's SHARE of a wider expert-parallel layer: most assignments
-    # arrive masked (the sentinel), so the grouped GEMM's trailing dummy
-    # blocks outnumber the real ones; True stores them as zeros without
-    # fetching or multiplying a weight (``grouped_matmul(dummy_expert=)``,
-    # Pallas GEMM with un-quantized weights).
-    skip_masked: bool = False
 
     @property
     def n(self) -> int:
@@ -170,9 +164,9 @@ def create_ep_moe_context(
         )
     if ctx.act_quant not in (None, "int8"):
         raise ValueError(f"act_quant must be None or 'int8', got {ctx.act_quant!r}")
-    if ctx.act_quant is not None and (ctx.gated or ctx.skip_masked):
+    if ctx.act_quant is not None and ctx.gated:
         raise ValueError(
-            "gated / skip_masked expert MLPs are built for bf16 and "
+            "gated expert MLPs are built for bf16 and "
             "weight-only-quantized GEMMs, not W8A8 (act_quant)")
     if ctx.transport == "fused" and ctx.dcn_axis is not None:
         raise ValueError(
@@ -350,7 +344,11 @@ def _expert_mlp(ctx: EPMoEContext, rows, eid, valid, w_up, w_down):
 
     rows: (R, H) received tokens; eid: (R,) local expert ids; valid: (R,)
     bool. w_up: (epr, H, F); w_down: (epr, F, H). Invalid rows are zero
-    and sorted into a trailing dummy group, so they contribute zeros.
+    and sorted into a trailing dummy group, so they contribute zeros;
+    the Pallas GEMM stores that group's blocks (and the capacity no row
+    fills) as zeros without fetching or multiplying a weight
+    (``grouped_matmul(dummy_expert=)``), the ``ragged_dot`` twin folds
+    them into the last expert's group.
 
     Either weight may instead be a WEIGHT-QUANTIZED dict
     ``{"q": (epr, K, N) int8/fp8, "scale": (epr, N) f32}`` (from
@@ -368,9 +366,6 @@ def _expert_mlp(ctx: EPMoEContext, rows, eid, valid, w_up, w_down):
     safe = jnp.clip(sti, 0, r - 1)
     ok = (sti < r) & valid[safe]
     xs = jnp.where(ok[:, None], rows[safe], 0).astype(ctx.dtype)
-    # dummy blocks (be == epr) read the LAST expert's weights; their rows
-    # are zero so the product is zero regardless
-    be_w = jnp.clip(be, 0, epr - 1)
 
     def act(h):
         # gated: the GEMM gave [gate | up]; the hidden is act(gate)·up
@@ -380,12 +375,15 @@ def _expert_mlp(ctx: EPMoEContext, rows, eid, valid, w_up, w_down):
         return _act(ctx.activation, h[:, :f]) * h[:, f:]
 
     if ctx.use_pallas_gemm:
-        gg_kw = {}
+        # the dummy tail (be == epr: the rows of no expert, and the
+        # capacity a step does not use) keeps its own id: the kernel
+        # neither fetches a weight for such a block nor multiplies it
+        gg_kw = {"block_m": ctx.block_m, "dummy_expert": epr}
         if ctx.gg_block_n is not None:
             gg_kw["block_n"] = ctx.gg_block_n
         if ctx.gg_block_k is not None:
             gg_kw["block_k"] = ctx.gg_block_k
-        if gg_kw:
+        if ctx.gg_block_n is not None or ctx.gg_block_k is not None:
             from triton_distributed_tpu.config import fused_vmem_budget
 
             gg_kw["vmem_limit_bytes"] = fused_vmem_budget()
@@ -393,14 +391,8 @@ def _expert_mlp(ctx: EPMoEContext, rows, eid, valid, w_up, w_down):
         def gg(inp, w):
             if isinstance(w, dict):
                 return grouped_matmul(
-                    inp, w["q"], be_w, w_scale=w["scale"],
-                    block_m=ctx.block_m, **gg_kw,
-                )
-            if ctx.skip_masked:
-                # the dummy tail keeps its own id: never multiplied
-                return grouped_matmul(inp, w, be, block_m=ctx.block_m,
-                                      dummy_expert=epr, **gg_kw)
-            return grouped_matmul(inp, w, be_w, block_m=ctx.block_m, **gg_kw)
+                    inp, w["q"], be, w_scale=w["scale"], **gg_kw)
+            return grouped_matmul(inp, w, be, **gg_kw)
 
         if (
             ctx.act_quant == "int8"
@@ -417,8 +409,8 @@ def _expert_mlp(ctx: EPMoEContext, rows, eid, valid, w_up, w_down):
 
             def gg8(q_in, s_in, w):
                 return grouped_matmul(
-                    q_in, w["q"], be_w, w_scale=w["scale"], x_scale=s_in,
-                    block_m=ctx.block_m, out_dtype=ctx.dtype, **gg_kw,
+                    q_in, w["q"], be, w_scale=w["scale"], x_scale=s_in,
+                    out_dtype=ctx.dtype, **gg_kw,
                 )
 
             xq, xsc = quantize_act_rows(xs)

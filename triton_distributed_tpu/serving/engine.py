@@ -276,6 +276,12 @@ class EngineStats:
     # only those from its window's first key on (0 without such layers)
     global_pages_walked: int = 0
     window_pages_walked: int = 0
+    # packed rows of the device steps that were no token's (the packed
+    # width less the step's tokens): ``serving_step`` hands their expert
+    # assignments to ``ops.ep_moe`` masked, so at least
+    # ``masked * topk // block_m`` blocks of an expert layer's grouped
+    # GEMM hold no row (0 for a model with no EP expert layer)
+    moe_masked_rows: int = 0
     prefix_hits: int = 0               # pages reattached from the cache
     # --- in-batch shared-prefix dedup (EngineConfig.prefix_share) ---
     shared_prefix_rows: int = 0        # batched rows marked SHARED_PREFIX
@@ -1115,7 +1121,11 @@ class ServingEngine:
                         stats.first_token_s += req.t_first - req.t_admit
                         stats.first_tokens += 1
             stats.step_times.append(dt)
-            stats.step_tokens.append(int(q_lens.sum()))
+            stats.step_tokens.append(report["tokens"])
+            c = self.model.config
+            if c.moe == "ep" and c.moe_layers:
+                # the step program masked its padding rows' assignments
+                stats.moe_masked_rows += self._t_pad - report["tokens"]
             stats.step_generated.append(gen_this_step)
             stats.note_shape(
                 self._grid_key, dt * 1e3,
