@@ -84,7 +84,7 @@ def _make_donating_runner(step, state, iters, donate_idx):
     in-flight DMAs target the persistent addresses). Each invocation
     consumes the donated tree and returns the final carry's version, so
     callers THREAD it: ``d, s = call(d)`` — the run/donate protocol of
-    production decode (models/transformer._decode_jit_state). The float
+    the serving step (models/transformer._serving_jit). The float
     fetch is inside ``call`` (the fence, as in :func:`_make_runner`)."""
     state = tuple(state)
     rest = state[:donate_idx] + (None,) + state[donate_idx + 1:]
@@ -789,8 +789,6 @@ def main(argv=None) -> None:
     for fn in (_bench_gemm_rs, _bench_wire_rings, _bench_schedule_search,
                _bench_group_gemm,
                _bench_moe_a2a, _bench_flash_decode,
-               _bench_serving_moe_decode, _bench_serving_multilayer,
-               _bench_serving_paged, _bench_generate_scan,
                _bench_serving_continuous, _bench_serving_disaggregated):
         try:
             print(json.dumps(fn(mesh, n, on_tpu, spec)), file=sys.stderr, flush=True)
@@ -1295,254 +1293,6 @@ def _bench_moe_a2a(mesh, n, on_tpu, spec):
     }
 
 
-# cross-metric scratch: the multi-layer serving bench reports its
-# per-layer marginal against the 1-layer step measured just before it
-_SHARED = {}
-
-
-def _bench_serving_multilayer(mesh, n, on_tpu, spec):
-    """Serving decode at MODEL depth (VERDICT r4 #3): n_layers=4 with
-    alternating dense/MoE blocks (MoE at 1 and 3 — the DeepSeek shape:
-    dense layer 0, MoE above, presets.deepseek_moe_16b), the per-layer
-    ``EPMoEState`` list threaded at depth, same per-layer dims as the
-    1-layer headline. Reports µs/layer marginal vs the 1-layer step —
-    serving claims are per-model, and layer-list state threading +
-    cross-layer XLA scheduling only show up at depth."""
-    from triton_distributed_tpu.models import Transformer, TransformerConfig
-
-    if on_tpu:
-        b, s_cap, layers = 128, 2048, 4
-        cfg = TransformerConfig(
-            vocab=4096, n_layers=layers, hidden=7168, ffn=2048, n_heads=56,
-            n_kv_heads=8, head_dim=128, moe="ep", moe_layers=(1, 3),
-            num_experts=8, topk=8, param_dtype=jnp.bfloat16,
-            moe_weight_quant="int8", moe_act_quant="int8", kv_quant="int8",
-            dense_weight_quant="int8", dense_act_quant="int8",
-        )
-    else:
-        b, s_cap, layers = 8, 256, 4
-        cfg = TransformerConfig(
-            vocab=512, n_layers=layers, hidden=256, ffn=128, n_heads=8,
-            n_kv_heads=4, head_dim=32, moe="ep", moe_layers=(1, 3),
-            num_experts=8, topk=2, param_dtype=jnp.bfloat16,
-        )
-    model = Transformer(cfg, mesh, tp_axis="x")
-    params = jax.tree.map(
-        lambda x, s: jax.device_put(x, s),
-        model.init(jax.random.PRNGKey(7)), model.shardings(),
-    )
-    params = model.quantize_moe_weights(params)
-    params = model.quantize_dense_weights(params)
-    caches = model.init_cache(b, s_cap)
-    lens = jnp.asarray(
-        np.random.default_rng(11).integers(s_cap // 8, 3 * s_cap // 4, (b,)),
-        jnp.int32,
-    )
-    toks0 = jnp.zeros((b,), jnp.int32)
-    moe_state = model.init_decode_state(b)
-
-    def step(state, s):
-        prm, caches, lens_, toks, mst = state
-        if mst is None:
-            logits, caches, lens_ = model.decode_step(prm, caches, lens_, toks)
-        else:
-            logits, caches, lens_, mst = model.decode_step(
-                prm, caches, lens_, toks, mst
-            )
-        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        s = s + jnp.sum(toks.astype(jnp.float32))
-        return (prm, caches, lens_, toks, mst), s
-
-    lo, hi = (4, 24) if on_tpu else (1, 3)
-    t_step = bench_loop(
-        step, (params, caches, lens, toks0, moe_state), lo=lo, hi=hi,
-        donate_idx=4 if moe_state is not None else None,
-    )
-    out = {
-        "metric": "serving_moe_decode_step_multilayer",
-        "value": round(t_step * 1e6, 1),
-        "unit": "us",
-        "n_layers": layers,
-        "tok_per_s": round(b / t_step, 0),
-        "config": (
-            f"n={n} B={b} hidden={cfg.hidden} layers={layers} "
-            f"moe_layers={cfg.moe_layers} S={s_cap} lens~U[S/8,3S/4] "
-            "dense0+alternating-MoE "
-            + ("self-transport(no wire)" if n == 1 else "multi-chip")
-        ),
-    }
-    t1 = _SHARED.get("serving_step_1l")
-    if t1:
-        # marginal cost of one ADDED layer vs the 1-layer measurement
-        # (layer 0 here is dense — cheaper than the MoE headline layer —
-        # so the honest comparison is per-MoE-layer: 2 MoE + 2 dense vs
-        # 1 MoE; report both raw marginal and the extrapolation ratio)
-        out["us_per_layer_marginal"] = round((t_step - t1) / (layers - 1) * 1e6, 1)
-        out["vs_1l_extrapolation"] = round(t_step / (layers * t1), 3)
-    return out
-
-
-def _bench_generate_scan(mesh, n, on_tpu, spec):
-    """On-device multi-step decode (VERDICT r4 #6): `generate_scan`
-    folds the whole decode into ONE jitted lax.scan — this times it at
-    the serving headline and reports per-step cost vs the single-step
-    extrapolation. Methodology: wall-clock DELTA between steps=64 and
-    steps=32 sequences (one dispatch each, host fetch as fence) — the
-    per-dispatch host cost cancels, exactly the overhead the scan
-    entry exists to kill. Fresh caches per invocation keep the
-    workload constant (caches/lens/state are donated); the LL state is
-    threaded call to call."""
-    import time as _time
-
-    from triton_distributed_tpu.models import Transformer, TransformerConfig
-
-    if on_tpu:
-        b, s_cap = 128, 2048
-        cfg = TransformerConfig(
-            vocab=4096, n_layers=1, hidden=7168, ffn=2048, n_heads=56,
-            n_kv_heads=8, head_dim=128, moe="ep", moe_layers=(0,),
-            num_experts=8, topk=8, param_dtype=jnp.bfloat16,
-            moe_weight_quant="int8", moe_act_quant="int8", kv_quant="int8",
-            dense_weight_quant="int8", dense_act_quant="int8",
-        )
-        lo_steps, hi_steps, reps = 32, 64, 5
-    else:
-        b, s_cap = 8, 256
-        cfg = TransformerConfig(
-            vocab=512, n_layers=1, hidden=256, ffn=128, n_heads=8,
-            n_kv_heads=4, head_dim=32, moe="ep", moe_layers=(0,),
-            num_experts=8, topk=2, param_dtype=jnp.bfloat16,
-        )
-        lo_steps, hi_steps, reps = 2, 4, 2
-    model = Transformer(cfg, mesh, tp_axis="x")
-    params = jax.tree.map(
-        lambda x, s: jax.device_put(x, s),
-        model.init(jax.random.PRNGKey(7)), model.shardings(),
-    )
-    params = model.quantize_moe_weights(params)
-    params = model.quantize_dense_weights(params)
-    lens = jnp.asarray(
-        np.random.default_rng(11).integers(s_cap // 8, 3 * s_cap // 4, (b,)),
-        jnp.int32,
-    )
-    toks0 = jnp.zeros((b,), jnp.int32)
-    mst = model.init_decode_state(b)
-
-    def run(steps, mst):
-        caches = model.init_cache(b, s_cap)    # outside the timed window
-        lens0 = lens + 0
-        t0 = _time.perf_counter()
-        out = model.generate_scan(
-            params, caches, lens0, toks0, steps, moe_state=mst
-        )
-        np.asarray(out[0])                     # host fetch = the fence
-        return _time.perf_counter() - t0, (out[3] if mst is not None else None)
-
-    for s in (lo_steps, hi_steps, lo_steps, hi_steps):  # compile + warm
-        _, mst = run(s, mst)
-    deltas = []
-    for _ in range(reps):
-        t_lo, mst = run(lo_steps, mst)
-        t_hi, mst = run(hi_steps, mst)
-        deltas.append((t_hi - t_lo) / (hi_steps - lo_steps))
-    t_step = float(np.median(deltas))
-    if t_step <= 0:
-        raise RuntimeError("generate_scan delta swamped by noise")
-    out = {
-        "metric": "generate_scan_step",
-        "value": round(t_step * 1e6, 1),
-        "unit": "us",
-        "tok_per_s": round(b / t_step, 0),
-        "steps": f"{lo_steps}->{hi_steps}",
-        "config": (
-            f"n={n} B={b} hidden={cfg.hidden} S={s_cap} one-program "
-            "lax.scan decode (donated carries, LL state threaded)"
-        ),
-    }
-    t1 = _SHARED.get("serving_step_1l")
-    if t1:
-        out["vs_single_step"] = round(t_step / t1, 3)
-    return out
-
-
-def _bench_serving_paged(mesh, n, on_tpu, spec):
-    """The serving headline FROM PAGE POOLS (VERDICT r4 #7): same
-    config as ``serving_moe_decode_step`` but the KV lives in int8 page
-    pools behind a block table (page 1024 per the docs/PERF.md
-    guidance) — the production serving mode (the reference's
-    block-table path is its default decode entry,
-    flash_decode.py:763-846). Proves the composition pool + dynamic
-    trips + int8 + LL MoE at the headline shapes; expected within ~10%
-    of the contiguous number."""
-    from triton_distributed_tpu.models import Transformer, TransformerConfig
-
-    if on_tpu:
-        b, s_cap, page = 128, 2048, 1024
-        cfg = TransformerConfig(
-            vocab=4096, n_layers=1, hidden=7168, ffn=2048, n_heads=56,
-            n_kv_heads=8, head_dim=128, moe="ep", moe_layers=(0,),
-            num_experts=8, topk=8, param_dtype=jnp.bfloat16,
-            moe_weight_quant="int8", moe_act_quant="int8", kv_quant="int8",
-            dense_weight_quant="int8", dense_act_quant="int8",
-        )
-    else:
-        b, s_cap, page = 8, 256, 32
-        cfg = TransformerConfig(
-            vocab=512, n_layers=1, hidden=256, ffn=128, n_heads=8,
-            n_kv_heads=4, head_dim=32, moe="ep", moe_layers=(0,),
-            num_experts=8, topk=2, param_dtype=jnp.bfloat16,
-        )
-    model = Transformer(cfg, mesh, tp_axis="x")
-    params = jax.tree.map(
-        lambda x, s: jax.device_put(x, s),
-        model.init(jax.random.PRNGKey(7)), model.shardings(),
-    )
-    params = model.quantize_moe_weights(params)
-    params = model.quantize_dense_weights(params)
-    caches, table = model.init_paged_cache(b, s_cap, page=page)
-    lens = jnp.asarray(
-        np.random.default_rng(11).integers(s_cap // 8, 3 * s_cap // 4, (b,)),
-        jnp.int32,
-    )
-    toks0 = jnp.zeros((b,), jnp.int32)
-    moe_state = model.init_decode_state(b)
-
-    def step(state, s):
-        prm, caches, lens_, toks, mst, table = state
-        if mst is None:
-            logits, caches, lens_ = model.decode_step(
-                prm, caches, lens_, toks, block_table=table
-            )
-        else:
-            logits, caches, lens_, mst = model.decode_step(
-                prm, caches, lens_, toks, mst, block_table=table
-            )
-        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        s = s + jnp.sum(toks.astype(jnp.float32))
-        return (prm, caches, lens_, toks, mst, table), s
-
-    lo, hi = (8, 64) if on_tpu else (1, 3)
-    t_step = bench_loop(
-        step, (params, caches, lens, toks0, moe_state, table), lo=lo, hi=hi,
-        donate_idx=4 if moe_state is not None else None,
-    )
-    out = {
-        "metric": "serving_moe_decode_step_paged",
-        "value": round(t_step * 1e6, 1),
-        "unit": "us",
-        "tok_per_s": round(b / t_step, 0),
-        "config": (
-            f"n={n} B={b} hidden={cfg.hidden} page={page} S={s_cap} "
-            "lens~U[S/8,3S/4] int8-KV page pools + block table "
-            + ("self-transport(no wire)" if n == 1 else "multi-chip")
-        ),
-    }
-    t1 = _SHARED.get("serving_step_1l")
-    if t1:
-        out["vs_contiguous"] = round(t_step / t1, 3)
-    return out
-
-
 def _serving_continuous_config(n, on_tpu, tiny=False, n_layers=None):
     """(model config, engine config, trace knobs) for the continuous
     bench. TPU: the serving headline model (hidden 7168, EP-MoE, every
@@ -1611,14 +1361,9 @@ def _bench_serving_continuous(mesh, n, on_tpu, spec, tiny=False,
     (ISSUE 6 tentpole acceptance): a seeded Poisson arrival trace with
     ~U[S/8, 3S/4] prompt lengths drives the ServingEngine — admission/
     eviction over the page pool, chunked prefill interleaved into
-    decode batches, one ragged mixed kernel launch per step — and the
-    same trace is then served by the FIXED-BATCH paged baseline (FCFS
-    rectangles of `slots` requests through prefill + generate_scan over
-    the paged decode path). Reports sustained tok/s, p50/p99 step time
-    and GOODPUT (completed requests' generated tokens per wall second)
-    for both; ``goodput_vs_fixed_batch`` > 1 is the acceptance."""
-    import time as _time
-
+    decode batches, one ragged mixed kernel launch per step. Reports
+    sustained tok/s, p50/p99 step time and GOODPUT (completed requests'
+    generated tokens per wall second)."""
     import jax
 
     from triton_distributed_tpu.models import Transformer
@@ -1627,7 +1372,7 @@ def _bench_serving_continuous(mesh, n, on_tpu, spec, tiny=False,
         ragged_serving_step_ms,
     )
 
-    cfg, ecfg, trace_kw, s_cap = _serving_continuous_config(
+    cfg, ecfg, trace_kw, _ = _serving_continuous_config(
         n, on_tpu, tiny, n_layers=n_layers
     )
     model = Transformer(cfg, mesh, tp_axis="x")
@@ -1688,55 +1433,10 @@ def _bench_serving_continuous(mesh, n, on_tpu, spec, tiny=False,
     eng_tuned = ServingEngine(model, params, ecfg)
     resolved_schedule = eng_tuned.grid_schedule.to_dict()
 
-    # ---- fixed-batch paged baseline on the SAME trace: FCFS
-    # rectangles of `slots` requests, padded prompts, every row decoded
-    # until the batch's LAST row finishes (the stragglers the engine
-    # does not wait for)
-    b = ecfg.slots
-    page = ecfg.page
-    r_ranks = mesh.shape["x"]
-    cap_align = r_ranks * page             # paged capacity granularity
-
-    def run_baseline():
-        trace = fresh_trace()
-        total_useful = 0
-        t0 = _time.perf_counter()
-        for i in range(0, len(trace), b):
-            batch = trace[i:i + b]
-            bb = len(batch)
-            maxlen = max(len(r.prompt) for r in batch)
-            steps = max(r.max_new for r in batch)
-            # ONE rectangle for all batches (a per-batch capacity
-            # would recompile prefill/scan per batch — charge the
-            # rectangle its true cost, not compile time)
-            cap = -(-(s_cap + trace_kw["max_new_hi"] + 1)
-                    // cap_align) * cap_align
-            toks = np.zeros((bb, s_cap), np.int32)
-            lens = np.zeros((bb,), np.int32)
-            for j, r in enumerate(batch):
-                toks[j, :len(r.prompt)] = r.prompt
-                lens[j] = len(r.prompt)
-            del maxlen
-            caches = model.init_cache(bb, cap)
-            last, caches, klens = model._prefill_jit(
-                params, caches, jnp.asarray(toks), jnp.asarray(lens)
-            )
-            first = jnp.argmax(last, axis=-1).astype(jnp.int32)
-            pcaches, table = model.paginate_caches(caches, page=page)
-            out = model.generate_scan(
-                params, pcaches, klens, first, int(steps) - 1,
-                block_table=table,
-            )
-            np.asarray(out[0])             # fence
-            total_useful += sum(r.max_new for r in batch)
-        return total_useful / (_time.perf_counter() - t0)
-
-    run_baseline()                          # compile warm
-    base_goodput = run_baseline()
-
     # model term: a representative steady step (every slot decoding at
     # the mean trace length)
     mean_len = (trace_kw["len_lo"] + trace_kw["len_hi"]) // 2
+    page = ecfg.page
     from triton_distributed_tpu.tune.perf_model import (
         measured_page_issue_ms,
     )
@@ -1752,8 +1452,6 @@ def _bench_serving_continuous(mesh, n, on_tpu, spec, tiny=False,
         # the machine the measurement next to it ran on
         issue_ms=measured_page_issue_ms(),
     )
-    ratio = (stats.goodput_tok_per_s / base_goodput
-             if base_goodput > 0 else float("inf"))
     return {
         "metric": "serving_continuous",
         "value": round(stats.goodput_tok_per_s, 1),
@@ -1766,8 +1464,6 @@ def _bench_serving_continuous(mesh, n, on_tpu, spec, tiny=False,
         "evictions": stats.evictions,
         "deferrals": stats.deferrals,
         "degraded_to_xla": stats.degraded,
-        "fixed_batch_goodput": round(base_goodput, 1),
-        "goodput_vs_fixed_batch": round(ratio, 3),
         "model_steady_step_ms": round(model_ms, 3),
         "n_layers": cfg.n_layers,
         "per_layer_pool_bytes": per_layer_pool_bytes,
@@ -3289,149 +2985,6 @@ def _bench_serving_multitenant(mesh, n, on_tpu, spec, tiny=False):
             f"slo_iact={slo_iact} brownout_slo={slo_brownout} "
             f"window=2 cooldown=3 temp=0.7 top_k=40 fleet_seed=1 "
             + ("tiny-dryrun" if tiny or not on_tpu else "headline")
-        ),
-    }
-
-
-def _bench_serving_moe_decode(mesh, n, on_tpu, spec):
-    """One FULL EP-MoE serving decode step on the chip (VERDICT r3 #3:
-    the workload every MoE transport improvement serves — the
-    reference's test_ep_moe_inference.py scenario). DeepSeek-ish
-    per-chip scale: B=128 last tokens, hidden 7168, topk 8 over the 8
-    locally-owned experts, GQA flash-decode attention over a 2048-token
-    cache, greedy argmax feeding the next step. The EP-MoE block rides
-    the fused chunked transport BARRIER-FREE (LL state threaded through
-    the loop carry). n=1: dispatch is self-transport (no wire) — what
-    is measured is the full per-chip serving step.
-
-    ``moe_block_us`` re-times the MoE block alone (routing + staging +
-    fused a2a + grouped expert MLP + combine) at the same shapes;
-    ``attn_rest_us`` is the difference (attention + projections + LM
-    head)."""
-    from triton_distributed_tpu.models import Transformer, TransformerConfig
-    from triton_distributed_tpu.ops import create_ep_moe_state, ep_moe
-
-    if on_tpu:
-        b, s_cap = 128, 2048
-        cfg = TransformerConfig(
-            vocab=4096, n_layers=1, hidden=7168, ffn=2048, n_heads=56,
-            n_kv_heads=8, head_dim=128, moe="ep", moe_layers=(0,),
-            num_experts=8, topk=8, param_dtype=jnp.bfloat16,
-            # serving weight path: int8 expert matrices (per-out-channel
-            # scales, grouped-GEMM epilogue dequant) — the decode GEMMs
-            # are weight-HBM-bound, so this is the production default
-            # (presets.deepseek_moe_16b); measured 1.88 -> 1.55 ms on
-            # the MoE block (docs/PERF.md)
-            moe_weight_quant="int8",
-            # W8A8 expert GEMMs: s8×s8 MXU at 2× the bf16 rate
-            moe_act_quant="int8",
-            # int8 KV cache: halves the attention DMA bytes + the cache
-            # HBM (production default, presets.deepseek_moe_16b)
-            kv_quant="int8",
-            # int8 dense projections (wqkv/wo/lm_head): same
-            # weight-HBM-bound argument as the expert matrices; W8A8
-            # on the projections (lm_head stays W8A16)
-            dense_weight_quant="int8",
-            dense_act_quant="int8",
-        )
-    else:
-        b, s_cap = 8, 256
-        cfg = TransformerConfig(
-            vocab=512, n_layers=1, hidden=256, ffn=128, n_heads=8,
-            n_kv_heads=4, head_dim=32, moe="ep", moe_layers=(0,),
-            num_experts=8, topk=2, param_dtype=jnp.bfloat16,
-        )
-    model = Transformer(cfg, mesh, tp_axis="x")
-    params = jax.tree.map(
-        lambda x, s: jax.device_put(x, s),
-        model.init(jax.random.PRNGKey(7)), model.shardings(),
-    )
-    params = model.quantize_moe_weights(params)
-    params = model.quantize_dense_weights(params)
-    caches = model.init_cache(b, s_cap)
-    # MIXED conversation lengths (a serving batch, not a lockstep one):
-    # uniform [S/8, 3S/4] so the longest row + the timing loop's appends
-    # stay inside capacity. The decode attention kernel walks
-    # ceil(len/block_k) blocks PER ROW (dynamic trip counts), so KV
-    # reads track the true lengths — a capacity-walk kernel would read
-    # S for every row.
-    lens = jnp.asarray(
-        np.random.default_rng(11).integers(s_cap // 8, 3 * s_cap // 4, (b,)),
-        jnp.int32,
-    )
-    toks0 = jnp.zeros((b,), jnp.int32)
-    # LL state rides UNCONDITIONALLY (r4 weak #3 closed): bench_loop's
-    # donate_idx threads the workspaces across runner invocations, so
-    # the persistent-buffer contract holds at any n — the bench times
-    # the same barrier-free path production decode runs
-    # (_decode_jit_state's donate protocol)
-    moe_state = model.init_decode_state(b)
-
-    # params ride the CARRY, not the closure: closed-over device arrays
-    # are embedded in the lowered module as literal constants (~1 GB of
-    # weights inside the HLO); as loop-invariant carry entries they
-    # lower as parameters and XLA hoists them.
-    def step(state, s):
-        prm, caches, lens, toks, mst = state
-        if mst is None:
-            logits, caches, lens = model.decode_step(prm, caches, lens, toks)
-        else:
-            logits, caches, lens, mst = model.decode_step(
-                prm, caches, lens, toks, mst
-            )
-        toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        s = s + jnp.sum(toks.astype(jnp.float32))
-        return (prm, caches, lens, toks, mst), s
-
-    lo, hi = (8, 64) if on_tpu else (1, 3)
-    t_step = bench_loop(
-        step, (params, caches, lens, toks0, moe_state), lo=lo, hi=hi,
-        donate_idx=4 if moe_state is not None else None,
-    )
-    _SHARED["serving_step_1l"] = t_step
-
-    # MoE block alone at the same shapes (own LL state)
-    blk = params["blocks"][0]
-    ctx = model._moe_ep_ctx(-(-b // model.token_shards), inference=True)
-    mst2 = create_ep_moe_state(ctx) if ctx.transport == "fused" else None
-    x0 = jax.random.normal(jax.random.PRNGKey(8), (b, cfg.hidden), cfg.dtype)
-    # quantized expert dicts pass through; plain arrays cast
-    w_up, w_down = (
-        w if isinstance(w, dict) else w.astype(cfg.dtype)
-        for w in (blk["moe_up"], blk["moe_down"])
-    )
-
-    def moe_step(state, s):
-        x, router, up, down, mst = state
-        logits_r = x.astype(jnp.float32) @ router
-        if mst is None:
-            y = ep_moe(x, logits_r, up, down, ctx)
-        else:
-            y, mst = ep_moe(x, logits_r, up, down, ctx, state=mst)
-        s = s + jnp.sum(y.astype(jnp.float32))
-        return (perturb(x, s), router, up, down, mst), s
-
-    lo2, hi2 = (16, 128) if on_tpu else (1, 3)
-    t_moe = bench_loop(
-        moe_step, (x0, blk["router"], w_up, w_down, mst2), lo=lo2, hi=hi2,
-        donate_idx=4 if mst2 is not None else None,
-    )
-
-    return {
-        "metric": "serving_moe_decode_step",
-        "value": round(t_step * 1e6, 1),
-        "unit": "us",
-        "moe_block_us": round(t_moe * 1e6, 1),
-        "attn_rest_us": round((t_step - t_moe) * 1e6, 1),
-        "tok_per_s": round(b / t_step, 0),
-        "transport": ctx.transport + ("+ll" if mst2 is not None else ""),
-        "config": (
-            f"n={n} B={b} hidden={cfg.hidden} topk={cfg.topk} "
-            f"experts/chip={cfg.num_experts} ffn={cfg.ffn} S={s_cap} "
-            f"lens~U[S/8,3S/4] wq={cfg.moe_weight_quant} "
-            f"aq={cfg.moe_act_quant} kvq={cfg.kv_quant} "
-            "1-layer EP-MoE decode "
-            + ("self-transport(no wire)" if n == 1 else "multi-chip")
         ),
     }
 

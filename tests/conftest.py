@@ -112,3 +112,66 @@ def moe_splits_data(n, m, num_experts, hidden, seed=0):
     ).astype(np.int32)
     toks = rng.standard_normal((n, m, hidden)).astype(np.float32)
     return toks, splits
+
+
+# ------------------------------------------------------------ serving helper
+# Shared by test_models / test_serving_step: the engine packs the steps
+# (the one packing contract), the device step hands out every position.
+
+def force_fused_ctx(use_pallas_gemm=False):
+    """Monkeypatch body for ``Transformer._moe_ep_ctx``: the serving
+    step rides the fused EP transport even off-TPU (tiny
+    interpreter-safe geometry), honoring the config's moe_wire_quant and
+    moe_act_quant (W8A8 lives in the Pallas grouped GEMM:
+    ``use_pallas_gemm``)."""
+    from triton_distributed_tpu import ops
+
+    def fused_ctx(self, m_local, inference=False, weights_quantized=None):
+        c = self.config
+        return ops.create_ep_moe_context(
+            self.mesh, self.tp_axis, num_experts=c.num_experts,
+            topk=c.topk, max_m=m_local * c.topk, hidden=c.hidden,
+            dtype=c.dtype, transport="fused" if inference else "xla",
+            use_pallas_gemm=use_pallas_gemm, block_m=8,
+            quant=c.moe_wire_quant if inference else None,
+            act_quant=c.moe_act_quant if inference else None,
+            batch_axes=tuple(self.dp_axes),
+        )
+
+    return fused_ctx
+
+
+def serve_all_logits(model, params, ecfg, prompts, *, max_new=1,
+                     use_pallas=False, **engine_kw):
+    """Serve ``prompts`` (arriving together) through a ``ServingEngine``
+    whose device step is ``Transformer._serving_all_logits_jit``.
+    Returns ``(engine, requests, logits)``: ``logits[i]`` is
+    ``(len(prompt) + max_new - 1, vocab)`` float32, row p the
+    next-token distribution the step that batched sequence position p
+    of request i computed there."""
+    from triton_distributed_tpu.serving import Request, ServingEngine
+
+    class AllLogitsEngine(ServingEngine):
+        def _step_jit(self):
+            return self.model._serving_all_logits_jit
+
+        def _run_device(self, arrays, block_q):
+            full = super()._run_device(arrays, block_q)     # (T, vocab)
+            _, _, token_pos, q_starts, q_lens = arrays[:5]
+            for s in np.nonzero(q_lens)[0]:
+                span = slice(q_starts[s], q_starts[s] + q_lens[s])
+                rows = seen.setdefault(self.slot_req[s].rid, {})
+                rows.update(zip(token_pos[span].tolist(), full[span]))
+            return full[np.clip(q_starts + q_lens - 1, 0, len(full) - 1)]
+
+    seen: dict = {}
+    eng = AllLogitsEngine(model, params, ecfg, use_pallas=use_pallas,
+                          propagate_failures=True, **engine_kw)
+    reqs = [Request(rid=i, prompt=np.asarray(p, np.int32), max_new=max_new,
+                    arrival=0.0) for i, p in enumerate(prompts)]
+    stats = eng.run(reqs)
+    assert stats.completed == len(reqs) and not stats.failures, stats.failures
+    logits = [np.stack([seen[r.rid][p]
+                        for p in range(len(r.prompt) + max_new - 1)])
+              for r in reqs]
+    return eng, reqs, logits

@@ -609,6 +609,11 @@ class TestServingFailover:
         transport degrades onto the XLA twin, and — the trip being
         stale for the rest of the arming — a probation probe re-promotes
         the DCN wire. Zero lost requests, final state un-degraded."""
+        # every step and ship program this trace launches is compiled
+        # BEFORE the 0.3 s deadline is armed: a first-use compile inside
+        # it trips the watchdog at site serving_step, not at the stall
+        self._engine(models1, roles1).run(
+            poisson_trace(**self.TRACE), max_ticks=800)
         trace = poisson_trace(**self.TRACE)
         eng = self._engine(models1, roles1)
         plan = FaultPlan(seed=1, faults=(Stall(site="kv_ship", rank=0),))

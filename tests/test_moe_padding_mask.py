@@ -140,14 +140,15 @@ def test_moe_masked_rows_counts_what_assemble_left_empty():
     assert dense.stats.moe_masked_rows == 0
 
 
-def test_decode_step_hands_the_op_its_logits_unmasked(monkeypatch):
-    """A caller with no mask behaves as before: ``decode_step`` gives
-    ``ops.ep_moe`` the router's logits, and the op routes."""
+def test_no_row_mask_hands_the_op_its_logits_unmasked(monkeypatch):
+    """A caller with no mask behaves as before: ``_decode_moe_ep``
+    gives ``ops.ep_moe`` the router's logits, and the op routes."""
     model = one_chip_model(**CONFIGS["softmax"])
     params = model.init(jax.random.PRNGKey(0))
     seen = spy_on_ep_moe(monkeypatch)
-    caches = model.init_cache(2, 16)
-    model.decode_step(params, caches, jnp.asarray([3, 5], jnp.int32),
-                      jnp.asarray([7, 9], jnp.int32))
+    xn = jax.random.normal(jax.random.PRNGKey(1),
+                           (2, model.config.hidden), jnp.float32)
+    y, state = model._decode_moe_ep(params["blocks"][1], xn, row_mask=None)
     logits, = seen
     assert logits.shape == (2, model.config.num_experts)
+    assert y.shape == xn.shape and state is None

@@ -1,105 +1,7 @@
-"""Paged serving: decode_step / generate over page pools + block table.
+"""``bench.py``'s donate-and-thread runner: the LL persistent-workspace
+contract its timing loops rest on."""
 
-The reference's block-table path is its DEFAULT decode entry
-(flash_decode.py:763-846); round 5 wires the repo's paged int8 pools
-into the model's serving loop — init_paged_cache / paginate_caches →
-decode_step(block_table=...) with paged attention partials AND the
-paged in-place append. These tests pin the paged path to the
-contiguous path bit-for-bit on the same state.
-"""
-
-import jax
 import jax.numpy as jnp
-import numpy as np
-import pytest
-
-from triton_distributed_tpu.models import Transformer, TransformerConfig
-
-CFG = dict(
-    vocab=128, n_layers=2, hidden=128, ffn=256,
-    n_heads=8, n_kv_heads=4, head_dim=16,
-    dtype=jnp.float32, param_dtype=jnp.float32,
-)
-
-
-def _model(mesh, kv_quant=None):
-    cfg = TransformerConfig(
-        **CFG, moe="ep", moe_layers=(1,), num_experts=8, topk=2,
-        kv_quant=kv_quant,
-    )
-    model = Transformer(cfg, mesh, "tp", ())
-    params = jax.tree.map(
-        lambda p, s: jax.device_put(p, s),
-        model.init(jax.random.PRNGKey(0)), model.shardings(),
-    )
-    return model, params
-
-
-@pytest.fixture(scope="module")
-def mesh_tp():
-    from jax.sharding import Mesh
-
-    return Mesh(np.asarray(jax.devices()), ("tp",))
-
-
-class TestPagedServing:
-    @pytest.mark.parametrize("kv_quant", [None, "int8"])
-    def test_paged_decode_matches_contiguous(self, mesh_tp, kv_quant):
-        """prefill → paginate_caches → paged decode_step must equal the
-        contiguous decode_step on the same state, across two steps
-        (the second step reads back what the paged APPEND wrote)."""
-        model, params = _model(mesh_tp, kv_quant)
-        b, smax, page = 4, 64, 4          # 8 ranks × 2 pages × 4 rows
-        prompt = jax.random.randint(jax.random.PRNGKey(3), (b, 8), 0, 128)
-        caches = model.init_cache(b, smax)
-        last, caches, lens = model.prefill(params, caches, prompt)
-        tok = jnp.argmax(last, axis=-1).astype(jnp.int32)
-
-        pcaches, table = model.paginate_caches(caches, page=page)
-        c_caches, c_lens, c_tok = caches, lens, tok
-        p_caches, p_lens, p_tok = pcaches, lens, tok
-        for _ in range(2):
-            lg_c, c_caches, c_lens = model.decode_step(
-                params, c_caches, c_lens, c_tok
-            )
-            lg_p, p_caches, p_lens = model.decode_step(
-                params, p_caches, p_lens, p_tok, block_table=table
-            )
-            np.testing.assert_allclose(
-                np.asarray(lg_p), np.asarray(lg_c), atol=1e-5, rtol=1e-5
-            )
-            c_tok = jnp.argmax(lg_c, axis=-1).astype(jnp.int32)
-            p_tok = jnp.argmax(lg_p, axis=-1).astype(jnp.int32)
-        assert np.asarray(p_lens).tolist() == np.asarray(c_lens).tolist()
-
-    def test_init_paged_cache_generate(self, mesh_tp):
-        """Zero-state paged serving: init_paged_cache + generate over
-        the table matches contiguous generate from zero caches."""
-        model, params = _model(mesh_tp)
-        b, smax, page, steps = 2, 64, 4, 3
-        first = jnp.array([5, 9], jnp.int32)
-        toks_c, _, lens_c = model.generate(
-            params, model.init_cache(b, smax),
-            jnp.zeros((b,), jnp.int32), first, steps,
-        )
-        pcaches, table = model.init_paged_cache(b, smax, page=page)
-        toks_p, _, lens_p = model.generate(
-            params, pcaches, jnp.zeros((b,), jnp.int32), first, steps,
-            block_table=table,
-        )
-        np.testing.assert_array_equal(np.asarray(toks_c), np.asarray(toks_p))
-        assert np.asarray(lens_p).tolist() == [steps] * b
-
-    def test_paged_capacity_contract(self, mesh_tp):
-        model, params = _model(mesh_tp)
-        with pytest.raises(ValueError, match="rank slices"):
-            model.init_paged_cache(2, 60, page=4)   # 60 % (8·4) != 0
-        pcaches, table = model.init_paged_cache(2, 64, page=4)
-        with pytest.raises(AssertionError, match="capacity"):
-            model.generate(
-                params, pcaches, jnp.full((2,), 63, jnp.int32),
-                jnp.zeros((2,), jnp.int32), 5, block_table=table,
-            )
 
 
 class TestDonatingRunner:

@@ -1,15 +1,16 @@
-"""Shard-resident serving state across the prefill→decode boundary.
+"""Shard-resident serving state: the donated ``ServingState`` pools of
+``Transformer._serving_jit`` on a multi-chip mesh.
 
-The serving contract on a dp×tp mesh (≡ the reference's SP decode
-layer, whose per-rank KV shard keeps one placement for the life of the
-session — sp_flash_decode_layer.py:45-184):
+The serving contract (≡ the reference's SP decode layer, whose per-rank
+KV shard keeps one placement for the life of the session —
+sp_flash_decode_layer.py:45-184):
 
-* ONE canonical cache placement (batch over dp, sequence over tp,
-  ``Transformer.cache_sharding``) from ``init_cache`` through prefill
-  into every decode step;
-* the decode jits DONATE the caches and kv_lens, and the pinned
-  output placements let XLA alias them — the per-step cache update is
-  in place, not a cache-sized copy;
+* ONE canonical pool placement (KV heads over tp,
+  ``Transformer._serving_pool_sharding``) from ``init_serving_state``
+  through every step;
+* the step jit DONATES the state, and the pinned output placements let
+  XLA alias the pools — the per-step append is in place, not a
+  pool-sized copy;
 * the shardguard utilities turn a violation (the round-4 "[SPMD]
   Involuntary full rematerialization" compile-log failure mode) into
   a loud CI failure.
@@ -19,6 +20,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from conftest import serve_all_logits
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from triton_distributed_tpu.models import Transformer, TransformerConfig
@@ -28,9 +30,22 @@ from triton_distributed_tpu.runtime import (
     find_involuntary_resharding,
     input_output_aliased_params,
 )
+from triton_distributed_tpu.serving import (
+    EngineConfig,
+    Request,
+    ServingEngine,
+)
+
+ENGINE = EngineConfig(slots=4, token_budget=32, chunk=8, page=8, npages=32)
+#: the pools are the state's only leaves of this size (tables and
+#: lengths are a few hundred bytes and pass through the step)
+POOL_BYTES = 1 << 12
 
 
 def _model(mesh, kv_quant=None):
+    """The dryrun mesh's serving model: tp over its ``tp`` axis, the
+    step replicated over ``dp`` (ragged serving is tp-only: dp composes
+    by one engine a dp group)."""
     cfg = TransformerConfig(
         vocab=128, n_layers=2, hidden=128, ffn=256,
         n_heads=8, n_kv_heads=4, head_dim=16,
@@ -38,7 +53,7 @@ def _model(mesh, kv_quant=None):
         kv_quant=kv_quant,
         dtype=jnp.float32, param_dtype=jnp.float32,
     )
-    model = Transformer(cfg, mesh, "tp", ("dp",))
+    model = Transformer(cfg, mesh, "tp", ())
     params = jax.tree.map(
         lambda p, s: jax.device_put(p, s),
         model.init(jax.random.PRNGKey(0)), model.shardings(),
@@ -46,78 +61,89 @@ def _model(mesh, kv_quant=None):
     return model, params
 
 
-def _assert_canonical(model, caches):
-    sh = model.cache_sharding
-    for leaf in jax.tree.leaves(caches):
-        assert leaf.sharding.is_equivalent_to(sh, leaf.ndim), (
-            f"cache leaf on {leaf.sharding} != canonical {sh}"
-        )
+def _first_step(model, params):
+    """``(engine, args)``: the argument tuple of an engine's first
+    device step (a chunk of 8 and a whole prompt of 5), its three
+    static arguments left off — what the shard guards pair with the
+    compiled program's parameters."""
+    eng = ServingEngine(model, params, ENGINE, use_pallas=False)
+    rng = np.random.default_rng(1)
+    for i, n in enumerate((11, 5)):
+        eng.submit(Request(rid=i, prompt=rng.integers(0, 128, (n,))
+                           .astype(np.int32), max_new=2, arrival=0.0))
+    eng._admit()
+    return eng, eng._step_args(eng._assemble()[:7], 8)[:9]
+
+
+def _compiled_step(model, args):
+    """``_serving_jit`` lowered from ABSTRACT arguments carrying the
+    canonical placements (params on ``shardings()``, pools on
+    ``_serving_pool_sharding``, the rest replicated) — lowering from
+    the live arrays would echo their shardings back and make the
+    boundary check vacuous."""
+    rep = NamedSharding(model.mesh, P())
+
+    def abstract(tree, sharding_of):
+        return jax.tree.map(
+            lambda x: jax.ShapeDtypeStruct(
+                x.shape, x.dtype, sharding=sharding_of(x)), tree)
+
+    params, state, *rest = args
+    pools = abstract(state.layers, lambda _: model._serving_pool_sharding)
+    return model._serving_jit.lower(
+        abstract(params, lambda x: x.sharding),
+        abstract(state.replace(layers=()), lambda _: rep)
+        .replace(layers=pools),
+        *abstract(rest, lambda _: rep), 8, False, 2,
+    ).compile()
+
+
+def _off_placement(model, args):
+    """The same arguments with the pools living replicated."""
+    params, state, *rest = args
+    bad = jax.tree.map(
+        lambda x: jax.device_put(
+            np.asarray(x), NamedSharding(model.mesh, P())),
+        state.layers)
+    return (params, state.replace(layers=bad), *rest)
 
 
 class TestServingShardResidency:
     @pytest.mark.parametrize("kv_quant", [None, "int8"])
     def test_decode_no_reshard_and_aliased(self, mesh2x4, kv_quant):
-        """Compile decode_step on the 2×4 dryrun mesh: (i) its cache
-        input shardings equal prefill's output shardings (no
-        involuntary reshard at the boundary), (ii) the compiled program
-        aliases the cache (and lens) inputs to outputs — in-place
-        update survived donation."""
+        """Compile the serving step on the 2×4 dryrun mesh: (i) every
+        argument arrives in the placement the program compiled for (no
+        involuntary reshard at the call boundary), (ii) the compiled
+        program aliases the donated pools to its outputs — the in-place
+        append survived donation — and a step's output pools keep the
+        placement."""
         model, params = _model(mesh2x4, kv_quant)
-        b = 4
-        tokens = jax.device_put(
-            jax.random.randint(jax.random.PRNGKey(1), (b, 16), 0, 128),
-            NamedSharding(mesh2x4, P("dp")),
-        )
-        caches = model.init_cache(b, 32)
-        _assert_canonical(model, caches)       # init placement
-        last, caches, lens = model._prefill_jit(params, caches, tokens)
-        _assert_canonical(model, caches)       # prefill kept it
-
-        first = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        args = (params, caches, lens, first)
-        # lower from ABSTRACT args carrying the canonical placements —
-        # lowering from the live arrays would echo their shardings back
-        # and make the boundary check vacuous
-        comp = model._decode_jit.lower(
-            *model.decode_abstract_args(*args)
-        ).compile()
-        # (i) every argument (params included) arrives in the placement
-        # the program compiled for — nothing is resharded per step
-        assert find_involuntary_resharding(comp, args, min_bytes=0) == []
-        # ... and the check is NON-vacuous: the same program must flag
-        # caches living in a non-canonical placement
-        bad_caches = jax.tree.map(
-            lambda x: jax.device_put(
-                np.asarray(x), NamedSharding(mesh2x4, P())
-            ),
-            caches,
-        )
+        eng, args = _first_step(model, params)
+        pool_sh = model._serving_pool_sharding
+        for leaf in jax.tree.leaves(args[1].layers):  # init placement
+            assert leaf.sharding.is_equivalent_to(pool_sh, leaf.ndim)
+        comp = _compiled_step(model, args)
+        # (the step's nine small host arrays are uploaded to one device
+        # and broadcast: a few hundred bytes, under the guard's floor)
         assert find_involuntary_resharding(
-            comp, (params, bad_caches, lens, first), min_bytes=0
-        )
-        # (ii) caches and kv_lens are input/output-aliased
-        assert_args_aliased(comp, args, lambda a: a[1])
-        assert_args_aliased(comp, args, lambda a: a[2])
-
-        logits, caches2, lens2 = comp(*args)
-        _assert_canonical(model, caches2)      # decode kept it too
-        assert np.asarray(lens2).tolist() == [17] * b
-        assert bool(jnp.isfinite(logits).all())
+            comp, args, min_bytes=POOL_BYTES) == []
+        # ... and the check is NON-vacuous: the same program must flag
+        # pools living in a non-canonical placement
+        assert find_involuntary_resharding(
+            comp, _off_placement(model, args), min_bytes=POOL_BYTES)
+        assert_args_aliased(comp, args, lambda a: a[1],
+                            min_bytes=POOL_BYTES)
+        logits, state = comp(*args)
+        for leaf in jax.tree.leaves(state.layers):    # the step kept it
+            assert leaf.sharding.is_equivalent_to(pool_sh, leaf.ndim)
+        assert logits.shape == (ENGINE.slots, 128)
+        assert bool(jnp.isfinite(logits[:2]).all())
 
     def test_decode_matches_replicated_reference(self, mesh2x4):
-        """The dp-sharded decode path must produce the same logits as
-        the same model run with everything on one device mesh."""
+        """The serving step over the dryrun mesh (heads and experts
+        over tp, replicated over dp) must produce the same logits as
+        the same model with everything on one device."""
         model, params = _model(mesh2x4)
-        b = 4
-        tokens = jax.random.randint(jax.random.PRNGKey(1), (b, 16), 0, 128)
-        caches = model.init_cache(b, 32)
-        last, caches, lens = model._prefill_jit(
-            params, caches,
-            jax.device_put(tokens, NamedSharding(mesh2x4, P("dp"))),
-        )
-        first = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        logits, _, _ = model._decode_jit(params, caches, lens, first)
-
         mesh1 = jax.sharding.Mesh(
             np.asarray(jax.devices()[:1]).reshape(1, 1), ("dp", "tp")
         )
@@ -126,14 +152,15 @@ class TestServingShardResidency:
             jax.tree.map(np.asarray, params),
             NamedSharding(mesh1, P()),
         )
-        caches1 = model1.init_cache(b, 32)
-        last1, caches1, lens1 = model1._prefill_jit(params1, caches1, tokens)
-        logits1, _, _ = model1._decode_jit(
-            params1, caches1, lens1,
-            jnp.argmax(last1, axis=-1).astype(jnp.int32),
-        )
+        rng = np.random.default_rng(1)
+        prompts = [rng.integers(0, 128, (n,)) for n in (11, 5)]
+        _, _, got = serve_all_logits(model, params, ENGINE, prompts,
+                                     max_new=2)
+        _, _, want = serve_all_logits(model1, params1, ENGINE, prompts,
+                                      max_new=2)
         np.testing.assert_allclose(
-            np.asarray(logits), np.asarray(logits1), atol=2e-4, rtol=2e-4
+            np.concatenate(got), np.concatenate(want),
+            atol=2e-4, rtol=2e-4
         )
 
     def test_guard_trips_on_seeded_mismatch(self, mesh2x4):
@@ -181,32 +208,16 @@ class TestServingShardResidency:
         assert 0 in input_output_aliased_params(comp2)
 
     def test_decode_boundary_violation_raises(self, mesh2x4):
-        """The ISSUE-1 negative path on the REAL decode program (not a
-        synthetic lambda): caches living off the canonical placement
+        """The ISSUE-1 negative path on the REAL serving program (not a
+        synthetic lambda): pools living off the canonical placement
         must make ``assert_no_involuntary_resharding`` raise with the
         offending leaf paths in the message."""
         model, params = _model(mesh2x4)
-        b = 4
-        tokens = jax.device_put(
-            jax.random.randint(jax.random.PRNGKey(1), (b, 16), 0, 128),
-            NamedSharding(mesh2x4, P("dp")),
-        )
-        caches = model.init_cache(b, 32)
-        last, caches, lens = model._prefill_jit(params, caches, tokens)
-        first = jnp.argmax(last, axis=-1).astype(jnp.int32)
-        args = (params, caches, lens, first)
-        comp = model._decode_jit.lower(
-            *model.decode_abstract_args(*args)
-        ).compile()
-        bad_caches = jax.tree.map(
-            lambda x: jax.device_put(
-                np.asarray(x), NamedSharding(mesh2x4, P())
-            ),
-            caches,
-        )
+        _, args = _first_step(model, params)
+        comp = _compiled_step(model, args)
         with pytest.raises(AssertionError, match="involuntary resharding"):
             assert_no_involuntary_resharding(
-                comp, (params, bad_caches, lens, first), min_bytes=0
+                comp, _off_placement(model, args), min_bytes=POOL_BYTES
             )
 
     def test_reshard_guard_min_bytes_filters_small_leaves(self, mesh2x4):

@@ -303,8 +303,9 @@ def test_compile_aot_cli_roundtrip(tmp_path):
 
 
 def test_generate_cli(capsys):
-    """The serving CLI: prefill + SP decode generate on a tiny preset
-    (the L7 surface a user drives; tutorial 13 is the library version)."""
+    """The serving CLI: a batch through ``ServingEngine`` on a tiny
+    preset (the L7 surface a user drives; tutorial 13 is the library
+    version)."""
     from triton_distributed_tpu.tools.generate import main
 
     main(["--preset", "tiny", "--batch", "2", "--prompt-len", "8",

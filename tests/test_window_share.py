@@ -412,10 +412,6 @@ REFUSED = {
     "do not lie in": lambda: tiny_config(first_expert_held=6),
     "Transformer.forward does not implement": lambda: one_chip_model(
         tiny_config()).forward(None, jnp.zeros((1, 8), jnp.int32)),
-    "Transformer.prefill does not implement": lambda: one_chip_model(
-        tiny_config()).prefill(None, None, jnp.zeros((1, 8), jnp.int32)),
-    "Transformer.decode_step does not implement": lambda: one_chip_model(
-        tiny_config()).decode_step(None, None, None, None),
 }
 
 

@@ -634,8 +634,7 @@ def pick_block_k(s_len: int, requested: int, *, head_dim: int = 128,
             f"flash_decode: cache slice length {s_len} has no 16-aligned "
             f"divisor <= block_k={requested}, and a whole-length KV block "
             f"(~{est >> 20} MB VMEM) exceeds the safe budget — pad the KV "
-            "cache capacity to a multiple of 16 (init_cache already does; "
-            "custom cache layouts must follow suit)"
+            "cache capacity to a multiple of 16"
         )
     return s_len
 
@@ -1446,10 +1445,10 @@ def _merge_shard_partials(out, lse, axis):
 def _merge_shard_partials_lse(out, lse, axis):
     """Like :func:`_merge_shard_partials` but returning (out, lse) —
     callers can merge FURTHER partials (e.g. the current decode step's
-    just-produced token, models/transformer.decode_step: the softmax
-    merge is associative, so the new token rides as an exact
-    single-position partial with lse = its raw score, and the cache
-    append no longer feeds the attention kernel)."""
+    just-produced token, ``layers.SpGQAFlashDecodeAttention.
+    token_partial``: the softmax merge is associative, so the new token
+    rides as an exact single-position partial with lse = its raw
+    score, and the cache append need not feed the attention kernel)."""
     outs = jax.lax.all_gather(out, axis)
     lses = jax.lax.all_gather(lse, axis)
     return combine_partials(outs, lses, out_dtype=out.dtype)
@@ -1706,8 +1705,8 @@ def sp_paged_gqa_fwd_batch_decode_q8(
     per-rank pool/table contract as :func:`sp_paged_gqa_fwd_batch_decode`
     with int8 pools + (R·npages_local, Hkv, page) f32 scale pools, all
     sharded ``P(axis)`` on dim 0. ``with_lse``: also return the merged
-    (B, Hq) lse so callers can fold further partials (the paged decode
-    step's just-produced token, models/transformer.decode_step)."""
+    (B, Hq) lse so callers can fold further partials (a decode step's
+    just-produced token, ``layers.SpGQAFlashDecodeAttention``)."""
     local_fn, merge_fn = _sp_paged_q8_fns(
         mesh, axis, scale, soft_cap, with_lse, interp_key()
     )

@@ -279,8 +279,7 @@ def append_kv(k_cache, v_cache, kv_lens, k_new, v_new, kv_layout="bhsd",
 
     A row whose length has reached the cache capacity S drops the write
     (JAX out-of-bounds scatter semantics) while the returned length
-    still increments — callers must enforce capacity up front (see the
-    check in models.Transformer.generate).
+    still increments — callers must enforce capacity up front.
 
     INT8 caches (``{"q", "scale"}`` dicts, bhsd only): the new rows are
     quantized per (b, h) — one f32 scale per appended D-row — and both

@@ -6,9 +6,13 @@ test_ag_gemm.py; DeepSeek MoE shapes, test_ep_moe_inference.py) and the
 SP decode layer. This module is the framework-level completion: a
 decoder whose every projection runs through the fused overlap ops, so
 the reference's flagship patterns (AG-GEMM up/qkv, GEMM-RS down/out —
-tutorials 07/08; MoE TP — ag_group_gemm/moe_reduce_rs; SP flash-decode
-— sp_flash_decode_layer.py) ARE the model's hot path, for training and
-decode alike.
+tutorials 07/08; MoE TP — ag_group_gemm/moe_reduce_rs) ARE the training
+path's hot path.
+
+Two entry points: :meth:`Transformer.forward` (training, and the plain
+reference the tests compare with) and :meth:`Transformer.serving_step`
+(everything served: one ragged step of prefill chunks and decode tokens
+over a paged :class:`ServingState`, driven by ``serving.ServingEngine``).
 
 Layout (Megatron sequence-parallel):
 
@@ -40,7 +44,6 @@ from triton_distributed_tpu.layers import (
     ColumnParallelLinear,
     ParallelMLP,
     RowParallelLinear,
-    SpGQAFlashDecodeAttention,
 )
 
 
@@ -70,15 +73,15 @@ class TransformerConfig:
     # per-token scales in the metadata (≡ the reference's headline fp8
     # WITH_SCALE dispatch). Halves the decode wire bytes at n>1;
     # measured neutral at n=1 self-transport (docs/PERF.md). Training
-    # and prefill paths are unaffected (they ride the differentiable
-    # full-precision transport).
+    # is unaffected (it rides the differentiable full-precision
+    # transport).
     moe_wire_quant: str | None = None
     # Weight-only quantization of the EP expert matrices ("int8" |
     # "fp8" | None): serving-decode grouped GEMMs are weight-HBM-bound
     # (B·topk rows vs MB-scale matrices), so 1-byte weights halve the
     # dominant read. Takes effect when the caller runs params through
     # :meth:`Transformer.quantize_moe_weights` (after init/load);
-    # training/prefill paths widen transparently. TPU-first extension —
+    # training (``forward``) widens transparently. TPU-first extension —
     # the reference quantizes only the moving tokens (WITH_SCALE fp8,
     # low_latency_all_to_all.py:82-90), not the stationary weights.
     moe_weight_quant: str | None = None
@@ -95,7 +98,7 @@ class TransformerConfig:
     # is B, so these matmuls are weight-HBM-bound exactly like the
     # expert GEMMs and 1-byte weights halve the dominant read. Takes
     # effect after :meth:`Transformer.quantize_dense_weights`;
-    # prefill/training widen transparently. TPU-first extension.
+    # ``forward`` widens transparently. TPU-first extension.
     dense_weight_quant: str | None = None
     # W8A8 dense projections ("int8" | None): also quantize the B
     # activation rows per step so the dense decode matmuls ride the
@@ -103,12 +106,12 @@ class TransformerConfig:
     # stays W8A16 (logits want the f32 accumulator unperturbed by
     # input quantization); applies to wqkv/wo/up/down.
     dense_act_quant: str | None = None
-    # INT8 KV cache ("int8" | None): decode caches store int8 values +
-    # per-(b, head, position) f32 scales and the SP flash-decode kernel
-    # folds the scales into the softmax — half the KV bytes at rest
+    # INT8 KV cache ("int8" | None): the serving page pools store int8
+    # values + per-(page, head, position) f32 scales and the ragged
+    # paged-attention kernel folds the scales into the softmax — half the KV bytes at rest
     # (2× context per chip) and on the attention DMA stream (measured
     # 25–40% faster decode attention at serving shapes, docs/PERF.md).
-    # TPU-first serving extension; prefill/training are unaffected.
+    # TPU-first serving extension; training is unaffected.
     kv_quant: str | None = None
     # rematerialize each block in backward (jax.checkpoint): trades one
     # extra forward per block for O(n_layers) less activation memory —
@@ -123,8 +126,7 @@ class TransformerConfig:
     # default is the model above: full causal attention, no rotation,
     # an un-gated two-matrix FFN of one width, a softmax router over
     # experts that are all held here. ``serving_step`` implements them;
-    # forward / prefill / decode_step raise on them by name
-    # (``_plain_only``).
+    # ``forward`` raises on them by name (``_plain_only``).
     # per-layer attention kind, "full" | "sliding" (() = all full), and
     # the sliding layers' window: query i sees keys i - window < j <= i
     layer_attn: tuple = ()
@@ -287,7 +289,8 @@ class TransformerConfig:
 
     @property
     def beyond_plain(self) -> tuple:
-        """Names of the set fields only ``serving_step`` implements."""
+        """Names of the set fields only ``serving_step`` implements
+        (``forward`` refuses them)."""
         return tuple(k for k, on in (
             ("layer_attn", bool(self.window_layers)),
             ("rope_layers", bool(self.rope_layers)),
@@ -310,33 +313,6 @@ class TransformerConfig:
     @property
     def qkv_dim(self) -> int:
         return self.q_dim + 2 * self.kv_dim
-
-
-def _cache_capacity(caches):
-    """Sequence capacity S of a per-layer cache list (plain bhsd arrays
-    or int8 {"q", "scale"} dicts)."""
-    ck = caches[0][0]
-    return (ck["q"] if isinstance(ck, dict) else ck).shape[2]
-
-
-def _serving_capacity(caches, block_table=None):
-    """Capacity in sequence positions: the bhsd S dim for contiguous
-    caches, R·pps·page for page pools."""
-    if block_table is None:
-        return _cache_capacity(caches)
-    page = _cache_capacity(caches)      # dim 2 of a pool IS the page
-    r, _, pps = block_table.shape
-    return r * pps * page
-
-
-def _update_q8(cache, q_new, s_new):
-    """Write a quantized (B, Hkv, S', …) prefix into an int8 cache dict."""
-    return {
-        "q": jax.lax.dynamic_update_slice(cache["q"], q_new, (0, 0, 0, 0)),
-        "scale": jax.lax.dynamic_update_slice(
-            cache["scale"], s_new.astype(cache["scale"].dtype), (0, 0, 0)
-        ),
-    }
 
 
 @dataclass(frozen=True)
@@ -368,14 +344,13 @@ class Transformer:
                 "pool is one chip's, the cp shard walk is not built "
                 "over it")
 
-    def _plain_only(self, path: str) -> None:
-        """``forward`` / ``prefill`` / ``decode_step`` run the plain
-        architecture; refuse, by name, a field only ``serving_step``
-        implements."""
+    def _plain_only(self) -> None:
+        """``forward`` runs the plain architecture; refuse, by name, a
+        field only ``serving_step`` implements."""
         beyond = self.config.beyond_plain
         if beyond:
             raise ValueError(
-                f"Transformer.{path} does not implement "
+                "Transformer.forward does not implement "
                 f"{', '.join(beyond)}: serve this configuration through "
                 "serving_step (ServingEngine)")
 
@@ -399,7 +374,7 @@ class Transformer:
     def token_shards(self) -> int:
         """Number of row shards of the SP activation layout (tp × dp) —
         the single definition of the padding/shard-count arithmetic used
-        by both prefill (EPMoEMLP) and decode (_decode_moe_ep)."""
+        by both forward (EPMoEMLP) and serving (_decode_moe_ep)."""
         return self.tp * int(
             np.prod([self.mesh.shape[a] for a in self.dp_axes]) or 1
         )
@@ -589,7 +564,7 @@ class Transformer:
         sharding from the source arrays. ``mode`` defaults to
         ``config.moe_weight_quant``; returns ``params`` unchanged when
         both are None. Decode consumes the dicts in the grouped-GEMM
-        epilogue; prefill/training widen transparently."""
+        epilogue; ``forward`` widens transparently."""
         mode = mode or self.config.moe_weight_quant
         if mode is None:
             return params
@@ -617,8 +592,8 @@ class Transformer:
         up/down per block, plus lm_head) with ``{"q": int8 (K, N),
         "scale": (N,) f32}`` dicts (per-out-channel, the same
         convention as the expert weights). Decode consumes them through
-        the grouped-GEMM epilogue-dequant kernel; prefill/training
-        widen transparently. Run AFTER init/load + device placement;
+        the grouped-GEMM epilogue-dequant kernel; ``forward`` widens
+        transparently. Run AFTER init/load + device placement;
         ``mode`` defaults to ``config.dense_weight_quant``."""
         mode = mode or self.config.dense_weight_quant
         if mode is None:
@@ -645,7 +620,7 @@ class Transformer:
         return out
 
     def _dense_w(self, w):
-        """Dense weight for a widening consumer (prefill/training):
+        """Dense weight for a widening consumer (``forward``):
         dequantize a dict, cast a plain array to the compute dtype."""
         if isinstance(w, dict):
             from triton_distributed_tpu.kernels.group_gemm import (
@@ -814,7 +789,7 @@ class Transformer:
     def _cp_attention(self, blk, x, b, s):
         """Context-parallel attention: sequence sharded over tp, heads
         whole, projection weights replicated (the long-context layout).
-        x: (B·S, H) SP rows → ((B·S, H) SP rows, k, v)."""
+        x: (B·S, H) SP rows → (B·S, H) SP rows."""
         from triton_distributed_tpu.kernels.ring_attention import (
             ring_attention,
             ulysses_attention,
@@ -836,17 +811,14 @@ class Transformer:
         attn = ring_attention if c.attn == "ring" else ulysses_attention
         o = attn(q, k, v, self.mesh, self.tp_axis, batch_axes=ba)
         o = o.reshape(b, s, c.q_dim) @ self._dense_w(blk["wo"])
-        out = jax.lax.with_sharding_constraint(
+        return jax.lax.with_sharding_constraint(
             o.reshape(b * s, c.hidden),
             NamedSharding(self.mesh, self.row_spec),
         )
-        return out, k, v
 
-    def _attention_kv(self, blk, x, b, s):
-        """Attention returning (out rows, k, v) — the K/V are what
-        :meth:`prefill` writes into the decode caches. Dispatches to the
-        context-parallel path for attn='ring'/'ulysses' (their K/V come
-        back sequence-sharded, matching the seq-sharded caches)."""
+    def _attention(self, blk, x, b, s):
+        """x: (B·S, H) SP rows → (B·S, H) SP rows. Heads sharded tp;
+        attn='ring'/'ulysses' take the context-parallel path."""
         c = self.config
         if c.attn != "tp":
             return self._cp_attention(blk, x, b, s)
@@ -866,14 +838,9 @@ class Transformer:
         probs = jax.nn.softmax(logits, axis=-1).astype(c.dtype)
         o = jnp.einsum("bhgst,bthd->bshgd", probs, v)
         o = o.reshape(b * s, hq * d)
-        out = ops.gemm_rs(o, self._dense_w(blk["wo"]), self._rs_ctx)
-        return out, k, v
+        return ops.gemm_rs(o, self._dense_w(blk["wo"]), self._rs_ctx)
 
-    def _attention(self, blk, x, b, s):
-        """x: (B·S, H) SP rows → (B·S, H) SP rows. Heads sharded tp."""
-        return self._attention_kv(blk, x, b, s)[0]
-
-    def _mlp_block(self, blk, x, inference=False):
+    def _mlp_block(self, blk, x):
         c = self.config
         if "up" in blk:
             p = {
@@ -894,18 +861,9 @@ class Transformer:
             return EPMoEMLP(
                 self._moe_ep_ctx(x.shape[0] // self.token_shards)
             )(moe_params, x)
-        # TP flavour — one routing computation feeds either body
+        # TP flavour
         logits = x.astype(jnp.float32) @ blk["router"]
         weights, ids = mu.select_experts(logits, c.topk)
-        if inference and not self.dp_axes:
-            # inference (no grads needed): the single-kernel overlapped
-            # engines replace the composed differentiable pipeline
-            from triton_distributed_tpu.ops import moe_tp_mlp_overlapped
-
-            return moe_tp_mlp_overlapped(
-                x, ids, weights, moe_params["up"], moe_params["down"],
-                self._moe_tp_ctx,
-            ).astype(c.dtype)
         from triton_distributed_tpu.layers import MoETPMLP
 
         return MoETPMLP(self._moe_tp_ctx)(moe_params, x, ids, weights)
@@ -917,22 +875,6 @@ class Transformer:
             x, NamedSharding(self.mesh, self.row_spec)
         )
 
-    def _block(self, blk, x, b, s, inference=False):
-        """One decoder block → (x, k, v). The SINGLE definition of the
-        block math — forward and prefill both run exactly this (prefill
-        keeps the k/v for cache filling; forward drops them);
-        ``inference`` selects the non-differentiable overlapped engines
-        where they exist (MoE-TP)."""
-        xn = self._rmsnorm(x, blk["norm_attn"])
-        # k/v are always produced; XLA dead-code-eliminates them when the
-        # caller (forward) drops them
-        h, k, v = self._attention_kv(blk, xn, b, s)
-        x = x + h
-        x = x + self._mlp_block(
-            blk, self._rmsnorm(x, blk["norm_mlp"]), inference=inference
-        )
-        return x, k, v
-
     def _head(self, params, x):
         x = self._rmsnorm(x, params["norm_f"])
         w = params["lm_head"]
@@ -942,13 +884,16 @@ class Transformer:
 
     def forward(self, params, tokens):
         """tokens: (B, S) int32 → logits (B·S, vocab) SP-row-sharded."""
-        self._plain_only("forward")
+        self._plain_only()
         c = self.config
         b, s = tokens.shape
         x = self._embed_rows(params, tokens)
 
         def block(x, blk):
-            return self._block(blk, x, b, s)[0]
+            x = x + self._attention(
+                blk, self._rmsnorm(x, blk["norm_attn"]), b, s)
+            return x + self._mlp_block(
+                blk, self._rmsnorm(x, blk["norm_mlp"]))
 
         if c.remat:
             from triton_distributed_tpu.config import (
@@ -983,271 +928,15 @@ class Transformer:
         new = jax.tree.map(lambda p, d: p - lr * d.astype(p.dtype), params, g)
         return l, new
 
-    # ---------------------------------------------------------------- decode
-
-    @functools.cached_property
-    def _sp_attn(self):
-        c = self.config
-        return SpGQAFlashDecodeAttention(
-            self.mesh, self.tp_axis, q_heads=c.n_heads,
-            kv_heads=c.n_kv_heads, head_dim=c.head_dim,
-            batch_axes=tuple(self.dp_axes),
-        )
-
-    @property
-    def cache_sharding(self):
-        """The ONE canonical KV-cache placement for the whole serving
-        session: batch over dp, sequence over tp (dims 0 and 2 of both
-        the (B, Hkv, S, D) planes and the (B, Hkv, S) int8 scales).
-        init_cache places with it, prefill and decode_step pin their
-        cache outputs to it, and the decode jits donate the caches —
-        so the cache is SHARD-RESIDENT and updated in place for the
-        life of the session (≡ sp_flash_decode_layer.py:45-184, whose
-        per-rank KV shard never changes placement), with no
-        involuntary remat/reshard across the prefill→decode boundary."""
-        ba = tuple(self.dp_axes)
-        return NamedSharding(
-            self.mesh, P(ba if ba else None, None, self.tp_axis)
-        )
-
-    @property
-    def batch_sharding(self):
-        """(B,)-vector placement matching :attr:`cache_sharding`'s
-        batch dim (kv_lens, last_tokens, per-row logits)."""
-        ba = tuple(self.dp_axes)
-        return NamedSharding(self.mesh, P(ba if ba else None))
-
-    def _pin_caches(self, caches, paged=False):
-        """with_sharding_constraint every cache leaf to the canonical
-        :attr:`cache_sharding` (same spec covers the 4D planes and the
-        3D scale leaves — batch dim 0, sequence dim 2); page pools pin
-        their rank-major page dim over tp instead."""
-        sh = self._paged_sharding if paged else self.cache_sharding
-        return jax.tree.map(
-            lambda x: jax.lax.with_sharding_constraint(x, sh), caches
-        )
-
-    def init_cache(self, batch: int, max_len: int):
-        """Per-layer (k, v) caches, (B, Hkv, S, D) ["bhsd", the fast
-        decode layout — contiguous KV block DMAs] placed on
-        :attr:`cache_sharding` — batch over dp, sequence over tp (≡ the
-        KV sharding of sp_flash_decode_layer.py: each rank holds its
-        slice of the sequence). With ``config.kv_quant``, each cache is
-        a ``{"q": int8, "scale": (B, Hkv, S) f32}`` dict (the
-        quantized-leaf convention shared with the expert weights)."""
-        c = self.config
-        spec = self.cache_sharding
-        if c.kv_quant is not None:
-            zq = jax.device_put(
-                jnp.zeros(
-                    (batch, c.n_kv_heads, max_len, c.head_dim), jnp.int8
-                ),
-                spec,
-            )
-            zs = jax.device_put(
-                jnp.ones((batch, c.n_kv_heads, max_len), jnp.float32), spec
-            )
-
-            # EVERY leaf gets its own buffer (`+ 0` after placement):
-            # the decode jits DONATE the caches, and donating one
-            # physical buffer through two pytree leaves is a runtime
-            # error ("attempt to donate the same buffer twice")
-            def fresh():
-                return {"q": zq + jnp.int8(0), "scale": zs + 0.0}
-
-            return [(fresh(), fresh()) for _ in range(c.n_layers)]
-        z = jnp.zeros((batch, c.n_kv_heads, max_len, c.head_dim), c.dtype)
-        zz = jax.device_put(z, spec)
-        return [
-            (zz + jnp.zeros((), c.dtype), zz + jnp.zeros((), c.dtype))
-            for _ in range(c.n_layers)
-        ]
-
-    @property
-    def _paged_sharding(self):
-        """Pool placement: pages (rank-major dim 0) over tp."""
-        return NamedSharding(self.mesh, P(self.tp_axis))
-
-    def init_paged_cache(self, batch: int, max_len: int, page: int = 1024):
-        """PAGED twin of :meth:`init_cache` — the production serving
-        mode (the reference's block-table path is its default decode
-        entry, flash_decode.py:763-846). Returns ``(caches, table)``:
-        per-layer (k_pool, v_pool) page pools of shape
-        (R·B·pps, Hkv, page, D) sharded over tp on the page dim (rank
-        r owns its sequence slice's pages), int8 ``{"q","scale"}``
-        dicts under ``config.kv_quant``; and ONE (R, B, pps) block
-        table of LOCAL page ids shared by every layer (dense identity
-        allocation — a serving stack with its own allocator passes any
-        table honoring the same contract). Paged mode is tp-only: the
-        pool layout is rank-major, so dp composes by running one model
-        per dp group."""
-        c = self.config
-        if self.dp_axes:
-            raise ValueError("paged caches are tp-only (rank-major pools)")
-        r = self.tp
-        if max_len % (r * page):
-            raise ValueError(
-                f"capacity {max_len} must split into {r} rank slices of "
-                f"whole {page}-row pages"
-            )
-        pps = max_len // r // page
-        npages = r * batch * pps
-        spec = self._paged_sharding
-        table = jax.device_put(
-            jnp.broadcast_to(
-                jnp.arange(batch * pps, dtype=jnp.int32).reshape(
-                    1, batch, pps
-                ),
-                (r, batch, pps),
-            ),
-            spec,
-        )
-        if c.kv_quant is not None:
-            zq = jax.device_put(
-                jnp.zeros((npages, c.n_kv_heads, page, c.head_dim),
-                          jnp.int8),
-                spec,
-            )
-            zs = jax.device_put(
-                jnp.ones((npages, c.n_kv_heads, page), jnp.float32), spec
-            )
-
-            def fresh():
-                # independent buffers per leaf — the decode jits donate
-                return {"q": zq + jnp.int8(0), "scale": zs + 0.0}
-
-            return [(fresh(), fresh()) for _ in range(c.n_layers)], table
-        z = jax.device_put(
-            jnp.zeros((npages, c.n_kv_heads, page, c.head_dim), c.dtype),
-            spec,
-        )
-        zero = jnp.zeros((), c.dtype)
-        return [(z + zero, z + zero) for _ in range(c.n_layers)], table
-
-    def paginate_caches(self, caches, page: int = 1024):
-        """Convert CONTIGUOUS (prefill-filled) caches into page pools +
-        table — the prefill→paged-decode bridge: one reshape/transpose
-        per plane, no gather (pages of the dense identity allocation
-        are exactly the contiguous cache's page-aligned rows)."""
-        r = self.tp
-
-        def split(x):                       # (B, Hkv, S, D?) → pools
-            b, hkv, s = x.shape[:3]
-            tail = x.shape[3:]
-            pps = s // r // page
-            y = x.reshape((b, hkv, r, pps, page) + tail)
-            # (R, B, pps, Hkv, page, tail) → rank-major page rows
-            y = jnp.moveaxis(y, (2, 0, 3, 1), (0, 1, 2, 3))
-            return jax.device_put(
-                y.reshape((r * b * pps, hkv, page) + tail),
-                self._paged_sharding,
-            )
-
-        out = []
-        batch = None
-        for ck, cv in caches:
-            if isinstance(ck, dict):
-                batch = ck["q"].shape[0]
-                s = ck["q"].shape[2]
-                ck = {"q": split(ck["q"]), "scale": split(ck["scale"])}
-                cv = {"q": split(cv["q"]), "scale": split(cv["scale"])}
-            else:
-                batch, s = ck.shape[0], ck.shape[2]
-                ck, cv = split(ck), split(cv)
-            out.append((ck, cv))
-        pps = s // r // page
-        table = jax.device_put(
-            jnp.broadcast_to(
-                jnp.arange(batch * pps, dtype=jnp.int32).reshape(
-                    1, batch, pps
-                ),
-                (r, batch, pps),
-            ),
-            self._paged_sharding,
-        )
-        return out, table
-
-    def prefill(self, params, caches, tokens, lens=None):
-        """Process a whole prompt batch in ONE forward pass and fill the
-        decode caches: returns (per-row last-position logits (B, vocab),
-        caches, kv_lens). The serving entry the reference leaves to the
-        serving stack — :meth:`generate` continues from here instead of
-        decoding the prompt token by token.
-
-        ``lens`` (B,) enables RAGGED batches: rows are right-padded to S
-        and ``lens[i]`` names row i's true prompt length. Causality makes
-        the short rows' valid positions independent of their padding, the
-        pad positions' K/V land beyond ``lens`` where decode never reads,
-        and the returned logits are taken at each row's ``lens-1``.
-
-        tokens: (B, S) int32, S ≤ cache capacity. Attention runs the
-        forward path of the configured mode (TP: AG-GEMM qkv → dense
-        causal softmax → GEMM-RS out; ring/ulysses: the CP kernels,
-        whose K/V come back sequence-sharded like the caches) while the
-        per-layer K/V are captured into the bhsd seq-sharded caches;
-        MoE-TP blocks run the overlapped inference engines.
-        """
-        self._plain_only("prefill")
-        c = self.config
-        b, s = tokens.shape
-        cap = _cache_capacity(caches)
-        assert s <= cap, f"prompt length {s} exceeds cache capacity {cap}"
-        x = self._embed_rows(params, tokens)
-        new_caches = []
-        for blk, (ck, cv) in zip(params["blocks"], caches):
-            x, k, v = self._block(blk, x, b, s, inference=True)
-            kb = k.transpose(0, 2, 1, 3)              # (B, Hkv, S, D)
-            vb = v.transpose(0, 2, 1, 3)
-            if isinstance(ck, dict):                  # int8 cache
-                from triton_distributed_tpu.kernels.flash_decode import (
-                    quantize_kv,
-                )
-
-                ck = _update_q8(ck, *quantize_kv(kb))
-                cv = _update_q8(cv, *quantize_kv(vb))
-            else:
-                ck = jax.lax.dynamic_update_slice(
-                    ck, kb.astype(ck.dtype), (0, 0, 0, 0)
-                )
-                cv = jax.lax.dynamic_update_slice(
-                    cv, vb.astype(cv.dtype), (0, 0, 0, 0)
-                )
-            new_caches.append((ck, cv))
-        logits = self._head(params, x)
-        if lens is None:
-            lens = jnp.full((b,), s, jnp.int32)
-        # clamp to the valid range: lens=0 would gather position -1 (the
-        # last PAD) and lens>s would make decode attend over unwritten
-        # cache rows — both silently wrong, neither assertable on traced
-        # values
-        lens = jnp.clip(lens.astype(jnp.int32), 1, s)
-        last = logits.reshape(b, s, -1)[jnp.arange(b), lens - 1]
-        # pin the serving state to the canonical placements so the
-        # prefill outputs are bit-identical in placement to decode's
-        # inputs — without this the dp×tp compile chooses freely and
-        # XLA full-rematerializes the caches at the phase boundary
-        # (last is pinned too: argmax over it produces the first decode
-        # step's last_tokens already batch-over-dp)
-        new_caches = self._pin_caches(new_caches)
-        lens = jax.lax.with_sharding_constraint(lens, self.batch_sharding)
-        last = jax.lax.with_sharding_constraint(last, self.batch_sharding)
-        return last, new_caches, lens
-
-    @functools.cached_property
-    def _prefill_jit(self):
-        # donate the (zero-filled) input caches: prefill writes into
-        # them and the output placement equals the input placement
-        # (cache_sharding), so XLA aliases instead of allocating a
-        # second cache-sized buffer set
-        return jax.jit(self.prefill, donate_argnums=(1,))
-        # lens=None and lens=(B,) trace separately
+    # ------------------------------------------------------- ragged serving
 
     def init_decode_state(self, batch: int, abstract: bool = False):
         """Per-layer persistent workspaces for the BARRIER-FREE fused
         EP-MoE decode transport (ops.EPMoEState): one state per MoE
         layer, None elsewhere. Returns None when the model has no EP
         layers or decode would ride the XLA transport (off-TPU / DCN tp
-        axis) — :meth:`decode_step` then needs no state at all.
+        axis) — :meth:`serving_step` then needs no state at all.
+        ``batch`` is the step's packed width (the engine's ``_t_pad``).
         ``abstract=True`` yields ShapeDtypeStruct leaves (topology
         compiles)."""
         c = self.config
@@ -1265,157 +954,6 @@ class Transformer:
             for i in range(c.n_layers)
         ]
 
-    def decode_step(self, params, caches, kv_lens, last_tokens,
-                    moe_state=None, block_table=None):
-        """One token of SP decode: replicated (B,) last tokens + seq-
-        sharded caches → (B, vocab) logits, updated caches/lens.
-
-        ``block_table`` switches to PAGED serving: ``caches`` are the
-        page pools from :meth:`init_paged_cache` /
-        :meth:`paginate_caches` and attention + append walk the table
-        (≡ the reference's block-table decode default,
-        flash_decode.py:763-846).
-
-        Attention runs through the distributed flash-decode layer
-        (local split-kv + AG(out,lse) + LSE combine); projections are
-        plain matmuls — at decode the M dim is B, far too small for the
-        overlap engines (matching the reference, whose decode path is
-        the SP attention kernel, not AG-GEMM).
-
-        ``moe_state`` (from :meth:`init_decode_state`): per-layer LL
-        workspaces — EP-MoE blocks then run the fused transport
-        BARRIER-FREE (≡ the reference's call_count protocol) and the
-        step returns a 4th element, the updated state to thread into
-        the next step.
-        """
-        self._plain_only("decode_step")
-        c = self.config
-        from triton_distributed_tpu.layers import append_kv
-
-        x = params["embed"][last_tokens].astype(c.dtype)        # (B, H)
-        # batch rows over dp end to end: the decode step is
-        # data-parallel over dp (each dp group serves its rows against
-        # its resident cache shards) — pinning x here keeps GSPMD from
-        # electing a layout that replicates the caches
-        x = jax.lax.with_sharding_constraint(x, self.batch_sharding)
-        b = x.shape[0]
-        new_caches = []
-        new_states = None if moe_state is None else list(moe_state)
-        from triton_distributed_tpu.kernels.flash_decode import (
-            combine_partials,
-        )
-
-        qkv_sh, wo_sh = self._attn_proj_shard
-        for li, (blk, (ck, cv)) in enumerate(zip(params["blocks"], caches)):
-            xn = self._rmsnorm(x, blk["norm_attn"])
-            qkv = self._dmm(xn, blk["wqkv"], shard=qkv_sh)      # (B, qkv)
-            q, k, v = jnp.split(qkv, [c.q_dim, c.q_dim + c.kv_dim], axis=-1)
-            q = q.reshape(b, c.n_heads, c.head_dim)
-            k = k.reshape(b, c.n_kv_heads, c.head_dim)
-            v = v.reshape(b, c.n_kv_heads, c.head_dim)
-            # attention over the OLD cache + the just-produced token as
-            # an exact single-position softmax partial (its lse is the
-            # raw score; weight-1 softmax over one position). The merge
-            # is associative, so this equals attending over the
-            # appended cache — WITHOUT the attention kernel reading the
-            # append's scatter output (XLA serializes scatter→kernel
-            # with a cache-sized copy pass; measured ~170 µs/step at
-            # the serving shape). The append below only feeds the NEXT
-            # step and schedules independently.
-            kv_quant = None
-            if isinstance(ck, dict):
-                # int8 cache: every LATER step reads this token's
-                # quantized form — attend it quantized NOW too, so the
-                # step's logits are bit-consistent with re-running
-                # attention over the appended quantized cache. The
-                # append below receives the SAME (q, scale) pairs the
-                # attention saw (re-quantizing the bf16 round-trip can
-                # shift the recomputed ints by 1 LSB — ADVICE r5), so
-                # the claimed bit-consistency is exact, not approximate.
-                from triton_distributed_tpu.kernels.flash_decode import (
-                    quantize_kv,
-                )
-
-                kq8, ks8 = quantize_kv(k)
-                vq8, vs8 = quantize_kv(v)
-                kv_quant = ((kq8, ks8), (vq8, vs8))
-                k = (kq8.astype(jnp.float32) * ks8[..., None]).astype(k.dtype)
-                v = (vq8.astype(jnp.float32) * vs8[..., None]).astype(v.dtype)
-            o_c, lse_c = self._sp_attn.partials(
-                q, ck, cv, kv_lens, block_table
-            )
-            # the token partial comes from the SAME layer so its score
-            # convention (scale, soft_cap) cannot drift from the
-            # kernel's lse domain
-            o_new, lse_new = self._sp_attn.token_partial(q, k, v)
-            o, _ = combine_partials(
-                jnp.stack([o_c.astype(jnp.float32), o_new]),
-                jnp.stack([lse_c, lse_new]),
-                out_dtype=o_c.dtype,
-            )
-            kq_pair = kv_quant[0] if kv_quant is not None else None
-            vq_pair = kv_quant[1] if kv_quant is not None else None
-            if block_table is None:
-                ck, cv, _ = append_kv(
-                    ck, cv, kv_lens, k, v, kv_layout="bhsd",
-                    k_quant=kq_pair, v_quant=vq_pair,
-                )
-            else:
-                from triton_distributed_tpu.layers import paged_append_kv
-
-                ck, cv, _ = paged_append_kv(
-                    ck, cv, block_table, kv_lens, k, v,
-                    k_quant=kq_pair, v_quant=vq_pair,
-                )
-            new_caches.append((ck, cv))
-            o = self._dmm(o.reshape(b, c.q_dim), blk["wo"], shard=wo_sh)
-            x = x + o
-            xn = self._rmsnorm(x, blk["norm_mlp"])
-            if "up" in blk:
-                h = jax.nn.silu(self._dmm(xn, blk["up"], shard="col"))
-                x = x + self._dmm(h, blk["down"], shard="row")
-            elif c.moe == "ep":
-                st = None if moe_state is None else moe_state[li]
-                y, st = self._decode_moe_ep(blk, xn, st)
-                x = x + y.astype(x.dtype)
-                if new_states is not None:
-                    new_states[li] = st
-            else:
-                # TP flavour: experts replicated on the expert dim (only
-                # F is sharded), so the per-topk gather stays shard-local
-                # — (B, H, F/tp) per device, no cross-shard weight moves
-                logits_r = xn.astype(jnp.float32) @ blk["router"]
-                w, ids = mu.select_experts(logits_r, c.topk)
-                y = jnp.zeros_like(xn, dtype=jnp.float32)
-                for t in range(c.topk):
-                    hh = jax.nn.silu(
-                        jnp.einsum("bh,bhf->bf", xn, blk["moe_up"][ids[:, t]].astype(c.dtype))
-                    )
-                    y += w[:, t:t + 1] * jnp.einsum(
-                        "bf,bfh->bh", hh, blk["moe_down"][ids[:, t]].astype(c.dtype)
-                    ).astype(jnp.float32)
-                x = x + y.astype(x.dtype)
-        x = self._rmsnorm(x, params["norm_f"])
-        if isinstance(params["lm_head"], dict):
-            # W8A16 deliberately: logits take the f32 accumulator
-            # without input-quantization noise
-            logits = self._dmm(
-                x, params["lm_head"], out_dtype=jnp.float32, act_quant=False
-            )
-        else:
-            logits = x.astype(jnp.float32) @ params["lm_head"]
-        # outputs pinned to the SAME placements as the inputs
-        # (cache_sharding / batch over dp): with the decode jits'
-        # donation this makes every step's cache update alias in place
-        # — no cache-sized copy, no cross-step reshard
-        new_caches = self._pin_caches(new_caches, paged=block_table is not None)
-        new_lens = jax.lax.with_sharding_constraint(
-            kv_lens + 1, self.batch_sharding
-        )
-        if moe_state is None:
-            return logits, new_caches, new_lens
-        return logits, new_caches, new_lens, new_states
-
     def _dense_mlp(self, xn, w_up, w_down):
         """The serving step's dense FFN on normed rows ``xn``:
         ``down(silu(up(x)))``, or gated (``config.gated_ffn``, ``w_up``
@@ -1429,8 +967,8 @@ class Transformer:
         return self._dmm(h, w_down, shard="row")
 
     def _decode_moe_ep(self, blk, xn, state=None, row_mask=None):
-        """Decode-step EP MoE: the B last-token activations ride the EP
-        dispatch → sharded grouped expert MLP → combine machinery, so
+        """A serving step's EP MoE: the B packed-token activations ride
+        the EP dispatch → sharded grouped expert MLP → combine machinery, so
         expert weights STAY sharded — no gathered (B, H, F) weight
         tensor ever materializes (the reference's EP-MoE inference
         headline: test_ep_moe_inference.py, decode-sized batches through
@@ -1480,161 +1018,19 @@ class Transformer:
             y = ops.ep_moe(xp, logits, w_up, w_down, ctx)
         return y[:b], state
 
-    def decode_abstract_args(self, params, caches, kv_lens, last_tokens):
-        """``ShapeDtypeStruct`` twins of one decode step's arguments
-        with the CANONICAL serving placements attached (caches on
-        :attr:`cache_sharding`, lens/tokens on :attr:`batch_sharding`;
-        params keep their live placements). Lower the decode jits from
-        THESE when compile-checking the serving data flow (dryrun /
-        shardguard tests): a program lowered from the live arrays
-        reports those arrays' own shardings back, so a phase-boundary
-        check against it could never fail."""
-
-        def abs_(x, s):
-            return jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=s)
-
-        return (
-            jax.tree.map(lambda x: abs_(x, x.sharding), params),
-            jax.tree.map(lambda x: abs_(x, self.cache_sharding), caches),
-            abs_(kv_lens, self.batch_sharding),
-            abs_(last_tokens, self.batch_sharding),
-        )
-
-    @functools.cached_property
-    def _decode_jit(self):
-        # donate caches + kv_lens: with the in/out placements pinned
-        # (cache_sharding), XLA aliases the cache params to the cache
-        # results and append_kv updates IN PLACE — the production entry
-        # no longer pays a cache-sized copy per token (≡ the reference
-        # kernels mutating the persistent cache tensors,
-        # flash_decode.py:763-846)
-        return jax.jit(self.decode_step, donate_argnums=(1, 2))
-
-    @functools.cached_property
-    def _decode_jit_state(self):
-        def step(params, caches, kv_lens, last_tokens, moe_state,
-                 block_table=None):
-            return self.decode_step(params, caches, kv_lens, last_tokens,
-                                    moe_state, block_table)
-
-        # donate the caches/lens (in-place update, see _decode_jit) AND
-        # the LL workspaces: the barrier-free protocol requires the
-        # SAME physical buffers across steps (skewed peers' in-flight
-        # DMAs target the persistent addresses)
-        return jax.jit(step, donate_argnums=(1, 2, 4))
-
-    def generate(self, params, caches, kv_lens, last_tokens, steps: int,
-                 moe_state=None, block_table=None):
-        """Greedy decode ``steps`` tokens. The whole decode step is one
-        jitted program (cached across steps and calls by shape). With
-        ``moe_state`` (init_decode_state), EP-MoE blocks run the
-        barrier-free fused transport and the state comes back as a 4th
-        result for continuation. With ``block_table``, caches are page
-        pools (init_paged_cache / paginate_caches)."""
-        cap = _serving_capacity(caches, block_table)
-        try:
-            max_len = int(np.asarray(kv_lens).max()) + steps
-            assert max_len <= cap, (
-                f"cache capacity {cap} < {max_len} needed — writes past "
-                f"capacity are silently dropped (see layers.append_kv)"
-            )
-        except jax.errors.TracerArrayConversionError:
-            pass  # traced lens: caller owns the capacity contract
-        out = []
-        for _ in range(steps):
-            if moe_state is None:
-                logits, caches, kv_lens = self._decode_jit(
-                    params, caches, kv_lens, last_tokens,
-                    block_table=block_table,
-                )
-            else:
-                logits, caches, kv_lens, moe_state = self._decode_jit_state(
-                    params, caches, kv_lens, last_tokens, moe_state,
-                    block_table=block_table,
-                )
-            last_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-            out.append(last_tokens)
-        toks = jnp.stack(out, axis=1)
-        if moe_state is None:
-            return toks, caches, kv_lens
-        return toks, caches, kv_lens, moe_state
-
-    @functools.cached_property
-    def _generate_scan_jit(self):
-        @functools.partial(
-            jax.jit, static_argnums=(4,), donate_argnums=(1, 2, 5)
-        )
-        def run(params, caches, kv_lens, last_tokens, steps, moe_state,
-                block_table=None):
-            def body(carry, _):
-                caches, lens, toks, state = carry
-                if state is None:
-                    logits, caches, lens = self.decode_step(
-                        params, caches, lens, toks,
-                        block_table=block_table,
-                    )
-                else:
-                    logits, caches, lens, state = self.decode_step(
-                        params, caches, lens, toks, state,
-                        block_table=block_table,
-                    )
-                toks = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-                return (caches, lens, toks, state), toks
-
-            (caches, lens, toks, state), out = jax.lax.scan(
-                body, (caches, kv_lens, last_tokens, moe_state),
-                None, length=steps,
-            )
-            return out.swapaxes(0, 1), caches, lens, state
-
-        return run
-
-    def generate_scan(self, params, caches, kv_lens, last_tokens,
-                      steps: int, moe_state=None, block_table=None):
-        """Greedy-decode ``steps`` tokens ON DEVICE: one jitted program
-        whose ``lax.scan`` carries the caches, lens, tokens and the LL
-        MoE state across steps — no host round-trip per token. Same
-        results as :meth:`generate` (the per-step twin kept for
-        step-at-a-time callers and CI); one dispatch per SEQUENCE
-        instead of one per token. The functional ``EPMoEState`` carry exists precisely
-        so the barrier-free fused transport can ride a scan; caches,
-        lens and state are donated (in place across calls, like the
-        per-step jits)."""
-        cap = _serving_capacity(caches, block_table)
-        try:
-            max_len = int(np.asarray(kv_lens).max()) + steps
-            assert max_len <= cap, (
-                f"cache capacity {cap} < {max_len} needed — writes past "
-                f"capacity are silently dropped (see layers.append_kv)"
-            )
-        except jax.errors.TracerArrayConversionError:
-            pass  # traced lens: caller owns the capacity contract
-        toks, caches, kv_lens, moe_state = self._generate_scan_jit(
-            params, caches, kv_lens, last_tokens, steps, moe_state,
-            block_table,
-        )
-        if moe_state is None:
-            return toks, caches, kv_lens
-        return toks, caches, kv_lens, moe_state
-
-    # ------------------------------------------------------- ragged serving
-
     @property
     def _serving_pool_sharding(self):
         """Serving pool placement: KV HEADS (dim 1) over tp. Heads are
         independent in GQA attention, so the ragged serving step never
         exchanges LSE partials across ranks — and the whole page pool
         (dim 0) is one shared allocation any rank can serve any request
-        from, which is what the engine's single free list requires.
-        (The decode path's sequence sharding instead concentrates a
-        short request's pages — and its attention work — on rank 0.)"""
+        from, which is what the engine's single free list requires."""
         return NamedSharding(self.mesh, P(None, self.tp_axis))
 
     def init_serving_state(self, slots: int, npages: int, page: int,
                            chunk: int | None = None):
         """Build a fresh :class:`~triton_distributed_tpu.serving.state.
-        ServingState` — the explicit serving-state object replacing the
-        ``init_paged_cache``/``paginate_caches`` tuple plumbing for the
+        ServingState` — the serving-state object of the
         continuous-batching engine: per-layer head-sharded page pools,
         one shared (slots, pages_per_seq) block table (allocator-owned,
         -1 = unallocated), per-slot kv_lens and cursors. Every leaf
@@ -1897,7 +1293,9 @@ class Transformer:
         state')`` — logits at each slot's LAST packed token (the
         next-token distribution for rows that finished a chunk at their
         prompt end, garbage for q_lens == 0 slots), plus ``moe_state'``
-        threaded as in :meth:`decode_step` when given.
+        (from :meth:`init_decode_state`: per-layer LL workspaces, so
+        EP-MoE blocks run the fused transport BARRIER-FREE) when given,
+        to thread into the next step.
 
         ``topologies``: optional (slots, 2+2W) int32 per-row attention-
         topology descriptors (kernels/ragged_paged_attention.py layout)
@@ -2154,8 +1552,9 @@ class Transformer:
 
     @functools.cached_property
     def _serving_jit(self):
-        # donate the ServingState (pool append aliases in place — the
-        # same discipline as the decode jits) and the LL MoE workspaces
+        # donate the ServingState (the pool append aliases in place) and
+        # the LL MoE workspaces (the barrier-free protocol needs the
+        # SAME physical buffers across steps)
         @functools.partial(
             jax.jit, static_argnums=(9, 10, 11), donate_argnums=(1, 8)
         )

@@ -4,16 +4,16 @@ Two silent performance killers on a multi-chip mesh:
 
 * **Involuntary resharding at a phase boundary** — a program compiled
   with parameter shardings that differ from the placements of the
-  arrays the caller will actually pass (e.g. prefill producing KV
-  caches in one layout while decode compiles wanting another). XLA
+  arrays the caller will actually pass (e.g. a serving state built in
+  one layout while the step compiles wanting another). XLA
   "fixes" it with a full copy/reshard of the argument every call —
-  cache-sized traffic per decode step at pod scale. The round-4
+  pool-sized traffic per serving step at pod scale. The round-4
   dryrun's compile log caught exactly this by accident ("[SPMD]
   Involuntary full rematerialization" over the cache params); these
   guards make it a CI failure instead of a log tail.
-* **A dropped donation** — a decode step whose cache arguments were
+* **A dropped donation** — a serving step whose page pools were
   donated but whose in/out placements diverged, so XLA allocates a
-  fresh cache-sized buffer per step instead of aliasing in place (≡
+  fresh pool-sized buffer per step instead of aliasing in place (≡
   the reference kernels mutating their persistent caches,
   flash_decode.py:763-846).
 
@@ -29,8 +29,10 @@ with the default ``keep_unused=False`` DROPS unused argument leaves
 from the compiled signature, shifting parameter numbers.
 
 IMPORTANT: lower the program from **abstract arguments carrying the
-intended placements** (``jax.ShapeDtypeStruct(..., sharding=canon)``,
-see ``Transformer.decode_abstract_args``), not from the live arrays —
+intended placements** (``jax.ShapeDtypeStruct(..., sharding=canon)``:
+for ``Transformer._serving_jit`` the params on ``shardings()`` and the
+``ServingState`` pools on ``_serving_pool_sharding``, as
+``tests/test_serving_shard.py`` does), not from the live arrays —
 a program lowered from committed arrays reports those arrays' own
 shardings back, so a boundary check against it can never fail.
 """

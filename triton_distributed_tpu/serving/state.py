@@ -1,16 +1,14 @@
 """ServingState: the explicit, donated, page-table-addressed decode state.
 
-Before this module, the serving decode state was an ad-hoc tuple spread
-across call sites: per-layer ``(k, v)`` cache tuples from
-``init_paged_cache``/``paginate_caches``, a separate block table, and a
-separate ``kv_lens`` vector, each threaded (and donated) individually.
-The continuous-batching engine needs them as ONE object with one
-placement story:
+Per-layer page pools, the block table and the per-slot lengths are ONE
+object with one placement story — what ``Transformer.serving_step``
+takes, donates and returns, and what the continuous-batching engine
+owns:
 
 * **page pools** per layer — ``(npages, Hkv, page, D)`` (int8
   ``{"q","scale"}`` dicts under ``kv_quant``), sharded over the KV-HEAD
-  dim on the tp axis. Head sharding (not the decode path's sequence
-  sharding) is the serving layout: GQA heads are independent, so ranks
+  dim on the tp axis. Head sharding (not sequence sharding) is the
+  serving layout: GQA heads are independent, so ranks
   never exchange LSE partials, and a request's pages live wholly in the
   shared pool — any rank can serve any mix of requests, which is what
   admission/eviction over one free list requires.

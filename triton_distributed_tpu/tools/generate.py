@@ -1,18 +1,18 @@
-"""Serving CLI: prefill a prompt batch, then SP flash-decode generate.
+"""Serving CLI: a batch of prompts through ``serving.ServingEngine``.
 
-The reference leaves serving orchestration to the caller (its surface
-is the SP decode layer); this CLI completes the loop at L7: build a
-preset model on the available mesh, run the one-pass prompt prefill
-into the sequence-sharded KV caches, and greedy-decode through the
-distributed flash-decode layer, reporting decode throughput.
+Builds a preset model on the available devices, submits ``--batch``
+random prompts of ``--prompt-len`` tokens to one continuous-batching
+engine (chunked prefill and decode share each ``Transformer.
+serving_step``), greedy-decodes ``--steps`` tokens each and reports the
+throughput of the second, compiled pass.
 
 Usage (any host; model sizes default to the tiny CI twins)::
 
     python -m triton_distributed_tpu.tools.generate \
         --preset tiny:llama_7b --batch 4 --prompt-len 64 --steps 32
 
-On a multi-chip mesh run one process per host via launch.sh; the tp
-axis spans all devices (decode KV is sequence-sharded over it).
+The tp axis spans as many devices as divide the model's KV heads (and
+experts): the serving pools shard heads over it.
 """
 
 from __future__ import annotations
@@ -27,11 +27,11 @@ def main(argv=None) -> None:
                    help="models.presets factory name (tiny, llama_7b, "
                         "llama_70b, mixtral_8x7b, deepseek_moe_16b; "
                         "tiny:<name> = the CI twin of <name>'s topology)")
-    p.add_argument("--batch", type=int, default=4)
+    p.add_argument("--batch", type=int, default=4,
+                   help="requests submitted together (= engine slots)")
     p.add_argument("--prompt-len", type=int, default=64)
-    p.add_argument("--steps", type=int, default=32)
-    p.add_argument("--capacity", type=int, default=None,
-                   help="KV cache capacity (default prompt+steps rounded up)")
+    p.add_argument("--steps", type=int, default=32,
+                   help="tokens generated per request")
     p.add_argument("--seed", type=int, default=0)
     p.add_argument("--watchdog-deadline", type=float, default=0.0,
                    help="seconds before a wedged collective launch aborts "
@@ -56,40 +56,45 @@ def main(argv=None) -> None:
 
 
 def _run(args) -> None:
+    import inspect
+
     import jax
-    import jax.numpy as jnp
     import numpy as np
     from jax.sharding import Mesh
 
-    from triton_distributed_tpu.config import enable_compile_cache
+    from triton_distributed_tpu.config import (
+        compiling_for_tpu,
+        enable_compile_cache,
+    )
     from triton_distributed_tpu.models import Transformer, presets
+    from triton_distributed_tpu.serving import (
+        EngineConfig,
+        Request,
+        ServingEngine,
+    )
 
     enable_compile_cache()
-
-    import inspect
-
-    def _factories():
-        return {
-            n: f for n, f in vars(presets).items()
-            if inspect.isfunction(f) and f.__module__ == presets.__name__
-        }
-
-    def _resolve(name):
-        f = _factories().get(name)
-        if f is None:
-            raise SystemExit(
-                f"unknown preset {name!r}; available: "
-                f"{sorted(_factories())} (or tiny:<name>)"
-            )
-        return f
-
-    if args.preset.startswith("tiny:"):
-        cfg = presets.tiny(_resolve(args.preset.split(":", 1)[1])())
-    else:
-        cfg = _resolve(args.preset)()
+    factories = {
+        n: f for n, f in vars(presets).items()
+        if inspect.isfunction(f) and f.__module__ == presets.__name__
+    }
+    tiny, _, name = args.preset.rpartition(":")
+    if name not in factories or tiny not in ("", "tiny"):
+        raise SystemExit(
+            f"unknown preset {args.preset!r}; available: "
+            f"{sorted(factories)} (or tiny:<name>)"
+        )
+    cfg = factories[name]()
+    if tiny:
+        cfg = presets.tiny(cfg)
 
     devs = jax.devices()
-    mesh = Mesh(np.asarray(devs), ("tp",))
+    tp = max(
+        d for d in range(1, len(devs) + 1)
+        if cfg.n_kv_heads % d == 0
+        and (cfg.moe == "none" or cfg.local_experts % d == 0)
+    )
+    mesh = Mesh(np.asarray(devs[:tp]), ("tp",))
     model = Transformer(cfg, mesh, "tp", ())
     params = jax.tree.map(
         lambda x, s: jax.device_put(x, s),
@@ -97,64 +102,53 @@ def _run(args) -> None:
         model.shardings(),
     )
     # serving weight quantization (preset-gated): expert matrices and
-    # dense projections to int8 + per-channel scales, consumed in the
-    # grouped-GEMM epilogue (the KV cache quantizes via init_cache
-    # when the preset sets kv_quant)
-    params = model.quantize_moe_weights(params)
-    params = model.quantize_dense_weights(params)
+    # dense projections to int8 + per-channel scales (the KV pools
+    # quantize when the preset sets kv_quant)
+    params = model.quantize_dense_weights(model.quantize_moe_weights(params))
 
-    cap = args.capacity or -(-(args.prompt_len + args.steps) // 128) * 128
-    prompt = jax.random.randint(
+    page = 128                      # int8 pools need page % 128 == 0
+    chunk = -(-min(args.prompt_len, 256) // 8) * 8
+    per_req = -(-(args.prompt_len + args.steps) // page)
+    ecfg = EngineConfig(
+        slots=args.batch, token_budget=max(chunk, 8 * args.batch),
+        chunk=chunk, page=page, npages=args.batch * per_req, seed=args.seed,
+    )
+    # the kernels on the TPU, their XLA twins elsewhere (the Pallas
+    # interpreter would serve the same tokens, minutes later)
+    eng = ServingEngine(model, params, ecfg,
+                        use_pallas=compiling_for_tpu())
+    prompts = np.asarray(jax.random.randint(
         jax.random.PRNGKey(args.seed + 1), (args.batch, args.prompt_len),
         0, cfg.vocab,
-    )
+    ), np.int32)
 
-    # compile-warm both phases on throwaway state so the timings below
-    # measure execution, not trace+compile
-    warm = model._prefill_jit(params, model.init_cache(args.batch, cap), prompt)
-    jax.block_until_ready(warm[0])
-    del warm  # cache-sized pytree — free it before the timed phases
+    def serve():
+        """One pass of the batch through the (one) engine."""
+        reqs = [Request(rid=i, prompt=row, max_new=args.steps,
+                        arrival=eng.step_count)
+                for i, row in enumerate(prompts)]
+        first_step = eng.step_count
+        t0 = time.perf_counter()
+        eng.run(reqs)
+        return reqs, time.perf_counter() - t0, eng.step_count - first_step
 
-    caches = model.init_cache(args.batch, cap)
-    t0 = time.perf_counter()
-    last_logits, caches, lens = model._prefill_jit(params, caches, prompt)
-    jax.block_until_ready(last_logits)
-    t_prefill = time.perf_counter() - t0
+    serve()                         # compiles every step program used
+    reqs, t_serve, n_steps = serve()
+    stats = eng.stats
+    if not all(r.done for r in reqs) or stats.failures or stats.degraded:
+        raise SystemExit(
+            f"engine did not serve the batch cleanly: "
+            f"done={[r.done for r in reqs]} failures={stats.failures} "
+            f"degraded={stats.degraded}")
 
-    first = jnp.argmax(last_logits, axis=-1).astype(jnp.int32)
-    # LL workspaces for EP-MoE decode (None for dense presets / off-TPU)
-    moe_state = model.init_decode_state(args.batch)
-    # one warm step to exclude decode compile from the timing — on
-    # THROWAWAY cache/lens buffers: the decode jits donate their cache
-    # and lens arguments (in-place update), so warming on the live ones
-    # would delete the buffers the timed run needs
-    warm_c = model.init_cache(args.batch, cap)
-    if moe_state is None:
-        _, caches_w, lens_w = model._decode_jit(params, warm_c, lens + 0, first)
-    else:
-        # the state is donated per step — keep threading the returned one
-        _, caches_w, lens_w, moe_state = model._decode_jit_state(
-            params, warm_c, lens + 0, first, moe_state
-        )
-    jax.block_until_ready(lens_w)
-    del warm_c, caches_w
-
-    t0 = time.perf_counter()
-    res = model.generate(
-        params, caches, lens, first, args.steps, moe_state=moe_state
-    )
-    toks, caches, lens = res[:3]
-    toks = np.asarray(toks)  # host fetch = the reliable fence
-    t_decode = time.perf_counter() - t0
-
-    tps = args.batch * args.steps / t_decode
-    print(f"preset={args.preset} devices={len(devs)} "
+    tps = args.batch * args.steps / t_serve
+    print(f"preset={args.preset} devices={tp} "
           f"B={args.batch} prompt={args.prompt_len} steps={args.steps}")
-    print(f"prefill: {t_prefill * 1e3:.1f} ms "
-          f"({args.batch * args.prompt_len / t_prefill:.0f} tok/s)")
-    print(f"decode:  {t_decode * 1e3:.1f} ms "
-          f"({tps:.0f} tok/s, {t_decode / args.steps * 1e3:.2f} ms/step)")
-    print("sample completion ids:", toks[0, : min(8, args.steps)].tolist())
+    print(f"decode:  {t_serve * 1e3:.1f} ms, prefill included "
+          f"({tps:.0f} tok/s, {n_steps} engine steps, "
+          f"{t_serve / n_steps * 1e3:.2f} ms/step)")
+    print("sample completion ids:",
+          [int(t) for t in reqs[0].generated[: min(8, args.steps)]])
 
 
 if __name__ == "__main__":
