@@ -300,7 +300,7 @@ def spread(eng, params, n: int) -> dict:
         "kv_pool": eng.state.layers[1][0]["q"],
     }
     if eng.moe_state is not None:       # always, on the chip (engine())
-        big["ll_dispatch"] = eng.moe_state[1].disp_tok
+        big["ll_dispatch"] = eng.moe_state[eng._t_pad][1].disp_tok
     out = {}
     for name, x in big.items():
         devs = {s.device.id for s in x.addressable_shards}
@@ -380,6 +380,7 @@ def window_leg(devices, on_chip: bool = True) -> dict:
         Request,
         ServingEngine,
     )
+    from triton_distributed_tpu.serving.engine import packed_width
     from triton_distributed_tpu.serving.state import ring_pages
 
     mesh = Mesh(np.asarray(devices), ("x",))
@@ -449,12 +450,14 @@ def window_leg(devices, on_chip: bool = True) -> dict:
     state = state.replace(layers=jax.tree.map(
         lambda a: arg(a.shape, a.dtype, pool_sh), state.layers))
     cap = auto_block_q(ecfg.chunk, cfg.n_heads // cfg.n_kv_heads)
-    t_pad, slots = ecfg.token_budget + cap, ecfg.slots
-    ints = [arg((t_pad,), jnp.int32)] * 3 + [arg((slots,), jnp.int32)] * 2
+    slots = ecfg.slots
+    # the lowest rung's step, at the width the engine gives it
+    width = packed_width(8, slots, ecfg.token_budget, cap)
+    ints = [arg((width,), jnp.int32)] * 3 + [arg((slots,), jnp.int32)] * 2
     lowered = big._serving_jit.lower(
         abstract, state, *ints,
         arg((slots, 2 + 2 * topo_width(cap)), jnp.int32),
-        big.init_decode_state(t_pad, abstract=True), 8, True, 2)
+        big.init_decode_state(width, abstract=True), 8, True, 2)
     text = lowered.as_text()
     for kernel in (f"ragged_paged_attention_w{cfg.window}",
                    "ragged_paged_attention", "kv_append"):
@@ -467,6 +470,7 @@ def window_leg(devices, on_chip: bool = True) -> dict:
         "ring_pages_per_slot": ring,
         "global_pages_walked": st.global_pages_walked,
         "window_pages_walked": st.window_pages_walked,
+        "published_rung_width": width,
         "published_rung_compile_s": round(time.perf_counter() - t0, 2),
         "published_rung_bytes": {
             "arguments": mem.argument_size_in_bytes,

@@ -463,12 +463,13 @@ class TestKernelProbation:
             raise RuntimeError("still broken")
 
         monkeypatch.setattr(rpa, "ragged_paged_attention", boom)
-        # shapes distinct from the re-promotion test above: the model's
-        # step jit is cached per (width, block) and a cache hit would
-        # replay the REAL kernel captured at an earlier trace
+        # shapes distinct from the re-promotion test above (3 slots, and
+        # a packed width of 32 against its 24): the model's step jit is
+        # cached per (width, block) and a cache hit would replay the
+        # REAL kernel captured at an earlier trace
         eng = ServingEngine(
             mp, pp,
-            EngineConfig(slots=2, token_budget=24, chunk=6, page=8,
+            EngineConfig(slots=3, token_budget=24, chunk=6, page=8,
                          npages=16),
             health=_fast_ledger(),
         )

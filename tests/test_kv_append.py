@@ -294,7 +294,14 @@ def test_assemble_packs_one_aligned_contiguous_span_a_slot(
             np.testing.assert_array_equal(
                 token_pos[a:a + ln], first + np.arange(ln))
         assert (token_pos[~covered] == -1).all()
-        assert (q_starts[q_lens == 0] >= eng.cfg.token_budget).all()
+        # the rest park where the batched rows cannot reach at the
+        # step's rung, a whole block short of the step's width
+        rung = eng._rung(int(q_lens.max()))
+        assert len(tokens) == eng._width(rung)
+        live = min(eng.cfg.slots * rung, eng.cfg.token_budget)
+        assert (q_starts[q_lens == 0] == live).all()
+        assert (q_starts + rung <= len(tokens)).all()
+        assert all(q_starts[s] + q_lens[s] <= live for s in batched)
         seen.append(len(batched))
         return out
 
@@ -359,17 +366,19 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.mark.parametrize("t", [768, 264], ids=["wide", "narrow"])
 @pytest.mark.parametrize(
     "npages,hkv,dtype",
     [(640, 16, jnp.int8), (1024, 8, jnp.bfloat16)],
     ids=["dsmoe16b_s8_640x16", "mixtral8x7b_bf16_1024x8"])
 def test_kernel_compiles_for_the_chip_at_the_cells_pool_shapes(
-        one_chip, npages, hkv, dtype):
-    """Mosaic accepts the kernel at ``T = 768``, page 128, head 128;
-    the pools are updated in place: no pool-sized temporary."""
+        one_chip, npages, hkv, dtype, t):
+    """Mosaic accepts the kernel at both packed widths of the cells'
+    engine (768, and a decode-only step's 264), page 128, head 128; the
+    pools are updated in place: no pool-sized temporary."""
     from triton_distributed_tpu.config import config
 
-    t, slots, page, d = 768, 32, 128, 128
+    slots, page, d = 32, 128, 128
 
     def arg(shape, dt):
         return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
@@ -404,9 +413,10 @@ def test_kernel_compiles_for_the_chip_at_the_cells_pool_shapes(
     "cap,bm,e,k,n,quant",
     [(12928, 128, 64, 2048, 1408, "w8a8"),
      (12928, 64, 64, 1408, 2048, "w8a16"),
-     (3840, 256, 8, 4096, 14336, None)],
+     (3840, 256, 8, 4096, 14336, None),
+     (9856, 128, 64, 2048, 1408, "w8a8")],
     ids=["dsmoe16b_w8a8_resident_up", "dsmoe16b_w8a16_resident_down",
-         "mixtral8x7b_bf16_tiled_up"])
+         "mixtral8x7b_bf16_tiled_up", "dsmoe16b_w8a8_narrow_step_up"])
 def test_grouped_matmul_with_a_dummy_tail_compiles_for_the_chip(
         one_chip, cap, bm, e, k, n, quant):
     """Mosaic accepts all three grouped-GEMM kernels with
@@ -445,3 +455,35 @@ def test_grouped_matmul_with_a_dummy_tail_compiles_for_the_chip(
         lowered.compile()
     finally:
         config.force_compile = old
+
+
+def test_int8_dense_projection_compiles_at_the_narrow_width(one_chip):
+    """``Transformer._dmm``'s W8A8 launch (ONE M-block, ``block_m`` the
+    packed width) at a decode-only step's 264 rows: not a multiple of
+    the int8 tile's 32 sublanes, and Mosaic takes it because the block
+    is the whole dimension (dsmoe's ``wqkv``)."""
+    from triton_distributed_tpu.config import config, fused_vmem_budget
+    from triton_distributed_tpu.kernels.group_gemm import grouped_matmul
+
+    def arg(shape, dt):
+        return jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+
+    m, k, n = 264, 2048, 6144
+
+    def fn(x, w, ws, xs):
+        return grouped_matmul(
+            x, w, jnp.zeros((1,), jnp.int32), w_scale=ws, x_scale=xs,
+            block_m=m, vmem_limit_bytes=fused_vmem_budget(),
+            out_dtype=jnp.bfloat16)
+
+    old = config.force_compile
+    config.force_compile = True
+    try:
+        lowered = jax.jit(fn).lower(
+            arg((m, k), jnp.int8), arg((1, k, n), jnp.int8),
+            arg((1, n), jnp.float32), arg((m, 1), jnp.float32))
+        assert "tpu_custom_call" in lowered.as_text()
+        lowered.compile()
+    finally:
+        config.force_compile = old
+

@@ -146,8 +146,9 @@ class TestDecode:
         monkeypatch.setattr(Transformer, "_moe_ep_ctx", force_fused_ctx())
         params = _sharded_params(model)
         eng_ll, _, ll = _serve(model, params)
-        state = eng_ll.moe_state
-        assert state is not None and state[1] is not None  # MoE layer 1
+        assert eng_ll.moe_state is not None
+        state = eng_ll.moe_state[eng_ll._t_pad]     # SERVE: one width
+        assert state[1] is not None                 # MoE layer 1
         eng_ref, _, ref = _serve(model, params, moe_state=None)
         assert eng_ref.moe_state is None
         np.testing.assert_allclose(
@@ -176,8 +177,9 @@ class TestDecode:
             ctx = m_ll._moe_ep_ctx(1, inference=True)
             assert ctx.transport == "fused"
             eng, _, ll = _serve(m_ll, params)
-            state = eng.moe_state
-            assert state is not None and state[1] is not None
+            assert eng.moe_state is not None
+            state = eng.moe_state[eng._t_pad]
+            assert state[1] is not None
             np.testing.assert_allclose(
                 np.concatenate(ll), np.concatenate(ref),
                 atol=1e-5, rtol=1e-5)

@@ -82,7 +82,7 @@ def test_padding_rows_reach_the_op_masked_and_real_rows_are_untouched(
     args, arrays = first_step_args(eng)
     token_pos, q_lens = arrays[2], arrays[4]
     t, tokens = token_pos.shape[0], int(q_lens.sum())
-    assert tokens == 8 + 5 and t == eng._t_pad
+    assert tokens == 8 + 5 and t == eng._width(8)
 
     seen = spy_on_ep_moe(monkeypatch)
     step = dict(block_q=8, use_pallas=False)
@@ -131,7 +131,7 @@ def test_moe_masked_rows_counts_what_assemble_left_empty():
     st = eng.stats
     assert eng.idle and st.completed == 3
     assert st.moe_masked_rows == (
-        len(st.step_tokens) * eng._t_pad - sum(st.step_tokens)) > 0
+        st.packed_rows - sum(st.step_tokens)) > 0
     # a model with no EP expert layer masks nothing
     dense = engine_with(one_chip_model(), (11, 5))
     for _ in range(20):

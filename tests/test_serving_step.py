@@ -148,13 +148,15 @@ def test_engine_threads_moe_state_across_steps(monkeypatch):
     model, params = _model(tp=2, moe="ep")
     eng = ServingEngine(model, params, ENGINE, use_pallas=False,
                         propagate_failures=True)
-    assert eng.moe_state is not None and eng.moe_state[1] is not None
+    state = eng.moe_state[eng._t_pad]          # ENGINE: one width
+    assert list(eng.moe_state) == [eng._t_pad] and state[1] is not None
     reqs = [Request(rid=i, prompt=p, max_new=3, arrival=0.0)
             for i, p in enumerate(_prompts(10, 5))]
     stats = eng.run(reqs)
     steps = len(stats.step_tokens)
     assert stats.completed == 2 and steps >= 4
-    assert int(np.asarray(eng.moe_state[1].parity)[0]) == steps % 2
+    assert int(np.asarray(eng.moe_state[eng._t_pad][1].parity)[0]) \
+        == steps % 2
     monkeypatch.undo()                  # the oracle routes by forward
     for req in reqs:
         assert req.generated == greedy_tokens(

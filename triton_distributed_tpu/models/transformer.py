@@ -936,7 +936,8 @@ class Transformer:
         layer, None elsewhere. Returns None when the model has no EP
         layers or decode would ride the XLA transport (off-TPU / DCN tp
         axis) — :meth:`serving_step` then needs no state at all.
-        ``batch`` is the step's packed width (the engine's ``_t_pad``).
+        ``batch`` is a step's packed width (the engine builds one set
+        per distinct width its steps take, ``ServingEngine._width``).
         ``abstract=True`` yields ShapeDtypeStruct leaves (topology
         compiles)."""
         c = self.config
@@ -1281,7 +1282,15 @@ class Transformer:
         sequence position (pos < 0 marks padding tokens — their K/V
         writes are dropped); ``q_starts``/``q_lens``: (slots,) per-slot
         spans into the packed array (8-aligned starts, ``q_lens == 0``
-        for slots not in this batch). THE PACKING CONTRACT, which the
+        for slots not in this batch). THE WIDTH ``T`` is the caller's:
+        the step reads it off ``tokens`` and every row-sized operation
+        (projections, routing, dispatch, the append's quantize) is that
+        wide. The engine gives a step at rung ``block_q`` the width
+        ``live + block_q`` with ``live = min(token_budget, slots *
+        block_q)`` where that is under its budget, ``token_budget +
+        block_q_cap`` otherwise; what the step needs is only ``q_starts[s]
+        + block_q <= T`` for EVERY slot (a ``q_lens == 0`` slot's
+        garbage block parks at ``live``). THE PACKING CONTRACT, which the
         pool append relies on: slot ``s``'s tokens are the ONE
         contiguous span ``[q_starts[s], q_starts[s] + q_lens[s])`` of
         the packed array, spans do not overlap, and they sit at

@@ -18,6 +18,16 @@ Scheduling model (all host-side, numpy; the device work is ONE jitted
   ``token_budget``: each active slot contributes
   ``min(chunk, remaining_sequence)`` tokens — 1 in steady decode, up
   to ``chunk`` while prefilling — packed at 8-aligned offsets;
+* THE PACKED WIDTH FOLLOWS THE STEP'S ``block_q`` RUNG ``b`` (the
+  ladder rung covering the step's longest row, already a static
+  argument of the step program): every batched row holds at most ``b``
+  tokens, so the rows live below ``live(b) = min(token_budget,
+  slots * b)``. Where that is under the budget the step's arrays are
+  ``live(b) + b`` rows (rows outside the batch park at ``live(b)``,
+  in a zone of the rung's own ``block_q``); otherwise they are the
+  widest, ``_t_pad = token_budget + block_q_cap``. A function of the
+  rung, the slots and the budget alone, so the step jit still holds
+  one program per rung (:meth:`ServingEngine._width`);
 * pages for the new tokens are allocated from one shared free list;
   allocation failure triggers eviction (victims: the latest-arrived
   active request not already in this step's batch — LIFO preemption),
@@ -276,8 +286,11 @@ class EngineStats:
     # only those from its window's first key on (0 without such layers)
     global_pages_walked: int = 0
     window_pages_walked: int = 0
-    # packed rows of the device steps that were no token's (the packed
-    # width less the step's tokens): ``serving_step`` hands their expert
+    # packed rows of the device steps: the sum of their widths (each
+    # step's follows its ``block_q`` rung, ``ServingEngine._width``)
+    packed_rows: int = 0
+    # packed rows of the device steps that were no token's (the step's
+    # own width less its tokens): ``serving_step`` hands their expert
     # assignments to ``ops.ep_moe`` masked, so at least
     # ``masked * topk // block_m`` blocks of an expert layer's grouped
     # GEMM hold no row (0 for a model with no EP expert layer)
@@ -437,6 +450,26 @@ def _ceil8(x: int) -> int:
     return -(-x // 8) * 8
 
 
+def live_rows(block_q: int, slots: int, token_budget: int) -> int:
+    """Packed rows the batched rows of a step at rung ``block_q`` can
+    reach: each of ``slots`` rows holds at most ``block_q`` tokens
+    (8-aligned), under the token budget."""
+    return min(token_budget, slots * _ceil8(block_q))
+
+
+def packed_width(block_q: int, slots: int, token_budget: int,
+                 block_q_cap: int) -> int:
+    """The packed width of a step at rung ``block_q``: its live rows
+    and a parking zone of ``block_q`` where the slots cannot fill the
+    budget at that rung, the widest (``token_budget + block_q_cap``)
+    where they can. A function of the rung, the slots and the budget,
+    so one program per rung."""
+    live = live_rows(block_q, slots, token_budget)
+    if live < token_budget:
+        return live + block_q
+    return token_budget + block_q_cap
+
+
 class ServingEngine:
     """The scheduler. Owns the host mirrors (free list, block table,
     lengths, cursors) and the device :class:`ServingState`; every
@@ -546,10 +579,11 @@ class ServingEngine:
         )
 
         self._block_q_cap = auto_block_q(cfg.chunk, g)
-        # the packed array carries a PARKING zone of block_q_cap tokens
-        # past the budget: rows outside the batch (q_len == 0) park
-        # their garbage writes there, where no valid span can be
-        # clobbered by the kernel's sequential out DMAs
+        # the packed array carries a PARKING zone of block_q tokens past
+        # its live rows: rows outside the batch (q_len == 0) park their
+        # garbage writes there, where no valid span can be clobbered by
+        # the kernel's sequential out DMAs. ``_t_pad`` is the WIDEST
+        # width a step takes (``_width``: a low rung's step is narrower)
         self._t_pad = cfg.token_budget + self._block_q_cap
         # grid-schedule resolution (explicit > stored > default): the
         # traffic key this engine's every step lands on. A winner
@@ -578,16 +612,23 @@ class ServingEngine:
             sched = GRID_DEFAULT      # stale ring entry: ignore
         self.grid_schedule = sched
         self._n_bufs = int(sched.n_bufs)
-        # tuned block_q is a FLOOR under the parking-zone cap: the
-        # packed array always carries block_q_cap parking tokens, so
-        # any block_q <= cap keeps garbage writes inside the zone
+        # tuned block_q is a FLOOR under the parking-zone cap: a step
+        # launches at the rung ``_rung`` gives, and its packed array
+        # carries that rung's parking tokens
         self._block_q_floor = int(sched.block_q)
-        # LL MoE workspaces sized to the PACKED step width (None when
-        # the model has no fused-transport EP layers)
-        self.moe_state = (
-            model.init_decode_state(self._t_pad)
-            if moe_state == "auto" else moe_state
-        )
+        # LL MoE workspaces, sized to the packed step width: one set per
+        # DISTINCT width, ``{width: per-layer states}``, built here and
+        # never inside a step (``EPMoEState.instance`` is static: a
+        # state belongs to the kernels compiled for its width). None
+        # when the model has no fused-transport EP layers
+        if moe_state == "auto":
+            moe_state = {
+                w: model.init_decode_state(w)
+                for w in sorted({self._width(b) for b in self._rungs()})
+            }
+            if None in moe_state.values():
+                moe_state = None
+        self.moe_state = moe_state
         if cfg.token_budget % 8:
             raise ValueError("token_budget must be 8-aligned")
         if cfg.chunk > cfg.token_budget:
@@ -635,6 +676,29 @@ class ServingEngine:
                 "sliding-window layers with prefill_only (the prefill "
                 "role of DisaggregatedEngine): kv_ship ships pages by "
                 "the global block table, which does not address a ring")
+
+    def _rung(self, max_q_len: int) -> int:
+        """The ``block_q`` a step whose longest row packs ``max_q_len``
+        tokens launches at: the ladder rung covering it, no lower than
+        the tuned floor (grid schedule), never past the cap."""
+        from triton_distributed_tpu.kernels.ragged_paged_attention import (
+            auto_block_q,
+        )
+
+        return min(self._block_q_cap,
+                   max(auto_block_q(max_q_len, self._g),
+                       self._block_q_floor))
+
+    def _rungs(self) -> list:
+        """Every ``block_q`` a step of this engine can launch at."""
+        return sorted({self._rung(1 << i) for i in
+                       range(self._block_q_cap.bit_length())})
+
+    def _width(self, block_q: int) -> int:
+        """:func:`packed_width` of a step of this engine at rung
+        ``block_q`` (``_t_pad`` is the widest)."""
+        return packed_width(block_q, self.cfg.slots, self.cfg.token_budget,
+                            self._block_q_cap)
 
     def _spec_key(self) -> tuple:
         """Speculation coordinates appended to the grid-schedule traffic
@@ -893,9 +957,7 @@ class ServingEngine:
         tokens = np.zeros((T,), np.int32)
         token_rows = np.zeros((T,), np.int32)
         token_pos = np.full((T,), -1, np.int32)
-        # inactive slots PARK their garbage output block past the
-        # budget (see __init__) — never over another row's valid span
-        q_starts = np.full((R,), cfg.token_budget, np.int32)
+        q_starts = np.zeros((R,), np.int32)
         q_lens = np.zeros((R,), np.int32)
         kv_dev = np.zeros((R,), np.int32)
         topo_w = topo_width(self._block_q_cap)
@@ -948,8 +1010,15 @@ class ServingEngine:
             self.stats.deferrals += 1
         if cfg.prefix_share and batched:
             self._dedup_shared_prefixes(batched, topo, topo_w)
-        return (tokens, token_rows, token_pos, q_starts, q_lens, kv_dev,
-                topo, batched, takes)
+        # the step's width follows its rung (``_width``): the batched
+        # rows end under ``live``, and inactive slots PARK their garbage
+        # output block there (see __init__) — never over another row's
+        # valid span
+        block_q = self._rung(int(q_lens.max()))
+        width = self._width(block_q)
+        q_starts[q_lens == 0] = live_rows(block_q, R, cfg.token_budget)
+        return (tokens[:width], token_rows[:width], token_pos[:width],
+                q_starts, q_lens, kv_dev, topo, batched, takes)
 
     def _step_jit(self):
         """The jitted device step this engine launches. The speculative
@@ -979,7 +1048,10 @@ class ServingEngine:
             jnp.asarray(token_rows), jnp.asarray(token_pos),
             jnp.asarray(q_starts), jnp.asarray(q_lens),
             jnp.asarray(topo),
-            self.moe_state, block_q, self.use_pallas, self._n_bufs,
+            # the workspaces of THIS step's width
+            None if self.moe_state is None
+            else self.moe_state[len(tokens)],
+            block_q, self.use_pallas, self._n_bufs,
         )
 
     def _phase(self, phase: str) -> _Phase:
@@ -1009,7 +1081,18 @@ class ServingEngine:
             if self.moe_state is None:
                 logits, self.state = out
             else:
-                logits, self.state, self.moe_state = out
+                logits, self.state, states = out
+                self.moe_state[len(arrays[0])] = states
+                # ONE parity sequence for every width: the barrier-free
+                # protocol alternates parity from a STEP to the next, so
+                # that a peer one step ahead signals the semaphores the
+                # step behind does not wait on — whichever widths the
+                # two steps have (the sets' kernels may share them)
+                for other in self.moe_state.values():
+                    if other is not states:
+                        for mine, new in zip(other, states):
+                            if mine is not None:
+                                mine.parity = new.parity
         with self._phase("fetch"):
             # the host fetch is the fence: the wait for the step program,
             # the copy down and the delinearize, deliberately one span (a
@@ -1024,10 +1107,6 @@ class ServingEngine:
     def step(self) -> dict:
         """One engine step: admit → assemble → device step → advance
         cursors/completions. Returns a small per-step report."""
-        from triton_distributed_tpu.kernels.ragged_paged_attention import (
-            auto_block_q,
-        )
-
         phase_s = self._phase_s
         for k in phase_s:
             phase_s[k] = 0.0
@@ -1041,10 +1120,7 @@ class ServingEngine:
             if not batched:
                 self.step_count += 1
                 return report
-            block_q = auto_block_q(int(q_lens.max()), self._g)
-            # tuned floor (grid schedule): never past the parking-zone cap
-            block_q = min(self._block_q_cap,
-                          max(block_q, self._block_q_floor))
+            block_q = self._rung(int(q_lens.max()))
             from triton_distributed_tpu.runtime.health import PeerState
 
             peer = self.health_peer
@@ -1122,10 +1198,11 @@ class ServingEngine:
                         stats.first_tokens += 1
             stats.step_times.append(dt)
             stats.step_tokens.append(report["tokens"])
+            stats.packed_rows += len(tokens)
             c = self.model.config
             if c.moe == "ep" and c.moe_layers:
                 # the step program masked its padding rows' assignments
-                stats.moe_masked_rows += self._t_pad - report["tokens"]
+                stats.moe_masked_rows += len(tokens) - report["tokens"]
             stats.step_generated.append(gen_this_step)
             stats.note_shape(
                 self._grid_key, dt * 1e3,
@@ -1440,10 +1517,10 @@ class DisaggregatedEngine:
             # packed width to 8·slots instead of the prefill budget,
             # never wider than it. Part of the point of the split: the
             # decode slice's steps stop paying prefill-sized
-            # buffers/blocks (the colocated engine cannot shrink its
-            # budget — its steps must carry prefill chunks). Evicted
-            # requests re-prefilling decode-side chunk at this
-            # narrower width.
+            # buffers/blocks (the colocated engine narrows only its
+            # low-rung steps, ``ServingEngine._width`` — its budget
+            # must still carry prefill chunks). Evicted requests
+            # re-prefilling decode-side chunk at this narrower width.
             dbudget = max(8, min(8 * cfg.slots, cfg.token_budget))
             decode_cfg = _rep(
                 cfg, token_budget=dbudget, chunk=min(cfg.chunk, dbudget),

@@ -149,14 +149,19 @@ def main():
         eng_ll, reqs_ll, _ = serve(model_ll, params_ll, prompts[:2])
     finally:
         config.force_fused_transport = False
-    state = eng_ll.moe_state
-    assert state is not None, "the fused transport did not engage"
-    layer = next(s for s in state if s is not None)
+    # one set of workspaces per packed width the engine's steps take (a
+    # decode-only step is narrower than a chunk step), one parity for
+    # all of them: it rolls once a step, whichever width the step has
+    states = eng_ll.moe_state
+    assert states is not None, "the fused transport did not engage"
+    parity = {
+        w: int(np.asarray(next(s for s in st if s is not None).parity)[0])
+        for w, st in states.items()}
     steps = len(eng_ll.stats.step_tokens)
-    assert int(np.asarray(layer.parity)[0]) == steps % 2
+    assert set(parity.values()) == {steps % 2}
     assert [r.generated for r in reqs_ll] == [r.generated for r in reqs_x]
-    print(f"LL carry: {steps} barrier-free steps, parity -> "
-          f"{int(np.asarray(layer.parity)[0])}, tokens == XLA transport")
+    print(f"LL carry: {steps} barrier-free steps, parity by width -> "
+          f"{parity}, tokens == XLA transport")
     print("tutorial 13 OK")
 
 
