@@ -95,6 +95,15 @@ WINDOW_TOL = 0.03                       # rms(kernel - twin) / rms(twin)
 PUBLISHED_CUT = dict(n_layers=5, experts_held=16, vocab=19200)
 PUBLISHED_ENGINE = dict(slots=32, token_budget=512, chunk=256, page=128,
                         npages=2176)
+#: the sala leg: the benchmark's cut of ``presets.minicpm_sala`` (the
+#: published layers 0, 4, ..., 28) and its engine; bounds on
+#: rms(kernel - twin) / rms(twin): the selected walk over bf16 pools
+#: differs from its twin as the contiguous walk does (bf16
+#: probabilities into the MXU), the lightning mixer is float32 both ways
+SALA_CUT = dict(n_layers=8, layer_stride=4)
+SALA_ENGINE = dict(slots=32, token_budget=512, chunk=256, page=128,
+                   npages=7296)
+SALA_TOL = {"selected": 0.03, "lightning": 1e-3}
 
 
 class SmokeFailure(Exception):
@@ -360,6 +369,46 @@ def overlap_ops(mesh) -> dict:
     return out
 
 
+def lower_published_rung(big, ecfg):
+    """``(lowered, width)``: the lowest rung's step of ``big`` at the
+    width ``ecfg``'s engine gives it, lowered from shapes alone (no
+    weight, no pool is made)."""
+    import jax
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from triton_distributed_tpu.kernels.ragged_paged_attention import (
+        auto_block_q,
+        topo_width,
+    )
+    from triton_distributed_tpu.serving.engine import packed_width
+
+    cfg = big.config
+    rep = NamedSharding(big.mesh, P())
+
+    def arg(shape, dtype, sharding=rep):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+    abstract = jax.tree.map(
+        lambda a, sh: arg(a.shape, a.dtype, sh),
+        jax.eval_shape(big.init, jax.random.PRNGKey(0)), big.shardings())
+    state = jax.eval_shape(
+        lambda: big.init_serving_state(
+            ecfg.slots, ecfg.npages, ecfg.page, chunk=ecfg.chunk))
+    pool_sh = big._serving_pool_sharding
+    state = state.replace(layers=jax.tree.map(
+        lambda a: arg(a.shape, a.dtype, pool_sh), state.layers))
+    cap = auto_block_q(ecfg.chunk, cfg.n_heads // cfg.n_kv_heads)
+    slots = ecfg.slots
+    width = packed_width(8, slots, ecfg.token_budget, cap)
+    ints = [arg((width,), jnp.int32)] * 3 + [arg((slots,), jnp.int32)] * 2
+    lowered = big._serving_jit.lower(
+        abstract, state, *ints,
+        arg((slots, 2 + 2 * topo_width(cap)), jnp.int32),
+        big.init_decode_state(width, abstract=True), 8, True, 2)
+    return lowered, width
+
+
 def window_leg(devices, on_chip: bool = True) -> dict:
     """Sliding-window layers over ring pools, a sigmoid-routed share of
     an expert layer, a shared expert: the width-cut twin served once by
@@ -368,19 +417,14 @@ def window_leg(devices, on_chip: bool = True) -> dict:
     import jax
     import jax.numpy as jnp
     import numpy as np
-    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from jax.sharding import Mesh
 
-    from triton_distributed_tpu.kernels.ragged_paged_attention import (
-        auto_block_q,
-        topo_width,
-    )
     from triton_distributed_tpu.models import Transformer, presets
     from triton_distributed_tpu.serving import (
         EngineConfig,
         Request,
         ServingEngine,
     )
-    from triton_distributed_tpu.serving.engine import packed_width
     from triton_distributed_tpu.serving.state import ring_pages
 
     mesh = Mesh(np.asarray(devices), ("x",))
@@ -434,30 +478,8 @@ def window_leg(devices, on_chip: bool = True) -> dict:
     t0 = time.perf_counter()
     cfg = presets.k_exaone_236b(param_dtype=jnp.bfloat16, **PUBLISHED_CUT)
     big = Transformer(cfg, mesh, tp_axis="x")
-    ecfg = EngineConfig(**PUBLISHED_ENGINE)
-    rep = NamedSharding(mesh, P())
-
-    def arg(shape, dtype, sharding=rep):
-        return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
-
-    abstract = jax.tree.map(
-        lambda a, sh: arg(a.shape, a.dtype, sh),
-        jax.eval_shape(big.init, jax.random.PRNGKey(0)), big.shardings())
-    state = jax.eval_shape(
-        lambda: big.init_serving_state(
-            ecfg.slots, ecfg.npages, ecfg.page, chunk=ecfg.chunk))
-    pool_sh = big._serving_pool_sharding
-    state = state.replace(layers=jax.tree.map(
-        lambda a: arg(a.shape, a.dtype, pool_sh), state.layers))
-    cap = auto_block_q(ecfg.chunk, cfg.n_heads // cfg.n_kv_heads)
-    slots = ecfg.slots
-    # the lowest rung's step, at the width the engine gives it
-    width = packed_width(8, slots, ecfg.token_budget, cap)
-    ints = [arg((width,), jnp.int32)] * 3 + [arg((slots,), jnp.int32)] * 2
-    lowered = big._serving_jit.lower(
-        abstract, state, *ints,
-        arg((slots, 2 + 2 * topo_width(cap)), jnp.int32),
-        big.init_decode_state(width, abstract=True), 8, True, 2)
+    lowered, width = lower_published_rung(
+        big, EngineConfig(**PUBLISHED_ENGINE))
     text = lowered.as_text()
     for kernel in (f"ragged_paged_attention_w{cfg.window}",
                    "ragged_paged_attention", "kv_append"):
@@ -470,6 +492,133 @@ def window_leg(devices, on_chip: bool = True) -> dict:
         "ring_pages_per_slot": ring,
         "global_pages_walked": st.global_pages_walked,
         "window_pages_walked": st.window_pages_walked,
+        "published_rung_width": width,
+        "published_rung_compile_s": round(time.perf_counter() - t0, 2),
+        "published_rung_bytes": {
+            "arguments": mem.argument_size_in_bytes,
+            "temporaries": mem.temp_size_in_bytes},
+    }
+
+
+def sala_leg(devices, on_chip: bool = True) -> dict:
+    """PR 33's two launches at MiniCPM-SALA's published widths against
+    their XLA twins on one mixed step each (outputs compared, rel rms):
+    the ragged kernel's selected walk (2 KV heads x 16 query heads x
+    128, pages of 128, blocks of 64, top-64 from compressed keys; two
+    decode rows past the dense length, one below it, a chunk of 16 at
+    12k) and the lightning mixer (32 heads x 128; spans of 1, 37 and
+    256, one from position 0, one slot not batched). Then the
+    published-width step of the benchmark's cut, lowered with both
+    launches in it and compiled for this device."""
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from triton_distributed_tpu.kernels import sparse_select as sel
+    from triton_distributed_tpu.kernels.lightning_attention import (
+        lightning_attention,
+        lightning_attention_xla,
+    )
+    from triton_distributed_tpu.kernels.ragged_paged_attention import (
+        pack_gqa_rows,
+        ragged_paged_attention,
+        ragged_paged_attention_xla,
+    )
+    from triton_distributed_tpu.models import Transformer, presets
+    from triton_distributed_tpu.serving import EngineConfig
+
+    def rel_rms(got, want):
+        got, want = (np.asarray(a, np.float32) for a in (got, want))
+        return float(np.sqrt(np.mean((got - want) ** 2))
+                     / np.sqrt(np.mean(want ** 2)))
+
+    cfg = presets.minicpm_sala(param_dtype=jnp.bfloat16, **SALA_CUT)
+    rng = np.random.default_rng(SEED)
+    t0 = time.perf_counter()
+    # ---- the selected walk
+    hkv, g, d, page = cfg.n_kv_heads, cfg.n_heads // cfg.n_kv_heads, 128, 128
+    lens, takes, pps = (9000, 20000, 3000, 12288), (1, 1, 1, 16), 192
+    r = len(lens)
+    table = jnp.asarray(rng.permutation(r * pps).reshape(r, pps), jnp.int32)
+    kp, vp = (jnp.asarray(rng.normal(size=(r * pps, hkv, page, d)),
+                          jnp.bfloat16) for _ in range(2))
+    starts = np.cumsum([0] + [-(-n // 8) * 8 for n in takes[:-1]])
+    t = int(starts[-1]) + 32
+    rows, pos = np.zeros((t,), np.int32), np.full((t,), -1, np.int32)
+    for i, (n, ln, s) in enumerate(zip(takes, lens, starts)):
+        rows[s:s + n], pos[s:s + n] = i, np.arange(ln - n, ln)
+    q = jnp.asarray(rng.normal(size=(t, hkv * g, d)), jnp.bfloat16)
+    lens_a, takes_a = (jnp.asarray(a, jnp.int32) for a in (lens, takes))
+    kc = sel.append_compressed(
+        jnp.zeros((r * pps, hkv, page // cfg.sparse_stride, d),
+                  jnp.bfloat16),
+        kp, table, lens_a, lens_a, kernel=cfg.sparse_kernel,
+        stride=cfg.sparse_stride, block_q=max(lens))
+    chosen = sel.select_blocks(
+        q, kc, table, jnp.asarray(rows), jnp.asarray(pos), lens_a, takes_a,
+        jnp.asarray(starts, jnp.int32), group=g, page=page, kernel=cfg.sparse_kernel,
+        stride=cfg.sparse_stride, block=cfg.sparse_block,
+        init_blocks=cfg.sparse_init_blocks, window=cfg.sparse_window,
+        topk=cfg.sparse_topk, dense_len=cfg.sparse_dense_len)
+    counts = np.asarray(chosen[1])
+    need((counts[:2] <= cfg.sparse_topk).all() and (counts[:2] >= 32).all()
+         and (counts[2] == -(-3000 // page)).all(),
+         f"selected walk: page counts {counts.tolist()} are not a top-"
+         f"{cfg.sparse_topk} selection's")
+    args = (pack_gqa_rows(q, hkv), kp, vp, lens_a, takes_a,
+            jnp.asarray(starts, jnp.int32), table)
+    kw = dict(group=g, selected=chosen, select_block=cfg.sparse_block)
+    got, _ = ragged_paged_attention(*args, block_q=16, with_lse=False, **kw)
+    want, _ = ragged_paged_attention_xla(*args, **kw)
+    live = np.concatenate([np.arange(s * g, (s + n) * g)
+                           for n, s in zip(takes, starts)])
+    rel_sel = rel_rms(np.asarray(got, np.float32)[:, live],
+                      np.asarray(want, np.float32)[:, live])
+    need(np.isfinite(rel_sel) and rel_sel <= SALA_TOL["selected"],
+         f"selected walk and its XLA twin disagree, rel rms {rel_sel:.5f}"
+         f" > {SALA_TOL['selected']}")
+    del kp, vp, kc, got, want, chosen
+    # ---- the lightning mixer
+    heads, bq = cfg.lightning_heads, 256
+    q_lens = jnp.asarray([1, 37, 0, 256, 1], jnp.int32)
+    q_starts = jnp.asarray([0, 8, 560, 48, 304], jnp.int32)
+    kv_lens = jnp.asarray([5000, 37, 0, 12288, 9001], jnp.int32)
+    tl = 560 + bq
+    lq, lk, lv = (jnp.asarray(rng.normal(size=(heads, tl, d)), jnp.float32)
+                  for _ in range(3))
+    state = jnp.asarray(rng.normal(size=(5, heads, d, d)), jnp.float32)
+    want_o, want_s = lightning_attention_xla(
+        lq, lk, lv, state, kv_lens, q_lens, q_starts, block_q=bq)
+    got_o, got_s = lightning_attention(
+        lq, lk, lv, state, kv_lens, q_lens, q_starts, block_q=bq)
+    live = np.concatenate([np.arange(int(s), int(s + n))
+                           for n, s in zip(q_lens, q_starts)])
+    rel_o = rel_rms(np.asarray(got_o)[:, live], np.asarray(want_o)[:, live])
+    rel_s = rel_rms(got_s, want_s)
+    need(max(rel_o, rel_s) <= SALA_TOL["lightning"],
+         f"lightning mixer and its XLA twin disagree, rel rms outputs "
+         f"{rel_o:.2e}, states {rel_s:.2e} > {SALA_TOL['lightning']}")
+    need(np.array_equal(np.asarray(got_s)[2], np.asarray(state)[2]),
+         "lightning mixer touched the state of a slot it did not batch")
+    t_twins = time.perf_counter() - t0
+
+    # ---- the published-width step, lowered and compiled, not run
+    t0 = time.perf_counter()
+    mesh = Mesh(np.asarray(devices), ("x",))
+    big = Transformer(cfg, mesh, tp_axis="x")
+    lowered, width = lower_published_rung(
+        big, EngineConfig(**SALA_ENGINE))
+    text = lowered.as_text()
+    for kernel in ("ragged_paged_attention_selected", "lightning_attention",
+                   "kv_append"):
+        need(not on_chip or f'kernel_name = "{kernel}"' in text,
+             f"published-width step lowered without the {kernel} kernel")
+    mem = lowered.compile().memory_analysis()
+    return {
+        "leg": "sala", "selected_rel_rms": round(rel_sel, 5),
+        "selected_pages": counts.tolist(),
+        "lightning_rel_rms": {"outputs": rel_o, "states": rel_s},
+        "twins_s": round(t_twins, 2),
         "published_rung_width": width,
         "published_rung_compile_s": round(time.perf_counter() - t0, 2),
         "published_rung_bytes": {
@@ -547,7 +696,9 @@ def main(argv=None) -> int:
     ap.add_argument("--legs", default="window,dsmoe",
                     help="comma-separated: window (the sliding-window / "
                          "expert-share twin + one published rung "
-                         "compiled), dsmoe (the served trace)")
+                         "compiled), dsmoe (the served trace), sala (the "
+                         "selected walk and the lightning mixer against "
+                         "their twins + one published rung compiled)")
     legs = ap.parse_args(argv).legs.split(",")
     t_start = time.perf_counter()
     # a wedged collective must end as a failure with every thread's
@@ -578,6 +729,8 @@ def main(argv=None) -> int:
         try:
             if "window" in legs:
                 say(**window_leg(devs[:1]))
+            if "sala" in legs:
+                say(**sala_leg(devs[:1]))
             if "dsmoe" in legs:
                 say(**leg(devs[:1]))
                 if len(devs) >= 4:

@@ -694,6 +694,262 @@ def _build_ragged(
     return call
 
 
+# --------------------------------------------------------- selected walk
+#
+# ``selected``: block-sparse attention over a per-(row, KV head) list of
+# pages. The selection (kernels/sparse_select.py) hands three arrays:
+#
+#   pages  (R, Hkv, W) int32   logical page indices of the row's walk,
+#                              ascending, the first ``counts`` valid:
+#                              the pages in which SOME query position
+#                              of the row selected a block
+#   counts (R, Hkv)    int32   pages walked (0 for a row not batched)
+#   bits   (Hkv, T·G, SELECT_WORDS) int32
+#                              per packed query row a bitmap over the
+#                              sequence's blocks of ``select_block``
+#                              tokens: bit ``b % 32`` of word ``b // 32``
+#                              = the row's query position attends block
+#                              ``b`` (the G heads of a token carry the
+#                              same words)
+#
+# Key ``j`` is visible to a query at position ``i`` iff ``j <= i`` and
+# bit ``j // select_block`` of the query's bitmap is set.
+
+#: int32 words of one query row's block bitmap (one lane tile)
+SELECT_WORDS = 128
+#: a row of at most this many tokens is walked as a query block of this
+#: many tokens whatever the launch's ``block_q``
+SELECT_SHORT = 8
+
+
+def _selected_kernel(
+    scale, page, n_bufs, hkv, g, d, block_q, block, *refs,
+):
+    """Grid (R·Hkv,): step ``i`` visits the i-th ACTIVE (row, KV head)
+    pair of ``order`` (row-major, so rows are visited in ascending
+    order and a short row's out block is healed by the next row's, as
+    in ``_ragged_kernel``); steps past ``n_active`` do nothing. Per
+    pair: the walk over ``pages[vr, :counts[vr]]`` with table-indexed
+    pool DMAs ``n_bufs`` deep, the query block and its bitmap words
+    fetched one pair ahead, every page masked causally and by block."""
+    (table_ref, kv_lens_ref, q_lens_ref, q_starts_ref, order_ref, n_ref,
+     pages_ref, counts_ref, q_hbm, k_hbm, v_hbm, bits_hbm, out_hbm,
+     qbuf, bbuf, kbuf, vbuf, obuf, sem_q, sem_b, sem_k, sem_v, sem_o,
+     slot_ref, m_ref, l_ref, acc_ref) = refs
+    i = pl.program_id(0)
+    n_active = n_ref[0]
+    npages = k_hbm.shape[0]
+    rows = block_q * g
+    bpp = page // block                       # blocks a page
+
+    def pair(step):
+        vr = order_ref[step]
+        return vr, jax.lax.div(vr, hkv), jax.lax.rem(vr, hkv)
+
+    def kvdma(step, j, slot):
+        vr, r, h = pair(step)
+        lp = pages_ref[vr, jnp.minimum(j, jnp.maximum(counts_ref[vr] - 1, 0))]
+        pid = jnp.clip(table_ref[r, lp], 0, npages - 1)
+        return [
+            pltpu.make_async_copy(
+                k_hbm.at[pid, h], kbuf.at[slot], sem_k.at[slot]),
+            pltpu.make_async_copy(
+                v_hbm.at[pid, h], vbuf.at[slot], sem_v.at[slot]),
+        ]
+
+    def qdma(step, qslot):
+        _, r, h = pair(step)
+        start = pl.multiple_of(q_starts_ref[r] * g, 8)
+        return [
+            pltpu.make_async_copy(
+                q_hbm.at[h, pl.ds(start, rows)], qbuf.at[qslot],
+                sem_q.at[qslot]),
+            pltpu.make_async_copy(
+                bits_hbm.at[h, pl.ds(start, rows)], bbuf.at[qslot],
+                sem_b.at[qslot]),
+        ]
+
+    @pl.when(i == 0)
+    def _warmup():
+        slot_ref[0] = 0                       # KV slot rotation carry
+
+        @pl.when(n_active > 0)
+        def _start_first():
+            for cp in qdma(0, 0) + kvdma(0, 0, 0):
+                cp.start()
+
+    @pl.when(i < n_active)
+    def _pair():
+        vr, r, h = pair(i)
+        s0 = slot_ref[0]
+        qslot = jax.lax.rem(i, 2)
+        cnt = jnp.maximum(counts_ref[vr], 1)
+        kv_len = kv_lens_ref[r]
+        q_len = q_lens_ref[r]
+        for cp in qdma(i, qslot):
+            cp.wait()
+        lane_w = jax.lax.broadcasted_iota(jnp.int32, (1, SELECT_WORDS), 1)
+        lane_p = jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
+        lane_blk = jax.lax.div(lane_p, block)
+        start = pl.multiple_of(q_starts_ref[r] * g, 8)
+
+        def walk(rows_c):
+            """The pair's walk over the first ``rows_c`` (static) rows
+            of its query block."""
+            m_ref[:rows_c] = jnp.full((rows_c, 1), NEG_INF, jnp.float32)
+            l_ref[:rows_c] = jnp.zeros((rows_c, 1), jnp.float32)
+            acc_ref[:rows_c] = jnp.zeros((rows_c, d), jnp.float32)
+            q = qbuf[qslot, :rows_c]              # (rows_c, d)
+            words = bbuf[qslot, :rows_c]          # (rows_c, SELECT_WORDS)
+            row_tok = jax.lax.div(
+                jax.lax.broadcasted_iota(jnp.int32, (rows_c, 1), 0), g)
+            limit = kv_len - q_len + row_tok + 1  # (rows_c, 1)
+
+            def body(j, _):
+                slot = jax.lax.rem(s0 + j, n_bufs)
+                nxt = jax.lax.rem(s0 + j + 1, n_bufs)
+
+                @pl.when(j + 1 < cnt)
+                def _prefetch_in_pair():
+                    for cp in kvdma(i, j + 1, nxt):
+                        cp.start()
+
+                @pl.when(jnp.logical_and(j + 1 == cnt, i + 1 < n_active))
+                def _prefetch_next_pair():
+                    for cp in qdma(i + 1, 1 - qslot) + kvdma(i + 1, 0, nxt):
+                        cp.start()
+
+                chaos_delay(site="ragged_paged", step=None, me=None, n=None)
+                for cp in kvdma(i, j, slot):
+                    cp.wait()
+                lp = pages_ref[vr, jnp.minimum(j, cnt - 1)]
+                # the page's ``bpp`` bits of every query row: one word
+                # of the row's bitmap, picked by a lane compare (no
+                # gather)
+                bit0 = lp * bpp
+                word = jnp.sum(
+                    jnp.where(lane_w == jax.lax.div(bit0, 32), words, 0),
+                    axis=1, keepdims=True)        # (rows_c, 1)
+                word = jax.lax.shift_right_logical(
+                    word,
+                    jnp.broadcast_to(jax.lax.rem(bit0, 32), word.shape))
+                chosen = jnp.zeros((rows_c, page), jnp.int32)
+                for b in range(bpp):              # static
+                    chosen = jnp.where(
+                        lane_blk == b,
+                        jax.lax.shift_right_logical(
+                            word, jnp.full_like(word, b)) & 1,
+                        chosen)
+                pos = lp * page + lane_p
+                valid = jnp.logical_and(pos < limit, chosen > 0)
+                s = jax.lax.dot_general(
+                    q, kbuf[slot], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale                         # (rows_c, page) f32
+                s = jnp.where(valid, s, NEG_INF)
+                m = m_ref[:rows_c]
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+                l_ref[:rows_c] = alpha * l_ref[:rows_c] + jnp.sum(
+                    p, axis=1, keepdims=True)
+                v = vbuf[slot]
+                acc_ref[:rows_c] = alpha * acc_ref[:rows_c] + jnp.dot(
+                    p.astype(v.dtype), v,
+                    preferred_element_type=jnp.float32)
+                m_ref[:rows_c] = m_new
+                return 0
+
+            jax.lax.fori_loop(0, cnt, body, 0)
+            l = l_ref[:rows_c]
+            obuf[:rows_c] = (
+                acc_ref[:rows_c] / jnp.where(l > 0.0, l, 1.0)
+            ).astype(obuf.dtype)
+            out = pltpu.make_async_copy(
+                obuf.at[pl.ds(0, rows_c)],
+                out_hbm.at[h, pl.ds(start, rows_c)], sem_o.at[0])
+            out.start()
+            # waited before the grid advances (the out self-heal's order)
+            out.wait()
+
+        # a row of at most SELECT_SHORT tokens (a decode row beside a
+        # prefill chunk) walks as a block of that many, not of block_q
+        if block_q > SELECT_SHORT:
+            short = q_len <= SELECT_SHORT
+            pl.when(short)(functools.partial(walk, SELECT_SHORT * g))
+            pl.when(jnp.logical_not(short))(functools.partial(walk, rows))
+        else:
+            walk(rows)
+        slot_ref[0] = jax.lax.rem(s0 + cnt, n_bufs)
+
+
+@functools.lru_cache(maxsize=64)
+def _build_selected(
+    r, pps, npages, t_tokens, hkv, g, d, page, block_q, block, width,
+    q_dtype, scale, n_bufs, interpret,
+):
+    """The selected walk's pallas_call: takes ``(table, kv_lens, q_lens,
+    q_starts, order, n_active, pages (R·Hkv, W), counts (R·Hkv,), q,
+    k_pool, v_pool, bits)`` and returns ``[out]``."""
+    q_dtype = jnp.dtype(q_dtype)
+    rows = block_q * g
+    kernel = functools.partial(
+        _selected_kernel, scale, page, n_bufs, hkv, g, d, block_q, block)
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=8,
+        grid=(r * hkv,),
+        in_specs=[any_, any_, any_, any_],    # q, k pool, v pool, bits
+        out_specs=[any_],
+        scratch_shapes=[
+            pltpu.VMEM((2, rows, d), q_dtype),               # qbuf
+            pltpu.VMEM((2, rows, SELECT_WORDS), jnp.int32),  # bbuf
+            pltpu.VMEM((n_bufs, page, d), q_dtype),          # kbuf
+            pltpu.VMEM((n_bufs, page, d), q_dtype),          # vbuf
+            pltpu.VMEM((rows, d), q_dtype),                  # obuf
+            pltpu.SemaphoreType.DMA((2,)),                   # sem_q
+            pltpu.SemaphoreType.DMA((2,)),                   # sem_b
+            pltpu.SemaphoreType.DMA((n_bufs,)),              # sem_k
+            pltpu.SemaphoreType.DMA((n_bufs,)),              # sem_v
+            pltpu.SemaphoreType.DMA((1,)),                   # sem_o
+            pltpu.SMEM((1,), jnp.int32),                     # slot carry
+            pltpu.VMEM((rows, 1), jnp.float32),              # m
+            pltpu.VMEM((rows, 1), jnp.float32),              # l
+            pltpu.VMEM((rows, d), jnp.float32),              # acc
+        ],
+    )
+    # q/out blocks, the bitmap words, softmax state (the (·, 1) columns
+    # pad to full lanes) and the (rows, page) score temporaries
+    total = (3 * rows * d * q_dtype.itemsize + 2 * rows * SELECT_WORDS * 4
+             + rows * (d + 2 * 128) * 4 + 6 * rows * page * 4
+             + 4 * n_bufs * page * d * q_dtype.itemsize)
+    return shmem_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((hkv, t_tokens * g, d), q_dtype)],
+        collective_id=None,
+        vmem_limit_bytes=(total + (8 << 20)) if total > (12 << 20) else None,
+        interpret=local_interpret() if interpret is None else interpret,
+        # a name of its own that a search for the kernel's still finds
+        name="ragged_paged_attention_selected",
+        dimension_semantics=("arbitrary",),
+    )
+
+
+def active_rows(q_lens):
+    """``(order (R,), n (1,))``: the rows with ``q_lens > 0`` in
+    ascending order, the rest of ``order`` repeating the last of them
+    (a grid step past ``n`` then re-visits a block it already holds:
+    no fetch, no write)."""
+    r = q_lens.shape[0]
+    on = q_lens > 0
+    n = jnp.sum(on.astype(jnp.int32))
+    order = jnp.sort(jnp.where(on, jnp.arange(r, dtype=jnp.int32), r))
+    last = order[jnp.maximum(n - 1, 0)]
+    order = jnp.where(jnp.arange(r) < n, order, jnp.where(n > 0, last, 0))
+    return order.astype(jnp.int32), n.reshape(1).astype(jnp.int32)
+
+
 def auto_block_q(max_q_len: int, g: int) -> int:
     """Smallest block from the {8, 16, 32, 64, 128, ...} ladder covering
     ``max_q_len`` whose GQA row count (block·G) is sublane-aligned —
@@ -710,14 +966,14 @@ def auto_block_q(max_q_len: int, g: int) -> int:
 @functools.partial(
     jax.jit,
     static_argnames=("group", "scale", "soft_cap", "block_q", "n_bufs",
-                     "with_lse", "interpret", "window"),
+                     "with_lse", "interpret", "window", "select_block"),
 )
 def ragged_paged_attention(
     q, k_pool, v_pool, kv_lens, q_lens, q_starts, block_table, *,
     group: int, topologies=None, k_scale=None, v_scale=None,
     scale: float | None = None, soft_cap: float = 0.0, block_q: int = 8,
     n_bufs: int = 2, with_lse: bool = True, interpret=None,
-    window: int | None = None,
+    window: int | None = None, selected=None, select_block: int = 0,
 ):
     """Mixed prefill-chunk/decode attention over a shared page pool.
 
@@ -745,6 +1001,15 @@ def ragged_paged_attention(
     page's id is only read while the page is walked). Descriptor rows
     beside a window must be CAUSAL.
 
+    ``selected``: None is the contiguous walk above, today's launch bit
+    for bit. ``(pages, counts, bits)`` (the layout notes above
+    ``_selected_kernel``) is BLOCK-SPARSE attention with blocks of
+    ``select_block`` tokens: each (row, KV head) walks its listed pages
+    only, and a key is visible where it is causal AND its block's bit
+    is set in the query row's bitmap. A launch of its own
+    (``ragged_paged_attention_selected``); bf16/f32 pools, no lse, no
+    window, CAUSAL rows only (``topologies`` is not read).
+
     Returns (out (Hkv, T·G, D) in q.dtype, lse (Hkv, T·G) f32 — None
     without ``with_lse``, which also drops the kernel's lse writes).
     Rows of dim 1 outside the per-row valid spans hold garbage (the
@@ -753,6 +1018,33 @@ def ragged_paged_attention(
     hkv, tg, d = q.shape
     g = group
     npages, _, page, _ = k_pool.shape
+    if selected is not None:
+        pages, counts, bits = selected
+        r, pps = block_table.shape
+        _check_selected(page, select_block, k_scale, window, with_lse,
+                        soft_cap)
+        if (block_q * g) % 8:
+            raise ValueError(
+                f"ragged_paged_attention: block_q·G = {block_q * g} must "
+                "be sublane-aligned (multiple of 8)")
+        call = _build_selected(
+            r, pps, npages, tg // g, hkv, g, d, page, block_q,
+            int(select_block), int(pages.shape[-1]),
+            jnp.dtype(q.dtype).name,
+            float(1.0 / math.sqrt(d) if scale is None else scale),
+            n_bufs, interpret,
+        )
+        # the (row, KV head) pairs ``r·Hkv + h`` of the batched rows
+        order, n_active = active_rows(jnp.repeat(q_lens, hkv))
+        (out,) = call(
+            block_table.astype(jnp.int32), kv_lens.astype(jnp.int32),
+            q_lens.astype(jnp.int32), q_starts.astype(jnp.int32),
+            order, n_active,
+            pages.astype(jnp.int32).reshape(r * hkv, -1),
+            counts.astype(jnp.int32).reshape(r * hkv),
+            q, k_pool, v_pool, bits.astype(jnp.int32),
+        )
+        return out, None
     assert v_pool.shape == k_pool.shape, (k_pool.shape, v_pool.shape)
     assert tg % g == 0, (tg, g)
     t_tokens = tg // g
@@ -812,17 +1104,57 @@ def ragged_paged_attention(
     return out, lse[..., 0]
 
 
+def _check_selected(page, block, k_scale, window, with_lse, soft_cap):
+    """What the selected walk is built for, refused by name."""
+    if block < 1 or page % block or 32 % (page // block):
+        raise ValueError(
+            f"ragged_paged_attention: selected needs select_block >= 1 "
+            f"dividing the page into a power-of-two number of blocks <= "
+            f"32 (got select_block={block}, page={page})")
+    for name, on in (("int8 pools (k_scale)", k_scale is not None),
+                     ("window", window is not None),
+                     ("with_lse", bool(with_lse)),
+                     ("soft_cap", soft_cap > 0.0)):
+        if on:
+            raise ValueError(
+                f"ragged_paged_attention: selected with {name} is not "
+                "built")
+
+
+def selected_bits(chosen, group: int):
+    """``chosen`` (T, Hkv, NB) bool, block ``b`` attended by token
+    ``t``'s queries of KV head ``h`` -> the kernel's bitmap
+    ``(Hkv, T·G, SELECT_WORDS)`` int32 (``NB <= 32 · SELECT_WORDS``)."""
+    t, hkv, nb = chosen.shape
+    cap = 32 * SELECT_WORDS
+    if nb > cap:
+        raise ValueError(
+            f"selected_bits: {nb} blocks a sequence, the bitmap holds "
+            f"{cap}")
+    c = jnp.pad(chosen, ((0, 0), (0, 0), (0, cap - nb)))
+    c = c.reshape(t, hkv, SELECT_WORDS, 32).astype(jnp.uint32)
+    words = jnp.sum(c << jnp.arange(32, dtype=jnp.uint32), axis=-1,
+                    dtype=jnp.uint32)
+    words = jax.lax.bitcast_convert_type(words, jnp.int32)
+    words = jnp.broadcast_to(
+        words.transpose(1, 0, 2)[:, :, None, :],
+        (hkv, t, group, SELECT_WORDS))
+    return words.reshape(hkv, t * group, SELECT_WORDS)
+
+
 def ragged_paged_attention_xla(
     q, k_pool, v_pool, kv_lens, q_lens, q_starts, block_table, *,
     group: int, topologies=None, k_scale=None, v_scale=None, scale=None,
-    soft_cap=0.0, window=None,
+    soft_cap=0.0, window=None, selected=None, select_block: int = 0,
 ):
     """Dense-XLA twin (correctness reference + degradation target):
     gather each row's pages into a contiguous cache and run the masked
     dense attention with the same causal-frontier semantics — including
     the per-row topology operand (TREE ancestor-bitmask masks; CAUSAL
     and SHARED_PREFIX rows mask causally). Same signature/garbage-rows
-    contract as :func:`ragged_paged_attention`.
+    contract as :func:`ragged_paged_attention`. With ``selected`` the
+    same block mask from the same bitmap (the page list is the
+    kernel's to walk: the twin gathers every page).
     """
     hkv, tg, d = q.shape
     g = group
@@ -831,6 +1163,10 @@ def ragged_paged_attention_xla(
     r, pps = block_table.shape
     if scale is None:
         scale = 1.0 / math.sqrt(d)
+    if selected is not None:
+        _check_selected(page, select_block, k_scale, window, False,
+                        soft_cap)
+        topologies = None
     if k_scale is not None:
         k_pool = (k_pool.astype(jnp.float32)
                   * k_scale[..., None]).astype(q.dtype)
@@ -893,6 +1229,15 @@ def ragged_paged_attention_xla(
             cp_ok, ok,
         )
     mask = ok[None, :, None, :]
+    if selected is not None:
+        # bit b of a token's bitmap: its queries attend block b
+        words = selected[2].reshape(hkv, t_tokens, g, -1)[:, :, 0]
+        blk = pos_s // select_block                          # (S,)
+        at = words[:, :, blk // 32]                          # (H, T, S)
+        bit = jax.lax.shift_right_logical(
+            at, jnp.broadcast_to((blk % 32).astype(jnp.int32), at.shape)
+        ) & 1
+        mask = mask & (bit > 0)[:, :, None, :]               # (H, T, 1, S)
     s = jnp.where(mask, s, NEG_INF)
     m = jnp.max(s, axis=-1, keepdims=True)
     p = jnp.where(mask, jnp.exp(s - m), 0.0)

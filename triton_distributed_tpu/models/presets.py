@@ -117,6 +117,58 @@ def k_exaone_236b(**overrides) -> TransformerConfig:
     return TransformerConfig(**cfg)
 
 
+#: MiniCPM-SALA's published ``mixer_types``: 8 block-sparse attention
+#: layers ("minicpm4") among 24 lightning layers
+_SALA_SPARSE_LAYERS = (0, 9, 16, 17, 22, 29, 30, 31)
+
+
+def minicpm_sala(**overrides) -> TransformerConfig:
+    """MiniCPM-SALA (openbmb, ``model_type: minicpm_sala``) as
+    published: 32 layers, hidden 4096, a gated FFN of 16384 on every
+    layer, vocabulary 73448, untied head; 8 block-sparse attention
+    layers (32 query / 2 KV heads x 128, q/k RMS norm, no rotation,
+    sigmoid output gate) and 24 lightning linear-attention layers (32
+    heads x 128, q/k norm, rotation at theta 1e4, output norm and
+    gate); muP scalings: embedding x 12, every sub-layer's output x
+    1.4 / sqrt(32), the final hidden state / (4096 / 256).
+
+    The sparse layers' sizes are MiniCPM4's published ``sparse_config``
+    (kernel 32, stride 16, block 64, 1 initial block, window 2048,
+    top-64, dense below 8192): the config names the family, not the
+    numbers.
+
+    A depth cut is two integers: ``n_layers`` and ``layer_stride`` run
+    the published layers ``0, layer_stride, 2 layer_stride, ...``
+    (their kinds kept; ``residual_scale`` stays the PUBLISHED depth's,
+    a constant of the model). The per-layer tuples are derived here."""
+    n = int(overrides.pop("n_layers", 32))
+    step = int(overrides.pop("layer_stride", 1))
+    if n < 1 or step < 1 or (n - 1) * step >= 32:
+        raise ValueError(
+            f"minicpm_sala: n_layers={n} at layer_stride={step} reaches "
+            "past the 32 published layers")
+    kinds = tuple(
+        "attention" if i * step in _SALA_SPARSE_LAYERS else "lightning"
+        for i in range(n))
+    cfg = dict(
+        vocab=73448, n_layers=n, hidden=4096, ffn=16384,
+        n_heads=32, n_kv_heads=2, head_dim=128, norm_eps=1e-6,
+        layer_mixer=kinds, lightning_heads=32,
+        sparse_kernel=32, sparse_stride=16, sparse_block=64,
+        sparse_init_blocks=1, sparse_window=2048, sparse_topk=64,
+        sparse_dense_len=8192,
+        rope_theta=1e4,
+        rope_layers=tuple(
+            i for i, k in enumerate(kinds) if k == "lightning"),
+        qk_norm=True, gated_ffn=True, out_gate=True, out_norm=True,
+        embed_scale=12.0, residual_scale=1.4 / 32 ** 0.5,
+        logit_divisor=4096 / 256,
+        dtype=jnp.bfloat16,
+    )
+    cfg.update(overrides)
+    return TransformerConfig(**cfg)
+
+
 def tiny(preset=None, **overrides) -> TransformerConfig:
     """CI-sized twin: same topology knobs as ``preset`` (or dense
     defaults), tiny dims — what the tests and the driver dryrun use."""
@@ -151,6 +203,24 @@ def tiny(preset=None, **overrides) -> TransformerConfig:
             shared_experts=preset.shared_experts,
             router=preset.router,
             routed_scale=preset.routed_scale,
+            # the mixers of PR 33 at the twin's depth and sizes: the
+            # first two layers' kinds, four heads a lightning layer,
+            # blocks of 8 with stride 2 / kernel 4, top-4, a window of
+            # 16 and a dense length of 32
+            layer_mixer=preset.layer_mixer[:2],
+            lightning_heads=min(preset.lightning_heads, 4),
+            sparse_kernel=min(preset.sparse_kernel, 4),
+            sparse_stride=min(preset.sparse_stride, 2),
+            sparse_block=min(preset.sparse_block, 8),
+            sparse_init_blocks=preset.sparse_init_blocks,
+            sparse_window=min(preset.sparse_window, 16),
+            sparse_topk=min(preset.sparse_topk, 4),
+            sparse_dense_len=min(preset.sparse_dense_len, 32),
+            out_gate=preset.out_gate,
+            out_norm=preset.out_norm,
+            embed_scale=preset.embed_scale,
+            residual_scale=preset.residual_scale,
+            logit_divisor=preset.logit_divisor,
         )
     cfg.update(overrides)
     return TransformerConfig(**cfg)

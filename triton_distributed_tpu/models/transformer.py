@@ -157,6 +157,45 @@ class TransformerConfig:
     # adds the part of the result they give (0 = all are held)
     experts_held: int = 0
     first_expert_held: int = 0
+    # ---- mixers beside softmax attention on every key (PR 33); the
+    # defaults are the model above. ``serving_step`` implements them,
+    # ``forward`` raises on them by name.
+    # per-layer mixer kind, "attention" | "lightning" (() = all
+    # attention). A LIGHTNING layer is linear attention with a per-head
+    # decay: ``lightning_heads`` query AND key/value heads of
+    # ``head_dim``, state S_t = lam_h S_{t-1} + k_t^T v_t (head_dim x
+    # head_dim, float32, one a slot and layer in ``ServingState``),
+    # o_t = (q_t / sqrt(head_dim)) S_t, lam_h = exp(-2^(-8 (h+1) /
+    # lightning_heads))
+    layer_mixer: tuple = ()
+    lightning_heads: int = 0
+    # BLOCK-SPARSE attention on the attention layers (``sparse_topk``
+    # 0 = dense): a query at position i >= ``sparse_dense_len`` attends
+    # the keys j <= i of its ``sparse_topk`` best blocks of
+    # ``sparse_block`` tokens, chosen per KV head from the scores of
+    # its query heads against COMPRESSED keys (the mean of
+    # ``sparse_kernel`` keys every ``sparse_stride``; kept in a pool
+    # beside K/V), with the first ``sparse_init_blocks`` blocks and the
+    # blocks of the last ``sparse_window`` positions always chosen
+    # (kernels/sparse_select.py has the lines)
+    sparse_kernel: int = 0
+    sparse_stride: int = 0
+    sparse_block: int = 0
+    sparse_init_blocks: int = 0
+    sparse_window: int = 0
+    sparse_topk: int = 0
+    sparse_dense_len: int = 0
+    # sigmoid output gate on every mixer: out = (o * sigmoid(a Wz)) Wo
+    # (parameter ``wz``), and on lightning layers an RMS norm of o over
+    # each head before it (gain ``norm_o``, (head_dim,))
+    out_gate: bool = False
+    out_norm: bool = False
+    # muP scalings: the embedding times ``embed_scale``, every
+    # sub-layer's output times ``residual_scale`` before it is added,
+    # the final hidden state divided by ``logit_divisor`` before the head
+    embed_scale: float = 1.0
+    residual_scale: float = 1.0
+    logit_divisor: float = 1.0
 
     def __post_init__(self):
         if self.attn not in ("tp", "ring", "ulysses"):
@@ -259,12 +298,81 @@ class TransformerConfig:
                 f"{', '.join(quantized)}: not built beside gated_ffn / "
                 "shared_experts / experts_held (their matrices are "
                 "served in param_dtype)")
+        if self.layer_mixer and (
+            len(self.layer_mixer) != self.n_layers
+            or set(self.layer_mixer) - {"attention", "lightning"}
+        ):
+            raise ValueError(
+                f"layer_mixer must give 'attention' or 'lightning' for "
+                f"each of the {self.n_layers} layers, got "
+                f"{self.layer_mixer!r}")
+        if bool(self.lightning_layers) != (self.lightning_heads > 0):
+            raise ValueError(
+                f"lightning_heads={self.lightning_heads} and layer_mixer's "
+                f"lightning layers {self.lightning_layers} go together")
+        sparse = ("sparse_kernel", "sparse_stride", "sparse_block",
+                  "sparse_init_blocks", "sparse_window", "sparse_dense_len")
+        if self.sparse_topk:
+            k, st, b = (self.sparse_kernel, self.sparse_stride,
+                        self.sparse_block)
+            if min(getattr(self, f) for f in sparse) < 1 or b % st \
+                    or k % st or self.sparse_dense_len % b:
+                raise ValueError(
+                    f"sparse_topk={self.sparse_topk} needs "
+                    f"{', '.join(sparse)} >= 1, sparse_stride dividing "
+                    "sparse_kernel and sparse_block, and sparse_block "
+                    "dividing sparse_dense_len (got "
+                    f"{[getattr(self, f) for f in sparse]})")
+        elif any(getattr(self, f) for f in sparse):
+            raise ValueError(
+                f"{', '.join(f for f in sparse if getattr(self, f))} "
+                "without sparse_topk")
+        stateful = [k for k, on in (
+            ("layer_mixer", bool(self.lightning_layers)),
+            ("sparse_topk", self.sparse_topk > 0)) if on]
+        if stateful and self.window_layers:
+            raise ValueError(
+                f"{', '.join(stateful)} with sliding-window layers "
+                "(layer_attn) in the same model: not built")
+        if stateful and self.kv_quant is not None:
+            raise ValueError(
+                f"{', '.join(stateful)} with kv_quant={self.kv_quant!r}: "
+                "compressed keys and the selected walk are built over "
+                "bf16 pools only")
+        if stateful and self.attn != "tp":
+            raise ValueError(
+                f"{', '.join(stateful)} with attn={self.attn!r}: built "
+                "for attn='tp' only")
+        if self.out_norm and not self.lightning_layers:
+            raise ValueError("out_norm is the lightning layers' output "
+                             "norm: no lightning layer in layer_mixer")
 
     @property
     def window_layers(self) -> tuple:
         """Indices of the sliding-window attention layers."""
         return tuple(
             i for i, k in enumerate(self.layer_attn) if k == "sliding")
+
+    @property
+    def lightning_layers(self) -> tuple:
+        """Indices of the lightning (linear-attention) layers."""
+        return tuple(
+            i for i, k in enumerate(self.layer_mixer) if k == "lightning")
+
+    @property
+    def sparse_layers(self) -> tuple:
+        """Indices of the block-sparse attention layers: every
+        attention layer of a model with ``sparse_topk``."""
+        if not self.sparse_topk:
+            return ()
+        return tuple(i for i in range(self.n_layers)
+                     if i not in self.lightning_layers)
+
+    def layer_heads(self, i: int) -> tuple:
+        """``(query heads, key/value heads)`` of layer ``i``'s mixer."""
+        if i in self.lightning_layers:
+            return self.lightning_heads, self.lightning_heads
+        return self.n_heads, self.n_kv_heads
 
     @property
     def local_experts(self) -> int:
@@ -300,6 +408,13 @@ class TransformerConfig:
             ("shared_experts", self.shared_experts > 0),
             ("router", self.router != "softmax"),
             ("experts_held", self.experts_held > 0),
+            ("layer_mixer", bool(self.lightning_layers)),
+            ("sparse_topk", self.sparse_topk > 0),
+            ("out_gate", self.out_gate),
+            ("out_norm", self.out_norm),
+            ("embed_scale", self.embed_scale != 1.0),
+            ("residual_scale", self.residual_scale != 1.0),
+            ("logit_divisor", self.logit_divisor != 1.0),
         ) if on)
 
     @property
@@ -343,6 +458,17 @@ class Transformer:
                 "sliding-window layers (layer_attn) with cp > 1: a ring "
                 "pool is one chip's, the cp shard walk is not built "
                 "over it")
+        stateful = [k for k, on in (
+            ("lightning layers (layer_mixer)", bool(c.lightning_layers)),
+            ("block-sparse attention (sparse_topk)", c.sparse_topk > 0),
+        ) if on]
+        for axis, n in (("tp", self.tp), ("cp", self.cp)):
+            if stateful and n > 1:
+                raise ValueError(
+                    f"{', '.join(stateful)} with {axis}={n}: the "
+                    "recurrent state, the compressed keys and the "
+                    "selection are one chip's; sharding them is not "
+                    "built")
 
     def _plain_only(self) -> None:
         """``forward`` runs the plain architecture; refuse, by name, a
@@ -522,15 +648,24 @@ class Transformer:
         up_w = 2 if c.gated_ffn else 1
         e, fd = c.local_experts, c.dense_ffn_width
         for i in range(c.n_layers):
+            # a lightning layer has its own head counts: as many
+            # key/value heads as query heads
+            hq, hkv = c.layer_heads(i)
+            qd = hq * c.head_dim
             blk = {
                 "norm_attn": jnp.ones((c.hidden,), pd),
                 "norm_mlp": jnp.ones((c.hidden,), pd),
-                "wqkv": dense(next(keys), (c.hidden, c.qkv_dim)),
-                "wo": dense(next(keys), (c.q_dim, c.hidden)),
+                "wqkv": dense(next(keys),
+                              (c.hidden, qd + 2 * hkv * c.head_dim)),
+                "wo": dense(next(keys), (qd, c.hidden)),
             }
             if c.qk_norm:
                 blk["norm_q"] = jnp.ones((c.head_dim,), pd)
                 blk["norm_k"] = jnp.ones((c.head_dim,), pd)
+            if c.out_gate:
+                blk["wz"] = dense(next(keys), (c.hidden, qd))
+            if c.out_norm and i in c.lightning_layers:
+                blk["norm_o"] = jnp.ones((c.head_dim,), pd)
             if c.moe != "none" and i in c.moe_layers:
                 blk["router"] = dense(next(keys), (c.hidden, c.num_experts))
                 if c.router == "sigmoid_bias":
@@ -758,6 +893,10 @@ class Transformer:
             }
             if c.qk_norm:
                 blk.update(norm_q=rep, norm_k=rep)
+            if c.out_gate:
+                blk.update(wz=ns(None, t))
+            if c.out_norm and i in c.lightning_layers:
+                blk.update(norm_o=rep)
             if c.moe != "none" and i in c.moe_layers:
                 if c.router == "sigmoid_bias":
                     blk.update(router_bias=rep)
@@ -1102,8 +1241,34 @@ class Transformer:
             zero = jnp.zeros((), c.dtype)
             return lambda: z + zero
 
+        lightning, sparse = c.lightning_layers, c.sparse_layers
+        if sparse and (page % c.sparse_block or page % c.sparse_stride
+                       or c.sparse_dense_len % page):
+            raise ValueError(
+                f"block-sparse attention (sparse_topk) needs a page that "
+                f"sparse_block={c.sparse_block} and sparse_stride="
+                f"{c.sparse_stride} divide and that divides "
+                f"sparse_dense_len={c.sparse_dense_len}, got page={page}")
         full = pools(npages)
-        if windowed:
+        recurrent = ckeys = ()
+        if lightning or sparse:
+            # a lightning layer keeps a state a slot and no pages; a
+            # sparse layer a pool of compressed keys beside K/V
+            # (serving/state.py). Each leaf its own buffer (donated)
+            recurrent = tuple(
+                jnp.zeros((slots, c.lightning_heads, c.head_dim,
+                           c.head_dim), jnp.float32)
+                if i in lightning else None for i in range(c.n_layers))
+            ckeys = tuple(
+                jax.device_put(
+                    jnp.zeros((npages, c.n_kv_heads,
+                               page // c.sparse_stride, c.head_dim),
+                              c.dtype), spec)
+                if i in sparse else None for i in range(c.n_layers))
+            layers = tuple(
+                None if i in lightning else (full(), full())
+                for i in range(c.n_layers))
+        elif windowed:
             # a window layer keeps slots · ring pages and no more
             ringed = pools(slots * ring)
             layers = tuple(
@@ -1122,6 +1287,8 @@ class Transformer:
             ring_table=ring_table(slots, pps, ring) if windowed else None,
             window_layers=windowed,
             ring=ring,
+            recurrent=recurrent,
+            ckeys=ckeys,
         )
 
     def _ragged_attn(self, qp, k_pool, v_pool, state, q_lens, q_starts,
@@ -1238,6 +1405,165 @@ class Transformer:
         )
         return out
 
+    def _lightning_mix(self, q, k, v, state, li, q_lens, q_starts,
+                       block_q, use_pallas):
+        """A lightning layer's mixing of the packed step: q, k, v (T,
+        heads · D) -> ``(o (T, heads · D) float32, the layer's new
+        recurrent state)``; kernel or XLA twin
+        (kernels/lightning_attention.py)."""
+        from triton_distributed_tpu.kernels.lightning_attention import (
+            lightning_attention,
+            lightning_attention_xla,
+        )
+
+        c = self.config
+        t = q.shape[0]
+        heads = c.lightning_heads
+        qh, kh, vh = (
+            a.reshape(t, heads, c.head_dim).transpose(1, 0, 2)
+            .astype(jnp.float32) for a in (q, k, v))
+        mix = lightning_attention if use_pallas else lightning_attention_xla
+        o, new = mix(qh, kh, vh, state.recurrent[li], state.kv_lens,
+                     q_lens, q_starts, block_q=block_q)
+        return o.transpose(1, 0, 2).reshape(t, heads * c.head_dim), new
+
+    def _gate_out(self, blk, o, xn, heads):
+        """The mixer's output before ``wo``: on a lightning layer RMS-
+        normed over each head (``config.out_norm``), then times the
+        sigmoid gate of the layer's normed input."""
+        c = self.config
+        t = o.shape[0]
+        o = o.astype(jnp.float32)
+        if "norm_o" in blk:
+            o = self._rmsnorm(o.reshape(t, heads, c.head_dim),
+                              blk["norm_o"]).reshape(t, -1)
+        gate = self._dmm(xn, blk["wz"], shard=self._attn_proj_shard[0])
+        return (o * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(
+            c.dtype)
+
+    def _attention_mix(self, li, q, k, v, pools, state, kv_shape,
+                       append_global, append_ring, token_rows, token_pos,
+                       q_lens, q_starts, topologies, block_q, use_pallas,
+                       n_bufs, new_ckeys):
+        """A softmax-attention layer of the packed step: append the
+        step's K/V to the layer's pools, then attend through them:
+        full causal, sliding-window over a ring (``append_ring``), or
+        block-sparse over the selected pages (``config.sparse_layers``).
+        Returns ``(o (T, q_dim), k_pool, v_pool)``."""
+        from triton_distributed_tpu.kernels.ragged_paged_attention import (
+            pack_gqa_rows,
+            unpack_gqa_rows,
+        )
+
+        scope = jax.named_scope
+        c = self.config
+        t = q.shape[0]
+        kp, vp = pools
+        k = k.reshape(kv_shape)
+        v = v.reshape(kv_shape)
+        is_window = append_ring is not None
+        append_layer = append_ring if is_window else append_global
+        with scope("kv_append"):
+            if isinstance(kp, dict):
+                from triton_distributed_tpu.kernels.flash_decode \
+                    import quantize_kv
+
+                k_new, v_new = (
+                    dict(zip(("q", "scale"), quantize_kv(x)))
+                    for x in (k, v)
+                )
+            else:
+                k_new, v_new = k.astype(kp.dtype), v.astype(vp.dtype)
+            kp, vp = append_layer(kp, vp, k_new, v_new)
+            kp = jax.tree.map(
+                lambda a: jax.lax.with_sharding_constraint(
+                    a, self._serving_pool_sharding
+                ), kp,
+            )
+            vp = jax.tree.map(
+                lambda a: jax.lax.with_sharding_constraint(
+                    a, self._serving_pool_sharding
+                ), vp,
+            )
+            if li in c.sparse_layers:
+                from triton_distributed_tpu.kernels.sparse_select import (
+                    append_compressed,
+                )
+
+                # the compressed keys the step's tokens complete, from
+                # the pool they were just appended to
+                new_ckeys[li] = append_compressed(
+                    state.ckeys[li], kp, state.block_table, state.kv_lens,
+                    q_lens, kernel=c.sparse_kernel, stride=c.sparse_stride,
+                    block_q=block_q)
+        with scope("attn"):
+            qp = pack_gqa_rows(
+                q.reshape(t, c.n_heads, c.head_dim), c.n_kv_heads
+            )
+            if li in c.sparse_layers:
+                o = self._selected_attn(
+                    qp, q, kp, vp, new_ckeys[li], state, token_rows,
+                    token_pos, q_lens, q_starts, block_q, use_pallas,
+                    n_bufs)
+            elif is_window:
+                # the ring table in the block table's place, and
+                # the walk bounded below by the window
+                o = self._ragged_attn(
+                    qp, kp, vp,
+                    state.replace(layers=(),
+                                  block_table=state.ring_table),
+                    q_lens, q_starts, block_q, use_pallas, n_bufs,
+                    topologies, window=c.window,
+                )
+            else:
+                attn = (self._cp_ragged_attn if state.cp > 1
+                        else self._ragged_attn)
+                o = attn(
+                    qp, kp, vp, state.replace(layers=()), q_lens,
+                    q_starts, block_q, use_pallas, n_bufs,
+                    topologies,
+                )
+            o = unpack_gqa_rows(o, c.n_heads).reshape(t, c.q_dim)
+        return o, kp, vp
+
+    def _selected_attn(self, qp, q, kp, vp, kc, state, token_rows,
+                       token_pos, q_lens, q_starts, block_q, use_pallas,
+                       n_bufs):
+        """Block-sparse attention of one layer: choose each query
+        position's blocks from the compressed keys (scope
+        ``sparse_select``), then the ragged kernel's walk over the
+        chosen pages, or its XLA twin with the same mask."""
+        from triton_distributed_tpu.kernels.ragged_paged_attention import (
+            ragged_paged_attention,
+            ragged_paged_attention_xla,
+        )
+        from triton_distributed_tpu.kernels.sparse_select import (
+            select_blocks,
+        )
+
+        c = self.config
+        g = c.n_heads // c.n_kv_heads
+        with jax.named_scope("sparse_select"):
+            chosen = select_blocks(
+                q.reshape(-1, c.n_heads, c.head_dim), kc,
+                state.block_table, token_rows, token_pos, state.kv_lens,
+                q_lens, q_starts, group=g, page=state.page,
+                kernel=c.sparse_kernel,
+                stride=c.sparse_stride, block=c.sparse_block,
+                init_blocks=c.sparse_init_blocks, window=c.sparse_window,
+                topk=c.sparse_topk, dense_len=c.sparse_dense_len)
+        kw = dict(group=g, selected=chosen, select_block=c.sparse_block)
+        if use_pallas:
+            o, _ = ragged_paged_attention(
+                qp, kp, vp, state.kv_lens, q_lens, q_starts,
+                state.block_table, block_q=block_q, n_bufs=n_bufs,
+                with_lse=False, **kw)
+        else:
+            o, _ = ragged_paged_attention_xla(
+                qp, kp, vp, state.kv_lens, q_lens, q_starts,
+                state.block_table, **kw)
+        return o
+
     def kv_append_by_kernel(self, use_pallas: bool) -> bool:
         """Does a serving step append by the ``kernels/kv_append``
         kernel (True) or by its XLA twin, the row scatter? Decided by
@@ -1341,6 +1667,16 @@ class Transformer:
         npages = state.npages
         with scope("embed"):
             x = params["embed"][tokens].astype(c.dtype)      # (T, H)
+            if c.embed_scale != 1.0:
+                x = x * c.embed_scale
+
+        def add(x, y):
+            # the residual stream takes a sub-layer's output times
+            # ``residual_scale`` (muP depth scaling; 1 = plain add)
+            if c.residual_scale != 1.0:
+                y = y * c.residual_scale
+            return x + y.astype(x.dtype)
+
         def appender(table, pool_pages):
             """``(kv_shape, append_layer)`` for the pools ``table``
             addresses (the block table, or a ring table)."""
@@ -1428,78 +1764,48 @@ class Transformer:
 
         new_layers = []
         new_states = None if moe_state is None else list(moe_state)
+        new_recurrent, new_ckeys = list(state.recurrent), list(state.ckeys)
+        lightning = c.lightning_layers
         qkv_sh, wo_sh = self._attn_proj_shard
-        for li, (blk, (kp, vp)) in enumerate(
+        for li, (blk, pools) in enumerate(
             zip(params["blocks"], state.layers)
         ):
+            hq, hkv = c.layer_heads(li)
             with scope("attn_proj"):
                 xn = self._rmsnorm(x, blk["norm_attn"])
                 qkv = self._dmm(xn, blk["wqkv"], shard=qkv_sh)  # (T, qkv)
                 q, k, v = jnp.split(
-                    qkv, [c.q_dim, c.q_dim + c.kv_dim], axis=-1
+                    qkv, [hq * c.head_dim, (hq + hkv) * c.head_dim],
+                    axis=-1,
                 )
                 if c.qk_norm or li in c.rope_layers:
                     with scope("qk_rope"):
                         q, k = self._qk_norm_rope(
                             blk, q, k,
                             rope if li in c.rope_layers else None)
-                k = k.reshape(kv_shape)
-                v = v.reshape(kv_shape)
-            is_window = li in windowed
-            append_layer = append_ring if is_window else append_global
-            with scope("kv_append"):
-                if isinstance(kp, dict):
-                    from triton_distributed_tpu.kernels.flash_decode \
-                        import quantize_kv
-
-                    k_new, v_new = (
-                        dict(zip(("q", "scale"), quantize_kv(x)))
-                        for x in (k, v)
-                    )
-                else:
-                    k_new, v_new = k.astype(kp.dtype), v.astype(vp.dtype)
-                kp, vp = append_layer(kp, vp, k_new, v_new)
-                kp = jax.tree.map(
-                    lambda a: jax.lax.with_sharding_constraint(
-                        a, self._serving_pool_sharding
-                    ), kp,
-                )
-                vp = jax.tree.map(
-                    lambda a: jax.lax.with_sharding_constraint(
-                        a, self._serving_pool_sharding
-                    ), vp,
-                )
-            new_layers.append((kp, vp))
-            with scope("attn"):
-                qp = pack_gqa_rows(
-                    q.reshape(t, c.n_heads, c.head_dim), c.n_kv_heads
-                )
-                if is_window:
-                    # the ring table in the block table's place, and
-                    # the walk bounded below by the window
-                    o = self._ragged_attn(
-                        qp, kp, vp,
-                        state.replace(layers=(),
-                                      block_table=state.ring_table),
-                        q_lens, q_starts, block_q, use_pallas, n_bufs,
-                        topologies, window=c.window,
-                    )
-                else:
-                    attn = (self._cp_ragged_attn if state.cp > 1
-                            else self._ragged_attn)
-                    o = attn(
-                        qp, kp, vp, state.replace(layers=()), q_lens,
-                        q_starts, block_q, use_pallas, n_bufs,
-                        topologies,
-                    )
-                o = unpack_gqa_rows(o, c.n_heads).reshape(t, c.q_dim)
+            if li in lightning:
+                new_layers.append(None)
+                with scope("attn"), scope("linear_attn"):
+                    o, new_recurrent[li] = self._lightning_mix(
+                        q, k, v, state, li, q_lens, q_starts, block_q,
+                        use_pallas)
+            else:
+                o, kp, vp = self._attention_mix(
+                    li, q, k, v, pools, state, kv_shape, append_global,
+                    append_ring if li in windowed else None, token_rows,
+                    token_pos, q_lens, q_starts, topologies, block_q,
+                    use_pallas, n_bufs, new_ckeys)
+                new_layers.append((kp, vp))
             with scope("attn_proj"):
-                x = x + self._dmm(o.astype(c.dtype), blk["wo"],
-                                  shard=wo_sh)
+                if c.out_gate:
+                    with scope("out_gate"):
+                        o = self._gate_out(blk, o, xn, hq)
+                x = add(x, self._dmm(o.astype(c.dtype), blk["wo"],
+                                     shard=wo_sh))
             if "up" in blk:
                 with scope("dense_ffn"):
                     xn = self._rmsnorm(x, blk["norm_mlp"])
-                    x = x + self._dense_mlp(xn, blk["up"], blk["down"])
+                    x = add(x, self._dense_mlp(xn, blk["up"], blk["down"]))
             elif c.moe == "ep":
                 with scope("moe_route"):
                     xn = self._rmsnorm(x, blk["norm_mlp"])
@@ -1535,6 +1841,8 @@ class Transformer:
                     x = x + y.astype(x.dtype)
         with scope("lm_head"):
             x = self._rmsnorm(x, params["norm_f"])
+            if c.logit_divisor != 1.0:
+                x = x / c.logit_divisor
             if all_logits:
                 # logits at EVERY packed position — the speculative
                 # verify pass needs the next-token distribution after
@@ -1554,7 +1862,9 @@ class Transformer:
                 )
             else:
                 logits = x_last.astype(jnp.float32) @ params["lm_head"]
-        new_state = state.replace(layers=tuple(new_layers))
+        new_state = state.replace(
+            layers=tuple(new_layers), recurrent=tuple(new_recurrent),
+            ckeys=tuple(new_ckeys))
         if moe_state is None:
             return logits, new_state
         return logits, new_state, new_states

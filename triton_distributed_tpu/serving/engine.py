@@ -167,16 +167,34 @@ class Request:
     t_admit: float | None = None
     t_first: float | None = None
 
+    # ``seq``'s buffer, prompt + ``max_new`` long, and how many of the
+    # generated tokens it holds
+    _seq_buf: np.ndarray | None = field(default=None, repr=False,
+                                        compare=False)
+    _seq_n: int = field(default=0, repr=False, compare=False)
+
     @property
     def seq(self) -> np.ndarray:
         """Every known token of the sequence: prompt + generated. The
         recompute prefix after an eviction IS this — re-prefilling it
-        resumes generation from the exact cursor."""
-        if not self.generated:
+        resumes generation from the exact cursor. A view of one buffer
+        that takes each generated token once: the scheduler reads it
+        several times a row and step, and a sequence can be tens of
+        thousands of tokens long."""
+        n = len(self.generated)
+        if not n:
             return self.prompt
-        return np.concatenate(
-            [self.prompt, np.asarray(self.generated, np.int32)]
-        )
+        lp = len(self.prompt)
+        buf = self._seq_buf
+        if buf is None or len(buf) < lp + n:
+            buf = self._seq_buf = np.empty(
+                (lp + max(n, self.max_new),), np.int32)
+            buf[:lp] = self.prompt
+            self._seq_n = 0
+        have = min(self._seq_n, n)
+        buf[lp + have:lp + n] = self.generated[have:n]
+        self._seq_n = n
+        return buf[:lp + n]
 
 
 @dataclass(frozen=True)
@@ -217,6 +235,14 @@ class EngineConfig:
     # motif traffic; token streams are unchanged (frozen pages with
     # equal chain hashes hold byte-identical KV by construction).
     prefix_share: bool = False
+    # greedy_on_device: a greedy engine takes each logits row's arg-max
+    # on the device (one tiny program behind the step's) and fetches a
+    # token id a row, not the (rows, vocab) float32 logits: 9.4 MB a
+    # step at 32 slots x 73448, 1.9 of the 4.2 ms the device idled in
+    # the fetch (the rest is the runtime's notice that the step is
+    # done). The host's non-finite check moves with it
+    # (``_greedy_tokens``).
+    greedy_on_device: bool = False
 
 
 #: the phases of one ``ServingEngine.step``, in order; each is the host
@@ -286,6 +312,17 @@ class EngineStats:
     # only those from its window's first key on (0 without such layers)
     global_pages_walked: int = 0
     window_pages_walked: int = 0
+    # the work of a model with block-sparse attention or lightning
+    # layers (0 without them), summed over the batched rows of the
+    # device steps, counted in ``_assemble``: pages ONE sparse layer's
+    # walk visits (a decode row at most ``sparse_topk``, one a chosen
+    # block; a prefill chunk the union of its positions' choices, read
+    # as every page it holds: an upper bound), rows whose last position
+    # is past ``sparse_dense_len`` (their blocks are chosen by score),
+    # and rows that read and write a lightning layer's recurrent state
+    selected_pages_walked: int = 0
+    sparse_rows: int = 0
+    state_rows: int = 0
     # packed rows of the device steps: the sum of their widths (each
     # step's follows its ``block_q`` rung, ``ServingEngine._width``)
     packed_rows: int = 0
@@ -446,6 +483,17 @@ def poisson_trace(seed: int, n_requests: int, mean_interarrival: float,
     return out
 
 
+def _greedy_tokens(logits):
+    """(rows, vocab) logits -> (rows,) int32: each row's arg-max (the
+    first of equals, as ``np.argmax``), -1 where the row's largest is
+    not finite (a NaN or +inf anywhere in it)."""
+    import jax.numpy as jnp
+
+    top = jnp.max(logits, axis=-1)
+    return jnp.where(jnp.isfinite(top), jnp.argmax(logits, axis=-1),
+                     -1).astype(jnp.int32)
+
+
 def _ceil8(x: int) -> int:
     return -(-x // 8) * 8
 
@@ -483,6 +531,7 @@ class ServingEngine:
                  grid_schedule=None, tenants=None,
                  aging_ticks: int = 64, ops=None,
                  propagate_failures: bool = False):
+        import jax
         import jax.numpy as jnp
 
         from triton_distributed_tpu.runtime.health import HealthLedger
@@ -513,6 +562,15 @@ class ServingEngine:
             if model.config.window_layers else 0
         if self._window:
             self._refuse_beside_window(cfg)
+        # lightning layers keep a recurrent state a slot, sparse layers
+        # compressed keys and a selection: likewise
+        mc = model.config
+        self._stateful = tuple(k for k, on in (
+            ("lightning layers (layer_mixer)", bool(mc.lightning_layers)),
+            ("block-sparse attention (sparse_topk)", mc.sparse_topk > 0),
+        ) if on)
+        if self._stateful:
+            self._refuse_beside_state(cfg)
         self.state = model.init_serving_state(
             cfg.slots, cfg.npages, cfg.page, chunk=cfg.chunk
         )
@@ -526,6 +584,13 @@ class ServingEngine:
                 len(st.window_layers), cfg.slots, st.ring,
                 cfg.slots * st.ring, self._window, cfg.chunk, cfg.page)
         self._jnp = jnp
+        if cfg.greedy_on_device and cfg.temperature > 0.0:
+            raise ValueError(
+                "greedy_on_device with temperature > 0: sampling needs "
+                "the logits on the host")
+        self._greedy = jax.jit(_greedy_tokens) \
+            if cfg.greedy_on_device else None
+        self._uploads: dict = {}        # name -> (host copy, device array)
         pps = self.state.pages_per_seq
         self.table = np.full((cfg.slots, pps), -1, np.int32)
         # context-parallel decode: a model whose mesh carries a cp axis
@@ -559,6 +624,8 @@ class ServingEngine:
         self.step_count = 0
         self._append_runs = 0           # of the batch last assembled
         self._pages_walked = [0, 0]     # likewise: [global, window]
+        # likewise: [selected pages, sparse rows, state rows]
+        self._state_work = [0, 0, 0]
         # seconds of the running step inside each phase (``_Phase``)
         self._phase_s = dict.fromkeys(PHASES, 0.0)
         # --- multi-tenancy (all defaults reproduce the single-tenant
@@ -676,6 +743,28 @@ class ServingEngine:
                 "sliding-window layers with prefill_only (the prefill "
                 "role of DisaggregatedEngine): kv_ship ships pages by "
                 "the global block table, which does not address a ring")
+
+    def _refuse_beside_state(self, cfg) -> None:
+        """A recurrent state is one matrix a slot, rebuilt only by a
+        recompute from position 0, and a block selection is made per
+        step from one slot's compressed keys: what would need a
+        snapshot, a rollback or a shipment of either is not built, and
+        raises here."""
+        what = ", ".join(self._stateful)
+        if cfg.prefix_cache or cfg.prefix_share:
+            raise ValueError(
+                f"{what} with prefix_cache / prefix_share: a request "
+                "that reattaches cached pages skips the positions that "
+                "built the recurrent state (it needs state snapshots)")
+        if self._spec_key() != (0, 0):
+            raise ValueError(
+                f"{what} under SpeculativeEngine: a rejected draft "
+                "needs a rollback of the recurrent state")
+        if cfg.prefill_only:
+            raise ValueError(
+                f"{what} with prefill_only (the prefill role of "
+                "DisaggregatedEngine): kv_ship ships pages, not the "
+                "recurrent state or the compressed keys")
 
     def _rung(self, max_q_len: int) -> int:
         """The ``block_q`` a step whose longest row packs ``max_q_len``
@@ -965,6 +1054,8 @@ class ServingEngine:
         next_start = 0
         self._append_runs = 0
         self._pages_walked = [0, 0]     # [global, window], one layer each
+        self._state_work = [0, 0, 0]
+        mc = self.model.config
         batched: set = set()
         takes: dict = {}
         for s in range(R):
@@ -995,10 +1086,19 @@ class ServingEngine:
                 kv_dev[s] = req.cursor + take
                 # the span's pages: the cursor's own up to the last held
                 self._append_runs += need - req.cursor // cfg.page
-                self._pages_walked[0] += need
+                if not mc.sparse_topk:
+                    # (a sparse layer walks a selection, counted below)
+                    self._pages_walked[0] += need
                 if self._window:
                     self._pages_walked[1] += need - max(
                         req.cursor - self._window + 1, 0) // cfg.page
+                if mc.sparse_topk:
+                    self._state_work[0] += (
+                        min(need, mc.sparse_topk) if take == 1 else need)
+                    self._state_work[1] += (
+                        req.cursor + take > mc.sparse_dense_len)
+                if mc.lightning_layers:
+                    self._state_work[2] += 1
                 next_start += _ceil8(take)
                 batched.add(s)
                 takes[s] = take
@@ -1045,14 +1145,29 @@ class ServingEngine:
         )
         return (
             self.params, state, jnp.asarray(tokens),
-            jnp.asarray(token_rows), jnp.asarray(token_pos),
-            jnp.asarray(q_starts), jnp.asarray(q_lens),
-            jnp.asarray(topo),
+            self._uploaded("token_rows", token_rows),
+            jnp.asarray(token_pos),
+            self._uploaded("q_starts", q_starts),
+            self._uploaded("q_lens", q_lens),
+            self._uploaded("topo", topo),
             # the workspaces of THIS step's width
             None if self.moe_state is None
             else self.moe_state[len(tokens)],
             block_q, self.use_pallas, self._n_bufs,
         )
+
+    def _uploaded(self, name: str, host: np.ndarray):
+        """The device copy of one of a step's host arrays — last step's
+        while the content is last step's: consecutive decode-only steps
+        over the same rows repeat their layout (rows, starts, lengths,
+        topology), and an upload costs the host ~0.3 ms whatever its
+        size. Only for arguments the step does not donate."""
+        last = self._uploads.get(name)
+        if last is not None and last[0].shape == host.shape \
+                and np.array_equal(last[0], host):
+            return last[1]
+        self._uploads[name] = (host.copy(), self._jnp.asarray(host))
+        return self._uploads[name][1]
 
     def _phase(self, phase: str) -> _Phase:
         return _Phase(self._phase_s, phase, self.step_count)
@@ -1078,6 +1193,9 @@ class ServingEngine:
                 self.stats.append_scatter_steps += 1
             self.stats.global_pages_walked += self._pages_walked[0]
             self.stats.window_pages_walked += self._pages_walked[1]
+            self.stats.selected_pages_walked += self._state_work[0]
+            self.stats.sparse_rows += self._state_work[1]
+            self.stats.state_rows += self._state_work[2]
             if self.moe_state is None:
                 logits, self.state = out
             else:
@@ -1098,7 +1216,8 @@ class ServingEngine:
             # the copy down and the delinearize, deliberately one span (a
             # block_until_ready before it would put a host wake-up on
             # the critical path untraced)
-            host_logits = np.asarray(logits)
+            host_logits = np.asarray(
+                logits if self._greedy is None else self._greedy(logits))
             # the uploads and the device logits are freed here, inside
             # the span, not on return (0.1-0.2 ms of a step's idle gap)
             del args, out, logits
@@ -1253,12 +1372,18 @@ class ServingEngine:
         request's token stream."""
         t = self.cfg.temperature
         if t <= 0.0:
-            tok = int(np.argmax(row_logits))
-            # argmax lands ON a NaN (or +inf) whenever the row holds
-            # one, so this O(1) look catches a poisoned distribution
-            # that would otherwise decode as a valid-looking token id
-            # (the sampling branch below raises on NaN by itself)
-            if not np.isfinite(row_logits[tok]):
+            if self._greedy is not None:
+                # the row's arg-max, taken and checked on the device
+                tok = int(row_logits)
+                finite = tok >= 0
+            else:
+                tok = int(np.argmax(row_logits))
+                # argmax lands ON a NaN (or +inf) whenever the row holds
+                # one, so this O(1) look catches a poisoned distribution
+                # that would otherwise decode as a valid-looking token
+                # id (the sampling branch below raises on NaN by itself)
+                finite = np.isfinite(row_logits[tok])
+            if not finite:
                 raise FloatingPointError(
                     f"non-finite logits for request {req.rid} at step "
                     f"{self.step_count}"
@@ -1349,6 +1474,11 @@ class ServingEngine:
                 "kv_ship / page migration with sliding-window layers: "
                 "pages ship by the global block table, which does not "
                 "address a ring pool")
+        if self._stateful:
+            raise ValueError(
+                f"kv_ship / page migration with {', '.join(self._stateful)}"
+                ": pages ship, the recurrent state and the compressed "
+                "keys do not")
         gather, _ = self._kv_wire_jits()
         return gather(self.state.layers,
                       jnp.asarray(list(pids), jnp.int32))
@@ -1505,6 +1635,12 @@ class DisaggregatedEngine:
                     "DisaggregatedEngine with sliding-window layers: "
                     "kv_ship ships pages by the global block table, "
                     "which does not address a ring pool")
+            if m.config.lightning_layers or m.config.sparse_topk:
+                raise ValueError(
+                    "DisaggregatedEngine with lightning layers "
+                    "(layer_mixer) or block-sparse attention "
+                    "(sparse_topk): kv_ship ships pages, not the "
+                    "recurrent state or the compressed keys")
         if transport == "auto":
             transport = "dcn" if hybrid_mesh is not None else "xla"
         if transport == "dcn" and hybrid_mesh is None:

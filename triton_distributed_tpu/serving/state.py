@@ -33,6 +33,21 @@ owns:
   a recompute does not rewrite before it reads. ``ring_pages`` is the
   least ``ring`` whose live positions never alias.
 
+* **recurrent states and compressed keys** (PR 33) — a LIGHTNING
+  (linear-attention) layer keeps no K/V pages: its entry of ``layers``
+  is ``None`` and ``recurrent[i]`` is its state, ``(slots, heads,
+  head_dim, head_dim)`` float32, one ``head_dim x head_dim`` matrix a
+  slot and head. The step reads and writes the matrices of the slots it
+  batches and starts a slot whose span begins at position 0 from zero,
+  so admission, eviction and slot reuse owe it nothing: a recompute
+  rebuilds it as it rebuilds pages. A BLOCK-SPARSE attention layer
+  keeps, beside its K/V pools, ``ckeys[i]``: ``(npages, Hkv, page //
+  stride, D)``, compressed key ``j`` (the mean of keys ``[j·stride,
+  j·stride + kernel)``) in the page and row of its first token, so the
+  block table addresses it and the allocator need not know of it. A
+  compressed key is written in the step that appends its last token
+  and never read before that.
+
 The object is a pytree (``jax.tree_util``): the serving-step jit
 donates it whole, and with the pool placements pinned the per-step
 append aliases in place — no pool-sized copy per step.
@@ -70,6 +85,11 @@ class ServingState:
     ring_table: object = None
     window_layers: tuple = ()
     ring: int = 0
+    # per layer (or ``()``): a lightning layer's recurrent state, a
+    # block-sparse layer's compressed-key pool, None elsewhere; a
+    # lightning layer's entry of ``layers`` is None
+    recurrent: tuple = ()
+    ckeys: tuple = ()
 
     def replace(self, **kw) -> "ServingState":
         return _dc_replace(self, **kw)
@@ -93,7 +113,8 @@ class ServingState:
         host allocator address); a window layer's holds
         ``slots · ring``."""
         i = next((i for i in range(len(self.layers))
-                  if i not in self.window_layers), 0)
+                  if i not in self.window_layers
+                  and self.layers[i] is not None), 0)
         return self.layer_pages(i)
 
     def layer_pages(self, i: int) -> int:
@@ -109,17 +130,19 @@ class ServingState:
 
 def _flatten(s: ServingState):
     return (
-        (s.layers, s.block_table, s.kv_lens, s.cursors, s.ring_table),
+        (s.layers, s.block_table, s.kv_lens, s.cursors, s.ring_table,
+         s.recurrent, s.ckeys),
         (s.page, s.cp, s.window_layers, s.ring),
     )
 
 
 def _unflatten(aux, children):
-    layers, table, lens, cursors, ring_table = children
+    layers, table, lens, cursors, ring_table, recurrent, ckeys = children
     return ServingState(
         layers=layers, block_table=table, kv_lens=lens, cursors=cursors,
         page=aux[0], cp=aux[1], ring_table=ring_table,
-        window_layers=aux[2], ring=aux[3],
+        window_layers=aux[2], ring=aux[3], recurrent=recurrent,
+        ckeys=ckeys,
     )
 
 
