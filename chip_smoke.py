@@ -30,7 +30,10 @@ custom call (and, on one chip, the pool append kernel's: no hidden
 scatter), and one mixed prefill+decode batch agrees between the
 kernel and its XLA twin on the same ServingState (logits, not tokens;
 once as served, once with every activation-side quantization off —
-the sharper instrument, see ``PARITY_VIEWS``).
+the sharper instrument, see ``PARITY_VIEWS``), and the engine launched
+steps ahead of the one before (``EngineStats.lookahead_steps`` > 0; PR
+34) with the streams of the same trace served once more in the drained
+order (every step retired in the call that launched it).
 
 Set-up/compile seconds are printed apart from run seconds; no speed is
 claimed. The last stdout line is
@@ -182,6 +185,7 @@ def parity(model, params, on_chip: bool, tol: float) -> dict:
     )
 
     class Probe(ServingEngine):
+        host_logits = True      # the logits are what is compared
         pair = None
         lowered = None
 
@@ -196,12 +200,12 @@ def parity(model, params, on_chip: bool, tol: float) -> dict:
                 *self._step_args(arrays, block_q)
             ).as_text()
             before = jax.tree.map(jnp.copy, (self.state, self.moe_state))
-            got = super()._run_device(arrays, block_q)
+            got = np.asarray(super()._run_device(arrays, block_q))
             after = (self.state, self.moe_state)
             self.state, self.moe_state = before
             self.use_pallas = False
             try:
-                want = super()._run_device(arrays, block_q)
+                want = np.asarray(super()._run_device(arrays, block_q))
             finally:
                 self.use_pallas = True
             self.state, self.moe_state = after
@@ -440,10 +444,13 @@ def window_leg(devices, on_chip: bool = True) -> dict:
     rng = np.random.default_rng(SEED)
     prompts = [rng.integers(0, model.config.vocab, (n,)).astype(np.int32)
                for n in WINDOW_PROMPTS]
+    class HostLogits(ServingEngine):
+        host_logits = True      # ``keep`` below records each row's
+
     served = {}
     for use_pallas in (True, False):
-        eng = ServingEngine(model, params, EngineConfig(**WINDOW_ENGINE),
-                            use_pallas=use_pallas, propagate_failures=True)
+        eng = HostLogits(model, params, EngineConfig(**WINDOW_ENGINE),
+                         use_pallas=use_pallas, propagate_failures=True)
         rows, sample = [], eng._sample
 
         def keep(row_logits, req, rows=rows, sample=sample):
@@ -662,6 +669,22 @@ def leg(devices, on_chip: bool = True) -> dict:
          f"the warm pass lowered {built[3] - built[2]} new program(s)")
     need([r.generated for r in cold] == [r.generated for r in warm],
          "the same seeded trace produced different token streams twice")
+    # the passes above launched step k + 1 before step k's tokens came
+    # down; the same trace once more in the DRAINED order (every step
+    # retired in the call that launched it) serves the same streams
+    ahead = eng.stats.lookahead_steps
+    need(ahead > 0, "no step was launched ahead of the one before")
+    eng._launch_ahead = lambda: False
+    drained, _, drained_steps = serve(eng)
+    del eng._launch_ahead
+    need(eng.stats.lookahead_steps == ahead,
+         "a step was launched ahead in the drained order")
+    need([r.generated for r in drained] == [r.generated for r in warm],
+         "launched ahead, the trace's token streams are not the drained "
+         "order's")
+    rec["lookahead"] = {
+        "steps_ahead": ahead, "steps": len(eng.stats.step_times),
+        "drained_pass_steps": drained_steps}
     if n > 1:
         rec["spread"] = spread(eng, params, n)
         rec["overlap_ops"] = overlap_ops(model.mesh)

@@ -141,6 +141,23 @@ def force_fused_ctx(use_pallas_gemm=False):
     return fused_ctx
 
 
+def drained(engine_cls):
+    """``engine_cls`` with its launch-ahead question forced to "no":
+    every step is retired in the ``step()`` call that launched it (the
+    synchronous order) — what the order that keeps one step in flight
+    is compared with."""
+    return type("Drained" + engine_cls.__name__, (engine_cls,),
+                {"_launch_ahead": lambda self: False})
+
+
+def on_host(engine_cls):
+    """``engine_cls`` with ``host_logits``: every step's logits come
+    down (a tap that reads or compares them needs that), so no step is
+    launched ahead."""
+    return type("Host" + engine_cls.__name__, (engine_cls,),
+                {"host_logits": True})
+
+
 def serve_all_logits(model, params, ecfg, prompts, *, max_new=1,
                      use_pallas=False, **engine_kw):
     """Serve ``prompts`` (arriving together) through a ``ServingEngine``
@@ -151,12 +168,13 @@ def serve_all_logits(model, params, ecfg, prompts, *, max_new=1,
     of request i computed there."""
     from triton_distributed_tpu.serving import Request, ServingEngine
 
-    class AllLogitsEngine(ServingEngine):
+    class AllLogitsEngine(on_host(ServingEngine)):
         def _step_jit(self):
             return self.model._serving_all_logits_jit
 
         def _run_device(self, arrays, block_q):
-            full = super()._run_device(arrays, block_q)     # (T, vocab)
+            full = np.asarray(
+                super()._run_device(arrays, block_q))       # (T, vocab)
             _, _, token_pos, q_starts, q_lens = arrays[:5]
             for s in np.nonzero(q_lens)[0]:
                 span = slice(q_starts[s], q_starts[s] + q_lens[s])

@@ -61,6 +61,9 @@ def test_leg_passes_its_own_checks_at_tiny_size(tiny, tmp_path,
         assert got["rms_rel_err"] <= tol
     assert not rec["degraded"] and not rec["failures"]
     assert rec["programs_lowered"]["warm_pass"] == 0
+    # the two passes launched ahead; the third ran in the drained order
+    assert 0 < rec["lookahead"]["steps_ahead"] < rec["lookahead"]["steps"]
+    assert rec["lookahead"]["drained_pass_steps"] > 0
     assert set(rec["overlap_ops"]) == {"ag_gemm", "gemm_rs"}
 
 

@@ -290,7 +290,8 @@ def test_assemble_packs_one_aligned_contiguous_span_a_slot(
             assert not covered[a:a + ln].any()
             covered[a:a + ln] = True
             assert (token_rows[a:a + ln] == s).all()
-            first = eng.slot_req[s].cursor
+            # (the launch-side cursor: the step in flight applied)
+            first = eng._view(eng.slot_req[s])[0]
             np.testing.assert_array_equal(
                 token_pos[a:a + ln], first + np.arange(ln))
         assert (token_pos[~covered] == -1).all()

@@ -13,6 +13,7 @@ keys of kernel 4 / stride 2.
 
 import hashlib
 import pathlib
+import re
 import sys
 
 import jax
@@ -27,7 +28,7 @@ if str(ROOT) not in sys.path:
 
 from benchmark.harness import weights  # noqa: E402
 from benchmark.models import minicpm_sala as ref  # noqa: E402
-from conftest import serve_all_logits  # noqa: E402
+from conftest import on_host, serve_all_logits  # noqa: E402
 from triton_distributed_tpu.kernels import sparse_select as sel  # noqa: E402
 from triton_distributed_tpu.kernels.lightning_attention import (  # noqa: E402
     decay_slopes,
@@ -250,17 +251,21 @@ def test_a_reused_slot_starts_from_zero_state_and_fresh_compressed_keys():
             got, reference_rows(params, sizes, req, 4), atol=1e-4, rtol=1e-4)
 
 
-@pytest.mark.parametrize("engine", [ServingEngine, "all_positions"])
-def test_greedy_on_device_serves_the_tokens_the_host_argmax_serves(engine):
-    """``EngineConfig.greedy_on_device``: the arg-max of each logits row
-    is taken on the device and one token id a row comes down, not the
-    logits; the served streams are the host arg-max's, also where the
-    step hands out logits at every packed position."""
+@pytest.mark.parametrize("engine", ["ids", "all_positions"])
+def test_the_device_arg_max_serves_the_tokens_the_host_argmax_serves(engine):
+    """A greedy engine takes the arg-max of each logits row on the
+    device and one token id a row comes down, not the logits
+    (``EngineConfig.greedy_on_device`` decides nothing any more); an
+    engine with ``host_logits`` fetches the logits and takes it on the
+    host. The served streams are the same, also where the host-side
+    engine's step hands out logits at every packed position."""
     import dataclasses
 
     model, _, params = seeded(tiny_config())
+
+    Host = on_host(ServingEngine)
     if engine == "all_positions":
-        class engine(ServingEngine):
+        class Host(Host):
             def _step_jit(self):
                 return self.model._serving_all_logits_jit
 
@@ -270,15 +275,18 @@ def test_greedy_on_device_serves_the_tokens_the_host_argmax_serves(engine):
                     s, req, take, logits[at], q_starts, q_lens)
 
     served = []
-    for on in (False, True):
-        eng = engine(
-            model, params, dataclasses.replace(ENGINE, greedy_on_device=on),
+    for cls, flag in ((Host, False), (ServingEngine, False),
+                      (ServingEngine, True)):
+        eng = cls(
+            model, params, dataclasses.replace(ENGINE, greedy_on_device=flag),
             use_pallas=False, propagate_failures=True)
+        assert (eng._greedy is None) == (cls is Host)
         reqs = [Request(rid=i, prompt=p, max_new=5)
                 for i, p in enumerate(prompts_of(PROMPTS))]
         assert eng.run(reqs).completed == len(reqs)
+        assert (eng.stats.lookahead_steps > 0) == (cls is not Host)
         served.append([r.generated for r in reqs])
-    assert served[0] == served[1]
+    assert served[0] == served[1] == served[2]
     # the global layers' walk is not counted where every attention
     # layer walks a selection
     assert eng.stats.global_pages_walked == 0
@@ -301,9 +309,14 @@ def test_a_layout_that_repeats_is_uploaded_once():
     while not eng.idle:
         eng.step()
         seen.append({k: id(v[1]) for k, v in eng._uploads.items()})
-    assert set(seen[-1]) == {"token_rows", "q_starts", "q_lens", "topo"}
-    # the last steps are decode-only over both rows, then over one
-    assert seen[-2] == seen[-3] == seen[-4]
+    # (a decode-only step's tokens all come from the device: the host's
+    # upload is zeros and the slots they come from, which repeat too)
+    assert set(seen[-1]) == {"tokens", "token_src", "token_rows",
+                             "q_starts", "q_lens", "topo"}
+    # the last steps are decode-only over both rows, then over one;
+    # the last call of all launches nothing (it retires the step in
+    # flight) and uploads nothing
+    assert seen[-3] == seen[-4] == seen[-5] != seen[-2] == seen[-1]
     assert seen[0]["q_lens"] != seen[-1]["q_lens"]
     assert len({s["topo"] for s in seen}) <= 2       # one a packed width
     again = ServingEngine(model, params, ENGINE, use_pallas=False)
@@ -325,13 +338,13 @@ def test_greedy_on_device_keeps_the_non_finite_check():
     assert np.asarray(_greedy_tokens(jnp.asarray(rows))).tolist() == [
         3, -1, -1, 0]
     model, _, params = seeded(tiny_config())
-    with pytest.raises(ValueError, match="greedy_on_device"):
-        ServingEngine(model, params, EngineConfig(
-            slots=2, token_budget=32, chunk=16, page=16, npages=8,
-            greedy_on_device=True, temperature=0.7))
-    eng = ServingEngine(model, params, EngineConfig(
+    # the flag decides nothing: sampling keeps the logits on the host
+    assert ServingEngine(model, params, EngineConfig(
         slots=2, token_budget=32, chunk=16, page=16, npages=8,
-        greedy_on_device=True), use_pallas=False)
+        greedy_on_device=True, temperature=0.7))._greedy is None
+    eng = ServingEngine(model, params, EngineConfig(
+        slots=2, token_budget=32, chunk=16, page=16, npages=8),
+        use_pallas=False)
     with pytest.raises(FloatingPointError, match="non-finite"):
         eng._sample(np.int32(-1), Request(rid=0, prompt=np.zeros(3, np.int32)))
 
@@ -687,9 +700,12 @@ def test_what_the_new_state_cannot_serve_is_refused_by_name(what):
 # ---------------------- (h) the accepted configurations' programs
 
 
-def _step_text(cfg, ecfg) -> str:
+def _step_texts(cfg, ecfg) -> tuple:
     """StableHLO of the rung-8 step of ``cfg`` by its XLA twins (no
-    kernel body, so no source line is in it), debug locations off."""
+    kernel body, so no source line is in it), debug locations off:
+    lowered from ``_step_args`` as the engine calls it, and with the
+    host's upload of the tokens in the place of the merged ``tokens``
+    (what a step was handed before PR 34)."""
     model = one_chip_model(cfg)
     params = jax.eval_shape(model.init, jax.random.PRNGKey(0))
     params = jax.tree.map(lambda a: jnp.zeros(a.shape, a.dtype), params)
@@ -701,20 +717,25 @@ def _step_text(cfg, ecfg) -> str:
                        max_new=2, arrival=0))
     eng._admit()
     *arrays, _, _ = eng._assemble()
-    return eng._step_jit().lower(
-        *eng._step_args(tuple(arrays), 8)).as_text()
+    args = list(eng._step_args(tuple(arrays), 8))
+    as_engine = eng._step_jit().lower(*args).as_text()
+    args[2] = jnp.asarray(arrays[0])
+    return as_engine, eng._step_jit().lower(*args).as_text()
 
 
+# per configuration: the digest through the engine's path (PR 34), and
+# with the uploaded tokens (taken on the tree before PR 33)
 ACCEPTED = {
     "dsmoe16b": (lambda: presets.tiny(presets.deepseek_moe_16b()),
-                 "57ca03e7507824f3"),
+                 "82dab0446a04e3f4", "57ca03e7507824f3"),
     "mixtral8x7b": (lambda: presets.tiny(presets.mixtral_8x7b()),
-                    "ee0bb4b9c115ff09"),
+                    "8c8a6a0b5e338ae8", "ee0bb4b9c115ff09"),
     "kexaone236b": (lambda: presets.tiny(
         presets.k_exaone_236b(), n_layers=5,
         layer_attn=("sliding", "sliding", "sliding", "full", "sliding"),
         rope_layers=(0, 1, 2, 4), moe_layers=(1, 2, 3, 4), window=16,
-        num_experts=8, experts_held=4, first_expert_held=2), "b82a79e936396471"),
+        num_experts=8, experts_held=4, first_expert_held=2),
+        "7ae6d2b45eac2673", "b82a79e936396471"),
 }
 
 
@@ -722,13 +743,28 @@ ACCEPTED = {
 def test_the_accepted_configurations_lower_the_programs_they_lowered(name):
     """The new fields default to the model that was there: the step
     program of each accepted configuration's twin is, instruction for
-    instruction, the one the tree before PR 33 lowered (the digests
-    were taken there, by this function), and holds none of the new
-    scopes or launches."""
-    build, digest = ACCEPTED[name]
-    text = _step_text(build(), EngineConfig(
+    instruction, the one the tree before PR 33 lowered (the second
+    digest was taken there, with the host's upload as ``tokens``), and
+    holds none of the new scopes or launches. As the ENGINE calls it
+    (the first digest, taken under PR 34) ``tokens`` is the array merged
+    on the device, a committed one: that argument of ``@main``, alone,
+    carries a replicated-sharding attribute, the private functions are
+    numbered from another start, and dsmoe's lowering shares three
+    helper bodies (``_where``, ``round``, ``clip``) it printed twice."""
+    build, engine_digest, upload_digest = ACCEPTED[name]
+    as_engine, with_upload = _step_texts(build(), EngineConfig(
         slots=4, token_budget=64, chunk=16, page=16, npages=32))
-    for new in ("linear_attn", "sparse_select", "out_gate",
-                "lightning_attention", "ragged_paged_attention_selected"):
-        assert new not in text
-    assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest
+    committed = " {sdy.sharding = #sdy.sharding<@mesh, [{}]>}"
+    main = {t: next(ln for ln in t.splitlines() if "@main(" in ln)
+            for t in (as_engine, with_upload)}
+    changed = [(a, b) for a, b in zip(*(
+        re.split(r", (?=%arg\d+:)", main[t])
+        for t in (with_upload, as_engine))) if a != b]
+    assert len(changed) == 1 and changed[0][1] == changed[0][0] + committed
+    for text, digest in ((as_engine, engine_digest),
+                         (with_upload, upload_digest)):
+        for new in ("linear_attn", "sparse_select", "out_gate",
+                    "lightning_attention",
+                    "ragged_paged_attention_selected"):
+            assert new not in text
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] == digest

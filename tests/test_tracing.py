@@ -25,6 +25,7 @@ from triton_distributed_tpu.serving import (
     make_drafter,
     poisson_trace,
 )
+from conftest import drained
 from triton_distributed_tpu.serving.engine import PHASES
 
 pytestmark = pytest.mark.fast
@@ -111,23 +112,31 @@ def _lists(stats):
 
 # ------------------------------------------------- the six per-step lists
 
+@pytest.mark.parametrize("order", ["ahead", "drained"])
 @pytest.mark.parametrize("kind", ["dense", "ep"])
 def test_each_list_grows_by_one_per_device_step_and_sums_to_the_wall(
-        models, kind):
+        models, kind, order):
+    """One entry a device step in every list, appended in the call that
+    RETIRES the step. A step's six entries are its own phases: retired
+    in the call that launched it (``drained``) they lie inside that
+    call; launched ahead, its admit .. dispatch ran in one call and its
+    fetch and advance in the next, and only the totals meet."""
     model, params = models[kind]
-    eng = ServingEngine(model, params, ECFG)
+    eng = (ServingEngine if order == "ahead"
+           else drained(ServingEngine))(model, params, ECFG)
     # nothing submitted: admit and assemble run, the device does not
     eng.step()
     assert all(v == [] for v in _lists(eng.stats).values())
     assert eng.stats.step_times == []
     trace = poisson_trace(7, 6, 1.0, 5, 30, 3, 6, 128)
     eng.submit_trace(trace)
-    walls, device_steps = [], 0
+    walls, all_walls, device_steps = [], [], 0
     while not eng.idle:
         before = len(eng.stats.step_times)
         t0 = time.perf_counter()
         eng.step()
         wall = time.perf_counter() - t0
+        all_walls.append(wall)
         ran = len(eng.stats.step_times) - before
         assert ran in (0, 1)
         device_steps += ran
@@ -136,13 +145,21 @@ def test_each_list_grows_by_one_per_device_step_and_sums_to_the_wall(
         if ran:
             walls.append(wall)
     assert device_steps == len(walls) > 5
+    assert eng.stats.lookahead_steps == (
+        device_steps - 1 if order == "ahead" else 0)
     lists = _lists(eng.stats)
     sums = [sum(lists[p][i] for p in PHASES) for i in range(device_steps)]
     assert all(v >= 0.0 for vs in lists.values() for v in vs)
-    # the spans lie inside step(): never more than its wall time, and
-    # (the typical step) within a few % of it
-    assert all(s <= w for s, w in zip(sums, walls))
-    assert np.median(np.asarray(sums) / np.asarray(walls)) > 0.9
+    if order == "drained":
+        # the spans lie inside step(): never more than its wall time,
+        # and (the typical step) within a few % of it
+        assert all(s <= w for s, w in zip(sums, walls))
+        assert np.median(np.asarray(sums) / np.asarray(walls)) > 0.9
+    else:
+        # launched ahead: the first call retires nothing and the last
+        # launches nothing, so the calls' walls hold every span once
+        assert len(all_walls) == device_steps + 1
+        assert 0.9 * sum(all_walls) < sum(sums) <= sum(all_walls)
     # step_times keeps its meaning: uploads + dispatch + fetch
     for i, dt in enumerate(eng.stats.step_times):
         assert dt == pytest.approx(
@@ -152,10 +169,12 @@ def test_each_list_grows_by_one_per_device_step_and_sums_to_the_wall(
 
 # ------------------------------------------------------ the six host spans
 
+@pytest.mark.parametrize("order", ["ahead", "drained"])
 def test_six_annotations_open_once_per_device_step_in_order(
-        models, annotations):
+        models, annotations, order):
     model, params = models["dense"]
-    eng = ServingEngine(model, params, ECFG)
+    eng = (ServingEngine if order == "ahead"
+           else drained(ServingEngine))(model, params, ECFG)
     eng.step()                                  # an empty step
     eng.submit_trace(poisson_trace(3, 4, 1.0, 5, 20, 2, 4, 128))
     while not eng.idle:
@@ -163,13 +182,38 @@ def test_six_annotations_open_once_per_device_step_in_order(
     assert not annotations.open_now
     calls = _per_step(annotations.log)
     assert calls[0] == [("engine.admit", 0), ("engine.assemble", 0)]
-    full = [c for c in calls if len(c) > 2]
-    assert len(full) == len(eng.stats.step_times) > 3
-    for call in calls:
-        # one step number on every span of a call, counting up
-        assert len({step for _, step in call}) == 1
-        assert [n for n, _ in call] in (SPANS[:2], SPANS)
     assert [c[0][1] for c in calls] == list(range(len(calls)))
+    full = [c for c in calls if len(c) > 2]
+    n = len(eng.stats.step_times)
+    assert n > 3
+    # the six spans of a DEVICE STEP share its number, each opened once
+    by_step = {}
+    for call in calls:
+        for name, step in call:
+            by_step.setdefault(step, []).append(name)
+    stepped = [k for k, names in by_step.items() if len(names) > 2]
+    assert len(stepped) == n
+    if order == "drained":
+        assert len(full) == n
+        for call in calls:
+            # one step number on every span of a call, counting up
+            assert len({step for _, step in call}) == 1
+            assert [n for n, _ in call] in (SPANS[:2], SPANS)
+        return
+    # launched ahead: a call opens admit .. dispatch of ITS step, then
+    # fetch and advance of the step BEFORE; the first call of a busy
+    # stretch retires nothing, the last launches nothing
+    assert len(full) == n + 1
+    assert [name for name, _ in full[0]] == SPANS[:4]
+    assert [name for name, _ in full[-1]] == SPANS[:2] + SPANS[4:]
+    for call in full[1:-1]:
+        assert [name for name, _ in call] == SPANS
+    for call in full[1:]:
+        k = call[0][1]
+        assert [step for _, step in call[-2:]] == [k - 1, k - 1]
+        assert {step for _, step in call[:-2]} == {k}
+    launched = set(stepped)
+    assert all(sorted(by_step[k]) == sorted(SPANS) for k in launched)
 
 
 def test_a_degraded_step_re_runs_upload_and_dispatch_into_one_entry(

@@ -24,6 +24,7 @@ if str(ROOT) not in sys.path:
 
 from benchmark.harness import weights  # noqa: E402
 from benchmark.models import exaone_moe as ref  # noqa: E402
+from conftest import on_host  # noqa: E402
 from triton_distributed_tpu.kernels import moe_utils as mu  # noqa: E402
 from triton_distributed_tpu.kernels.group_gemm import (  # noqa: E402
     grouped_matmul,
@@ -95,7 +96,8 @@ def one_chip_model(cfg):
 def serve(model, params, use_pallas, ecfg=ENGINE, prompts=PROMPTS,
           max_new=6, seed=0):
     """``(requests, {rid: (max_new, vocab) logits})`` of one short run."""
-    eng = ServingEngine(model, params, ecfg, use_pallas=use_pallas)
+    # ``keep`` below reads each row's logits
+    eng = on_host(ServingEngine)(model, params, ecfg, use_pallas=use_pallas)
     seen, sample = {}, eng._sample
 
     def keep(row_logits, req):

@@ -49,6 +49,7 @@ over onto the survivor).
 
 from __future__ import annotations
 
+import itertools
 import logging
 import time
 from collections import deque
@@ -56,6 +57,7 @@ from dataclasses import dataclass, field
 
 import jax
 import numpy as np
+from jax.sharding import NamedSharding, PartitionSpec
 
 
 #: Priority classes, best-first: admission, eviction-victim selection
@@ -235,13 +237,13 @@ class EngineConfig:
     # motif traffic; token streams are unchanged (frozen pages with
     # equal chain hashes hold byte-identical KV by construction).
     prefix_share: bool = False
-    # greedy_on_device: a greedy engine takes each logits row's arg-max
-    # on the device (one tiny program behind the step's) and fetches a
-    # token id a row, not the (rows, vocab) float32 logits: 9.4 MB a
-    # step at 32 slots x 73448, 1.9 of the 4.2 ms the device idled in
-    # the fetch (the rest is the runtime's notice that the step is
-    # done). The host's non-finite check moves with it
-    # (``_greedy_tokens``).
+    # greedy_on_device: decides nothing since PR 34. Every greedy engine
+    # (``temperature <= 0``) whose advance needs no logits on the host
+    # takes each row's arg-max on the device and fetches a token id a
+    # row (``ServingEngine._greedy``), flag or no flag. The field stays
+    # accepted because ``benchmark/configs/minicpmsala9b-d8.json`` names
+    # it and no benchmark file may be edited; its removal is a line for
+    # the next ``benchmark`` issue.
     greedy_on_device: bool = False
 
 
@@ -274,13 +276,21 @@ class _Phase:
 
 @dataclass
 class EngineStats:
+    # Everything here describes RETIRED device steps (fetched and
+    # advanced) and nothing of the step in flight: a per-step list gets
+    # a step's entry, and a counter its share, in the ``step()`` call
+    # that retires it.
     # seconds of host clock per device step: uploads + dispatch + fetch
     # (``upload_times + dispatch_times + fetch_times`` of that step)
     step_times: list = field(default_factory=list)
     # the six phases of ``ServingEngine.step`` (``PHASES``), seconds of
     # host clock, one entry per step that ran the device (an empty step
     # appends to none); a degraded step's re-run is summed into its
-    # entry. The six entries of a step sum to its wall time in step().
+    # entry. A step's six entries are ITS OWN phases: launched ahead,
+    # its admit, assemble, upload and dispatch ran in one call of
+    # ``step()`` and its fetch and advance in the next, so they sum to
+    # a call's wall time only for a step retired in the call that
+    # launched it.
     admit_times: list = field(default_factory=list)
     assemble_times: list = field(default_factory=list)
     upload_times: list = field(default_factory=list)
@@ -326,6 +336,11 @@ class EngineStats:
     # packed rows of the device steps: the sum of their widths (each
     # step's follows its ``block_q`` rung, ``ServingEngine._width``)
     packed_rows: int = 0
+    # device steps dispatched while the step before was not yet retired
+    # (its token ids still on the device): ``ServingEngine.step``
+    # launches ahead whenever ``_launch_ahead`` finds nothing that
+    # needs them on the host first. Decided at dispatch.
+    lookahead_steps: int = 0
     # packed rows of the device steps that were no token's (the step's
     # own width less its tokens): ``serving_step`` hands their expert
     # assignments to ``ops.ep_moe`` masked, so at least
@@ -494,6 +509,47 @@ def _greedy_tokens(logits):
                      -1).astype(jnp.int32)
 
 
+def _merge_tokens(tokens, src, ids):
+    """A step's packed tokens: the host's ``tokens``, but where ``src``
+    names a slot (``>= 0``) that slot's token of the step in flight,
+    out of ``ids`` (``_greedy_tokens`` of its logits) — the token the
+    host has not seen yet. A ``-1`` there (a non-finite row: the fetch
+    of that step raises) is clamped to 0 before it indexes the
+    embedding."""
+    import jax.numpy as jnp
+
+    return jnp.where(src >= 0, jnp.maximum(ids[jnp.maximum(src, 0)], 0),
+                     tokens)
+
+
+# module-level, so every engine of a process shares one compiled
+# program a shape
+_greedy_jit = jax.jit(_greedy_tokens)
+_merge_jit = jax.jit(_merge_tokens)
+
+
+@dataclass
+class _Flight:
+    """One device step from its dispatch to its retirement: what
+    ``ServingEngine._retire`` needs to fetch its result and advance its
+    rows, and what it adds to ``EngineStats`` then. While it is
+    ``ServingEngine._flight`` it is also the launch-side view of its
+    rows (``ServingEngine._view``)."""
+
+    step: int                     # ``step_count`` at its launch
+    phase_s: dict                 # its own seconds in each of ``PHASES``
+    report: dict                  # what ``step()`` returns of it
+    takes: dict                   # slot -> packed tokens of its row,
+                                  # its batched slots ascending
+    q_starts: np.ndarray
+    q_lens: np.ndarray
+    counts: dict                  # EngineStats counter -> its share
+    ahead: bool                   # dispatched before the last was retired
+    freezes: bool                 # a row's cursor crosses a page's end
+    out: object = None            # device: (slots,) ids or the logits
+    host: np.ndarray | None = None  # ``out`` fetched
+
+
 def _ceil8(x: int) -> int:
     return -(-x // 8) * 8
 
@@ -522,7 +578,28 @@ class ServingEngine:
     """The scheduler. Owns the host mirrors (free list, block table,
     lengths, cursors) and the device :class:`ServingState`; every
     :meth:`step` assembles one ragged batch and runs one jitted
-    ``model.serving_step``."""
+    ``model.serving_step``.
+
+    ONE DEVICE STEP IS KEPT IN FLIGHT where nothing needs its result on
+    the host first (:meth:`_launch_ahead`): a call of :meth:`step`
+    admits, assembles, uploads and dispatches step k, and only THEN
+    fetches step k - 1's token ids and advances its rows, so the device
+    finds step k queued when k - 1 ends. A decode row's token of the
+    step in flight stays on the device and is merged into step k's
+    packed tokens there (``_merge_tokens``). What is public after
+    ``step()`` returns (``Request.cursor`` / ``generated`` / ``done``,
+    ``EngineStats``) describes RETIRED steps only; the launch-side view
+    of a row (:meth:`_view`) is the engine's own."""
+
+    #: True on an engine whose advance reads each row's LOGITS on the
+    #: host (the speculative engine's verify loop, a probe that compares
+    #: them): they come down every step and no step is launched ahead.
+    #: False: a greedy engine (``temperature <= 0``) takes each row's
+    #: arg-max on the device and fetches token ids.
+    host_logits = False
+    # the device step dispatched and not yet retired (a class default:
+    # analysis/servlint.py builds its shell without ``__init__``)
+    _flight: _Flight | None = None
 
     def __init__(self, model, params, cfg: EngineConfig, *,
                  moe_state="auto", use_pallas: bool = True,
@@ -584,12 +661,20 @@ class ServingEngine:
                 len(st.window_layers), cfg.slots, st.ring,
                 cfg.slots * st.ring, self._window, cfg.chunk, cfg.page)
         self._jnp = jnp
-        if cfg.greedy_on_device and cfg.temperature > 0.0:
-            raise ValueError(
-                "greedy_on_device with temperature > 0: sampling needs "
-                "the logits on the host")
-        self._greedy = jax.jit(_greedy_tokens) \
-            if cfg.greedy_on_device else None
+        # a greedy engine's tokens: ``_greedy_tokens`` of each step's
+        # logits, taken behind the step program and kept on the device
+        # (sampling and ``host_logits`` need the logits on the host)
+        self._greedy = None if cfg.temperature > 0.0 or self.host_logits \
+            else _greedy_jit
+        # the newest step's ids on the device, in flight or retired.
+        # Zeros until the first step, placed as a step's are, so that
+        # the first step's tokens are merged like every other's: the
+        # step program sees ONE kind of ``tokens`` a width (a merged,
+        # committed array; the host's upload is uncommitted, and the
+        # pair would lower every width's first program twice)
+        self._ids = None if self._greedy is None else jax.device_put(
+            np.zeros((cfg.slots,), np.int32),
+            NamedSharding(model.mesh, PartitionSpec()))
         self._uploads: dict = {}        # name -> (host copy, device array)
         pps = self.state.pages_per_seq
         self.table = np.full((cfg.slots, pps), -1, np.int32)
@@ -623,6 +708,9 @@ class ServingEngine:
         self.stats = EngineStats()
         self.step_count = 0
         self._append_runs = 0           # of the batch last assembled
+        # likewise: per packed position the slot whose token of the
+        # step in flight belongs there, -1 where the host's own stands
+        self._token_src = None
         self._pages_walked = [0, 0]     # likewise: [global, window]
         # likewise: [selected pages, sparse rows, state rows]
         self._state_work = [0, 0, 0]
@@ -809,6 +897,7 @@ class ServingEngine:
     @property
     def idle(self) -> bool:
         return (not self.pending and not self.waiting
+                and self._flight is None
                 and all(r is None for r in self.slot_req))
 
     # ------------------------------------------------------------ tenancy
@@ -877,11 +966,35 @@ class ServingEngine:
 
     # ---------------------------------------------------------------- step
 
+    def _view(self, req) -> tuple:
+        """``(cursor, length, pending)`` of a resident request as the
+        step about to be assembled finds it — the LAUNCH-SIDE view,
+        private to the engine: the committed ``req.cursor`` and
+        ``len(req.seq)`` with the step in flight applied. ``pending``:
+        that step's row reaches the sequence's frontier, so the token at
+        ``length - 1`` exists only on the device yet (``_ids[slot]``).
+        A request that reaches ``max_new`` with the step in flight has
+        nothing left (``length == cursor``): it gets no further row.
+        With nothing in flight this is the committed state."""
+        cur, n = req.cursor, len(req.seq)
+        f = self._flight
+        take = f.takes.get(req.slot, 0) if f is not None else 0
+        if not take:
+            return cur, n, False
+        cur += take
+        if cur < n:
+            return cur, n, False            # a prefill chunk in flight
+        target = 1 if self.cfg.prefill_only else req.max_new
+        if len(req.generated) + 1 >= target:
+            return cur, cur, False          # completes with that step
+        return cur, n + 1, True
+
     def _row_take_bound(self, req) -> int:
         """Upper bound on the tokens this request's next row packs —
         the admission/reservation headroom term. The speculative engine
         widens it by its draft budget."""
-        return min(self._chunk_for(req), len(req.seq) - req.cursor)
+        cur, n, _ = self._view(req)
+        return min(self._chunk_for(req), n - cur)
 
     def _committed_pages(self) -> int:
         """Pages the already-admitted slots will claim for their NEXT
@@ -892,9 +1005,9 @@ class ServingEngine:
             if req is None or req.parked or req.done:
                 continue
             take = self._row_take_bound(req)
+            cur = self._view(req)[0]
             tot += max(
-                self._pages_held(req.cursor + take)
-                - self._pages_held(req.cursor), 0,
+                self._pages_held(cur + take) - self._pages_held(cur), 0,
             )
         return tot
 
@@ -916,7 +1029,8 @@ class ServingEngine:
         ]
         if tc.page_share < 1.0:
             cap = int(tc.page_share * self.pool.npages)
-            held = sum(self._pages_held(r.cursor) for r in resident)
+            held = sum(self._pages_held(self._view(r)[0])
+                       for r in resident)
             if held + self._pages_held(first) > cap:
                 return False
         if tc.token_budget is not None:
@@ -984,9 +1098,13 @@ class ServingEngine:
         the next ``min(chunk, remaining)`` sequence tokens. The
         speculative engine appends provisional draft tokens to steady
         decode rows (its override records which tail is draft)."""
-        take = min(self._chunk_for(req), len(req.seq) - req.cursor)
-        return np.asarray(req.seq[req.cursor:req.cursor + take],
-                          np.int32)
+        cur, n, pending = self._view(req)
+        if pending:
+            # the row's one token is on the device (``_assemble`` names
+            # the slot it comes from); the host packs a 0 in its place
+            return np.zeros((1,), np.int32)
+        take = min(self._chunk_for(req), n - cur)
+        return np.asarray(req.seq[cur:cur + take], np.int32)
 
     def _row_topology(self, s: int, req, take: int):
         """Per-row attention-topology descriptor (one
@@ -1014,7 +1132,8 @@ class ServingEngine:
         page = self.cfg.page
         for s in sorted(batched):
             req = self.slot_req[s]
-            frozen = min(req.cursor // page, self.state.pages_per_seq)
+            cursor = self._view(req)[0]
+            frozen = min(cursor // page, self.state.pages_per_seq)
             if frozen <= 0:
                 continue
             run = 0
@@ -1031,7 +1150,7 @@ class ServingEngine:
                     run = p + 1
             if run > 0 and topo[s, 0] == TOPO_CAUSAL:
                 topo[s] = shared_prefix_topology_row(
-                    min(run * page, int(req.cursor)), width
+                    min(run * page, cursor), width
                 )
                 self.stats.shared_prefix_rows += 1
 
@@ -1044,6 +1163,7 @@ class ServingEngine:
         cfg = self.cfg
         R, T = cfg.slots, self._t_pad
         tokens = np.zeros((T,), np.int32)
+        token_src = np.full((T,), -1, np.int32)
         token_rows = np.zeros((T,), np.int32)
         token_pos = np.full((T,), -1, np.int32)
         q_starts = np.zeros((R,), np.int32)
@@ -1062,7 +1182,9 @@ class ServingEngine:
             req = self.slot_req[s]
             if req is None or req.parked or req.done:
                 continue
-            if len(req.seq) - req.cursor <= 0:
+            # the launch-side view: with the step in flight applied
+            cur, n, pending = self._view(req)
+            if n - cur <= 0:
                 continue
             row = self._plan_row(req)
             take = len(row)
@@ -1071,32 +1193,34 @@ class ServingEngine:
             if next_start + _ceil8(take) > cfg.token_budget:
                 self.stats.deferrals += 1
                 continue                   # token budget spent
-            held = self._pages_held(req.cursor)
-            need = self._pages_held(req.cursor + take)
+            held = self._pages_held(cur)
+            need = self._pages_held(cur + take)
             if self.ops.ensure_pages(self, s, held, need, batched):
                 # allocation succeeded
                 span = slice(next_start, next_start + take)
                 tokens[span] = row
+                if pending:
+                    token_src[next_start] = s
                 token_rows[span] = s
                 token_pos[span] = np.arange(
-                    req.cursor, req.cursor + take, dtype=np.int32
+                    cur, cur + take, dtype=np.int32
                 )
                 q_starts[s] = next_start
                 q_lens[s] = take
-                kv_dev[s] = req.cursor + take
+                kv_dev[s] = cur + take
                 # the span's pages: the cursor's own up to the last held
-                self._append_runs += need - req.cursor // cfg.page
+                self._append_runs += need - cur // cfg.page
                 if not mc.sparse_topk:
                     # (a sparse layer walks a selection, counted below)
                     self._pages_walked[0] += need
                 if self._window:
                     self._pages_walked[1] += need - max(
-                        req.cursor - self._window + 1, 0) // cfg.page
+                        cur - self._window + 1, 0) // cfg.page
                 if mc.sparse_topk:
                     self._state_work[0] += (
                         min(need, mc.sparse_topk) if take == 1 else need)
                     self._state_work[1] += (
-                        req.cursor + take > mc.sparse_dense_len)
+                        cur + take > mc.sparse_dense_len)
                 if mc.lightning_layers:
                     self._state_work[2] += 1
                 next_start += _ceil8(take)
@@ -1117,6 +1241,7 @@ class ServingEngine:
         block_q = self._rung(int(q_lens.max()))
         width = self._width(block_q)
         q_starts[q_lens == 0] = live_rows(block_q, R, cfg.token_budget)
+        self._token_src = token_src[:width]
         return (tokens[:width], token_rows[:width], token_pos[:width],
                 q_starts, q_lens, kv_dev, topo, batched, takes)
 
@@ -1138,13 +1263,27 @@ class ServingEngine:
         state = self.state.replace(
             block_table=jnp.asarray(self.table),
             kv_lens=jnp.asarray(kv_dev),
-            cursors=jnp.asarray(
-                [0 if r is None else r.cursor for r in self.slot_req],
-                dtype=jnp.int32,
-            ),
+            # (made int32 on the host: ``jnp.asarray(list, dtype=)``
+            # is a conversion program on the device, a step)
+            cursors=jnp.asarray(np.asarray(
+                [0 if r is None else self._view(r)[0]
+                 for r in self.slot_req], np.int32)),
         )
+        if self._greedy is None:
+            # the logits come down: every token is the host's
+            tokens_dev = jnp.asarray(tokens)
+        else:
+            # a greedy engine merges in front of EVERY step, also one
+            # that takes no token from the device: one tiny program a
+            # packed width, compiled where the width's step program is
+            # (a warm-up that has run a width has run both).
+            # ``_token_src`` is of the batch last assembled, as
+            # ``arrays`` are
+            tokens_dev = _merge_jit(
+                self._uploaded("tokens", tokens),
+                self._uploaded("token_src", self._token_src), self._ids)
         return (
-            self.params, state, jnp.asarray(tokens),
+            self.params, state, tokens_dev,
             self._uploaded("token_rows", token_rows),
             jnp.asarray(token_pos),
             self._uploaded("q_starts", q_starts),
@@ -1160,8 +1299,10 @@ class ServingEngine:
         """The device copy of one of a step's host arrays — last step's
         while the content is last step's: consecutive decode-only steps
         over the same rows repeat their layout (rows, starts, lengths,
-        topology), and an upload costs the host ~0.3 ms whatever its
-        size. Only for arguments the step does not donate."""
+        topology; and their tokens, all of which come from the device:
+        zeros and the slots they come from), and an upload costs the
+        host ~0.3 ms whatever its size. Only for arguments the step
+        does not donate."""
         last = self._uploads.get(name)
         if last is not None and last[0].shape == host.shape \
                 and np.array_equal(last[0], host):
@@ -1173,6 +1314,10 @@ class ServingEngine:
         return _Phase(self._phase_s, phase, self.step_count)
 
     def _run_device(self, arrays, block_q):
+        """Upload one assembled batch and dispatch its step; returns the
+        step's result ON THE DEVICE — a greedy engine's ``(slots,)``
+        token ids (``_greedy_tokens`` behind the step program), else the
+        logits. Nothing here waits for the device: :meth:`_fetch` does."""
         from triton_distributed_tpu.lang.launch import maybe_instrument
 
         with self._phase("upload"):
@@ -1187,15 +1332,6 @@ class ServingEngine:
                 step=self.step_count,
             )
             out = step_fn(*args)
-            if self.model.kv_append_by_kernel(self.use_pallas):
-                self.stats.append_runs += self._append_runs
-            else:
-                self.stats.append_scatter_steps += 1
-            self.stats.global_pages_walked += self._pages_walked[0]
-            self.stats.window_pages_walked += self._pages_walked[1]
-            self.stats.selected_pages_walked += self._state_work[0]
-            self.stats.sparse_rows += self._state_work[1]
-            self.stats.state_rows += self._state_work[2]
             if self.moe_state is None:
                 logits, self.state = out
             else:
@@ -1211,76 +1347,208 @@ class ServingEngine:
                         for mine, new in zip(other, states):
                             if mine is not None:
                                 mine.parity = new.parity
-        with self._phase("fetch"):
+            if self._greedy is None:
+                return logits
+            # the ids stay on the device: the next step's packed tokens
+            # are merged from them there (``_merge_tokens``)
+            self._ids = self._greedy(logits)
+            return self._ids
+
+    def _fetch(self, flight: _Flight) -> None:
+        """Bring a dispatched step's result down (``flight.host``)."""
+        with _Phase(flight.phase_s, "fetch", flight.step):
             # the host fetch is the fence: the wait for the step program,
             # the copy down and the delinearize, deliberately one span (a
             # block_until_ready before it would put a host wake-up on
-            # the critical path untraced)
-            host_logits = np.asarray(
-                logits if self._greedy is None else self._greedy(logits))
-            # the uploads and the device logits are freed here, inside
-            # the span, not on return (0.1-0.2 ms of a step's idle gap)
-            del args, out, logits
-            return host_logits
+            # the critical path untraced). Launched ahead, the wait is
+            # for the step BEFORE the one the device now runs or holds
+            # queued.
+            flight.host = np.asarray(flight.out)
+            # the device logits are freed here, inside the span, not on
+            # return (0.1-0.2 ms of a step's idle gap); ``_ids`` keeps
+            # a greedy engine's (slots,) ids
+            flight.out = None
+
+    def _launch_ahead(self) -> bool:
+        """THE ONE QUESTION ``step()`` asks before it assembles: may
+        this step be dispatched before the step in flight is retired —
+        or does assembling or advancing it need a value that is still
+        on the device, or state that only a retirement commits? Read
+        from what the engine can observe, each step anew; where the
+        answer is no, the step in flight is retired FIRST and the step
+        runs in the synchronous order (launch, fetch, advance in one
+        call)."""
+        from triton_distributed_tpu.runtime.health import PeerState
+
+        cfg = self.cfg
+        if self._greedy is None:
+            # the logits come down: a draw keyed on (seed, rid, n), or
+            # an advance that reads them (``host_logits``), picks the
+            # next token on the host
+            return False
+        if cfg.prefix_cache and (
+                self._flight is not None and self._flight.freezes
+                or self.waiting or self.pending
+                and self.pending[0].arrival <= self.step_count):
+            # the registry is written at a retirement (a page the step
+            # in flight freezes is published there, when every token
+            # its chain hash covers is down) and read by an admission
+            # (``_attach_prefix``, which also takes cached pages out of
+            # the pool's headroom) and by the dedup: whatever that step
+            # freezes is in it before this one looks anything up
+            return False
+        if self.stats.degraded or self.health.state(
+                self.health_peer) is not PeerState.HEALTHY:
+            # a probing or degraded step: the fallback re-runs the
+            # batch it just launched, and the ledger hears of a clean
+            # step only once its result is down
+            return False
+        # what admission will find waiting (``pending`` is in arrival
+        # order: ``ProtocolOps.admit`` moves its head over likewise)
+        due = (*self.waiting, *itertools.takewhile(
+            lambda p: p.arrival <= self.step_count, self.pending))
+        worst = max((self._eff_rank(r) for r in self.slot_req
+                     if r is not None and not r.done), default=0)
+        if worst and any(self._eff_rank(w) < worst for w in due):
+            # admission may pre-empt a resident for a request that
+            # outranks it (``_preempt_for``): the victim's replay needs
+            # its committed cursor and every token it generated
+            return False
+        # ``ensure_pages`` may have to evict, likewise: the pages this
+        # step can claim are those the residents' next rows need and the
+        # first chunks of the requests it may admit — as many as slots
+        # are free, and no more pages than admission promises out of
+        # ``pool.available``. That is the SUM over a cp pool's shards,
+        # while each page is claimed on the shard that owns its index
+        # (a sequence's first pages all on shard 0): the step is safe
+        # where the fullest shard could hold all of it
+        claimed = self._committed_pages()
+        free = sum(r is None for r in self.slot_req)
+        firsts = sorted((self._pages_held(min(self._chunk_for(r),
+                                              len(r.seq)))
+                         for r in due), reverse=True)[:free]
+        claimed += min(sum(firsts), max(self.pool.available - claimed, 0))
+        return claimed <= self.pool.headroom
+
+    def _prepare(self, arrays, takes, report) -> tuple:
+        """The rest of the assemble phase for a batch that launches: the
+        step's rung, the health ledger's say on the path it takes, and
+        its :class:`_Flight`. Returns ``(flight, block_q, probing)``."""
+        from triton_distributed_tpu.runtime.health import PeerState
+
+        tokens, q_starts, q_lens, kv_lens = (
+            arrays[0], arrays[3], arrays[4], arrays[5])
+        block_q = self._rung(int(q_lens.max()))
+        peer = self.health_peer
+        if self.use_pallas \
+                and self.health.state(peer) is PeerState.UNHEALTHY:
+            # the ledger condemned the fused path out-of-band (a
+            # shared ledger's other role, a watchdog trip): demote
+            # before launching
+            self.use_pallas = False
+            self.stats.degraded = True
+        # PROBATION: on the seeded schedule, try the fused path again
+        probing = (not self.use_pallas
+                   and self.health.probe_due(peer, self.step_count))
+        if probing:
+            self.use_pallas = True
+        c = self.model.config
+        page = self.cfg.page
+        flight = _Flight(
+            step=self.step_count, phase_s=self._phase_s, report=report,
+            takes=takes, q_starts=q_starts, q_lens=q_lens,
+            ahead=self._flight is not None,
+            # ``kv_lens`` is each row's cursor past its take
+            freezes=bool((kv_lens // page > (kv_lens - q_lens) // page)
+                         .any()),
+            counts={
+                "global_pages_walked": self._pages_walked[0],
+                "window_pages_walked": self._pages_walked[1],
+                "selected_pages_walked": self._state_work[0],
+                "sparse_rows": self._state_work[1],
+                "state_rows": self._state_work[2],
+                "packed_rows": len(tokens),
+                # the step program masks its padding rows' assignments
+                "moe_masked_rows": len(tokens) - report["tokens"]
+                if c.moe == "ep" and c.moe_layers else 0,
+            })
+        return flight, block_q, probing
+
+    def drain(self) -> dict | None:
+        """Retire the step in flight, if there is one: afterwards the
+        public state is the whole of what was dispatched, and nothing
+        of this engine's is still on the device alone. Returns that
+        step's report. ``run()`` ends with it.
+
+        THE CONTRACT for everyone who is not the engine: whoever reads
+        or moves this engine's slots, block table or pool between its
+        steps — parks, ships, lands or frees a slot, re-queues or
+        migrates a resident request, reserves pages, flips a role —
+        calls ``drain()`` first (``DisaggregatedEngine.tick`` and
+        ``fleet.Replica.step`` do, after every step of theirs).
+        ``_launch_ahead`` answers only for what the engine itself
+        decides; the hooks (``on_complete``, ``on_preempt``) are called
+        from a retirement with the request's committed state."""
+        flight, self._flight = self._flight, None
+        return None if flight is None else self._retire(flight)
 
     def step(self) -> dict:
-        """One engine step: admit → assemble → device step → advance
-        cursors/completions. Returns a small per-step report."""
-        phase_s = self._phase_s
-        for k in phase_s:
-            phase_s[k] = 0.0
+        """One engine step: admit → assemble → upload → dispatch step k,
+        THEN fetch and advance step k - 1 (cursors, tokens, completions)
+        while the device runs k. Where :meth:`_launch_ahead` says no,
+        the same in the synchronous order: k - 1 is retired first, and k
+        in this call too. Returns the report of the step this call
+        retired (of the one it launched, if it retired none)."""
+        ahead = self._launch_ahead()
+        retired = None if ahead else self.drain()
+        self._phase_s = dict.fromkeys(PHASES, 0.0)
+        evictions = self.stats.evictions
         with self._phase("admit"):
             self._admit()
         with self._phase("assemble"):
             (tokens, token_rows, token_pos, q_starts, q_lens, kv_dev,
              topo, batched, takes) = self._assemble()
+            if self._flight is not None \
+                    and self.stats.evictions != evictions:
+                raise RuntimeError(
+                    "a request was evicted with a step in flight: its "
+                    "replay would miss that step's token")
             report = {"step": self.step_count, "batched": len(batched),
                       "tokens": int(q_lens.sum())}
-            if not batched:
-                self.step_count += 1
-                return report
-            block_q = self._rung(int(q_lens.max()))
-            from triton_distributed_tpu.runtime.health import PeerState
+            if batched:
+                arrays = (tokens, token_rows, token_pos, q_starts, q_lens,
+                          kv_dev, topo)
+                flight, block_q, probing = self._prepare(
+                    arrays, takes, report)
+        if not batched:
+            # nothing to launch: what is in flight is retired
+            retired = self.drain() or retired
+            self.step_count += 1
+            return retired or report
+        from triton_distributed_tpu.runtime.health import PeerState
 
-            peer = self.health_peer
-            if self.use_pallas \
-                    and self.health.state(peer) is PeerState.UNHEALTHY:
-                # the ledger condemned the fused path out-of-band (a
-                # shared ledger's other role, a watchdog trip): demote
-                # before launching
-                self.use_pallas = False
-                self.stats.degraded = True
-            # PROBATION: on the seeded schedule, try the fused path again
-            probing = (not self.use_pallas
-                       and self.health.probe_due(peer, self.step_count))
-            if probing:
-                self.use_pallas = True
-            arrays = (tokens, token_rows, token_pos, q_starts, q_lens,
-                      kv_dev, topo)
+        peer = self.health_peer
+
+        def run_device():
+            flight.out = self._run_device(arrays, block_q)
+            # the pool append of the step as it ran: by the kernel, a
+            # (slot, page) run at a time, or by the row scatter
+            by_kernel = self.model.kv_append_by_kernel(self.use_pallas)
+            flight.counts["append_runs"] = by_kernel * self._append_runs
+            flight.counts["append_scatter_steps"] = int(not by_kernel)
+            if not ahead:
+                # the synchronous order: this step's own result, inside
+                # the guarded run as ever
+                self._fetch(flight)
+
         try:
-            logits = self._run_device(arrays, block_q)
+            run_device()
         except Exception as e:
-            self.stats.failures.append({
-                "step": self.step_count, "site": "serving_step",
-                "error": f"{type(e).__name__}: {e}",
-            })
-            if not self.use_pallas or self.propagate_failures:
+            if not self._failed(self.step_count, e, probing):
                 raise
-            # degradation: fall back to the XLA twin (the op-level
-            # with_fallback story at engine level) — scheduling state is
-            # untouched, re-run the batch. The failure is a ledger
-            # signal: a probe failure drops straight back to UNHEALTHY,
-            # a first failure is fatal (kernel_error) so re-entry to the
-            # fused path only ever happens through clean probes.
-            if probing:
-                self.health.probe_result(peer, False,
-                                         step=self.step_count)
-            else:
-                self.health.record("kernel_error", peer,
-                                   step=self.step_count)
-            self.use_pallas = False
-            self.stats.degraded = True
-            logits = self._run_device(arrays, block_q)
+            # scheduling state is untouched: re-run the batch on the
+            # XLA twin
+            run_device()
         else:
             if probing:
                 st = self.health.probe_result(peer, True,
@@ -1299,15 +1567,64 @@ class ServingEngine:
                     self.use_pallas = True
                     self.stats.degraded = False
                     self.stats.repromotions += 1
-        stats = self.stats
+        # step k is queued behind k - 1 on the device: now k - 1's ids
+        # come down and its rows advance, under the device's step
+        before, self._flight = self._flight, flight
+        if before is not None:
+            retired = self._retire(before)
+        if not ahead:
+            retired = self.drain()
+        self.step_count += 1
+        return retired or report
+
+    def _failed(self, step: int, e: Exception, probing: bool = False) -> bool:
+        """Book the failure of device step ``step`` and degrade: fall
+        back to the XLA twin (the op-level with_fallback story at engine
+        level). The failure is a ledger signal: a probe failure drops
+        straight back to UNHEALTHY, a first failure is fatal
+        (kernel_error), so re-entry to the fused path only ever happens
+        through clean probes. False: there is no twin left to fall back
+        to, or failures propagate — the caller raises."""
+        self.stats.failures.append({
+            "step": step, "site": "serving_step",
+            "error": f"{type(e).__name__}: {e}",
+        })
+        if not self.use_pallas or self.propagate_failures:
+            return False
+        if probing:
+            self.health.probe_result(self.health_peer, False, step=step)
+        else:
+            self.health.record("kernel_error", self.health_peer, step=step)
+        self.use_pallas = False
+        self.stats.degraded = True
+        return True
+
+    def _retire(self, flight: _Flight) -> dict:
+        """Fetch a dispatched step's result (if it is not down yet) and
+        advance its rows: cursors, generated tokens, ``t_first``,
+        completions, freed slots, and the step's entries and counts in
+        ``EngineStats`` — everything public about it, in one call."""
+        if flight.host is None:
+            try:
+                self._fetch(flight)
+            except Exception as e:
+                # a step launched ahead whose failure surfaces only now,
+                # as its result comes down: the pools its program
+                # donated are gone with it, so there is no batch to
+                # re-run; it is booked and told to the ledger (every
+                # later step is drained and on the twin), then raised
+                self._failed(flight.step, e)
+                raise
+        stats, phase_s, report = self.stats, flight.phase_s, flight.report
         dt = phase_s["upload"] + phase_s["dispatch"] + phase_s["fetch"]
-        with self._phase("advance"):
+        with _Phase(phase_s, "advance", flight.step):
             gen_this_step = 0
             prefill_this_step = 0
-            for s in sorted(batched):
+            for s, take in flight.takes.items():
                 req = self.slot_req[s]
                 emitted, prefill_toks = self._advance_row(
-                    s, req, takes[s], logits, q_starts, q_lens)
+                    s, req, take, flight.host,
+                    flight.q_starts, flight.q_lens)
                 gen_this_step += emitted
                 prefill_this_step += prefill_toks
                 if req.t_first is None and req.generated:
@@ -1317,11 +1634,9 @@ class ServingEngine:
                         stats.first_tokens += 1
             stats.step_times.append(dt)
             stats.step_tokens.append(report["tokens"])
-            stats.packed_rows += len(tokens)
-            c = self.model.config
-            if c.moe == "ep" and c.moe_layers:
-                # the step program masked its padding rows' assignments
-                stats.moe_masked_rows += len(tokens) - report["tokens"]
+            for k, v in flight.counts.items():
+                setattr(stats, k, getattr(stats, k) + v)
+            stats.lookahead_steps += flight.ahead
             stats.step_generated.append(gen_this_step)
             stats.note_shape(
                 self._grid_key, dt * 1e3,
@@ -1335,7 +1650,6 @@ class ServingEngine:
             )
         for k, v in phase_s.items():
             getattr(stats, k + "_times").append(v)
-        self.step_count += 1
         return report
 
     def _advance_row(self, s: int, req, take: int, logits,
@@ -1374,6 +1688,7 @@ class ServingEngine:
         if t <= 0.0:
             if self._greedy is not None:
                 # the row's arg-max, taken and checked on the device
+                # (-1: not finite) one step before it came down
                 tok = int(row_logits)
                 finite = tok >= 0
             else:
@@ -1411,6 +1726,7 @@ class ServingEngine:
             if self.idle:
                 break
             self.step()
+        self.drain()
         return self.stats
 
     # ------------------------------------------------ shipped admission
@@ -2043,13 +2359,18 @@ class DisaggregatedEngine:
         commit fence). A fault-plan :class:`SliceDeath` whose step has
         arrived fails the dead role over onto the survivor first."""
         self._check_slice_deaths()
+        # the hook's parking, the ships' reservations and commits and a
+        # failover move both roles' slots between their steps: neither
+        # keeps a step in flight past its own (``ServingEngine.drain``)
         rep_p = (None if self._dead_role == "prefill" or self.prefill.idle
                  else self.prefill.step())
+        self.prefill.drain()
         if self._dead_role is None:
             self._launch_ships()
             self._commit_ships()
         rep_d = (None if self._dead_role == "decode" or self.decode.idle
                  else self.decode.step())
+        self.decode.drain()
         self.ticks += 1
         if (self.stats.failover_role is not None
                 and self.stats.recovery_tick is None

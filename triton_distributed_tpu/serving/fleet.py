@@ -140,7 +140,14 @@ class Replica:
 
     def step(self):
         e = self.engine
-        return e.tick() if hasattr(e, "tick") else e.step()
+        if hasattr(e, "tick"):
+            return e.tick()
+        # the fleet reads and moves a replica's slots between its steps
+        # (routing, failover, drain, migration): none stays in flight
+        # (the contract of ``ServingEngine.drain``)
+        rep = e.step()
+        e.drain()
+        return rep
 
     @property
     def idle(self) -> bool:

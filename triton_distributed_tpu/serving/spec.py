@@ -321,6 +321,11 @@ class SpeculativeEngine(ServingEngine):
     byte-identical to the plain engine while branchy traffic accepts
     more tokens per step than any single linear path could."""
 
+    # the verify / accept loop reads the logits after every draft token
+    # on the host, and a rejected draft rolls the cursor back: a step's
+    # result is down before the next is assembled
+    host_logits = True
+
     def __init__(self, model, params, cfg, *, drafter: Drafter | None = None,
                  spec_k: int = 4, spec_tree: int = 0,
                  adaptive_k: bool = False, **kw):

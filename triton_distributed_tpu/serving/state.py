@@ -278,6 +278,12 @@ class PagePool:
         del idx
         return self._by_hash.get(chain_hash)
 
+    @property
+    def headroom(self) -> int:
+        """Pages ANY set of allocations can count on, wherever their
+        logical indices fall (a cp pool: its fullest shard's)."""
+        return self.available
+
     def can_hold(self, held: int, need: int) -> bool:
         """Whether growing a sequence from ``held`` to ``need`` pages
         can be satisfied — the allocation gate the protocol's ``alloc``
@@ -419,6 +425,10 @@ class CpPagePool:
         s = self.owner_of(idx)
         lp = self.shards[s].lookup(chain_hash)
         return None if lp is None else s * self.npages_shard + lp
+
+    @property
+    def headroom(self) -> int:
+        return min(s.available for s in self.shards)
 
     def can_hold(self, held: int, need: int) -> bool:
         """Exact per-shard gate: pages ``held..need-1`` route to their

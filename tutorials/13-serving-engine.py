@@ -69,7 +69,13 @@ def build(cfg, mesh, key=0):
 def serve(model, params, prompts):
     """All ``prompts`` through one engine. Returns the engine, the
     requests, and each request's FIRST-token logits row."""
-    eng = ServingEngine(model, params, ENGINE)
+    class HostLogits(ServingEngine):
+        # the logits come down (a greedy engine otherwise keeps each
+        # row's arg-max on the device and fetches token ids, one step
+        # in flight): ``keep`` below reads a row's
+        host_logits = True
+
+    eng = HostLogits(model, params, ENGINE)
     first, sample = {}, eng._sample
 
     def keep(row_logits, req):
