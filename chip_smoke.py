@@ -107,6 +107,20 @@ SALA_CUT = dict(n_layers=8, layer_stride=4)
 SALA_ENGINE = dict(slots=32, token_budget=512, chunk=256, page=128,
                    npages=7296)
 SALA_TOL = {"selected": 0.03, "lightning": 1e-3}
+#: the mla leg (PR 35): ``presets.dots_vlm1`` cut in WIDTH to a twin
+#: that compiles in seconds: a dense and two sparse layers, the latent
+#: entry, both head sizes, YaRN and the page as served (512 + 64, 192 /
+#: 128, x 40 over 4096, 128), 8 heads, a share of the experts held (4
+#: of 32 in 8 groups, from expert 8 on). Prompts on both sides of a
+#: chunk and of a page; bound on rms(kernel - twin) / rms(twin) over the
+#: rows both paths answered for the same tokens
+MLA_TWIN = dict(n_layers=3, n_dense_layers=1, hidden=256, ffn=256,
+                dense_ffn=512, n_heads=8, n_kv_heads=8, q_latent=128,
+                vocab=1024, num_experts=32, experts_held=4,
+                first_expert_held=8)
+MLA_ENGINE = dict(slots=4, token_budget=512, chunk=256, page=128, npages=16)
+MLA_PROMPTS = (300, 40, 130)
+MLA_TOL = 0.03
 
 
 class SmokeFailure(Exception):
@@ -413,6 +427,43 @@ def lower_published_rung(big, ecfg):
     return lowered, width
 
 
+def serve_rows(model, params, ecfg: dict, prompts, use_pallas: bool,
+               what: str):
+    """``prompts`` (six new tokens each, arriving a step apart) through
+    a ``ServingEngine`` whose logits come down. Returns ``(engine,
+    {rid: [each served row's float32 logits]}, [each request's tokens],
+    stats)``; fails the smoke unless every request completed cleanly."""
+    import numpy as np
+
+    from triton_distributed_tpu.serving import (
+        EngineConfig,
+        Request,
+        ServingEngine,
+    )
+
+    class HostLogits(ServingEngine):
+        host_logits = True      # ``keep`` below records each row's
+
+    eng = HostLogits(model, params, EngineConfig(**ecfg),
+                     use_pallas=use_pallas, propagate_failures=True)
+    rows, sample = {}, eng._sample
+
+    def keep(row_logits, req):
+        rows.setdefault(req.rid, []).append(
+            np.asarray(row_logits, np.float32))
+        return sample(row_logits, req)
+
+    eng._sample = keep
+    reqs = [Request(rid=i, prompt=p, max_new=6, arrival=float(i))
+            for i, p in enumerate(prompts)]
+    stats = eng.run(reqs, max_steps=MAX_STEPS)
+    need(stats.completed == len(prompts) and not stats.failures
+         and not stats.degraded,
+         f"{what} (use_pallas={use_pallas}) did not complete cleanly: "
+         f"{stats.failures}")
+    return eng, rows, [r.generated for r in reqs], stats
+
+
 def window_leg(devices, on_chip: bool = True) -> dict:
     """Sliding-window layers over ring pools, a sigmoid-routed share of
     an expert layer, a shared expert: the width-cut twin served once by
@@ -424,11 +475,7 @@ def window_leg(devices, on_chip: bool = True) -> dict:
     from jax.sharding import Mesh
 
     from triton_distributed_tpu.models import Transformer, presets
-    from triton_distributed_tpu.serving import (
-        EngineConfig,
-        Request,
-        ServingEngine,
-    )
+    from triton_distributed_tpu.serving import EngineConfig
     from triton_distributed_tpu.serving.state import ring_pages
 
     mesh = Mesh(np.asarray(devices), ("x",))
@@ -444,33 +491,18 @@ def window_leg(devices, on_chip: bool = True) -> dict:
     rng = np.random.default_rng(SEED)
     prompts = [rng.integers(0, model.config.vocab, (n,)).astype(np.int32)
                for n in WINDOW_PROMPTS]
-    class HostLogits(ServingEngine):
-        host_logits = True      # ``keep`` below records each row's
-
     served = {}
     for use_pallas in (True, False):
-        eng = HostLogits(model, params, EngineConfig(**WINDOW_ENGINE),
-                         use_pallas=use_pallas, propagate_failures=True)
-        rows, sample = [], eng._sample
-
-        def keep(row_logits, req, rows=rows, sample=sample):
-            rows.append(np.asarray(row_logits, np.float32))
-            return sample(row_logits, req)
-
-        eng._sample = keep
-        stats = eng.run([Request(rid=i, prompt=p, max_new=6,
-                                 arrival=float(i))
-                         for i, p in enumerate(prompts)],
-                        max_steps=MAX_STEPS)
-        need(stats.completed == len(prompts) and not stats.failures
-             and not stats.degraded,
-             f"window twin (use_pallas={use_pallas}) did not complete "
-             f"cleanly: {stats.failures}")
+        eng, rows, _, stats = serve_rows(
+            model, params, WINDOW_ENGINE, prompts, use_pallas,
+            "window twin")
         need(eng.state.ring == ring and all(
             eng.state.layer_pages(i) == WINDOW_ENGINE["slots"] * ring
             for i in eng.state.window_layers),
             "a window layer holds more than slots x ring pages")
-        served[use_pallas] = (np.stack(rows), stats)
+        served[use_pallas] = (
+            np.stack([r for rid in sorted(rows) for r in rows[rid]]),
+            stats)
     got, want = served[True][0], served[False][0]
     need(np.isfinite(got).all(), "window twin: logits not finite")
     rel = float(np.sqrt(np.mean((got - want) ** 2))
@@ -634,6 +666,70 @@ def sala_leg(devices, on_chip: bool = True) -> dict:
     }
 
 
+def mla_leg(devices) -> dict:
+    """PR 35's latent pool on the chip: a width-cut twin of
+    ``presets.dots_vlm1`` serves three short requests through
+    ``ServingEngine`` by the kernels (the latent walk, the one-pool
+    append) and again by their XLA twins; every served row's logits
+    are compared (rel rms)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from triton_distributed_tpu.models import Transformer, presets
+
+    t0 = time.perf_counter()
+    model = Transformer(
+        presets.dots_vlm1(param_dtype=jnp.bfloat16, **MLA_TWIN),
+        Mesh(np.asarray(devices), ("x",)), tp_axis="x")
+    params = jax.block_until_ready(jax.jit(
+        model.init, out_shardings=model.shardings())(
+            jax.random.PRNGKey(SEED)))
+    rng = np.random.default_rng(SEED)
+    prompts = [rng.integers(0, model.config.vocab, (n,)).astype(np.int32)
+               for n in MLA_PROMPTS]
+
+    served = {}
+    for use_pallas in (True, False):
+        eng, rows, tokens, stats = serve_rows(
+            model, params, MLA_ENGINE, prompts, use_pallas, "mla twin")
+        pool, v = eng.state.layers[0]
+        need(v is None and pool.shape == (
+            MLA_ENGINE["npages"], 1, MLA_ENGINE["page"],
+            model.config.latent_stored),
+            "a latent layer holds more than one entry a token")
+        served[use_pallas] = (rows, tokens, stats)
+    # a request's rows are comparable while both paths fed it the same
+    # tokens: up to and with the first row whose arg-max parts them (on
+    # seeded random weights a near-tie flips on bf16 rounding, and
+    # every later row then answers another sequence)
+    got, want = [], []
+    for rid, (a, b) in enumerate(zip(served[True][1], served[False][1])):
+        same = next((i for i, (x, y) in enumerate(zip(a, b)) if x != y),
+                    len(a) - 1) + 1
+        got += served[True][0][rid][:same]
+        want += served[False][0][rid][:same]
+    got, want = np.stack(got), np.stack(want)
+    need(len(got) >= 2 * len(prompts),
+         f"mla twin: only {len(got)} comparable rows: the paths part at "
+         "once")
+    need(np.isfinite(got).all(), "mla twin: logits not finite")
+    rel = float(np.sqrt(np.mean((got - want) ** 2))
+                / np.sqrt(np.mean(want ** 2)))
+    need(rel <= MLA_TOL,
+         f"mla twin: kernels and XLA twins disagree, rel rms {rel:.4f}"
+         f" > {MLA_TOL}")
+    st = served[True][2]
+    need(st.append_runs > 0 and st.latent_rows > 0,
+         "mla twin: the kernel path did not book its append or its walk")
+    return {"leg": "mla", "twin_rel_rms": round(rel, 5),
+            "rows_compared": len(got),
+            "twin_s": round(time.perf_counter() - t0, 2),
+            "latent_pages_walked": st.latent_pages_walked,
+            "latent_rows": st.latent_rows, "append_runs": st.append_runs}
+
+
 def leg(devices, on_chip: bool = True) -> dict:
     """The whole smoke on one device set."""
     n = len(devices)
@@ -721,7 +817,9 @@ def main(argv=None) -> int:
                          "expert-share twin + one published rung "
                          "compiled), dsmoe (the served trace), sala (the "
                          "selected walk and the lightning mixer against "
-                         "their twins + one published rung compiled)")
+                         "their twins + one published rung compiled), mla "
+                         "(a width-cut latent-attention twin served by "
+                         "kernels and by XLA twins)")
     legs = ap.parse_args(argv).legs.split(",")
     t_start = time.perf_counter()
     # a wedged collective must end as a failure with every thread's
@@ -754,6 +852,8 @@ def main(argv=None) -> int:
                 say(**window_leg(devs[:1]))
             if "sala" in legs:
                 say(**sala_leg(devs[:1]))
+            if "mla" in legs:
+                say(**mla_leg(devs[:1]))
             if "dsmoe" in legs:
                 say(**leg(devs[:1]))
                 if len(devs) >= 4:

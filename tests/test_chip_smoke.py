@@ -9,6 +9,7 @@ import subprocess
 import sys
 
 import jax
+import jax.numpy as jnp
 import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parents[1]
@@ -110,3 +111,22 @@ def test_window_leg_passes_its_own_checks_at_tiny_size(tmp_path,
     assert rec["twin_rel_rms"] <= chip_smoke.WINDOW_TOL
     assert rec["ring_pages_per_slot"] == 6      # ceil((24 + 15) / 8) + 1
     assert 0 < rec["window_pages_walked"] < rec["global_pages_walked"]
+
+
+def test_mla_leg_passes_its_own_checks_at_tiny_size(tmp_path, monkeypatch):
+    """The mla leg (a latent pool served by the latent walk and the
+    one-pool append, against their XLA twins) at CPU sizes."""
+    monkeypatch.setenv("TDTPU_AUTOTUNE_LOG_DIR", str(tmp_path))
+    monkeypatch.setattr(chip_smoke, "MLA_TWIN", dict(
+        n_layers=3, n_dense_layers=1, hidden=64, ffn=64, dense_ffn=96,
+        n_heads=4, n_kv_heads=4, head_dim=12, q_latent=24, kv_latent=16,
+        qk_nope_dim=8, qk_rope_dim=4, v_head_dim=8, vocab=128,
+        num_experts=16, topk=4, router_groups=4, router_topk_groups=2,
+        experts_held=4, first_expert_held=8, dtype=jnp.float32))
+    monkeypatch.setattr(chip_smoke, "MLA_ENGINE", dict(
+        slots=4, token_budget=64, chunk=16, page=16, npages=32))
+    monkeypatch.setattr(chip_smoke, "MLA_PROMPTS", (70, 5, 33))
+    rec = chip_smoke.mla_leg(jax.devices()[:1])
+    assert rec["twin_rel_rms"] <= chip_smoke.MLA_TOL
+    assert rec["latent_rows"] > 0 and rec["append_runs"] > 0
+    assert rec["latent_pages_walked"] > 0

@@ -125,13 +125,14 @@ def append_rows_xla(pool, new, rows):
     return flat.at[rows].set(new).reshape(pool.shape)
 
 
-def _append_kernel(hkv, page, d, tile, lt, quant, units, *refs):
+def _append_kernel(hkv, page, d, tile, lt, quant, n_pools, units, *refs):
     """Software-pipelined loop over the step's units (module docstring).
     ``refs``: the HBM operands ``[pool, new] × (K, V) [+ (scale, new
     scale) × (K, V)]``, the aliased HBM outputs, then per stream a
     page buffer and a window buffer (2 slots each) and the DMA
-    semaphores."""
-    n_streams = 4 if quant else 2
+    semaphores. ``n_pools`` 1: ONE pool (a latent pool: one entry a
+    token, no V), the same loop over half the streams."""
+    n_streams = n_pools * (2 if quant else 1)
     ins = refs[:2 * n_streams]
     outs = refs[2 * n_streams:3 * n_streams]
     scratch = refs[3 * n_streams:]
@@ -142,7 +143,7 @@ def _append_kernel(hkv, page, d, tile, lt, quant, units, *refs):
         streams.append(dict(
             pool=ins[2 * k], new=ins[2 * k + 1], out=outs[k],
             buf=bufs[2 * k], win=bufs[2 * k + 1], k=k,
-            scale=k >= 2,
+            scale=k >= n_pools,
         ))
     n = units[0]
     n_lg = page // lt
@@ -279,25 +280,27 @@ def _append_kernel(hkv, page, d, tile, lt, quant, units, *refs):
 
 @functools.lru_cache(maxsize=64)
 def _build_append(npages, hkv, page, d, dtype, quant, interpret,
-                  token=()):
+                  token=(), n_pools=2):
     """The pallas_call, cached on the static geometry: taking ``(units,
     k_pool, k_new, v_pool, v_new[, k_scale, k_snew, v_scale, v_snew])``
-    and returning the pools (and scale planes) in place."""
+    and returning the pools (and scale planes) in place. ``n_pools``
+    1: ``(units, pool, new)``, the launch ``kv_append_latent``."""
     del token
     dtype = jnp.dtype(dtype)
     tile = row_tile(dtype, page)
     lt = lane_tile(page)
     n_lg = page // lt
-    kernel = functools.partial(_append_kernel, hkv, page, d, tile, lt, quant)
+    kernel = functools.partial(
+        _append_kernel, hkv, page, d, tile, lt, quant, n_pools)
     pool = jax.ShapeDtypeStruct((npages, hkv, page, d), dtype)
     plane = jax.ShapeDtypeStruct((npages, hkv, page), jnp.float32)
-    out_shape = [pool, pool] + ([plane, plane] if quant else [])
+    out_shape = [pool] * n_pools + ([plane] * n_pools if quant else [])
     n_streams = len(out_shape)
     scratch = []
-    for _ in range(2):
+    for _ in range(n_pools):
         scratch += [pltpu.VMEM((2, hkv, page, d), dtype),
                     pltpu.VMEM((2, hkv, page + tile, d), dtype)]
-    for _ in range(2 if quant else 0):
+    for _ in range(n_pools if quant else 0):
         scratch += [pltpu.VMEM((2, hkv, page), jnp.float32),
                     pltpu.VMEM((2, n_lg + 1, hkv, lt), jnp.float32)]
     scratch += [pltpu.SemaphoreType.DMA((n_streams, 2))] * 3
@@ -318,7 +321,8 @@ def _build_append(npages, hkv, page, d, dtype, quant, interpret,
         # operand 0 is the scalar-prefetched unit list; pools sit at
         # 1, 3[, 5, 7] and come back as outputs 0, 1[, 2, 3]
         input_output_aliases={1 + 2 * k: k for k in range(n_streams)},
-        name="kv_append" + ("_q8" if quant else ""),
+        name="kv_append" + ("_latent" if n_pools == 1 else "")
+        + ("_q8" if quant else ""),
         dimension_semantics=("arbitrary",),
     )
 
@@ -350,9 +354,13 @@ def kv_append(units, k_pool, v_pool, k_new, v_new, *, interpret=None):
     ``(npages, Hkv, page, D)`` arrays, or ``{"q": int8 pool, "scale":
     (npages, Hkv, page) f32}`` dicts; ``k_new``/``v_new``: ``(T, Hkv,
     D)`` rows in the pool's dtype, or ``{"q": int8 rows, "scale": (T,
-    Hkv) f32}`` dicts. Returns the pools in the form given. Rows outside
-    every unit's run, and whole pages no unit names, keep their bytes.
+    Hkv) f32}`` dicts. ``v_pool`` and ``v_new`` None: ``k_pool`` is a
+    LATENT pool (one entry a token, no V), appended as the one stream.
+    Returns the pools in the form given. Rows outside every unit's
+    run, and whole pages no unit names, keep their bytes.
     """
+    if v_pool is None:
+        return _append_latent(units, k_pool, k_new, interpret)
     quant = isinstance(k_pool, dict)
     kq, vq, kn, vn = (
         x["q"] if quant else x for x in (k_pool, v_pool, k_new, v_new))
@@ -378,6 +386,23 @@ def kv_append(units, k_pool, v_pool, k_new, v_new, *, interpret=None):
         return ({"q": out[0], "scale": out[2]},
                 {"q": out[1], "scale": out[3]})
     return out[0], out[1]
+
+
+def _append_latent(units, pool, new, interpret):
+    """:func:`kv_append` for a LATENT pool ``(npages, 1, page, D)``:
+    one entry a token for every head and no V pool, so one stream of
+    the same unit loop (launch ``kv_append_latent``). ``new``: (T, 1,
+    D). Returns ``(pool, None)``."""
+    npages, hkv, page, d = pool.shape
+    tile = row_tile(pool.dtype, page)
+    if page % tile:
+        raise ValueError(
+            f"kv_append: page={page} must be whole {tile}-row tiles")
+    call = _build_append(
+        npages, hkv, page, d, jnp.dtype(pool.dtype).name, False,
+        interpret, n_pools=1)
+    (out,) = call(units, pool, _pad_rows(new, page, tile))
+    return out, None
 
 
 # ------------------------------------------------------------ lint surface

@@ -47,15 +47,31 @@ def select_experts(gate_logits, topk: int, *, renormalize: bool = True):
 
 
 def select_experts_sigmoid_bias(gate_logits, bias, topk: int, *,
-                                scale: float = 1.0):
+                                scale: float = 1.0, groups: int = 1,
+                                topk_groups: int = 1):
     """Sigmoid router with a selection bias (the DeepSeek-V3 family's
-    ``scoring_func: sigmoid``, no group limit) → (weights (M, k) f32,
-    expert ids (M, k) int32): scores ``s = sigmoid(logits)``; the top-k
-    of ``s + bias`` are SELECTED, and weighted by their own ``s``
-    renormalised over the k and times ``scale``
-    (``routed_scaling_factor``). The bias only moves the choice."""
+    ``scoring_func: sigmoid``) → (weights (M, k) f32, expert ids (M, k)
+    int32): scores ``s = sigmoid(logits)``; the top-k of ``s + bias``
+    are SELECTED, and weighted by their own ``s`` renormalised over the
+    k and times ``scale`` (``routed_scaling_factor``). The bias only
+    moves the choice.
+
+    ``groups`` > 1 is the GROUP-LIMITED choice (``topk_method:
+    noaux_tc``): the experts lie in ``groups`` equal runs, a group
+    scores the sum of its two largest ``s + bias``, and the top-k is
+    taken among the experts of the ``topk_groups`` best groups only.
+    Ties go to the lower group and the lower expert (``lax.top_k``)."""
     s = jax.nn.sigmoid(gate_logits.astype(jnp.float32))
-    _, ids = jax.lax.top_k(s + bias.astype(jnp.float32), topk)
+    c = s + bias.astype(jnp.float32)
+    if groups > 1:
+        m, e = c.shape
+        best2, _ = jax.lax.top_k(c.reshape(m, groups, e // groups), 2)
+        _, kept = jax.lax.top_k(jnp.sum(best2, axis=-1), topk_groups)
+        in_kept = jnp.any(
+            jnp.arange(groups)[None, :, None] == kept[:, None, :], axis=-1)
+        c = jnp.where(jnp.repeat(in_kept, e // groups, axis=1), c,
+                      -jnp.inf)
+    _, ids = jax.lax.top_k(c, topk)
     w = jnp.take_along_axis(s, ids, axis=-1)
     w = w / jnp.sum(w, axis=-1, keepdims=True) * scale
     return w, ids.astype(jnp.int32)

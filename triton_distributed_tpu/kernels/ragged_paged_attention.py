@@ -936,6 +936,229 @@ def _build_selected(
     )
 
 
+# ----------------------------------------------------------- latent walk
+#
+# ``latent``: attention over a LATENT pool (multi-head latent attention,
+# absorbed). The pool holds ONE entry a token for ALL the G query heads,
+# ``(npages, 1, page, Dp)``: ``[c_kv (d_latent) | k_pe (d_rope) | zeros]``
+# with ``Dp`` whole 128-lane tiles. A query row is ``[q_nope W_k^T
+# (d_latent) | q_pe (d_rope) | zeros]``; its score against entry ``j``
+# is their dot product over all ``Dp`` columns (the zero tail adds
+# nothing) and its value is the entry's first ``d_latent`` columns. The
+# caller applies W_kvb's value part to the output.
+#
+# G is the model's head count (128 at the published size): ONE token's
+# heads already fill an MXU tile, so the query block is cut by TOKENS,
+# not by the launch's ``block_q``: a decode row (``q_len == 1``) walks
+# as a block of G rows, any other row as ``ceil(q_len / LATENT_TQ)``
+# blocks of ``LATENT_TQ · G`` rows, each walking the pages up to its own
+# last position. A block of ``block_q · G`` rows would be 32768 x Dp at
+# the chunk rung (42 MB) and would give a decode row 8 x its work.
+
+#: tokens of one query block of a row with more than one token
+LATENT_TQ = 8
+#: keys scored at a time: this many tokens of consecutive pages are
+#: fetched into one buffer and multiplied as one block
+LATENT_KV_BLOCK = 512
+
+
+def _latent_kernel(
+    scale, page, n_bufs, g, dl, kb, tq, *refs,
+):
+    """Grid (R,): one request row a step; a row outside the batch
+    (``q_len == 0``) does nothing. Each query block starts its own
+    fetches (no state is carried from block to block or row to row)
+    and waits for its output before the next begins."""
+    (table_ref, kv_lens_ref, q_lens_ref, q_starts_ref, q_hbm, k_hbm,
+     out_hbm, qbuf, kbuf, obuf, sem_q, sem_k, sem_o, m_ref, l_ref,
+     acc_ref) = refs
+    r = pl.program_id(0)
+    npages = k_hbm.shape[0]
+    pps = table_ref.shape[1]
+    kbp = kb * page
+    kv_len = kv_lens_ref[r]
+    q_len = q_lens_ref[r]
+    q_start = q_starts_ref[r]
+    last_page = jnp.minimum(_n_valid_pages(kv_len, page), pps) - 1
+
+    def fetch(j, slot):
+        """The copies of key block ``j`` (pages ``[j·kb, (j+1)·kb)``; a
+        page past the row's last is its last again, masked by its
+        positions)."""
+        cps = []
+        for u in range(kb):
+            lp = jnp.minimum(j * kb + u, last_page)
+            pid = jnp.clip(table_ref[r, lp], 0, npages - 1)
+            cps.append(pltpu.make_async_copy(
+                k_hbm.at[pid], kbuf.at[slot, pl.ds(u * page, page)],
+                sem_k.at[slot, u]))
+        return cps
+
+    def walk(n_tok, i):
+        """Query block ``i`` of the row: ``n_tok`` (static) tokens from
+        the row's token ``i · n_tok`` on."""
+        rows = n_tok * g
+        first = i * n_tok
+        at = (q_start + first) * g
+        if g % 8 == 0:
+            at = pl.multiple_of(at, 8)
+        base = kv_len - q_len + first         # position of the first token
+        nblk = jnp.maximum(
+            jax.lax.div(jnp.minimum(base + n_tok, kv_len) + kbp - 1, kbp),
+            1)
+        q_in = pltpu.make_async_copy(
+            q_hbm.at[pl.ds(at, rows)], qbuf.at[pl.ds(0, rows)],
+            sem_q.at[0])
+        q_in.start()
+        for cp in fetch(0, 0):
+            cp.start()
+        m_ref[:rows] = jnp.full((rows, 1), NEG_INF, jnp.float32)
+        l_ref[:rows] = jnp.zeros((rows, 1), jnp.float32)
+        acc_ref[:rows] = jnp.zeros((rows, dl), jnp.float32)
+        limit = base + 1 + jax.lax.div(
+            jax.lax.broadcasted_iota(jnp.int32, (rows, 1), 0), g)
+        q_in.wait()
+        q = qbuf[:rows]                       # (rows, Dp)
+
+        def body(j, _):
+            slot = jax.lax.rem(j, n_bufs)
+
+            @pl.when(j + 1 < nblk)
+            def _prefetch():
+                for cp in fetch(j + 1, jax.lax.rem(j + 1, n_bufs)):
+                    cp.start()
+
+            chaos_delay(site="ragged_paged", step=None, me=None, n=None)
+            for cp in fetch(j, slot):
+                cp.wait()
+
+            def block(masked):
+                k = kbuf[slot]                # (kbp, Dp)
+                s = jax.lax.dot_general(
+                    q, k, (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale                     # (rows, kbp) f32
+                if masked:
+                    valid = j * kbp + jax.lax.broadcasted_iota(
+                        jnp.int32, (1, kbp), 1) < limit
+                    s = jnp.where(valid, s, NEG_INF)
+                m = m_ref[:rows]
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(s - m_new)
+                if masked:
+                    p = jnp.where(valid, p, 0.0)
+                l_ref[:rows] = alpha * l_ref[:rows] + jnp.sum(
+                    p, axis=1, keepdims=True)
+                acc_ref[:rows] = alpha * acc_ref[:rows] + jnp.dot(
+                    p.astype(k.dtype), k[:, :dl],
+                    preferred_element_type=jnp.float32)
+                m_ref[:rows] = m_new
+
+            # only a block that reaches past the first token's own
+            # position (or the row's length) pays the mask
+            frontier = (j + 1) * kbp > base + 1
+            pl.when(frontier)(functools.partial(block, True))
+            pl.when(jnp.logical_not(frontier))(
+                functools.partial(block, False))
+            return 0
+
+        jax.lax.fori_loop(0, nblk, body, 0)
+        l = l_ref[:rows]
+        obuf[:rows] = (
+            acc_ref[:rows] / jnp.where(l > 0.0, l, 1.0)).astype(obuf.dtype)
+        out = pltpu.make_async_copy(
+            obuf.at[pl.ds(0, rows)], out_hbm.at[pl.ds(at, rows)],
+            sem_o.at[0])
+        out.start()
+        out.wait()
+
+    @pl.when(q_len == 1)
+    def _decode_row():
+        walk(1, 0)
+
+    @pl.when(q_len > 1)
+    def _chunk_row():
+        def one(i, _):
+            walk(tq, i)
+            return 0
+
+        jax.lax.fori_loop(0, jax.lax.div(q_len + tq - 1, tq), one, 0)
+
+
+@functools.lru_cache(maxsize=64)
+def _build_latent(
+    r, pps, npages, t_tokens, g, dp, dl, page, q_dtype, scale, n_bufs,
+    interpret,
+):
+    """The latent walk's pallas_call: takes ``(table, kv_lens, q_lens,
+    q_starts, q (T·G, Dp), pool (npages, page, Dp))`` and returns
+    ``[out (T·G, d_latent)]``."""
+    q_dtype = jnp.dtype(q_dtype)
+    kb = max(1, LATENT_KV_BLOCK // page)
+    tq = LATENT_TQ
+    rows = tq * g
+    kernel = functools.partial(
+        _latent_kernel, scale, page, n_bufs, g, dl, kb, tq)
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=4,
+        grid=(r,),
+        in_specs=[any_, any_],                # q, latent pool
+        out_specs=[any_],
+        scratch_shapes=[
+            pltpu.VMEM((rows, dp), q_dtype),                 # qbuf
+            pltpu.VMEM((n_bufs, kb * page, dp), q_dtype),    # kbuf
+            pltpu.VMEM((rows, dl), q_dtype),                 # obuf
+            pltpu.SemaphoreType.DMA((1,)),                   # sem_q
+            pltpu.SemaphoreType.DMA((n_bufs, kb)),           # sem_k
+            pltpu.SemaphoreType.DMA((1,)),                   # sem_o
+            pltpu.VMEM((rows, 1), jnp.float32),              # m
+            pltpu.VMEM((rows, 1), jnp.float32),              # l
+            pltpu.VMEM((rows, dl), jnp.float32),             # acc
+        ],
+    )
+    # q/out blocks, the key buffers, softmax state (the (·, 1) columns
+    # pad to full lanes) and the (rows, kv block) score temporaries
+    total = (rows * (dp + dl) * q_dtype.itemsize
+             + n_bufs * kb * page * dp * q_dtype.itemsize
+             + rows * (dl + 2 * 128) * 4 + 4 * rows * kb * page * 4)
+    return shmem_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((t_tokens * g, dl), q_dtype)],
+        collective_id=None,
+        vmem_limit_bytes=(total + (8 << 20)) if total > (12 << 20) else None,
+        interpret=local_interpret() if interpret is None else interpret,
+        # a name of its own that a search for the kernel's still finds
+        name="ragged_paged_attention_latent",
+        # no state is carried from row to row
+        dimension_semantics=("arbitrary",),
+    )
+
+
+def _check_latent(latent, dp, k_scale, v_pool, window, selected,
+                  topologies, with_lse, soft_cap):
+    """What the latent walk is built for, refused by name."""
+    dl, dr = (int(x) for x in latent)
+    if dl < 1 or dr < 0 or dl + dr > dp:
+        raise ValueError(
+            f"ragged_paged_attention: latent=({dl}, {dr}) does not fit "
+            f"the pool's {dp} columns")
+    for name, on in (("int8 pools (k_scale)", k_scale is not None),
+                     ("a V pool", v_pool is not None),
+                     ("window", window is not None),
+                     ("selected", selected is not None),
+                     ("topologies", topologies is not None),
+                     ("with_lse", bool(with_lse)),
+                     ("soft_cap", soft_cap > 0.0)):
+        if on:
+            raise ValueError(
+                f"ragged_paged_attention: latent with {name} is not "
+                "built")
+    return dl
+
+
 def active_rows(q_lens):
     """``(order (R,), n (1,))``: the rows with ``q_lens > 0`` in
     ascending order, the rest of ``order`` repeating the last of them
@@ -966,7 +1189,8 @@ def auto_block_q(max_q_len: int, g: int) -> int:
 @functools.partial(
     jax.jit,
     static_argnames=("group", "scale", "soft_cap", "block_q", "n_bufs",
-                     "with_lse", "interpret", "window", "select_block"),
+                     "with_lse", "interpret", "window", "select_block",
+                     "latent"),
 )
 def ragged_paged_attention(
     q, k_pool, v_pool, kv_lens, q_lens, q_starts, block_table, *,
@@ -974,6 +1198,7 @@ def ragged_paged_attention(
     scale: float | None = None, soft_cap: float = 0.0, block_q: int = 8,
     n_bufs: int = 2, with_lse: bool = True, interpret=None,
     window: int | None = None, selected=None, select_block: int = 0,
+    latent: tuple | None = None,
 ):
     """Mixed prefill-chunk/decode attention over a shared page pool.
 
@@ -1010,6 +1235,17 @@ def ragged_paged_attention(
     (``ragged_paged_attention_selected``); bf16/f32 pools, no lse, no
     window, CAUSAL rows only (``topologies`` is not read).
 
+    ``latent`` (static): None is attention over K and V pools, today's
+    launch bit for bit. ``(d_latent, d_rope)`` is ABSORBED latent
+    attention (the layout notes above ``_latent_kernel``): ``k_pool``
+    ``(npages, 1, page, Dp)`` holds one entry a token for all ``group``
+    heads, ``v_pool`` is None, ``q`` is ``(1, T·G, Dp)`` and the result
+    ``(1, T·G, d_latent)``; ``scale`` is the caller's (the model's
+    softmax scale is not ``Dp``'s). A launch of its own
+    (``ragged_paged_attention_latent``) whose query blocks are cut by
+    tokens whatever ``block_q``; bf16/f32 pools, no lse, CAUSAL rows
+    only.
+
     Returns (out (Hkv, T·G, D) in q.dtype, lse (Hkv, T·G) f32 — None
     without ``with_lse``, which also drops the kernel's lse writes).
     Rows of dim 1 outside the per-row valid spans hold garbage (the
@@ -1018,6 +1254,26 @@ def ragged_paged_attention(
     hkv, tg, d = q.shape
     g = group
     npages, _, page, _ = k_pool.shape
+    if latent is not None:
+        dl = _check_latent(latent, d, k_scale, v_pool, window, selected,
+                           topologies, with_lse, soft_cap)
+        if scale is None:
+            raise ValueError(
+                "ragged_paged_attention: latent needs scale= (the "
+                "model's softmax scale)")
+        r, pps = block_table.shape
+        if g % 8 and not local_interpret(interpret):
+            raise ValueError(
+                f"ragged_paged_attention: latent needs group={g} to be "
+                "sublane-aligned (a multiple of 8) under Mosaic")
+        call = _build_latent(
+            r, pps, npages, tg // g, g, d, dl, page,
+            jnp.dtype(q.dtype).name, float(scale), n_bufs, interpret)
+        (out,) = call(
+            block_table.astype(jnp.int32), kv_lens.astype(jnp.int32),
+            q_lens.astype(jnp.int32), q_starts.astype(jnp.int32),
+            q[0], k_pool[:, 0])
+        return out[None], None
     if selected is not None:
         pages, counts, bits = selected
         r, pps = block_table.shape
@@ -1146,6 +1402,7 @@ def ragged_paged_attention_xla(
     q, k_pool, v_pool, kv_lens, q_lens, q_starts, block_table, *,
     group: int, topologies=None, k_scale=None, v_scale=None, scale=None,
     soft_cap=0.0, window=None, selected=None, select_block: int = 0,
+    latent=None,
 ):
     """Dense-XLA twin (correctness reference + degradation target):
     gather each row's pages into a contiguous cache and run the masked
@@ -1154,13 +1411,19 @@ def ragged_paged_attention_xla(
     and SHARED_PREFIX rows mask causally). Same signature/garbage-rows
     contract as :func:`ragged_paged_attention`. With ``selected`` the
     same block mask from the same bitmap (the page list is the
-    kernel's to walk: the twin gathers every page).
+    kernel's to walk: the twin gathers every page). With ``latent``
+    the same scores over the entries' columns and the values their
+    first ``d_latent``.
     """
     hkv, tg, d = q.shape
     g = group
     t_tokens = tg // g
     npages, _, page, _ = k_pool.shape
     r, pps = block_table.shape
+    if latent is not None:
+        dl = _check_latent(latent, d, k_scale, v_pool, window, selected,
+                           topologies, False, soft_cap)
+        v_pool = k_pool[..., :dl]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     if selected is not None:
@@ -1175,7 +1438,8 @@ def ragged_paged_attention_xla(
     safe = jnp.clip(block_table.astype(jnp.int32), 0, npages - 1)
     # (R, pps, Hkv, page, D) → (R, Hkv, pps·page, D)
     kc = k_pool[safe].transpose(0, 2, 1, 3, 4).reshape(r, hkv, -1, d)
-    vc = v_pool[safe].transpose(0, 2, 1, 3, 4).reshape(r, hkv, -1, d)
+    vc = v_pool[safe].transpose(0, 2, 1, 3, 4).reshape(
+        r, hkv, -1, v_pool.shape[-1])
     s_cap = pps * page
 
     # token t of the packed array belongs to row rt with position
@@ -1249,7 +1513,7 @@ def ragged_paged_attention_xla(
         NEG_INF,
     )
     return (
-        out.reshape(hkv, tg, d).astype(q.dtype),
+        out.reshape(hkv, tg, -1).astype(q.dtype),
         lse.reshape(hkv, tg),
     )
 

@@ -169,6 +169,46 @@ def minicpm_sala(**overrides) -> TransformerConfig:
     return TransformerConfig(**cfg)
 
 
+def dots_vlm1(**overrides) -> TransformerConfig:
+    """dots.vlm1's language model (rednote-hilab, ``model_type:
+    dots_vlm``; DeepSeek-V3's block) as published: 61 layers, hidden
+    7168, 128 heads of latent attention (MLA: query rank 1536, cached
+    latent 512 + one shared rotated key of 64, q.k head 128 + 64, value
+    head 128) with YaRN x 40 over 4096 positions, 3 dense layers (gated,
+    18432) then 58 sparse ones: 256 experts of width 2048 in 8 groups,
+    top-8 inside the best 4 groups by sigmoid score + selection bias,
+    weights renormalised and x 2.5, one shared expert; vocabulary
+    129280, untied head. Its multi-token-prediction module and its
+    vision encoder are not part of this forward pass and not stated
+    here.
+
+    The cut is five integers: ``n_layers`` with ``n_dense_layers`` of
+    them dense at the front, ``experts_held`` (with
+    ``first_expert_held``) one chip's share of an expert-parallel
+    layer, and ``vocab`` its slice of the vocabulary."""
+    n = int(overrides.pop("n_layers", 61))
+    dense = int(overrides.pop("n_dense_layers", 3))
+    if not 0 <= dense <= n:
+        raise ValueError(
+            f"dots_vlm1: n_dense_layers={dense} of n_layers={n}")
+    cfg = dict(
+        vocab=129280, n_layers=n, hidden=7168, ffn=2048, dense_ffn=18432,
+        n_heads=128, n_kv_heads=128, head_dim=192, norm_eps=1e-6,
+        kv_latent=512, q_latent=1536, qk_nope_dim=128, qk_rope_dim=64,
+        v_head_dim=128,
+        rope_theta=1e4, rope_yarn_factor=40.0, rope_yarn_original=4096,
+        rope_yarn_beta_fast=32.0, rope_yarn_beta_slow=1.0,
+        rope_mscale_all_dim=1.0,
+        gated_ffn=True,
+        moe="ep", moe_layers=tuple(range(dense, n)), num_experts=256,
+        topk=8, shared_experts=1, router="sigmoid_bias",
+        routed_scale=2.5, router_groups=8, router_topk_groups=4,
+        dtype=jnp.bfloat16,
+    )
+    cfg.update(overrides)
+    return TransformerConfig(**cfg)
+
+
 def tiny(preset=None, **overrides) -> TransformerConfig:
     """CI-sized twin: same topology knobs as ``preset`` (or dense
     defaults), tiny dims — what the tests and the driver dryrun use."""
@@ -221,6 +261,24 @@ def tiny(preset=None, **overrides) -> TransformerConfig:
             embed_scale=preset.embed_scale,
             residual_scale=preset.residual_scale,
             logit_divisor=preset.logit_divisor,
+            # the group-limited router of PR 35: at most four groups
+            # (two experts or more each), the kept ones holding topk
+            router_groups=min(preset.router_groups, 4),
+            router_topk_groups=min(preset.router_topk_groups, 2),
         )
+        if preset.kv_latent:
+            # latent attention at the twin's sizes: ranks 24 / 16,
+            # q.k head 8 + 4, value head 8, four heads; YaRN over 16
+            # positions at the preset's other numbers
+            cfg.update(
+                n_heads=4, n_kv_heads=4, head_dim=12,
+                kv_latent=16, q_latent=24, qk_nope_dim=8, qk_rope_dim=4,
+                v_head_dim=8,
+                rope_yarn_factor=min(preset.rope_yarn_factor, 4.0),
+                rope_yarn_original=min(preset.rope_yarn_original, 16),
+                rope_yarn_beta_fast=preset.rope_yarn_beta_fast,
+                rope_yarn_beta_slow=preset.rope_yarn_beta_slow,
+                rope_mscale_all_dim=preset.rope_mscale_all_dim,
+            )
     cfg.update(overrides)
     return TransformerConfig(**cfg)

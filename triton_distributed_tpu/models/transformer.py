@@ -196,6 +196,41 @@ class TransformerConfig:
     embed_scale: float = 1.0
     residual_scale: float = 1.0
     logit_divisor: float = 1.0
+    # ---- latent attention (MLA) and group-limited routing (PR 35);
+    # the defaults are the model above. ``serving_step`` implements
+    # them, ``forward`` raises on them by name.
+    # ``kv_latent`` > 0 (0 = K and V pages): every layer caches ONE
+    # entry a token, the RMS-normed latent ``c_kv`` (``kv_latent``
+    # values) and the rotated key ``k_pe`` (``qk_rope_dim``) that all
+    # heads share; a head's key is [c_kv W_kvb's key part (
+    # ``qk_nope_dim``) | k_pe], its value c_kv W_kvb's value part
+    # (``v_head_dim``); queries come through a latent of ``q_latent``
+    # with an RMS norm inside. ``head_dim`` is then the q.k head size
+    # ``qk_nope_dim + qk_rope_dim`` and ``n_kv_heads == n_heads``. The
+    # step attends ABSORBED: W_kvb's key part folded into the query,
+    # its value part applied after the walk over the latents
+    kv_latent: int = 0
+    q_latent: int = 0
+    qk_nope_dim: int = 0
+    qk_rope_dim: int = 0
+    v_head_dim: int = 0
+    # YaRN frequencies for the rotation (``rope_yarn_factor`` 1 = the
+    # plain ones): the ``qk_rope_dim`` / 2 frequencies of base
+    # ``rope_theta`` blended towards ``f / factor`` between the
+    # dimensions that turn ``beta_fast`` and ``beta_slow`` times in
+    # ``rope_yarn_original`` positions, and the softmax scale times
+    # ``(0.1 rope_mscale_all_dim ln(factor) + 1)^2``
+    rope_yarn_factor: float = 1.0
+    rope_yarn_original: int = 0
+    rope_yarn_beta_fast: float = 32.0
+    rope_yarn_beta_slow: float = 1.0
+    rope_mscale_all_dim: float = 0.0
+    # group-limited choice of the ``sigmoid_bias`` router (1, 1 = no
+    # limit): the experts lie in ``router_groups`` equal groups, a
+    # group scores the sum of its two largest biased scores, and the
+    # top-k is taken inside the ``router_topk_groups`` best groups
+    router_groups: int = 1
+    router_topk_groups: int = 1
 
     def __post_init__(self):
         if self.attn not in ("tp", "ring", "ulysses"):
@@ -261,7 +296,10 @@ class TransformerConfig:
             raise ValueError(
                 f"window={self.window} and layer_attn's sliding layers "
                 f"{self.window_layers} go together")
-        if bool(self.rope_layers) != (self.rope_theta > 0) or any(
+        # (a latent model rotates its decoupled halves on every layer:
+        # rope_theta is its base, rope_layers stays empty)
+        if bool(self.rope_layers) != (
+                self.rope_theta > 0 and not self.kv_latent) or any(
                 not 0 <= i < self.n_layers for i in self.rope_layers):
             raise ValueError(
                 f"rope_layers={self.rope_layers!r} need rope_theta > 0 "
@@ -346,6 +384,69 @@ class TransformerConfig:
         if self.out_norm and not self.lightning_layers:
             raise ValueError("out_norm is the lightning layers' output "
                              "norm: no lightning layer in layer_mixer")
+        latent = ("kv_latent", "q_latent", "qk_nope_dim", "qk_rope_dim",
+                  "v_head_dim")
+        if self.kv_latent:
+            if min(getattr(self, f) for f in latent) < 1 \
+                    or self.qk_rope_dim % 2 or self.rope_theta <= 0 \
+                    or self.head_dim != self.qk_nope_dim + self.qk_rope_dim \
+                    or self.n_kv_heads != self.n_heads:
+                raise ValueError(
+                    f"kv_latent={self.kv_latent} needs {', '.join(latent)} "
+                    ">= 1, an even qk_rope_dim, rope_theta > 0, head_dim "
+                    "= qk_nope_dim + qk_rope_dim and n_kv_heads = n_heads "
+                    f"(got {[getattr(self, f) for f in latent]}, head_dim="
+                    f"{self.head_dim}, rope_theta={self.rope_theta}, "
+                    f"n_kv_heads={self.n_kv_heads})")
+            beside = [k for k, on in (
+                ("kv_quant", self.kv_quant is not None),
+                ("sliding-window layers (layer_attn)",
+                 bool(self.window_layers)),
+                ("lightning layers (layer_mixer)",
+                 bool(self.lightning_layers)),
+                ("block-sparse attention (sparse_topk)",
+                 self.sparse_topk > 0),
+                ("rope_layers", bool(self.rope_layers)),
+                ("qk_norm", self.qk_norm),
+                ("out_gate", self.out_gate),
+                ("dense_weight_quant",
+                 self.dense_weight_quant is not None),
+                (f"attn={self.attn!r}", self.attn != "tp"),
+            ) if on]
+            if beside:
+                raise ValueError(
+                    f"kv_latent={self.kv_latent} with "
+                    f"{', '.join(beside)}: a latent pool holds one "
+                    "bf16 entry a token for every head and is walked "
+                    "whole by every layer; not built beside these")
+        elif any(getattr(self, f) for f in latent):
+            raise ValueError(
+                f"{', '.join(f for f in latent if getattr(self, f))} "
+                "without kv_latent")
+        yarn = self.rope_yarn_factor != 1.0
+        if self.rope_yarn_factor < 1.0 or (yarn and (
+                not self.kv_latent or self.rope_yarn_original < 1
+                or self.rope_yarn_beta_fast <= self.rope_yarn_beta_slow)):
+            raise ValueError(
+                f"rope_yarn_factor={self.rope_yarn_factor} must be >= 1 "
+                "and, past 1, needs kv_latent (the rotation it is built "
+                "into), rope_yarn_original >= 1 and rope_yarn_beta_fast "
+                "> rope_yarn_beta_slow")
+        g, kg = self.router_groups, self.router_topk_groups
+        if g < 1 or not 1 <= kg <= g:
+            raise ValueError(
+                f"router_groups={g}, router_topk_groups={kg}: need 1 <= "
+                "router_topk_groups <= router_groups")
+        if g > 1 and (self.router != "sigmoid_bias"
+                      or self.num_experts % g
+                      or self.num_experts // g < 2
+                      or kg * (self.num_experts // g) < self.topk):
+            raise ValueError(
+                f"router_groups={g} is the sigmoid_bias router's "
+                f"(got router={self.router!r}); the groups divide "
+                f"num_experts={self.num_experts} into two experts or "
+                f"more each, and router_topk_groups={kg} of them hold "
+                f"topk={self.topk}")
 
     @property
     def window_layers(self) -> tuple:
@@ -415,7 +516,56 @@ class TransformerConfig:
             ("embed_scale", self.embed_scale != 1.0),
             ("residual_scale", self.residual_scale != 1.0),
             ("logit_divisor", self.logit_divisor != 1.0),
+            ("kv_latent", self.kv_latent > 0),
+            ("router_groups", self.router_groups > 1),
         ) if on)
+
+    @property
+    def latent_width(self) -> int:
+        """Values a latent pool NEEDS a token and layer: ``c_kv`` and
+        the shared rotated key."""
+        return self.kv_latent + self.qk_rope_dim
+
+    @property
+    def latent_stored(self) -> int:
+        """Values a latent pool STORES a token and layer: the entry
+        padded with zeros to whole 128-lane tiles (one array a page:
+        one DMA, one score product; a (page, qk_rope_dim) array of its
+        own would occupy a whole lane tile all the same)."""
+        return -(-self.latent_width // 128) * 128
+
+    @property
+    def yarn_inv_freq(self):
+        """The rotation's ``qk_rope_dim / 2`` inverse frequencies,
+        float32 (numpy): base ``rope_theta``, YaRN-blended where
+        ``rope_yarn_factor`` > 1."""
+        dim, base = self.qk_rope_dim, float(self.rope_theta)
+        f = base ** (-np.arange(0, dim, 2, dtype=np.float64) / dim)
+        factor = float(self.rope_yarn_factor)
+        if factor == 1.0:
+            return f.astype(np.float32)
+
+        def correction_dim(turns):
+            return dim * np.log(self.rope_yarn_original
+                                / (turns * 2 * np.pi)) / (2 * np.log(base))
+
+        lo = max(np.floor(correction_dim(self.rope_yarn_beta_fast)), 0)
+        hi = min(np.ceil(correction_dim(self.rope_yarn_beta_slow)),
+                 dim - 1)
+        ramp = np.clip(
+            (np.arange(dim // 2, dtype=np.float64) - lo)
+            / max(hi - lo, 1e-3), 0, 1)
+        return (f * (1 - ramp) + f / factor * ramp).astype(np.float32)
+
+    @property
+    def latent_softmax_scale(self) -> float:
+        """``head_dim^-0.5 m^2``, ``m = 0.1 rope_mscale_all_dim
+        ln(rope_yarn_factor) + 1`` (1 without YaRN)."""
+        m = 1.0
+        if self.rope_yarn_factor > 1.0 and self.rope_mscale_all_dim:
+            m = 0.1 * self.rope_mscale_all_dim * float(
+                np.log(self.rope_yarn_factor)) + 1.0
+        return float(self.head_dim ** -0.5 * m * m)
 
     @property
     def q_dim(self) -> int:
@@ -428,6 +578,15 @@ class TransformerConfig:
     @property
     def qkv_dim(self) -> int:
         return self.q_dim + 2 * self.kv_dim
+
+
+def _rotate_half(x, cos, sin):
+    """``x`` (T, heads, d) rotated by the tables of
+    ``Transformer._rope_tables``, in float32, back in ``x``'s dtype."""
+    xf = x.astype(jnp.float32)
+    x1, x2 = jnp.split(xf, 2, axis=-1)
+    rot = jnp.concatenate([-x2, x1], axis=-1)
+    return (xf * cos + rot * sin).astype(x.dtype)
 
 
 @dataclass(frozen=True)
@@ -469,6 +628,12 @@ class Transformer:
                     "recurrent state, the compressed keys and the "
                     "selection are one chip's; sharding them is not "
                     "built")
+            if c.kv_latent and n > 1:
+                raise ValueError(
+                    f"a latent pool (kv_latent) with {axis}={n}: the "
+                    "latents are ONE head's, which a head-sharded pool "
+                    "cannot split and the cp shard walk is not built "
+                    "over")
 
     def _plain_only(self) -> None:
         """``forward`` runs the plain architecture; refuse, by name, a
@@ -630,8 +795,10 @@ class Transformer:
 
     def init(self, key):
         c = self.config
-        keys = iter(jax.random.split(key, 4 + 8 * c.n_layers))
-        # (a layer with every new field draws 8 keys: the split holds)
+        keys = iter(jax.random.split(
+            key, 4 + (11 if c.kv_latent else 8) * c.n_layers))
+        # (a layer with every field of PR 33 draws 8 keys, a latent
+        # layer 11: the split holds)
         pd = c.param_dtype
         s = 1.0 / (c.hidden ** 0.5)
 
@@ -655,10 +822,31 @@ class Transformer:
             blk = {
                 "norm_attn": jnp.ones((c.hidden,), pd),
                 "norm_mlp": jnp.ones((c.hidden,), pd),
-                "wqkv": dense(next(keys),
-                              (c.hidden, qd + 2 * hkv * c.head_dim)),
-                "wo": dense(next(keys), (qd, c.hidden)),
             }
+            if c.kv_latent:
+                # latent attention: two low-rank paths with a norm
+                # inside each, W_kvb = per head [key part | value part]
+                od = hq * c.v_head_dim
+                blk.update(
+                    wq_a=dense(next(keys), (c.hidden, c.q_latent)),
+                    norm_qa=jnp.ones((c.q_latent,), pd),
+                    wq_b=dense(next(keys), (c.q_latent, qd),
+                               c.q_latent ** -0.5),
+                    wkv_a=dense(next(keys), (c.hidden, c.latent_width)),
+                    norm_kva=jnp.ones((c.kv_latent,), pd),
+                    wkv_b=dense(
+                        next(keys),
+                        (c.kv_latent,
+                         hq * (c.qk_nope_dim + c.v_head_dim)),
+                        c.kv_latent ** -0.5),
+                    wo=dense(next(keys), (od, c.hidden), od ** -0.5),
+                )
+            else:
+                blk.update(
+                    wqkv=dense(next(keys),
+                               (c.hidden, qd + 2 * hkv * c.head_dim)),
+                    wo=dense(next(keys), (qd, c.hidden)),
+                )
             if c.qk_norm:
                 blk["norm_q"] = jnp.ones((c.head_dim,), pd)
                 blk["norm_k"] = jnp.ones((c.head_dim,), pd)
@@ -888,6 +1076,11 @@ class Transformer:
             else:
                 # CP attention: projections replicated, sequence sharded
                 attn_sh = {"wqkv": rep, "wo": rep}
+            if c.kv_latent:
+                # one chip's (tp > 1 is refused): every leaf whole
+                attn_sh = dict.fromkeys(
+                    ("wq_a", "norm_qa", "wq_b", "wkv_a", "norm_kva",
+                     "wkv_b", "wo"), rep)
             blk = {
                 "norm_attn": rep, "norm_mlp": rep, **attn_sh,
             }
@@ -1134,7 +1327,8 @@ class Transformer:
                 if c.router == "sigmoid_bias":
                     w, ids = mu.select_experts_sigmoid_bias(
                         logits, blk["router_bias"], c.topk,
-                        scale=c.routed_scale)
+                        scale=c.routed_scale, groups=c.router_groups,
+                        topk_groups=c.router_topk_groups)
                 else:
                     w, ids = mu.select_experts(logits, c.topk)
                 logits = mu.held_assignments(
@@ -1220,24 +1414,19 @@ class Transformer:
                     "layers needs chunk= (it sizes their ring pools)")
             ring = ring_pages(int(chunk), c.window, page)
 
-        def pools(n):
+        def pools(n, heads=c.n_kv_heads, width=c.head_dim):
             """``(make, ...)``: one template of an n-page pool on the
             device; every call of ``make`` an independent buffer (the
             step jit donates each leaf)."""
             if c.kv_quant is not None:
                 zq = jax.device_put(
-                    jnp.zeros((n, c.n_kv_heads, page, c.head_dim),
-                              jnp.int8),
-                    spec,
-                )
+                    jnp.zeros((n, heads, page, width), jnp.int8), spec)
                 zs = jax.device_put(
-                    jnp.ones((n, c.n_kv_heads, page), jnp.float32), spec
+                    jnp.ones((n, heads, page), jnp.float32), spec
                 )
                 return lambda: {"q": zq + jnp.int8(0), "scale": zs + 0.0}
             z = jax.device_put(
-                jnp.zeros((n, c.n_kv_heads, page, c.head_dim), c.dtype),
-                spec,
-            )
+                jnp.zeros((n, heads, page, width), c.dtype), spec)
             zero = jnp.zeros((), c.dtype)
             return lambda: z + zero
 
@@ -1249,9 +1438,15 @@ class Transformer:
                 f"sparse_block={c.sparse_block} and sparse_stride="
                 f"{c.sparse_stride} divide and that divides "
                 f"sparse_dense_len={c.sparse_dense_len}, got page={page}")
-        full = pools(npages)
+        # a latent pool: ONE entry a token and layer for all the heads,
+        # ``(npages, 1, page, latent_stored)``, addressed by the same
+        # block table; no V pool (serving/state.py)
+        full = pools(npages, 1, c.latent_stored) if c.kv_latent \
+            else pools(npages)
         recurrent = ckeys = ()
-        if lightning or sparse:
+        if c.kv_latent:
+            layers = tuple((full(), None) for _ in range(c.n_layers))
+        elif lightning or sparse:
             # a lightning layer keeps a state a slot and no pages; a
             # sparse layer a pool of compressed keys beside K/V
             # (serving/state.py). Each leaf its own buffer (donated)
@@ -1314,14 +1509,18 @@ class Transformer:
         )
 
     def _rope_tables(self, token_pos):
-        """``(cos, sin)``, each (T, 1, head_dim) float32, of the packed
-        tokens' sequence positions (padding tokens: position 0) at
-        ``config.rope_theta``, the two halves of the head alike
-        (rotate-half)."""
+        """``(cos, sin)``, each (T, 1, rotated dims) float32, of the
+        packed tokens' sequence positions (padding tokens: position 0),
+        the two halves alike (rotate-half): over ``head_dim`` at
+        ``config.rope_theta``, or, in a latent model, over
+        ``qk_rope_dim`` at the (YaRN) ``config.yarn_inv_freq``."""
         c = self.config
-        half = c.head_dim // 2
-        inv_freq = c.rope_theta ** (
-            -jnp.arange(half, dtype=jnp.float32) / half)
+        if c.kv_latent:
+            inv_freq = jnp.asarray(c.yarn_inv_freq)
+        else:
+            half = c.head_dim // 2
+            inv_freq = c.rope_theta ** (
+                -jnp.arange(half, dtype=jnp.float32) / half)
         ang = jnp.maximum(token_pos, 0).astype(jnp.float32)[:, None] \
             * inv_freq[None, :]
         ang = jnp.concatenate([ang, ang], axis=-1)[:, None, :]
@@ -1340,14 +1539,81 @@ class Transformer:
             if c.qk_norm:
                 x = self._rmsnorm(x, blk[gain])
             if rope is not None:
-                cos, sin = rope
-                xf = x.astype(jnp.float32)
-                x1, x2 = jnp.split(xf, 2, axis=-1)
-                rot = jnp.concatenate([-x2, x1], axis=-1)
-                x = (xf * cos + rot * sin).astype(x.dtype)
+                x = _rotate_half(x, *rope)
             return x.reshape(t, -1)
 
         return one(q, "norm_q"), one(k, "norm_k")
+
+    def _latent_qkv(self, blk, xn, rope):
+        """The latent layer's projections of normed rows ``xn`` (T, H):
+        ``(q (T, heads · latent_stored), entry (T, 1, latent_stored))``
+        in the compute dtype. ``q`` is ABSORBED, per head [q_nope
+        W_kvb's key part^T (kv_latent) | rotated q_pe | zeros], and
+        ``entry`` what the pool caches, [rmsnorm(c_kv) | rotated k_pe |
+        zeros]: a head's score is their dot product."""
+        c = self.config
+        t = xn.shape[0]
+        h, dn, dr, dl = c.n_heads, c.qk_nope_dim, c.qk_rope_dim, c.kv_latent
+        with jax.named_scope("mla_lowrank"):
+            cq = self._rmsnorm(self._dmm(xn, blk["wq_a"]), blk["norm_qa"])
+            q = self._dmm(cq, blk["wq_b"]).reshape(t, h, dn + dr)
+            kv = self._dmm(xn, blk["wkv_a"])                  # (T, dl + dr)
+            ckv = self._rmsnorm(kv[:, :dl], blk["norm_kva"])
+        with jax.named_scope("qk_rope"):
+            q_pe = _rotate_half(q[..., dn:], *rope)           # (T, h, dr)
+            k_pe = _rotate_half(kv[:, None, dl:], *rope)      # (T, 1, dr)
+        pad = c.latent_stored - c.latent_width
+        with jax.named_scope("mla_absorb"):
+            wk = blk["wkv_b"].astype(c.dtype).reshape(
+                dl, h, dn + c.v_head_dim)[..., :dn]
+            qa = jnp.einsum("thn,lhn->thl", q[..., :dn], wk,
+                            preferred_element_type=jnp.float32)
+            qf = jnp.concatenate(
+                [qa.astype(c.dtype), q_pe,
+                 jnp.zeros((t, h, pad), c.dtype)], axis=-1)
+        entry = jnp.concatenate(
+            [ckv[:, None, :], k_pe, jnp.zeros((t, 1, pad), c.dtype)],
+            axis=-1)
+        return qf.reshape(t, h * c.latent_stored), entry
+
+    def _latent_out(self, blk, o):
+        """The walk's output ``o`` (T · heads, kv_latent), each head's
+        softmax-weighted latents, through W_kvb's value part: (T,
+        heads · v_head_dim)."""
+        c = self.config
+        h, dn, dv = c.n_heads, c.qk_nope_dim, c.v_head_dim
+        with jax.named_scope("mla_absorb"):
+            wv = blk["wkv_b"].astype(c.dtype).reshape(
+                c.kv_latent, h, dn + dv)[..., dn:]
+            y = jnp.einsum("thl,lhv->thv",
+                           o.reshape(-1, h, c.kv_latent), wv,
+                           preferred_element_type=jnp.float32)
+        return y.astype(c.dtype).reshape(-1, h * dv)
+
+    def _latent_mix(self, qf, entry, pool, state, append, q_lens,
+                    q_starts, block_q, use_pallas, n_bufs):
+        """A latent layer's append and walk: the step's entries into
+        the layer's pool, then every head's absorbed attention through
+        it. Returns ``(o (T · heads, kv_latent), pool)``."""
+        from triton_distributed_tpu.kernels.ragged_paged_attention import (
+            ragged_paged_attention,
+            ragged_paged_attention_xla,
+        )
+
+        c = self.config
+        with jax.named_scope("kv_append"):
+            pool = append(pool, entry.astype(pool.dtype))
+        with jax.named_scope("attn"):
+            attend = (ragged_paged_attention if use_pallas
+                      else ragged_paged_attention_xla)
+            kw = dict(block_q=block_q, n_bufs=n_bufs, with_lse=False) \
+                if use_pallas else {}
+            o, _ = attend(
+                qf.reshape(1, -1, c.latent_stored), pool, None,
+                state.kv_lens, q_lens, q_starts, state.block_table,
+                group=c.n_heads, scale=c.latent_softmax_scale,
+                latent=(c.kv_latent, c.qk_rope_dim), **kw)
+        return o[0], pool
 
     def _cp_ragged_attn(self, qp, kp, vp, state, q_lens, q_starts,
                         block_q, use_pallas, n_bufs, topologies):
@@ -1594,6 +1860,45 @@ class Transformer:
             check_vma=False,
         ))
 
+    @functools.cached_property
+    def _latent_append_kernel(self):
+        """``(units, pool, new) -> pool``: one latent layer's append
+        through ``kernels/kv_append`` (its one-pool launch), under one
+        jit so that a step's layers trace and lower it once."""
+        from triton_distributed_tpu.kernels.kv_append import kv_append
+
+        return jax.jit(
+            lambda units, pool, new: kv_append(
+                units, pool, None, new, None)[0])
+
+    def _latent_appender(self, state, token_rows, token_pos, q_starts,
+                         q_lens, use_pallas):
+        """``(pool, entries (T, 1, latent_stored)) -> pool`` for the
+        step: the kernel over the step's (slot, page) runs, or its XLA
+        twin, the row scatter (``use_pallas=False``)."""
+        from triton_distributed_tpu.kernels.kv_append import (
+            append_rows_xla,
+            append_units,
+        )
+
+        t, page = token_pos.shape[0], state.page
+        if self.kv_append_by_kernel(use_pallas):
+            return functools.partial(
+                self._latent_append_kernel,
+                append_units(
+                    q_starts, q_lens,
+                    token_pos[jnp.clip(q_starts, 0, t - 1)],
+                    state.block_table, page=page, t=t))
+        pos_c = jnp.maximum(token_pos, 0)
+        local_page = state.block_table[
+            jnp.clip(token_rows, 0, state.slots - 1),
+            jnp.clip(pos_c // page, 0, state.pages_per_seq - 1)]
+        # padding tokens and unallocated entries land past the last row
+        pid = jnp.where((token_pos >= 0) & (local_page >= 0), local_page,
+                        state.npages)
+        rows = pid * page + pos_c % page
+        return lambda pool, new: append_rows_xla(pool, new[:, 0], rows)
+
     def serving_step(self, params, state, tokens, token_rows, token_pos,
                      q_starts, q_lens, topologies=None, moe_state=None, *,
                      block_q: int = 8, use_pallas: bool = True,
@@ -1753,12 +2058,19 @@ class Transformer:
         with scope("kv_append"):
             # one description of the step's append per KIND of pool:
             # the global layers' by the block table, the window layers'
-            # by the ring table (same kernel, same packing contract)
-            kv_shape, append_global = appender(state.block_table, npages)
+            # by the ring table (same kernel, same packing contract),
+            # a latent model's by the block table, one stream
+            if c.kv_latent:
+                append_latent = self._latent_appender(
+                    state, token_rows, token_pos, q_starts, q_lens,
+                    use_pallas)
+            else:
+                kv_shape, append_global = appender(
+                    state.block_table, npages)
             if windowed:
                 _, append_ring = appender(
                     state.ring_table, state.slots * state.ring)
-        if c.rope_layers:
+        if c.rope_layers or c.kv_latent:
             with scope("attn_proj"), scope("qk_rope"):
                 rope = self._rope_tables(token_pos)
 
@@ -1771,37 +2083,51 @@ class Transformer:
             zip(params["blocks"], state.layers)
         ):
             hq, hkv = c.layer_heads(li)
-            with scope("attn_proj"):
-                xn = self._rmsnorm(x, blk["norm_attn"])
-                qkv = self._dmm(xn, blk["wqkv"], shard=qkv_sh)  # (T, qkv)
-                q, k, v = jnp.split(
-                    qkv, [hq * c.head_dim, (hq + hkv) * c.head_dim],
-                    axis=-1,
-                )
-                if c.qk_norm or li in c.rope_layers:
-                    with scope("qk_rope"):
-                        q, k = self._qk_norm_rope(
-                            blk, q, k,
-                            rope if li in c.rope_layers else None)
-            if li in lightning:
-                new_layers.append(None)
-                with scope("attn"), scope("linear_attn"):
-                    o, new_recurrent[li] = self._lightning_mix(
-                        q, k, v, state, li, q_lens, q_starts, block_q,
-                        use_pallas)
+            if c.kv_latent:
+                # latent attention: project (absorbed), append the
+                # step's entries, walk them, un-absorb
+                with scope("attn_proj"):
+                    xn = self._rmsnorm(x, blk["norm_attn"])
+                    qf, entry = self._latent_qkv(blk, xn, rope)
+                o, pool = self._latent_mix(
+                    qf, entry, pools[0], state, append_latent, q_lens,
+                    q_starts, block_q, use_pallas, n_bufs)
+                new_layers.append((pool, None))
+                with scope("attn_proj"):
+                    x = add(x, self._dmm(self._latent_out(blk, o),
+                                         blk["wo"]))
             else:
-                o, kp, vp = self._attention_mix(
-                    li, q, k, v, pools, state, kv_shape, append_global,
-                    append_ring if li in windowed else None, token_rows,
-                    token_pos, q_lens, q_starts, topologies, block_q,
-                    use_pallas, n_bufs, new_ckeys)
-                new_layers.append((kp, vp))
-            with scope("attn_proj"):
-                if c.out_gate:
-                    with scope("out_gate"):
-                        o = self._gate_out(blk, o, xn, hq)
-                x = add(x, self._dmm(o.astype(c.dtype), blk["wo"],
-                                     shard=wo_sh))
+                with scope("attn_proj"):
+                    xn = self._rmsnorm(x, blk["norm_attn"])
+                    qkv = self._dmm(xn, blk["wqkv"], shard=qkv_sh)  # (T, qkv)
+                    q, k, v = jnp.split(
+                        qkv, [hq * c.head_dim, (hq + hkv) * c.head_dim],
+                        axis=-1,
+                    )
+                    if c.qk_norm or li in c.rope_layers:
+                        with scope("qk_rope"):
+                            q, k = self._qk_norm_rope(
+                                blk, q, k,
+                                rope if li in c.rope_layers else None)
+                if li in lightning:
+                    new_layers.append(None)
+                    with scope("attn"), scope("linear_attn"):
+                        o, new_recurrent[li] = self._lightning_mix(
+                            q, k, v, state, li, q_lens, q_starts, block_q,
+                            use_pallas)
+                else:
+                    o, kp, vp = self._attention_mix(
+                        li, q, k, v, pools, state, kv_shape, append_global,
+                        append_ring if li in windowed else None, token_rows,
+                        token_pos, q_lens, q_starts, topologies, block_q,
+                        use_pallas, n_bufs, new_ckeys)
+                    new_layers.append((kp, vp))
+                with scope("attn_proj"):
+                    if c.out_gate:
+                        with scope("out_gate"):
+                            o = self._gate_out(blk, o, xn, hq)
+                    x = add(x, self._dmm(o.astype(c.dtype), blk["wo"],
+                                         shard=wo_sh))
             if "up" in blk:
                 with scope("dense_ffn"):
                     xn = self._rmsnorm(x, blk["norm_mlp"])

@@ -333,6 +333,17 @@ class EngineStats:
     selected_pages_walked: int = 0
     sparse_rows: int = 0
     state_rows: int = 0
+    # the work of a model with a LATENT pool (``kv_latent``; 0 without
+    # one), summed over the batched rows of the device steps, counted
+    # in ``_assemble``: pages ONE layer's latent walk fetches (a decode
+    # row every page it holds, once; a row of more tokens once per
+    # query block of ``LATENT_TQ`` tokens, each block walking up to its
+    # own last position), rows batched through the walk, and rows of
+    # more than one token attended EXPANDED (keys and values
+    # up-projected from the cached latents; 0: every row runs absorbed)
+    latent_pages_walked: int = 0
+    latent_rows: int = 0
+    chunk_rows_expanded: int = 0
     # packed rows of the device steps: the sum of their widths (each
     # step's follows its ``block_q`` rung, ``ServingEngine._width``)
     packed_rows: int = 0
@@ -574,6 +585,106 @@ def packed_width(block_q: int, slots: int, token_budget: int,
     return token_budget + block_q_cap
 
 
+# ---------------------------------------------- kind of state x feature
+#
+# A model's layers may keep, beside or in place of plain K/V pages, a
+# RING pool (sliding-window layers), a RECURRENT state a slot with
+# compressed keys and a selection (lightning layers, block-sparse
+# attention), or a LATENT pool (``kv_latent``: one entry a token for
+# every head). What would read, keep, roll back or ship a second copy
+# of such state is not built for it; ``REFUSED[kind][feature]`` is the
+# reason, raised by name where the feature is asked for (a pair that
+# is absent is served). ``{what}`` is the kind's name in the model.
+
+REFUSED = {
+    "window": {
+        "prefix_cache":
+            "{what} with prefix_cache=True (and with it prefix_share / "
+            "SHARED_PREFIX rows): a cached prefix page of a window "
+            "layer is overwritten by its ring",
+        "speculation":
+            "{what} under SpeculativeEngine: a rejected draft rolls "
+            "the cursor back over ring pages the draft has already "
+            "overwritten",
+        "prefill_only":
+            "{what} with prefill_only (the prefill role of "
+            "DisaggregatedEngine): kv_ship ships pages by the global "
+            "block table, which does not address a ring",
+        "gather_pages":
+            "kv_ship / page migration with {what}: pages ship by the "
+            "global block table, which does not address a ring pool",
+        "disaggregated":
+            "DisaggregatedEngine with {what}: kv_ship ships pages by "
+            "the global block table, which does not address a ring "
+            "pool",
+    },
+    "recurrent": {
+        "prefix_cache":
+            "{what} with prefix_cache / prefix_share: a request that "
+            "reattaches cached pages skips the positions that built "
+            "the recurrent state (it needs state snapshots)",
+        "speculation":
+            "{what} under SpeculativeEngine: a rejected draft needs a "
+            "rollback of the recurrent state",
+        "prefill_only":
+            "{what} with prefill_only (the prefill role of "
+            "DisaggregatedEngine): kv_ship ships pages, not the "
+            "recurrent state or the compressed keys",
+        "gather_pages":
+            "kv_ship / page migration with {what}: pages ship, the "
+            "recurrent state and the compressed keys do not",
+        "disaggregated":
+            "DisaggregatedEngine with {what}: kv_ship ships pages, not "
+            "the recurrent state or the compressed keys",
+    },
+    "latent": {
+        "prefix_cache":
+            "{what} with prefix_cache / prefix_share: the latent walk "
+            "reads CAUSAL rows only (no SHARED_PREFIX topology) and a "
+            "latent page under a second owner is not tested",
+        "speculation":
+            "{what} under SpeculativeEngine: the latent walk has no "
+            "verify-tree topology",
+        "prefill_only":
+            "{what} with prefill_only (the prefill role of "
+            "DisaggregatedEngine): kv_ship's wire layout is K and V "
+            "pages, a latent pool has one array and no V",
+        "gather_pages":
+            "kv_ship / page migration with {what}: the wire layout is "
+            "K and V pages, a latent pool has one array and no V",
+        "disaggregated":
+            "DisaggregatedEngine with {what}: kv_ship's wire layout is "
+            "K and V pages, a latent pool has one array and no V",
+    },
+}
+
+
+def state_kinds(mc) -> dict:
+    """``{kind: its name in a message}`` of the kinds of state (the
+    keys of ``REFUSED``) that the layers of model config ``mc`` keep."""
+    kinds = {}
+    if mc.window_layers:
+        kinds["window"] = "sliding-window layers"
+    stateful = [name for name, on in (
+        ("lightning layers (layer_mixer)", bool(mc.lightning_layers)),
+        ("block-sparse attention (sparse_topk)", mc.sparse_topk > 0),
+    ) if on]
+    if stateful:
+        kinds["recurrent"] = ", ".join(stateful)
+    if mc.kv_latent:
+        kinds["latent"] = "a latent pool (kv_latent)"
+    return kinds
+
+
+def refuse(kinds: dict, feature: str) -> None:
+    """Raise ``REFUSED``'s reason if one of ``kinds`` (``state_kinds``)
+    cannot serve ``feature``."""
+    for kind, what in kinds.items():
+        why = REFUSED[kind].get(feature)
+        if why is not None:
+            raise ValueError(why.format(what=what))
+
+
 class ServingEngine:
     """The scheduler. Owns the host mirrors (free list, block table,
     lengths, cursors) and the device :class:`ServingState`; every
@@ -633,21 +744,17 @@ class ServingEngine:
         self.health = health if health is not None else HealthLedger(
             seed=cfg.seed)
         self.health_peer = health_peer
-        # sliding-window layers keep ring pools (serving/state.py); what
-        # a ring cannot serve yet is refused here, by name
-        self._window = int(model.config.window) \
-            if model.config.window_layers else 0
-        if self._window:
-            self._refuse_beside_window(cfg)
-        # lightning layers keep a recurrent state a slot, sparse layers
-        # compressed keys and a selection: likewise
+        # what a kind of state beside plain K/V pages cannot serve yet
+        # is refused here, by name (``REFUSED``)
         mc = model.config
-        self._stateful = tuple(k for k, on in (
-            ("lightning layers (layer_mixer)", bool(mc.lightning_layers)),
-            ("block-sparse attention (sparse_topk)", mc.sparse_topk > 0),
-        ) if on)
-        if self._stateful:
-            self._refuse_beside_state(cfg)
+        self._window = int(mc.window) if mc.window_layers else 0
+        self._kinds = state_kinds(mc)
+        for feature, on in (
+                ("prefix_cache", cfg.prefix_cache or cfg.prefix_share),
+                ("speculation", self._spec_key() != (0, 0)),
+                ("prefill_only", cfg.prefill_only)):
+            if on:
+                refuse(self._kinds, feature)
         self.state = model.init_serving_state(
             cfg.slots, cfg.npages, cfg.page, chunk=cfg.chunk
         )
@@ -714,6 +821,7 @@ class ServingEngine:
         self._pages_walked = [0, 0]     # likewise: [global, window]
         # likewise: [selected pages, sparse rows, state rows]
         self._state_work = [0, 0, 0]
+        self._latent_work = [0, 0]      # likewise: [pages fetched, rows]
         # seconds of the running step inside each phase (``_Phase``)
         self._phase_s = dict.fromkeys(PHASES, 0.0)
         # --- multi-tenancy (all defaults reproduce the single-tenant
@@ -812,48 +920,6 @@ class ServingEngine:
                 "topology row with its per-shard frontier shift"
             )
 
-    def _refuse_beside_window(self, cfg) -> None:
-        """A ring pool holds a slot's last ``chunk + window`` positions
-        and nothing else: what would read or keep an older page of a
-        window layer is not built, and raises here."""
-        if cfg.prefix_cache:
-            raise ValueError(
-                "sliding-window layers with prefix_cache=True (and with "
-                "it prefix_share / SHARED_PREFIX rows): a cached prefix "
-                "page of a window layer is overwritten by its ring")
-        if self._spec_key() != (0, 0):
-            raise ValueError(
-                "sliding-window layers under SpeculativeEngine: a "
-                "rejected draft rolls the cursor back over ring pages "
-                "the draft has already overwritten")
-        if cfg.prefill_only:
-            raise ValueError(
-                "sliding-window layers with prefill_only (the prefill "
-                "role of DisaggregatedEngine): kv_ship ships pages by "
-                "the global block table, which does not address a ring")
-
-    def _refuse_beside_state(self, cfg) -> None:
-        """A recurrent state is one matrix a slot, rebuilt only by a
-        recompute from position 0, and a block selection is made per
-        step from one slot's compressed keys: what would need a
-        snapshot, a rollback or a shipment of either is not built, and
-        raises here."""
-        what = ", ".join(self._stateful)
-        if cfg.prefix_cache or cfg.prefix_share:
-            raise ValueError(
-                f"{what} with prefix_cache / prefix_share: a request "
-                "that reattaches cached pages skips the positions that "
-                "built the recurrent state (it needs state snapshots)")
-        if self._spec_key() != (0, 0):
-            raise ValueError(
-                f"{what} under SpeculativeEngine: a rejected draft "
-                "needs a rollback of the recurrent state")
-        if cfg.prefill_only:
-            raise ValueError(
-                f"{what} with prefill_only (the prefill role of "
-                "DisaggregatedEngine): kv_ship ships pages, not the "
-                "recurrent state or the compressed keys")
-
     def _rung(self, max_q_len: int) -> int:
         """The ``block_q`` a step whose longest row packs ``max_q_len``
         tokens launches at: the ladder rung covering it, no lower than
@@ -862,6 +928,10 @@ class ServingEngine:
             auto_block_q,
         )
 
+        if self.model.config.kv_latent and max_q_len > 1:
+            # the latent walk cuts its query blocks by tokens whatever
+            # ``block_q``: two rungs, decode-only and the cap
+            return self._block_q_cap
         return min(self._block_q_cap,
                    max(auto_block_q(max_q_len, self._g),
                        self._block_q_floor))
@@ -1156,6 +1226,7 @@ class ServingEngine:
 
     def _assemble(self):
         from triton_distributed_tpu.kernels.ragged_paged_attention import (
+            LATENT_TQ,
             causal_topologies,
             topo_width,
         )
@@ -1175,6 +1246,7 @@ class ServingEngine:
         self._append_runs = 0
         self._pages_walked = [0, 0]     # [global, window], one layer each
         self._state_work = [0, 0, 0]
+        self._latent_work = [0, 0]      # [pages fetched, rows]
         mc = self.model.config
         batched: set = set()
         takes: dict = {}
@@ -1223,6 +1295,11 @@ class ServingEngine:
                         cur + take > mc.sparse_dense_len)
                 if mc.lightning_layers:
                     self._state_work[2] += 1
+                if mc.kv_latent:
+                    self._latent_work[0] += sum(
+                        self._pages_held(cur + min(i + LATENT_TQ, take))
+                        for i in range(0, take, LATENT_TQ))
+                    self._latent_work[1] += 1
                 next_start += _ceil8(take)
                 batched.add(s)
                 takes[s] = take
@@ -1467,6 +1544,8 @@ class ServingEngine:
                 "selected_pages_walked": self._state_work[0],
                 "sparse_rows": self._state_work[1],
                 "state_rows": self._state_work[2],
+                "latent_pages_walked": self._latent_work[0],
+                "latent_rows": self._latent_work[1],
                 "packed_rows": len(tokens),
                 # the step program masks its padding rows' assignments
                 "moe_masked_rows": len(tokens) - report["tokens"]
@@ -1785,16 +1864,7 @@ class ServingEngine:
         ``kv_quant``)."""
         import jax.numpy as jnp
 
-        if self._window:
-            raise ValueError(
-                "kv_ship / page migration with sliding-window layers: "
-                "pages ship by the global block table, which does not "
-                "address a ring pool")
-        if self._stateful:
-            raise ValueError(
-                f"kv_ship / page migration with {', '.join(self._stateful)}"
-                ": pages ship, the recurrent state and the compressed "
-                "keys do not")
+        refuse(self._kinds, "gather_pages")
         gather, _ = self._kv_wire_jits()
         return gather(self.state.layers,
                       jnp.asarray(list(pids), jnp.int32))
@@ -1946,17 +2016,7 @@ class DisaggregatedEngine:
         if transport not in ("auto", "dcn", "xla"):
             raise ValueError(f"unknown transport {transport!r}")
         for m in (prefill_model, decode_model):
-            if m.config.window_layers:
-                raise ValueError(
-                    "DisaggregatedEngine with sliding-window layers: "
-                    "kv_ship ships pages by the global block table, "
-                    "which does not address a ring pool")
-            if m.config.lightning_layers or m.config.sparse_topk:
-                raise ValueError(
-                    "DisaggregatedEngine with lightning layers "
-                    "(layer_mixer) or block-sparse attention "
-                    "(sparse_topk): kv_ship ships pages, not the "
-                    "recurrent state or the compressed keys")
+            refuse(state_kinds(m.config), "disaggregated")
         if transport == "auto":
             transport = "dcn" if hybrid_mesh is not None else "xla"
         if transport == "dcn" and hybrid_mesh is None:

@@ -48,6 +48,17 @@ owns:
   compressed key is written in the step that appends its last token
   and never read before that.
 
+* **latent pools** (PR 35) — a model with LATENT attention
+  (``TransformerConfig.kv_latent``) keeps, per layer, ONE array and no
+  V pool: ``layers[i] = (pool, None)`` with ``pool`` ``(npages, 1,
+  page, latent_stored)``, one entry a token for ALL the heads, the
+  RMS-normed latent ``c_kv`` (``kv_latent`` values), the rotated key
+  every head shares (``qk_rope_dim``) and zeros up to whole 128-lane
+  tiles. The block table addresses it as it does K/V pages, so the
+  allocator, admission, eviction and re-prefill need nothing new; the
+  step appends one stream (``kv_append_latent``) and every head walks
+  the same entries absorbed (``ragged_paged_attention(latent=)``).
+
 The object is a pytree (``jax.tree_util``): the serving-step jit
 donates it whole, and with the pool placements pinned the per-step
 append aliases in place — no pool-sized copy per step.
@@ -66,7 +77,9 @@ import numpy as np
 class ServingState:
     """One engine's device-resident serving state (see module docs)."""
 
-    layers: tuple       # per-layer (k_pool, v_pool); dicts under kv_quant
+    # per layer (k_pool, v_pool), dicts under kv_quant; (pool, None) a
+    # latent layer; None a lightning layer
+    layers: tuple
     block_table: object  # (slots, pages_per_seq) int32
     kv_lens: object      # (slots,) int32 — includes the in-flight step
     cursors: object      # (slots,) int32
