@@ -7,6 +7,8 @@ torch reference, and — beyond the reference's scope — the op must be
 trainable end-to-end on the XLA transport.
 """
 
+from dataclasses import replace
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -95,6 +97,57 @@ def test_weight_quantized_experts_vs_dense(mesh8, use_pallas_gemm):
     out = ep_moe(xs, logitss, wq_up, wq_down, ctx)
     err = np.abs(np.asarray(out) - np.asarray(ref))
     assert np.max(err) < 0.05 * np.abs(np.asarray(ref)).max()
+
+
+@pytest.mark.parametrize("block_m, act_quant", [
+    (16, None), (32, None), (64, None), (32, "int8")])
+def test_expert_mlp_matches_its_ragged_dot_twin_at_small_blocks(
+        block_m, act_quant):
+    """``_expert_mlp`` by the grouped-GEMM kernel (interpreted) against
+    its ``ragged_dot`` twin on ONE input at the alignment blocks the
+    served widths now take (``expert_block_m``): received rows out of
+    expert order, invalid rows among them (a masked assignment's slot,
+    the window's slack), two experts with no row at all, the dummy
+    tail. W8A8 at an int8 operand's least block, 32 rows; its twin
+    widens the same int8 weights and keeps the activations."""
+    from jax.sharding import Mesh
+
+    from triton_distributed_tpu.kernels.group_gemm import (
+        quantize_grouped_weights,
+    )
+    from triton_distributed_tpu.ops.moe import _expert_mlp
+
+    epr, r, h, f = 6, 88, 128, 256
+    rng = np.random.default_rng(7)
+    eid = rng.choice([0, 1, 3, 4], r).astype(np.int32)   # 2, 5: no row
+    valid = rng.random(r) < 0.6
+    valid[-9:] = False
+    rows = rng.standard_normal((r, h)).astype(np.float32)
+    w_up = jnp.asarray(rng.standard_normal((epr, h, f)) * 0.05,
+                       jnp.float32)
+    w_down = jnp.asarray(rng.standard_normal((epr, f, h)) * 0.05,
+                         jnp.float32)
+    tol = 1e-5
+    if act_quant:
+        w_up, w_down = (dict(zip(("q", "scale"),
+                                 quantize_grouped_weights(w, "int8")))
+                        for w in (w_up, w_down))
+        tol = 0.03
+    ctx = create_ep_moe_context(
+        Mesh(np.asarray(jax.devices()[:1]), ("x",)), "x", num_experts=epr,
+        topk=TOPK, max_m=r, hidden=h, dtype=jnp.float32, transport="xla",
+        block_m=block_m, act_quant=act_quant)
+    assert ctx.aligned_rows == mu.aligned_capacity(r, epr + 1, block_m)
+    args = (jnp.asarray(rows), jnp.asarray(eid), jnp.asarray(valid),
+            w_up, w_down)
+    got = np.asarray(_expert_mlp(ctx, *args))
+    twin = np.asarray(_expert_mlp(
+        replace(ctx, use_pallas_gemm=False, act_quant=None), *args))
+    assert got.shape == twin.shape == (r, h)
+    assert not got[~valid].any() and not twin[~valid].any()
+    assert np.abs(twin[valid]).max() > 0.1
+    np.testing.assert_allclose(got, twin, atol=tol * np.abs(twin).max(),
+                               rtol=tol)
 
 
 class TestChunkedWire:

@@ -261,8 +261,18 @@ class TestDecode:
             )
         finally:
             config.force_compile = old
-        assert ctx_q.gg_block_n is not None and ctx_q.block_m == 64
-        assert ctx_raw.gg_block_n is None and ctx_raw.block_m == 256
+        # the gate decides the schedule; the block is the rule's at
+        # this step's rows (16 a shard), each regime's own
+        from triton_distributed_tpu.models.transformer import (
+            expert_block_m,
+        )
+
+        rows = 16 * model.tp
+        assert ctx_q.gg_block_n is not None and ctx_q.block_m == \
+            expert_block_m(rows, 2, 8, resident=True, floor=16, cap=64)
+        assert ctx_raw.gg_block_n is None and ctx_raw.block_m == \
+            expert_block_m(rows, 2, 8, resident=False, floor=64, cap=256)
+        assert ctx_q.block_m < ctx_raw.block_m
 
 
 class TestRemat:

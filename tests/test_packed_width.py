@@ -9,6 +9,7 @@ width are ``test_kv_append`` / ``test_window_share`` (their engines'
 low rungs are narrow too).
 """
 
+import json
 import pathlib
 import sys
 
@@ -23,11 +24,15 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from benchmark.harness import weights  # noqa: E402
+from benchmark.harness import program, weights  # noqa: E402
 from benchmark.models import exaone_moe as ref  # noqa: E402
 from test_serving_step import CFG, _model  # noqa: E402
 from test_window_share import sizes_of, tiny_config  # noqa: E402
+from triton_distributed_tpu.kernels import moe_utils as mu  # noqa: E402
 from triton_distributed_tpu.models import Transformer  # noqa: E402
+from triton_distributed_tpu.models.transformer import (  # noqa: E402
+    expert_block_m,
+)
 from triton_distributed_tpu.serving import (  # noqa: E402
     EngineConfig,
     Request,
@@ -197,6 +202,85 @@ def test_speculative_rows_verify_the_same_stream_at_the_narrow_width():
     assert [r.generated for r in spec] == [r.generated for r in plain]
 
 
+# ------------------------------------- the expert layer's alignment block
+
+
+@pytest.mark.parametrize("rows, topk, experts, resident, floor, cap, block", [
+    # resident weights: half the even share (24.75 / 2 -> 16; 72 / 2 ->
+    # 64), never under an int8 operand's tile
+    (264, 6, 64, True, 32, 128, 32),
+    (768, 6, 64, True, 32, 128, 64),
+    # tiled weights: twice the share (33 -> 64; 96 -> 128; 132 -> 256)
+    (264, 8, 128, False, 64, 256, 64),
+    (768, 8, 128, False, 64, 256, 128),
+    (264, 2, 8, False, 64, 256, 256),
+    # an exact power of two stays (128 x 2 / 8 / 2 = 16)
+    (128, 2, 8, True, 8, 64, 16),
+    (128, 2, 8, False, 16, 256, 64),
+    # never over the cap, never under the floor
+    (4096, 6, 64, True, 32, 128, 128),
+    (4096, 2, 8, False, 64, 256, 256),
+    (264, 8, 256, False, 64, 256, 64),
+    (8, 2, 256, True, 16, 64, 16),
+    (1, 1, 8, True, 8, 64, 8),
+])
+def test_the_block_is_a_power_of_two_from_the_even_share(
+        rows, topk, experts, resident, floor, cap, block):
+    assert expert_block_m(rows, topk, experts, resident=resident,
+                          floor=floor, cap=cap) == block
+
+
+#: configuration file -> {width: (block_m, rows of the sorted buffer)}
+#: on the chip; mixtral's are what every width took before PR 36
+CELL_BLOCKS = {
+    "dsmoe16b-d9": {264: (32, 3616), 768: (64, 8704)},
+    "mixtral8x7b-d2": {264: (256, 3072), 768: (256, 3840)},
+    "kexaone236b-ep8-d5": {264: (64, 3200), 768: (128, 8320)},
+    "dotsvlm1-ep32-d5": {264: (64, 2688), 768: (64, 6720)},
+    "minicpmsala9b-d8": {264: None, 768: None},
+}
+
+
+@pytest.mark.parametrize("width", [264, 768])
+@pytest.mark.parametrize("name", sorted(CELL_BLOCKS))
+def test_the_cells_expert_blocks_follow_their_two_widths(
+        name, width, monkeypatch):
+    """The five configurations as the benchmark runs them, at the two
+    widths of the cells' engine: compiling for the chip the block is
+    the rule's (a function of the width, the router and where the
+    weights live, not of the preset), the counter's rows are the
+    buffer ``moe_align_block_size`` builds; off the chip, and for
+    training, the context is what it was."""
+    from triton_distributed_tpu.config import config as tcfg
+
+    conf = json.loads(
+        (ROOT / "benchmark" / "configs" / f"{name}.json").read_text())
+    c = program.model_config(conf)
+    model = Transformer(c, Mesh(np.asarray(jax.devices()[:1]), ("x",)),
+                        tp_axis="x")
+    want = CELL_BLOCKS[name][width]
+    if want is None:                  # no EP expert layer
+        assert model.moe_aligned_rows(width) == 0
+        return
+    wq = c.moe_weight_quant is not None
+    off = model._moe_ep_ctx(width, inference=True, weights_quantized=wq)
+    assert (off.block_m, off.transport, off.use_pallas_gemm) == (
+        128, "xla", False)
+    monkeypatch.setattr(tcfg, "force_compile", True)  # compiling_for_tpu()
+    ctx = model._moe_ep_ctx(width, inference=True, weights_quantized=wq)
+    train = model._moe_ep_ctx(width)
+    rows = model.moe_aligned_rows(width)
+    assert (train.block_m, train.transport, train.use_pallas_gemm) == (
+        128, "xla", False)
+    assert ctx.transport == "fused" and ctx.use_pallas_gemm
+    assert (ctx.block_m, ctx.aligned_rows) == want
+    assert rows == ctx.aligned_rows == mu.moe_align_block_size(
+        np.zeros((ctx.recv_rows, 1), np.int32), ctx.experts_per_rank + 1,
+        ctx.block_m)[0].shape[0]
+    # the resident regime is dsmoe's alone (a 2.9 MB int8 expert)
+    assert (ctx.gg_block_n is not None) == (name == "dsmoe16b-d9")
+
+
 # ----------------------------------------------------------- the counters
 
 
@@ -210,6 +294,11 @@ def test_packed_rows_sums_the_widths_and_masked_rows_the_rest(moe):
     assert st.packed_rows == sum(widths) < len(widths) * eng._t_pad
     assert st.moe_masked_rows == (
         st.packed_rows - sum(st.step_tokens) if moe == "ep" else 0)
+    # a step's program allocates its width's sorted buffer a layer
+    aligned = {w: model.moe_aligned_rows(w, params) for w in set(widths)}
+    assert st.moe_aligned_rows == sum(aligned[w] for w in widths)
+    assert all((rows > w * model.config.topk) == (moe == "ep")
+               for w, rows in aligned.items())
 
 
 # ------------------------------------------------------ one program a rung

@@ -31,6 +31,7 @@ Layout (Megatron sequence-parallel):
 from __future__ import annotations
 
 import functools
+import math
 from dataclasses import dataclass, field
 
 import jax
@@ -580,6 +581,44 @@ class TransformerConfig:
         return self.q_dim + 2 * self.kv_dim
 
 
+def expert_block_m(rows: int, topk: int, experts: int, *, resident: bool,
+                   floor: int, cap: int) -> int:
+    """The alignment block of an EP expert layer's sorted buffer for a
+    step of ``rows`` packed rows whose router chooses ``topk`` of
+    ``experts``.
+
+    Every expert's segment of the sorted buffer is padded to a multiple
+    of the block, so the buffer — and with it the sort, the gather into
+    it, the re-quantization, the activation and the GEMMs' stores, all
+    of ``ops/moe.py::_expert_mlp`` — is ``received rows + (held experts
+    + 1)·(block − 1)`` rows long whatever the step holds. A served
+    step's grouped GEMM is weight-byte-bound (a decode-only step gives
+    a touched expert 1–7 rows), so the block buys nothing on the MXU
+    and is sized from the rows an expert gets from a FULL step under an
+    even router, ``share = rows·topk / experts``, to a power of two:
+
+    - ``resident`` weights (an expert's matrix stays in VMEM over its
+      consecutive blocks, so a second block costs a grid step and no
+      re-fetch): HALF the share;
+    - tiled weights (every block re-streams the matrix): TWICE the
+      share, so an expert at twice its even share still fits one block;
+
+    never under ``floor`` nor over ``cap``. Measured on a v5e, one layer
+    at the benchmark's shapes under a cell's load (PR 36; ``CHANGES.md``
+    has the whole sweep, block x configuration x width): dsmoe (64
+    experts, top-6, W8A8 resident) 0.70 ms at block 32 against 1.06 at
+    128 on a 264-row step, 1.21 at 64 against 1.44 on a 768-row one;
+    kexaone (a 16-of-128 share, top-8, tiled) 0.91 at 64 against 1.15
+    at 256, and 3.27 at 128 against 3.79. Under 64 rows a tiled block
+    loses again (dots 1.14 at 32, 0.97 at 64): a block that holds no
+    row still walks its (N, K) grid steps, and there are more of them.
+    mixtral (8 experts, top-2, tiled) keeps 256 at both of its widths.
+    """
+    share = rows * topk / experts * (0.5 if resident else 2)
+    block = 1 << (max(1, math.ceil(share)) - 1).bit_length()
+    return max(floor, min(cap, block))
+
+
 def _rotate_half(x, cos, sin):
     """``x`` (T, heads, d) rotated by the tables of
     ``Transformer._rope_tables``, in float32, back in ``x``'s dtype."""
@@ -738,17 +777,13 @@ class Transformer:
             and not is_dcn_axis(self.mesh, self.tp_axis)
         )
         pallas_ok = fused_ok and compiling_for_tpu()
-        # the scalar-prefetch grouped-GEMM kernel in WEIGHT-RESIDENT
-        # mode (whole-N/K tiles, block_m 64) wins the decode-size expert
-        # MLP on hardware: less alignment padding without per-block
-        # weight re-streaming (measured 2.60 → 1.83 ms/block at the
-        # serving headline vs ragged_dot — see group_gemm.grouped_matmul
-        # and docs/PERF.md's serving section); off-TPU / training keep
-        # the differentiable ragged_dot path
-        # weight residency needs one expert's FULL (hidden, ffn) matrix
-        # double-buffered in VMEM — gate on the budget (e.g. Mixtral's
-        # 117 MB expert exceeds a v5e's VMEM; fall back to the tiled
-        # schedule at block_m 256, the tiled-sweep optimum)
+        # the scalar-prefetch grouped-GEMM kernel serves the expert MLP
+        # on hardware (off-TPU / training keep the differentiable
+        # ragged_dot path). WEIGHT-RESIDENT mode (whole-N/K tiles) needs
+        # one expert's FULL (hidden, ffn) matrix double-buffered in
+        # VMEM — gate on the budget (dsmoe's 2.9 MB int8 expert fits;
+        # Mixtral's 117 MB one does not and takes the tiled schedule,
+        # where every M-block of an expert re-streams its matrix)
         from triton_distributed_tpu.config import fused_vmem_budget
         from triton_distributed_tpu.kernels.group_gemm import (
             resident_weight_itemsize,
@@ -769,13 +804,22 @@ class Transformer:
         )
         # W8A8 engages only where its int8 weight dicts will exist
         a8 = c.moe_act_quant if (pallas_ok and wq_mode == "int8") else None
-        # block_m: W8A8's s8×s8 MXU rate needs ≥128-row blocks, while
-        # W8A16 prefers 64 (less alignment padding; weight residency
-        # removes the re-streaming penalty) — both measured, docs/PERF.md
-        if wr_ok:
-            bm = 128 if a8 else 64
+        if pallas_ok:
+            # the alignment block follows the step's width
+            # (``expert_block_m``). Caps: the blocks tuned for steps
+            # whose every row is a token (``docs/PERF.md``, another
+            # machine: 128 W8A8, 64 W8A16, 256 tiled); of the
+            # benchmark's steps only mixtral's reach one. Floors: an
+            # operand's sublane tile (32 rows int8, 16 bfloat16) where
+            # the weights are resident, 64 where every M-block walks
+            # its own (N, K) tiles
+            tile = 32 // (1 if a8 else jnp.dtype(c.dtype).itemsize)
+            bm = expert_block_m(
+                m_local * self.tp, c.topk, c.num_experts, resident=wr_ok,
+                floor=tile if wr_ok else 64,
+                cap=(128 if a8 else 64) if wr_ok else 256)
         else:
-            bm = 256 if pallas_ok else 128
+            bm = 128
         return ops.create_ep_moe_context(
             self.mesh, self.tp_axis, num_experts=c.local_experts,
             topk=c.topk,
@@ -1286,6 +1330,21 @@ class Transformer:
             if i in c.moe_layers else None
             for i in range(c.n_layers)
         ]
+
+    def moe_aligned_rows(self, batch: int, params=None) -> int:
+        """Rows of the expert-sorted buffer ONE EP expert layer of a
+        serving step ``batch`` packed rows wide allocates
+        (``EPMoEContext.aligned_rows``: a static of that width's
+        program, sized from ``params``' expert leaves as the step
+        sizes it); 0 for a model with no EP expert layer."""
+        c = self.config
+        if c.moe != "ep" or not c.moe_layers:
+            return 0
+        wq = None if params is None else isinstance(
+            params["blocks"][c.moe_layers[0]]["moe_up"], dict)
+        return self._moe_ep_ctx(
+            -(-batch // self.token_shards), inference=True,
+            weights_quantized=wq).aligned_rows
 
     def _dense_mlp(self, xn, w_up, w_down):
         """The serving step's dense FFN on normed rows ``xn``:

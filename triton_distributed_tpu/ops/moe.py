@@ -94,10 +94,14 @@ class EPMoEContext:
     # W8A8 expert GEMMs ("int8"): quantize the ACTIVATIONS per row too
     # and run the MXU's native s8×s8→s32 path (2× the bf16 rate, the
     # remaining lever once the weight-resident schedule has minimized
-    # HBM reads). Requires int8 weight dicts + the Pallas GEMM; sweet
-    # spot block_m=128 (the int8 rate needs ≥128-row blocks while the
-    # alignment-padding tax grows with block_m — measured 292 vs 356 µs
-    # per decode up-GEMM against W8A16 at bm=64, docs/PERF.md).
+    # HBM reads). Requires int8 weight dicts + the Pallas GEMM. Its
+    # block_m is the caller's: every array of ``_expert_mlp`` (sorted
+    # rows, their int8 copy, the hidden, the output) is
+    # ``aligned_rows`` long, so the block is sized to the rows an
+    # expert is expected to get, not to the MXU (a served step's
+    # grouped GEMM is weight-byte-bound: ``models/transformer.py::
+    # expert_block_m`` has the rule and its chip numbers); an int8
+    # operand's sublane tile, 32 rows, is the least.
     act_quant: str | None = None
     # gated expert MLP: ``w_up`` is (epr, H, 2F), gate columns first —
     # ONE grouped GEMM gives both halves and the hidden activation is
@@ -132,6 +136,24 @@ class EPMoEContext:
     @property
     def experts_per_rank(self) -> int:
         return self.num_experts // self.n
+
+    @property
+    def recv_rows(self) -> int:
+        """Rows ``_expert_mlp`` is handed: every peer's receive slot."""
+        if self.transport == "fused":
+            from triton_distributed_tpu.kernels import moe_dispatch as md
+
+            return self.n * md.slot_pad(self.a2a)
+        return self.n * self.max_m
+
+    @property
+    def aligned_rows(self) -> int:
+        """Rows of the expert-sorted buffer (and of every array of
+        ``_expert_mlp`` behind it): the received rows plus up to
+        ``block_m - 1`` rows of alignment an expert and the dummy
+        group."""
+        return mu.aligned_capacity(
+            self.recv_rows, self.experts_per_rank + 1, self.block_m)
 
     @property
     def a2a(self) -> ma.MoEAllToAllContext:
@@ -558,7 +580,7 @@ def _ep_assignments_device(ctx: EPMoEContext, x, flat_e, w_flat, out_rows,
             eid, valid = _slot_tables(ctx, rspl, slot_m)
         with jax.named_scope("moe_gemm"):
             y = _expert_mlp(
-                ctx, toks.reshape(ctx.n * slot_m, ctx.hidden), eid, valid,
+                ctx, toks.reshape(ctx.recv_rows, ctx.hidden), eid, valid,
                 w_up, w_down,
             )
         with jax.named_scope("moe_combine"):
@@ -597,7 +619,7 @@ def _ep_assignments_device(ctx: EPMoEContext, x, flat_e, w_flat, out_rows,
             eid, valid = _slot_tables(ctx, rspl, ctx.max_m)
         with jax.named_scope("moe_gemm"):
             y = _expert_mlp(
-                ctx, toks.reshape(ctx.n * ctx.max_m, ctx.hidden), eid,
+                ctx, toks.reshape(ctx.recv_rows, ctx.hidden), eid,
                 valid, w_up, w_down,
             )
         with jax.named_scope("moe_combine"):

@@ -358,6 +358,12 @@ class EngineStats:
     # ``masked * topk // block_m`` blocks of an expert layer's grouped
     # GEMM hold no row (0 for a model with no EP expert layer)
     moe_masked_rows: int = 0
+    # rows of the expert-sorted buffer ONE EP expert layer of each
+    # device step's program allocates, summed over the steps
+    # (``Transformer.moe_aligned_rows`` at the step's width: a static
+    # of the program — every array of ``ops/moe.py::_expert_mlp`` is
+    # that long whatever the step holds; 0 with no EP expert layer)
+    moe_aligned_rows: int = 0
     prefix_hits: int = 0               # pages reattached from the cache
     # --- in-batch shared-prefix dedup (EngineConfig.prefix_share) ---
     shared_prefix_rows: int = 0        # batched rows marked SHARED_PREFIX
@@ -892,6 +898,7 @@ class ServingEngine:
             if None in moe_state.values():
                 moe_state = None
         self.moe_state = moe_state
+        self._aligned_rows: dict = {}      # ``_moe_aligned_rows``
         if cfg.token_budget % 8:
             raise ValueError("token_budget must be 8-aligned")
         if cfg.chunk > cfg.token_budget:
@@ -940,6 +947,14 @@ class ServingEngine:
         """Every ``block_q`` a step of this engine can launch at."""
         return sorted({self._rung(1 << i) for i in
                        range(self._block_q_cap.bit_length())})
+
+    def _moe_aligned_rows(self, width: int) -> int:
+        """``Transformer.moe_aligned_rows`` of this engine's step at
+        ``width`` (one context a width, like its step program)."""
+        if width not in self._aligned_rows:
+            self._aligned_rows[width] = self.model.moe_aligned_rows(
+                width, self.params)
+        return self._aligned_rows[width]
 
     def _width(self, block_q: int) -> int:
         """:func:`packed_width` of a step of this engine at rung
@@ -1550,6 +1565,7 @@ class ServingEngine:
                 # the step program masks its padding rows' assignments
                 "moe_masked_rows": len(tokens) - report["tokens"]
                 if c.moe == "ep" and c.moe_layers else 0,
+                "moe_aligned_rows": self._moe_aligned_rows(len(tokens)),
             })
         return flight, block_q, probing
 
