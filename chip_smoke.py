@@ -732,6 +732,8 @@ def mla_leg(devices) -> dict:
 
 def leg(devices, on_chip: bool = True) -> dict:
     """The whole smoke on one device set."""
+    from triton_distributed_tpu import tracing
+
     n = len(devices)
     built = [programs_lowered()]
     t0 = time.perf_counter()
@@ -759,10 +761,16 @@ def leg(devices, on_chip: bool = True) -> dict:
     eng = engine(model, params, on_chip)
     cold, t_cold, _ = serve(eng)
     built.append(programs_lowered())
+    # readiness, from inside: where this process's set-up went
+    print(tracing.ready_line(), flush=True)
+    step_programs = eng.stats.programs_built
     warm, t_warm, steps = serve(eng)
     built.append(programs_lowered())
     need(built[3] == built[2],
          f"the warm pass lowered {built[3] - built[2]} new program(s)")
+    need(eng.stats.programs_built == step_programs,
+         "the warm pass dispatched a step program a first time: "
+         f"{tracing.startup_log()['spans'][-1]}")
     need([r.generated for r in cold] == [r.generated for r in warm],
          "the same seeded trace produced different token streams twice")
     # the passes above launched step k + 1 before step k's tokens came

@@ -1,18 +1,27 @@
 """The engine's step, measured from inside (ISSUE 26): the six host
 spans and per-step lists of ``ServingEngine.step``, the device scopes of
 the jitted serving step, and the request stamps with their counters.
+And start-up (ISSUE 39): the ``setup.*`` spans, the log of every program
+built under a span, ``EngineStats.programs_built``.
 
 All on the CPU at a tiny size: what is opened, when, how often and
 under which name — never a time (times come from the chip).
 """
 
+import gc
+import json
+import os
+import pathlib
 import re
+import subprocess
+import sys
 import time
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax._src import monitoring     # the listeners' getters are not public
 from jax.sharding import Mesh
 
 from triton_distributed_tpu.models import Transformer, TransformerConfig
@@ -26,7 +35,14 @@ from triton_distributed_tpu.serving import (
     poisson_trace,
 )
 from conftest import drained
+from triton_distributed_tpu import tracing
 from triton_distributed_tpu.serving.engine import PHASES
+
+ROOT = pathlib.Path(__file__).resolve().parents[1]
+if str(ROOT) not in sys.path:
+    sys.path.insert(0, str(ROOT))
+
+from benchmark.harness import metrics, program  # noqa: E402
 
 pytestmark = pytest.mark.fast
 
@@ -76,12 +92,21 @@ class _Annotation:
 
     log: list = []
     open_now: list = []
+    #: every ``setup.*`` span as opened: (name, attributes, the spans
+    #: open round it, outermost first)
+    setup: list = []
 
     def __init__(self, name, **kw):
-        self.name, self.step = name, kw.get("step")
+        self.name, self.step, self.kw = name, kw.get("step"), kw
 
     def __enter__(self):
-        _Annotation.log.append((self.name, self.step))
+        if self.name.startswith("setup."):
+            # set-up has a list of its own: ``log`` is the engine's
+            # phases, as before there was a ``setup.*`` span
+            _Annotation.setup.append(
+                (self.name, self.kw, tuple(_Annotation.open_now)))
+        else:
+            _Annotation.log.append((self.name, self.step))
         _Annotation.open_now.append(self.name)
 
     def __exit__(self, *exc):
@@ -91,7 +116,7 @@ class _Annotation:
 
 @pytest.fixture
 def annotations(monkeypatch):
-    _Annotation.log, _Annotation.open_now = [], []
+    _Annotation.log, _Annotation.open_now, _Annotation.setup = [], [], []
     monkeypatch.setattr(jax.profiler, "TraceAnnotation", _Annotation)
     return _Annotation
 
@@ -422,3 +447,296 @@ def test_token_streams_are_identical_with_the_profiler_on_and_off(
                     seen[ev.name] = seen.get(ev.name, 0) + 1
     assert set(seen) == set(SPANS)
     assert {seen[n] for n in SPANS[2:]} == {len(stats.step_times)}
+
+
+# ------------------------------------------------- start-up (ISSUE 39)
+
+BENCH = ROOT / "benchmark"
+SETUP_METRICS = sorted(p.name[:-len(".json")] for p in
+                       (BENCH / "layer_metrics").glob("setup_*.json"))
+
+
+def _log_since(mark):
+    """The process-wide log's entries made since ``mark`` (the lengths
+    ``_mark()`` took): the suite shares one process."""
+    log = tracing.startup_log()
+    return {k: log[k][mark[k]:] for k in log}
+
+
+def _mark():
+    return {k: len(v) for k, v in tracing.startup_log().items()}
+
+
+def _serve(eng, first_rid, lengths=(3, 9)):
+    """One request alone per prompt length (its chunk sets the rung),
+    each decoding a few tokens: the shape of the benchmark's warm-up."""
+    for i, n in enumerate(lengths):
+        req = Request(rid=first_rid + i, max_new=3,
+                      prompt=np.arange(n, dtype=np.int32))
+        eng.run([req], max_steps=64)
+        assert req.done
+
+
+@pytest.mark.parametrize("kind", ["dense", "ep"])
+def test_the_setup_spans_open_once_a_construction_children_inside(
+        mesh1, models, annotations, kind):
+    model, params = _model(mesh1, kind)
+    assert annotations.setup == [("setup.model", {}, ())]
+    ServingEngine(model, params, ECFG, use_pallas=False)
+    assert not annotations.open_now
+    assert [(n, held) for n, _, held in annotations.setup[1:]] == [
+        ("setup.engine", ()),
+        ("setup.state", ("setup.engine",)),
+        ("setup.workspaces", ("setup.engine",))]
+    # a second construction: each once more, never a fifth name
+    ServingEngine(model, params, ECFG, use_pallas=False)
+    assert [n for n, _, _ in annotations.setup[4:]] == [
+        "setup.engine", "setup.state", "setup.workspaces"]
+    assert annotations.log == []        # no phase of a step was opened
+
+
+@pytest.mark.parametrize("order", ["ahead", "drained"])
+def test_setup_program_opens_once_a_program_key_inside_its_dispatch(
+        models, annotations, order):
+    model, params = models["dense"]
+    eng = (ServingEngine if order == "ahead"
+           else drained(ServingEngine))(model, params, ECFG,
+                                        use_pallas=False)
+    mark = _mark()
+    _serve(eng, 0)
+    built = [(kw, held) for n, kw, held in annotations.setup
+             if n == "setup.program"]
+    rungs = eng._rungs()
+    assert len(rungs) == 2
+    assert [(kw["block_q"], kw["width"]) for kw, _ in built] == [
+        (b, eng._width(b)) for b in rungs]
+    for kw, held in built:
+        # nested in the dispatch of the step it names
+        assert held == ("engine.dispatch",) and kw["step"] >= 0
+    assert eng.stats.programs_built == len(rungs)
+    # a second pass over the same rungs opens none and builds nothing
+    _serve(eng, 10)
+    assert sum(n == "setup.program" for n, _, _ in annotations.setup) \
+        == len(rungs) == eng.stats.programs_built
+    new = _log_since(mark)
+    spans = [s for s in new["spans"] if s["name"] == "setup.program"]
+    assert [(s["block_q"], s["width"]) for s in spans] == [
+        (b, eng._width(b)) for b in rungs]
+    assert eng.stats.program_build_s == sum(s["seconds"] for s in spans)
+    # the six phases are as before (admit .. advance, in order)
+    assert all([n for n, _ in call] in (SPANS[:2], SPANS[:4], SPANS,
+                                        SPANS[:2] + SPANS[4:])
+               for call in _per_step(annotations.log))
+
+
+def test_programs_built_is_one_per_key_the_harness_warm_up_visits(mesh1):
+    model, params = _model(mesh1, "ep")
+    eng = ServingEngine(
+        model, params, EngineConfig(slots=4, token_budget=64, chunk=32,
+                                    page=8, npages=64),
+        use_pallas=False, propagate_failures=True)
+    keys, run = set(), eng._run_device
+
+    def spy(arrays, block_q):
+        keys.add((block_q, len(arrays[0])))
+        return run(arrays, block_q)
+
+    eng._run_device = spy
+    mark = _mark()
+    warm = program.warm_up(eng, CFG["vocab"])
+    # one key a rung: the width follows the rung
+    assert len(keys) == len(warm["rungs"]) == 3
+    assert keys == {(b, eng._width(b)) for b in warm["rungs"]}
+    assert eng.stats.programs_built == len(keys)
+    new = _log_since(mark)
+    assert {(s["block_q"], s["width"]) for s in new["spans"]} == keys
+    steps = [p for p in new["programs"] if p["span"] == "setup.program"
+             and p["fun_name"] == "jit(step)"]
+    assert [(p["block_q"], p["width"]) for p in steps] == sorted(keys)
+    # what the benchmark's window must read: a second warm-up builds
+    # nothing, so both counters are still over it
+    before = program.stats_snapshot(eng)["numbers"]
+    program.warm_up(eng, CFG["vocab"])
+    after = program.stats_snapshot(eng)["numbers"]
+    assert after["programs_built"] == before["programs_built"] == len(keys)
+    assert after["program_build_s"] == before["program_build_s"]
+
+
+def test_a_build_under_a_key_seen_before_is_a_rebuild_not_a_step_program(
+        mesh1, models):
+    model, params = models["dense"]
+    eng = ServingEngine(model, params, ECFG, use_pallas=False)
+    _serve(eng, 0)
+    built, mark = eng.stats.programs_built, _mark()
+    rebuilt = tracing.summary()["rebuilt_programs"]
+    # the same keys, through a jit that has not built them: a twin
+    # model's (its ``_serving_jit`` is its own)
+    eng.model = _model(mesh1, "dense")[0]
+    _serve(eng, 10)
+    assert eng.stats.programs_built == built
+    new = _log_since(mark)
+    assert [s for s in new["spans"] if s["name"] == "setup.program"] == []
+    again = [p for p in new["programs"] if p["fun_name"] == "jit(step)"]
+    assert len(again) >= 2
+    assert {p["span"] for p in again} == {"engine.dispatch"}
+    assert all(p["block_q"] is None and p["step"] is not None
+               for p in again)
+    assert tracing.summary()["rebuilt_programs"] == rebuilt + len(again)
+
+
+def test_a_nested_jit_is_booked_once_by_containment():
+    seen = []
+
+    def listen(event, duration, **kw):
+        if event.endswith("jaxpr_trace_duration"):
+            seen.append((kw["fun_name"], duration))
+
+    @jax.jit
+    def inner_of_the_nest(x):
+        return x * 2
+
+    @jax.jit
+    def outer_of_the_nest(x):
+        return inner_of_the_nest(x) + inner_of_the_nest(x + 1)
+
+    tracing.install()
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        mark = _mark()
+        with tracing.Span("setup.test_nest"):
+            outer_of_the_nest(jnp.ones((3,), jnp.float32))
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    traced = dict(seen)
+    assert {"inner_of_the_nest", "outer_of_the_nest"} <= set(traced)
+    new = _log_since(mark)["programs"]
+    nest = [p for p in new if "of_the_nest" in p["fun_name"]]
+    # ONE program: the inner jit was traced inside the outer one and
+    # lowered with it; the record's trace is the outer's alone
+    assert [p["fun_name"] for p in nest] == ["jit(outer_of_the_nest)"]
+    assert nest[0]["trace_s"] == traced["outer_of_the_nest"]
+    assert nest[0]["span"] == "setup.test_nest"
+    assert nest[0]["inside"] is None
+    assert tracing._open.traces == {} and tracing._open.stack == []
+
+
+@pytest.mark.parametrize("cache", ["hit", "miss", "off"])
+def test_a_record_reads_its_cache_state_from_the_events_after_its_lowering(
+        cache):
+    """JAX's own order, replayed: trace, lowering, then inside the
+    backend's compile the cache's nameless events."""
+    say, dur = jax.monitoring.record_event, \
+        jax.monitoring.record_event_duration_secs
+    core, pc = "/jax/core/compile/", "/jax/compilation_cache/"
+    tracing.install()
+    mark = _mark()
+    with tracing.Span("setup.test_cache"):
+        dur(core + "jaxpr_trace_duration", 0.5, fun_name="replayed")
+        # JAX traces small things between a trace and its lowering:
+        # the record takes the trace of ITS name
+        dur(core + "jaxpr_trace_duration", 0.0625, fun_name="a_cast")
+        dur(core + "jaxpr_to_mlir_module_duration", 0.25,
+            fun_name="jit(replayed)")
+        if cache != "off":
+            say(pc + "compile_requests_use_cache")
+        if cache == "hit":
+            say(pc + "cache_hits")
+            dur(pc + "compile_time_saved_sec", 9.0)
+            dur(pc + "cache_retrieval_time_sec", 0.125)
+        if cache == "miss":
+            say(pc + "cache_misses")
+        dur(core + "backend_compile_duration", 1.0,
+            fun_name="jit(replayed)")
+    # outside every span: no record, and its cache events are nobody's
+    dur(core + "jaxpr_to_mlir_module_duration", 0.25, fun_name="jit(late)")
+    say(pc + "cache_misses")
+    new = _log_since(mark)
+    assert [p["fun_name"] for p in new["programs"]] == ["jit(replayed)"]
+    rec = new["programs"][0]
+    assert rec["cache"] == cache
+    assert (rec["trace_s"], rec["lower_s"]) == (0.5, 0.25)
+    # the backend's second holds the retrieval: never added twice
+    assert rec["compile_s"] + rec["retrieval_s"] == 1.0
+    assert rec["retrieval_s"] == (0.125 if cache == "hit" else 0.0)
+    summed = tracing.summary(new)
+    assert summed["cache_misses"] == (cache == "miss")
+    assert summed["step_programs"] == 0 == summed["rebuilt_programs"]
+
+
+def test_collector_pauses_go_to_the_innermost_open_setup_span_only():
+    tracing.install()
+    mark = _mark()
+    acc = {"dispatch": 0.0}
+    with tracing.Span("setup.test_outer"):
+        with tracing.Span("setup.test_inner"):
+            # a phase of a step is no set-up: it collects nothing
+            with tracing.Span("engine.dispatch", acc, "dispatch"):
+                gc.collect()
+    assert tracing._setup_open == 0
+    gc.collect()                          # no span open: one comparison
+    spans = {s["name"]: s for s in _log_since(mark)["spans"]}
+    assert set(spans) == {"setup.test_outer", "setup.test_inner"}
+    assert spans["setup.test_inner"]["gc_s"] > 0.0
+    assert spans["setup.test_outer"]["gc_s"] == 0.0
+    assert acc["dispatch"] > 0.0
+
+
+def test_a_second_model_and_engine_register_no_second_listener(
+        mesh1, models):
+    def ours():
+        return (
+            sum(f is tracing._on_duration for f in
+                monitoring.get_event_duration_listeners()),
+            sum(f is tracing._on_event for f in
+                monitoring.get_event_listeners()),
+            sum(f is tracing._on_gc for f in gc.callbacks))
+
+    assert ours() == (1, 1, 1)            # ``models`` built the first
+    model, params = _model(mesh1, "dense")
+    ServingEngine(model, params, ECFG)
+    ServingEngine(model, params, ECFG)
+    assert ours() == (1, 1, 1)
+
+
+def test_importing_the_package_registers_no_listener():
+    code = (
+        "import gc\n"
+        "from jax._src import monitoring\n"
+        "import triton_distributed_tpu.models, "
+        "triton_distributed_tpu.serving, triton_distributed_tpu.tracing\n"
+        "assert not monitoring.get_event_duration_listeners()\n"
+        "assert not monitoring.get_event_listeners()\n"
+        "assert triton_distributed_tpu.tracing._on_gc not in gc.callbacks\n")
+    done = subprocess.run(
+        [sys.executable, "-c", code], cwd=ROOT, capture_output=True,
+        text=True, timeout=300,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"})
+    assert done.returncode == 0, done.stderr[-2000:]
+
+
+def test_the_benchmarks_readers_read_a_tiny_engines_start_up(mesh1):
+    """Every ``setup_*`` metric file through the benchmark's own
+    ``read_layer_metric``, on this process's log; the sums of
+    ``benchmark/README-pr39.md`` hold."""
+    model, params = _model(mesh1, "ep")
+    eng = ServingEngine(model, params, ECFG, use_pallas=False)
+    program.warm_up(eng, CFG["vocab"])
+    assert len(SETUP_METRICS) == 13
+    read = {}
+    for name in SETUP_METRICS:
+        definition = json.loads(
+            (BENCH / "layer_metrics" / f"{name}.json").read_text())
+        read[name] = metrics.read_layer_metric({}, definition)
+        assert read[name] is not None and read[name] >= 0, name
+    # the log is the process's: at least this engine's programs
+    assert read["setup_step_programs"] >= eng.stats.programs_built == 2
+    assert (read["setup_trace_s"] + read["setup_lower_s"]
+            + read["setup_compile_s"]) <= read["setup_step_programs_s"]
+    assert read["setup_state_s"] + read["setup_workspaces_s"] \
+        <= read["setup_engine_s"]
+    assert read["setup_step_program_s_max"] <= read["setup_step_programs_s"]
+    # and they agree with the program's own sums, name for name
+    summed = tracing.summary()
+    assert {f"setup_{k}": v for k, v in summed.items()} == read
+    line = tracing.ready_line()
+    assert line.startswith("ready: model ") and "step programs" in line

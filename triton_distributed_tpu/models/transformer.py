@@ -39,7 +39,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from triton_distributed_tpu import ops
+from triton_distributed_tpu import ops, tracing
 from triton_distributed_tpu.kernels import moe_utils as mu
 from triton_distributed_tpu.layers import (
     ColumnParallelLinear,
@@ -645,6 +645,15 @@ class Transformer:
     cp_axis: str | None = None
 
     def __post_init__(self):
+        # the build log's listeners, once a process: at the first model,
+        # never at import
+        tracing.install()
+        with tracing.Span("setup.model"):
+            self._check()
+
+    def _check(self) -> None:
+        """Refuse, by name, what this configuration on this mesh cannot
+        run."""
         c = self.config
         if c.experts_held and self.tp > 1:
             raise ValueError(

@@ -49,6 +49,7 @@ over onto the survivor).
 
 from __future__ import annotations
 
+import contextlib
 import itertools
 import logging
 import time
@@ -58,6 +59,8 @@ from dataclasses import dataclass, field
 import jax
 import numpy as np
 from jax.sharding import NamedSharding, PartitionSpec
+
+from triton_distributed_tpu.tracing import Span, spanned
 
 
 #: Priority classes, best-first: admission, eviction-victim selection
@@ -247,31 +250,15 @@ class EngineConfig:
     greedy_on_device: bool = False
 
 
+#: what a step whose program is built already opens in
+#: ``setup.program``'s place: nothing
+_STEADY = contextlib.nullcontext()
+
 #: the phases of one ``ServingEngine.step``, in order; each is the host
-#: span ``engine.<phase>`` and the per-step list ``EngineStats.<phase>_times``
+#: span ``engine.<phase>`` (``tracing.Span``: on a profiler's trace, and
+#: a ``perf_counter`` pair) and the per-step list
+#: ``EngineStats.<phase>_times``
 PHASES = ("admit", "assemble", "upload", "dispatch", "fetch", "advance")
-
-
-class _Phase:
-    """One phase of a step, measured twice: as the host span
-    ``engine.<phase>`` (a ``jax.profiler.TraceAnnotation``, so it lies on
-    the device trace's clock; ``step=`` is what the spans of one step
-    share; a no-op while no profiler runs) and as a ``perf_counter``
-    pair added to ``acc[phase]``, which is what remains untraced."""
-
-    __slots__ = ("ann", "acc", "phase", "t0")
-
-    def __init__(self, acc: dict, phase: str, step: int):
-        self.ann = jax.profiler.TraceAnnotation(f"engine.{phase}", step=step)
-        self.acc, self.phase = acc, phase
-
-    def __enter__(self):
-        self.ann.__enter__()
-        self.t0 = time.perf_counter()
-
-    def __exit__(self, *exc):
-        self.acc[self.phase] += time.perf_counter() - self.t0
-        self.ann.__exit__(*exc)
 
 
 @dataclass
@@ -364,6 +351,15 @@ class EngineStats:
     # of the program — every array of ``ops/moe.py::_expert_mlp`` is
     # that long whatever the step holds; 0 with no EP expert layer)
     moe_aligned_rows: int = 0
+    # step programs this engine dispatched a FIRST time (one a program
+    # key: ``block_q``, packed width, ``use_pallas``, ``n_bufs``), and
+    # the seconds of their ``setup.program`` spans: tracing, lowering
+    # and the compile or the load from the cache. Set-up is no step's:
+    # booked at the dispatch, not at the retirement. A warm-up that has
+    # visited every rung leaves both still; ``tracing.startup_log``
+    # names each program's key and step
+    programs_built: int = 0
+    program_build_s: float = 0.0
     prefix_hits: int = 0               # pages reattached from the cache
     # --- in-batch shared-prefix dedup (EngineConfig.prefix_share) ---
     shared_prefix_rows: int = 0        # batched rows marked SHARED_PREFIX
@@ -718,6 +714,7 @@ class ServingEngine:
     # analysis/servlint.py builds its shell without ``__init__``)
     _flight: _Flight | None = None
 
+    @spanned("setup.engine")
     def __init__(self, model, params, cfg: EngineConfig, *,
                  moe_state="auto", use_pallas: bool = True,
                  on_complete=None, health=None,
@@ -761,9 +758,10 @@ class ServingEngine:
                 ("prefill_only", cfg.prefill_only)):
             if on:
                 refuse(self._kinds, feature)
-        self.state = model.init_serving_state(
-            cfg.slots, cfg.npages, cfg.page, chunk=cfg.chunk
-        )
+        with Span("setup.state"):
+            self.state = model.init_serving_state(
+                cfg.slots, cfg.npages, cfg.page, chunk=cfg.chunk
+            )
         if self._window:
             st = self.state
             logging.getLogger(__name__).info(
@@ -828,7 +826,7 @@ class ServingEngine:
         # likewise: [selected pages, sparse rows, state rows]
         self._state_work = [0, 0, 0]
         self._latent_work = [0, 0]      # likewise: [pages fetched, rows]
-        # seconds of the running step inside each phase (``_Phase``)
+        # seconds of the running step inside each phase (``_phase``)
         self._phase_s = dict.fromkeys(PHASES, 0.0)
         # --- multi-tenancy (all defaults reproduce the single-tenant
         # engine exactly: one implicit tenant at full shares, rank 0,
@@ -873,32 +871,35 @@ class ServingEngine:
         self._grid_key = (cfg.slots, self._t_pad, c.n_kv_heads, g,
                           c.head_dim, cfg.page, cfg.chunk) \
             + self._spec_key()
-        sched = resolve_schedule(
-            "flash_decode.ragged_paged", self._grid_key, (model.tp,),
-            "int8" if c.kv_quant is not None else None, grid_schedule,
-        )
-        if getattr(sched, "kind", "ring") != "grid":
-            sched = GRID_DEFAULT      # stale ring entry: ignore
-        self.grid_schedule = sched
-        self._n_bufs = int(sched.n_bufs)
-        # tuned block_q is a FLOOR under the parking-zone cap: a step
-        # launches at the rung ``_rung`` gives, and its packed array
-        # carries that rung's parking tokens
-        self._block_q_floor = int(sched.block_q)
-        # LL MoE workspaces, sized to the packed step width: one set per
-        # DISTINCT width, ``{width: per-layer states}``, built here and
-        # never inside a step (``EPMoEState.instance`` is static: a
-        # state belongs to the kernels compiled for its width). None
-        # when the model has no fused-transport EP layers
-        if moe_state == "auto":
-            moe_state = {
-                w: model.init_decode_state(w)
-                for w in sorted({self._width(b) for b in self._rungs()})
-            }
-            if None in moe_state.values():
-                moe_state = None
-        self.moe_state = moe_state
+        with Span("setup.workspaces"):
+            sched = resolve_schedule(
+                "flash_decode.ragged_paged", self._grid_key, (model.tp,),
+                "int8" if c.kv_quant is not None else None, grid_schedule,
+            )
+            if getattr(sched, "kind", "ring") != "grid":
+                sched = GRID_DEFAULT      # stale ring entry: ignore
+            self.grid_schedule = sched
+            self._n_bufs = int(sched.n_bufs)
+            # tuned block_q is a FLOOR under the parking-zone cap: a step
+            # launches at the rung ``_rung`` gives, and its packed array
+            # carries that rung's parking tokens
+            self._block_q_floor = int(sched.block_q)
+            # LL MoE workspaces, sized to the packed step width: one set per
+            # DISTINCT width, ``{width: per-layer states}``, built here and
+            # never inside a step (``EPMoEState.instance`` is static: a
+            # state belongs to the kernels compiled for its width). None
+            # when the model has no fused-transport EP layers
+            if moe_state == "auto":
+                moe_state = {
+                    w: model.init_decode_state(w)
+                    for w in sorted({self._width(b) for b in self._rungs()})
+                }
+                if None in moe_state.values():
+                    moe_state = None
+            self.moe_state = moe_state
         self._aligned_rows: dict = {}      # ``_moe_aligned_rows``
+        # program keys (``_run_device``) this engine has dispatched
+        self._dispatched: set = set()
         if cfg.token_budget % 8:
             raise ValueError("token_budget must be 8-aligned")
         if cfg.chunk > cfg.token_budget:
@@ -1402,8 +1403,9 @@ class ServingEngine:
         self._uploads[name] = (host.copy(), self._jnp.asarray(host))
         return self._uploads[name][1]
 
-    def _phase(self, phase: str) -> _Phase:
-        return _Phase(self._phase_s, phase, self.step_count)
+    def _phase(self, phase: str) -> Span:
+        return Span(f"engine.{phase}", self._phase_s, phase,
+                    step=self.step_count)
 
     def _run_device(self, arrays, block_q):
         """Upload one assembled batch and dispatch its step; returns the
@@ -1414,7 +1416,19 @@ class ServingEngine:
 
         with self._phase("upload"):
             args = self._step_args(arrays, block_q)
-        with self._phase("dispatch"):
+        # the program this step runs is built (traced, lowered, compiled
+        # or loaded from the cache) inside its FIRST dispatch, which the
+        # span ``setup.program`` holds; a build under a key seen before
+        # is the log's, as a rebuild. The jitted call stays in THIS
+        # frame, first dispatch or not: JAX's tracing and lowering times
+        # move by seconds with the shape of the Python stack above them
+        # (PERF.md §6, PR 39)
+        width = len(arrays[0])
+        key = (block_q, width, self.use_pallas, self._n_bufs)
+        build = _STEADY if key in self._dispatched else Span(
+            "setup.program", step=self.step_count, block_q=block_q,
+            width=width)
+        with self._phase("dispatch"), build:
             # host-mode heartbeat around the jitted step: an armed
             # watchdog sees a wedged serving step (site "serving_step"),
             # and a fault-plan Stall at that site gates here
@@ -1428,7 +1442,7 @@ class ServingEngine:
                 logits, self.state = out
             else:
                 logits, self.state, states = out
-                self.moe_state[len(arrays[0])] = states
+                self.moe_state[width] = states
                 # ONE parity sequence for every width: the barrier-free
                 # protocol alternates parity from a STEP to the next, so
                 # that a peer one step ahead signals the semaphores the
@@ -1439,16 +1453,20 @@ class ServingEngine:
                         for mine, new in zip(other, states):
                             if mine is not None:
                                 mine.parity = new.parity
-            if self._greedy is None:
-                return logits
-            # the ids stay on the device: the next step's packed tokens
-            # are merged from them there (``_merge_tokens``)
-            self._ids = self._greedy(logits)
-            return self._ids
+            if self._greedy is not None:
+                # the ids stay on the device: the next step's packed
+                # tokens are merged from them there (``_merge_tokens``)
+                logits = self._ids = self._greedy(logits)
+        if build is not _STEADY:
+            self._dispatched.add(key)
+            self.stats.programs_built += 1
+            self.stats.program_build_s += build.seconds
+        return logits
 
     def _fetch(self, flight: _Flight) -> None:
         """Bring a dispatched step's result down (``flight.host``)."""
-        with _Phase(flight.phase_s, "fetch", flight.step):
+        with Span("engine.fetch", flight.phase_s, "fetch",
+                  step=flight.step):
             # the host fetch is the fence: the wait for the step program,
             # the copy down and the delinearize, deliberately one span (a
             # block_until_ready before it would put a host wake-up on
@@ -1712,7 +1730,7 @@ class ServingEngine:
                 raise
         stats, phase_s, report = self.stats, flight.phase_s, flight.report
         dt = phase_s["upload"] + phase_s["dispatch"] + phase_s["fetch"]
-        with _Phase(phase_s, "advance", flight.step):
+        with Span("engine.advance", phase_s, "advance", step=flight.step):
             gen_this_step = 0
             prefill_this_step = 0
             for s, take in flight.takes.items():
