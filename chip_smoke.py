@@ -281,10 +281,13 @@ def engine(model, params, on_chip: bool):
             -(-eng._t_pad // model.token_shards), inference=True,
             weights_quantized=True,
         )
+        # one chip exchanges with nobody: no workspaces; across chips
+        # every width's step carries its own
         need(ctx.transport == "fused" and ctx.use_pallas_gemm
-             and eng.moe_state is not None,
+             and all((ws is None) == ctx.local
+                     for ws in eng.moe_state.values()),
              f"EP context resolved transport={ctx.transport!r} "
-             f"use_pallas_gemm={ctx.use_pallas_gemm}")
+             f"use_pallas_gemm={ctx.use_pallas_gemm} ranks={ctx.n}")
     return eng
 
 
@@ -326,7 +329,7 @@ def spread(eng, params, n: int) -> dict:
         "moe_up": params["blocks"][1]["moe_up"]["q"],
         "kv_pool": eng.state.layers[1][0]["q"],
     }
-    if eng.moe_state is not None:       # always, on the chip (engine())
+    if eng.moe_state[eng._t_pad] is not None:   # across chips (engine())
         big["ll_dispatch"] = eng.moe_state[eng._t_pad][1].disp_tok
     out = {}
     for name, x in big.items():

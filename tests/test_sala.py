@@ -724,18 +724,23 @@ def _step_texts(cfg, ecfg) -> tuple:
 
 
 # per configuration: the digest through the engine's path (PR 34), and
-# with the uploaded tokens (taken on the tree before PR 33)
+# with the uploaded tokens (taken on the tree before PR 33). RE-TAKEN IN
+# PR 40, which changes these three programs on purpose: their expert
+# layers, at one rank, sort once and exchange with nobody
+# (``tests/test_moe_local.py`` holds the new block to the old results).
+# Before: 82dab0446a04e3f4 / 57ca03e7507824f3, 8c8a6a0b5e338ae8 /
+# ee0bb4b9c115ff09, 7ae6d2b45eac2673 / b82a79e936396471
 ACCEPTED = {
     "dsmoe16b": (lambda: presets.tiny(presets.deepseek_moe_16b()),
-                 "82dab0446a04e3f4", "57ca03e7507824f3"),
+                 "1cb5357bf36f5ab5", "620b044c34f39bba"),
     "mixtral8x7b": (lambda: presets.tiny(presets.mixtral_8x7b()),
-                    "8c8a6a0b5e338ae8", "ee0bb4b9c115ff09"),
+                    "5b29d8790cd7dd07", "479209065905fea7"),
     "kexaone236b": (lambda: presets.tiny(
         presets.k_exaone_236b(), n_layers=5,
         layer_attn=("sliding", "sliding", "sliding", "full", "sliding"),
         rope_layers=(0, 1, 2, 4), moe_layers=(1, 2, 3, 4), window=16,
         num_experts=8, experts_held=4, first_expert_held=2),
-        "7ae6d2b45eac2673", "b82a79e936396471"),
+        "fbd6fa9258d83387", "c03340e322b6ca17"),
 }
 
 
@@ -743,9 +748,10 @@ ACCEPTED = {
 def test_the_accepted_configurations_lower_the_programs_they_lowered(name):
     """The new fields default to the model that was there: the step
     program of each accepted configuration's twin is, instruction for
-    instruction, the one the tree before PR 33 lowered (the second
-    digest was taken there, with the host's upload as ``tokens``), and
-    holds none of the new scopes or launches. As the ENGINE calls it
+    instruction, the one the tree before PR 33 lowered but for PR 40's
+    expert layer (the second digest was taken there, with the host's
+    upload as ``tokens``, and again under PR 40), and holds none of the
+    new scopes or launches. As the ENGINE calls it
     (the first digest, taken under PR 34) ``tokens`` is the array merged
     on the device, a committed one: that argument of ``@main``, alone,
     carries a replicated-sharding attribute, the private functions are

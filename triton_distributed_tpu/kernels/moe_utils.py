@@ -98,41 +98,61 @@ def aligned_capacity(total: int, num_experts: int, block_m: int) -> int:
     return round_up_to_block(total + num_experts * (block_m - 1), block_m)
 
 
-def moe_align_block_size(topk_ids, num_experts: int, block_m: int):
+def moe_align_block_size(topk_ids, num_experts: int, block_m: int, *,
+                         positions: bool = False):
     """Sort (token, slot) pairs by expert and pad segments to block_m.
 
-    topk_ids: (M, k) int32. Returns:
+    topk_ids: (M, k) int32, every id in [0, num_experts). Returns:
       sorted_token_ids: (cap,) int32 — flat source index ``row*k + slot``
         per padded position, sentinel ``M*k`` for padding (gather a zero
         row there);
       block_expert: (cap//block_m,) int32 — owning expert of each block;
-      splits: (num_experts,) int32 — true token count per expert.
+      splits: (num_experts,) int32 — true token count per expert;
+      with ``positions``, also (M*k,) int32 — each flat source index's
+        padded position (the inverse of ``sorted_token_ids``: what an
+        un-sort gathers by).
     ≡ moe_ag_scatter_align_block_size (csrc/lib/moe_utils.cu:61-356).
+
+    ONE sort; counts, offsets and block owners by COMPARISON against
+    the expert ids (a fused compare-and-sum over pairs × experts,
+    ~1 µs on the chip), not by a scatter-add, a gather from a table of
+    offsets or a bisection: each of those is serialized there, 10–20 µs
+    for a few thousand int32 and a ``while`` for the bisection —
+    several times the sort itself (PERF.md §6, PR 40).
     """
     m, k = topk_ids.shape
     total = m * k
     cap = aligned_capacity(total, num_experts, block_m)
     flat = topk_ids.reshape(-1).astype(jnp.int32)
+    experts = jnp.arange(num_experts, dtype=jnp.int32)
 
-    splits = jnp.zeros((num_experts,), jnp.int32).at[flat].add(1)
+    splits = jnp.sum(flat[:, None] == experts[None, :], axis=0,
+                     dtype=jnp.int32)
     padded = round_up_to_block(splits, block_m)
-    padded_offs = exclusive_cumsum(padded)
-    offs = exclusive_cumsum(splits)
-
-    order = jnp.argsort(flat, stable=True).astype(jnp.int32)   # (total,)
-    sorted_experts = flat[order]
-    rank_in_expert = jnp.arange(total, dtype=jnp.int32) - offs[sorted_experts]
-    dest = padded_offs[sorted_experts] + rank_in_expert
+    # the one sort carries each id's flat source index (a stable argsort)
+    sorted_experts, order = jax.lax.sort(
+        (flat, jnp.arange(total, dtype=jnp.int32)), num_keys=1,
+        is_stable=True)
+    # a sorted pair's padded position: its sorted position plus the
+    # alignment padding of the experts before its own
+    pad_before = exclusive_cumsum(padded - splits)
+    dest = jnp.arange(total, dtype=jnp.int32) + jnp.sum(
+        jnp.where(sorted_experts[:, None] == experts[None, :],
+                  pad_before[None, :], 0), axis=1, dtype=jnp.int32)
 
     sorted_token_ids = jnp.full((cap,), total, jnp.int32).at[dest].set(order)
 
     nblocks = cap // block_m
     block_start = jnp.arange(nblocks, dtype=jnp.int32) * block_m
     block_expert = jnp.searchsorted(
-        jnp.cumsum(padded), block_start, side="right"
+        jnp.cumsum(padded), block_start, side="right",
+        method="compare_all",
     ).astype(jnp.int32)
     block_expert = jnp.clip(block_expert, 0, num_experts - 1)
-    return sorted_token_ids, block_expert, splits
+    if not positions:
+        return sorted_token_ids, block_expert, splits
+    inverse = jnp.zeros((total,), jnp.int32).at[order].set(dest)
+    return sorted_token_ids, block_expert, splits, inverse
 
 
 def gather_sorted(x, sorted_token_ids, topk: int):

@@ -72,10 +72,11 @@ class TransformerConfig:
     # Quantized wire for the fused EP-MoE DECODE transport ("fp8" |
     # "int8" | None): tokens cross the a2a at 1 byte/elem with
     # per-token scales in the metadata (≡ the reference's headline fp8
-    # WITH_SCALE dispatch). Halves the decode wire bytes at n>1;
-    # measured neutral at n=1 self-transport (docs/PERF.md). Training
-    # is unaffected (it rides the differentiable full-precision
-    # transport).
+    # WITH_SCALE dispatch). Halves the decode wire bytes at n>1. ONE
+    # rank (tp = 1) exchanges with nobody and has no wire: nothing is
+    # quantized for it there (``ops/moe.py::EPMoEContext.local``).
+    # Training is unaffected (it rides the differentiable
+    # full-precision transport).
     moe_wire_quant: str | None = None
     # Weight-only quantization of the EP expert matrices ("int8" |
     # "fp8" | None): serving-decode grouped GEMMs are weight-HBM-bound
@@ -589,8 +590,9 @@ def expert_block_m(rows: int, topk: int, experts: int, *, resident: bool,
 
     Every expert's segment of the sorted buffer is padded to a multiple
     of the block, so the buffer — and with it the sort, the gather into
-    it, the re-quantization, the activation and the GEMMs' stores, all
-    of ``ops/moe.py::_expert_mlp`` — is ``received rows + (held experts
+    it, the re-quantization, the activation and the GEMMs' stores
+    (``ops/moe.py::_grouped_mlp`` and the gather in front of it) — is
+    ``received rows + (held experts
     + 1)·(block − 1)`` rows long whatever the step holds. A served
     step's grouped GEMM is weight-byte-bound (a decode-only step gives
     a touched expert 1–7 rows), so the block buys nothing on the MXU
@@ -1319,8 +1321,10 @@ class Transformer:
         """Per-layer persistent workspaces for the BARRIER-FREE fused
         EP-MoE decode transport (ops.EPMoEState): one state per MoE
         layer, None elsewhere. Returns None when the model has no EP
-        layers or decode would ride the XLA transport (off-TPU / DCN tp
-        axis) — :meth:`serving_step` then needs no state at all.
+        layers, when the exchange has ONE rank (tp = 1: no exchange,
+        so no receive windows) or when decode would ride the XLA
+        transport (off-TPU / DCN tp axis) — :meth:`serving_step` then
+        needs no state at all.
         ``batch`` is a step's packed width (the engine builds one set
         per distinct width its steps take, ``ServingEngine._width``).
         ``abstract=True`` yields ShapeDtypeStruct leaves (topology
@@ -1330,7 +1334,7 @@ class Transformer:
             return None
         m_local = -(-batch // self.token_shards)
         ctx = self._moe_ep_ctx(m_local, inference=True)
-        if ctx.transport != "fused":
+        if ctx.local or ctx.transport != "fused":
             return None
         from triton_distributed_tpu.ops import create_ep_moe_state
 
@@ -1355,6 +1359,16 @@ class Transformer:
             -(-batch // self.token_shards), inference=True,
             weights_quantized=wq).aligned_rows
 
+    @property
+    def moe_local(self) -> bool:
+        """Whether a serving step's EP expert layers exchange with
+        nobody (``EPMoEContext.local``: ONE rank on the tp axis, a
+        static of the mesh): no dispatch, no combine, no workspaces.
+        False for a model with no EP expert layer."""
+        c = self.config
+        return (c.moe == "ep" and bool(c.moe_layers)
+                and self._moe_ep_ctx(1, inference=True).local)
+
     def _dense_mlp(self, xn, w_up, w_down):
         """The serving step's dense FFN on normed rows ``xn``:
         ``down(silu(up(x)))``, or gated (``config.gated_ffn``, ``w_up``
@@ -1376,7 +1390,9 @@ class Transformer:
         low_latency_all_to_all.py:36-118). B is padded up to the token
         -shard count; pad rows are discarded after the combine. With
         ``state``, the transport runs barrier-free over the persistent
-        workspaces; returns (y, state'). ``row_mask`` (B,) bool: the
+        workspaces; returns (y, state'). At tp = 1 there is no exchange
+        and no state: the op sorts, gathers, multiplies and un-sorts in
+        place (``EPMoEContext.local``). ``row_mask`` (B,) bool: the
         rows that are tokens of the step — the assignments of every
         other row (a serving step's padding) are handed to the op
         masked, so they are neither shipped nor multiplied; their ``y``

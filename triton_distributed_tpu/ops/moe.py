@@ -20,6 +20,11 @@ weighted combine. Three transports:
 * ``transport="xla"``: ``lax.all_to_all`` — differentiable end-to-end
   (sort/gather/scatter/topk-softmax all have transpose rules), which is
   what makes EP *training* possible; the reference is inference-only.
+
+ONE rank (``EPMoEContext.local``: a one-chip mesh axis) exchanges with
+nobody: the body sorts its own assignments once, gathers once, runs the
+same grouped GEMMs and un-sorts — no transport of any kind, no wire
+quantization, no receive windows (``_local_assignments_device``).
 """
 
 from __future__ import annotations
@@ -89,7 +94,10 @@ class EPMoEContext:
     # 1 byte/elem with per-token scales in the wire metadata (≡ the
     # reference's headline fp8 WITH_SCALE dispatch). Carried by the
     # "fused" and "pallas" transports; the XLA transport is the
-    # differentiable path and stays full-precision.
+    # differentiable path and stays full-precision. It is the encoding
+    # of bytes that cross a link: ONE rank (``local``) has no wire, so
+    # the field is accepted there (a preset is written for any mesh)
+    # and nothing is quantized for it.
     quant: str | None = None
     # W8A8 expert GEMMs ("int8"): quantize the ACTIVATIONS per row too
     # and run the MXU's native s8×s8→s32 path (2× the bf16 rate, the
@@ -138,8 +146,18 @@ class EPMoEContext:
         return self.num_experts // self.n
 
     @property
+    def local(self) -> bool:
+        """ONE rank holds every expert of the exchange (a static of the
+        mesh): its assignments are sorted and multiplied where they
+        are, whatever ``transport`` says."""
+        return self.n == 1
+
+    @property
     def recv_rows(self) -> int:
-        """Rows ``_expert_mlp`` is handed: every peer's receive slot."""
+        """Rows the expert MLP is handed: every peer's receive slot —
+        ONE rank's own ``max_m`` assignments, in place."""
+        if self.local:
+            return self.max_m
         if self.transport == "fused":
             from triton_distributed_tpu.kernels import moe_dispatch as md
 
@@ -149,7 +167,7 @@ class EPMoEContext:
     @property
     def aligned_rows(self) -> int:
         """Rows of the expert-sorted buffer (and of every array of
-        ``_expert_mlp`` behind it): the received rows plus up to
+        ``_grouped_mlp`` behind it): the received rows plus up to
         ``block_m - 1`` rows of alignment an expert and the dummy
         group."""
         return mu.aligned_capacity(
@@ -267,6 +285,11 @@ def create_ep_moe_state(ctx: EPMoEContext, abstract: bool = False) -> EPMoEState
             "EPMoEState rides the flat fused transport "
             f"(got transport={ctx.transport!r}, dcn_axis={ctx.dcn_axis!r})"
         )
+    if ctx.local:
+        raise ValueError(
+            "EPMoEState: one EP rank exchanges with nobody, so there are "
+            "no receive windows to keep — call ep_moe without state"
+        )
     a2a = ctx.a2a
     (tok_shape, tok_dt), (meta_shape, meta_dt) = md.ll_workspace_shapes(a2a)
     row_axes = tuple(ctx.batch_axes) + ctx.ep_axes
@@ -361,16 +384,18 @@ def _combine(ctx: EPMoEContext, y_slots, splits, total):
     return ma.combine_unstage(a2a, toks, splits, total)
 
 
-def _expert_mlp(ctx: EPMoEContext, rows, eid, valid, w_up, w_down):
-    """Grouped MLP over this rank's experts.
+def _grouped_mlp(ctx: EPMoEContext, xs, be, counts, w_up, w_down):
+    """The two grouped GEMMs over this rank's experts.
 
-    rows: (R, H) received tokens; eid: (R,) local expert ids; valid: (R,)
-    bool. w_up: (epr, H, F); w_down: (epr, F, H). Invalid rows are zero
-    and sorted into a trailing dummy group, so they contribute zeros;
-    the Pallas GEMM stores that group's blocks (and the capacity no row
-    fills) as zeros without fetching or multiplying a weight
+    xs: (cap, H) rows sorted by local expert, each expert's segment
+    padded to ``ctx.block_m`` (``moe_utils.moe_align_block_size``: ``be``
+    the blocks' owners, ``counts`` the true rows an expert and of the
+    trailing DUMMY group ``epr``). w_up: (epr, H, F); w_down: (epr, F,
+    H). The dummy group's rows and the padding are zero and contribute
+    zeros; the Pallas GEMM stores that group's blocks (and the capacity
+    no row fills) as zeros without fetching or multiplying a weight
     (``grouped_matmul(dummy_expert=)``), the ``ragged_dot`` twin folds
-    them into the last expert's group.
+    them into the last expert's group. Returns (cap, H), sorted.
 
     Either weight may instead be a WEIGHT-QUANTIZED dict
     ``{"q": (epr, K, N) int8/fp8, "scale": (epr, N) f32}`` (from
@@ -379,15 +404,7 @@ def _expert_mlp(ctx: EPMoEContext, rows, eid, valid, w_up, w_down):
     dominate decode-size grouped GEMMs; the XLA twin widens first.
     """
     epr = ctx.experts_per_rank
-    r = rows.shape[0]
-    # sort received rows by local expert, invalid rows to a dummy tail
-    # group — the align-block trick over receive-side data
-    ids = jnp.where(valid, eid, epr).astype(jnp.int32)[:, None]
-    sti, be, counts = mu.moe_align_block_size(ids, epr + 1, ctx.block_m)
-    cap = sti.shape[0]
-    safe = jnp.clip(sti, 0, r - 1)
-    ok = (sti < r) & valid[safe]
-    xs = jnp.where(ok[:, None], rows[safe], 0).astype(ctx.dtype)
+    cap = xs.shape[0]
 
     def act(h):
         # gated: the GEMM gave [gate | up]; the hidden is act(gate)·up
@@ -464,18 +481,93 @@ def _expert_mlp(ctx: EPMoEContext, rows, eid, valid, w_up, w_down):
         h = act(h).astype(ctx.dtype)
         y = jax.lax.ragged_dot(h, w_down, gs)
     # no post-GEMM re-masking: invalid/slack rows entered the GEMMs as
-    # exact zeros (xs above), so their outputs are exact zeros — the
-    # old (cap, H) `where` pass was a full ~23 MB r+w of dead HBM
-    # bandwidth at serving shapes.
+    # exact zeros (the caller's ``xs``), so their outputs are exact
+    # zeros — the old (cap, H) `where` pass was a full ~23 MB r+w of
+    # dead HBM bandwidth at serving shapes.
+    return y
+
+
+def _expert_mlp(ctx: EPMoEContext, rows, eid, valid, w_up, w_down):
+    """Grouped MLP over RECEIVED rows (the exchange's receive side).
+
+    rows: (R, H) received tokens; eid: (R,) local expert ids; valid: (R,)
+    bool. Invalid rows are zero and sorted into the trailing dummy group
+    of :func:`_grouped_mlp`. Returns (R, H) in receive order."""
+    epr = ctx.experts_per_rank
+    r = rows.shape[0]
+    # sort received rows by local expert, invalid rows to a dummy tail
+    # group — the align-block trick over receive-side data
+    ids = jnp.where(valid, eid, epr).astype(jnp.int32)[:, None]
+    sti, be, counts = mu.moe_align_block_size(ids, epr + 1, ctx.block_m)
+    safe = jnp.clip(sti, 0, r - 1)
+    ok = (sti < r) & valid[safe]
+    xs = jnp.where(ok[:, None], rows[safe], 0).astype(ctx.dtype)
+    y = _grouped_mlp(ctx, xs, be, counts, w_up, w_down)
     # un-sort via inverse-permutation GATHER: every received row index
     # appears exactly once in sti (it is a sort of all r rows), so the
     # inverse is total — scatter only the (cap,) int32 iota (trivial;
     # padding entries drop out of bounds), then move the big array with
     # one gather instead of scattering (cap, H) rows.
     inv = jnp.zeros((r,), jnp.int32).at[sti].set(
-        jnp.arange(cap, dtype=jnp.int32), mode="drop"
+        jnp.arange(sti.shape[0], dtype=jnp.int32), mode="drop"
     )
     return y[inv]
+
+
+def _weighted(y, w, live):
+    """Expert outputs ``y`` (.., H) times their f32 combine weights ``w``
+    (the same shape less H), in f32, where ``live`` (= ``w != 0``).
+    Masked assignments carry weight exactly 0, but their y rows may be
+    garbage (untransported window slack) — zero them before the MAC so
+    a stray inf/nan cannot poison the sum. Under debug_checksum the
+    poison NaNs ride rows with nonzero weight, so they stay loud."""
+    return jnp.where(
+        live[..., None], y.astype(jnp.float32) * w[..., None], 0.0)
+
+
+def _local_assignments_device(ctx: EPMoEContext, x, flat_e, w_flat,
+                              out_rows, w_up, w_down):
+    """:func:`_ep_assignments_device` where the exchange has ONE rank
+    (``ctx.local``): every expert of it is held here, so nothing is
+    staged, shipped, received or returned — one sort of the assignments
+    by expert with block alignment, one gather of their rows into the
+    sorted buffer, the grouped GEMMs, one un-sort gather, the weighted
+    sum. No wire, so no wire quantization either (``ctx.quant`` encodes
+    bytes that cross a link); no loop: a block's owner is looked up by
+    comparison. The four device scopes stay, over what took the
+    exchange's place."""
+    epr = ctx.experts_per_rank
+    total = flat_e.shape[0]
+    with jax.named_scope("moe_route"):
+        # a masked assignment (the sentinel, or any id past the held
+        # experts) sorts into the dummy group
+        ids = jnp.minimum(flat_e.astype(jnp.int32), epr)[:, None]
+        sti, be, counts, inv = mu.moe_align_block_size(
+            ids, epr + 1, ctx.block_m, positions=True)
+    with jax.named_scope("moe_dispatch"):
+        # the dummy group's rows (its blocks are the trailing ones) and
+        # every segment's padding enter the GEMMs as zeros: they gather
+        # a zero row appended to ``x`` (a select behind the gather is a
+        # second pass over the whole sorted buffer where the compiler
+        # does not fuse the two: 156 µs a layer at 8320 x 6144)
+        ok = (sti < total) & jnp.repeat(be < epr, ctx.block_m)
+        rows = jnp.where(ok, sti // ctx.topk, x.shape[0])
+        xs = jnp.concatenate(
+            [x, jnp.zeros((1, ctx.hidden), x.dtype)])[rows].astype(ctx.dtype)
+    with jax.named_scope("moe_gemm"):
+        y = _grouped_mlp(ctx, xs, be, counts, w_up, w_down)
+    with jax.named_scope("moe_combine"):
+        # un-sort TOP-K-MAJOR, one gather to (topk, rows, H), and add
+        # the k weighted (rows, H) slabs in one fused pass. Summing the
+        # middle dim of a (rows, topk, H) un-sort reduces across
+        # sublanes (81 µs a layer at kexaone's 264 x 8 x 6144), and a
+        # ``sum`` over the leading dim made the compiler write the whole
+        # f32 copy out first (360 µs at 768 rows): PERF.md §6, PR 40
+        by_k = (out_rows, ctx.topk)
+        y_k, w_k = y[inv.reshape(by_k).T], w_flat.reshape(by_k).T
+        live = w_k != 0
+        return functools.reduce(jnp.add, (
+            _weighted(y_k[k], w_k[k], live[k]) for k in range(ctx.topk)))
 
 
 def _slot_tables(ctx: EPMoEContext, rspl, slot_m: int, shift=None):
@@ -507,12 +599,28 @@ def _ep_assignments_device(ctx: EPMoEContext, x, flat_e, w_flat, out_rows,
     (T,) f32 combine weights, exactly 0 for masked assignments.
     Returns (out_rows, H) f32 weighted sums (out_rows == R) — plus the
     updated workspace dict when ``state`` is given (the barrier-free LL
-    transport; fused only).
+    transport; fused only). ONE rank (``ctx.local``) takes
+    :func:`_local_assignments_device`: the same result with no exchange.
     """
     # device scopes (``jax.named_scope``, one component of each
     # operation's ``op_name``; trace-time only): moe_route, moe_dispatch,
     # moe_gemm, moe_combine — what a profile of any step that runs this
     # block (serving, decode, training) is read by
+    if ctx.local:
+        # (never with a ``state``: ``ep_moe_device`` refuses one)
+        return _local_assignments_device(
+            ctx, x, flat_e, w_flat, out_rows, w_up, w_down)
+    return _exchange_assignments_device(
+        ctx, x, flat_e, w_flat, out_rows, w_up, w_down, state, instance)
+
+
+def _exchange_assignments_device(ctx: EPMoEContext, x, flat_e, w_flat,
+                                 out_rows, w_up, w_down, state=None,
+                                 instance=0):
+    """:func:`_ep_assignments_device` between ranks: the whole protocol
+    (sort and split counts, stage, dispatch, receive-side tables, the
+    expert MLP over the received rows, the return leg, the weighted
+    sum) over ``ctx.transport``."""
     total = flat_e.shape[0]
     new_state = None
     with jax.named_scope("moe_route"):
@@ -631,26 +739,20 @@ def _ep_assignments_device(ctx: EPMoEContext, x, flat_e, w_flat, out_rows,
 
     # back to assignment order via inverse-permutation GATHER (scatter
     # only the (T,) iota; total-coverage since ``order`` is a
-    # permutation), then reduce the topk groups with a segmented sum —
-    # assignment t belongs to token t//topk, so the (T, H) array IS
-    # (out_rows, topk, H) row-major. One gather + one reduction pass
-    # instead of a full-width f32 select pass + an f32 scatter-add.
+    # permutation), then reduce the topk groups with a segmented sum
     with jax.named_scope("moe_combine"):
         inv_order = jnp.zeros((total,), jnp.int32).at[order].set(
             jnp.arange(total, dtype=jnp.int32)
         )
-        y_orig = y_sorted[inv_order]               # (T, H) assignment order
-        # masked assignments carry weight exactly 0, but their y rows
-        # may be garbage (untransported window slack) — zero them before
-        # the MAC so a stray inf/nan cannot poison the sum. Under
-        # debug_checksum the poison NaNs ride rows with nonzero weight,
-        # so they stay loud.
-        y_use = jnp.where(
-            (w_flat != 0)[:, None],
-            y_orig.astype(jnp.float32) * w_flat[:, None],
-            0.0,
-        )
-        out = y_use.reshape(out_rows, ctx.topk, ctx.hidden).sum(axis=1)
+        # assignment t belongs to token t // topk, so the (T, H) array
+        # IS (out_rows, topk, H) row-major. One gather + one reduction
+        # pass instead of a full-width f32 select pass + an f32
+        # scatter-add.
+        by_k = (out_rows, ctx.topk)
+        w_k = w_flat.reshape(by_k)
+        out = _weighted(
+            y_sorted[inv_order].reshape(by_k + (ctx.hidden,)),
+            w_k, w_k != 0).sum(axis=1)
     return (out, new_state) if state is not None else out
 
 
@@ -765,13 +867,15 @@ def ep_moe_device(x, logits, w_up, w_down, ctx: EPMoEContext, state=None,
         f"unresolved transport {ctx.transport!r} — build contexts via "
         "create_ep_moe_context"
     )
-    if state is not None and (ctx.transport != "fused" or ctx.dcn_axis):
+    if state is not None and (
+            ctx.transport != "fused" or ctx.dcn_axis or ctx.local):
         # reject here (not just in the ep_moe host entry): a state
         # silently dropped on a downgraded transport would surface as
         # None['parity'] a step later, far from the cause
         raise ValueError(
-            "ep_moe_device state= rides the flat fused transport only "
-            f"(got transport={ctx.transport!r}, dcn_axis={ctx.dcn_axis!r})"
+            "ep_moe_device state= rides the flat fused transport between "
+            f"ranks only (got transport={ctx.transport!r}, "
+            f"dcn_axis={ctx.dcn_axis!r}, ranks={ctx.n})"
         )
     if isinstance(logits, tuple):
         # PRE-ROUTED (``ep_moe(routed=)``): the caller's router chose;
@@ -791,10 +895,13 @@ def ep_moe_device(x, logits, w_up, w_down, ctx: EPMoEContext, state=None,
         ctx, x, flat_e, w_flat, x.shape[0], w_up, w_down,
         state=state, instance=instance,
     )
-    if state is not None:
-        out, new_state = res
-        return out.astype(x.dtype), new_state
-    return res.astype(x.dtype)
+    # (the cast is the root of the fusion that holds the weighted sum:
+    # under the scope, so that a profile books that fusion on it)
+    with jax.named_scope("moe_combine"):
+        if state is not None:
+            out, new_state = res
+            return out.astype(x.dtype), new_state
+        return res.astype(x.dtype)
 
 
 @functools.lru_cache(maxsize=64)
@@ -889,8 +996,9 @@ def _transport_degrade_reason(ctx: EPMoEContext) -> str | None:
     unhealthy peer in the active fault plan or a prior watchdog trip.
     Quantized wire payloads cannot demote (the XLA transport is
     full-precision only) — those keep the fused path and surface
-    whatever the fault is."""
-    if ctx.transport not in ("fused", "pallas") or ctx.quant is not None:
+    whatever the fault is. ONE rank has no transport to demote."""
+    if (ctx.local or ctx.transport not in ("fused", "pallas")
+            or ctx.quant is not None):
         return None
     from triton_distributed_tpu.runtime import faults, watchdog
 

@@ -348,9 +348,15 @@ class EngineStats:
     # rows of the expert-sorted buffer ONE EP expert layer of each
     # device step's program allocates, summed over the steps
     # (``Transformer.moe_aligned_rows`` at the step's width: a static
-    # of the program — every array of ``ops/moe.py::_expert_mlp`` is
+    # of the program — every array of ``ops/moe.py::_grouped_mlp`` is
     # that long whatever the step holds; 0 with no EP expert layer)
     moe_aligned_rows: int = 0
+    # device steps whose EP expert layers ran the LOCAL path (ONE rank
+    # on the tp axis, ``Transformer.moe_local``: sort once, gather
+    # once, the grouped GEMMs — no dispatch, no combine, no
+    # workspaces): every step of such an engine; 0 at tp > 1 and with
+    # no EP expert layer
+    moe_local_steps: int = 0
     # step programs this engine dispatched a FIRST time (one a program
     # key: ``block_q``, packed width, ``use_pallas``, ``n_bufs``), and
     # the seconds of their ``setup.program`` spans: tracing, lowering
@@ -887,17 +893,19 @@ class ServingEngine:
             # LL MoE workspaces, sized to the packed step width: one set per
             # DISTINCT width, ``{width: per-layer states}``, built here and
             # never inside a step (``EPMoEState.instance`` is static: a
-            # state belongs to the kernels compiled for its width). None
-            # when the model has no fused-transport EP layers
+            # state belongs to the kernels compiled for its width). An
+            # entry is None where that width's step carries no
+            # workspaces (the XLA transport; ONE rank on the tp axis,
+            # which exchanges with nobody: ``Transformer.moe_local``);
+            # the whole is None for a model with no EP expert layer
             if moe_state == "auto":
                 moe_state = {
                     w: model.init_decode_state(w)
                     for w in sorted({self._width(b) for b in self._rungs()})
-                }
-                if None in moe_state.values():
-                    moe_state = None
+                } if c.moe == "ep" and c.moe_layers else None
             self.moe_state = moe_state
         self._aligned_rows: dict = {}      # ``_moe_aligned_rows``
+        self._moe_local = int(model.moe_local)
         # program keys (``_run_device``) this engine has dispatched
         self._dispatched: set = set()
         if cfg.token_budget % 8:
@@ -1438,7 +1446,7 @@ class ServingEngine:
                 step=self.step_count,
             )
             out = step_fn(*args)
-            if self.moe_state is None:
+            if self.moe_state is None or self.moe_state[width] is None:
                 logits, self.state = out
             else:
                 logits, self.state, states = out
@@ -1584,6 +1592,7 @@ class ServingEngine:
                 "moe_masked_rows": len(tokens) - report["tokens"]
                 if c.moe == "ep" and c.moe_layers else 0,
                 "moe_aligned_rows": self._moe_aligned_rows(len(tokens)),
+                "moe_local_steps": self._moe_local,
             })
         return flight, block_q, probing
 

@@ -159,7 +159,8 @@ def main():
     # decode-only step is narrower than a chunk step), one parity for
     # all of them: it rolls once a step, whichever width the step has
     states = eng_ll.moe_state
-    assert states is not None, "the fused transport did not engage"
+    assert states is not None and None not in states.values(), \
+        "the fused transport did not engage"
     parity = {
         w: int(np.asarray(next(s for s in st if s is not None).parity)[0])
         for w, st in states.items()}
