@@ -1,6 +1,16 @@
 """Kernel library (L4): collective and compute-communication-overlap kernels.
 
 Reference: python/triton_dist/kernels/nvidia/ (see SURVEY.md §2.3).
+
+The serving step's mixers are imported where they are launched
+(``models/transformer.py``), not re-exported here:
+``ragged_paged_attention`` (softmax over paged K/V: causal, windowed,
+selected pages, latent), ``kv_append``, ``sparse_select``,
+``lightning_attention`` (linear attention, one scalar decay a head:
+``S_t = lam S_{t-1} + k_t^T v_t``) and ``kda_attention`` (the gated
+delta rule, a decay a channel and token and a correction term: ``S_t =
+(I - b_t k_t k_t^T) Diag(a_t) S_{t-1} + b_t k_t v_t^T``), each a Pallas
+kernel with an XLA twin of the same arguments.
 """
 
 from triton_distributed_tpu.kernels.ag_gemm import (

@@ -209,6 +209,39 @@ def dots_vlm1(**overrides) -> TransformerConfig:
     return TransformerConfig(**cfg)
 
 
+def solar_open2(**overrides) -> TransformerConfig:
+    """Solar-Open2-250B (upstage, ``model_type: solar_open2``) as
+    published: 48 layers, hidden 4096, in periods of four: one softmax
+    GQA layer (``gqa_layers`` 0, 4, ..., 44: 64 query / 8 KV heads x
+    128, no rotation, sigmoid output gate) then three gated delta-rule
+    linear-attention layers (KDA: 64 heads x 128, a causal depthwise
+    convolution of 4 taps on q, k and v, a per-channel decay and an
+    output gate through rank-128 pairs, beta up to 2, output norm);
+    every layer sparse (``first_k_dense_replace`` 0): 320 experts of
+    width 1280, top-8 by sigmoid score + selection bias, weights
+    renormalised (x 1), one shared expert; vocabulary 196608, untied
+    head.
+
+    The cut is four integers: ``n_layers`` (the published layers 0,
+    1, ...: the pattern follows), ``experts_held`` (with
+    ``first_expert_held``) one chip's share of an expert-parallel
+    layer, and ``vocab`` its slice of the vocabulary."""
+    n = int(overrides.get("n_layers", 48))
+    kinds = tuple("attention" if i % 4 == 0 else "kda" for i in range(n))
+    cfg = dict(
+        vocab=196608, n_layers=n, hidden=4096, ffn=1280,
+        n_heads=64, n_kv_heads=8, head_dim=128, norm_eps=1e-5,
+        layer_mixer=kinds, kda_heads=64, kda_conv=4, kda_rank=128,
+        kda_beta_scale=2.0,
+        gated_ffn=True, out_gate=True, out_norm=True,
+        moe="ep", moe_layers=tuple(range(n)), num_experts=320, topk=8,
+        shared_experts=1, router="sigmoid_bias", routed_scale=1.0,
+        dtype=jnp.bfloat16,
+    )
+    cfg.update(overrides)
+    return TransformerConfig(**cfg)
+
+
 def tiny(preset=None, **overrides) -> TransformerConfig:
     """CI-sized twin: same topology knobs as ``preset`` (or dense
     defaults), tiny dims — what the tests and the driver dryrun use."""
@@ -258,6 +291,12 @@ def tiny(preset=None, **overrides) -> TransformerConfig:
             sparse_dense_len=min(preset.sparse_dense_len, 32),
             out_gate=preset.out_gate,
             out_norm=preset.out_norm,
+            # the gated delta-rule layers of PR 41 at the twin's sizes:
+            # four heads, the published taps, a rank of 8
+            kda_heads=min(preset.kda_heads, 4),
+            kda_conv=preset.kda_conv,
+            kda_rank=min(preset.kda_rank, 8),
+            kda_beta_scale=preset.kda_beta_scale,
             embed_scale=preset.embed_scale,
             residual_scale=preset.residual_scale,
             logit_divisor=preset.logit_divisor,

@@ -233,6 +233,25 @@ class TransformerConfig:
     # top-k is taken inside the ``router_topk_groups`` best groups
     router_groups: int = 1
     router_topk_groups: int = 1
+    # ---- gated delta-rule linear attention (PR 41); the defaults are
+    # the model above. ``serving_step`` implements it, ``forward``
+    # raises on it by name.
+    # ``layer_mixer[i] == "kda"``: ``kda_heads`` query AND key/value
+    # heads of ``head_dim``; q, k, v through a causal depthwise
+    # convolution of ``kda_conv`` taps + SiLU (its last ``kda_conv - 1``
+    # pre-activation rows a slot kept beside the matrix), q and k
+    # L2-normed a head; a decay PER CHANNEL ``alpha_t = exp(-exp(a_log_h)
+    # softplus(x wa_down wa_up + dt_bias))`` and an output gate
+    # ``sigmoid(x wg_down wg_up)`` (under ``out_gate``), both through a
+    # rank-``kda_rank`` pair; ``beta_t = kda_beta_scale sigmoid(x
+    # wbeta)`` (2: eigenvalues down to -1); state ``S_t = (I - beta_t
+    # k_t k_t^T) Diag(alpha_t) S_{t-1} + beta_t k_t v_t^T`` (head_dim x
+    # head_dim float32), ``o_t = S_t^T q_t / sqrt(head_dim)``
+    # (kernels/kda_attention.py has the lines)
+    kda_heads: int = 0
+    kda_conv: int = 0
+    kda_rank: int = 0
+    kda_beta_scale: float = 1.0
 
     def __post_init__(self):
         if self.attn not in ("tp", "ring", "ulysses"):
@@ -340,16 +359,48 @@ class TransformerConfig:
                 "served in param_dtype)")
         if self.layer_mixer and (
             len(self.layer_mixer) != self.n_layers
-            or set(self.layer_mixer) - {"attention", "lightning"}
+            or set(self.layer_mixer) - {"attention", "lightning", "kda"}
         ):
             raise ValueError(
-                f"layer_mixer must give 'attention' or 'lightning' for "
-                f"each of the {self.n_layers} layers, got "
+                f"layer_mixer must give 'attention', 'lightning' or "
+                f"'kda' for each of the {self.n_layers} layers, got "
                 f"{self.layer_mixer!r}")
         if bool(self.lightning_layers) != (self.lightning_heads > 0):
             raise ValueError(
                 f"lightning_heads={self.lightning_heads} and layer_mixer's "
                 f"lightning layers {self.lightning_layers} go together")
+        kda = ("kda_heads", "kda_conv", "kda_rank")
+        if self.kda_layers:
+            if self.kda_heads < 1 or self.kda_conv < 2 \
+                    or self.kda_rank < 1 \
+                    or not 0.0 < self.kda_beta_scale <= 2.0:
+                raise ValueError(
+                    f"layer_mixer's kda layers {self.kda_layers} need "
+                    "kda_heads >= 1, kda_conv >= 2 taps, kda_rank >= 1 "
+                    "and 0 < kda_beta_scale <= 2 (got "
+                    f"{[getattr(self, f) for f in kda]}, "
+                    f"{self.kda_beta_scale})")
+            beside = [k for k, on in (
+                ("lightning layers (layer_mixer)",
+                 bool(self.lightning_layers)),
+                ("block-sparse attention (sparse_topk)",
+                 self.sparse_topk > 0),
+                ("qk_norm", self.qk_norm),
+                ("rope_layers on a kda layer",
+                 bool(set(self.rope_layers) & set(self.kda_layers))),
+                ("kv_latent", self.kv_latent > 0),
+                ("dense_weight_quant",
+                 self.dense_weight_quant is not None),
+            ) if on]
+            if beside:
+                raise ValueError(
+                    f"kda layers (layer_mixer) with {', '.join(beside)}: "
+                    "not built")
+        elif any(getattr(self, f) for f in kda) \
+                or self.kda_beta_scale != 1.0:
+            raise ValueError(
+                "kda_heads / kda_conv / kda_rank / kda_beta_scale "
+                "without a 'kda' layer in layer_mixer")
         sparse = ("sparse_kernel", "sparse_stride", "sparse_block",
                   "sparse_init_blocks", "sparse_window", "sparse_dense_len")
         if self.sparse_topk:
@@ -368,7 +419,7 @@ class TransformerConfig:
                 f"{', '.join(f for f in sparse if getattr(self, f))} "
                 "without sparse_topk")
         stateful = [k for k, on in (
-            ("layer_mixer", bool(self.lightning_layers)),
+            ("layer_mixer", bool(self.recurrent_layers)),
             ("sparse_topk", self.sparse_topk > 0)) if on]
         if stateful and self.window_layers:
             raise ValueError(
@@ -383,9 +434,10 @@ class TransformerConfig:
             raise ValueError(
                 f"{', '.join(stateful)} with attn={self.attn!r}: built "
                 "for attn='tp' only")
-        if self.out_norm and not self.lightning_layers:
-            raise ValueError("out_norm is the lightning layers' output "
-                             "norm: no lightning layer in layer_mixer")
+        if self.out_norm and not self.recurrent_layers:
+            raise ValueError("out_norm is the recurrent (lightning, kda) "
+                             "layers' output norm: no such layer in "
+                             "layer_mixer")
         latent = ("kv_latent", "q_latent", "qk_nope_dim", "qk_rope_dim",
                   "v_head_dim")
         if self.kv_latent:
@@ -463,6 +515,19 @@ class TransformerConfig:
             i for i, k in enumerate(self.layer_mixer) if k == "lightning")
 
     @property
+    def kda_layers(self) -> tuple:
+        """Indices of the gated delta-rule (KDA) layers."""
+        return tuple(
+            i for i, k in enumerate(self.layer_mixer) if k == "kda")
+
+    @property
+    def recurrent_layers(self) -> tuple:
+        """Indices of the layers that keep a recurrent state a slot
+        and no K/V pages: lightning and kda."""
+        return tuple(i for i, k in enumerate(self.layer_mixer)
+                     if k != "attention")
+
+    @property
     def sparse_layers(self) -> tuple:
         """Indices of the block-sparse attention layers: every
         attention layer of a model with ``sparse_topk``."""
@@ -475,6 +540,8 @@ class TransformerConfig:
         """``(query heads, key/value heads)`` of layer ``i``'s mixer."""
         if i in self.lightning_layers:
             return self.lightning_heads, self.lightning_heads
+        if i in self.kda_layers:
+            return self.kda_heads, self.kda_heads
         return self.n_heads, self.n_kv_heads
 
     @property
@@ -511,7 +578,7 @@ class TransformerConfig:
             ("shared_experts", self.shared_experts > 0),
             ("router", self.router != "softmax"),
             ("experts_held", self.experts_held > 0),
-            ("layer_mixer", bool(self.lightning_layers)),
+            ("layer_mixer", bool(self.recurrent_layers)),
             ("sparse_topk", self.sparse_topk > 0),
             ("out_gate", self.out_gate),
             ("out_norm", self.out_norm),
@@ -669,6 +736,7 @@ class Transformer:
                 "over it")
         stateful = [k for k, on in (
             ("lightning layers (layer_mixer)", bool(c.lightning_layers)),
+            ("kda layers (layer_mixer)", bool(c.kda_layers)),
             ("block-sparse attention (sparse_topk)", c.sparse_topk > 0),
         ) if on]
         for axis, n in (("tp", self.tp), ("cp", self.cp)):
@@ -851,9 +919,10 @@ class Transformer:
     def init(self, key):
         c = self.config
         keys = iter(jax.random.split(
-            key, 4 + (11 if c.kv_latent else 8) * c.n_layers))
+            key, 4 + (16 if c.kda_layers else 11 if c.kv_latent else 8)
+            * c.n_layers))
         # (a layer with every field of PR 33 draws 8 keys, a latent
-        # layer 11: the split holds)
+        # layer 11, a kda layer with shared experts 16: the split holds)
         pd = c.param_dtype
         s = 1.0 / (c.hidden ** 0.5)
 
@@ -902,12 +971,31 @@ class Transformer:
                                (c.hidden, qd + 2 * hkv * c.head_dim)),
                     wo=dense(next(keys), (qd, c.hidden)),
                 )
+            if i in c.kda_layers:
+                # the convolution's taps over [q | k | v], the decay's
+                # rank-kda_rank pair with its per-head and per-channel
+                # terms, beta's projection
+                blk.update(
+                    conv_w=dense(next(keys), (c.kda_conv, 3 * qd),
+                                 c.kda_conv ** -0.5),
+                    wa_down=dense(next(keys), (c.hidden, c.kda_rank)),
+                    wa_up=dense(next(keys), (c.kda_rank, qd),
+                                c.kda_rank ** -0.5),
+                    a_log=dense(next(keys), (hq,), 0.5),
+                    dt_bias=dense(next(keys), (qd,), 1.0),
+                    wbeta=dense(next(keys), (c.hidden, hq)),
+                )
             if c.qk_norm:
                 blk["norm_q"] = jnp.ones((c.head_dim,), pd)
                 blk["norm_k"] = jnp.ones((c.head_dim,), pd)
-            if c.out_gate:
+            if c.out_gate and i in c.kda_layers:
+                # a kda layer's gate goes through a rank-kda_rank pair
+                blk["wg_down"] = dense(next(keys), (c.hidden, c.kda_rank))
+                blk["wg_up"] = dense(next(keys), (c.kda_rank, qd),
+                                     c.kda_rank ** -0.5)
+            elif c.out_gate:
                 blk["wz"] = dense(next(keys), (c.hidden, qd))
-            if c.out_norm and i in c.lightning_layers:
+            if c.out_norm and i in c.recurrent_layers:
                 blk["norm_o"] = jnp.ones((c.head_dim,), pd)
             if c.moe != "none" and i in c.moe_layers:
                 blk["router"] = dense(next(keys), (c.hidden, c.num_experts))
@@ -1141,9 +1229,16 @@ class Transformer:
             }
             if c.qk_norm:
                 blk.update(norm_q=rep, norm_k=rep)
-            if c.out_gate:
+            if i in c.kda_layers:
+                # one chip's (tp > 1 is refused): every leaf whole
+                blk.update(dict.fromkeys(
+                    ("conv_w", "wa_down", "wa_up", "a_log", "dt_bias",
+                     "wbeta"), rep))
+            if c.out_gate and i in c.kda_layers:
+                blk.update(wg_down=rep, wg_up=rep)
+            elif c.out_gate:
                 blk.update(wz=ns(None, t))
-            if c.out_norm and i in c.lightning_layers:
+            if c.out_norm and i in c.recurrent_layers:
                 blk.update(norm_o=rep)
             if c.moe != "none" and i in c.moe_layers:
                 if c.router == "sigmoid_bias":
@@ -1514,7 +1609,8 @@ class Transformer:
             zero = jnp.zeros((), c.dtype)
             return lambda: z + zero
 
-        lightning, sparse = c.lightning_layers, c.sparse_layers
+        lightning, sparse, kda = (c.lightning_layers, c.sparse_layers,
+                                  c.kda_layers)
         if sparse and (page % c.sparse_block or page % c.sparse_stride
                        or c.sparse_dense_len % page):
             raise ValueError(
@@ -1530,14 +1626,22 @@ class Transformer:
         recurrent = ckeys = ()
         if c.kv_latent:
             layers = tuple((full(), None) for _ in range(c.n_layers))
-        elif lightning or sparse:
-            # a lightning layer keeps a state a slot and no pages; a
-            # sparse layer a pool of compressed keys beside K/V
-            # (serving/state.py). Each leaf its own buffer (donated)
+        elif lightning or sparse or kda:
+            # a lightning layer keeps a state a slot and no pages, a kda
+            # layer the pair (matrix, convolution tail); a sparse layer
+            # a pool of compressed keys beside K/V (serving/state.py).
+            # Each leaf its own buffer (donated)
+            def matrix(heads):
+                return jnp.zeros((slots, heads, c.head_dim, c.head_dim),
+                                 jnp.float32)
+
             recurrent = tuple(
-                jnp.zeros((slots, c.lightning_heads, c.head_dim,
-                           c.head_dim), jnp.float32)
-                if i in lightning else None for i in range(c.n_layers))
+                matrix(c.lightning_heads) if i in lightning
+                else (matrix(c.kda_heads),
+                      jnp.zeros((slots, c.kda_conv - 1,
+                                 3 * c.kda_heads * c.head_dim),
+                                jnp.float32)) if i in kda
+                else None for i in range(c.n_layers))
             ckeys = tuple(
                 jax.device_put(
                     jnp.zeros((npages, c.n_kv_heads,
@@ -1545,7 +1649,7 @@ class Transformer:
                               c.dtype), spec)
                 if i in sparse else None for i in range(c.n_layers))
             layers = tuple(
-                None if i in lightning else (full(), full())
+                None if i in c.recurrent_layers else (full(), full())
                 for i in range(c.n_layers))
         elif windowed:
             # a window layer keeps slots · ring pages and no more
@@ -1777,17 +1881,121 @@ class Transformer:
                      q_lens, q_starts, block_q=block_q)
         return o.transpose(1, 0, 2).reshape(t, heads * c.head_dim), new
 
+    def _kda_inputs(self, blk, xn, pre, state, li, q_lens, q_starts):
+        """A kda layer's inputs to the recurrence from the packed
+        step's pre-activation rows ``pre`` (T, 3 · heads · D) float32:
+        ``(q, k, v, g (T, heads, D) float32, beta (T, heads) float32,
+        the layer's new convolution tail)``. The first ``kda_conv - 1``
+        tokens of a span convolve with the slot's tail (zeros where the
+        span starts at position 0); the span's last ``kda_conv - 1``
+        pre-activation rows are the new tail."""
+        scope = jax.named_scope
+        c = self.config
+        f32 = jnp.float32
+        t, taps = pre.shape[0], c.kda_conv
+        heads, d = c.kda_heads, c.head_dim
+        held = state.recurrent[li][1]              # (slots, taps - 1, 3HD)
+        with scope("kda_conv"):
+            first = (state.kv_lens - q_lens) == 0
+            tail = jnp.where(first[:, None, None], 0.0, held)
+            w = blk["conv_w"].astype(f32)
+            # every token from the rows before it IN THE STEP ...
+            conv = w[taps - 1] * pre
+            for m in range(1, taps):
+                conv = conv + w[taps - 1 - m] * jnp.roll(pre, m, axis=0)
+            # ... but for a span's first taps - 1 tokens, whose rows
+            # before lie in the slot's tail: those (slots x (taps - 1)
+            # rows, not the step's width) from the tail and the span's
+            # own first rows, written over the others
+            ahead = jnp.arange(taps - 1)[None]
+            at = q_starts[:, None] + ahead
+            ext = jnp.concatenate(
+                [tail, pre[jnp.clip(at, 0, t - 1)]], axis=1)
+            head = sum(w[i] * ext[:, i:i + taps - 1] for i in range(taps))
+            conv = conv.at[
+                jnp.where((q_lens > 0)[:, None], at, t).reshape(-1)
+            ].set(head.reshape(-1, head.shape[-1]), mode="drop")
+            src = q_lens[:, None] - (taps - 1) + ahead
+            new_tail = jnp.where(
+                (src >= 0)[..., None],
+                pre[jnp.clip(q_starts[:, None] + src, 0, t - 1)],
+                jnp.take_along_axis(
+                    tail, jnp.clip(src + taps - 1, 0, taps - 2)[..., None],
+                    axis=1))
+            new_tail = jnp.where((q_lens > 0)[:, None, None], new_tail,
+                                 held)
+            q, k, v = (a.reshape(t, heads, d) for a in jnp.split(
+                jax.nn.silu(conv), 3, axis=-1))
+        with scope("kda_gates"):
+            def l2norm(a):
+                return a * jax.lax.rsqrt(
+                    jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+            dt = jnp.dot(self._dmm(xn, blk["wa_down"]),
+                         blk["wa_up"].astype(c.dtype),
+                         preferred_element_type=f32) \
+                + blk["dt_bias"].astype(f32)
+            g = -jnp.exp(blk["a_log"].astype(f32))[None, :, None] \
+                * jax.nn.softplus(dt).reshape(t, heads, d)
+            beta = c.kda_beta_scale * jax.nn.sigmoid(jnp.dot(
+                xn, blk["wbeta"].astype(c.dtype),
+                preferred_element_type=f32))
+            q, k = l2norm(q), l2norm(k)
+        return q, k, v, g, beta, new_tail
+
+    def _kda_layer(self, blk, xn, state, li, q_lens, q_starts, block_q,
+                   use_pallas):
+        """A kda layer's mixer on the packed step's normed input
+        ``xn``: ``(o (T, heads · D) float32, the layer's new (matrix,
+        tail))``."""
+        scope = jax.named_scope
+        with scope("attn_proj"):
+            # the pre-activation rows come out of the product in
+            # float32: the convolution's tail keeps them
+            pre = jnp.dot(xn, blk["wqkv"].astype(self.config.dtype),
+                          preferred_element_type=jnp.float32)
+            q, k, v, g, beta, tail = self._kda_inputs(
+                blk, xn, pre, state, li, q_lens, q_starts)
+        with scope("attn"), scope("kda_attn"):
+            o, matrix = self._kda_mix(
+                q, k, v, g, beta, state, li, q_lens, q_starts, block_q,
+                use_pallas)
+        return o, (matrix, tail)
+
+    def _kda_mix(self, q, k, v, g, beta, state, li, q_lens, q_starts,
+                 block_q, use_pallas):
+        """A kda layer's mixing of the packed step: q, k, v, g (T,
+        heads, D), beta (T, heads) -> ``(o (T, heads · D) float32, the
+        layer's new state matrix)``; kernel or XLA twin
+        (kernels/kda_attention.py)."""
+        from triton_distributed_tpu.kernels.kda_attention import (
+            kda_attention,
+            kda_attention_xla,
+        )
+
+        t = q.shape[0]
+        mix = kda_attention if use_pallas else kda_attention_xla
+        o, new = mix(*(a.transpose(1, 0, 2) for a in (q, k, v, g)), beta.T,
+                     state.recurrent[li][0], state.kv_lens, q_lens,
+                     q_starts, block_q=block_q)
+        return o.transpose(1, 0, 2).reshape(t, -1), new
+
     def _gate_out(self, blk, o, xn, heads):
-        """The mixer's output before ``wo``: on a lightning layer RMS-
-        normed over each head (``config.out_norm``), then times the
-        sigmoid gate of the layer's normed input."""
+        """The mixer's output before ``wo``: on a recurrent (lightning,
+        kda) layer RMS-normed over each head (``config.out_norm``), then
+        times the sigmoid gate of the layer's normed input (a kda
+        layer's through its rank-``kda_rank`` pair)."""
         c = self.config
         t = o.shape[0]
         o = o.astype(jnp.float32)
         if "norm_o" in blk:
             o = self._rmsnorm(o.reshape(t, heads, c.head_dim),
                               blk["norm_o"]).reshape(t, -1)
-        gate = self._dmm(xn, blk["wz"], shard=self._attn_proj_shard[0])
+        if "wg_down" in blk:
+            gate = self._dmm(self._dmm(xn, blk["wg_down"]), blk["wg_up"])
+        else:
+            gate = self._dmm(xn, blk["wz"],
+                             shard=self._attn_proj_shard[0])
         return (o * jax.nn.sigmoid(gate.astype(jnp.float32))).astype(
             c.dtype)
 
@@ -2045,10 +2253,12 @@ class Transformer:
         # device scopes (``jax.named_scope``: one component of every
         # operation's ``op_name``, trace-time only): embed, attn_proj,
         # kv_append, attn, dense_ffn, lm_head here; moe_route,
-        # moe_dispatch, moe_gemm, moe_combine in ops/moe.py. Two are
+        # moe_dispatch, moe_gemm, moe_combine in ops/moe.py. Others are
         # NESTED in those, so that a reading of "under none of the ten"
-        # still covers them: qk_rope (q/k norm + rotation) inside
-        # attn_proj, shared_expert inside dense_ffn
+        # still covers them: qk_rope (q/k norm + rotation), out_gate and
+        # a kda layer's kda_conv / kda_gates inside attn_proj,
+        # linear_attn / kda_attn inside attn, shared_expert inside
+        # dense_ffn
         scope = jax.named_scope
         c = self.config
         t = tokens.shape[0]
@@ -2161,7 +2371,7 @@ class Transformer:
         new_layers = []
         new_states = None if moe_state is None else list(moe_state)
         new_recurrent, new_ckeys = list(state.recurrent), list(state.ckeys)
-        lightning = c.lightning_layers
+        lightning, kda = c.lightning_layers, c.kda_layers
         qkv_sh, wo_sh = self._attn_proj_shard
         for li, (blk, pools) in enumerate(
             zip(params["blocks"], state.layers)
@@ -2193,7 +2403,18 @@ class Transformer:
                             q, k = self._qk_norm_rope(
                                 blk, q, k,
                                 rope if li in c.rope_layers else None)
-                if li in lightning:
+                if li in kda:
+                    # (a kda layer makes its own inputs from ``xn``; the
+                    # q, k, v above go unused and out of the program.
+                    # The lines above stay the parent's to the letter: an
+                    # ``if`` round them cost the accepted cells ~0.8 s
+                    # of warm tracing on the chip: PERF.md section 6,
+                    # PR 41)
+                    new_layers.append(None)
+                    o, new_recurrent[li] = self._kda_layer(
+                        blk, xn, state, li, q_lens, q_starts, block_q,
+                        use_pallas)
+                elif li in lightning:
                     new_layers.append(None)
                     with scope("attn"), scope("linear_attn"):
                         o, new_recurrent[li] = self._lightning_mix(

@@ -316,10 +316,18 @@ class EngineStats:
     # block; a prefill chunk the union of its positions' choices, read
     # as every page it holds: an upper bound), rows whose last position
     # is past ``sparse_dense_len`` (their blocks are chosen by score),
-    # and rows that read and write a lightning layer's recurrent state
+    # and rows that read and write a recurrent (lightning, kda) layer's
+    # state
     selected_pages_walked: int = 0
     sparse_rows: int = 0
     state_rows: int = 0
+    # the work of a model with gated delta-rule (kda) layers (0 without
+    # them), counted beside ``state_rows``: rows batched through ONE
+    # layer's ``kda_attention`` launch, and those of them with more
+    # than one token (at most SHORT of them the rank-1 form a token at
+    # a time, more the chunk form: kernels/kda_attention.py)
+    kda_rows: int = 0
+    kda_chunk_rows: int = 0
     # the work of a model with a LATENT pool (``kv_latent``; 0 without
     # one), summed over the batched rows of the device steps, counted
     # in ``_assemble``: pages ONE layer's latent walk fetches (a decode
@@ -597,8 +605,9 @@ def packed_width(block_q: int, slots: int, token_budget: int,
 #
 # A model's layers may keep, beside or in place of plain K/V pages, a
 # RING pool (sliding-window layers), a RECURRENT state a slot with
-# compressed keys and a selection (lightning layers, block-sparse
-# attention), or a LATENT pool (``kv_latent``: one entry a token for
+# compressed keys and a selection (lightning layers, kda layers with
+# their convolution tail, block-sparse attention), or a LATENT pool
+# (``kv_latent``: one entry a token for
 # every head). What would read, keep, roll back or ship a second copy
 # of such state is not built for it; ``REFUSED[kind][feature]`` is the
 # reason, raised by name where the feature is asked for (a pair that
@@ -675,6 +684,8 @@ def state_kinds(mc) -> dict:
         kinds["window"] = "sliding-window layers"
     stateful = [name for name, on in (
         ("lightning layers (layer_mixer)", bool(mc.lightning_layers)),
+        ("kda layers (layer_mixer: a state matrix and a convolution "
+         "tail a slot)", bool(mc.kda_layers)),
         ("block-sparse attention (sparse_topk)", mc.sparse_topk > 0),
     ) if on]
     if stateful:
@@ -832,6 +843,7 @@ class ServingEngine:
         # likewise: [selected pages, sparse rows, state rows]
         self._state_work = [0, 0, 0]
         self._latent_work = [0, 0]      # likewise: [pages fetched, rows]
+        self._kda_work = [0, 0]         # likewise: [rows, chunk rows]
         # seconds of the running step inside each phase (``_phase``)
         self._phase_s = dict.fromkeys(PHASES, 0.0)
         # --- multi-tenancy (all defaults reproduce the single-tenant
@@ -1271,6 +1283,7 @@ class ServingEngine:
         self._pages_walked = [0, 0]     # [global, window], one layer each
         self._state_work = [0, 0, 0]
         self._latent_work = [0, 0]      # [pages fetched, rows]
+        self._kda_work = [0, 0]         # [rows, rows of several tokens]
         mc = self.model.config
         batched: set = set()
         takes: dict = {}
@@ -1317,8 +1330,11 @@ class ServingEngine:
                         min(need, mc.sparse_topk) if take == 1 else need)
                     self._state_work[1] += (
                         cur + take > mc.sparse_dense_len)
-                if mc.lightning_layers:
+                if mc.recurrent_layers:
                     self._state_work[2] += 1
+                if mc.kda_layers:
+                    self._kda_work[0] += 1
+                    self._kda_work[1] += take > 1
                 if mc.kv_latent:
                     self._latent_work[0] += sum(
                         self._pages_held(cur + min(i + LATENT_TQ, take))
@@ -1585,6 +1601,8 @@ class ServingEngine:
                 "selected_pages_walked": self._state_work[0],
                 "sparse_rows": self._state_work[1],
                 "state_rows": self._state_work[2],
+                "kda_rows": self._kda_work[0],
+                "kda_chunk_rows": self._kda_work[1],
                 "latent_pages_walked": self._latent_work[0],
                 "latent_rows": self._latent_work[1],
                 "packed_rows": len(tokens),

@@ -48,6 +48,22 @@ owns:
   compressed key is written in the step that appends its last token
   and never read before that.
 
+* **a state of two parts** (PR 41) — a KDA (gated delta-rule linear
+  attention) layer keeps no K/V pages either: its entry of ``layers``
+  is ``None`` and ``recurrent[i]`` is the PAIR ``(matrix, tail)``: the
+  delta rule's state ``(slots, kda_heads, head_dim, head_dim)`` float32
+  (``kernels/kda_attention.py`` reads and writes the matrices of the
+  slots a step batches, in place) and the short convolution's tail
+  ``(slots, kda_conv - 1, 3 · kda_heads · head_dim)`` float32, the last
+  pre-activation rows of [q | k | v] the slot's last step left, which
+  the first tokens of its next span convolve with
+  (``Transformer._kda_inputs``). Both follow the lightning contract: a
+  span from position 0 starts from zeros whatever the slot held, a slot
+  that is not batched keeps both as they are, and a recompute rebuilds
+  both. What would need a SNAPSHOT of either (prefix cache, a rolled-
+  back draft, page shipping) is refused by name
+  (``serving/engine.py:REFUSED["recurrent"]``).
+
 * **latent pools** (PR 35) — a model with LATENT attention
   (``TransformerConfig.kv_latent``) keeps, per layer, ONE array and no
   V pool: ``layers[i] = (pool, None)`` with ``pool`` ``(npages, 1,
@@ -78,7 +94,7 @@ class ServingState:
     """One engine's device-resident serving state (see module docs)."""
 
     # per layer (k_pool, v_pool), dicts under kv_quant; (pool, None) a
-    # latent layer; None a lightning layer
+    # latent layer; None a lightning or kda layer
     layers: tuple
     block_table: object  # (slots, pages_per_seq) int32
     kv_lens: object      # (slots,) int32 — includes the in-flight step
@@ -98,9 +114,10 @@ class ServingState:
     ring_table: object = None
     window_layers: tuple = ()
     ring: int = 0
-    # per layer (or ``()``): a lightning layer's recurrent state, a
-    # block-sparse layer's compressed-key pool, None elsewhere; a
-    # lightning layer's entry of ``layers`` is None
+    # per layer (or ``()``): a lightning layer's recurrent state, a kda
+    # layer's pair (state matrix, convolution tail), a block-sparse
+    # layer's compressed-key pool, None elsewhere; a lightning or kda
+    # layer's entry of ``layers`` is None
     recurrent: tuple = ()
     ckeys: tuple = ()
 
