@@ -421,7 +421,7 @@ def lower_published_rung(big, ecfg):
         lambda a: arg(a.shape, a.dtype, pool_sh), state.layers))
     cap = auto_block_q(ecfg.chunk, cfg.n_heads // cfg.n_kv_heads)
     slots = ecfg.slots
-    width = packed_width(8, slots, ecfg.token_budget, cap)
+    width = packed_width(8, slots, ecfg.token_budget)
     ints = [arg((width,), jnp.int32)] * 3 + [arg((slots,), jnp.int32)] * 2
     lowered = big._serving_jit.lower(
         abstract, state, *ints,

@@ -295,14 +295,16 @@ def test_assemble_packs_one_aligned_contiguous_span_a_slot(
             np.testing.assert_array_equal(
                 token_pos[a:a + ln], first + np.arange(ln))
         assert (token_pos[~covered] == -1).all()
-        # the rest park where the batched rows cannot reach at the
-        # step's rung, a whole block short of the step's width
+        # the rest stay at row 0 (every launch skips them), and the
+        # step is the narrowest width of its rung that holds the
+        # launch's block of every batched row
         rung = eng._rung(int(q_lens.max()))
-        assert len(tokens) == eng._width(rung)
-        live = min(eng.cfg.slots * rung, eng.cfg.token_budget)
-        assert (q_starts[q_lens == 0] == live).all()
-        assert (q_starts + rung <= len(tokens)).all()
-        assert all(q_starts[s] + q_lens[s] <= live for s in batched)
+        assert len(tokens) in eng._widths(rung)
+        assert (q_starts[q_lens == 0] == 0).all()
+        need = model.step_rows_needed(q_starts, q_lens, rung)
+        assert need == max((q_starts[s] + rung for s in batched), default=0)
+        assert len(tokens) == min(
+            w for w in eng._widths(rung) if w >= need)
         seen.append(len(batched))
         return out
 

@@ -354,7 +354,7 @@ def test_the_engine_counts_the_steps_that_ran_the_local_path(moe, tp):
         # off the chip the exchange rides the XLA transport: no
         # workspaces at either tp, one (absent) entry a width
         assert sorted(eng.moe_state) == sorted(
-            {eng._width(b) for b in eng._rungs()})
+            {w for b in eng._rungs() for w in eng._widths(b)})
         assert set(eng.moe_state.values()) == {None}
     for req in reqs:
         assert req.generated == greedy_tokens(

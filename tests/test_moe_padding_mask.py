@@ -82,7 +82,7 @@ def test_padding_rows_reach_the_op_masked_and_real_rows_are_untouched(
     args, arrays = first_step_args(eng)
     token_pos, q_lens = arrays[2], arrays[4]
     t, tokens = token_pos.shape[0], int(q_lens.sum())
-    assert tokens == 8 + 5 and t == eng._width(8)
+    assert tokens == 8 + 5 and (t,) == eng._widths(8)
 
     seen = spy_on_ep_moe(monkeypatch)
     step = dict(block_q=8, use_pallas=False)

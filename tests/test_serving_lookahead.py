@@ -160,8 +160,9 @@ def test_a_mixed_step_after_the_warm_up_lowers_no_program():
     model, params, ecfg, _ = _kind("dense")
     eng = ServingEngine(model, params, ecfg, use_pallas=False,
                         propagate_failures=True)
-    wide = max(eng._width(b) for b in eng._rungs())
-    assert len({eng._width(b) for b in eng._rungs()}) == 2
+    widths = {w for b in eng._rungs() for w in eng._widths(b)}
+    wide = max(widths)
+    assert len(widths) == 2
     program.warm_up(eng, model.config.vocab)
     lowered = []
     jax.monitoring.register_event_duration_secs_listener(

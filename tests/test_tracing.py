@@ -509,7 +509,7 @@ def test_setup_program_opens_once_a_program_key_inside_its_dispatch(
     rungs = eng._rungs()
     assert len(rungs) == 2
     assert [(kw["block_q"], kw["width"]) for kw, _ in built] == [
-        (b, eng._width(b)) for b in rungs]
+        (b, w) for b in rungs for w in eng._widths(b)]
     for kw, held in built:
         # nested in the dispatch of the step it names
         assert held == ("engine.dispatch",) and kw["step"] >= 0
@@ -521,7 +521,7 @@ def test_setup_program_opens_once_a_program_key_inside_its_dispatch(
     new = _log_since(mark)
     spans = [s for s in new["spans"] if s["name"] == "setup.program"]
     assert [(s["block_q"], s["width"]) for s in spans] == [
-        (b, eng._width(b)) for b in rungs]
+        (b, w) for b in rungs for w in eng._widths(b)]
     assert eng.stats.program_build_s == sum(s["seconds"] for s in spans)
     # the six phases are as before (admit .. advance, in order)
     assert all([n for n, _ in call] in (SPANS[:2], SPANS[:4], SPANS,
@@ -544,9 +544,11 @@ def test_programs_built_is_one_per_key_the_harness_warm_up_visits(mesh1):
     eng._run_device = spy
     mark = _mark()
     warm = program.warm_up(eng, CFG["vocab"])
-    # one key a rung: the width follows the rung
-    assert len(keys) == len(warm["rungs"]) == 3
-    assert keys == {(b, eng._width(b)) for b in warm["rungs"]}
+    # the warm-up sends a request per block of the old ladder; the
+    # engine launches at two rungs, one width each at this size
+    assert len(warm["rungs"]) == 3 and eng._rungs() == [8, 32]
+    assert keys == {(b, w) for b in eng._rungs() for w in eng._widths(b)}
+    assert len(keys) == 2
     assert eng.stats.programs_built == len(keys)
     new = _log_since(mark)
     assert {(s["block_q"], s["width"]) for s in new["spans"]} == keys

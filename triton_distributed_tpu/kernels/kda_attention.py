@@ -74,6 +74,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -99,6 +100,17 @@ def _across(row, d):
     a state vreg: 0.87 against 1.42 ms for 32 decode rows of 64
     heads."""
     return jnp.broadcast_to(row, (d, d)).T
+
+
+def query_block_tokens(q_lens, block_q: int):
+    """Host side: per row, the packed tokens from ``q_starts[r]`` on
+    that a launch at ``block_q`` fetches and writes for that row:
+    ``SHORT`` for a row of at most that many, else ``block_q``; 0 for a
+    row outside the batch (never visited). ``q_starts[r] + this <= T``
+    is all the launch asks of the packed width."""
+    q_lens = np.asarray(q_lens)
+    block = np.where(q_lens <= SHORT, min(SHORT, block_q), block_q)
+    return np.where(q_lens > 0, block, 0)
 
 
 def _kda_kernel(groups, hg, d, block_q, scale, order_ref, n_ref,

@@ -47,6 +47,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -99,6 +100,17 @@ def _span_update(q, k, v, s_prev, slope, n, first, scale):
 #: the step's ``block_q``: beside a prefill chunk every row would
 #: otherwise pay the chunk's (block_q x block_q) products
 SHORT = 8
+
+
+def query_block_tokens(q_lens, block_q: int):
+    """Host side: per row, the packed tokens from ``q_starts[r]`` on
+    that a launch at ``block_q`` fetches and writes for that row:
+    ``SHORT`` for a row of at most that many, else ``block_q``; 0 for a
+    row outside the batch (never visited). ``q_starts[r] + this <= T``
+    is all the launch asks of the packed width."""
+    q_lens = np.asarray(q_lens)
+    block = np.where(q_lens <= SHORT, min(SHORT, block_q), block_q)
+    return np.where(q_lens > 0, block, 0)
 
 
 def _lightning_kernel(heads, d, block_q, scale, order_ref, n_ref,

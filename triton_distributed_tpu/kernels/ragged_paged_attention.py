@@ -51,9 +51,12 @@ Layout contract (the "GQA-rows" packing):
   tokens and over-write garbage outputs there, which the ascending
   sequential grid self-heals (row r+1 re-writes its own rows after
   row r; the final row's tail needs ``q_starts[-1] + block_q <= T``
-  of slack in the packed array — the engine reserves it). Out-DMAs
-  are waited before the grid step ends so the self-heal ordering is
-  real, not racy.
+  of slack in the packed array — the CALLER's to give: the engine
+  sizes each step's array to the largest such end over its batched
+  rows, ``query_block_tokens`` says the block; a ``q_len == 0`` row
+  needs none where ``topologies`` is given, its body is skipped).
+  Out-DMAs are waited before the grid step ends so the self-heal
+  ordering is real, not racy.
 
 The kernel is LOCAL (no remote DMA): under tensor parallelism the
 serving state shards the pools over the KV-HEAD dim (heads are
@@ -1171,6 +1174,26 @@ def active_rows(q_lens):
     last = order[jnp.maximum(n - 1, 0)]
     order = jnp.where(jnp.arange(r) < n, order, jnp.where(n > 0, last, 0))
     return order.astype(jnp.int32), n.reshape(1).astype(jnp.int32)
+
+
+def query_block_tokens(q_lens, block_q: int, *, latent: bool = False):
+    """Host side: per row, the packed tokens from ``q_starts[r]`` on
+    that a launch at ``block_q`` MOVES for that row (its query block
+    in, its out block back), so that ``q_starts[r] + this <= T`` is the
+    whole of what the launch asks of the packed width; 0 for a row
+    outside the batch (``q_lens == 0``: with ``topologies`` the
+    contiguous walk skips its body, the other two never visit it). The
+    contiguous and the selected walk fetch the launch's ``block_q`` for
+    every row they visit (a row of at most ``SELECT_SHORT`` tokens then
+    WALKS and writes that many only); the latent walk (``latent``) cuts
+    a row into blocks of ``LATENT_TQ`` tokens and moves a decode row's
+    one."""
+    q_lens = np.asarray(q_lens)
+    if latent:
+        block = np.where(q_lens == 1, 1, -(-q_lens // LATENT_TQ) * LATENT_TQ)
+    else:
+        block = np.full_like(q_lens, block_q)
+    return np.where(q_lens > 0, block, 0)
 
 
 def auto_block_q(max_q_len: int, g: int) -> int:

@@ -2191,6 +2191,37 @@ class Transformer:
         rows = pid * page + pos_c % page
         return lambda pool, new: append_rows_xla(pool, new[:, 0], rows)
 
+    def step_rows_needed(self, q_starts, q_lens, block_q: int) -> int:
+        """Host side: the least packed width ``T`` at which
+        :meth:`serving_step` can run this batch at ``block_q`` — the
+        largest ``q_starts[s] + block(s)`` over the batched rows,
+        8-aligned, ``block(s)`` the tokens the attention launches of
+        this model's layers move for a row of ``q_lens[s]`` (each
+        kernel's own ``query_block_tokens``; the largest of the kinds
+        of layer the model has). 0 for an empty batch."""
+        from triton_distributed_tpu.kernels import (
+            kda_attention,
+            lightning_attention,
+            ragged_paged_attention,
+        )
+
+        c = self.config
+        blocks = []
+        if len(c.recurrent_layers) < c.n_layers:
+            # the softmax layers: latent in a latent model, else the
+            # contiguous, windowed or selected walk
+            blocks.append(ragged_paged_attention.query_block_tokens(
+                q_lens, block_q, latent=bool(c.kv_latent)))
+        if c.lightning_layers:
+            blocks.append(
+                lightning_attention.query_block_tokens(q_lens, block_q))
+        if c.kda_layers:
+            blocks.append(
+                kda_attention.query_block_tokens(q_lens, block_q))
+        ends = np.where(np.asarray(q_lens) > 0,
+                        np.asarray(q_starts) + np.maximum.reduce(blocks), 0)
+        return -(-int(ends.max(initial=0)) // 8) * 8
+
     def serving_step(self, params, state, tokens, token_rows, token_pos,
                      q_starts, q_lens, topologies=None, moe_state=None, *,
                      block_q: int = 8, use_pallas: bool = True,
@@ -2208,12 +2239,15 @@ class Transformer:
         for slots not in this batch). THE WIDTH ``T`` is the caller's:
         the step reads it off ``tokens`` and every row-sized operation
         (projections, routing, dispatch, the append's quantize) is that
-        wide. The engine gives a step at rung ``block_q`` the width
-        ``live + block_q`` with ``live = min(token_budget, slots *
-        block_q)`` where that is under its budget, ``token_budget +
-        block_q_cap`` otherwise; what the step needs is only ``q_starts[s]
-        + block_q <= T`` for EVERY slot (a ``q_lens == 0`` slot's
-        garbage block parks at ``live``). THE PACKING CONTRACT, which the
+        wide. What the step asks of it is ``q_starts[s] + block(s) <=
+        T`` for every slot IN the batch, ``block(s)`` the packed tokens
+        its attention launches move for that row
+        (:meth:`step_rows_needed` says the least such ``T``; the engine
+        takes the narrowest width of its ladder that covers it). A
+        ``q_lens == 0`` slot asks nothing where ``topologies`` is given:
+        every launch then skips it, whatever its ``q_starts`` (without
+        the operand the contiguous walk writes a garbage block there,
+        and the caller parks it past every span). THE PACKING CONTRACT, which the
         pool append relies on: slot ``s``'s tokens are the ONE
         contiguous span ``[q_starts[s], q_starts[s] + q_lens[s])`` of
         the packed array, spans do not overlap, and they sit at

@@ -18,16 +18,21 @@ Scheduling model (all host-side, numpy; the device work is ONE jitted
   ``token_budget``: each active slot contributes
   ``min(chunk, remaining_sequence)`` tokens — 1 in steady decode, up
   to ``chunk`` while prefilling — packed at 8-aligned offsets;
-* THE PACKED WIDTH FOLLOWS THE STEP'S ``block_q`` RUNG ``b`` (the
-  ladder rung covering the step's longest row, already a static
-  argument of the step program): every batched row holds at most ``b``
-  tokens, so the rows live below ``live(b) = min(token_budget,
-  slots * b)``. Where that is under the budget the step's arrays are
-  ``live(b) + b`` rows (rows outside the batch park at ``live(b)``,
-  in a zone of the rung's own ``block_q``); otherwise they are the
-  widest, ``_t_pad = token_budget + block_q_cap``. A function of the
-  rung, the slots and the budget alone, so the step jit still holds
-  one program per rung (:meth:`ServingEngine._width`);
+* THE PACKED WIDTH FOLLOWS THE BATCH. A step launches at one of TWO
+  ``block_q`` rungs: the lowest (8, or the tuned floor) where every
+  row fits it — a decode-only step, ``live + 8`` rows wide with
+  ``live = min(token_budget, 8 * slots)`` —, else the cap (the chunk's
+  block). What a step at the cap needs is ``q_starts[s] + block(s)``
+  rows for its batched rows (``Transformer.step_rows_needed``: the
+  query block each attention launch moves for the row; a slot outside
+  the batch needs nothing, every launch skips it), and its arrays are
+  the narrowest width of a short LADDER that covers that
+  (:func:`chunk_widths`: a chunk behind every decode row, a midpoint,
+  and the widest, ``_t_pad = token_budget + block_q_cap``). The key of
+  the step program, ``(block_q, width)``, is thus a function of what
+  ``_assemble`` packed; a rung's first launch builds the programs of
+  all its widths (:meth:`ServingEngine._unbuilt`), so none is built
+  under traffic;
 * pages for the new tokens are allocated from one shared free list;
   allocation failure triggers eviction (victims: the latest-arrived
   active request not already in this step's batch — LIFO preemption),
@@ -340,8 +345,14 @@ class EngineStats:
     latent_rows: int = 0
     chunk_rows_expanded: int = 0
     # packed rows of the device steps: the sum of their widths (each
-    # step's follows its ``block_q`` rung, ``ServingEngine._width``)
+    # step's follows its batch, ``ServingEngine._widths``)
     packed_rows: int = 0
+    # the steps that hold a CHUNK (a row longer than the low rung's
+    # block: they launch at the cap), the sum of their widths, and
+    # those of them narrower than the widest (``_t_pad``)
+    chunk_steps: int = 0
+    chunk_packed_rows: int = 0
+    chunk_narrow_steps: int = 0
     # device steps dispatched while the step before was not yet retired
     # (its token ids still on the device): ``ServingEngine.step``
     # launches ahead whenever ``_launch_ahead`` finds nothing that
@@ -588,17 +599,29 @@ def live_rows(block_q: int, slots: int, token_budget: int) -> int:
     return min(token_budget, slots * _ceil8(block_q))
 
 
-def packed_width(block_q: int, slots: int, token_budget: int,
-                 block_q_cap: int) -> int:
-    """The packed width of a step at rung ``block_q``: its live rows
-    and a parking zone of ``block_q`` where the slots cannot fill the
-    budget at that rung, the widest (``token_budget + block_q_cap``)
-    where they can. A function of the rung, the slots and the budget,
-    so one program per rung."""
-    live = live_rows(block_q, slots, token_budget)
-    if live < token_budget:
-        return live + block_q
-    return token_budget + block_q_cap
+def packed_width(block_q: int, slots: int, token_budget: int) -> int:
+    """The packed width of a step whose every row fits ``block_q``
+    tokens (the low rung; a decode-only step): its live rows and one
+    block, which covers ``q_starts[s] + block_q`` of its last row."""
+    return live_rows(block_q, slots, token_budget) + block_q
+
+
+#: rows a width must save against the next wider one of its ladder to
+#: be worth a step program of its own: each costs 2-4 s of a warm start
+#: (PERF.md section 6, PR 42)
+WORTH_A_PROGRAM = 64
+
+
+def chunk_widths(narrowest: int, widest: int, grain: int) -> tuple:
+    """The ladder of packed widths, ascending, of the steps that hold a
+    chunk: ``widest``, the multiple of ``grain`` at or under the
+    midpoint, and ``narrowest`` — each only where it saves
+    ``WORTH_A_PROGRAM`` rows against the next one up."""
+    ladder = [widest]
+    for w in ((narrowest + widest) // 2 // grain * grain, narrowest):
+        if w >= narrowest and ladder[0] - w >= WORTH_A_PROGRAM:
+            ladder.insert(0, w)
+    return tuple(ladder)
 
 
 # ---------------------------------------------- kind of state x feature
@@ -864,12 +887,13 @@ class ServingEngine:
         )
 
         self._block_q_cap = auto_block_q(cfg.chunk, g)
-        # the packed array carries a PARKING zone of block_q tokens past
-        # its live rows: rows outside the batch (q_len == 0) park their
-        # garbage writes there, where no valid span can be clobbered by
-        # the kernel's sequential out DMAs. ``_t_pad`` is the WIDEST
-        # width a step takes (``_width``: a low rung's step is narrower)
+        # ``_t_pad`` is the WIDEST width a step takes: a row that starts
+        # at the budget's end and moves the cap's block (``_widths``: a
+        # step is as wide as its batch needs, mostly narrower). No row
+        # PARKS anywhere: a slot outside the batch (q_len == 0) is
+        # skipped by every launch, given ``topologies``
         self._t_pad = cfg.token_budget + self._block_q_cap
+        self._ladders: dict = {}           # ``_widths``, by rung
         # grid-schedule resolution (explicit > stored > default): the
         # traffic key this engine's every step lands on. A winner
         # persisted by tune.traffic after an earlier run is picked up
@@ -898,10 +922,12 @@ class ServingEngine:
                 sched = GRID_DEFAULT      # stale ring entry: ignore
             self.grid_schedule = sched
             self._n_bufs = int(sched.n_bufs)
-            # tuned block_q is a FLOOR under the parking-zone cap: a step
-            # launches at the rung ``_rung`` gives, and its packed array
-            # carries that rung's parking tokens
+            # tuned block_q is a FLOOR under the cap: a step launches at
+            # the rung ``_rung`` gives
             self._block_q_floor = int(sched.block_q)
+            # the LOW rung: the least block a row of one token launches at
+            self._block_q_low = min(self._block_q_cap, max(
+                auto_block_q(1, g), self._block_q_floor))
             # LL MoE workspaces, sized to the packed step width: one set per
             # DISTINCT width, ``{width: per-layer states}``, built here and
             # never inside a step (``EPMoEState.instance`` is static: a
@@ -913,7 +939,8 @@ class ServingEngine:
             if moe_state == "auto":
                 moe_state = {
                     w: model.init_decode_state(w)
-                    for w in sorted({self._width(b) for b in self._rungs()})
+                    for w in sorted({w for b in self._rungs()
+                                     for w in self._widths(b)})
                 } if c.moe == "ep" and c.moe_layers else None
             self.moe_state = moe_state
         self._aligned_rows: dict = {}      # ``_moe_aligned_rows``
@@ -950,24 +977,24 @@ class ServingEngine:
 
     def _rung(self, max_q_len: int) -> int:
         """The ``block_q`` a step whose longest row packs ``max_q_len``
-        tokens launches at: the ladder rung covering it, no lower than
-        the tuned floor (grid schedule), never past the cap."""
+        tokens launches at. TWO rungs: the lowest block (no lower than
+        the tuned floor of the grid schedule, never past the cap) where
+        it covers that row — a decode-only step —, else the cap. The
+        blocks between would each cost a step program a width (2-4 s of
+        a warm start) to spare the rows beside a prompt's TAIL the
+        cap's query block, which they move beside every full chunk
+        anyway."""
         from triton_distributed_tpu.kernels.ragged_paged_attention import (
             auto_block_q,
         )
 
-        if self.model.config.kv_latent and max_q_len > 1:
-            # the latent walk cuts its query blocks by tokens whatever
-            # ``block_q``: two rungs, decode-only and the cap
-            return self._block_q_cap
-        return min(self._block_q_cap,
-                   max(auto_block_q(max_q_len, self._g),
-                       self._block_q_floor))
+        low = self._block_q_low
+        return low if auto_block_q(max_q_len, self._g) <= low \
+            else self._block_q_cap
 
     def _rungs(self) -> list:
         """Every ``block_q`` a step of this engine can launch at."""
-        return sorted({self._rung(1 << i) for i in
-                       range(self._block_q_cap.bit_length())})
+        return sorted({self._block_q_low, self._block_q_cap})
 
     def _moe_aligned_rows(self, width: int) -> int:
         """``Transformer.moe_aligned_rows`` of this engine's step at
@@ -977,11 +1004,58 @@ class ServingEngine:
                 width, self.params)
         return self._aligned_rows[width]
 
-    def _width(self, block_q: int) -> int:
-        """:func:`packed_width` of a step of this engine at rung
-        ``block_q`` (``_t_pad`` is the widest)."""
-        return packed_width(block_q, self.cfg.slots, self.cfg.token_budget,
-                            self._block_q_cap)
+    def _widths(self, block_q: int) -> tuple:
+        """The packed widths, ascending, a step of this engine at rung
+        ``block_q`` takes: ``_assemble`` picks the narrowest that covers
+        what its batch needs. Under the cap one, :func:`packed_width`.
+        At the cap :func:`chunk_widths` from that width and a chunk
+        behind it up to ``_t_pad``, no further than the first that
+        covers every batch the model's launches can ask for: a row of
+        ``t`` tokens packed against the budget's end asks most
+        (``Transformer.step_rows_needed``; a latent model's walk moves
+        a row's own tokens only, so its one width covers the budget)."""
+        if block_q not in self._ladders:
+            cfg, cap = self.cfg, self._block_q_cap
+            ladder = (packed_width(self._block_q_low, cfg.slots,
+                                   cfg.token_budget),)
+            if block_q == cap:
+                reach = max(
+                    self.model.step_rows_needed(
+                        [cfg.token_budget - _ceil8(t)], [t], cap)
+                    for t in range(1, cfg.chunk + 1))
+                ladder = chunk_widths(ladder[0] + cap, self._t_pad,
+                                      max(8, cap // 2))
+                # the widths some batch outgrows, and the first none does
+                ladder = ladder[:1 + sum(w < reach for w in ladder)]
+            self._ladders[block_q] = ladder
+        return self._ladders[block_q]
+
+    def _unbuilt(self, block_q: int, width: int) -> list:
+        """The widths of rung ``block_q`` other than ``width`` whose
+        step program this engine has not dispatched on its present
+        path: all of them at the rung's first launch, none afterwards.
+        ``step`` builds them there, each by an EMPTY batch
+        (:meth:`_empty_batch`) through the very frames a step's
+        dispatch runs in, so that no later batch — wider than any a
+        warm-up sent — builds a program under traffic."""
+        return [w for w in self._widths(block_q) if w != width and (
+            block_q, w, self.use_pallas, self._n_bufs)
+            not in self._dispatched]
+
+    def _empty_batch(self, width: int) -> tuple:
+        """The arrays of a step of ``width`` rows that holds no row at
+        all: every launch skips every slot, nothing is appended, and
+        the ``ServingState`` comes back as it went in."""
+        from triton_distributed_tpu.kernels.ragged_paged_attention import (
+            causal_topologies,
+            topo_width,
+        )
+
+        slots = np.zeros((self.cfg.slots,), np.int32)
+        rows = np.zeros((width,), np.int32)
+        return (rows, rows, np.full((width,), -1, np.int32), slots, slots,
+                slots, causal_topologies(self.cfg.slots,
+                                         topo_width(self._block_q_cap)))
 
     def _spec_key(self) -> tuple:
         """Speculation coordinates appended to the grid-schedule traffic
@@ -1351,13 +1425,14 @@ class ServingEngine:
             self.stats.deferrals += 1
         if cfg.prefix_share and batched:
             self._dedup_shared_prefixes(batched, topo, topo_w)
-        # the step's width follows its rung (``_width``): the batched
-        # rows end under ``live``, and inactive slots PARK their garbage
-        # output block there (see __init__) — never over another row's
-        # valid span
+        # the step is as wide as THIS batch needs at its rung: the
+        # narrowest of the rung's widths that covers every batched
+        # row's ``q_starts[s] + block(s)`` (the model says what its
+        # launches move for a row; a slot outside the batch stays at 0
+        # and is skipped by all of them)
         block_q = self._rung(int(q_lens.max()))
-        width = self._width(block_q)
-        q_starts[q_lens == 0] = live_rows(block_q, R, cfg.token_budget)
+        need = self.model.step_rows_needed(q_starts, q_lens, block_q)
+        width = next(w for w in self._widths(block_q) if w >= need)
         self._token_src = token_src[:width]
         return (tokens[:width], token_rows[:width], token_pos[:width],
                 q_starts, q_lens, kv_dev, topo, batched, takes)
@@ -1377,6 +1452,9 @@ class ServingEngine:
         jnp = self._jnp
         (tokens, token_rows, token_pos, q_starts, q_lens, kv_dev,
          topo) = arrays
+        # a slot outside the batch sits at row 0 of a width that has no
+        # room to park it: only a launch told to skip it is safe
+        assert topo is not None, "a step's batch needs its topologies"
         state = self.state.replace(
             block_table=jnp.asarray(self.table),
             kv_lens=jnp.asarray(kv_dev),
@@ -1588,6 +1666,8 @@ class ServingEngine:
             self.use_pallas = True
         c = self.model.config
         page = self.cfg.page
+        # a step that holds a chunk: a row longer than the low rung
+        chunk = int(int(q_lens.max()) > self._block_q_low)
         flight = _Flight(
             step=self.step_count, phase_s=self._phase_s, report=report,
             takes=takes, q_starts=q_starts, q_lens=q_lens,
@@ -1606,6 +1686,9 @@ class ServingEngine:
                 "latent_pages_walked": self._latent_work[0],
                 "latent_rows": self._latent_work[1],
                 "packed_rows": len(tokens),
+                "chunk_steps": chunk,
+                "chunk_packed_rows": chunk * len(tokens),
+                "chunk_narrow_steps": chunk * (len(tokens) < self._t_pad),
                 # the step program masks its padding rows' assignments
                 "moe_masked_rows": len(tokens) - report["tokens"]
                 if c.moe == "ep" and c.moe_layers else 0,
@@ -1658,6 +1741,11 @@ class ServingEngine:
             if batched:
                 arrays = (tokens, token_rows, token_pos, q_starts, q_lens,
                           kv_dev, topo)
+                if self._unbuilt(self._rung(int(q_lens.max())),
+                                 len(tokens)):
+                    # set-up, a rung's first launch: its other widths
+                    # are built with nothing in flight
+                    retired = self.drain() or retired
                 flight, block_q, probing = self._prepare(
                     arrays, takes, report)
         if not batched:
@@ -1670,6 +1758,18 @@ class ServingEngine:
         peer = self.health_peer
 
         def run_device():
+            # a rung's first launch on this path builds the programs of
+            # its other widths too, in THIS frame (what lies above the
+            # jitted call moves its tracing and the compile cache's
+            # key: PERF.md section 6, PR 39); the empty batches leave
+            # the state, and the ids a merge reads, as they were
+            unbuilt = self._unbuilt(block_q, len(arrays[0]))
+            if unbuilt:
+                ids, token_src = self._ids, self._token_src
+                for w in unbuilt:
+                    self._token_src = np.full((w,), -1, np.int32)
+                    self._run_device(self._empty_batch(w), block_q)
+                self._ids, self._token_src = ids, token_src
             flight.out = self._run_device(arrays, block_q)
             # the pool append of the step as it ran: by the kernel, a
             # (slot, page) run at a time, or by the row scatter
@@ -2090,9 +2190,9 @@ class DisaggregatedEngine:
             # packed width to 8·slots instead of the prefill budget,
             # never wider than it. Part of the point of the split: the
             # decode slice's steps stop paying prefill-sized
-            # buffers/blocks (the colocated engine narrows only its
-            # low-rung steps, ``ServingEngine._width`` — its budget
-            # must still carry prefill chunks). Evicted requests
+            # buffers/blocks (the colocated engine's steps follow their
+            # batch, ``ServingEngine._widths`` — its budget must still
+            # carry prefill chunks). Evicted requests
             # re-prefilling decode-side chunk at this narrower width.
             dbudget = max(8, min(8 * cfg.slots, cfg.token_budget))
             decode_cfg = _rep(

@@ -332,9 +332,9 @@ class SpeculativeEngine(ServingEngine):
         if spec_k < 1:
             raise ValueError(f"spec_k must be >= 1, got {spec_k}")
         if spec_k + 1 > cfg.chunk:
-            # the chunk bound sizes the kernel's block_q cap and the
-            # packed array's parking zone — a verify row wider than a
-            # prefill chunk would invalidate both
+            # the chunk bound sizes the kernel's block_q cap and with
+            # it the packed array's widest width — a verify row wider
+            # than a prefill chunk would invalidate both
             raise ValueError(
                 f"spec_k={spec_k} verify row exceeds chunk={cfg.chunk}")
         if spec_tree:
