@@ -36,10 +36,13 @@ from triton_distributed_tpu.kernels.lightning_attention import (  # noqa: E402
     lightning_attention_xla,
 )
 from triton_distributed_tpu.kernels.ragged_paged_attention import (  # noqa: E402
+    SELECT_KV_PAGES,
     _build_ragged,
     pack_gqa_rows,
     ragged_paged_attention,
     ragged_paged_attention_xla,
+    select_token_rows,
+    selected_bits,
 )
 from triton_distributed_tpu.models import Transformer, presets  # noqa: E402
 from triton_distributed_tpu.models.transformer import (  # noqa: E402
@@ -131,6 +134,9 @@ def test_engine_through_states_and_selected_pages_equals_the_reference(
     st = eng.stats
     assert st.state_rows > 0 and st.sparse_rows > 0
     assert st.selected_pages_walked > 0
+    # five prompts' chunks and tails, then one-token rows: five decode
+    # steps a request
+    assert st.selected_rows > st.selected_token_rows >= 5 * 5
     # one program per rung and width, whatever the contexts
     # (+ 1: the first step sees the pools as init_serving_state placed
     # them, every later one as a step returned them: PERF.md section 7)
@@ -544,6 +550,175 @@ def test_the_selected_kernel_is_its_twin_on_a_sparse_selection():
         np.testing.assert_allclose(
             np.asarray(got)[:, s * g:(s + n) * g],
             np.asarray(want)[:, s * g:(s + n) * g], atol=2e-5, rtol=2e-5)
+
+
+# ------------------------- (d2) a one-token row's tile of the walk
+
+KB = SELECT_KV_PAGES
+
+
+def _listed_step(seed, lens, takes, n_listed, *, g=4, dtype=jnp.float32,
+                 block_q=8, page=16, pps=16, hkv=2, d=16, block=8):
+    """A packed step with a selection made by hand, so that a one-token
+    row lists exactly ``n_listed[row]`` pages (None: every page it
+    holds, the contiguous list of a row below the dense length; a row
+    of more tokens chooses each position's own block and a random half
+    of those before it). Returns the kernel's arguments, its
+    ``selected`` and the rows' packed spans."""
+    rng = np.random.default_rng(seed)
+    r, npages, bpp = len(lens), len(lens) * pps, page // block
+    table = jnp.asarray(rng.permutation(npages).reshape(r, pps), jnp.int32)
+    kp, vp = (jnp.asarray(rng.normal(size=(npages, hkv, page, d)), dtype)
+              for _ in range(2))
+    starts, at = [], 0
+    for n in takes:
+        starts.append(at if n else 0)
+        at += -(-n // 8) * 8
+    # as wide as if every row were batched: steps of one ``block_q``
+    # share their programs
+    t = 8 * (r - 1) + -(-max(takes) // 8) * 8 + block_q
+    chosen = np.zeros((t, hkv, pps * bpp), bool)
+    pages = np.zeros((r, hkv, pps), np.int32)
+    counts = np.zeros((r, hkv), np.int32)
+    for i, (ln, n, s, want) in enumerate(zip(lens, takes, starts, n_listed)):
+        for h in range(hkv if n else 0):
+            held = -(-ln // page)
+            if n == 1 and want is not None:
+                # ``want`` distinct pages, the last held among them
+                # (the token's own), one or both blocks of each
+                listed = np.append(rng.choice(
+                    held - 1, want - 1, replace=False), held - 1)
+                for pg in listed:
+                    chosen[s, h, pg * bpp + rng.choice(
+                        bpp, 1 + rng.integers(bpp), replace=False)] = True
+                chosen[s, h, (ln - 1) // block] = True
+            for k in range(n if n > 1 or want is None else 0):
+                own = (ln - n + k) // block
+                chosen[s + k, h, :own + 1] = (
+                    want is None or rng.random(own + 1) < 0.5)
+                chosen[s + k, h, own] = True
+            pg = np.unique(np.nonzero(chosen[s:s + n, h].any(0))[0] // bpp)
+            pg = pg[pg < held]
+            pages[i, h, :len(pg)], counts[i, h] = pg, len(pg)
+    q = jnp.asarray(rng.normal(size=(t, hkv * g, d)), dtype)
+    lens_a, takes_a, starts_a = (
+        jnp.asarray(a, jnp.int32) for a in (lens, takes, starts))
+    args = (pack_gqa_rows(q, hkv), kp, vp, lens_a, takes_a, starts_a, table)
+    sel_ = (jnp.asarray(pages), jnp.asarray(counts),
+            selected_bits(jnp.asarray(chosen), g))
+    spans = [(s * g, (s + n) * g) for s, n in zip(starts, takes) if n]
+    return args, dict(group=g, selected=sel_, select_block=block), spans
+
+
+def _kernel_is_twin(args, kw, spans, block_q, tol):
+    want, _ = ragged_paged_attention_xla(*args, **kw)
+    got, _ = ragged_paged_attention(
+        *args, block_q=block_q, with_lse=False, **kw)
+    got, want = (np.asarray(a, np.float32) for a in (got, want))
+    for lo, hi in spans:
+        np.testing.assert_allclose(
+            got[:, lo:hi], want[:, lo:hi], atol=tol, rtol=tol)
+    return got
+
+
+@pytest.mark.parametrize("g,dtype,tok", [
+    (16, jnp.bfloat16, 1), (8, jnp.bfloat16, 2), (4, jnp.float32, 2)],
+    ids=["g16_bf16", "g8_bf16", "g4_f32"])
+def test_a_one_token_row_walks_its_tokens_rows_against_key_blocks(
+        g, dtype, tok):
+    """Five decode rows that list 1, kb - 1, kb, kb + 1 and 2 kb + 1
+    pages (a block's masked tail, a pair handing over to the next at
+    every fill of its last block) at the group sizes and dtypes that
+    decide the tile: ``tok`` tokens' rows, the fewest that fill the
+    dtype's sublane tile."""
+    assert select_token_rows(g, dtype, 8) == tok
+    n_listed = (1, KB - 1, KB, KB + 1, 2 * KB + 1)
+    args, kw, spans = _listed_step(
+        21, lens=(9, 100, 177, 250, 203), takes=(1,) * 5,
+        n_listed=n_listed, g=g, dtype=dtype)
+    assert np.asarray(kw["selected"][1]).tolist() == [[n, n] for n in n_listed]
+    _kernel_is_twin(args, kw, spans, 8,
+                    2e-5 if dtype == jnp.float32 else 2e-2)
+
+
+@pytest.mark.parametrize("n_pages", [1, KB - 1, KB, KB + 1, 2 * KB + 1])
+def test_a_pair_hands_over_to_the_next_at_any_fill_of_its_last_block(
+        n_pages):
+    """A decode row listing ``n_pages`` between two others: its first
+    key block was started by the pair before it, and its last starts
+    the next pair's query rows and first block."""
+    args, kw, spans = _listed_step(
+        30 + n_pages, lens=(120, 256, 77, 0, 0), takes=(1, 1, 1, 0, 0),
+        n_listed=(KB + 2, n_pages, 2, None, None))
+    _kernel_is_twin(args, kw, spans, 8, 2e-5)
+
+
+def test_a_row_below_the_dense_length_beside_one_above_it():
+    """A decode row that lists every page it holds, every block of it
+    (the contiguous list the selection gives below the dense length),
+    between two that list a choice."""
+    args, kw, spans = _listed_step(
+        13, lens=(100, 20, 200, 47, 31), takes=(1,) * 5,
+        n_listed=(3, None, KB + 1, None, 2))
+    assert np.asarray(kw["selected"][1])[:, 0].tolist() == [3, 2, KB + 1, 3, 2]
+    _kernel_is_twin(args, kw, spans, 8, 2e-5)
+
+
+def test_decode_rows_before_and_after_a_chunk_row_at_block_q_256():
+    """The fetch ahead changes size at both hand-overs: a one-token
+    pair starts a chunk pair's 256-token query block and single page,
+    the chunk pair a one-token pair's rows and key block; a tail of 5
+    tokens takes the short tile between them."""
+    args, kw, spans = _listed_step(
+        41, lens=(150, 256, 90, 61, 230), takes=(1, 40, 1, 5, 1),
+        n_listed=(KB + 1, None, 3, None, 2 * KB), block_q=256)
+    _kernel_is_twin(args, kw, spans, 256, 2e-5)
+
+
+def test_a_batch_whose_first_rows_are_not_batched():
+    """Rows outside the batch (``q_lens`` 0, ``counts`` 0) come first:
+    the first ACTIVE pair is warmed, at its own size."""
+    args, kw, spans = _listed_step(
+        43, lens=(0, 0, 140, 0, 33), takes=(0, 0, 1, 0, 1),
+        n_listed=(None, None, KB + 1, None, 2))
+    assert np.asarray(kw["selected"][1]).tolist()[:2] == [[0, 0], [0, 0]]
+    _kernel_is_twin(args, kw, spans, 8, 2e-5)
+
+
+def test_rows_of_two_to_eight_tokens_keep_their_walk_bit_for_bit():
+    """Rows of 2, 5 and 8 tokens beside decode rows: at ``block_q`` 16
+    they walk (and now fetch) the short tile of 8 tokens, a page an
+    iteration, and give the bits the launch's own block of 8 gives
+    them at ``block_q`` 8: the walk PR 45 found, whatever the decode
+    rows beside them do."""
+    args, kw, spans = _listed_step(
+        47, lens=(130, 64, 250, 99, 18), takes=(2, 1, 5, 1, 8),
+        n_listed=(None, 3, None, KB + 1, None), block_q=16)
+    at16 = _kernel_is_twin(args, kw, spans, 16, 2e-5)
+    at8 = _kernel_is_twin(args, kw, spans, 8, 2e-5)
+    for lo, hi in (spans[0], spans[2], spans[4]):
+        np.testing.assert_array_equal(at16[:, lo:hi], at8[:, lo:hi])
+
+
+@pytest.mark.parametrize("model", ["sparse", "dense"])
+def test_assemble_counts_the_rows_of_the_selected_walk(model):
+    """``selected_rows`` counts the rows ``_assemble`` batches on a
+    model with sparse layers and ``selected_token_rows`` those of ONE
+    token (a prompt's tail of one token is one too); a dense model
+    reads 0 / 0. (Booked into ``EngineStats`` with the step's other
+    counts: the engine test above reads them there.)"""
+    if model == "sparse":
+        mdl, _, params = seeded(tiny_config())
+    else:
+        mdl = one_chip_model(presets.tiny())
+        params = mdl.init(jax.random.PRNGKey(0))
+    eng = ServingEngine(mdl, params, ENGINE, use_pallas=False)
+    for i, p in enumerate(prompts_of((1, 40, 1, 9))):
+        eng.submit(Request(rid=i, prompt=p, max_new=2, arrival=0))
+    eng._admit()
+    eng._assemble()
+    assert (*eng._selected_work, eng._state_work[0] > 0) == (
+        (4, 2, True) if model == "sparse" else (0, 0, False))
 
 
 # --------------------------------------------- (e) compressed keys

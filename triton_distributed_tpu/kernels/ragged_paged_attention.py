@@ -717,28 +717,71 @@ def _build_ragged(
 #
 # Key ``j`` is visible to a query at position ``i`` iff ``j <= i`` and
 # bit ``j // select_block`` of the query's bitmap is set.
+#
+# A (row, KV head) pair is walked by the LENGTH of its row, three tiles
+# in one launch (what a pair walks it also FETCHES and writes back: the
+# query rows, their bitmap words, the out rows):
+#
+#   q_len == 1            ``select_token_rows`` tokens' G rows (ONE
+#   (every decode row)    token's where G fills the query dtype's
+#                         sublane tile) against a key block of
+#                         ``SELECT_KV_PAGES`` listed pages an iteration:
+#                         that many K and V copies into one buffer, one
+#                         QK^T, one mask row (the token's own: built a
+#                         page at a time from the page's index and its
+#                         bitmap word), one softmax update, one PV
+#   2 .. SELECT_SHORT     ``SELECT_SHORT`` tokens' rows against one page
+#   (at block_q above it) an iteration
+#   longer                the launch's ``block_q`` tokens' rows against
+#                         one page an iteration
+#
+# Buffers: the key blocks' ``2 + n_bufs`` slots of ``SELECT_KV_PAGES``
+# pages each — slots 0 / 1 hold the FIRST block of the pair of that
+# parity, fetched with its query rows and bitmap words a whole pair
+# ahead (under the walk of the pair before it); the rest are the ring a
+# pair's further blocks go round, one block ahead. No state is carried
+# from pair to pair but what those fetches left in the other parity's
+# buffers.
 
 #: int32 words of one query row's block bitmap (one lane tile)
 SELECT_WORDS = 128
-#: a row of at most this many tokens is walked as a query block of this
-#: many tokens whatever the launch's ``block_q``
+#: a row of 2 to this many tokens is walked (fetched, written back) as
+#: a query block of this many tokens whatever the launch's ``block_q``;
+#: a row of ONE token as ``select_token_rows`` tokens
 SELECT_SHORT = 8
+#: listed pages of one key block of a one-token row's walk (fetched
+#: into one buffer, scored as one block)
+SELECT_KV_PAGES = 4
+
+
+def select_token_rows(g: int, q_dtype, block_q: int) -> int:
+    """Tokens a one-token row of the selected walk is walked as: the
+    fewest whose ``t · G`` query rows fill the dtype's sublane tile (8
+    rows of 4 bytes, 16 of 2), never more than ``SELECT_SHORT`` or the
+    launch's block."""
+    tile = 8 * max(1, 4 // jnp.dtype(q_dtype).itemsize)
+    return min(tile // math.gcd(g, tile), SELECT_SHORT, block_q)
 
 
 def _selected_kernel(
-    scale, page, n_bufs, hkv, g, d, block_q, block, *refs,
+    scale, page, n_bufs, hkv, g, d, block_q, block, tok, kb, *refs,
 ):
     """Grid (R·Hkv,): step ``i`` visits the i-th ACTIVE (row, KV head)
     pair of ``order`` (row-major, so rows are visited in ascending
     order and a short row's out block is healed by the next row's, as
     in ``_ragged_kernel``); steps past ``n_active`` do nothing. Per
-    pair: the walk over ``pages[vr, :counts[vr]]`` with table-indexed
-    pool DMAs ``n_bufs`` deep, the query block and its bitmap words
-    fetched one pair ahead, every page masked causally and by block."""
+    pair: the walk over ``pages[vr, :counts[vr]]`` by key blocks — one
+    page, or ``kb`` pages for a row of one token (``tok`` tokens' rows:
+    the layout notes above) — with table-indexed pool DMAs, every key
+    masked causally and by block. A pair's FIRST key block, its query
+    rows and their bitmap words are fetched a whole pair ahead (when
+    the pair before it starts, at the size THIS pair walks) into the
+    buffers of the pair's parity; its further blocks go round a ring
+    of ``n_bufs`` slots, one block ahead."""
     (table_ref, kv_lens_ref, q_lens_ref, q_starts_ref, order_ref, n_ref,
      pages_ref, counts_ref, q_hbm, k_hbm, v_hbm, bits_hbm, out_hbm,
      qbuf, bbuf, kbuf, vbuf, obuf, sem_q, sem_b, sem_k, sem_v, sem_o,
-     slot_ref, m_ref, l_ref, acc_ref) = refs
+     m_ref, l_ref, acc_ref) = refs
     i = pl.program_id(0)
     n_active = n_ref[0]
     npages = k_hbm.shape[0]
@@ -749,81 +792,156 @@ def _selected_kernel(
         vr = order_ref[step]
         return vr, jax.lax.div(vr, hkv), jax.lax.rem(vr, hkv)
 
-    def kvdma(step, j, slot):
-        vr, r, h = pair(step)
-        lp = pages_ref[vr, jnp.minimum(j, jnp.maximum(counts_ref[vr] - 1, 0))]
+    def by_len(q_len, fn):
+        """``fn(rows_c, token)`` under the one ``pl.when`` a row of
+        ``q_len`` tokens falls in: the (static) query rows it walks,
+        and whether as a row of ONE token (``kb`` listed pages an
+        iteration) or a page an iteration."""
+        pl.when(q_len == 1)(functools.partial(fn, tok * g, True))
+        if block_q > SELECT_SHORT:
+            # a row of at most SELECT_SHORT tokens (a prompt's tail, a
+            # verify row) walks as a block of that many, not of block_q
+            pl.when(jnp.logical_and(q_len > 1, q_len <= SELECT_SHORT))(
+                functools.partial(fn, SELECT_SHORT * g, False))
+            pl.when(q_len > SELECT_SHORT)(
+                functools.partial(fn, rows, False))
+        else:
+            pl.when(q_len > 1)(functools.partial(fn, rows, False))
+
+    def slot_of(parity, j):
+        # the buffer of a pair's key block ``j``: its first in slot
+        # ``parity`` (0 / 1: fetched a pair ahead), the rest round the
+        # ring behind them
+        return jnp.where(j == 0, parity, 2 + jax.lax.rem(j - 1, n_bufs))
+
+    def part(u):
+        # page ``u`` of a slot's buffer (static or traced ``u``)
+        at = u * page
+        return pl.ds(at if isinstance(u, int) else pl.multiple_of(at, page),
+                     page)
+
+    def kvdma(pr, at, slot, u=0):
+        """The K and V copies of pair ``pr``'s ``at``-th listed page
+        into page ``u`` of a slot (a page past the list's end is its
+        last again: masked whole by the walk)."""
+        vr, r, h = pr
+        lp = pages_ref[vr, jnp.minimum(at, jnp.maximum(counts_ref[vr] - 1, 0))]
         pid = jnp.clip(table_ref[r, lp], 0, npages - 1)
         return [
             pltpu.make_async_copy(
-                k_hbm.at[pid, h], kbuf.at[slot], sem_k.at[slot]),
+                k_hbm.at[pid, h], kbuf.at[slot, part(u)], sem_k.at[slot, u]),
             pltpu.make_async_copy(
-                v_hbm.at[pid, h], vbuf.at[slot], sem_v.at[slot]),
+                v_hbm.at[pid, h], vbuf.at[slot, part(u)], sem_v.at[slot, u]),
         ]
 
-    def qdma(step, qslot):
-        _, r, h = pair(step)
+    def each_page(fn):
+        # a rolled loop: a step program traces (and lowers) one page's
+        # copies, not ``kb`` of them, wherever a block starts or lands
+        jax.lax.fori_loop(0, kb, lambda u, _: fn(u) or 0, 0)
+
+    def start_block(pr, j, slot, token):
+        """Start the copies of pair ``pr``'s key block ``j`` into a
+        slot: a one-token row's listed pages ``[j·kb, (j+1)·kb)``, any
+        other row's page ``j``."""
+        def one(u):
+            for cp in kvdma(pr, j * kb + u, slot, u):
+                cp.start()
+
+        if token:
+            each_page(one)
+        else:
+            for cp in kvdma(pr, j, slot):
+                cp.start()
+
+    def wait_block(slot):
+        """Wait for a one-token row's ``start_block`` into the slot:
+        the same destinations and semaphores, no address of a source
+        worked out again."""
+        def one(u):
+            pltpu.make_async_copy(
+                k_hbm.at[0, 0], kbuf.at[slot, part(u)],
+                sem_k.at[slot, u]).wait()
+            pltpu.make_async_copy(
+                v_hbm.at[0, 0], vbuf.at[slot, part(u)],
+                sem_v.at[slot, u]).wait()
+
+        each_page(one)
+
+    def qdma(pr, parity, rows_c):
+        _, r, h = pr
         start = pl.multiple_of(q_starts_ref[r] * g, 8)
         return [
             pltpu.make_async_copy(
-                q_hbm.at[h, pl.ds(start, rows)], qbuf.at[qslot],
-                sem_q.at[qslot]),
+                q_hbm.at[h, pl.ds(start, rows_c)],
+                qbuf.at[parity, pl.ds(0, rows_c)], sem_q.at[parity]),
             pltpu.make_async_copy(
-                bits_hbm.at[h, pl.ds(start, rows)], bbuf.at[qslot],
-                sem_b.at[qslot]),
+                bits_hbm.at[h, pl.ds(start, rows_c)],
+                bbuf.at[parity, pl.ds(0, rows_c)], sem_b.at[parity]),
         ]
 
-    @pl.when(i == 0)
-    def _warmup():
-        slot_ref[0] = 0                       # KV slot rotation carry
+    def start_pair(step, parity):
+        """Start what the pair of grid step ``step`` finds waiting: the
+        query rows and bitmap words it will walk and its first key
+        block, in the buffers of its parity."""
+        pr = pair(step)
 
-        @pl.when(n_active > 0)
-        def _start_first():
-            for cp in qdma(0, 0) + kvdma(0, 0, 0):
+        def go(rows_c, token):
+            for cp in qdma(pr, parity, rows_c):
                 cp.start()
+            start_block(pr, 0, parity, token)
+
+        by_len(q_lens_ref[pr[1]], go)
+
+    # the fetches of the NEXT pair run under the whole of this pair's
+    # walk (the other parity's buffers are free since the pair before
+    # this one ended); grid step 0 starts its own pair's first. One
+    # rolled loop, so that a step program traces ``start_pair`` once
+    jax.lax.fori_loop(
+        jnp.where(i == 0, 0, i + 1), jnp.minimum(i + 2, n_active),
+        lambda t, _: start_pair(t, jax.lax.rem(t, 2)) or 0, 0)
 
     @pl.when(i < n_active)
     def _pair():
-        vr, r, h = pair(i)
-        s0 = slot_ref[0]
-        qslot = jax.lax.rem(i, 2)
+        pr = vr, r, h = pair(i)
+        parity = jax.lax.rem(i, 2)
         cnt = jnp.maximum(counts_ref[vr], 1)
         kv_len = kv_lens_ref[r]
         q_len = q_lens_ref[r]
-        for cp in qdma(i, qslot):
-            cp.wait()
         lane_w = jax.lax.broadcasted_iota(jnp.int32, (1, SELECT_WORDS), 1)
         lane_p = jax.lax.broadcasted_iota(jnp.int32, (1, page), 1)
         lane_blk = jax.lax.div(lane_p, block)
         start = pl.multiple_of(q_starts_ref[r] * g, 8)
 
+        def look_ahead(j, nblk, token):
+            @pl.when(j + 1 < nblk)
+            def _prefetch():
+                start_block(pr, j + 1, slot_of(parity, j + 1), token)
+
+        def write_out(rows_c):
+            out = pltpu.make_async_copy(
+                obuf.at[pl.ds(0, rows_c)],
+                out_hbm.at[h, pl.ds(start, rows_c)], sem_o.at[0])
+            out.start()
+            # waited before the grid advances (the out self-heal's order)
+            out.wait()
+
         def walk(rows_c):
             """The pair's walk over the first ``rows_c`` (static) rows
-            of its query block."""
+            of its query block, a page an iteration."""
             m_ref[:rows_c] = jnp.full((rows_c, 1), NEG_INF, jnp.float32)
             l_ref[:rows_c] = jnp.zeros((rows_c, 1), jnp.float32)
             acc_ref[:rows_c] = jnp.zeros((rows_c, d), jnp.float32)
-            q = qbuf[qslot, :rows_c]              # (rows_c, d)
-            words = bbuf[qslot, :rows_c]          # (rows_c, SELECT_WORDS)
+            q = qbuf[parity, :rows_c]             # (rows_c, d)
+            words = bbuf[parity, :rows_c]         # (rows_c, SELECT_WORDS)
             row_tok = jax.lax.div(
                 jax.lax.broadcasted_iota(jnp.int32, (rows_c, 1), 0), g)
             limit = kv_len - q_len + row_tok + 1  # (rows_c, 1)
 
             def body(j, _):
-                slot = jax.lax.rem(s0 + j, n_bufs)
-                nxt = jax.lax.rem(s0 + j + 1, n_bufs)
-
-                @pl.when(j + 1 < cnt)
-                def _prefetch_in_pair():
-                    for cp in kvdma(i, j + 1, nxt):
-                        cp.start()
-
-                @pl.when(jnp.logical_and(j + 1 == cnt, i + 1 < n_active))
-                def _prefetch_next_pair():
-                    for cp in qdma(i + 1, 1 - qslot) + kvdma(i + 1, 0, nxt):
-                        cp.start()
-
+                slot = slot_of(parity, j)
+                look_ahead(j, cnt, False)
                 chaos_delay(site="ragged_paged", step=None, me=None, n=None)
-                for cp in kvdma(i, j, slot):
+                for cp in kvdma(pr, j, slot):
                     cp.wait()
                 lp = pages_ref[vr, jnp.minimum(j, cnt - 1)]
                 # the page's ``bpp`` bits of every query row: one word
@@ -846,7 +964,7 @@ def _selected_kernel(
                 pos = lp * page + lane_p
                 valid = jnp.logical_and(pos < limit, chosen > 0)
                 s = jax.lax.dot_general(
-                    q, kbuf[slot], (((1,), (1,)), ((), ())),
+                    q, kbuf[slot, :page], (((1,), (1,)), ((), ())),
                     preferred_element_type=jnp.float32,
                 ) * scale                         # (rows_c, page) f32
                 s = jnp.where(valid, s, NEG_INF)
@@ -856,7 +974,7 @@ def _selected_kernel(
                 p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
                 l_ref[:rows_c] = alpha * l_ref[:rows_c] + jnp.sum(
                     p, axis=1, keepdims=True)
-                v = vbuf[slot]
+                v = vbuf[slot, :page]
                 acc_ref[:rows_c] = alpha * acc_ref[:rows_c] + jnp.dot(
                     p.astype(v.dtype), v,
                     preferred_element_type=jnp.float32)
@@ -868,22 +986,77 @@ def _selected_kernel(
             obuf[:rows_c] = (
                 acc_ref[:rows_c] / jnp.where(l > 0.0, l, 1.0)
             ).astype(obuf.dtype)
-            out = pltpu.make_async_copy(
-                obuf.at[pl.ds(0, rows_c)],
-                out_hbm.at[h, pl.ds(start, rows_c)], sem_o.at[0])
-            out.start()
-            # waited before the grid advances (the out self-heal's order)
-            out.wait()
+            write_out(rows_c)
 
-        # a row of at most SELECT_SHORT tokens (a decode row beside a
-        # prefill chunk) walks as a block of that many, not of block_q
-        if block_q > SELECT_SHORT:
-            short = q_len <= SELECT_SHORT
-            pl.when(short)(functools.partial(walk, SELECT_SHORT * g))
-            pl.when(jnp.logical_not(short))(functools.partial(walk, rows))
-        else:
-            walk(rows)
-        slot_ref[0] = jax.lax.rem(s0 + cnt, n_bufs)
+        def token_walk(rows_c):
+            """The walk of a row of ONE token: its ``rows_c`` (static)
+            query rows against ``kb`` listed pages an iteration. Every
+            live row is the one token's, so the mask is ONE row: the
+            token's bitmap words (row 0's) and its position
+            ``kv_len - 1``."""
+            nblk = jax.lax.div(cnt + kb - 1, kb)
+            q = qbuf[parity, :rows_c]             # (rows_c, d)
+            words = bbuf[parity, :1]              # (1, SELECT_WORDS)
+
+            def body(j, carry):
+                m, l, acc = carry
+                slot = slot_of(parity, j)
+                look_ahead(j, nblk, True)
+                chaos_delay(site="ragged_paged", step=None, me=None, n=None)
+                wait_block(slot)
+                seen = []
+                for u in range(kb):               # static: a page's lanes
+                    at = j * kb + u
+                    lp = pages_ref[vr, jnp.minimum(at, cnt - 1)]
+                    bit0 = lp * bpp
+                    word = jnp.sum(
+                        jnp.where(lane_w == jax.lax.div(bit0, 32), words, 0),
+                        axis=1, keepdims=True)    # (1, 1)
+                    bit = jax.lax.shift_right_logical(
+                        jnp.broadcast_to(word, (1, page)),
+                        jax.lax.rem(bit0, 32) + lane_blk) & 1
+                    # a page past the list's end (the last again) sees
+                    # nothing
+                    lim = jnp.where(at < cnt, kv_len, 0)
+                    seen.append(jnp.where(lp * page + lane_p < lim, bit, 0))
+                seen = jnp.concatenate(seen, axis=1)
+                valid = jnp.broadcast_to(seen, (rows_c, kb * page)) > 0
+                s = jax.lax.dot_general(
+                    q, kbuf[slot], (((1,), (1,)), ((), ())),
+                    preferred_element_type=jnp.float32,
+                ) * scale                         # (rows_c, kb·page) f32
+                s = jnp.where(valid, s, NEG_INF)
+                m_new = jnp.maximum(m, jnp.max(s, axis=1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.where(valid, jnp.exp(s - m_new), 0.0)
+                l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+                v = vbuf[slot]
+                acc = alpha * acc + jnp.dot(
+                    p.astype(v.dtype), v, preferred_element_type=jnp.float32)
+                return m_new, l, acc
+
+            _, l, acc = jax.lax.fori_loop(0, nblk, body, (
+                jnp.full((rows_c, 1), NEG_INF, jnp.float32),
+                jnp.zeros((rows_c, 1), jnp.float32),
+                jnp.zeros((rows_c, d), jnp.float32)))
+            obuf[:rows_c] = (
+                acc / jnp.where(l > 0.0, l, 1.0)).astype(obuf.dtype)
+            # the rest of the row's packing slot goes back as zeros: a
+            # later layer's chunk form multiplies a padding token's
+            # rows by 0 (kernels/lightning_attention.py), so they must
+            # be finite, which rows nobody wrote need not be
+            slot_rows = min(SELECT_SHORT, block_q) * g
+            if slot_rows > rows_c:
+                obuf[rows_c:slot_rows] = jnp.zeros(
+                    (slot_rows - rows_c, d), obuf.dtype)
+            write_out(slot_rows)
+
+        def walk_pair(rows_c, token):
+            for cp in qdma(pr, parity, rows_c):
+                cp.wait()
+            (token_walk if token else walk)(rows_c)
+
+        by_len(q_len, walk_pair)
 
 
 @functools.lru_cache(maxsize=64)
@@ -896,8 +1069,10 @@ def _build_selected(
     k_pool, v_pool, bits)`` and returns ``[out]``."""
     q_dtype = jnp.dtype(q_dtype)
     rows = block_q * g
+    tok, kb = select_token_rows(g, q_dtype, block_q), SELECT_KV_PAGES
     kernel = functools.partial(
-        _selected_kernel, scale, page, n_bufs, hkv, g, d, block_q, block)
+        _selected_kernel, scale, page, n_bufs, hkv, g, d, block_q, block,
+        tok, kb)
     any_ = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=8,
@@ -907,25 +1082,26 @@ def _build_selected(
         scratch_shapes=[
             pltpu.VMEM((2, rows, d), q_dtype),               # qbuf
             pltpu.VMEM((2, rows, SELECT_WORDS), jnp.int32),  # bbuf
-            pltpu.VMEM((n_bufs, page, d), q_dtype),          # kbuf
-            pltpu.VMEM((n_bufs, page, d), q_dtype),          # vbuf
+            # two first-block buffers (a pair's parity) + the ring
+            pltpu.VMEM((2 + n_bufs, kb * page, d), q_dtype),  # kbuf
+            pltpu.VMEM((2 + n_bufs, kb * page, d), q_dtype),  # vbuf
             pltpu.VMEM((rows, d), q_dtype),                  # obuf
             pltpu.SemaphoreType.DMA((2,)),                   # sem_q
             pltpu.SemaphoreType.DMA((2,)),                   # sem_b
-            pltpu.SemaphoreType.DMA((n_bufs,)),              # sem_k
-            pltpu.SemaphoreType.DMA((n_bufs,)),              # sem_v
+            pltpu.SemaphoreType.DMA((2 + n_bufs, kb)),       # sem_k
+            pltpu.SemaphoreType.DMA((2 + n_bufs, kb)),       # sem_v
             pltpu.SemaphoreType.DMA((1,)),                   # sem_o
-            pltpu.SMEM((1,), jnp.int32),                     # slot carry
             pltpu.VMEM((rows, 1), jnp.float32),              # m
             pltpu.VMEM((rows, 1), jnp.float32),              # l
             pltpu.VMEM((rows, d), jnp.float32),              # acc
         ],
     )
     # q/out blocks, the bitmap words, softmax state (the (·, 1) columns
-    # pad to full lanes) and the (rows, page) score temporaries
+    # pad to full lanes) and the score temporaries of the widest tile
+    scores = max(rows * page, tok * g * kb * page)
     total = (3 * rows * d * q_dtype.itemsize + 2 * rows * SELECT_WORDS * 4
-             + rows * (d + 2 * 128) * 4 + 6 * rows * page * 4
-             + 4 * n_bufs * page * d * q_dtype.itemsize)
+             + rows * (d + 2 * 128) * 4 + 6 * scores * 4
+             + 2 * (2 + n_bufs) * kb * page * d * q_dtype.itemsize)
     return shmem_call(
         kernel,
         grid_spec=grid_spec,
@@ -1183,11 +1359,15 @@ def query_block_tokens(q_lens, block_q: int, *, latent: bool = False):
     whole of what the launch asks of the packed width; 0 for a row
     outside the batch (``q_lens == 0``: with ``topologies`` the
     contiguous walk skips its body, the other two never visit it). The
-    contiguous and the selected walk fetch the launch's ``block_q`` for
-    every row they visit (a row of at most ``SELECT_SHORT`` tokens then
-    WALKS and writes that many only); the latent walk (``latent``) cuts
-    a row into blocks of ``LATENT_TQ`` tokens and moves a decode row's
-    one."""
+    contiguous walk moves the launch's ``block_q`` for every row it
+    visits; the latent walk (``latent``) cuts a row into blocks of
+    ``LATENT_TQ`` tokens and moves a decode row's one. The selected
+    walk moves LESS than this says for a short row — a row of one token
+    fetches ``select_token_rows`` tokens (one where G fills a sublane
+    tile) and writes its 8-token packing slot back, a row of 2 to
+    ``SELECT_SHORT`` tokens fetches and writes ``SELECT_SHORT`` — and
+    ``block_q`` for a longer one: for it this is a safe over-estimate,
+    not narrowed (the engine's widths were sized by it)."""
     q_lens = np.asarray(q_lens)
     if latent:
         block = np.where(q_lens == 1, 1, -(-q_lens // LATENT_TQ) * LATENT_TQ)

@@ -326,6 +326,12 @@ class EngineStats:
     selected_pages_walked: int = 0
     sparse_rows: int = 0
     state_rows: int = 0
+    # rows batched through ONE sparse layer's selected walk, and those
+    # of them of ONE token (every decode row): the walk's third tile,
+    # one token's query rows against several listed pages an iteration
+    # (kernels/ragged_paged_attention.py, the selected walk's notes)
+    selected_rows: int = 0
+    selected_token_rows: int = 0
     # the work of a model with gated delta-rule (kda) layers (0 without
     # them), counted beside ``state_rows``: rows batched through ONE
     # layer's ``kda_attention`` launch, and those of them with more
@@ -865,6 +871,8 @@ class ServingEngine:
         self._pages_walked = [0, 0]     # likewise: [global, window]
         # likewise: [selected pages, sparse rows, state rows]
         self._state_work = [0, 0, 0]
+        # likewise: [rows through the selected walk, of them one-token]
+        self._selected_work = [0, 0]
         self._latent_work = [0, 0]      # likewise: [pages fetched, rows]
         self._kda_work = [0, 0]         # likewise: [rows, chunk rows]
         # seconds of the running step inside each phase (``_phase``)
@@ -1356,6 +1364,7 @@ class ServingEngine:
         self._append_runs = 0
         self._pages_walked = [0, 0]     # [global, window], one layer each
         self._state_work = [0, 0, 0]
+        self._selected_work = [0, 0]    # [rows, rows of one token]
         self._latent_work = [0, 0]      # [pages fetched, rows]
         self._kda_work = [0, 0]         # [rows, rows of several tokens]
         mc = self.model.config
@@ -1404,6 +1413,8 @@ class ServingEngine:
                         min(need, mc.sparse_topk) if take == 1 else need)
                     self._state_work[1] += (
                         cur + take > mc.sparse_dense_len)
+                    self._selected_work[0] += 1
+                    self._selected_work[1] += take == 1
                 if mc.recurrent_layers:
                     self._state_work[2] += 1
                 if mc.kda_layers:
@@ -1681,6 +1692,8 @@ class ServingEngine:
                 "selected_pages_walked": self._state_work[0],
                 "sparse_rows": self._state_work[1],
                 "state_rows": self._state_work[2],
+                "selected_rows": self._selected_work[0],
+                "selected_token_rows": self._selected_work[1],
                 "kda_rows": self._kda_work[0],
                 "kda_chunk_rows": self._kda_work[1],
                 "latent_pages_walked": self._latent_work[0],
