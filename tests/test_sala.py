@@ -31,6 +31,8 @@ from benchmark.models import minicpm_sala as ref  # noqa: E402
 from conftest import on_host, serve_all_logits  # noqa: E402
 from triton_distributed_tpu.kernels import sparse_select as sel  # noqa: E402
 from triton_distributed_tpu.kernels.lightning_attention import (  # noqa: E402
+    SHORT,
+    _span_update,
     decay_slopes,
     lightning_attention,
     lightning_attention_xla,
@@ -137,6 +139,11 @@ def test_engine_through_states_and_selected_pages_equals_the_reference(
     # five prompts' chunks and tails, then one-token rows: five decode
     # steps a request
     assert st.selected_rows > st.selected_token_rows >= 5 * 5
+    # chunks of 16 take the lightning launch's chunk form; tails of at
+    # most SHORT tokens (70 = 4 x 16 + 6, 9 ...) and decode rows its
+    # rank-1 form
+    assert st.state_rows == st.selected_rows
+    assert st.state_rows > st.state_token_rows > st.selected_token_rows
     # one program per rung and width, whatever the contexts
     # (+ 1: the first step sees the pools as init_serving_state placed
     # them, every later one as a step returned them: PERF.md section 7)
@@ -192,30 +199,90 @@ def _naive_recurrence(q, k, v, state, kv_lens, q_lens, q_starts):
     return o, new
 
 
-@pytest.mark.parametrize("mix", [lightning_attention_xla, lightning_attention],
-                         ids=["xla_twin", "kernel_interpreted"])
+MIXES = pytest.mark.parametrize(
+    "mix", [lightning_attention_xla, lightning_attention],
+    ids=["xla_twin", "kernel_interpreted"])
+
+
+def _mixed(mix, seed, q_lens, q_starts, kv_lens, t, block_q, h=4, d=16):
+    """One launch on seeded inputs against the float64 recurrence:
+    every span's outputs and every state at 1e-4. Returns ``(o, new,
+    inputs)``."""
+    rng = np.random.default_rng(seed)
+    q, k, v = (jnp.asarray(rng.normal(size=(h, t, d)), jnp.float32)
+               for _ in range(3))
+    state = jnp.asarray(rng.normal(size=(len(q_lens), h, d, d)), jnp.float32)
+    lens = tuple(jnp.asarray(a, jnp.int32)
+                 for a in (kv_lens, q_lens, q_starts))
+    o, new = mix(q, k, v, state, *lens, block_q=block_q)
+    want_o, want_s = _naive_recurrence(q, k, v, state, *lens)
+    for start, n in zip(q_starts, q_lens):
+        np.testing.assert_allclose(
+            np.asarray(o)[:, start:start + n], want_o[:, start:start + n],
+            atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(np.asarray(new), want_s, atol=1e-4, rtol=1e-4)
+    for r, n in enumerate(q_lens):
+        if not n:       # a slot not batched: untouched, bit for bit
+            np.testing.assert_array_equal(np.asarray(new)[r],
+                                          np.asarray(state)[r])
+    return np.asarray(o), np.asarray(new), (q, k, v, state)
+
+
+@MIXES
 def test_the_chunk_form_is_the_token_by_token_recurrence(mix):
     """Spans of 1, 5 and a whole block, one starting at position 0
     (from zero whatever the slot held), one slot not batched (its state
     untouched): outputs and states equal the recurrence run a token at
     a time in float64 (1e-4: float32 accumulation over 16 positions)."""
-    h, d, r, t, b = 4, 16, 4, 48, 16
-    rng = np.random.default_rng(0)
-    q, k, v = (jnp.asarray(rng.normal(size=(h, t, d)), jnp.float32)
-               for _ in range(3))
-    state = jnp.asarray(rng.normal(size=(r, h, d, d)), jnp.float32)
-    q_lens = jnp.asarray([5, 0, 1, 16], jnp.int32)
-    q_starts = jnp.asarray([0, 32, 8, 16], jnp.int32)
-    kv_lens = jnp.asarray([5, 0, 9, 40], jnp.int32)
-    o, new = mix(q, k, v, state, kv_lens, q_lens, q_starts, block_q=b)
-    want_o, want_s = _naive_recurrence(
-        q, k, v, state, kv_lens, q_lens, q_starts)
-    for rr in range(r):
-        span = slice(int(q_starts[rr]), int(q_starts[rr] + q_lens[rr]))
-        np.testing.assert_allclose(np.asarray(o)[:, span], want_o[:, span],
-                                   atol=1e-4, rtol=1e-4)
-    np.testing.assert_allclose(np.asarray(new), want_s, atol=1e-4, rtol=1e-4)
-    np.testing.assert_array_equal(np.asarray(new)[1], np.asarray(state)[1])
+    _mixed(mix, 0, (5, 0, 1, 16), (0, 32, 8, 16), (5, 0, 9, 40), 48, 16)
+
+
+@MIXES
+@pytest.mark.parametrize("n", range(1, SHORT + 1))
+def test_a_short_span_is_the_recurrence_a_token_at_a_time(mix, n):
+    """Spans of ``n`` <= SHORT tokens (the kernel's rank-1 form; the
+    twin's chunk form): in a reused slot (the state carried), from
+    position 0 (zero state whatever the slot held), beside a slot not
+    batched, at the launch's one block (``block_q`` 8) and beside a
+    longer block (16: two bodies): the float64 recurrence. The out
+    rows past a span that no later row writes are finite (the next
+    layer multiplies padding by 0)."""
+    q_lens, q_starts, kv_lens = (n, n, 0, 1), (0, 8, 0, 16), (40 + n, n, 0, 7)
+    for block_q in (8, 16):
+        o, _, _ = _mixed(mix, n, q_lens, q_starts, kv_lens, 40, block_q)
+        assert np.isfinite(o[:, :24]).all()
+
+
+@MIXES
+def test_lightning_decode_rows_before_and_after_a_chunk_row_at_block_q_256(
+        mix):
+    """The launch changes body (and fetch size) at both hand-overs: a
+    one-token row, a chunk row of 40 at a block of 256, a one-token
+    row, a tail of 5 from position 0, a one-token row; every short
+    row's block lies inside the chunk row's and is written after it."""
+    _mixed(mix, 3, (1, 40, 1, 5, 1), (0, 8, 48, 56, 64),
+           (150, 256, 90, 5, 230), 8 + 256, 256)
+
+
+def test_a_row_longer_than_short_is_span_update_bit_for_bit():
+    """Rows of 9 and 16 tokens beside short rows take the chunk form:
+    the launch returns the bits ``_span_update`` returns for the row's
+    block, head by head (the function the parent ran for every row)."""
+    q_lens, q_starts, kv_lens = (1, 9, 3, 16), (0, 8, 24, 32), (9, 30, 3, 16)
+    o, new, (q, k, v, state) = _mixed(
+        lightning_attention, 5, q_lens, q_starts, kv_lens, 48, 16)
+    slopes = decay_slopes(4)
+    chunk = jax.jit(_span_update, static_argnames="scale")
+    for r in (1, 3):
+        rows = slice(q_starts[r], q_starts[r] + 16)
+        for h in range(4):
+            want_o, want_s = chunk(
+                q[h, rows], k[h, rows], v[h, rows], state[r, h], slopes[h],
+                jnp.int32(q_lens[r]), kv_lens[r] == q_lens[r], scale=0.25)
+            n = q_lens[r]
+            np.testing.assert_array_equal(
+                o[h, rows][:n], np.asarray(want_o)[:n])
+            np.testing.assert_array_equal(new[r, h], np.asarray(want_s))
 
 
 def test_the_references_blocked_evaluation_is_its_one_shot_evaluation(
@@ -700,6 +767,29 @@ def test_rows_of_two_to_eight_tokens_keep_their_walk_bit_for_bit():
         np.testing.assert_array_equal(at16[:, lo:hi], at8[:, lo:hi])
 
 
+def _assembled(model):
+    """An engine of the tiny ``model`` kind (``sparse``: the lightning +
+    block-sparse twin; ``dense``; ``kda``) after one ``_assemble`` of
+    prompts of 1, 40, 1 and 9 tokens at a chunk of 16."""
+    if model == "sparse":
+        mdl, _, params = seeded(tiny_config())
+    else:
+        mdl = one_chip_model(
+            presets.tiny() if model == "dense" else presets.tiny(
+                presets.solar_open2(n_layers=4), n_layers=4,
+                layer_mixer=("kda", "kda", "kda", "attention"),
+                moe_layers=(0, 1, 2, 3), n_heads=8, n_kv_heads=2, vocab=96))
+        params = jax.tree.map(
+            lambda a: jnp.zeros(a.shape, a.dtype),
+            jax.eval_shape(mdl.init, jax.random.PRNGKey(0)))
+    eng = ServingEngine(mdl, params, ENGINE, use_pallas=False)
+    for i, p in enumerate(prompts_of((1, 40, 1, 9))):
+        eng.submit(Request(rid=i, prompt=p, max_new=2, arrival=0))
+    eng._admit()
+    eng._assemble()
+    return eng
+
+
 @pytest.mark.parametrize("model", ["sparse", "dense"])
 def test_assemble_counts_the_rows_of_the_selected_walk(model):
     """``selected_rows`` counts the rows ``_assemble`` batches on a
@@ -707,18 +797,22 @@ def test_assemble_counts_the_rows_of_the_selected_walk(model):
     token (a prompt's tail of one token is one too); a dense model
     reads 0 / 0. (Booked into ``EngineStats`` with the step's other
     counts: the engine test above reads them there.)"""
-    if model == "sparse":
-        mdl, _, params = seeded(tiny_config())
-    else:
-        mdl = one_chip_model(presets.tiny())
-        params = mdl.init(jax.random.PRNGKey(0))
-    eng = ServingEngine(mdl, params, ENGINE, use_pallas=False)
-    for i, p in enumerate(prompts_of((1, 40, 1, 9))):
-        eng.submit(Request(rid=i, prompt=p, max_new=2, arrival=0))
-    eng._admit()
-    eng._assemble()
+    eng = _assembled(model)
     assert (*eng._selected_work, eng._state_work[0] > 0) == (
         (4, 2, True) if model == "sparse" else (0, 0, False))
+
+
+@pytest.mark.parametrize("model,rows,short", [
+    ("sparse", 4, 2), ("dense", 0, 0), ("kda", 4, 0)])
+def test_assemble_counts_the_short_rows_of_the_lightning_launch(
+        model, rows, short):
+    """Of ``state_rows``, ``state_token_rows`` counts those a lightning
+    layer's launch runs in its rank-1 form (at most SHORT tokens: the
+    prompts of one token; the chunks of 16 and the 9-token prompt take
+    the chunk form); a dense model has no state rows, a kda model's are
+    another kernel's."""
+    eng = _assembled(model)
+    assert tuple(eng._state_work[2:]) == (rows, short)
 
 
 # --------------------------------------------- (e) compressed keys

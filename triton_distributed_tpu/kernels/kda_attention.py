@@ -79,6 +79,7 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 from triton_distributed_tpu.config import local_interpret
+from triton_distributed_tpu.kernels.lightning_attention import across
 from triton_distributed_tpu.kernels.ragged_paged_attention import active_rows
 from triton_distributed_tpu.lang.launch import shmem_call
 
@@ -89,17 +90,6 @@ HI = jax.lax.Precision.HIGHEST
 SHORT = 8
 #: tokens of one sub-chunk of the chunk form
 SUB = 16
-
-
-def _across(row, d):
-    """``row`` (1, D) over key channels as the matrix (D, D) whose
-    every COLUMN it is (row ``c`` holds ``row[c]`` in every lane): what
-    scales or fills the state's rows. One 128 x 128 transpose of the
-    row repeated down the sublanes; measured on a v5e (PR 41) against
-    the transpose of an (8, D) block and a lane broadcast of its column
-    a state vreg: 0.87 against 1.42 ms for 32 decode rows of 64
-    heads."""
-    return jnp.broadcast_to(row, (d, d)).T
 
 
 def query_block_tokens(q_lens, block_q: int):
@@ -213,7 +203,7 @@ def _kda_kernel(groups, hg, d, block_q, scale, order_ref, n_ref,
                 # S^T q = S'^T q + u (k . q)
                 obuf[hl, t:t + 1] = scale * (p[1:2] + u * jnp.sum(
                     k_t * q_t, axis=1, keepdims=True))
-                return s * _across(a_t, d) + _across(k_t, d) * u
+                return s * across(a_t, d) + across(k_t, d) * u
 
             s_out[0, hl] = token(0, jnp.where(first, 0.0, s_in[0, hl]))
             for t in range(1, SHORT):
@@ -274,7 +264,7 @@ def _kda_kernel(groups, hg, d, block_q, scale, order_ref, n_ref,
                     o = jnp.where(it == t, o_t, o)
                 obuf[hl, rows] = scale * (carried + o)
                 kw = k_ * jnp.exp(rest)
-                s_out[0, hl] = _across(jnp.exp(gam[SUB - 1:SUB]), d) * s \
+                s_out[0, hl] = across(jnp.exp(gam[SUB - 1:SUB]), d) * s \
                     + jax.lax.dot_general(
                         kw, bc * u, (((0,), (0,)), ((), ())),
                         precision=HI, preferred_element_type=f32)

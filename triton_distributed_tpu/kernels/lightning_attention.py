@@ -10,34 +10,39 @@ tokens, each row with a recurrent state of its own: per head ``h`` a
 Row ``r`` of the step holds the ``q_lens[r]`` tokens at packed offsets
 ``[q_starts[r], q_starts[r] + q_lens[r])``, which sit at sequence
 positions ``[kv_lens[r] - q_lens[r], kv_lens[r])`` (the serving step's
-packing contract, ``Transformer.serving_step``). The span is computed
-in the CHUNK FORM, exact for any span length: with ``n = q_lens[r]``,
-``t, j`` local indices and ``S`` the slot's state before the span,
+packing contract, ``Transformer.serving_step``). A span longer than
+``SHORT`` tokens is computed in the CHUNK FORM, exact for any span
+length: with ``n = q_lens[r]``, ``t, j`` local indices and ``S`` the
+slot's state before the span,
 
     o_t  = (q_t / sqrt D) ( lam^(t+1) S + sum_{j <= t} lam^(t-j) k_j^T v_j )
     S'   = lam^n S + sum_j lam^(n-1-j) k_j^T v_j
 
-so a decode row (``n = 1``) is the rank-1 update and a prefill chunk
-one ``(n x n)`` decay-masked product plus the carried state. A span
-that starts at position 0 starts from ``S = 0`` whatever the slot held
-(a slot reused by another request needs no reset); a row with
-``q_lens == 0`` is not visited and its state stays as it is.
+a prefill chunk being one ``(n x n)`` decay-masked product plus the
+carried state. A span of at most ``SHORT`` tokens (a decode row, a
+prompt's tail) is the RANK-1 FORM, the recurrence itself a token at a
+time on the vector unit: ``k_t`` down the state's rows times ``v_t``
+along them, ``o_t`` the sum down the rows of ``q_t``-scaled ``S_t``; no
+product, no decay mask. The twin evaluates the chunk form for every
+span: two evaluations of one recurrence. A span that starts at position
+0 starts from ``S = 0`` whatever the slot held (a slot reused by another
+request needs no reset); a row with ``q_lens == 0`` is not visited and
+its state stays as it is.
 
 ``lightning_attention`` is the Pallas kernel: grid over the step's
 ACTIVE rows (a compacted list, so an inactive slot costs no state
 traffic), the state block of the visited slot pipelined in and out by
 its BlockSpec and aliased in place, the row's q/k/v block fetched by
-double-buffered DMAs one row ahead, every product in float32.
+double-buffered DMAs one row ahead, everything in float32.
 ``lightning_attention_xla`` is its twin (``use_pallas=False`` and the
-tests): the same lines as gathers and einsums.
+tests): the chunk form's lines as gathers and einsums.
 
 Both take q, k, v HEAD-MAJOR, ``(H, T, D)`` float32, and return
 ``(o (H, T, D) float32, state')``. Rows of ``o`` outside every span
 hold garbage, as the ragged attention kernel's do: a row shorter than
 its block writes the whole block, and the ascending order of the visits
 lets the next row write over it. The block is ``block_q`` tokens, or
-``SHORT`` for a row of at most that many (a decode row beside a prefill
-chunk does not pay the chunk's products).
+``SHORT`` for a row of at most that many.
 """
 
 from __future__ import annotations
@@ -96,10 +101,40 @@ def _span_update(q, k, v, s_prev, slope, n, first, scale):
 
 
 #: a row of at most this many tokens (a decode row, a prompt's tail)
-#: is computed, fetched and written as a block of this many, whatever
-#: the step's ``block_q``: beside a prefill chunk every row would
-#: otherwise pay the chunk's (block_q x block_q) products
+#: runs the rank-1 form and is fetched and written as a block of this
+#: many, whatever the step's ``block_q``
 SHORT = 8
+#: heads of a short row whose first tokens are computed side by side
+#: (one straight-line body, so one head's loads and transposes run under
+#: another's arithmetic; traced once): measured on a v5e at the decode
+#: shape of minicpmsala9b.docbatch, PR 47 (``CHANGES.md`` has the table)
+SHORT_HEADS = 4
+
+
+def short_row(n):
+    """Whether a row of ``n`` tokens runs the rank-1 form (and moves a
+    block of ``SHORT`` tokens); host or device values."""
+    return n <= SHORT
+
+
+def across(row, d):
+    """``row`` (1, D) over key channels as the matrix (D, D) whose
+    every COLUMN it is (row ``c`` holds ``row[c]`` in every lane): what
+    scales or fills the state's rows. One 128 x 128 transpose of the
+    row repeated down the sublanes; measured on a v5e (PR 41) against
+    the transpose of an (8, D) block and a lane broadcast of its column
+    a state vreg: 0.87 against 1.42 ms for 32 decode rows of 64
+    heads."""
+    return jnp.broadcast_to(row, (d, d)).T
+
+
+def _token_update(q_t, k_t, v_t, s, lam):
+    """One token of one head, the recurrence itself: ``q_t`` (scaled),
+    ``k_t``, ``v_t``, ``lam`` (1, D), ``s`` (D, D). Returns ``(o_t (1,
+    D), s_t)``."""
+    d = s.shape[0]
+    s = lam * s + across(k_t, d) * v_t
+    return jnp.sum(across(q_t, d) * s, axis=0, keepdims=True), s
 
 
 def query_block_tokens(q_lens, block_q: int):
@@ -109,7 +144,7 @@ def query_block_tokens(q_lens, block_q: int):
     row outside the batch (never visited). ``q_starts[r] + this <= T``
     is all the launch asks of the packed width."""
     q_lens = np.asarray(q_lens)
-    block = np.where(q_lens <= SHORT, min(SHORT, block_q), block_q)
+    block = np.where(short_row(q_lens), min(SHORT, block_q), block_q)
     return np.where(q_lens > 0, block, 0)
 
 
@@ -124,7 +159,7 @@ def _lightning_kernel(heads, d, block_q, scale, order_ref, n_ref,
     def by_size(step, fn):
         """``fn(b)`` with ``b`` the static block of row ``order[step]``:
         SHORT if it holds at most SHORT tokens, else ``block_q``."""
-        short = q_lens_ref[order_ref[step]] <= SHORT
+        short = short_row(q_lens_ref[order_ref[step]])
         for b in sizes:
             if len(sizes) == 1:
                 fn(b)
@@ -173,19 +208,72 @@ def _lightning_kernel(heads, d, block_q, scale, order_ref, n_ref,
         first = kv_lens_ref[r] - n == 0
         start = pl.multiple_of(q_starts_ref[r], 8)
 
+        def chunk_head(b, h, _):
+            o, s_new = _span_update(
+                qbuf[slot, h, :b], kbuf[slot, h, :b], vbuf[slot, h, :b],
+                s_in[0, h], slopes_ref[h], n, first, scale)
+            obuf[h, :b] = o
+            s_out[0, h] = s_new
+            return 0
+
+        def short_heads(b):
+            """The rank-1 form: every head's first token, ``SHORT_HEADS``
+            heads side by side; then, for the rare row that has more,
+            every head's other tokens one at a time."""
+            t8 = jax.lax.broadcasted_iota(jnp.int32, (b, 1), 0)
+
+            def lam_of(h):
+                return jnp.exp(jnp.full((1, d), -slopes_ref[h], jnp.float32))
+
+            def first_token(h):
+                o, s = _token_update(
+                    qbuf[slot, h, 0:1] * scale, kbuf[slot, h, 0:1],
+                    vbuf[slot, h, 0:1],
+                    jnp.where(first, 0.0, s_in[0, h]), lam_of(h))
+                obuf[h, :b] = jnp.where(t8 == 0, o, 0.0)
+                s_out[0, h] = s
+
+            side = math.gcd(heads, SHORT_HEADS)
+
+            def group(g, _):
+                jax.lax.fori_loop(
+                    0, side, lambda x, _: first_token(g * side + x), None,
+                    unroll=True)
+
+            jax.lax.fori_loop(0, heads // side, group, None)
+
+            @pl.when(n > 1)
+            def _tail():
+                def head(h, _):
+                    qb, kb, vb = (buf[slot, h, :b]
+                                  for buf in (qbuf, kbuf, vbuf))
+                    lam = lam_of(h)
+
+                    def token(t, carry):
+                        s, o = carry
+                        at = t8 == t
+
+                        def row(x):
+                            return jnp.sum(jnp.where(at, x, 0.0), axis=0,
+                                           keepdims=True)
+
+                        o_t, s = _token_update(
+                            row(qb) * scale, row(kb), row(vb), s, lam)
+                        return s, jnp.where(at, o_t, o)
+
+                    s_out[0, h], obuf[h, :b] = jax.lax.fori_loop(
+                        1, n, token, (s_out[0, h], obuf[h, :b]))
+
+                jax.lax.fori_loop(0, heads, head, None)
+
         def span(b):
             for cp in fetch(i, slot, b):
                 cp.wait()
-
-            def head(h, _):
-                o, s_new = _span_update(
-                    qbuf[slot, h, :b], kbuf[slot, h, :b], vbuf[slot, h, :b],
-                    s_in[0, h], slopes_ref[h], n, first, scale)
-                obuf[h, :b] = o
-                s_out[0, h] = s_new
-                return 0
-
-            jax.lax.fori_loop(0, heads, head, 0)
+            if b <= SHORT:
+                short_heads(b)
+            else:
+                jax.lax.fori_loop(
+                    0, heads, functools.partial(chunk_head, b), 0)
             out = pltpu.make_async_copy(
                 obuf.at[:, pl.ds(0, b)], o_hbm.at[:, pl.ds(start, b)],
                 sem_o.at[0])

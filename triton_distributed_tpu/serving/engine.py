@@ -332,6 +332,11 @@ class EngineStats:
     # (kernels/ragged_paged_attention.py, the selected walk's notes)
     selected_rows: int = 0
     selected_token_rows: int = 0
+    # of ``state_rows``, those a LIGHTNING layer's launch runs in the
+    # rank-1 form, a token at a time (every decode row, a prompt's tail
+    # of at most SHORT tokens: kernels/lightning_attention.py); the
+    # rest take its chunk form (0 for a model without such layers)
+    state_token_rows: int = 0
     # the work of a model with gated delta-rule (kda) layers (0 without
     # them), counted beside ``state_rows``: rows batched through ONE
     # layer's ``kda_attention`` launch, and those of them with more
@@ -869,8 +874,9 @@ class ServingEngine:
         # step in flight belongs there, -1 where the host's own stands
         self._token_src = None
         self._pages_walked = [0, 0]     # likewise: [global, window]
-        # likewise: [selected pages, sparse rows, state rows]
-        self._state_work = [0, 0, 0]
+        # likewise: [selected pages, sparse rows, state rows, of them
+        # short rows of a lightning launch]
+        self._state_work = [0, 0, 0, 0]
         # likewise: [rows through the selected walk, of them one-token]
         self._selected_work = [0, 0]
         self._latent_work = [0, 0]      # likewise: [pages fetched, rows]
@@ -1343,6 +1349,9 @@ class ServingEngine:
                 self.stats.shared_prefix_rows += 1
 
     def _assemble(self):
+        from triton_distributed_tpu.kernels.lightning_attention import (
+            short_row,
+        )
         from triton_distributed_tpu.kernels.ragged_paged_attention import (
             LATENT_TQ,
             causal_topologies,
@@ -1363,7 +1372,7 @@ class ServingEngine:
         next_start = 0
         self._append_runs = 0
         self._pages_walked = [0, 0]     # [global, window], one layer each
-        self._state_work = [0, 0, 0]
+        self._state_work = [0, 0, 0, 0]
         self._selected_work = [0, 0]    # [rows, rows of one token]
         self._latent_work = [0, 0]      # [pages fetched, rows]
         self._kda_work = [0, 0]         # [rows, rows of several tokens]
@@ -1417,6 +1426,8 @@ class ServingEngine:
                     self._selected_work[1] += take == 1
                 if mc.recurrent_layers:
                     self._state_work[2] += 1
+                    if mc.lightning_layers:
+                        self._state_work[3] += short_row(take)
                 if mc.kda_layers:
                     self._kda_work[0] += 1
                     self._kda_work[1] += take > 1
@@ -1692,6 +1703,7 @@ class ServingEngine:
                 "selected_pages_walked": self._state_work[0],
                 "sparse_rows": self._state_work[1],
                 "state_rows": self._state_work[2],
+                "state_token_rows": self._state_work[3],
                 "selected_rows": self._selected_work[0],
                 "selected_token_rows": self._selected_work[1],
                 "kda_rows": self._kda_work[0],
