@@ -1,11 +1,28 @@
-"""Driver benchmark: fused AG-GEMM throughput on the north-star TP shape.
+"""Kernel-alone timers, the lint gate and the chaos replay.
 
-Measures the flagship overlap op (BASELINE.md north-star: fused AG-GEMM on
-Llama-7B TP shapes, reference tutorial 07 / test_ag_gemm.py) on whatever
-devices are present — the one real TPU chip under the driver, or the
-virtual CPU mesh during development.
+NOT the benchmark. Serving speed — and training speed, when it gets a
+cell — is ``benchmark/run.py``'s: ``BENCHMARK.json`` declares its cells
+and metrics, the driver runs it on the chip, and every number in
+``PERF.md`` and ``PERF_LEDGER.jsonl`` comes from it. This file keeps
+what nothing there duplicates:
 
-Methodology (the round-1 numbers were dispatch-overhead artifacts):
+* **The in-jit runners** (``bench_loop``, ``bench_paired``,
+  ``_make_donating_runner``) and the timers of the overlap kernels
+  ALONE: fused AG-GEMM on the Llama-7B TP shape (in ``main``), GEMM-RS,
+  the quantized wire rings, the schedule search, the grouped GEMM, the
+  MoE all-to-all transport and flash-decode. No ledger line rests on
+  them yet (no cell runs more than one chip); they wait for the first
+  4-chip cell.
+* ``--lint``: shmemlint, the Mosaic pre-flight, the degradation-target
+  gates and servlint before any timing (exit 2 on errors;
+  ``docs/ANALYSIS.md``, ``docs/LINT.md``).
+* ``--dryrun [--faults SPEC]``: the hardware-free replay of a nightly
+  chaos line through ``ServingEngine`` at interpreter-tiny shapes
+  (``docs/ROBUSTNESS.md`` §1). It prints COUNTS — requests completed,
+  evictions, degradations, watchdog trips — and no rate.
+
+Methodology of the timers (the round-1 numbers were dispatch-overhead
+artifacts):
 
 * Every timing is an **in-jit ``lax.fori_loop``** whose carry chains each
   iteration's output back into the next iteration's input, timed as the
@@ -29,12 +46,13 @@ Prints ONE JSON line on stdout:
 unoverlapped baseline (all_gather → dot, ≡ the reference's torch_ag_gemm
 cuBLAS+NCCL baseline, test_ag_gemm.py) measured the same way on the same
 hardware; the baseline's own TFLOPs ride along so both sides are visible.
-Secondary metrics (gemm_rs, grouped-GEMM MFU, MoE a2a transport,
-flash-decode HBM%) go to stderr, one JSON line each.
+The other timers (gemm_rs, wire rings, schedule search, grouped-GEMM MFU,
+MoE a2a transport, flash-decode HBM%) go to stderr, one JSON line each.
 """
 
 from __future__ import annotations
 
+import contextlib
 import functools
 import json
 import os
@@ -43,9 +61,9 @@ import time
 
 # CPU dev-box runs (JAX_PLATFORMS=cpu) get the same virtual 8-device
 # mesh the test harness uses (tests/conftest.py): the multi-rank rows —
-# the 2×(n/2) disaggregated serving split, the DCN rails, the ring
-# engines — then exercise their real cross-device paths instead of
-# degenerating to n=1. Real-TPU runs are untouched.
+# the DCN rails, the ring engines — then exercise their real
+# cross-device paths instead of degenerating to n=1. Real-TPU runs are
+# untouched.
 if os.environ.get("JAX_PLATFORMS") == "cpu":
     _flags = os.environ.get("XLA_FLAGS", "")
     if "--xla_force_host_platform_device_count" not in _flags:
@@ -245,7 +263,8 @@ def _parse_args(argv=None):
     import argparse
 
     ap = argparse.ArgumentParser(
-        description="triton_distributed_tpu driver benchmark"
+        description="triton_distributed_tpu kernel-alone timers, lint gate "
+        "and chaos replay (serving speed: benchmark/run.py)"
     )
     ap.add_argument(
         "--lint", action="store_true",
@@ -268,12 +287,13 @@ def _parse_args(argv=None):
     )
     ap.add_argument(
         "--dryrun", action="store_true",
-        help="hardware-free engine exercise: run ONLY the "
-        "serving_continuous bench at interpreter-tiny shapes (whatever "
-        "the platform) and exit — with --faults, the fault plan is "
-        "active inside the ragged kernel and the scheduler's "
-        "eviction/degradation behavior runs under it (the robustness "
-        "follow-on: chaos-line replay without a TPU)",
+        help="hardware-free chaos replay: serve a seeded trace through "
+        "ServingEngine at interpreter-tiny shapes (whatever the "
+        "platform), print its counts and exit — with --faults, the "
+        "fault plan is active inside the ragged kernel and the "
+        "scheduler's eviction/degradation behavior runs under it, a "
+        "watchdog armed round the run (TDTPU_BENCH_WATCHDOG seconds, "
+        "default 10)",
     )
     ap.add_argument(
         "--faults", default=None, metavar="SPEC",
@@ -285,47 +305,9 @@ def _parse_args(argv=None):
     )
     ap.add_argument(
         "--n-layers", type=int, default=None, metavar="L",
-        help="serving benches: override the model depth (default 1). "
-        "The serving-state donation path only shows its cost at depth "
-        "> 1 — per-layer pool bytes are reported so the sweep is "
-        "explainable (ISSUE-12 satellite / ISSUE-6 follow-on)",
-    )
-    ap.add_argument(
-        "--tree", action="store_true",
-        help="serving_speculative: the tree-speculation paired row — "
-        "spec_tree verify trees (TreeDrafter sibling branches) vs "
-        "linear draft-k on a branchy SAMPLED motif trace (token "
-        "mismatches must be 0 and accepted/step strictly above "
-        "linear), plus the in-batch shared-prefix dedup row "
-        "(deduped pages > 0, token-exact; ISSUE-18)",
-    )
-    ap.add_argument(
-        "--spec-k", type=int, default=None, metavar="K",
-        help="serving_fleet: run SPECULATIVE replicas (draft-k K, "
-        "ngram drafter) against a non-speculative fleet on the "
-        "IDENTICAL trace — per-replica accepted-tokens/step and the "
-        "goodput ratio are reported (ISSUE-13 satellite)",
-    )
-    ap.add_argument(
-        "scenario", nargs="?", default=None,
-        help="run ONLY this named scenario (currently: serving_fleet "
-        "— the multi-replica router bench, --spec-k K for speculative "
-        "replicas — serving_speculative — the draft-k speculative "
-        "engine vs the plain engine, colocated AND disaggregated — or "
-        "serving_elastic — autoscale grow from a reserve mesh, a "
-        "mid-trace drain with live KV-page migration — or "
-        "serving_multitenant — priority preemption, deadline routing "
-        "and brownout shedding under a 4x batch flood — or "
-        "serving_longcontext — context-parallel decode over the "
-        "cp-sharded page pool: a request k× one pool shard served "
-        "token-exact vs the single-slice oracle, the short-request "
-        "goodput tax, and the priced long-context placement verdict "
-        "(ISSUE-20); all compose "
-        "with --dryrun and --faults, e.g. the ISSUE-16 acceptance "
-        "line 'serving_multitenant --dryrun --faults \"seed=1; "
-        "ReplicaDeath(replica=1, step=8)\"' — or train_step — the "
-        "dp×tp×cp train step on the int8 EF gradient ring vs the "
-        "single-device reference and the exact psum twin, ISSUE-14)",
+        help="--dryrun: override the model depth (default 1). The "
+        "serving-state donation path is only exercised at depth > 1; "
+        "per-layer pool bytes are reported",
     )
     return ap.parse_args(argv)
 
@@ -565,67 +547,14 @@ def main(argv=None) -> None:
             file=sys.stderr, flush=True,
         )
 
-    if args.scenario is not None:
-        from triton_distributed_tpu.tune.perf_model import detect_spec
-
-        scenarios = {
-            "serving_fleet": _bench_serving_fleet,
-            "serving_speculative": _bench_serving_speculative,
-            "serving_elastic": _bench_serving_elastic,
-            "serving_multitenant": _bench_serving_multitenant,
-            "serving_longcontext": _bench_serving_longcontext,
-            "train_step": _bench_train_step,
-        }
-        bench_fn = scenarios.get(args.scenario)
-        if bench_fn is None:
-            print(json.dumps({"error":
-                              f"unknown scenario {args.scenario!r}"}),
-                  file=sys.stderr, flush=True)
-            sys.exit(2)
-        devs = jax.devices()
-        mesh = Mesh(np.asarray(devs), ("x",))
-        on_tpu = jax.default_backend() == "tpu"
-        if not (on_tpu or args.dryrun):
-            # the full-size scenario needs the chip; the toy shapes are
-            # an explicit request, never a silent substitute
-            raise RuntimeError(
-                f"scenario {args.scenario!r} found no TPU "
-                f"(backend={jax.default_backend()!r}): pass --dryrun "
-                "for the CPU-sized shapes"
-            )
-        kw = {}
-        if args.scenario == "serving_fleet" and args.spec_k:
-            kw["spec_k"] = args.spec_k
-        if args.scenario == "serving_speculative" and args.tree:
-            kw["tree"] = True
-        out = bench_fn(
-            mesh, len(devs), on_tpu, detect_spec(),
-            tiny=args.dryrun, **kw,
-        )
-        out["faults"] = args.faults
-        print(json.dumps(out), flush=True)
-        return
-
     if args.dryrun:
-        from triton_distributed_tpu.tune.perf_model import detect_spec
-
         devs = jax.devices()
-        mesh = Mesh(np.asarray(devs), ("x",))
-        on_tpu = jax.default_backend() == "tpu"
         out = _bench_serving_continuous(
-            mesh, len(devs), on_tpu, detect_spec(), tiny=True,
+            Mesh(np.asarray(devs), ("x",)), len(devs),
             n_layers=args.n_layers,
         )
         out["faults"] = args.faults
         print(json.dumps(out), flush=True)
-        # the disaggregated twin at the same interpreter shapes: the
-        # split-role engine, the DCN wire rails and the perf-model
-        # placement gate all run hardware-free too
-        out2 = _bench_serving_disaggregated(
-            mesh, len(devs), on_tpu, detect_spec(), tiny=True,
-        )
-        out2["faults"] = args.faults
-        print(json.dumps(out2), flush=True)
         return
 
     from triton_distributed_tpu.kernels.ag_gemm import (
@@ -787,9 +716,7 @@ def main(argv=None) -> None:
 
     failed = []
     for fn in (_bench_gemm_rs, _bench_wire_rings, _bench_schedule_search,
-               _bench_group_gemm,
-               _bench_moe_a2a, _bench_flash_decode,
-               _bench_serving_continuous, _bench_serving_disaggregated):
+               _bench_group_gemm, _bench_moe_a2a, _bench_flash_decode):
         try:
             print(json.dumps(fn(mesh, n, on_tpu, spec)), file=sys.stderr, flush=True)
         except Exception as e:
@@ -1293,106 +1220,91 @@ def _bench_moe_a2a(mesh, n, on_tpu, spec):
     }
 
 
-def _serving_continuous_config(n, on_tpu, tiny=False, n_layers=None):
-    """(model config, engine config, trace knobs) for the continuous
-    bench. TPU: the serving headline model (hidden 7168, EP-MoE, every
-    int8 knob) under the ISSUE-6 traffic shape — B≫128 requests,
-    lengths ~U[S/8, 3S/4] against S=2048. Off-TPU (and --dryrun):
-    interpreter-sized shapes, same shape of traffic. ``n_layers``
-    overrides the model depth (the ``--n-layers`` donation sweep —
-    depth > 1 exercises the per-layer serving-state donation path the
-    default depth-1 bench never touches)."""
-    import jax.numpy as jnp
+def _serving_continuous_config(n, n_layers=None):
+    """(model config, engine config, trace knobs) of the ``--dryrun``
+    replay: interpreter-sized shapes under the ISSUE-6 shape of traffic
+    (lengths ~U[S/8, 3S/4] against S=64). ``n_layers`` overrides the
+    model depth (``--n-layers``: depth > 1 exercises the per-layer
+    serving-state donation path the default depth 1 never touches)."""
+    from dataclasses import replace
 
     from triton_distributed_tpu.models import TransformerConfig
     from triton_distributed_tpu.serving import EngineConfig
 
     # KV heads shard over tp in the serving state — keep divisible
     n_kv = n if n > 4 else 4
-    if on_tpu and not tiny:
-        s_cap = 2048
-        cfg = TransformerConfig(
-            vocab=4096, n_layers=1, hidden=7168, ffn=2048, n_heads=7 * n_kv,
-            n_kv_heads=n_kv, head_dim=128, moe="ep", moe_layers=(0,),
-            num_experts=max(8, n), topk=8, param_dtype=jnp.bfloat16,
-            moe_weight_quant="int8", moe_act_quant="int8", kv_quant="int8",
-            dense_weight_quant="int8", dense_act_quant="int8",
-        )
-        ecfg = EngineConfig(
-            slots=160, token_budget=512, chunk=256, page=1024,
-            npages=352, max_steps=200_000,
-        )
-        trace_kw = dict(
-            n_requests=256, mean_interarrival=0.25,
-            len_lo=s_cap // 8, len_hi=3 * s_cap // 4,
-            max_new_lo=16, max_new_hi=64, vocab=4096,
-        )
-    else:
-        s_cap = 64
-        cfg = TransformerConfig(
-            vocab=256, n_layers=1, hidden=128, ffn=128, n_heads=2 * n_kv,
-            n_kv_heads=n_kv, head_dim=32, moe="ep", moe_layers=(0,),
-            num_experts=max(4, n), topk=2, param_dtype=jnp.bfloat16,
-            dtype=jnp.float32,
-        )
-        ecfg = EngineConfig(
-            slots=6, token_budget=48, chunk=16, page=8,
-            npages=40, max_steps=5_000,
-        )
-        trace_kw = dict(
-            n_requests=24, mean_interarrival=0.6,
-            len_lo=s_cap // 8, len_hi=3 * s_cap // 4,
-            max_new_lo=3, max_new_hi=8, vocab=256,
-        )
+    s_cap = 64
+    cfg = TransformerConfig(
+        vocab=256, n_layers=1, hidden=128, ffn=128, n_heads=2 * n_kv,
+        n_kv_heads=n_kv, head_dim=32, moe="ep", moe_layers=(0,),
+        num_experts=max(4, n), topk=2, param_dtype=jnp.bfloat16,
+        dtype=jnp.float32,
+    )
+    ecfg = EngineConfig(
+        slots=6, token_budget=48, chunk=16, page=8,
+        npages=40, max_steps=5_000,
+    )
+    trace_kw = dict(
+        n_requests=24, mean_interarrival=0.6,
+        len_lo=s_cap // 8, len_hi=3 * s_cap // 4,
+        max_new_lo=3, max_new_hi=8, vocab=256,
+    )
     if n_layers is not None and n_layers != cfg.n_layers:
-        from dataclasses import replace as _rep2
-
         # keep the MoE layer set valid at the new depth (drop layers
         # past it; added depth is dense — the donation path under test
         # is per-layer KV state, not expert count)
         moe_layers = tuple(l for l in cfg.moe_layers if l < n_layers)
-        cfg = _rep2(cfg, n_layers=int(n_layers), moe_layers=moe_layers)
-    return cfg, ecfg, trace_kw, s_cap
+        cfg = replace(cfg, n_layers=int(n_layers), moe_layers=moe_layers)
+    return cfg, ecfg, trace_kw
 
 
-def _bench_serving_continuous(mesh, n, on_tpu, spec, tiny=False,
-                              n_layers=None):
-    """CONTINUOUS-BATCHING serving on the ragged paged-attention kernel
-    (ISSUE 6 tentpole acceptance): a seeded Poisson arrival trace with
-    ~U[S/8, 3S/4] prompt lengths drives the ServingEngine — admission/
-    eviction over the page pool, chunked prefill interleaved into
-    decode batches, one ragged mixed kernel launch per step. Reports
-    sustained tok/s, p50/p99 step time and GOODPUT (completed requests'
-    generated tokens per wall second)."""
-    import jax
-
+def _bench_serving_continuous(mesh, n, n_layers=None):
+    """The ``--dryrun`` replay: a seeded Poisson arrival trace drives
+    the ServingEngine at interpreter-tiny shapes — admission/eviction
+    over the page pool, chunked prefill interleaved into decode
+    batches, one ragged mixed kernel launch per step — under whatever
+    fault plan ``--faults`` activated. Reports what the replay is for:
+    requests completed, evictions, deferrals, degradations and the
+    failures behind them, watchdog trips. No rate: serving speed is
+    ``benchmark/run.py``'s."""
     from triton_distributed_tpu.models import Transformer
+    from triton_distributed_tpu.runtime import faults, watchdog
     from triton_distributed_tpu.serving import ServingEngine, poisson_trace
-    from triton_distributed_tpu.tune.perf_model import (
-        ragged_serving_step_ms,
-    )
 
-    cfg, ecfg, trace_kw, _ = _serving_continuous_config(
-        n, on_tpu, tiny, n_layers=n_layers
-    )
+    cfg, ecfg, trace_kw = _serving_continuous_config(n, n_layers=n_layers)
     model = Transformer(cfg, mesh, tp_axis="x")
     params = jax.tree.map(
-        lambda x, s: jax.device_put(x, s),
-        model.init(jax.random.PRNGKey(7)), model.shardings(),
+        jax.device_put, model.init(jax.random.PRNGKey(7)), model.shardings()
     )
     params = model.quantize_moe_weights(params)
     params = model.quantize_dense_weights(params)
 
-    def fresh_trace():
-        return poisson_trace(seed=11, **trace_kw)
-
-    # ---- continuous engine (run twice; first run pays the compiles)
-    for _warm in (False, True):
-        trace = fresh_trace()
-        eng = ServingEngine(model, params, ecfg)
-        stats = eng.run(trace)
-    assert stats.completed == trace_kw["n_requests"], (
-        stats.completed, stats.deferrals)
+    # under --faults, arm the collective watchdog around the run (the
+    # serving_step host heartbeat is live) so a stalled step TRIPS — the
+    # trip feeds the health ledger and releases the stall gates —
+    # instead of wedging the replay. Trips are reported, not fatal: the
+    # run's recovery is the thing under test. Generous default: the run
+    # pays its jit compiles, only a real stall should out-wait it
+    guard = contextlib.nullcontext()
+    if faults.active_plan() is not None:
+        guard = watchdog.collective_watchdog(
+            deadline=float(os.environ.get("TDTPU_BENCH_WATCHDOG", "10.0"))
+        )
+    wd_trips = []
+    eng = ServingEngine(model, params, ecfg)
+    try:
+        with guard:
+            eng.run(poisson_trace(seed=11, **trace_kw))
+    except watchdog.WatchdogTimeout as e:
+        wd_trips.append(str(e).splitlines()[0])
+    finally:
+        watchdog.clear_trip()
+    stats = eng.stats
+    if stats.completed != trace_kw["n_requests"] and not wd_trips:
+        raise RuntimeError(
+            f"dryrun lost requests: {stats.completed} of "
+            f"{trace_kw['n_requests']} completed, {stats.deferrals} deferrals"
+        )
     # per-layer KV pool footprint: at depth > 1 the engine carries one
     # (k_pool, v_pool) pair PER LAYER, all donated through the jitted
     # step — the `--n-layers` sweep's reported quantity
@@ -1403,1588 +1315,30 @@ def _bench_serving_continuous(mesh, n, on_tpu, spec, tiny=False,
     # their own buffers (the step jit donates the whole ServingState —
     # a buffer shared across layers would alias the in-place appends).
     # Verified at ANY depth, but only depth > 1 exercises it.
-    _leaves = jax.tree.leaves(eng.state.layers)
-    _ptrs = {
-        x.addressable_shards[0].data.unsafe_buffer_pointer()
-        for x in _leaves
+    leaves = jax.tree.leaves(eng.state.layers)
+    ptrs = {
+        x.addressable_shards[0].data.unsafe_buffer_pointer() for x in leaves
     }
-    donation_distinct = len(_ptrs) == len(_leaves)
-
-    # ---- traffic-tuned grid schedules: the run's shape ledger feeds a
-    # dryrun schedule search per hot key (oracle-gated, perf-model
-    # priced); winners persist in the store and the REBUILT engine
-    # resolves them with zero search cost on its build path
-    from triton_distributed_tpu.tune import traffic as traffic_lib
-
-    wire_key = "int8" if cfg.kv_quant is not None else None
-    tune_reports = traffic_lib.retune_hot_shapes(
-        stats, mesh_shape=(model.tp,), wire=wire_key, dryrun=True,
-    )
-    tuned_vs_default = [
-        {
-            "key": str(rep.get("key", "")),
-            "default_ms": round(rep["default_ms"], 4),
-            "tuned_ms": round(rep["winner_ms"], 4),
-            "winner": rep["winner"],
-            "cached": rep["cached"],
-        }
-        for rep in tune_reports if "error" not in rep
-    ]
-    eng_tuned = ServingEngine(model, params, ecfg)
-    resolved_schedule = eng_tuned.grid_schedule.to_dict()
-
-    # model term: a representative steady step (every slot decoding at
-    # the mean trace length)
-    mean_len = (trace_kw["len_lo"] + trace_kw["len_hi"]) // 2
-    page = ecfg.page
-    from triton_distributed_tpu.tune.perf_model import (
-        measured_page_issue_ms,
-    )
-
-    model_ms = ragged_serving_step_ms(
-        [mean_len] * ecfg.slots, [1] * ecfg.slots, page=page,
-        hkv=cfg.n_kv_heads // n, g=cfg.n_heads // cfg.n_kv_heads,
-        d=cfg.head_dim, hidden=cfg.hidden, n_layers=cfg.n_layers,
-        spec=spec, quant=cfg.kv_quant is not None,
-        # the backend's MEASURED per-page issue cost (ROADMAP
-        # follow-on): off-TPU the interpreter pays milliseconds per
-        # page, not the v5e's 0.17 µs — the model term should track
-        # the machine the measurement next to it ran on
-        issue_ms=measured_page_issue_ms(),
-    )
     return {
         "metric": "serving_continuous",
-        "value": round(stats.goodput_tok_per_s, 1),
-        "unit": "tok/s goodput",
-        "sustained_tok_per_s": round(stats.sustained_tok_per_s, 1),
-        "p50_step_ms": round(stats.p50_step_ms, 2),
-        "p99_step_ms": round(stats.p99_step_ms, 2),
-        "steps": len(stats.step_times),
+        "requests": trace_kw["n_requests"],
         "completed": stats.completed,
+        "steps": len(stats.step_times),
         "evictions": stats.evictions,
         "deferrals": stats.deferrals,
         "degraded_to_xla": stats.degraded,
-        "model_steady_step_ms": round(model_ms, 3),
+        "repromotions": stats.repromotions,
+        "failures": stats.failures,
+        "watchdog_trips": wd_trips,
         "n_layers": cfg.n_layers,
         "per_layer_pool_bytes": per_layer_pool_bytes,
         "pool_bytes_total": per_layer_pool_bytes * cfg.n_layers,
-        "donation_distinct_buffers": donation_distinct,
-        "tuned_vs_default": tuned_vs_default,
-        "tuned_strictly_better": sum(
-            1 for r in tuned_vs_default
-            if r["tuned_ms"] < r["default_ms"]
-        ),
-        "resolved_grid_schedule": resolved_schedule,
+        "donation_distinct_buffers": len(ptrs) == len(leaves),
         "config": (
             f"n={n} slots={ecfg.slots} budget={ecfg.token_budget} "
-            f"chunk={ecfg.chunk} page={page} npages={ecfg.npages} "
-            f"requests={trace_kw['n_requests']} "
+            f"chunk={ecfg.chunk} page={ecfg.page} npages={ecfg.npages} "
             f"lens~U[{trace_kw['len_lo']},{trace_kw['len_hi']}] "
-            f"poisson(seed=11) hidden={cfg.hidden} "
-            f"kvq={cfg.kv_quant} "
-            + ("tiny-dryrun" if tiny or not on_tpu else "headline")
-        ),
-    }
-
-
-def _bench_serving_longcontext(mesh, n, on_tpu, spec, tiny=False):
-    """LONG-CONTEXT serving (ISSUE 20 tentpole acceptance): a tp×cp
-    mesh replica whose page-table walk is context-parallel — each cp
-    rank walks only its own pool shard and the per-rank (out, lse)
-    partials merge through the LSE-combine contract — serves a request
-    whose KV need is a MULTIPLE of one pool shard (inadmissible on any
-    cp-free replica of the same per-slice pool), token-exact against a
-    single-slice oracle engine given one pool of the combined size.
-    The paired row: (a) the capacity ratio the cp axis bought with
-    ``token_mismatches == 0``, (b) short-request goodput on the SAME
-    cp engine vs the cp-free engine (the hop tax short traffic pays),
-    (c) the PRICED placement verdict — what the fleet router tells a
-    cp-free replica refusing the long request, and the modeled
-    cp-vs-flat step cost crossover behind it."""
-    import jax
-
-    from triton_distributed_tpu.models import Transformer, TransformerConfig
-    from triton_distributed_tpu.serving import (
-        EngineConfig,
-        Request,
-        ServingEngine,
-        poisson_trace,
-    )
-    from triton_distributed_tpu.tune.perf_model import (
-        cp_decode_step_ms,
-        ragged_serving_step_ms,
-        refuse_long_context,
-    )
-
-    devs = jax.devices()
-    if len(devs) < 2:
-        return {"metric": "serving_longcontext",
-                "error": "needs >= 2 devices for a cp=2 axis"}
-    cp = 2
-    tp = 2 if len(devs) >= 4 else 1
-    mesh_cp = Mesh(
-        np.asarray(devs[:tp * cp]).reshape(tp, cp), ("x", "cp"))
-    mesh_flat = Mesh(np.asarray(devs[:tp]), ("x",))
-
-    import jax.numpy as jnp
-
-    n_kv = max(tp, 2)
-    cfg = TransformerConfig(
-        vocab=256, n_layers=2, hidden=128, ffn=128, n_heads=2 * n_kv,
-        n_kv_heads=n_kv, head_dim=32, dtype=jnp.float32,
-    )
-    # one pool shard: 8 pages of 8 tokens. The long request needs
-    # ~12 pages — inadmissible on one shard, admitted under cp=2.
-    npages_shard, page = 8, 8
-    ecfg = EngineConfig(slots=4, token_budget=32, chunk=16, page=page,
-                        npages=npages_shard, max_steps=5_000,
-                        temperature=0.0)
-    ecfg_oracle = EngineConfig(
-        slots=4, token_budget=32, chunk=16, page=page,
-        npages=cp * npages_shard, max_steps=5_000, temperature=0.0)
-
-    def build(m, cp_axis, use_pallas):
-        model = Transformer(cfg, m, tp_axis="x", cp_axis=cp_axis)
-        params = jax.tree.map(
-            lambda x, s: jax.device_put(x, s),
-            model.init(jax.random.PRNGKey(7)), model.shardings(),
-        )
-        return model, params, use_pallas
-
-    model_cp, params_cp, _ = build(mesh_cp, "cp", False)
-    model_fl, params_fl, _ = build(mesh_flat, None, False)
-    use_pallas = bool(on_tpu)
-
-    # ---- (a) capacity: long requests k× one pool shard, cp vs oracle
-    rng = np.random.default_rng(23)
-    long_prompt = rng.integers(1, 255, size=84).astype(np.int32)
-    short_prompts = [rng.integers(1, 255, size=12).astype(np.int32)
-                     for _ in range(3)]
-
-    def long_trace():
-        reqs = [Request(rid=0, prompt=long_prompt.copy(), max_new=10,
-                        arrival=0)]
-        reqs += [Request(rid=i + 1, prompt=p.copy(), max_new=4,
-                         arrival=0) for i, p in enumerate(short_prompts)]
-        return reqs
-
-    t_cp = long_trace()
-    eng_cp = ServingEngine(model_cp, params_cp, ecfg,
-                           use_pallas=use_pallas)
-    stats_cp = eng_cp.run(t_cp)
-    t_or = long_trace()
-    eng_or = ServingEngine(model_fl, params_fl, ecfg_oracle,
-                           use_pallas=use_pallas)
-    eng_or.run(t_or)
-    streams_cp = {r.rid: list(r.generated) for r in t_cp}
-    streams_or = {r.rid: list(r.generated) for r in t_or}
-    mismatches = sum(
-        1 for rid in streams_or
-        for a, b in zip(streams_cp.get(rid, []), streams_or[rid])
-        if a != b
-    ) + sum(
-        1 for rid in streams_or
-        if len(streams_cp.get(rid, [])) != len(streams_or[rid])
-    )
-    need_pages = -(-(len(long_prompt) + 10) // page)
-    leaked = int(np.asarray(eng_cp.pool.refs).sum())
-
-    # ---- (b) short-request goodput: cp engine vs cp-free engine on
-    # an identical short-only Poisson trace (both warmed once)
-    trace_kw = dict(n_requests=12, mean_interarrival=0.6, len_lo=8,
-                    len_hi=40, max_new_lo=3, max_new_hi=6, vocab=256)
-
-    def short_goodput(model, params, cfg_e):
-        for _warm in (False, True):
-            eng = ServingEngine(model, params, cfg_e,
-                                use_pallas=use_pallas)
-            st = eng.run(poisson_trace(seed=11, **trace_kw))
-        return st
-
-    st_cp = short_goodput(model_cp, params_cp, ecfg)
-    st_fl = short_goodput(model_fl, params_fl, ecfg)
-    ratio = (st_cp.goodput_tok_per_s / st_fl.goodput_tok_per_s
-             if st_fl.goodput_tok_per_s > 0 else float("inf"))
-
-    # ---- (c) priced placement verdict: what a cp-free replica of one
-    # pool shard says when refusing the long request, and the modeled
-    # cp-vs-flat step-cost pair behind the router's choice
-    verdict = refuse_long_context(
-        cfg, page, need_pages,
-        pool_pages=npages_shard,
-        pages_per_seq=min(npages_shard, 1024),
-        cp=1, spec=spec,
-    )
-    kv = need_pages * page
-    hkv = cfg.n_kv_heads // tp
-    g = cfg.n_heads // cfg.n_kv_heads
-    cp_ms = cp_decode_step_ms(
-        kv, cp=cp, page=page, hkv=hkv, g=g, d=cfg.head_dim,
-        hidden=cfg.hidden, n_layers=cfg.n_layers, spec=spec,
-        quant=cfg.kv_quant is not None)
-    flat_ms = ragged_serving_step_ms(
-        [kv], [1], page=page, hkv=hkv, g=g, d=cfg.head_dim,
-        hidden=cfg.hidden, n_layers=cfg.n_layers, spec=spec,
-        quant=cfg.kv_quant is not None)
-    return {
-        "metric": "serving_longcontext",
-        "value": round(need_pages / npages_shard, 3),
-        "unit": "x one-pool capacity served",
-        "token_mismatches": int(mismatches),
-        "leaked_pages": leaked,
-        "long_request_pages": need_pages,
-        "pool_pages_per_shard": npages_shard,
-        "cp": cp,
-        "tp": tp,
-        "completed_long": stats_cp.completed,
-        "evictions": stats_cp.evictions,
-        "short_goodput_cp_tok_per_s": round(
-            st_cp.goodput_tok_per_s, 1),
-        "short_goodput_flat_tok_per_s": round(
-            st_fl.goodput_tok_per_s, 1),
-        "short_goodput_ratio": round(ratio, 3),
-        "placement_verdict": verdict,
-        "model_cp_step_ms": round(cp_ms, 4),
-        "model_flat_step_ms": round(flat_ms, 4),
-        "config": (
-            f"tp={tp} cp={cp} slots={ecfg.slots} page={page} "
-            f"npages/shard={npages_shard} long={len(long_prompt)}+10 "
-            f"hidden={cfg.hidden} "
-            + ("tiny-dryrun" if tiny or not on_tpu else "headline")
-        ),
-    }
-
-
-def _bench_serving_disaggregated(mesh, n, on_tpu, spec, tiny=False):
-    """DISAGGREGATED prefill/decode (ISSUE 7 tentpole acceptance): the
-    PR-6 Poisson trace served by a two-role topology on a 2×(n/2)
-    hybrid mesh — a prefill slice runs chunked prefill, each finished
-    request's int8 KV pages ship slice→slice on the quantized DCN wire
-    (payload + per-row scale planes, the pool's native bytes), landing
-    in the decode slice's pool overlapped with its decode steps — vs
-    the COLOCATED PR-6 engine on the same n/2-chip slice serving the
-    same trace. The number disaggregation must win is DECODE p99 step
-    time: colocated decode steps carry interleaved prefill chunks (the
-    contention), the decode role's steps never do. Both engines run the
-    satellite temperature/top-k sampler (request-keyed draws — the two
-    topologies still produce identical token streams, asserted here)."""
-    import jax
-
-    from triton_distributed_tpu.models import Transformer
-    from triton_distributed_tpu.serving import (
-        DisaggregatedEngine,
-        ServingEngine,
-        poisson_trace,
-    )
-    from triton_distributed_tpu.tune.perf_model import (
-        kv_ship_ms,
-        measured_page_issue_ms,
-        refuse_disaggregation,
-    )
-
-    devs = jax.devices()
-    if len(devs) < 2:
-        return {"metric": "serving_disaggregated",
-                "error": "needs >= 2 devices for a 2x(n/2) role split"}
-    half = len(devs) // 2
-    mesh_p = Mesh(np.asarray(devs[:half]), ("x",))
-    mesh_d = Mesh(np.asarray(devs[half:2 * half]), ("x",))
-    hybrid = Mesh(
-        np.asarray(devs[:2 * half]).reshape(2, half), ("dcn", "x")
-    )
-
-    cfg, ecfg, trace_kw, s_cap = _serving_continuous_config(
-        half, on_tpu, tiny
-    )
-    from dataclasses import replace as _rep
-
-    if not on_tpu or tiny:
-        # the CONTENDED shape of the comparison: prefill chunks much
-        # wider than a decode batch (budget ≫ 8·slots), prompts many
-        # chunks long, arrivals dense enough that colocated decode
-        # steps almost always carry a prefill chunk. The decode role's
-        # engine auto-narrows to an 8·slots packed width, so its steps
-        # never pay the prefill-sized rectangle — the width gap that
-        # IS the interference, visible even on the dev box where the
-        # XLA-twin step cost is rectangle-shaped.
-        s_cap = 256
-        # int8 KV pools even at interpreter shapes: the ship's payload
-        # is then the pool's native int8 bytes + per-row scale planes —
-        # the quantized wire (and its compression) under test
-        cfg = _rep(cfg, kv_quant="int8")
-        ecfg = _rep(
-            ecfg, slots=6, token_budget=256, chunk=128, page=8,
-            npages=192,
-        )
-        trace_kw = dict(
-            n_requests=24, mean_interarrival=0.8,
-            len_lo=64, len_hi=192, max_new_lo=4, max_new_hi=10,
-            vocab=trace_kw["vocab"],
-        )
-    ecfg = _rep(ecfg, temperature=0.7, top_k=40, seed=11)
-
-    def build(mesh_role):
-        model = Transformer(cfg, mesh_role, tp_axis="x")
-        params = jax.tree.map(
-            lambda x, s: jax.device_put(x, s),
-            model.init(jax.random.PRNGKey(7)), model.shardings(),
-        )
-        params = model.quantize_moe_weights(params)
-        params = model.quantize_dense_weights(params)
-        return model, params
-
-    model_p, params_p = build(mesh_p)
-    model_d, params_d = build(mesh_d)
-
-    def fresh_trace():
-        return poisson_trace(seed=11, **trace_kw)
-
-    import os as _os
-
-    from triton_distributed_tpu.runtime import faults as _rt_faults
-    from triton_distributed_tpu.runtime import watchdog as _rt_watchdog
-
-    wd_trips = []
-
-    def _guarded(run_fn):
-        """Under --faults, arm the collective watchdog around the run
-        (the serving_step / kv_ship host heartbeats are live) so a
-        stalled ship or step TRIPS — the trip feeds the health ledger
-        and releases the stall gates — instead of wedging the bench.
-        Trips are reported, not fatal: the run's recovery behavior is
-        the thing under test."""
-        if _rt_faults.active_plan() is None:
-            return run_fn()
-        # generous default: the first guarded run pays jit compiles,
-        # which can take seconds on the dev box — only a real stall
-        # (or a wedged slice) should out-wait this
-        deadline = float(_os.environ.get("TDTPU_BENCH_WATCHDOG", "10.0"))
-        box = {}
-        try:
-            with _rt_watchdog.collective_watchdog(deadline=deadline):
-                box["stats"] = run_fn()
-        except _rt_watchdog.WatchdogTimeout as e:
-            wd_trips.append(str(e).splitlines()[0])
-        finally:
-            _rt_watchdog.clear_trip()
-        return box.get("stats")
-
-    # ---- colocated baseline on the SAME n/2-chip slice (run twice;
-    # the first run pays the compiles). Under a SliceDeath fault plan
-    # this engine is untouched (no slice roles), so its token streams
-    # stay the fault-free reference the failover must reproduce.
-    for _warm in (False, True):
-        trace_c = fresh_trace()
-        eng_c = ServingEngine(model_p, params_p, ecfg)
-        stats_c = _guarded(lambda: eng_c.run(trace_c))
-    assert stats_c is not None and (
-        stats_c.completed == trace_kw["n_requests"]
-    ), (stats_c and stats_c.completed, wd_trips)
-
-    # ---- disaggregated, KV on the quantized DCN wire
-    for _warm in (False, True):
-        trace_d = fresh_trace()
-        eng = DisaggregatedEngine(
-            model_p, params_p, model_d, params_d, ecfg,
-            hybrid_mesh=hybrid, dcn_axis="dcn", transport="dcn",
-            ship_delay_steps=1,
-        )
-        stats = _guarded(lambda: eng.run(trace_d))
-    assert stats is not None and (
-        stats.completed == trace_kw["n_requests"]
-    ), (stats and stats.completed, len(eng._ready), len(eng._inflight),
-        wd_trips)
-    # token-exactness across topologies (int8 KV pages shipped
-    # verbatim + request-keyed sampling): the split changes WHERE work
-    # runs, never what it computes
-    mismatches = sum(
-        a.generated != b.generated for a, b in zip(trace_c, trace_d)
-    )
-
-    mean_len = (trace_kw["len_lo"] + trace_kw["len_hi"]) // 2
-    pages_per_req = -(-mean_len // ecfg.page)
-    hkv_l = cfg.n_kv_heads // half
-    ship_model_ms = kv_ship_ms(
-        pages_per_req, ecfg.page, hkv_l, cfg.head_dim, cfg.n_layers,
-        cfg.kv_quant is not None, spec,
-    )
-    refusal = refuse_disaggregation(
-        cfg, ecfg.page,
-        {"prompt_len": mean_len,
-         "max_new": (trace_kw["max_new_lo"] + trace_kw["max_new_hi"]) // 2},
-        spec,
-    )
-    # the measured per-page issue cost (ROADMAP follow-on): steady-state
-    # decode walks ~ceil(len/page) pages per active row, so the decode
-    # role's p50 step over its typical row count prices one page walk
-    steady_rows = max(
-        1, min(ecfg.slots, int(np.median(
-            [t for t in stats.decode.step_tokens if t > 0] or [1]
-        )))
-    )
-    measured_issue = (
-        stats.decode.p50_step_ms / (steady_rows * pages_per_req)
-        if pages_per_req else 0.0
-    )
-
-    p99_c = stats_c.decode_p99_step_ms
-    p99_d = stats.decode_p99_step_ms
-    return {
-        "metric": "serving_disaggregated",
-        "value": round(p99_d, 2),
-        "unit": "ms decode p99",
-        "colocated_decode_p99_ms": round(p99_c, 2),
-        "decode_p99_vs_colocated": round(p99_d / p99_c, 3) if p99_c else None,
-        "decode_p99_improved": bool(p99_d < p99_c),
-        "goodput_tok_per_s": round(stats.goodput_tok_per_s, 1),
-        "colocated_goodput": round(stats_c.goodput_tok_per_s, 1),
-        "goodput_vs_colocated": round(
-            stats.goodput_tok_per_s / stats_c.goodput_tok_per_s, 3
-        ) if stats_c.goodput_tok_per_s else None,
-        "ships": stats.ships,
-        "ship_p50_ms": round(float(np.median(stats.ship_ms)), 2)
-        if stats.ship_ms else 0.0,
-        "shipped_wire_bytes": stats.shipped_wire_bytes,
-        "wire_compression_vs_raw": round(stats.wire_compression, 3),
-        "degraded_transport": stats.degraded_transport,
-        "final_transport": eng.transport,
-        "ship_retries": stats.ship_retries,
-        "transport_repromotions": stats.transport_repromotions,
-        "kernel_repromotions": (
-            stats.prefill.repromotions + stats.decode.repromotions
-        ),
-        # failover outcome (ISSUE 10): under a SliceDeath plan the
-        # colocated run above is the fault-free token reference, so
-        # token_mismatches_vs_colocated == 0 IS the token-exactness
-        # acceptance; lost_requests must be 0
-        "failover": stats.failover,
-        "lost_requests": trace_kw["n_requests"] - stats.completed,
-        "watchdog_trips": wd_trips,
-        "health": eng.health.snapshot(),
-        "token_mismatches_vs_colocated": mismatches,
-        "prefill_evictions": stats.prefill.evictions,
-        "decode_evictions": stats.decode.evictions,
-        "kv_ship_model_ms_per_req": round(ship_model_ms, 4),
-        "auto_placement": ("refused: " + refusal) if refusal else "accepted",
-        "measured_page_issue_ms": round(measured_issue, 4),
-        "model_page_issue_ms": measured_page_issue_ms(),
-        "config": (
-            f"2x{half} hybrid mesh, slots={ecfg.slots} "
-            f"budget={ecfg.token_budget} chunk={ecfg.chunk} "
-            f"page={ecfg.page} npages={ecfg.npages} "
-            f"requests={trace_kw['n_requests']} "
-            f"lens~U[{trace_kw['len_lo']},{trace_kw['len_hi']}] "
-            f"temp=0.7 top_k=40 kvq={cfg.kv_quant} "
-            + ("tiny-dryrun" if tiny or not on_tpu else "headline")
-        ),
-    }
-
-
-def _bench_serving_speculative_tree(mesh, n, on_tpu, spec, tiny=False):
-    """The --tree paired row (ISSUE-18 acceptance): tree speculation
-    (spec_tree verify trees under the kernel's TREE topology, the
-    TreeDrafter's trunk + sibling branches) against linear draft-k on
-    a BRANCHY SAMPLED motif trace — small top_k temperature sampling
-    makes the prompt self-history genuinely ambiguous, the regime
-    where sibling rescue branches accept tokens the single linear
-    draft loses. Both engines must reproduce the plain engine's
-    streams byte-identically; the tree row must land strictly more
-    accepted tokens per verify step. Rides the pinned small recipe
-    (the acceptance comparison is about scheduling, not FLOPs) so the
-    row is deterministic on CPU and TPU alike. Also emits the
-    in-batch shared-prefix dedup paired row: requests sharing a long
-    prompt prefix served with ``prefix_share`` fold their duplicate
-    frozen prefix pages onto one canonical page (deduped pages > 0,
-    token-exact, goodput no worse)."""
-    import jax
-    from dataclasses import replace as _sp_rep
-
-    from triton_distributed_tpu.models import Transformer, TransformerConfig
-    from triton_distributed_tpu.serving import (
-        EngineConfig,
-        NGramDrafter,
-        Request,
-        ServingEngine,
-        SpeculativeEngine,
-        TreeDrafter,
-        poisson_trace,
-    )
-    from triton_distributed_tpu.tune.perf_model import (
-        DEFAULT_SPEC_ACCEPTANCE,
-        expected_accepted_per_step,
-        expected_accepted_per_step_tree,
-    )
-
-    cfg = TransformerConfig(
-        vocab=128, n_layers=2, hidden=64, ffn=128, n_heads=4,
-        n_kv_heads=2, head_dim=16, dtype=jnp.float32,
-        param_dtype=jnp.float32,
-    )
-    mesh1 = Mesh(np.asarray(jax.devices()[:1]), ("x",))
-    model = Transformer(cfg, mesh1, tp_axis="x")
-    params = model.init(jax.random.PRNGKey(0))
-    ecfg = EngineConfig(slots=4, token_budget=48, chunk=16, page=8,
-                        npages=40, temperature=1.0, top_k=4, seed=5)
-    spec_tree, spec_k = 8, 4
-
-    def branchy_trace():
-        base = poisson_trace(13, 6, 0.5, 8, 30, 16, 24, 128)
-        rng = np.random.default_rng(13 + 1000)
-        for r in base:
-            ln = len(r.prompt)
-            motif = rng.integers(0, 128, (5,)).astype(np.int32)
-            r.prompt = np.tile(motif, -(-ln // 5))[:ln]
-        return base
-
-    t_ref = branchy_trace()
-    stats_ref = ServingEngine(model, params, ecfg).run(
-        t_ref, max_steps=800)
-    t_tree = branchy_trace()
-    stats_tree = SpeculativeEngine(
-        model, params, ecfg, spec_tree=spec_tree,
-        drafter=TreeDrafter(branches=3, branch_len=2),
-    ).run(t_tree, max_steps=800)
-    t_lin = branchy_trace()
-    stats_lin = SpeculativeEngine(
-        model, params, ecfg, spec_k=spec_k, drafter=NGramDrafter(),
-    ).run(t_lin, max_steps=800)
-    assert (stats_ref.completed == stats_tree.completed
-            == stats_lin.completed == len(t_ref))
-    mism_tree = sum(
-        a.generated != b.generated for a, b in zip(t_ref, t_tree))
-    mism_lin = sum(
-        a.generated != b.generated for a, b in zip(t_ref, t_lin))
-    tree_acc = stats_tree.accepted_tokens_per_step
-    lin_acc = stats_lin.accepted_tokens_per_step
-
-    # ---- shared-prefix dedup paired row: one long common prefix
-    def shared_trace():
-        rng = np.random.default_rng(21)
-        prefix = rng.integers(0, 128, (24,)).astype(np.int32)
-        return [
-            Request(rid=i,
-                    prompt=np.concatenate(
-                        [prefix,
-                         rng.integers(0, 128, (4,)).astype(np.int32)]),
-                    max_new=6, arrival=0.1 * i)
-            for i in range(6)
-        ]
-
-    dcfg = _sp_rep(ecfg, slots=3, npages=64)
-    for _warm in (False, True):            # warm run pays the compiles
-        t_base = shared_trace()
-        stats_base = ServingEngine(model, params, dcfg).run(
-            t_base, max_steps=800)
-    for _warm in (False, True):
-        t_dd = shared_trace()
-        stats_dd = ServingEngine(
-            model, params,
-            _sp_rep(dcfg, prefix_cache=True, prefix_share=True),
-        ).run(t_dd, max_steps=800)
-    assert stats_base.completed == stats_dd.completed == len(t_base)
-    mism_dd = sum(
-        a.generated != b.generated for a, b in zip(t_base, t_dd))
-
-    return {
-        "metric": "serving_speculative_tree",
-        "value": round(tree_acc, 3),
-        "unit": "accepted tok/verify-step",
-        "accepted_tokens_per_step": round(tree_acc, 3),
-        "linear_accepted_tokens_per_step": round(lin_acc, 3),
-        "tree_beats_linear": bool(tree_acc > lin_acc),
-        "token_mismatches_vs_nonspeculative": mism_tree,
-        "linear_token_mismatches_vs_nonspeculative": mism_lin,
-        "spec_rows": stats_tree.spec_rows,
-        "draft_tokens": stats_tree.draft_tokens,
-        "rolled_back_tokens": stats_tree.rolled_back_tokens,
-        "steps": len(stats_tree.step_times),
-        "steps_linear": len(stats_lin.step_times),
-        "steps_nonspeculative": len(stats_ref.step_times),
-        "model_accepted_per_step_linear_prior": round(
-            expected_accepted_per_step(spec_k, DEFAULT_SPEC_ACCEPTANCE),
-            3),
-        "model_accepted_per_step_tree_prior": round(
-            expected_accepted_per_step_tree(
-                spec_tree, DEFAULT_SPEC_ACCEPTANCE, branches=3), 3),
-        # the shared-prefix dedup row
-        "shared_prefix_rows": stats_dd.shared_prefix_rows,
-        "deduped_pages": stats_dd.deduped_pages,
-        "dedup_token_mismatches": mism_dd,
-        # scheduler-level goodput (generated tokens per STEP): the
-        # deterministic "no worse" pin — dedup changes page aliasing,
-        # never the step count or the streams. Wall-clock goodput rides
-        # alongside; at interpreter-tiny shapes it sees the host-side
-        # table rewrite but not the KV reads dedup saves, so it is
-        # reported, not gated on.
-        "dedup_goodput_ratio": round(
-            (stats_dd.generated_tokens / len(stats_dd.step_times))
-            / (stats_base.generated_tokens / len(stats_base.step_times)),
-            3),
-        "dedup_wallclock_goodput_ratio": round(
-            stats_dd.goodput_tok_per_s / stats_base.goodput_tok_per_s, 3
-        ) if stats_base.goodput_tok_per_s else None,
-        "config": (
-            f"spec_tree={spec_tree} TreeDrafter(branches=3, "
-            f"branch_len=2) vs spec_k={spec_k} ngram, top_k=4 "
-            f"temperature=1.0 branchy motif trace; dedup: 6 requests "
-            f"sharing a 24-token prefix, page=8 "
-            + ("tiny-dryrun" if tiny or not on_tpu else "headline")
-        ),
-    }
-
-
-def _bench_serving_speculative(mesh, n, on_tpu, spec, tiny=False,
-                               tree=False):
-    if tree:
-        return _bench_serving_speculative_tree(mesh, n, on_tpu, spec,
-                                               tiny=tiny)
-    """SPECULATIVE decoding (ISSUE 12 tentpole acceptance): the PR-6
-    Poisson trace with MOTIF-HEAVY prompts (repeated 5-token motifs —
-    the traffic shape prompt-lookup speculation exists for) served
-    three ways: (1) the plain colocated engine — the token-stream
-    reference; (2) the colocated SpeculativeEngine (n-gram drafter,
-    spec_k=4) — must reproduce the reference streams byte-identically
-    while emitting >1 accepted token per verify row; (3) the
-    disaggregated engine with a speculative decode role — same streams
-    again, with KV still shipping on the quantized DCN wire at the
-    CHANGED cadence (fewer, wider decode steps). Reports the decode
-    p50/p99 deltas speculation buys and the perf-model rows that price
-    the cadence change for placement (`spec_step_ms`, the truncated-
-    geometric accepted/step prior, and `refuse_disaggregation` with
-    and without `spec_k` in the traffic dict)."""
-    import jax
-
-    from triton_distributed_tpu.models import Transformer
-    from triton_distributed_tpu.serving import (
-        DisaggregatedEngine,
-        NGramDrafter,
-        ServingEngine,
-        SpeculativeEngine,
-        poisson_trace,
-    )
-    from triton_distributed_tpu.tune.perf_model import (
-        DEFAULT_SPEC_ACCEPTANCE,
-        expected_accepted_per_step,
-        measured_page_issue_ms,
-        ragged_serving_step_ms,
-        refuse_disaggregation,
-        spec_step_ms,
-    )
-
-    devs = jax.devices()
-    if len(devs) < 2:
-        return {"metric": "serving_speculative",
-                "error": "needs >= 2 devices for the disaggregated leg"}
-    half = len(devs) // 2
-    mesh_p = Mesh(np.asarray(devs[:half]), ("x",))
-    mesh_d = Mesh(np.asarray(devs[half:2 * half]), ("x",))
-    hybrid = Mesh(
-        np.asarray(devs[:2 * half]).reshape(2, half), ("dcn", "x")
-    )
-
-    cfg, ecfg, trace_kw, s_cap = _serving_continuous_config(
-        half, on_tpu, tiny
-    )
-    from dataclasses import replace as _rep
-
-    # GREEDY decode: at temperature 0 every engine argmaxes the same
-    # logits, so acceptance is purely "did the drafter guess the
-    # model's next token" — the honest accepted/step for prompt-lookup
-    ecfg = _rep(ecfg, temperature=0.0, seed=11)
-    if not on_tpu or tiny:
-        # decode-heavy traffic: long generation tails (greedy decode on
-        # a tiny model settles into repetitive continuations — the
-        # regime prompt-lookup drafting feeds on) and pool headroom for
-        # the provisional draft pages
-        trace_kw = dict(
-            trace_kw, len_lo=8, len_hi=32,
-            max_new_lo=16, max_new_hi=32,
-        )
-        ecfg = _rep(ecfg, npages=64)
-    spec_k = 4
-
-    def build(mesh_role):
-        model = Transformer(cfg, mesh_role, tp_axis="x")
-        params = jax.tree.map(
-            lambda x, s: jax.device_put(x, s),
-            model.init(jax.random.PRNGKey(7)), model.shardings(),
-        )
-        params = model.quantize_moe_weights(params)
-        params = model.quantize_dense_weights(params)
-        return model, params
-
-    model_p, params_p = build(mesh_p)
-    model_d, params_d = build(mesh_d)
-
-    def fresh_trace():
-        """The Poisson arrivals/max_new, with every prompt rewritten
-        into a repeated 5-token motif (fresh Request objects per call —
-        engines mutate them in place). Deterministic."""
-        base = poisson_trace(seed=11, **trace_kw)
-        rng = np.random.default_rng(29)
-        for r in base:
-            ln = len(r.prompt)
-            motif = rng.integers(
-                0, trace_kw["vocab"], (5,)).astype(np.int32)
-            r.prompt = np.tile(motif, -(-ln // 5))[:ln]
-        return base
-
-    # ---- (1) plain colocated reference (warm run pays compiles)
-    for _warm in (False, True):
-        trace_ref = fresh_trace()
-        eng_ref = ServingEngine(model_p, params_p, ecfg)
-        stats_ref = eng_ref.run(trace_ref)
-    assert stats_ref.completed == trace_kw["n_requests"], (
-        stats_ref.completed, stats_ref.deferrals)
-
-    # ---- (2) colocated speculative, n-gram drafter
-    for _warm in (False, True):
-        trace_s = fresh_trace()
-        eng_s = SpeculativeEngine(
-            model_p, params_p, ecfg, spec_k=spec_k,
-            drafter=NGramDrafter(),
-        )
-        stats_s = eng_s.run(trace_s)
-    assert stats_s.completed == trace_kw["n_requests"], (
-        stats_s.completed, stats_s.deferrals)
-    mism_coloc = sum(
-        a.generated != b.generated for a, b in zip(trace_ref, trace_s)
-    )
-
-    # ---- (3) disaggregated with a speculative decode role
-    for _warm in (False, True):
-        trace_d = fresh_trace()
-        eng_d = DisaggregatedEngine(
-            model_p, params_p, model_d, params_d, ecfg,
-            hybrid_mesh=hybrid, dcn_axis="dcn", transport="dcn",
-            ship_delay_steps=1, spec_k=spec_k, drafter=NGramDrafter(),
-        )
-        stats_d = eng_d.run(trace_d)
-    assert stats_d.completed == trace_kw["n_requests"], (
-        stats_d.completed, len(eng_d._ready), len(eng_d._inflight))
-    mism_disagg = sum(
-        a.generated != b.generated for a, b in zip(trace_ref, trace_d)
-    )
-
-    # ---- perf-model: the priced ship-cadence change. Speculation
-    # widens each decode row to q=1+k and shrinks the decode window to
-    # max_new/accepted steps — the rows placement reasons with.
-    mean_len = (trace_kw["len_lo"] + trace_kw["len_hi"]) // 2
-    hkv_l = max(1, cfg.n_kv_heads // half)
-    g = cfg.n_heads // cfg.n_kv_heads
-    plain_ms = ragged_serving_step_ms(
-        [mean_len] * ecfg.slots, [1] * ecfg.slots, page=ecfg.page,
-        hkv=hkv_l, g=g, d=cfg.head_dim, hidden=cfg.hidden,
-        n_layers=cfg.n_layers, spec=spec,
-        quant=cfg.kv_quant is not None,
-        issue_ms=measured_page_issue_ms(),
-    )
-    spec_ms = spec_step_ms(
-        [mean_len] * ecfg.slots, spec_k=spec_k, page=ecfg.page,
-        hkv=hkv_l, g=g, d=cfg.head_dim, hidden=cfg.hidden,
-        n_layers=cfg.n_layers, spec=spec,
-        quant=cfg.kv_quant is not None,
-        issue_ms=measured_page_issue_ms(),
-    )
-    prior_acc = expected_accepted_per_step(
-        spec_k, DEFAULT_SPEC_ACCEPTANCE
-    )
-    measured_acc = stats_s.accepted_tokens_per_step
-    traffic = {
-        "prompt_len": mean_len,
-        "max_new": (trace_kw["max_new_lo"]
-                    + trace_kw["max_new_hi"]) // 2,
-    }
-    refusal_plain = refuse_disaggregation(cfg, ecfg.page, traffic, spec)
-    p_meas = (min(1.0, stats_s.draft_acceptance_rate)
-              if stats_s.draft_tokens else DEFAULT_SPEC_ACCEPTANCE)
-    refusal_spec = refuse_disaggregation(
-        cfg, ecfg.page,
-        dict(traffic, spec_k=spec_k, spec_acceptance=p_meas),
-        spec,
-    )
-
-    p50_ref, p99_ref = (stats_ref.decode_p50_step_ms,
-                        stats_ref.decode_p99_step_ms)
-    p50_s, p99_s = (stats_s.decode_p50_step_ms,
-                    stats_s.decode_p99_step_ms)
-    return {
-        "metric": "serving_speculative",
-        "value": round(measured_acc, 3),
-        "unit": "accepted tok/verify-step",
-        "accepted_tokens_per_step": round(measured_acc, 3),
-        "draft_acceptance_rate": round(
-            stats_s.draft_acceptance_rate, 3),
-        "token_mismatches_vs_nonspeculative": mism_coloc,
-        "token_mismatches_disaggregated": mism_disagg,
-        "spec_rows": stats_s.spec_rows,
-        "draft_tokens": stats_s.draft_tokens,
-        "rolled_back_tokens": stats_s.rolled_back_tokens,
-        "steps": len(stats_s.step_times),
-        "steps_nonspeculative": len(stats_ref.step_times),
-        "decode_p50_step_ms": round(p50_s, 2),
-        "decode_p99_step_ms": round(p99_s, 2),
-        "decode_p50_delta_ms": round(p50_s - p50_ref, 2),
-        "decode_p99_delta_ms": round(p99_s - p99_ref, 2),
-        "goodput_tok_per_s": round(stats_s.goodput_tok_per_s, 1),
-        "goodput_vs_nonspeculative": round(
-            stats_s.goodput_tok_per_s / stats_ref.goodput_tok_per_s, 3
-        ) if stats_ref.goodput_tok_per_s else None,
-        "disagg_accepted_tokens_per_step": round(
-            stats_d.decode.accepted_tokens_per_step, 3),
-        "disagg_ships": stats_d.ships,
-        "disagg_decode_p99_ms": round(stats_d.decode_p99_step_ms, 2),
-        # the priced cadence change: ms per EMITTED token, before and
-        # after speculation — what replica_load_ms and auto placement
-        # now reason with
-        "model_plain_step_ms": round(plain_ms, 4),
-        "model_spec_step_ms": round(spec_ms, 4),
-        "model_accepted_per_step_prior": round(prior_acc, 3),
-        "model_ms_per_token_plain": round(plain_ms, 4),
-        "model_ms_per_token_spec": round(
-            spec_ms / max(measured_acc, 1.0), 4),
-        "auto_placement_plain": (
-            ("refused: " + refusal_plain) if refusal_plain
-            else "accepted"),
-        "auto_placement_spec": (
-            ("refused: " + refusal_spec) if refusal_spec
-            else "accepted"),
-        "config": (
-            f"2x{half} hybrid mesh, spec_k={spec_k} ngram drafter "
-            f"slots={ecfg.slots} budget={ecfg.token_budget} "
-            f"chunk={ecfg.chunk} page={ecfg.page} "
-            f"npages={ecfg.npages} requests={trace_kw['n_requests']} "
-            f"motif-prompts lens~U[{trace_kw['len_lo']},"
-            f"{trace_kw['len_hi']}] greedy "
-            + ("tiny-dryrun" if tiny or not on_tpu else "headline")
-        ),
-    }
-
-
-def _fleet_trace(trace_kw, page):
-    """The serving_fleet traffic: the seeded Poisson base PLUS two
-    session bursts, each sharing its OWN 10-page prompt prefix — a
-    leader arrives early and its followers arrive after the leader's
-    prefill has published the prefix pages. A cache-aware router lands
-    every follower on resident pages (one prefill per session);
-    round-robin scatters each session across replicas and pays the
-    prefill once per replica. Deterministic; fresh Request objects per
-    call (engines mutate them in place)."""
-    from triton_distributed_tpu.serving import poisson_trace
-    from triton_distributed_tpu.serving.engine import Request
-
-    base = poisson_trace(seed=13, **trace_kw)
-    rng = np.random.default_rng(17)
-    out = list(base)
-    rid = len(base)
-    for s in range(2):
-        prefix = rng.integers(
-            0, trace_kw["vocab"], (10 * page,)).astype(np.int32)
-        # leader at 1.0/2.0 (prefilled well before the acceptance
-        # plan's step-8 death); followers straddle the death
-        arrivals = [1.0 + s] + [8.0 + s + 1.5 * j for j in range(5)]
-        for a in arrivals:
-            tail = rng.integers(
-                0, trace_kw["vocab"], (int(rng.integers(4, 12)),)
-            ).astype(np.int32)
-            req = Request(
-                rid=rid,
-                prompt=np.concatenate([prefix, tail]),
-                max_new=int(rng.integers(trace_kw["max_new_lo"],
-                                         trace_kw["max_new_hi"])),
-                arrival=a,
-            )
-            req.session = f"burst-{s}"
-            out.append(req)
-            rid += 1
-    return out
-
-
-def _bench_serving_fleet(mesh, n, on_tpu, spec, tiny=False,
-                         spec_k=None):
-    """FLEET serving (ISSUE 11 tentpole acceptance): 3 engine replicas,
-    each on its own mesh slice carved by ``carve_replica_meshes``,
-    behind the scored ``FleetRouter`` (prefix overlap × health × load
-    estimate, session affinity, spill) vs a ROUND-ROBIN baseline on
-    the same Poisson + shared-prefix-burst trace. Under a --faults
-    ``ReplicaDeath`` plan the dead replica's in-flight requests drain
-    back through the router onto the survivor: ``lost_requests`` must
-    be 0 and the token streams byte-identical to the fault-free
-    reference run (request-keyed sampling — placement cannot change
-    tokens).
-
-    ``--spec-k K`` (ISSUE 13 satellite) swaps every replica for a
-    :class:`SpeculativeEngine` at draft budget K (ngram drafter, motif
-    prompts so prompt-lookup drafting has something to accept): the
-    NON-speculative scored fleet becomes the reference run, so the
-    token oracle simultaneously proves fleet-level speculative
-    token-exactness, and the output adds per-replica accepted
-    tokens/step plus the spec-vs-plain goodput ratio on the identical
-    trace."""
-    import os as _os
-
-    import jax
-
-    from triton_distributed_tpu.models import Transformer
-    from triton_distributed_tpu.runtime import faults as _rt_faults
-    from triton_distributed_tpu.runtime import watchdog as _rt_watchdog
-    from triton_distributed_tpu.runtime.topology import (
-        carve_replica_meshes,
-    )
-    from triton_distributed_tpu.serving import (
-        NGramDrafter,
-        ServingEngine,
-        SpeculativeEngine,
-    )
-    from triton_distributed_tpu.serving.fleet import (
-        RouterConfig,
-        ServingFleet,
-    )
-
-    devs = jax.devices()
-    # 3 replicas: the acceptance plan kills replica 1 mid-trace, and
-    # with TWO survivors the router keeps being a router afterwards —
-    # a 2-replica fleet degenerates to "route everything to the lone
-    # survivor" where every policy is equal
-    n_replicas = 3
-    meshes = carve_replica_meshes(n_replicas, devs)
-    w = int(meshes[0].devices.size)
-    cfg, ecfg, trace_kw, s_cap = _serving_continuous_config(
-        w, on_tpu, tiny
-    )
-    from dataclasses import replace as _rep
-
-    if not on_tpu or tiny:
-        # small enough for the CI smoke, big enough that the burst's
-        # shared prefix (10 pages, ~5 prefill chunks) dominates the
-        # routing decision
-        trace_kw = dict(
-            n_requests=12, mean_interarrival=1.0,
-            len_lo=8, len_hi=40,
-            # spec fleets need decode room for the drafter to earn
-            # accepts; the plain fleet headline keeps short tails
-            max_new_lo=8 if spec_k else 3,
-            max_new_hi=14 if spec_k else 7,
-            vocab=trace_kw["vocab"],
-        )
-        ecfg = _rep(ecfg, slots=4, token_budget=48, chunk=16, page=8,
-                    npages=64)
-    ecfg = _rep(ecfg, prefix_cache=True, temperature=0.7, top_k=40,
-                seed=11)
-
-    models = []
-    for m in meshes:
-        model = Transformer(cfg, m, tp_axis="x")
-        params = jax.tree.map(
-            lambda x, s: jax.device_put(x, s),
-            model.init(jax.random.PRNGKey(7)), model.shardings(),
-        )
-        params = model.quantize_moe_weights(params)
-        params = model.quantize_dense_weights(params)
-        models.append((model, params))
-
-    def fresh_trace():
-        out = _fleet_trace(trace_kw, ecfg.page)
-        if spec_k:
-            # motif prompts: prompt-lookup drafting needs repeats to
-            # accept — without them the spec fleet degenerates to a
-            # k=0 fleet and the ratio measures only verify overhead
-            rng = np.random.default_rng(23)
-            for r in out:
-                ln = len(r.prompt)
-                motif = rng.integers(
-                    0, trace_kw["vocab"], (5,)).astype(np.int32)
-                r.prompt = np.tile(motif, -(-ln // 5))[:ln]
-        return out
-
-    n_total = len(fresh_trace())
-
-    def build_fleet(policy, k=None):
-        if k:
-            engines = [SpeculativeEngine(model, params, ecfg,
-                                         spec_k=k,
-                                         drafter=NGramDrafter())
-                       for model, params in models]
-        else:
-            engines = [ServingEngine(model, params, ecfg)
-                       for model, params in models]
-        return ServingFleet(
-            engines, seed=1, router=RouterConfig(policy=policy),
-            meshes=meshes,
-        )
-
-    wd_trips = []
-
-    def _guarded(run_fn):
-        # same contract as the disaggregated bench: under --faults the
-        # collective watchdog is armed so a stalled router_dispatch /
-        # serving_step trips into the ledger instead of wedging
-        if _rt_faults.active_plan() is None:
-            return run_fn()
-        deadline = float(_os.environ.get("TDTPU_BENCH_WATCHDOG", "10.0"))
-        box = {}
-        try:
-            with _rt_watchdog.collective_watchdog(deadline=deadline):
-                box["out"] = run_fn()
-        except _rt_watchdog.WatchdogTimeout as e:
-            wd_trips.append(str(e).splitlines()[0])
-        finally:
-            _rt_watchdog.clear_trip()
-        return box.get("out")
-
-    # ---- fault-free reference (the token oracle; run twice — the
-    # first run pays every jit compile for both replica models)
-    plan = _rt_faults.active_plan()
-    _rt_faults.set_fault_plan(None)
-    try:
-        for _warm in (False, True):
-            ref_fleet = build_fleet("scored")
-            ref_fleet.run(fresh_trace())
-    finally:
-        _rt_faults.set_fault_plan(plan)
-    ref_tokens = ref_fleet.token_streams()
-    assert ref_fleet.stats.lost_requests == 0, ref_fleet.stats
-
-    # ---- the routed fleet under the active plan (the headline run;
-    # with --spec-k these replicas are SPECULATIVE and the non-spec
-    # reference above doubles as the goodput baseline)
-    fleet = build_fleet("scored", k=spec_k)
-    stats = _guarded(lambda: fleet.run(fresh_trace()))
-    assert stats is not None, wd_trips
-
-    # ---- round-robin baseline under the SAME plan
-    rr = build_fleet("round_robin", k=spec_k)
-    rr_stats = _guarded(lambda: rr.run(fresh_trace()))
-    assert rr_stats is not None, wd_trips
-
-    tokens = fleet.token_streams()
-    mismatches = sum(
-        1 for rid, t in ref_tokens.items() if tokens.get(rid) != t
-    )
-
-    def hit_rate(fl):
-        total_pages = sum(
-            len(rec["req"].prompt) // ecfg.page
-            for rec in fl.stats.records.values())
-        return fl.prefix_hits / total_pages if total_pages else 0.0
-
-    goodput = fleet.goodput_tok_per_s
-    rr_goodput = rr.goodput_tok_per_s
-    out = {
-        "metric": "serving_fleet",
-        "value": round(goodput, 1),
-        "unit": "tok/s fleet goodput (modeled wall)",
-        "rr_goodput": round(rr_goodput, 1),
-        "goodput_vs_round_robin": round(goodput / rr_goodput, 3)
-        if rr_goodput else None,
-        "ticks": fleet.ticks,
-        "rr_ticks": rr.ticks,
-        "p99_ttft_ticks": round(stats.p99_ttft_ticks, 2),
-        "p99_tpot_ticks": round(stats.p99_tpot_ticks, 2),
-        "rr_p99_ttft_ticks": round(rr_stats.p99_ttft_ticks, 2),
-        "prefix_hit_rate": round(hit_rate(fleet), 3),
-        "rr_prefix_hit_rate": round(hit_rate(rr), 3),
-        "completed": stats.completed,
-        "lost_requests": stats.lost_requests,
-        "rr_lost_requests": rr_stats.lost_requests,
-        "token_mismatches_vs_fault_free": mismatches,
-        "deaths": stats.deaths,
-        "failover_requeued": stats.failover_requeued,
-        "failover_re_prefill_tokens": stats.failover_re_prefill_tokens,
-        "routed": {str(k): v for k, v in sorted(stats.routed.items())},
-        "spills": stats.spills,
-        "affinity_hits": stats.affinity_hits,
-        "probes": stats.probes,
-        "rotation": list(fleet.rotation()),
-        "watchdog_trips": wd_trips,
-        "health": fleet.health.snapshot(),
-        "config": (
-            f"replicas={n_replicas}x{w} slots={ecfg.slots} "
-            f"budget={ecfg.token_budget} chunk={ecfg.chunk} "
-            f"page={ecfg.page} npages={ecfg.npages} "
-            f"requests={n_total} temp=0.7 top_k=40 "
-            f"prefix_cache=on fleet_seed=1 "
-            + (f"spec_k={spec_k} ngram-drafter " if spec_k else "")
-            + ("tiny-dryrun" if tiny or not on_tpu else "headline")
-        ),
-    }
-    if spec_k:
-        nonspec_goodput = ref_fleet.goodput_tok_per_s
-        out.update({
-            "spec_k": spec_k,
-            # per-replica accepted tokens per verify step — the spec
-            # win the router's load term prices replicas by
-            "accepted_tokens_per_step": {
-                str(r.index): round(
-                    r.engine.stats.accepted_tokens_per_step, 3)
-                for r in fleet.replicas},
-            "spec_rows": {
-                str(r.index): r.engine.stats.spec_rows
-                for r in fleet.replicas},
-            "nonspec_goodput": round(nonspec_goodput, 1),
-            "goodput_vs_nonspec": round(goodput / nonspec_goodput, 3)
-            if nonspec_goodput else None,
-        })
-    return out
-
-
-def _bench_serving_elastic(mesh, n, on_tpu, spec, tiny=False):
-    """ELASTIC fleet (ISSUE 13 tentpole acceptance): 2 active replicas
-    plus one RESERVE slice carved by ``carve_replica_meshes(...,
-    reserve=1)``, a seeded :class:`FleetAutoscaler` that spawns from
-    the reserve under sustained priced pressure (the newcomer earns
-    admission through the PR-10 probation-probe path), then a planned
-    ``drain`` of replica 0 once the newcomer is HEALTHY — its resident
-    rows MIGRATE their committed KV pages over the kv_ship wire when
-    ``perf_model.migrate_vs_reprefill_ms`` prices the wire under the
-    recompute. Composes with the --faults acceptance plan
-    ``ReplicaDeath(replica=1, step=N)``: the death, the grow and the
-    drain all land in one run, and still lost_requests == 0 with every
-    stream byte-identical to the fault-free reference. The whole
-    grow/drain/migrate event log is replayed twice under the same
-    fleet seed and must come back identical."""
-    import os as _os
-
-    import jax
-
-    from triton_distributed_tpu import config as _config
-    from triton_distributed_tpu.models import Transformer
-    from triton_distributed_tpu.runtime import faults as _rt_faults
-    from triton_distributed_tpu.runtime import watchdog as _rt_watchdog
-    from triton_distributed_tpu.runtime.health import (
-        HealthLedger,
-        PeerState,
-    )
-    from triton_distributed_tpu.runtime.topology import (
-        carve_replica_meshes,
-    )
-    from triton_distributed_tpu.serving import ServingEngine
-    from triton_distributed_tpu.serving.fleet import (
-        AutoscalerConfig,
-        RouterConfig,
-        ServingFleet,
-    )
-
-    devs = jax.devices()
-    n_active = 2
-    active_meshes, spare_meshes = carve_replica_meshes(
-        n_active, devs, reserve=1)
-    w = int(active_meshes[0].devices.size)
-    cfg, ecfg, trace_kw, s_cap = _serving_continuous_config(
-        w, on_tpu, tiny
-    )
-    from dataclasses import replace as _rep
-
-    if not on_tpu or tiny:
-        trace_kw = dict(
-            n_requests=14, mean_interarrival=0.6,
-            len_lo=8, len_hi=40, max_new_lo=4, max_new_hi=8,
-            vocab=trace_kw["vocab"],
-        )
-        ecfg = _rep(ecfg, slots=4, token_budget=48, chunk=16, page=8,
-                    npages=64)
-    ecfg = _rep(ecfg, prefix_cache=True, temperature=0.7, top_k=40,
-                seed=11)
-
-    models = []
-    for m in list(active_meshes) + list(spare_meshes):
-        model = Transformer(cfg, m, tp_axis="x")
-        params = jax.tree.map(
-            lambda x, s: jax.device_put(x, s),
-            model.init(jax.random.PRNGKey(7)), model.shardings(),
-        )
-        params = model.quantize_moe_weights(params)
-        params = model.quantize_dense_weights(params)
-        models.append((model, params))
-
-    def fresh_trace():
-        return _fleet_trace(trace_kw, ecfg.page)
-
-    n_total = len(fresh_trace())
-    grown_peer = f"replica:{n_active}"
-
-    def build_fleet(elastic=True):
-        engines = [ServingEngine(model, params, ecfg)
-                   for model, params in models[:n_active]]
-        spare_model, spare_params = models[n_active]
-        if not elastic:
-            return ServingFleet(
-                engines, seed=1, router=RouterConfig(),
-                meshes=list(active_meshes))
-        return ServingFleet(
-            engines, seed=1,
-            router=RouterConfig(queue_cap=4),
-            # fast probation so the grown replica earns admission
-            # within the trace (the PR-10 knobs, not a blind add)
-            health=HealthLedger(seed=1, probation_after=1,
-                                promote_after=1, probe_interval=2),
-            meshes=list(active_meshes),
-            reserve=[(lambda: ServingEngine(spare_model, spare_params,
-                                            ecfg),
-                      spare_meshes[0])],
-            autoscaler=AutoscalerConfig(slo_ms=0.0, window=2,
-                                        cooldown=50, max_replicas=3),
-        )
-
-    def drive(fleet, max_ticks=2000):
-        """fleet.run plus the drain trigger: once the grown replica is
-        HEALTHY, replica 0 is drained — the planned-retirement half of
-        the elastic story, with the autoscaler's grow and the fault
-        plan's death composing around it."""
-        fleet.submit_trace(fresh_trace())
-        prev = _config.fleet_seed()
-        _config.set_fleet_seed(fleet.seed)
-        drained = False
-        try:
-            for _ in range(max_ticks):
-                if fleet.idle:
-                    break
-                if (not drained and fleet.stats.grows
-                        and fleet.health.state(grown_peer)
-                        is PeerState.HEALTHY):
-                    fleet.drain(0)
-                    drained = True
-                fleet.tick()
-        finally:
-            _config.set_fleet_seed(prev)
-        return fleet.stats
-
-    wd_trips = []
-
-    def _guarded(run_fn):
-        if _rt_faults.active_plan() is None:
-            return run_fn()
-        deadline = float(_os.environ.get("TDTPU_BENCH_WATCHDOG", "10.0"))
-        box = {}
-        try:
-            with _rt_watchdog.collective_watchdog(deadline=deadline):
-                box["out"] = run_fn()
-        except _rt_watchdog.WatchdogTimeout as e:
-            wd_trips.append(str(e).splitlines()[0])
-        finally:
-            _rt_watchdog.clear_trip()
-        return box.get("out")
-
-    # ---- fault-free static reference (the token oracle; run twice —
-    # the first run pays every jit compile for the replica models)
-    plan = _rt_faults.active_plan()
-    _rt_faults.set_fault_plan(None)
-    try:
-        for _warm in (False, True):
-            ref_fleet = build_fleet(elastic=False)
-            ref_fleet.run(fresh_trace())
-    finally:
-        _rt_faults.set_fault_plan(plan)
-    ref_tokens = ref_fleet.token_streams()
-    assert ref_fleet.stats.lost_requests == 0, ref_fleet.stats
-
-    # ---- the elastic run under the active plan (grow + drain +
-    # migrate + whatever the plan throws at it)
-    fleet = build_fleet()
-    stats = _guarded(lambda: drive(fleet))
-    assert stats is not None, wd_trips
-
-    # ---- replay determinism: the same fleet seed and trace must
-    # produce the byte-identical grow/drain/migration event log
-    fleet2 = build_fleet()
-    stats2 = _guarded(lambda: drive(fleet2))
-    assert stats2 is not None, wd_trips
-    events_deterministic = list(stats.events) == list(stats2.events)
-
-    tokens = fleet.token_streams()
-    mismatches = sum(
-        1 for rid, t in ref_tokens.items() if tokens.get(rid) != t
-    )
-    goodput = fleet.goodput_tok_per_s
-    priced = [(round(wms, 6), round(rms, 6))
-              for wms, rms in stats.migration_priced]
-    return {
-        "metric": "serving_elastic",
-        "value": round(goodput, 1),
-        "unit": "tok/s fleet goodput (modeled wall)",
-        "ticks": fleet.ticks,
-        "completed": stats.completed,
-        "lost_requests": stats.lost_requests,
-        "token_mismatches_vs_fault_free": mismatches,
-        "grows": stats.grows,
-        "drains": stats.drains,
-        "drain_requeued": stats.drain_requeued,
-        "migrations": stats.migrations,
-        "migrations_cheaper_than_reprefill": stats.migrations_cheaper,
-        "migrated_pages": stats.migrated_pages,
-        "migration_wire_bytes": stats.migration_wire_bytes,
-        "migration_priced_ms": priced[:8],
-        "migration_refusals": stats.migration_refusals,
-        "migration_failures": stats.migration_failures,
-        "deaths": stats.deaths,
-        "failover_requeued": stats.failover_requeued,
-        "admission_rejections": stats.admission_rejections,
-        "probes": stats.probes,
-        "routed": {str(k): v for k, v in sorted(stats.routed.items())},
-        "rotation": list(fleet.rotation()),
-        "event_log": [list(e) for e in stats.events[:24]],
-        "event_log_deterministic": events_deterministic,
-        "watchdog_trips": wd_trips,
-        "health": fleet.health.snapshot(),
-        "config": (
-            f"active={n_active}x{w} reserve=1x{w} slots={ecfg.slots} "
-            f"budget={ecfg.token_budget} chunk={ecfg.chunk} "
-            f"page={ecfg.page} npages={ecfg.npages} "
-            f"requests={n_total} queue_cap=4 slo_ms=0.0 window=2 "
-            f"temp=0.7 top_k=40 prefix_cache=on fleet_seed=1 "
-            + ("tiny-dryrun" if tiny or not on_tpu else "headline")
-        ),
-    }
-
-
-def _bench_serving_multitenant(mesh, n, on_tpu, spec, tiny=False):
-    """MULTI-TENANT fleet (ISSUE 16 tentpole acceptance): 3 replicas,
-    an interactive trickle under a 4x BATCH FLOOD plus a background
-    drip, per-tenant :class:`TenantConfig` (tight interactive SLO →
-    the router's deadline-slack term is live), the seeded
-    :class:`BrownoutController` armed and ``queue_cap`` admission
-    counting tier-visible depth. Four runs:
-
-    1. fault-free SINGLE-TENANT oracle over the identical trace — the
-       token-exactness reference (sampling is request-keyed, so
-       preemption/shed/retry may reorder WHEN a token appears, never
-       WHICH token);
-    2. flood-free interactive-only run under the SAME fault plan —
-       the p99 baseline the brownout + preemption must protect;
-    3. the headline multi-tenant run under the plan (the acceptance
-       line adds ``--faults "seed=1; ReplicaDeath(replica=1,
-       step=8)"``): interactive p99 no worse than (2), every shed on
-       background/batch with background shed strictly first,
-       preemptions > 0, zero pool-page leaks on live replicas, zero
-       lost requests;
-    4. a same-seed replay of (3) — the event log (placement,
-       preemption, shed, brownout transition, retune) must come back
-       byte-identical (the PR-13 replay contract extended to the
-       multi-tenant events)."""
-    import os as _os
-
-    import jax
-
-    from triton_distributed_tpu import config as _config
-    from triton_distributed_tpu.models import Transformer
-    from triton_distributed_tpu.runtime import faults as _rt_faults
-    from triton_distributed_tpu.runtime import watchdog as _rt_watchdog
-    from triton_distributed_tpu.runtime.topology import (
-        carve_replica_meshes,
-    )
-    from triton_distributed_tpu.serving import (
-        BrownoutConfig,
-        Request,
-        ServingEngine,
-        TenantConfig,
-    )
-    from triton_distributed_tpu.serving.fleet import (
-        RouterConfig,
-        ServingFleet,
-    )
-
-    devs = jax.devices()
-    n_replicas = 3
-    meshes = carve_replica_meshes(n_replicas, devs)
-    w = int(meshes[0].devices.size)
-    cfg, ecfg, _trace_kw, _s_cap = _serving_continuous_config(
-        w, on_tpu, tiny
-    )
-    from dataclasses import replace as _rep
-
-    if not on_tpu or tiny:
-        ecfg = _rep(ecfg, slots=4, token_budget=48, chunk=16, page=8,
-                    npages=64)
-    ecfg = _rep(ecfg, prefix_cache=True, temperature=0.7, top_k=40,
-                seed=11)
-    # SLOs scale with the perf model's step cost: interpreter-tiny
-    # models step in ~microseconds of MODEL time, headline in ms
-    slo_iact = 0.05 if (tiny or not on_tpu) else 50.0
-    slo_brownout = 0.004 if (tiny or not on_tpu) else 4.0
-    tenants = {
-        "iact": TenantConfig(priority="interactive", slo_ms=slo_iact),
-        "bat": TenantConfig(priority="batch"),
-        "bg": TenantConfig(priority="background"),
-    }
-
-    models = []
-    for m in meshes:
-        model = Transformer(cfg, m, tp_axis="x")
-        params = jax.tree.map(
-            lambda x, s: jax.device_put(x, s),
-            model.init(jax.random.PRNGKey(7)), model.shardings(),
-        )
-        params = model.quantize_moe_weights(params)
-        params = model.quantize_dense_weights(params)
-        models.append((model, params))
-
-    import numpy as _np
-
-    n_iact, n_bat, n_bg = 6, 24, 6       # the 4x batch flood
-
-    def fresh_trace(only_interactive=False):
-        out, rid = [], 0
-
-        def mk(rid, arrival, tenant, plen):
-            rng = _np.random.default_rng(5000 + rid)
-            prompt = rng.integers(
-                0, cfg.vocab, (plen,)).astype(_np.int32)
-            r = Request(rid=rid, prompt=prompt, max_new=5,
-                        arrival=arrival)
-            r.tenant = tenant
-            return r
-
-        for i in range(n_iact):
-            out.append(mk(rid, i * 3.0, "iact", 20)); rid += 1
-        for i in range(n_bat):
-            r = mk(rid, 1.0 + i * 0.2, "bat", 24); rid += 1
-            if not only_interactive:
-                out.append(r)
-        for i in range(n_bg):
-            r = mk(rid, i * 1.5, "bg", 16); rid += 1
-            if not only_interactive:
-                out.append(r)
-        return out
-
-    def build_fleet(multitenant=True):
-        engines = [ServingEngine(model, params, ecfg)
-                   for model, params in models]
-        if not multitenant:
-            return ServingFleet(engines, seed=1,
-                                router=RouterConfig(),
-                                meshes=list(meshes))
-        return ServingFleet(
-            engines, seed=1,
-            router=RouterConfig(queue_cap=3),
-            meshes=list(meshes),
-            tenants=tenants,
-            brownout=BrownoutConfig(slo_ms=slo_brownout, window=2,
-                                    cooldown=3),
-        )
-
-    def drive(fleet, trace, max_ticks=2000):
-        fleet.submit_trace(trace)
-        prev = _config.fleet_seed()
-        _config.set_fleet_seed(fleet.seed)
-        try:
-            for _ in range(max_ticks):
-                if fleet.idle:
-                    break
-                fleet.tick()
-        finally:
-            _config.set_fleet_seed(prev)
-        return fleet.stats
-
-    wd_trips = []
-
-    def _guarded(run_fn):
-        if _rt_faults.active_plan() is None:
-            return run_fn()
-        deadline = float(_os.environ.get("TDTPU_BENCH_WATCHDOG",
-                                         "10.0"))
-        box = {}
-        try:
-            with _rt_watchdog.collective_watchdog(deadline=deadline):
-                box["out"] = run_fn()
-        except _rt_watchdog.WatchdogTimeout as e:
-            wd_trips.append(str(e).splitlines()[0])
-        finally:
-            _rt_watchdog.clear_trip()
-        return box.get("out")
-
-    # ---- (1) fault-free single-tenant oracle (run twice — the first
-    # pays every jit compile for the replica models)
-    plan = _rt_faults.active_plan()
-    _rt_faults.set_fault_plan(None)
-    try:
-        for _warm in (False, True):
-            oracle = build_fleet(multitenant=False)
-            drive(oracle, fresh_trace())
-    finally:
-        _rt_faults.set_fault_plan(plan)
-    ref_tokens = oracle.token_streams()
-    assert oracle.stats.lost_requests == 0, oracle.stats
-
-    # ---- (2) flood-free interactive-only baseline, SAME fault plan:
-    # the p99 the flood must not degrade
-    base = build_fleet()
-    base_stats = _guarded(
-        lambda: drive(base, fresh_trace(only_interactive=True)))
-    assert base_stats is not None, wd_trips
-    p99_free = base.per_tenant()["iact"]["p99_ttft_ticks"]
-
-    # ---- (3) the headline multi-tenant flood under the plan
-    fleet = build_fleet()
-    stats = _guarded(lambda: drive(fleet, fresh_trace()))
-    assert stats is not None, wd_trips
-
-    # ---- (4) same-seed replay: byte-identical event log
-    fleet2 = build_fleet()
-    stats2 = _guarded(lambda: drive(fleet2, fresh_trace()))
-    assert stats2 is not None, wd_trips
-    events_deterministic = list(stats.events) == list(stats2.events)
-
-    per_tenant = fleet.per_tenant()
-    p99_flood = per_tenant["iact"]["p99_ttft_ticks"]
-    tokens = fleet.token_streams()
-    mismatches = sum(
-        1 for rid, t in ref_tokens.items() if tokens.get(rid) != t
-    )
-    shed_tiers = [e[3].split("tier=")[1].split()[0]
-                  for e in stats.events if e[0] == "shed"]
-    bg_shed_first = ("batch" not in shed_tiers
-                     or "background" in
-                     shed_tiers[:shed_tiers.index("batch")])
-    leaked = sum(role.pool.held_pages
-                 for r in fleet._alive() for role in r._roles)
-
-    # the acceptance pins — loud here, and ci/fast.sh re-derives them
-    # from the JSON so the smoke exits nonzero on any regression
-    assert stats.lost_requests == 0, stats
-    assert mismatches == 0, (
-        f"{mismatches} admitted streams diverged from the fault-free "
-        "single-tenant oracle")
-    assert set(shed_tiers) <= {"background", "batch"}, shed_tiers
-    assert bg_shed_first, shed_tiers
-    assert fleet.preemptions > 0, "flood never forced a preemption"
-    assert leaked == 0, f"{leaked} pool pages leaked on live replicas"
-    assert p99_flood <= p99_free, (
-        f"interactive p99 degraded under flood: "
-        f"{p99_flood} > {p99_free}")
-
-    return {
-        "metric": "serving_multitenant",
-        "value": round(fleet.goodput_tok_per_s, 1),
-        "unit": "tok/s fleet goodput (modeled wall)",
-        "ticks": fleet.ticks,
-        "completed": stats.completed,
-        "lost_requests": stats.lost_requests,
-        "token_mismatches_vs_single_tenant_oracle": mismatches,
-        "interactive_p99_ttft_ticks_flood": p99_flood,
-        "interactive_p99_ttft_ticks_flood_free": p99_free,
-        "preemptions": fleet.preemptions,
-        "tenant_preemptions": fleet.tenant_preemptions(),
-        "sheds_by_tier": dict(stats.sheds),
-        "background_shed_before_batch": bg_shed_first,
-        "brownout_transitions": [
-            e[3] for e in stats.events if e[0] == "brownout"],
-        "pool_pages_leaked": leaked,
-        "deaths": stats.deaths,
-        "failover_requeued": stats.failover_requeued,
-        "admission_rejections": stats.admission_rejections,
-        "per_tenant": per_tenant,
-        "routed": {str(k): v for k, v in sorted(stats.routed.items())},
-        "event_log": [list(e) for e in stats.events[:24]],
-        "event_log_deterministic": events_deterministic,
-        "watchdog_trips": wd_trips,
-        "config": (
-            f"replicas={n_replicas}x{w} slots={ecfg.slots} "
-            f"budget={ecfg.token_budget} chunk={ecfg.chunk} "
-            f"page={ecfg.page} npages={ecfg.npages} "
-            f"trace={n_iact}iact+{n_bat}bat+{n_bg}bg queue_cap=3 "
-            f"slo_iact={slo_iact} brownout_slo={slo_brownout} "
-            f"window=2 cooldown=3 temp=0.7 top_k=40 fleet_seed=1 "
-            + ("tiny-dryrun" if tiny or not on_tpu else "headline")
+            f"poisson(seed=11) hidden={cfg.hidden} tiny-dryrun"
         ),
     }
 
@@ -3038,91 +1392,6 @@ def _bench_flash_decode(mesh, n, on_tpu, spec):
         "hbm_pct": round(100 * gbps / spec.hbm_gbps, 1),
         "int8_kv_us": round(t_q8 * 1e6, 1),
         "config": f"B={b} Hq={hq} Hkv={hkv} D={d} S={s_len} bf16 (+int8-KV twin)",
-    }
-
-
-def _bench_train_step(mesh, n, on_tpu, spec, tiny=False):
-    """TRAINING (ISSUE 14 acceptance): the dp2×tp2×cp2 train step on
-    the int8 EF gradient ring — CP ring attention over "cp", Megatron
-    MLP over "tp", the wire-quantized dp all-reduce — vs the
-    single-device dense reference and the exact psum twin. One row
-    reports: the ring's wire bytes vs the bf16 baseline (~2× down),
-    the final-loss delta against its pinned tolerance, and the EF
-    link-aggregate error strictly below the no-EF control."""
-    import numpy as _np
-
-    from jax.sharding import Mesh as _Mesh, PartitionSpec as _P
-
-    from triton_distributed_tpu import train
-    from triton_distributed_tpu.train import grad_wire, step as _stepmod
-
-    steps = 5 if tiny else 20
-    cfg = train.TrainConfig()
-    trainer = train.Trainer(cfg)
-    batches = [trainer.make_batch(k) for k in range(steps)]
-    t0 = time.perf_counter()
-    dist = [trainer.step(tok, tgt)["loss"] for tok, tgt in batches]
-    dt = time.perf_counter() - t0
-
-    params = _stepmod.init_params(cfg)
-    opt = _stepmod.init_opt_state(params)
-    ref = []
-    for tok, tgt in batches:
-        params, opt, loss = train.train_step_reference(
-            params, opt, tok, tgt, cfg)
-        ref.append(float(loss))
-    loss_tol = 0.05
-    delta = abs(dist[-1] - ref[-1])
-
-    # EF vs the no-EF control on the metric EF bounds: the
-    # link-aggregate (stripe-summed) reduce-scatter error (see
-    # train/grad_wire.py — per-element error is the SR noise floor
-    # either way)
-    nring, srows, cols = 4, 8, 128
-    ring_mesh = _Mesh(_np.asarray(jax.devices()[:nring]), ("x",))
-
-    def agg_err(ef):
-        errs = []
-        for seed in (0, 1, 2):
-            rng = _np.random.RandomState(seed)
-            x = rng.standard_normal(
-                (nring * nring * srows, cols)).astype(_np.float32)
-            exact = x.reshape(nring, nring * srows, cols).sum(axis=0)
-            fn = jax.shard_map(
-                lambda v: grad_wire.ef_ring_reduce_scatter(
-                    v, "x", n=nring, wire="int8", seed=seed + 7, ef=ef),
-                mesh=ring_mesh, in_specs=_P("x", None),
-                out_specs=_P("x", None), check_vma=False,
-            )
-            err = _np.asarray(jax.jit(fn)(x)) - exact
-            errs.append(
-                float(_np.abs(
-                    err.reshape(nring, srows, cols).sum(axis=0)).mean()))
-        return float(_np.mean(errs))
-
-    ef_err, ctl_err = agg_err(True), agg_err(False)
-    wires = trainer.wire_report()
-    ok = (delta < loss_tol and ef_err < ctl_err
-          and wires["ratio"] > 1.9)
-    return {
-        "metric": "train_step",
-        "value": round(dt / steps * 1e3, 2),
-        "unit": "ms/step",
-        "config": (f"dp{cfg.dp}×tp{cfg.tp}×cp{cfg.cp} "
-                   f"attn={cfg.attn} wire={trainer.wire} "
-                   f"microbatches={cfg.microbatches}"),
-        "steps": steps,
-        "final_loss": round(dist[-1], 6),
-        "final_loss_ref": round(ref[-1], 6),
-        "final_loss_delta": round(delta, 6),
-        "loss_tol": loss_tol,
-        "grad_ring_bytes": wires["wire_bytes"],
-        "grad_ring_bf16_bytes": wires["bf16_bytes"],
-        "grad_ring_byte_ratio": round(wires["ratio"], 3),
-        "ef_agg_err": round(ef_err, 6),
-        "no_ef_agg_err": round(ctl_err, 6),
-        "ef_below_control": ef_err < ctl_err,
-        "ok": ok,
     }
 
 

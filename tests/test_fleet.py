@@ -261,7 +261,8 @@ class TestCacheAwareRouting:
         assert scored.stats.lost_requests == 0
         assert rr.stats.lost_requests == 0
         assert scored.prefix_hits > rr.prefix_hits
-        assert scored.goodput_tok_per_s > 0
+        assert scored.stats.completed == len(trace())
+        assert scored.generated_tokens > 0
 
 
 # ------------------------------------------------------------ failover
@@ -607,6 +608,47 @@ class TestAutoscaler:
         kinds = [e[0] for e in stats.events]
         assert "grow" in kinds
         assert not fleet._reserve             # spare consumed
+
+    def test_grow_then_drain_onto_the_newcomer(self, fleet_models):
+        """The elastic composition (``ci/fast.sh``'s elastic smoke until
+        PR 48): a 1-replica fleet grows under queue pressure, the grown
+        replica earns admission through probation, THEN replica 0 is
+        drained onto it — nothing lost, and at least one live KV-page
+        migration priced cheaper than re-prefilling the same pages."""
+        from triton_distributed_tpu.serving import AutoscalerConfig
+
+        m0, p0 = fleet_models[0]
+        ledger = _fast_ledger()
+        fleet = ServingFleet(
+            [ServingEngine(m0, p0, EngineConfig(**ECFG),
+                           use_pallas=False)],
+            seed=3, health=ledger,
+            reserve=[_spare_factory(fleet_models)],
+            autoscaler=AutoscalerConfig(slo_ms=0.0, window=2,
+                                        cooldown=50, max_replicas=2))
+        trace = [_req(i, i * 0.5, plen=12, max_new=5)
+                 for i in range(18)]
+        config.set_fleet_seed(fleet.seed)
+        fleet.submit_trace(trace)
+        drained = False
+        for _ in range(500):
+            if fleet.idle:
+                break
+            if (not drained and fleet.stats.grows
+                    and ledger.state("replica:1") is PeerState.HEALTHY
+                    and 1 in fleet.rotation()
+                    and fleet.replicas[0].held()):
+                fleet.drain(0)
+                drained = True
+            fleet.tick()
+        st = fleet.stats
+        assert st.lost_requests == 0
+        assert st.completed == len(trace)
+        assert len(st.grows) == 1
+        assert drained and len(st.drains) == 1
+        assert st.migrations >= 1
+        assert st.migrations_cheaper >= 1, st.migration_priced
+        assert fleet.rotation() == (1,)
 
     def test_grow_without_reserve_refused(self, fleet_models):
         fleet = _fleet(fleet_models)

@@ -29,6 +29,7 @@ All sim-free: host-side scheduling over the engines' CPU (XLA) paths.
 """
 
 import gc
+from contextlib import nullcontext
 
 import numpy as np
 import pytest
@@ -592,25 +593,40 @@ class TestBrownout:
                 assert role.throttled_tiers == frozenset()
         _assert_no_leaks(fleet)
 
-    def test_interactive_p99_protected_under_flood(self, fleet_models):
+    @pytest.mark.parametrize("death", [False, True],
+                             ids=["no_fault", "replica_death"])
+    def test_interactive_p99_protected_under_flood(self, fleet_models,
+                                                   death):
         """The acceptance pin in miniature: interactive p99 TTFT under
         a batch flood (brownout armed) is no worse than without the
-        flood."""
-        base = _fleet(fleet_models, tenants=dict(TEN), queue_cap=3,
-                      brownout=BrownoutConfig(slo_ms=0.004, window=2,
-                                              cooldown=3))
-        base.run(_mixed_trace(n_bat=0, n_bg=0), max_ticks=800)
-        p99_free = base.stats.per_tenant()["iact"]["p99_ttft_ticks"]
+        flood — also when replica 1 dies at step 8 of BOTH runs (the
+        composition ``ci/fast.sh``'s multi-tenant smoke held until
+        PR 48): sheds land on background / batch only, nothing is lost,
+        no page leaks."""
+        plan = FaultPlan(
+            seed=1, faults=(ReplicaDeath(replica=1, step=8),)
+        ) if death else None
 
-        fleet = _fleet(fleet_models, tenants=dict(TEN), queue_cap=3,
-                       brownout=BrownoutConfig(slo_ms=0.004, window=2,
-                                               cooldown=3))
-        st = fleet.run(_mixed_trace(n_bat=24, n_bg=6), max_ticks=800)
-        assert st.lost_requests == 0
+        def run(trace):
+            fleet = _fleet(fleet_models, tenants=dict(TEN), queue_cap=3,
+                           brownout=BrownoutConfig(slo_ms=0.004,
+                                                   window=2, cooldown=3))
+            with faults.fault_plan(plan) if plan else nullcontext():
+                st = fleet.run(trace, max_ticks=800)
+            assert st.lost_requests == 0
+            assert st.deaths == ([(1, 8)] if death else [])
+            return fleet
+
+        base = run(_mixed_trace(n_bat=0, n_bg=0))
+        p99_free = base.per_tenant()["iact"]["p99_ttft_ticks"]
+        fleet = run(_mixed_trace(n_bat=24, n_bg=6))
         p99_flood = fleet.per_tenant()["iact"]["p99_ttft_ticks"]
         assert p99_flood <= p99_free, (
             f"interactive p99 degraded under flood: "
             f"{p99_flood} > {p99_free}")
+        assert set(fleet.stats.sheds) <= {"background", "batch"}
+        assert sum(fleet.stats.sheds.values()) >= 1
+        _assert_no_leaks(fleet)
 
 
 # ------------------------------------- drain × preemption interplay

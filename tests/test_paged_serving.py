@@ -1,5 +1,10 @@
-"""``bench.py``'s donate-and-thread runner: the LL persistent-workspace
-contract its timing loops rest on."""
+"""The donate-and-thread runner of ``bench.py`` (the repository root's
+kernel-alone timer file; ``_make_donating_runner``, which ``bench_loop``
+uses under ``donate_idx``): the LL persistent-workspace contract its
+timing loops rest on. This is the runner's only guard — nothing else
+imports ``bench.py``, and no tier-1 test or benchmark cell runs its
+timers — so a change to the runner that hands a step fresh buffers
+fails here and nowhere else."""
 
 import jax.numpy as jnp
 

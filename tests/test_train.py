@@ -223,6 +223,25 @@ class TestTrainStep:
         assert a == b
 
 
+# -------------------------------------- the families the step launches
+
+
+@pytest.mark.parametrize("fam", train.TRAIN_ENGINE_FAMILIES)
+def test_train_family_registered_lint_clean_with_fallback(fam):
+    """The ``bench.py --lint`` train gate, in tier-1 (it was an inline
+    block of ``ci/fast.sh`` until PR 48): every family the train step
+    launches is registered, lints clean at mesh 8 and declares a
+    degradation target that resolves — the ledger's demotion (wire ring
+    → exact psum twin) needs somewhere to go."""
+    from triton_distributed_tpu.analysis.lint import lint_family
+    from triton_distributed_tpu.kernels.registry import (
+        missing_degradation_targets,
+    )
+
+    assert lint_family(fam, n=8) == []
+    assert fam not in {f for f, _ in missing_degradation_targets()}
+
+
 # ------------------------------------------------- chaos + probation
 
 

@@ -2,7 +2,7 @@
 per-chunk scales.
 
 The MoE A2A transport already proved out fp8+scales wire compression
-(kernels/moe_all_to_all.py, BENCH_r05 "fp8+scales fused-chunked-dma");
+(kernels/moe_all_to_all.py; docs/PERF.md, "fp8+scales fused-chunked-dma");
 this module generalizes the idea to the AG/RS streaming rings so the
 fused TP engines (ag_gemm, gemm_rs, the moe_tp_fused pair) and the
 standalone ring collectives can move 1-byte slabs on comm-bound shapes

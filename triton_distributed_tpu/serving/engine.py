@@ -444,36 +444,6 @@ class EngineStats:
         return [k for k, _ in ranked[:max(0, int(top))]]
 
     @property
-    def total_time(self) -> float:
-        return float(sum(self.step_times))
-
-    @property
-    def sustained_tok_per_s(self) -> float:
-        t = self.total_time
-        return (sum(self.step_tokens) / t) if t > 0 else 0.0
-
-    @property
-    def goodput_tok_per_s(self) -> float:
-        """GENERATED tokens of completed requests per wall second — the
-        metric padding cannot inflate (prefill re-computation after an
-        eviction, padded rectangle slots, and abandoned work all count
-        against it)."""
-        t = self.total_time
-        return (self.generated_tokens / t) if t > 0 else 0.0
-
-    @property
-    def p99_step_ms(self) -> float:
-        if not self.step_times:
-            return 0.0
-        return float(np.percentile(np.asarray(self.step_times), 99) * 1e3)
-
-    @property
-    def p50_step_ms(self) -> float:
-        if not self.step_times:
-            return 0.0
-        return float(np.percentile(np.asarray(self.step_times), 50) * 1e3)
-
-    @property
     def accepted_tokens_per_step(self) -> float:
         """Tokens a speculative verify row emits per engine step it
         runs in — the speculation multiplier. Every verify row emits at
@@ -495,33 +465,6 @@ class EngineStats:
         """k -> verify-row count under the adaptive drafter, ascending
         k — shows where the per-request budget actually settled."""
         return dict(sorted(self.adaptive_k_rows.items()))
-
-    @property
-    def decode_p99_step_ms(self) -> float:
-        """p99 over the steps that generated at least one token — the
-        latency a decoding request actually observes. In a colocated
-        engine these steps carry interleaved prefill chunks (the
-        contention disaggregation removes); in a decode-role engine
-        every step qualifies."""
-        ts = [
-            t for t, g in zip(self.step_times, self.step_generated)
-            if g > 0
-        ]
-        if not ts:
-            return 0.0
-        return float(np.percentile(np.asarray(ts), 99) * 1e3)
-
-    @property
-    def decode_p50_step_ms(self) -> float:
-        """Median of the token-generating steps — the speculative
-        bench's headline pair with :attr:`decode_p99_step_ms`."""
-        ts = [
-            t for t, g in zip(self.step_times, self.step_generated)
-            if g > 0
-        ]
-        if not ts:
-            return 0.0
-        return float(np.percentile(np.asarray(ts), 50) * 1e3)
 
 
 def poisson_trace(seed: int, n_requests: int, mean_interarrival: float,
@@ -2131,15 +2074,6 @@ class DisaggStats:
     @property
     def completed(self) -> int:
         return self.decode.completed
-
-    @property
-    def goodput_tok_per_s(self) -> float:
-        t = max(self.prefill.total_time, self.decode.total_time)
-        return (self.decode.generated_tokens / t) if t > 0 else 0.0
-
-    @property
-    def decode_p99_step_ms(self) -> float:
-        return self.decode.decode_p99_step_ms
 
     @property
     def wire_compression(self) -> float:

@@ -1732,18 +1732,6 @@ class ServingFleet:
         return sum(r["n"] for r in self.stats.records.values()
                    if r["completion_tick"] is not None)
 
-    @property
-    def goodput_tok_per_s(self) -> float:
-        """Generated tokens of completed requests per MODELED wall
-        second, where fleet wall = the SLOWEST replica's accumulated
-        perf-model step time (replicas run concurrently on their own
-        slices). Modeled, not measured: deterministic across runs, and
-        it credits compute the router actually avoided — a prefix hit
-        skips prefill chunks the model would otherwise bill. The
-        measured host wall lives in ``stats.replica_time``."""
-        wall = max(self.stats.replica_model_ms.values(), default=0.0)
-        return self.generated_tokens / (wall / 1e3) if wall > 0 else 0.0
-
     def token_streams(self) -> dict:
         """rid -> completed token list (None while incomplete) — what
         the bench diffs against the fault-free reference run."""
