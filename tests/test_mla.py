@@ -527,7 +527,7 @@ def test_every_kind_of_state_answers_every_feature_from_one_table():
     would be SERVED, and none is today)."""
     features = {"prefix_cache", "speculation", "prefill_only",
                 "gather_pages", "disaggregated"}
-    assert set(REFUSED) == {"window", "recurrent", "latent"}
+    assert set(REFUSED) == {"window", "recurrent", "latent", "index"}
     for kind, row in REFUSED.items():
         assert set(row) == features, kind
         assert all("{what}" in why for why in row.values())

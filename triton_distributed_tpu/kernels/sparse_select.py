@@ -114,20 +114,16 @@ def forced_blocks(pos, n_blocks: int, *, block, init_blocks, window):
     return (b < init_blocks) | ((b >= lo) & (b <= pos[:, None] // block))
 
 
-def choose_blocks(scores, forced, topk: int):
-    """``scores`` (..., NB) with ``forced`` (..., NB) -> bool (..., NB):
-    the ``topk`` largest with the forced ones at +inf, ties to the
-    lower block."""
-    n_blocks = scores.shape[-1]
-    if topk >= n_blocks:
-        return jnp.ones(scores.shape, bool)
+def kth_largest_key(scores, topk: int):
+    """``scores`` (..., N) float32 -> ``(key (..., N) uint32, kth (...,
+    1) uint32)``: keys whose unsigned order is the floats', and the
+    ``topk``-th largest of them (``topk < N``)."""
     # ``lax.top_k`` lowers to a full sort of every row (6 of the 9 ms a
     # chunk step's selection took on the v5e). The chosen SET needs only
     # the topk-th largest value: found exactly, four bits a pass, on keys
     # whose unsigned order is the floats' (``+ 0.0``: no negative zero)
     bits = jax.lax.bitcast_convert_type(
-        jnp.where(forced, jnp.inf, scores).astype(jnp.float32) + 0.0,
-        jnp.uint32)
+        scores.astype(jnp.float32) + 0.0, jnp.uint32)
     sign = jnp.uint32(1 << 31)
     key = jnp.where(bits >= sign, ~bits, bits | sign)
     # kth: the largest value that ``topk`` keys reach. A pass tries the
@@ -140,6 +136,17 @@ def choose_blocks(scores, forced, topk: int):
                         dtype=jnp.int32)                     # (..., 15)
         digit = jnp.sum(reach >= topk, axis=-1, keepdims=True)
         kth = kth | (digit.astype(jnp.uint32) << shift)
+    return key, kth
+
+
+def choose_blocks(scores, forced, topk: int):
+    """``scores`` (..., NB) with ``forced`` (..., NB) -> bool (..., NB):
+    the ``topk`` largest with the forced ones at +inf, ties to the
+    lower block."""
+    n_blocks = scores.shape[-1]
+    if topk >= n_blocks:
+        return jnp.ones(scores.shape, bool)
+    key, kth = kth_largest_key(jnp.where(forced, jnp.inf, scores), topk)
     above = key > kth
     tie = key == kth
     # ties to the lower block: a tie's rank among the ties, as a product

@@ -242,6 +242,36 @@ def solar_open2(**overrides) -> TransformerConfig:
     return TransformerConfig(**cfg)
 
 
+def keye_vl2_30b(**overrides) -> TransformerConfig:
+    """Keye-VL-2.0-30B-A3B's language model (Kwai-Keye, ``model_type:
+    KeyeVL2``) as published: 48 identical layers, hidden 2048, GQA 32
+    query / 4 KV heads x 128 with q/k RMS norm, rotation at theta 1e7
+    (M-RoPE, ``mrope_section`` [16, 24, 24]: on text its three position
+    components are equal and the rotation is the ordinary one over all
+    64 frequency pairs), and on every layer ``sa_config``'s token-level
+    selection: an indexer of 16 heads x 64 with one key head keeps each
+    query's 2048 best cached tokens; every layer sparse: 128 experts of
+    width 768, top-8 of the softmax renormalised, no shared expert;
+    vocabulary 151936, untied head. Its vision tower is not part of
+    this forward pass and not stated here.
+
+    The cut is three integers: ``n_layers``, ``experts_held`` (with
+    ``first_expert_held``) one chip's share of an expert-parallel
+    layer, and ``vocab`` its slice of the vocabulary."""
+    n = int(overrides.get("n_layers", 48))
+    cfg = dict(
+        vocab=151936, n_layers=n, hidden=2048, ffn=768,
+        n_heads=32, n_kv_heads=4, head_dim=128, norm_eps=1e-6,
+        rope_theta=1e7, rope_layers=tuple(range(n)), qk_norm=True,
+        index_heads=16, index_dim=64, index_topk=2048,
+        gated_ffn=True,
+        moe="ep", moe_layers=tuple(range(n)), num_experts=128, topk=8,
+        dtype=jnp.bfloat16,
+    )
+    cfg.update(overrides)
+    return TransformerConfig(**cfg)
+
+
 def tiny(preset=None, **overrides) -> TransformerConfig:
     """CI-sized twin: same topology knobs as ``preset`` (or dense
     defaults), tiny dims — what the tests and the driver dryrun use."""
@@ -289,6 +319,11 @@ def tiny(preset=None, **overrides) -> TransformerConfig:
             sparse_window=min(preset.sparse_window, 16),
             sparse_topk=min(preset.sparse_topk, 4),
             sparse_dense_len=min(preset.sparse_dense_len, 32),
+            # the token selection of PR 49 at the twin's sizes: an
+            # indexer of two heads of 8 that keeps 8 tokens
+            index_heads=min(preset.index_heads, 2),
+            index_dim=min(preset.index_dim, 8),
+            index_topk=min(preset.index_topk, 8),
             out_gate=preset.out_gate,
             out_norm=preset.out_norm,
             # the gated delta-rule layers of PR 41 at the twin's sizes:

@@ -187,6 +187,20 @@ class TransformerConfig:
     sparse_window: int = 0
     sparse_topk: int = 0
     sparse_dense_len: int = 0
+    # TOKEN-LEVEL learned selection on the attention layers
+    # (``index_topk`` 0 = none; a selection kind beside the block one,
+    # not a mixer): an INDEXER of ``index_heads`` query heads of
+    # ``index_dim`` and ONE key head scores every cached token, I[t, s]
+    # = sum_j w[t, j] relu(qI[t, j] . kI[s] / sqrt(index_dim)), and a
+    # query at position t >= index_topk attends its ``index_topk``
+    # best keys s <= t (ties to the lower s; every key before that), one
+    # choice for all heads. qI, kI (layer-normed) and w come from the
+    # layer's normed input through ``w_index``; qI and kI are rotated at
+    # ``rope_theta`` over all ``index_dim``; the keys live in a pool
+    # beside K/V (kernels/token_select.py has the lines)
+    index_heads: int = 0
+    index_dim: int = 0
+    index_topk: int = 0
     # sigmoid output gate on every mixer: out = (o * sigmoid(a Wz)) Wo
     # (parameter ``wz``), and on lightning layers an RMS norm of o over
     # each head before it (gain ``norm_o``, (head_dim,))
@@ -418,9 +432,37 @@ class TransformerConfig:
             raise ValueError(
                 f"{', '.join(f for f in sparse if getattr(self, f))} "
                 "without sparse_topk")
+        index = ("index_heads", "index_dim", "index_topk")
+        if self.index_topk:
+            if min(getattr(self, f) for f in index) < 1 \
+                    or self.index_dim % 2 or self.rope_theta <= 0:
+                raise ValueError(
+                    f"index_topk={self.index_topk} needs {', '.join(index)} "
+                    ">= 1, an even index_dim and rope_theta > 0 (the "
+                    "indexer's rotation; got "
+                    f"{[getattr(self, f) for f in index]}, rope_theta="
+                    f"{self.rope_theta})")
+            beside = [k for k, on in (
+                ("block-sparse attention (sparse_topk)",
+                 self.sparse_topk > 0),
+                ("recurrent layers (layer_mixer)",
+                 bool(self.recurrent_layers)),
+                ("kv_latent", self.kv_latent > 0),
+                ("out_gate", self.out_gate),
+            ) if on]
+            if beside:
+                raise ValueError(
+                    f"index_topk={self.index_topk} with "
+                    f"{', '.join(beside)}: a token selection is built "
+                    "over the K/V pools of plain GQA layers only")
+        elif any(getattr(self, f) for f in index):
+            raise ValueError(
+                f"{', '.join(f for f in index if getattr(self, f))} "
+                "without index_topk")
         stateful = [k for k, on in (
             ("layer_mixer", bool(self.recurrent_layers)),
-            ("sparse_topk", self.sparse_topk > 0)) if on]
+            ("sparse_topk", self.sparse_topk > 0),
+            ("index_topk", self.index_topk > 0)) if on]
         if stateful and self.window_layers:
             raise ValueError(
                 f"{', '.join(stateful)} with sliding-window layers "
@@ -428,8 +470,8 @@ class TransformerConfig:
         if stateful and self.kv_quant is not None:
             raise ValueError(
                 f"{', '.join(stateful)} with kv_quant={self.kv_quant!r}: "
-                "compressed keys and the selected walk are built over "
-                "bf16 pools only")
+                "compressed keys, indexer keys and the selected walks are "
+                "built over bf16 pools only")
         if stateful and self.attn != "tp":
             raise ValueError(
                 f"{', '.join(stateful)} with attn={self.attn!r}: built "
@@ -536,6 +578,22 @@ class TransformerConfig:
         return tuple(i for i in range(self.n_layers)
                      if i not in self.lightning_layers)
 
+    @property
+    def index_width(self) -> int:
+        """Columns of ``w_index``: the indexer's queries, its one key
+        and its head weights, side by side."""
+        return (self.index_heads + 1) * self.index_dim + self.index_heads
+
+    @property
+    def index_stored(self) -> int:
+        """Values the indexer-key pool STORES a token and layer
+        (``kernels/token_select.py::index_stored``)."""
+        from triton_distributed_tpu.kernels.token_select import (
+            index_stored,
+        )
+
+        return index_stored(self.index_dim)
+
     def layer_heads(self, i: int) -> tuple:
         """``(query heads, key/value heads)`` of layer ``i``'s mixer."""
         if i in self.lightning_layers:
@@ -580,6 +638,7 @@ class TransformerConfig:
             ("experts_held", self.experts_held > 0),
             ("layer_mixer", bool(self.recurrent_layers)),
             ("sparse_topk", self.sparse_topk > 0),
+            ("index_topk", self.index_topk > 0),
             ("out_gate", self.out_gate),
             ("out_norm", self.out_norm),
             ("embed_scale", self.embed_scale != 1.0),
@@ -738,14 +797,15 @@ class Transformer:
             ("lightning layers (layer_mixer)", bool(c.lightning_layers)),
             ("kda layers (layer_mixer)", bool(c.kda_layers)),
             ("block-sparse attention (sparse_topk)", c.sparse_topk > 0),
+            ("a token selection (index_topk)", c.index_topk > 0),
         ) if on]
         for axis, n in (("tp", self.tp), ("cp", self.cp)):
             if stateful and n > 1:
                 raise ValueError(
                     f"{', '.join(stateful)} with {axis}={n}: the "
-                    "recurrent state, the compressed keys and the "
-                    "selection are one chip's; sharding them is not "
-                    "built")
+                    "recurrent state, the compressed keys, the indexer's "
+                    "keys and the selection are one chip's; sharding "
+                    "them is not built")
             if c.kv_latent and n > 1:
                 raise ValueError(
                     f"a latent pool (kv_latent) with {axis}={n}: the "
@@ -988,6 +1048,11 @@ class Transformer:
             if c.qk_norm:
                 blk["norm_q"] = jnp.ones((c.head_dim,), pd)
                 blk["norm_k"] = jnp.ones((c.head_dim,), pd)
+            if c.index_topk:
+                # the indexer: [queries | key | head weights] as ONE
+                # matrix, and the gain of its key's layer norm
+                blk["w_index"] = dense(next(keys), (c.hidden, c.index_width))
+                blk["norm_ki"] = jnp.ones((c.index_dim,), pd)
             if c.out_gate and i in c.kda_layers:
                 # a kda layer's gate goes through a rank-kda_rank pair
                 blk["wg_down"] = dense(next(keys), (c.hidden, c.kda_rank))
@@ -1229,6 +1294,8 @@ class Transformer:
             }
             if c.qk_norm:
                 blk.update(norm_q=rep, norm_k=rep)
+            if c.index_topk:
+                blk.update(w_index=rep, norm_ki=rep)
             if i in c.kda_layers:
                 # one chip's (tp > 1 is refused): every leaf whole
                 blk.update(dict.fromkeys(
@@ -1618,6 +1685,10 @@ class Transformer:
                 f"sparse_block={c.sparse_block} and sparse_stride="
                 f"{c.sparse_stride} divide and that divides "
                 f"sparse_dense_len={c.sparse_dense_len}, got page={page}")
+        if c.index_topk and c.index_topk % page:
+            raise ValueError(
+                f"a token selection (index_topk) needs a page that "
+                f"divides index_topk={c.index_topk}, got page={page}")
         # a latent pool: ONE entry a token and layer for all the heads,
         # ``(npages, 1, page, latent_stored)``, addressed by the same
         # block table; no V pool (serving/state.py)
@@ -1651,6 +1722,16 @@ class Transformer:
             layers = tuple(
                 None if i in c.recurrent_layers else (full(), full())
                 for i in range(c.n_layers))
+        elif c.index_topk:
+            # every layer a pool of indexer keys beside K/V: ONE entry a
+            # token for all the heads, ``(npages, 1, page,
+            # index_stored)``, addressed by the same block table
+            ckeys = tuple(
+                jax.device_put(
+                    jnp.zeros((npages, 1, page, c.index_stored), c.dtype),
+                    spec)
+                for _ in range(c.n_layers))
+            layers = tuple((full(), full()) for _ in range(c.n_layers))
         elif windowed:
             # a window layer keeps slots · ring pages and no more
             ringed = pools(slots * ring)
@@ -1696,17 +1777,18 @@ class Transformer:
             n_bufs=n_bufs, with_lse=with_lse, window=window,
         )
 
-    def _rope_tables(self, token_pos):
+    def _rope_tables(self, token_pos, dim=None):
         """``(cos, sin)``, each (T, 1, rotated dims) float32, of the
         packed tokens' sequence positions (padding tokens: position 0),
-        the two halves alike (rotate-half): over ``head_dim`` at
-        ``config.rope_theta``, or, in a latent model, over
-        ``qk_rope_dim`` at the (YaRN) ``config.yarn_inv_freq``."""
+        the two halves alike (rotate-half): over ``head_dim`` (or
+        ``dim``: an indexer's heads) at ``config.rope_theta``, or, in a
+        latent model, over ``qk_rope_dim`` at the (YaRN)
+        ``config.yarn_inv_freq``."""
         c = self.config
         if c.kv_latent:
             inv_freq = jnp.asarray(c.yarn_inv_freq)
         else:
-            half = c.head_dim // 2
+            half = (dim or c.head_dim) // 2
             inv_freq = c.rope_theta ** (
                 -jnp.arange(half, dtype=jnp.float32) / half)
         ang = jnp.maximum(token_pos, 0).astype(jnp.float32)[:, None] \
@@ -2002,12 +2084,15 @@ class Transformer:
     def _attention_mix(self, li, q, k, v, pools, state, kv_shape,
                        append_global, append_ring, token_rows, token_pos,
                        q_lens, q_starts, topologies, block_q, use_pallas,
-                       n_bufs, new_ckeys):
+                       n_bufs, new_ckeys, index=None):
         """A softmax-attention layer of the packed step: append the
         step's K/V to the layer's pools, then attend through them:
-        full causal, sliding-window over a ring (``append_ring``), or
-        block-sparse over the selected pages (``config.sparse_layers``).
-        Returns ``(o (T, q_dim), k_pool, v_pool)``."""
+        full causal, sliding-window over a ring (``append_ring``),
+        block-sparse over the selected pages (``config.sparse_layers``)
+        or over the tokens an indexer keeps (``index``: its ``(queries,
+        head weights, key entries, append)`` of the step, under
+        ``config.index_topk``). Returns ``(o (T, q_dim), k_pool,
+        v_pool)``."""
         from triton_distributed_tpu.kernels.ragged_paged_attention import (
             pack_gqa_rows,
             unpack_gqa_rows,
@@ -2054,6 +2139,12 @@ class Transformer:
                     state.ckeys[li], kp, state.block_table, state.kv_lens,
                     q_lens, kernel=c.sparse_kernel, stride=c.sparse_stride,
                     block_q=block_q)
+            if index is not None:
+                # the indexer's keys of the step's tokens, beside their
+                # K/V: same table, same rows
+                qi, wi, entry, append_index = index
+                new_ckeys[li] = append_index(
+                    state.ckeys[li], entry.astype(state.ckeys[li].dtype))
         with scope("attn"):
             qp = pack_gqa_rows(
                 q.reshape(t, c.n_heads, c.head_dim), c.n_kv_heads
@@ -2063,6 +2154,10 @@ class Transformer:
                     qp, q, kp, vp, new_ckeys[li], state, token_rows,
                     token_pos, q_lens, q_starts, block_q, use_pallas,
                     n_bufs)
+            elif index is not None:
+                o = self._token_selected_attn(
+                    qp, qi, wi, kp, vp, new_ckeys[li], state, token_rows,
+                    token_pos, q_lens, q_starts, block_q, use_pallas)
             elif is_window:
                 # the ring table in the block table's place, and
                 # the walk bounded below by the window
@@ -2120,6 +2215,77 @@ class Transformer:
             o, _ = ragged_paged_attention_xla(
                 qp, kp, vp, state.kv_lens, q_lens, q_starts,
                 state.block_table, **kw)
+        return o
+
+    def _index_proj(self, blk, xn, rope):
+        """The indexer's projections of normed rows ``xn`` (T, H),
+        scope ``dsa_index_proj``: ``(qI (T, index_heads, index_dim), w
+        (T, index_heads) float32, entry (T, 1, index_stored))``. qI
+        and the key are rotated to their token's position (``rope``:
+        the step's tables over ``index_dim``), the key layer-normed
+        first; ``w`` carries ``index_heads^-0.5``; ``entry`` is what
+        the pool caches, [key | zeros]. One product in float32, queries
+        and key then in the compute dtype."""
+        c = self.config
+        t = xn.shape[0]
+        j, di = c.index_heads, c.index_dim
+        with jax.named_scope("dsa_index_proj"):
+            y = jnp.dot(xn, blk["w_index"].astype(c.dtype),
+                        preferred_element_type=jnp.float32)
+            qi = y[:, :j * di].reshape(t, j, di)
+            ki = y[:, j * di:(j + 1) * di]
+            w = y[:, (j + 1) * di:] * j ** -0.5
+            ki = ki - jnp.mean(ki, axis=-1, keepdims=True)
+            ki = ki * jax.lax.rsqrt(
+                jnp.mean(ki * ki, axis=-1, keepdims=True) + c.norm_eps
+            ) * blk["norm_ki"].astype(jnp.float32)
+            qi = _rotate_half(qi, *rope).astype(c.dtype)
+            ki = _rotate_half(ki[:, None, :], *rope).astype(c.dtype)
+            entry = jnp.pad(
+                ki, ((0, 0), (0, 0), (0, c.index_stored - di)))
+        return qi, w, entry
+
+    def _token_selected_attn(self, qp, qi, wi, kp, vp, ki_pool, state,
+                             token_rows, token_pos, q_lens, q_starts,
+                             block_q, use_pallas):
+        """Attention of one layer over the tokens its indexer keeps:
+        score every cached key of every batched row (scope
+        ``dsa_scan``), keep each query position's ``index_topk`` best
+        (``dsa_select``), walk the kept tokens (``dsa_walk``): the
+        kernels of ``kernels/token_select.py`` or their XLA twins."""
+        from triton_distributed_tpu.kernels import token_select as ts
+
+        c = self.config
+        scope = jax.named_scope
+        g = c.n_heads // c.n_kv_heads
+        scale = c.index_dim ** -0.5
+        with scope("dsa_scan"):
+            if use_pallas:
+                scores = ts.index_scores(
+                    qi, wi, ki_pool, state.kv_lens, q_lens, q_starts,
+                    state.block_table, topk=c.index_topk, block_q=block_q,
+                    scale=scale)
+            else:
+                scores = ts.index_scores_xla(
+                    qi, wi, ki_pool, token_rows, state.block_table,
+                    scale=scale)
+        with scope("dsa_select"):
+            words = ts.select_tokens(
+                scores, token_rows, token_pos, state.kv_lens, q_lens,
+                q_starts, page=state.page, pps=state.pages_per_seq,
+                topk=c.index_topk)
+        with scope("dsa_walk"):
+            if use_pallas:
+                o = ts.token_walk(
+                    qp, kp, vp, words, state.kv_lens, q_lens, q_starts,
+                    state.block_table, group=g, block_q=block_q)
+                # (a padding token's rows are written by no block)
+                live = jnp.repeat(token_pos >= 0, g)
+                o = jnp.where(live[None, :, None], o, 0)
+            else:
+                o = ts.token_walk_xla(
+                    qp, kp, vp, words, token_rows, state.block_table,
+                    group=g)
         return o
 
     def kv_append_by_kernel(self, use_pallas: bool) -> bool:
@@ -2398,9 +2564,18 @@ class Transformer:
             if windowed:
                 _, append_ring = appender(
                     state.ring_table, state.slots * state.ring)
+            if c.index_topk:
+                # the indexer keys' pool: one entry a token, the latent
+                # pool's one-stream append by the block table
+                append_index = self._latent_appender(
+                    state, token_rows, token_pos, q_starts, q_lens,
+                    use_pallas)
         if c.rope_layers or c.kv_latent:
             with scope("attn_proj"), scope("qk_rope"):
                 rope = self._rope_tables(token_pos)
+        if c.index_topk:
+            with scope("attn_proj"), scope("dsa_index_proj"):
+                index_rope = self._rope_tables(token_pos, c.index_dim)
 
         new_layers = []
         new_states = None if moe_state is None else list(moe_state)
@@ -2454,6 +2629,16 @@ class Transformer:
                         o, new_recurrent[li] = self._lightning_mix(
                             q, k, v, state, li, q_lens, q_starts, block_q,
                             use_pallas)
+                elif c.index_topk:
+                    with scope("attn_proj"):
+                        index = self._index_proj(blk, xn, index_rope) + (
+                            append_index,)
+                    o, kp, vp = self._attention_mix(
+                        li, q, k, v, pools, state, kv_shape, append_global,
+                        None, token_rows, token_pos, q_lens, q_starts,
+                        topologies, block_q, use_pallas, n_bufs, new_ckeys,
+                        index=index)
+                    new_layers.append((kp, vp))
                 else:
                     o, kp, vp = self._attention_mix(
                         li, q, k, v, pools, state, kv_shape, append_global,

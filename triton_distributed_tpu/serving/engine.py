@@ -344,6 +344,18 @@ class EngineStats:
     # a time, more the chunk form: kernels/kda_attention.py)
     kda_rows: int = 0
     kda_chunk_rows: int = 0
+    # the work of a model with a token selection (``index_topk``; 0
+    # without one), per layer, summed over the batched rows of the
+    # device steps, counted in ``_assemble``: rows through the token
+    # walk, those of them whose last position is past ``index_topk``
+    # (their tokens are kept by score), the indexer keys those rows'
+    # contexts hold (what ONE layer's scan reads), and the (query
+    # position, key) pairs the walk attends (``min(position + 1,
+    # index_topk)`` a query position)
+    dsa_rows: int = 0
+    dsa_sparse_rows: int = 0
+    index_keys_scanned: int = 0
+    dsa_selected_tokens: int = 0
     # the work of a model with a LATENT pool (``kv_latent``; 0 without
     # one), summed over the batched rows of the device steps, counted
     # in ``_assemble``: pages ONE layer's latent walk fetches (a decode
@@ -631,6 +643,26 @@ REFUSED = {
             "DisaggregatedEngine with {what}: kv_ship ships pages, not "
             "the recurrent state or the compressed keys",
     },
+    "index": {
+        "prefix_cache":
+            "{what} with prefix_cache / prefix_share: the token walk "
+            "reads CAUSAL rows only (no SHARED_PREFIX topology) and a "
+            "page of indexer keys under a second owner is not tested",
+        "speculation":
+            "{what} under SpeculativeEngine: the token walk has no "
+            "verify-tree topology (a draft token's selection is its "
+            "own)",
+        "prefill_only":
+            "{what} with prefill_only (the prefill role of "
+            "DisaggregatedEngine): kv_ship ships K and V pages, not "
+            "the indexer's keys",
+        "gather_pages":
+            "kv_ship / page migration with {what}: K and V pages "
+            "ship, the indexer's keys do not",
+        "disaggregated":
+            "DisaggregatedEngine with {what}: kv_ship ships K and V "
+            "pages, not the indexer's keys",
+    },
     "latent": {
         "prefix_cache":
             "{what} with prefix_cache / prefix_share: the latent walk "
@@ -669,6 +701,9 @@ def state_kinds(mc) -> dict:
         kinds["recurrent"] = ", ".join(stateful)
     if mc.kv_latent:
         kinds["latent"] = "a latent pool (kv_latent)"
+    if mc.index_topk:
+        kinds["index"] = ("a token selection (index_topk: a pool of "
+                          "indexer keys beside K/V)")
     return kinds
 
 
@@ -824,6 +859,8 @@ class ServingEngine:
         self._selected_work = [0, 0]
         self._latent_work = [0, 0]      # likewise: [pages fetched, rows]
         self._kda_work = [0, 0]         # likewise: [rows, chunk rows]
+        # likewise: [rows, rows past index_topk, keys scanned, pairs]
+        self._dsa_work = [0, 0, 0, 0]
         # seconds of the running step inside each phase (``_phase``)
         self._phase_s = dict.fromkeys(PHASES, 0.0)
         # --- multi-tenancy (all defaults reproduce the single-tenant
@@ -1319,6 +1356,7 @@ class ServingEngine:
         self._selected_work = [0, 0]    # [rows, rows of one token]
         self._latent_work = [0, 0]      # [pages fetched, rows]
         self._kda_work = [0, 0]         # [rows, rows of several tokens]
+        self._dsa_work = [0, 0, 0, 0]
         mc = self.model.config
         batched: set = set()
         takes: dict = {}
@@ -1374,6 +1412,15 @@ class ServingEngine:
                 if mc.kda_layers:
                     self._kda_work[0] += 1
                     self._kda_work[1] += take > 1
+                if mc.index_topk:
+                    k, end = mc.index_topk, cur + take
+                    self._dsa_work[0] += 1
+                    self._dsa_work[1] += end > k
+                    self._dsa_work[2] += end if end > k else 0
+                    # sum over positions cur .. end - 1 of min(p + 1, k)
+                    low = max(min(end, k) - cur, 0)
+                    self._dsa_work[3] += (
+                        low * (2 * cur + low + 1) // 2 + (take - low) * k)
                 if mc.kv_latent:
                     self._latent_work[0] += sum(
                         self._pages_held(cur + min(i + LATENT_TQ, take))
@@ -1651,6 +1698,10 @@ class ServingEngine:
                 "selected_token_rows": self._selected_work[1],
                 "kda_rows": self._kda_work[0],
                 "kda_chunk_rows": self._kda_work[1],
+                "dsa_rows": self._dsa_work[0],
+                "dsa_sparse_rows": self._dsa_work[1],
+                "index_keys_scanned": self._dsa_work[2],
+                "dsa_selected_tokens": self._dsa_work[3],
                 "latent_pages_walked": self._latent_work[0],
                 "latent_rows": self._latent_work[1],
                 "packed_rows": len(tokens),
