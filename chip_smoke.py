@@ -669,6 +669,64 @@ def sala_leg(devices, on_chip: bool = True) -> dict:
     }
 
 
+def keye_leg() -> dict:
+    """PR 50's selection kernel against its XLA twin at the shapes of
+    the benchmark's ``keyevl2.docs16k`` (16 slots, a block table of
+    1024 pages of 128, top-2048): a decode-only step (16 one-token rows
+    at 13k-26k keys) and a step that holds a chunk (one 256-token row
+    at 16k keys, a 5-token tail and a row under ``topk`` beside 13
+    one-token rows, one slot not batched), each once on random scores
+    (no tie at the kth place) and once on scores rounded to 1/64 (ties
+    at every kth place, kept by their place). The mask words must be
+    EQUAL, bit for bit."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from triton_distributed_tpu.kernels import token_select as ts
+
+    t0 = time.perf_counter()
+    slots, pps, page, topk = 16, 1024, 128, 2048
+    sizes = dict(page=page, pps=pps, topk=topk)
+    decode = np.linspace(13000, 26273, slots).astype(np.int32)
+    steps = {
+        "decode": (np.ones(slots, np.int32), decode),
+        "chunk": (np.asarray([256, 5, 1, 0] + [1] * 12, np.int32),
+                  np.concatenate([[16384 + 256, 9001, 1500, 4000],
+                                  decode[4:]]).astype(np.int32)),
+    }
+    equal = {}
+    for name, (q_lens, kv_lens) in steps.items():
+        blocks = -(-q_lens // 8) * 8
+        q_starts = (np.cumsum(blocks) - blocks) * (q_lens > 0)
+        t = int(blocks.sum()) + 8
+        pos = np.full((t,), -1, np.int32)
+        for n, ln, s in zip(q_lens, kv_lens, q_starts):
+            pos[s:s + n] = np.arange(ln - n, ln)
+        scores = jax.random.normal(
+            jax.random.PRNGKey(SEED), (t, ts.scores_width(pps, page)),
+            jnp.float32)
+        # what the scan leaves where no query has a key in view
+        scores = jnp.where(
+            jnp.arange(scores.shape[1])[None, :] > jnp.asarray(pos)[:, None],
+            3e38, scores)
+        for kind, sc in (("random", scores),
+                         ("tied", jnp.round(scores * 64) / 64)):
+            got = ts.select_tokens(
+                sc, *(jnp.asarray(a, jnp.int32)
+                      for a in (kv_lens, q_lens, q_starts)), **sizes)
+            want = ts.select_tokens_xla(sc, jnp.asarray(pos), **sizes)
+            same = bool(jnp.array_equal(got, want))
+            kept = int(jnp.sum(jax.lax.population_count(got)))
+            need(same, f"the selection kernel's mask words differ from "
+                       f"its XLA twin's on the {name} step, {kind} scores")
+            need(kept == int(np.minimum(pos + 1, topk)[pos >= 0].sum()),
+                 f"{name} step, {kind} scores: {kept} keys kept")
+            equal[f"{name}.{kind}"] = same
+    return {"leg": "keye", "words_equal": equal,
+            "twins_s": round(time.perf_counter() - t0, 2)}
+
+
 def mla_leg(devices) -> dict:
     """PR 35's latent pool on the chip: a width-cut twin of
     ``presets.dots_vlm1`` serves three short requests through
@@ -830,7 +888,9 @@ def main(argv=None) -> int:
                          "selected walk and the lightning mixer against "
                          "their twins + one published rung compiled), mla "
                          "(a width-cut latent-attention twin served by "
-                         "kernels and by XLA twins)")
+                         "kernels and by XLA twins), keye (the token "
+                         "selection's kernel against its twin at the "
+                         "benchmark's shapes)")
     legs = ap.parse_args(argv).legs.split(",")
     t_start = time.perf_counter()
     # a wedged collective must end as a failure with every thread's
@@ -865,6 +925,8 @@ def main(argv=None) -> int:
                 say(**sala_leg(devs[:1]))
             if "mla" in legs:
                 say(**mla_leg(devs[:1]))
+            if "keye" in legs:
+                say(**keye_leg())
             if "dsmoe" in legs:
                 say(**leg(devs[:1]))
                 if len(devs) >= 4:

@@ -25,7 +25,7 @@ ROOT = pathlib.Path(__file__).resolve().parents[1]
 if str(ROOT) not in sys.path:
     sys.path.insert(0, str(ROOT))
 
-from benchmark.harness import weights  # noqa: E402
+from benchmark.harness import metrics, weights  # noqa: E402
 from benchmark.models import keye_vl2 as ref  # noqa: E402
 from conftest import serve_all_logits  # noqa: E402
 from triton_distributed_tpu.kernels import token_select as ts  # noqa: E402
@@ -209,39 +209,160 @@ def _step(rows, *, page=8, pps=16, seed=0, slots=None):
         token_rows=jnp.asarray(token_rows), token_pos=jnp.asarray(token_pos))
 
 
-@pytest.mark.parametrize("rows", [
-    [(3, 1), (7, 1)],                       # no row past topk: no score read
-    [(20, 1), (5, 1), (30, 1)],             # decode-only, cap 32
-    [(100, 1), (9, 1)],                     # decode-only, cap 128
-    [(0, 16), (40, 1), (8, 5)],             # a chunk beside decode rows
-    [(3, 5), (90, 1), (20, 16)],            # a short tail beside both
-    [(100, 16), (120, 1)],                  # the top cap
-], ids=["dense", "single32", "single128", "mixed", "tail", "top_cap"])
-def test_the_selection_is_the_references_at_every_context_cap(
-        rows, monkeypatch):
-    """``select_tokens`` on random scores: every live query position's
-    kept set is the reference's top-k of the same scores (a row at or
-    under ``topk`` keeps all), whichever rung of the ladder and whether
-    the step is decode-only or not; padding tokens keep nothing."""
-    topk = 8
-    st = _step(rows)
+def _select(how, scores, st, topk):
+    """The step's mask words by the kernel (interpreted) or its twin."""
+    sizes = dict(page=st["page"], pps=st["pps"], topk=topk)
+    if how == "xla_twin":
+        return ts.select_tokens_xla(scores, st["token_pos"], **sizes)
+    return ts.select_tokens(
+        scores, st["kv_lens"], st["q_lens"], st["q_starts"], **sizes)
+
+
+def _assert_the_references_choice(words, scores, st, topk):
     cap = st["pps"] * st["page"]
-    assert ts.select_caps(2048, 1024 * 128) == [2048, 32768, 131072]
-    assert ts.select_caps(topk, cap) == [8, 128]
-    monkeypatch.setattr(ts, "STEP", 4)      # the middle rung, at this size
-    assert ts.select_caps(topk, cap) == [8, 32, 128]
-    scores = jax.random.normal(
-        jax.random.PRNGKey(1), (st["t"], ts.scores_width(st["pps"], 8)))
-    words = ts.select_tokens(
-        scores, st["token_rows"], st["token_pos"], st["kv_lens"],
-        st["q_lens"], st["q_starts"], page=8, pps=st["pps"], topk=topk)
-    assert words.shape == (ts.word_planes(st["pps"]), st["t"], 8)
-    got = np.asarray(ts.unpack_words(words, st["pps"]))
+    assert words.shape == (ts.word_planes(st["pps"]), st["t"], st["page"])
     pos = np.asarray(st["token_pos"])
     want = _reference_kept(np.asarray(scores)[:, :cap],
                            np.maximum(pos, 0), topk)
-    want = want & (pos >= 0)[:, None]
-    np.testing.assert_array_equal(got, want)
+    np.testing.assert_array_equal(
+        np.asarray(ts.unpack_words(words, st["pps"])),
+        want & (pos >= 0)[:, None])
+
+
+@pytest.mark.parametrize("how", ["xla_twin", "kernel_interpreted"])
+@pytest.mark.parametrize("rows", [
+    [(3, 1), (7, 1)],                       # no row past topk: no score read
+    [(20, 1), (5, 1), (30, 1)],             # decode-only, 4 pages in view
+    [(100, 1), (9, 1)],                     # decode-only, 13 pages in view
+    [(0, 16), (40, 1), (8, 5)],             # a chunk beside decode rows
+    [(3, 5), (90, 1), (20, 16)],            # a short tail beside both
+    [(100, 16), (120, 1)],                  # the table's reach
+], ids=["dense", "single32", "single128", "mixed", "tail", "top_cap"])
+def test_the_selection_is_the_references_at_every_context_cap(rows, how):
+    """The selection on random scores: every live query position's
+    kept set is the reference's top-k of the same scores (a row at or
+    under ``topk`` keeps all), however many pages its row holds and
+    whether the step is decode-only or not; padding tokens and tokens
+    of no batched row keep nothing. The kernel's words are the twin's
+    bit for bit."""
+    topk = 8
+    st = _step(rows)
+    scores = jax.random.normal(
+        jax.random.PRNGKey(1), (st["t"], ts.scores_width(st["pps"], 8)))
+    words = _select(how, scores, st, topk)
+    _assert_the_references_choice(words, scores, st, topk)
+    np.testing.assert_array_equal(
+        np.asarray(words), np.asarray(_select("xla_twin", scores, st, topk)))
+
+
+def _edge(case):
+    """(rows, ``_step``'s other arguments, scores -> scores): what the
+    kernel must get right where its tiles, pages and passes meet."""
+    rng = np.random.default_rng(11)
+
+    def ties(sc, pos):
+        # five keys above all, then equal scores from key 5 on in every
+        # other place, the rest far below: the ties cross page boundaries
+        # (8) and a one-token row's plane boundary (64), and the topk-th
+        # place falls among them
+        sc -= 10.0
+        sc[:, 5::2] = 0.5
+        sc[:, :5] = 2.0 + np.arange(5)
+        return sc
+
+    def zeros(sc, pos):
+        # -inf, -0.0, +0.0 and a few positive scores: fewer than topk
+        # keys above zero, so the kth is a zero of either sign
+        kind = rng.integers(0, 8, sc.shape)
+        return np.select([kind == 0, kind < 3, kind < 7],
+                         [-np.inf, -0.0, 0.0], np.abs(sc)).astype(np.float32)
+
+    def garbage(sc, pos):
+        # what the scan leaves where no query has a key in view
+        return np.where(np.arange(sc.shape[1])[None, :] > pos[:, None],
+                        3e38, sc).astype(np.float32)
+
+    wide = dict(pps=256)          # 32-page planes a word bit: 8 planes
+    return {
+        "ties_across_pages": ([(70, 1), (20, 16), (9, 3)], {}, ties),
+        "ties_in_a_chunk_from_zero": ([(0, 16), (100, 1)], {}, ties),
+        "at_topk_and_one_past": ([(7, 1), (8, 1), (0, 8), (1, 8)], {}, None),
+        "ends_mid_page": ([(42, 1), (50, 3), (27, 16)], {}, None),
+        "zeros_of_both_signs": ([(60, 1), (30, 16), (12, 5)], {}, zeros),
+        "garbage_out_of_view": ([(33, 1), (18, 16), (90, 2)], {}, garbage),
+        "rows_outside_the_batch": ([(40, 1), (10, 16)], dict(slots=5),
+                                   garbage),
+        "a_wide_table": ([(1500, 1), (700, 16), (30, 5), (2040, 1)], wide,
+                         garbage),
+        "ties_in_a_wide_table": ([(1100, 1), (300, 16)], wide, ties),
+    }[case]
+
+
+@pytest.mark.parametrize("case", [
+    "ties_across_pages", "ties_in_a_chunk_from_zero", "at_topk_and_one_past",
+    "ends_mid_page", "zeros_of_both_signs", "garbage_out_of_view",
+    "rows_outside_the_batch", "a_wide_table", "ties_in_a_wide_table"])
+def test_the_selection_kernel_at_its_edges(case):
+    """The kernel's words equal the twin's bit for bit, and both are the
+    reference's choice: ties kept from the lower key across a page and
+    a plane boundary, a row exactly at ``topk`` and one past it, a
+    context that ends inside a page, -inf / -0.0 / equal zeros at the
+    kth place, huge values where no query has a key in view (which
+    change nothing), slots no row of the batch uses, a table wide
+    enough (256 pages) that a key block is eight word planes at one
+    bit."""
+    topk = 8
+    rows, step, plant = _edge(case)
+    st = _step(rows, **step)
+    base = np.asarray(jax.random.normal(
+        jax.random.PRNGKey(2), (st["t"], ts.scores_width(st["pps"], 8))))
+    pos = np.asarray(st["token_pos"])
+    scores = jnp.asarray(plant(base.copy(), pos) if plant else base)
+    words = _select("kernel_interpreted", scores, st, topk)
+    np.testing.assert_array_equal(
+        np.asarray(words), np.asarray(_select("xla_twin", scores, st, topk)))
+    _assert_the_references_choice(words, scores, st, topk)
+    if case.startswith("garbage"):
+        np.testing.assert_array_equal(np.asarray(words), np.asarray(
+            _select("kernel_interpreted", jnp.asarray(base), st, topk)))
+
+
+def test_the_selections_counter_and_the_two_metric_files_that_read_it():
+    """``EngineStats.dsa_select_pairs``: a row's query positions past
+    ``topk`` x the keys of the pages it holds, summed over the device
+    steps — a prompt of 43 in chunks of 16 (8 x 16, 16 x 32, 11 x 48)
+    and three decode steps (1 x 48 each) at pages of 8, ``topk`` 8. The
+    benchmark's two PR 50 metric files read it and the step times; a
+    program without the counter reads 0 and nothing raises."""
+    import json
+
+    model, _, params = seeded(tiny_config())
+    ecfg = EngineConfig(slots=1, token_budget=32, chunk=16, page=8,
+                        npages=16)
+    eng, _, _ = serve_all_logits(
+        model, params, ecfg, prompts_of((43,), seed=3), max_new=4)
+    assert eng.stats.dsa_select_pairs == 8 * 16 + 16 * 32 + 11 * 48 + 3 * 48
+    assert eng.stats.index_keys_scanned == 16 + 32 + 43 + 44 + 45 + 46
+    layer_metrics = ROOT / "benchmark" / "layer_metrics"
+    read = {
+        name: json.loads((layer_metrics / f"{name}.json").read_text())
+        for name in ("dsa_select_pairs_per_step", "keyevl2_step_ms_p95")}
+    rec = {"counters": {"stats.dsa_select_pairs": 1312, "device_steps": 6},
+           "series": {"step_device_ms": [11.0] * 19 + [30.0]}}
+    assert metrics.read_layer_metric(
+        rec, read["dsa_select_pairs_per_step"]) == pytest.approx(1312 / 6)
+    assert 11.0 < metrics.read_layer_metric(
+        rec, read["keyevl2_step_ms_p95"]) <= 30.0
+    parent = {"counters": {"device_steps": 6}, "series": {}}
+    assert metrics.read_layer_metric(
+        parent, read["dsa_select_pairs_per_step"]) == 0
+    assert metrics.read_layer_metric(
+        parent, read["keyevl2_step_ms_p95"]) is None
+    entries = {m["name"]: m for m in json.loads(
+        (ROOT / "BENCHMARK.json").read_text())["per_layer"]}
+    for name in read:
+        assert entries[name]["workloads"] == ["keyevl2.docs16k"]
+        assert entries[name]["moves"] == "itl_p95_ms"
 
 
 # ------------------------------------------------------------ (c) the walk

@@ -16,40 +16,48 @@ head weights ``w[t, j]``::
 
 ONE selection a query position, shared by every head.
 
-Three stages, each a function here and its XLA twin:
+Three stages, each a Pallas kernel here with its XLA twin (the twins
+gather a row's whole table reach a token: the CPU references', the
+tests' and the degraded engine's sizes), each a grid over the ROWS with
+``kv_lens`` / ``q_lens`` / ``q_starts`` prefetched, each visiting a row
+by what it holds: its own pages, a one-token row as its 8-token packing
+slot, a longer row its tokens:
 
-``index_scores``   the SCAN. A Pallas kernel, grid over the rows: a
-                   row's queries (one 8-token packing slot, or the
-                   launch's ``block_q`` tokens) against its indexer
-                   keys, a block of pages an iteration, ``I`` out in
-                   float32. A row whose context is at most ``topk``
-                   selects everything and is not scanned.
-``select_tokens``  the SELECTION, XLA: the bitwise threshold search of
-                   ``sparse_select.kth_largest_key`` on ``I``, ties by a
-                   running count, under ``select_caps``' three context
-                   caps (``lax.switch`` takes the smallest that covers
-                   the longest row), once for the one-token rows' ``R``
-                   queries and once for the longer rows' packed tokens,
-                   so a chunk's choice is made at ITS context's cap,
-                   not at the longest resident decode row's. Hands the
-                   walk its MASK WORDS.
-``token_walk``     the WALK. A Pallas kernel, grid over the rows: the
-                   online-softmax page walk of ``ragged_paged_attention``
-                   under a per-(query position, key) mask. It reads
-                   every page of the row's context: with seeded weights
-                   the kept tokens of a 20k-token context lie in every
-                   one of its pages (~13 a page), so a walk by page has
-                   nothing to skip; a gather by token is 2 x 4 copies of
-                   256 bytes a token from a head-major pool.
+``index_scores``   the SCAN (launch ``dsa_index_scores``; twin
+                   ``index_scores_xla``): a row's queries (one 8-token
+                   packing slot, or the launch's ``block_q`` tokens)
+                   against its indexer keys, a block of pages an
+                   iteration, ``I`` out in float32. A row whose context
+                   is at most ``topk`` selects everything and is not
+                   scanned.
+``select_tokens``  the SELECTION (launch ``dsa_select_tokens``; twin
+                   ``select_tokens_xla``): a row's queries 8 at a time,
+                   each tile among the scores of the row's OWN pages,
+                   read into VMEM once as order-preserving integers. The
+                   ``topk``-th largest key of every query is found bit
+                   by bit (``BITS`` a pass, a pass a compare-and-count
+                   over the resident keys), ties are kept from the lower
+                   key by a count carried across the pages, and the
+                   tile's MASK WORDS are written straight out. A query
+                   below ``topk`` keeps every key in view and reads no
+                   score.
+``token_walk``     the WALK (launch ``ragged_paged_attention_tokens``;
+                   twin ``token_walk_xla``): the online-softmax page walk
+                   of ``ragged_paged_attention`` under a per-(query
+                   position, key) mask. It reads every page of the row's
+                   context: with seeded weights the kept tokens of a
+                   20k-token context lie in every one of its pages (~13
+                   a page), so a walk by page has nothing to skip; a
+                   gather by token is 2 x 4 copies of 256 bytes a token
+                   from a head-major pool.
 
 THE MASK WORDS ``(PW, T, page)`` int32, ``PW = ceil(pps / 32)``: key
 ``s`` of a row, in its logical page ``p = s // page`` at offset ``k``,
 is bit ``p // PW`` of word ``[p % PW, t, k]``. A page's ``page`` keys
-are one bit of ``page`` consecutive words: the walk takes plane ``p %
-PW`` (a dynamic index on a leading dimension), shifts by ``p // PW``
-and has the page's mask with no gather and no lane shuffle. The width
-does not depend on the context cap, so the walk is one launch outside
-the ``lax.switch``.
+are one bit of ``page`` consecutive words: the selection ORs a page's
+shifted 0 / 1 mask into plane ``p % PW``, and the walk takes that plane
+(a dynamic index on a leading dimension), shifts by ``p // PW`` and has
+the page's mask with no gather and no lane shuffle.
 """
 
 from __future__ import annotations
@@ -74,16 +82,30 @@ from triton_distributed_tpu.lang.launch import shmem_call
 #: 8-token packing slot, whatever the launch's ``block_q``
 SHORT = 8
 #: pages of one key block of such a row (fetched into one buffer,
-#: scored as one block); a longer row goes a page at a time. 8: a
+#: scored as one block); a longer row goes a page at a time; the
+#: selection's key planes of one ``kbuf`` entry, whatever the row. 8: a
 #: decode row holds 100-200 pages at the benchmark's contexts, and the
 #: selected walk's probe (CHANGES.md, PR 45) read 0.131 us a page at 8
 #: against 0.167 at 4 on lists a quarter as long
 KV_PAGES = 8
 #: lanes of a vreg: the stored width of an indexer key is whole tiles
 LANES = 128
-#: the middle rung of ``select_caps``, in ``topk``s: 32768 keys at the
-#: published 2048, over the benchmark's longest context (27136)
-STEP = 16
+#: bits of the key a pass of the selection's search settles (1, 2 or
+#: 4: ``2^BITS - 1`` candidates a pass, up to ``32 / BITS`` passes). 2:
+#: a pass ends in a reduction across lanes, a branch on its result and
+#: the next candidates' broadcast, which a 16-row decode step pays 16
+#: times a pass; on the v5e (PERF.md section 5, PR 50) a launch of 16
+#: one-token rows at 13k-26k keys takes 143 us at 2 against 160 at 1, a
+#: 256-token row at 8k / 21k keys 290 / 415 against 343 / 458; 4 bits
+#: (15 candidates a pass) read 2304-21248 keys 1.3-2.2 x slower than 2
+BITS = 2
+#: entries of ``kbuf`` a step of the search's counting loop reads (the
+#: loop's scalar work and register moves, once a step, cost as much as
+#: one entry's 24 vector operations: 19 bundles for two entries against
+#: 13 for one)
+STRIDE = 2
+I32_MIN = -(1 << 31)
+I32_MAX = (1 << 31) - 1
 
 
 def index_stored(index_dim: int) -> int:
@@ -163,85 +185,412 @@ def choose_tokens(scores, topk: int):
     return above | (tie & (_running_count(tie) <= room.astype(jnp.float32)))
 
 
-def select_caps(topk: int, capacity: int) -> list:
-    """The ladder of context caps a choice is made at: ``topk`` (no
-    score is read), ``STEP · topk`` and the block table's reach. Three
-    rungs, not a fine ladder: every rung is a branch of every layer of
-    every step program (PERF.md §6, PR 49: ten branches a layer made
-    executables of ~36 MB and a cold start of 165 s)."""
-    caps = [min(topk, capacity)]
-    for cap in (STEP * topk, capacity):
-        if caps[-1] < capacity:
-            caps.append(min(cap, capacity))
-    return caps
+def select_tokens_xla(scores, token_pos, *, page: int, pps: int, topk: int):
+    """The selection's twin: every packed token's choice among ALL the
+    keys its row's table reaches, ``scores`` (T, >= pps · page) float32
+    -> the mask words ``(PW, T, page)`` int32: every key in view up to
+    ``topk`` of them, then the ``topk`` best by score; zeros for a
+    padding token."""
+    cap = pps * page
+    seen = ((jnp.arange(cap)[None, :] <= token_pos[:, None])
+            & (token_pos >= 0)[:, None])
+    kept = seen
+    if cap > topk:                          # else no query chooses by score
+        kept = choose_tokens(
+            jnp.where(seen, scores[:, :cap], -jnp.inf), topk) & seen
+        kept = jnp.where((token_pos < topk)[:, None], seen, kept)
+    return pack_words(kept, page=page, pps=pps)
 
 
-def _kept(scores, pos, on, cap, topk):
-    """bool (N, cap): the keys each of ``N`` queries at ``pos`` keeps
-    (none where not ``on``): every key in view up to ``topk`` of them,
-    then the ``topk`` best by ``scores`` (N, >= cap)."""
-    seen = (jnp.arange(cap)[None, :] <= pos[:, None]) & on[:, None]
-    if cap == topk:
-        return seen                         # no row past topk: no score read
-    kept = choose_tokens(jnp.where(seen, scores[:, :cap], -jnp.inf), topk)
-    return jnp.where((pos < topk)[:, None], seen, kept & seen)
+def _select_kernel(page, pps, topk, *refs):
+    """Grid (R,): row ``r``'s queries, ``SHORT`` positions a TILE, each
+    tile among the keys of the row's own pages.
+
+    A tile's scores are copied into a slot of ``kbuf`` a block of
+    ``KV_PAGES`` pages an entry, all copies in flight at once and
+    started while the tile BEFORE it (of this row or of the row before)
+    is searched, then turned, in place, into order-preserving integers
+    (kept in the float32 they came as), MIN where a query does not see
+    the key: ``KV_PAGES`` PLANES ``(SHORT, page)`` an entry. A row of
+    more tokens than one: a plane is a page, a query a sublane. A
+    ONE-TOKEN row: a plane holds eight pages of its one query, a page a
+    sublane, packed to the front of the slot (no plane carries seven
+    dead sublanes through the search). Between the two forms' loading
+    and writing the SEARCH is shared: up to ``32 / BITS`` passes, each
+    counting the keys that reach ``2^BITS - 1`` candidates, find every
+    sublane's ``topk``-th largest key and how many keys lie above it;
+    it stops at the pass after which EXACTLY ``topk`` keys reach every
+    choosing query's candidate (then the keys that reach it are kept;
+    only where more reach a fully settled kth are ties ranked). The
+    tile's words leave ``wacc`` under the next tile's search."""
+    (kv_lens_ref, q_lens_ref, q_starts_ref, sc_hbm, _, out_hbm, kbuf, wacc,
+     tri, kth_ref, room_ref, flying, sem_s, sem_o) = refs
+    r = pl.program_id(0)
+    pw = wacc.shape[0]
+    span = KV_PAGES * page
+    plane = (SHORT, page)
+    rows = pl.num_programs(0)
+
+    def plan(row, j):
+        """Tile ``j`` of ``row``: its first token and first position,
+        the row's key blocks, and whether a live query of the tile
+        chooses by score (else nothing of its scores is read)."""
+        kv_len, q_len = kv_lens_ref[row], q_lens_ref[row]
+        nb = jnp.minimum(_n_valid_pages(kv_len, page), pps)
+        first = kv_len - q_len + j * SHORT
+        need = first + jnp.minimum(q_len - j * SHORT, SHORT) > topk
+        return (pl.multiple_of(q_starts_ref[row] + j * SHORT, SHORT), first,
+                jax.lax.div(nb + KV_PAGES - 1, KV_PAGES), need)
+
+    q_len = q_lens_ref[r]
+    one = q_len == 1
+    ntile = jax.lax.div(q_len + SHORT - 1, SHORT)
+    nblk = plan(r, 0)[2]
+    ngrp = jax.lax.div(nblk + KV_PAGES - 1, KV_PAGES)   # of a one-token row
+    sub = jax.lax.broadcasted_iota(jnp.int32, plane, 0)
+    lane = jax.lax.broadcasted_iota(jnp.int32, plane, 1)
+
+    @pl.when(r == 0)
+    def _first():
+        flying[0] = 0                     # a tile's words are on their way out
+        flying[1] = 0                     # the kbuf slot of the next tile
+        flying[2] = -1                    # (row, tile) whose scores are on
+        flying[3] = -1                    # their way into that slot
+        tri[...] = (                      # tri[k, l] = k <= l
+            jax.lax.broadcasted_iota(jnp.int32, (page, page), 0)
+            <= jax.lax.broadcasted_iota(jnp.int32, (page, page), 1)
+        ).astype(tri.dtype)
+
+    def lanes(u):
+        return slice(u * page, (u + 1) * page)
+
+    def as_int(x):                        # kbuf is float32, as the copies are
+        return jax.lax.bitcast_convert_type(x, jnp.int32)
+
+    def as_float(x):
+        return jax.lax.bitcast_convert_type(x, jnp.float32)
+
+    def int_keys(b, seen):
+        """A float32's bits -> the int32 of the same order (-0.0 as
+        +0.0), MIN where not ``seen``."""
+        b = jnp.where(b == I32_MIN, 0, b)
+        return jnp.where(seen, jnp.where(b < 0, b ^ I32_MAX, b), I32_MIN)
+
+    def scores(slot, t0, blocks, arrive):
+        """The scores of the tile at token ``t0`` against its row's
+        ``blocks`` key blocks into ``kbuf[slot]``: started, or awaited
+        (a slice of fewer rows than a tile is no copy Mosaic makes: a
+        one-token row's seven other rows come along)."""
+        def copy(i, _):
+            cp = pltpu.make_async_copy(
+                sc_hbm.at[pl.ds(t0, SHORT),
+                          pl.ds(pl.multiple_of(i * span, span), span)],
+                kbuf.at[slot, i], sem_s.at[slot])
+            cp.wait() if arrive else cp.start()
+            return 0
+
+        jax.lax.fori_loop(0, blocks, copy, 0)
+
+    def rank_of(tie):
+        """A tie's place among its sublane's ties: a product with a
+        triangle of ones (0 / 1 both sides: exact)."""
+        return jnp.dot(tie.astype(tri.dtype), tri[...],
+                       preferred_element_type=jnp.float32)
+
+    def tile(j, _):
+        t0, first, _, need = plan(r, j)
+        live = j * SHORT + sub < q_len
+        pos = first + jnp.where(one, 0, sub)
+        slot = flying[1]
+        kb = kbuf.at[slot]
+
+        @pl.when(need)
+        def _scores():
+            @pl.when(jnp.logical_or(flying[2] != r, flying[3] != j))
+            def _now():
+                scores(slot, t0, nblk, False)
+
+            scores(slot, t0, nblk, True)
+
+        # the next tile's scores, of this row or of the row after it,
+        # come in under this tile's search
+        last = j + 1 == ntile
+        after = jnp.minimum(r + 1, rows - 1)
+        row2, j2 = jnp.where(last, after, r), jnp.where(last, 0, j + 1)
+        t2, _, blocks2, need2 = plan(row2, j2)
+        ahead = jnp.logical_and(need2, jnp.logical_or(
+            jnp.logical_not(last),
+            jnp.logical_and(r + 1 < rows, q_lens_ref[after] > 0)))
+        flying[1] = 1 - slot
+        flying[2] = jnp.where(ahead, row2, -1)
+        flying[3] = j2
+        pl.when(ahead)(lambda: scores(1 - slot, t2, blocks2, False))
+
+        def seen_one(q):
+            """Plane ``q`` of a one-token row: pages ``8q .. 8q + 7``."""
+            return (q * KV_PAGES + sub) * page + lane <= pos
+
+        def seen_tile(p):
+            return (p * page + lane <= pos) & live
+
+        @pl.when(jnp.logical_and(need, one))
+        def _keys_one():
+            def group(g, _):
+                # entries 8g .. 8g + 7, a plane each, into entry g
+                planes = []
+                for u in range(KV_PAGES):
+                    q = g * KV_PAGES + u
+                    at = jnp.minimum(q, nblk - 1)
+                    dense = kb[at, :, lanes(0)]     # row 0 in sublane 0
+                    for v in range(1, KV_PAGES):  # a sublane broadcast each
+                        dense = jnp.where(
+                            sub == v, kb[at, 0:1, lanes(v)], dense)
+                    planes.append(int_keys(as_int(dense), seen_one(q)))
+                for u, keys in enumerate(planes):
+                    kb[g, :, lanes(u)] = as_float(keys)
+                return 0
+
+            jax.lax.fori_loop(0, ngrp, group, 0)
+
+        @pl.when(jnp.logical_and(need, jnp.logical_not(one)))
+        def _keys_tile():
+            def block(g, _):
+                for u in range(KV_PAGES):
+                    kb[g, :, lanes(u)] = as_float(int_keys(
+                        as_int(kb[g, :, lanes(u)]),
+                        seen_tile(g * KV_PAGES + u)))
+                return 0
+
+            jax.lax.fori_loop(0, nblk, block, 0)
+
+        # --- the search: a sublane's kth key, bit by bit from the top
+        cands = range(1, 1 << BITS)
+        chooses = (pos >= topk) & (jnp.where(
+            one, need.astype(jnp.int32), live.astype(jnp.int32)) > 0)
+
+        def one_pass(carry):
+            i, kth, above, reach, _ = carry             # (SHORT, 1) each
+            shift = 32 - BITS * (i + 1)
+            tried = [jnp.broadcast_to(
+                (kth | jax.lax.shift_left(jnp.int32(c), shift)) ^ I32_MIN,
+                plane) for c in cands]
+
+            def count(step, at, accs):
+                # ``step`` entries from ``at``; KV_PAGES running counts a
+                # candidate, a plane each: no sum waits for another
+                for b in range(step):
+                    keys = as_int(kb[at + b])
+                    accs = tuple(
+                        tuple(a + (keys[:, lanes(u)] >= c).astype(jnp.int32)
+                              for u, a in enumerate(acc))
+                        for acc, c in zip(accs, tried))
+                return accs
+
+            n = jnp.where(one, ngrp, nblk)
+            whole = jax.lax.div(n, STRIDE)
+            accs = jax.lax.fori_loop(
+                0, whole, lambda i, a: count(STRIDE, i * STRIDE, a),
+                tuple(tuple(jnp.zeros(plane, jnp.int32)
+                            for _ in range(KV_PAGES)) for _ in cands))
+            accs = jax.lax.fori_loop(
+                whole * STRIDE, n, functools.partial(count, 1), accs)
+            cnts = []                     # float32: exact below 2^24
+            for acc in accs:
+                acc = list(acc)
+                while len(acc) > 1:           # a tree, not a chain
+                    acc = [x + y for x, y in zip(acc[::2], acc[1::2])]
+                cnt = jnp.sum(acc[0].astype(jnp.float32), axis=1,
+                              keepdims=True)
+                cnts.append(jnp.where(
+                    one, jnp.sum(cnt, axis=0, keepdims=True), cnt))
+            # ``topk`` keys reach the candidates up to ``digit``: kth is
+            # the largest of them in the last pass that has one, kth + 1
+            # the smallest of the others in the last pass that has one
+            digit = jnp.zeros((SHORT, 1), jnp.int32)
+            for cnt in cnts:
+                reach = jnp.where(cnt >= topk, cnt, reach)
+                digit = digit + (cnt >= topk).astype(jnp.int32)
+            for cnt in reversed(cnts):
+                above = jnp.where(cnt >= topk, above, cnt)
+            kth = kth | jax.lax.shift_left(digit, jnp.full_like(digit, shift))
+            # a query whose candidate EXACTLY topk keys reach is done:
+            # it keeps the keys that reach it, whatever the lower bits
+            todo = jnp.sum(jnp.where(
+                chooses & (jnp.broadcast_to(reach, plane) != topk), 1, 0
+            )[:, :1])
+            return i + 1, kth, above, reach, todo
+
+        kth = jnp.zeros((SHORT, 1), jnp.int32)
+        none = jnp.zeros((SHORT, 1), jnp.float32)
+        _, kth, above, _, todo = jax.lax.while_loop(
+            lambda c: jnp.logical_and(c[0] < 32 // BITS, c[4] > 0),
+            one_pass, (0, kth, none, none, need.astype(jnp.int32)))
+        kth_ref[...] = jnp.broadcast_to(kth ^ I32_MIN, plane)
+        room_ref[...] = jnp.broadcast_to(topk - above, plane)
+        # every bit settled and still more than topk keys reach some
+        # query's kth: its ties are kept by their place
+        ranked = todo > 0
+
+        # --- the mask words (the tile's before them, of this row or of
+        # one before it, leave ``wacc`` under this tile's search)
+        landed(t0)
+        wacc[...] = jnp.zeros(wacc.shape, jnp.int32)
+        low = pos < topk
+
+        def kept_of(k, seen, before, ahead=None):
+            """The plane's kept keys (int32 0 / 1) and the ties ahead
+            of the next plane; ``before`` ties ahead of this one (None:
+            every tie is kept); ``ahead(ties)``: the ties of the
+            sublanes before each one, where the plane's sublanes are
+            one query's."""
+            kth = kth_ref[...]
+            if before is None:
+                return (seen & (low | (k >= kth))).astype(jnp.int32), None
+            tie = k == kth
+            rank = rank_of(tie)
+            ties = rank[:, page - 1:page]
+            if ahead is None:
+                after = before + ties
+            else:
+                after = before + jnp.sum(ties, axis=0, keepdims=True)
+                before = before + ahead(ties)
+            kept = (k > kth) | (
+                tie & (before + rank <= room_ref[...]))
+            return (seen & (low | kept)).astype(jnp.int32), after
+
+        def put(m, kept):
+            """``kept``: KV_PAGES arrays (rows, page) of pages ``8m ..
+            8m + 7`` into the words of the tile's first ``rows``
+            tokens."""
+            rows = kept[0].shape[0]
+            if pw % KV_PAGES:                 # a narrow table: page by page
+                for v, k in enumerate(kept):
+                    p = m * KV_PAGES + v
+                    q = jax.lax.rem(p, pw)
+                    wacc[q, :rows] = wacc[q, :rows] | jax.lax.shift_left(
+                        k, jnp.full_like(k, jax.lax.div(p, pw)))
+                return
+            # the block's pages are consecutive planes at one bit
+            first = m * KV_PAGES
+            at = pl.ds(pl.multiple_of(jax.lax.rem(first, pw), KV_PAGES),
+                       KV_PAGES)
+            new = jnp.stack(kept)
+            wacc[at, :rows] = wacc[at, :rows] | jax.lax.shift_left(
+                new, jnp.full_like(new, jax.lax.div(first, pw)))
+
+        def ahead(ties):
+            wide = jnp.broadcast_to(ties, plane)
+            upto = wide                       # a running sum down the sublanes
+            for d in (1, 2, 4):
+                upto = upto + jnp.where(sub >= d, pltpu.roll(upto, d, 0), 0.0)
+            return upto - wide
+
+        def words_one(by_place):
+            def group(g, before):
+                for u in range(KV_PAGES):
+                    q = g * KV_PAGES + u
+                    kept, before = kept_of(
+                        as_int(kb[g, :, lanes(u)]), seen_one(q), before,
+                        ahead if by_place else None)
+                    put(q, [kept[v:v + 1] for v in range(KV_PAGES)])
+                return before
+
+            jax.lax.fori_loop(
+                0, ngrp, group,
+                jnp.zeros((1, 1), jnp.float32) if by_place else None)
+
+        def words_tile(by_place):
+            def block(g, before):
+                kept = []
+                for u in range(KV_PAGES):
+                    mine, before = kept_of(
+                        as_int(kb[g, :, lanes(u)]),
+                        seen_tile(g * KV_PAGES + u), before)
+                    kept.append(mine)
+                put(g, kept)
+                return before
+
+            jax.lax.fori_loop(
+                0, nblk, block,
+                jnp.zeros((SHORT, 1), jnp.float32) if by_place else None)
+
+        for form, words in ((one, words_one),
+                            (jnp.logical_not(one), words_tile)):
+            for by_place in (False, True):
+                pl.when(jnp.logical_and(form, ranked == by_place))(
+                    functools.partial(words, by_place))
+
+        out(t0).start()
+        flying[0] = 1
+        return 0
+
+    def out(t0):
+        return pltpu.make_async_copy(
+            wacc, out_hbm.at[:, pl.ds(t0, SHORT)], sem_o.at[0])
+
+    def landed(t0):
+        @pl.when(flying[0] > 0)
+        def _():
+            out(t0).wait()                # (every tile's copy is as large)
+            flying[0] = 0
+
+    jax.lax.fori_loop(0, ntile, tile, 0)
+    pl.when(r == rows - 1)(functools.partial(landed, 0))
 
 
-def _rows_at(cap, scores, kv_lens, q_lens, q_starts, *, page, pps, topk):
-    """Mask words ``(PW, R, page)`` of every ONE-TOKEN row's token, with
-    contexts of at most ``cap`` keys in view (zeros for other rows)."""
-    first = jnp.clip(q_starts, 0, scores.shape[0] - 1)
-    return pack_words(
-        _kept(scores[first], kv_lens - 1, q_lens == 1, cap, topk),
-        page=page, pps=pps)
+@functools.lru_cache(maxsize=64)
+def _build_select(r, pps, t, page, topk, interpret):
+    """The selection's pallas_call: ``(kv_lens, q_lens, q_starts, I (T,
+    width) float32, zeros (PW, T, page) int32) -> [words]``, the words
+    written over the zeros (no row visits a token outside the batched
+    rows' blocks)."""
+    assert KV_PAGES == SHORT        # a one-token row's plane: a page a sublane
+    pw = word_planes(pps)
+    any_ = pl.BlockSpec(memory_space=pl.ANY)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(r,),
+        in_specs=[any_, any_],
+        out_specs=[any_],
+        scratch_shapes=[
+            pltpu.VMEM((2, -(-pps // KV_PAGES), SHORT, KV_PAGES * page),
+                       jnp.float32),                               # kbuf
+            pltpu.VMEM((pw, SHORT, page), jnp.int32),              # wacc
+            pltpu.VMEM((page, page), jnp.float32),                 # tri
+            pltpu.VMEM((SHORT, page), jnp.int32),                  # kth
+            pltpu.VMEM((SHORT, page), jnp.float32),                # room
+            pltpu.SMEM((4,), jnp.int32),                           # flying
+            pltpu.SemaphoreType.DMA((2,)),                         # sem_s
+            pltpu.SemaphoreType.DMA((1,)),                         # sem_o
+        ],
+    )
+    return shmem_call(
+        functools.partial(_select_kernel, page, pps, topk),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((pw, t, page), jnp.int32)],
+        collective_id=None,
+        input_output_aliases={4: 0},
+        interpret=local_interpret() if interpret is None else interpret,
+        name="dsa_select_tokens",
+        dimension_semantics=("arbitrary",),
+    )
 
 
-def _packed_at(cap, scores, token_pos, of_long, *, page, pps, topk):
-    """Mask words ``(PW, T, page)`` of the packed tokens ``of_long``
-    (those of the rows of more than one token), with contexts of at
-    most ``cap`` keys in view (zeros for the others)."""
-    return pack_words(_kept(scores, token_pos, of_long, cap, topk),
-                      page=page, pps=pps)
-
-
-def select_tokens(scores, token_rows, token_pos, kv_lens, q_lens,
-                  q_starts, *, page: int, pps: int, topk: int):
+@functools.partial(
+    jax.jit, static_argnames=("page", "pps", "topk", "interpret"))
+def select_tokens(scores, kv_lens, q_lens, q_starts, *, page: int,
+                  pps: int, topk: int, interpret=None):
     """``scores`` (T, >= pps · page) float32 from ``index_scores`` (read
-    only where a live query past ``topk`` has a key in view) -> the
-    mask words ``(PW, T, page)`` int32 of the step.
-
-    Two choices, each one ``lax.switch`` over ``select_caps`` (the
-    smallest cap that covers the longest row of its kind; the first
-    rung reads no score): the ONE-TOKEN rows' (every decode row:
-    ``R`` queries, whatever the step is wide) and the LONGER rows' (a
-    chunk, a prompt's tail: every packed token, at a cap its own rows
-    set, not the resident decode rows')."""
+    only where a live query past ``topk`` has a key in view: the pages
+    of its OWN row's context) -> the mask words ``(PW, T, page)`` int32
+    of the step: for each live query position the ``topk`` largest of
+    its scores, ties to the lower key; every key in view for a query at
+    a position below ``topk``; zeros for every other token."""
     assert topk % page == 0, (topk, page)
-    t = token_pos.shape[0]
-    r = kv_lens.shape[0]
-    kw = dict(page=page, pps=pps, topk=topk)
-    live = token_pos >= 0
-    row_of = jnp.clip(token_rows, 0, r - 1)
-
-    def pick(caps, longest, fn, *operands):
-        branches = [functools.partial(fn, cap, **kw) for cap in caps]
-        if len(branches) == 1:
-            return branches[0](*operands)
-        rung = sum((longest > c).astype(jnp.int32) for c in caps[:-1])
-        return jax.lax.switch(rung, branches, *operands)
-
-    one = q_lens == 1
-    caps = select_caps(topk, pps * page)
-    by_row = pick(
-        caps,
-        jnp.max(jnp.where(one, kv_lens, 0)),
-        _rows_at, scores, kv_lens, q_lens, q_starts)          # (PW, R, page)
-    packed = pick(
-        caps,
-        jnp.max(jnp.where(q_lens > 1, kv_lens, 0)),
-        _packed_at, scores, token_pos, live & (q_lens[row_of] > 1))
-    mine = live & one[row_of]
-    return jnp.where(mine[None, :, None], by_row[:, row_of], packed)
+    t = scores.shape[0]
+    call = _build_select(kv_lens.shape[0], pps, t, page, topk, interpret)
+    return call(kv_lens, q_lens, q_starts, scores,
+                jnp.zeros((word_planes(pps), t, page), jnp.int32))[0]
 
 
 # ------------------------------------------------------------------- scan

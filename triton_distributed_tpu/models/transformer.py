@@ -2270,10 +2270,13 @@ class Transformer:
                     qi, wi, ki_pool, token_rows, state.block_table,
                     scale=scale)
         with scope("dsa_select"):
-            words = ts.select_tokens(
-                scores, token_rows, token_pos, state.kv_lens, q_lens,
-                q_starts, page=state.page, pps=state.pages_per_seq,
-                topk=c.index_topk)
+            sizes = dict(page=state.page, pps=state.pages_per_seq,
+                         topk=c.index_topk)
+            if use_pallas:
+                words = ts.select_tokens(
+                    scores, state.kv_lens, q_lens, q_starts, **sizes)
+            else:
+                words = ts.select_tokens_xla(scores, token_pos, **sizes)
         with scope("dsa_walk"):
             if use_pallas:
                 o = ts.token_walk(

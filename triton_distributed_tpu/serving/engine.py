@@ -349,13 +349,15 @@ class EngineStats:
     # device steps, counted in ``_assemble``: rows through the token
     # walk, those of them whose last position is past ``index_topk``
     # (their tokens are kept by score), the indexer keys those rows'
-    # contexts hold (what ONE layer's scan reads), and the (query
-    # position, key) pairs the walk attends (``min(position + 1,
-    # index_topk)`` a query position)
+    # contexts hold (what ONE layer's scan reads), the (query position,
+    # key) pairs the walk attends (``min(position + 1, index_topk)`` a
+    # query position), and the pairs the selection reads scores of (a
+    # row's query positions past ``index_topk`` x its pages' keys)
     dsa_rows: int = 0
     dsa_sparse_rows: int = 0
     index_keys_scanned: int = 0
     dsa_selected_tokens: int = 0
+    dsa_select_pairs: int = 0
     # the work of a model with a LATENT pool (``kv_latent``; 0 without
     # one), summed over the batched rows of the device steps, counted
     # in ``_assemble``: pages ONE layer's latent walk fetches (a decode
@@ -859,8 +861,8 @@ class ServingEngine:
         self._selected_work = [0, 0]
         self._latent_work = [0, 0]      # likewise: [pages fetched, rows]
         self._kda_work = [0, 0]         # likewise: [rows, chunk rows]
-        # likewise: [rows, rows past index_topk, keys scanned, pairs]
-        self._dsa_work = [0, 0, 0, 0]
+        # [rows, rows past topk, keys scanned, pairs walked, pairs chosen from]
+        self._dsa_work = [0, 0, 0, 0, 0]
         # seconds of the running step inside each phase (``_phase``)
         self._phase_s = dict.fromkeys(PHASES, 0.0)
         # --- multi-tenancy (all defaults reproduce the single-tenant
@@ -1356,7 +1358,7 @@ class ServingEngine:
         self._selected_work = [0, 0]    # [rows, rows of one token]
         self._latent_work = [0, 0]      # [pages fetched, rows]
         self._kda_work = [0, 0]         # [rows, rows of several tokens]
-        self._dsa_work = [0, 0, 0, 0]
+        self._dsa_work = [0, 0, 0, 0, 0]
         mc = self.model.config
         batched: set = set()
         takes: dict = {}
@@ -1421,6 +1423,8 @@ class ServingEngine:
                     low = max(min(end, k) - cur, 0)
                     self._dsa_work[3] += (
                         low * (2 * cur + low + 1) // 2 + (take - low) * k)
+                    self._dsa_work[4] += (
+                        max(end - max(cur, k), 0) * need * cfg.page)
                 if mc.kv_latent:
                     self._latent_work[0] += sum(
                         self._pages_held(cur + min(i + LATENT_TQ, take))
@@ -1702,6 +1706,7 @@ class ServingEngine:
                 "dsa_sparse_rows": self._dsa_work[1],
                 "index_keys_scanned": self._dsa_work[2],
                 "dsa_selected_tokens": self._dsa_work[3],
+                "dsa_select_pairs": self._dsa_work[4],
                 "latent_pages_walked": self._latent_work[0],
                 "latent_rows": self._latent_work[1],
                 "packed_rows": len(tokens),
